@@ -335,15 +335,19 @@ class TestRemotePrefetch:
         win, and byte-exact equality with the serial read."""
         import time as _time
 
-        nbytes = 24 << 20
+        # small blocks, long RTT: the simulated latency (a sleep, which
+        # overlaps on any box) must dominate the byte copies (which do not
+        # under the suite's six-worker load), or the ratio measures the box
+        block = 256 << 10
+        nbytes = 12 * block
         payload = bytes(np.random.default_rng(0).integers(0, 256, nbytes, np.uint8))
         path = mem_url + "/big.bin"
         fs = tfs.filesystem_for(path)
         with fs.open(path, "wb") as fh:
             fh.write(payload)
-        monkeypatch.setenv("TFR_REMOTE_BLOCK_BYTES", str(2 << 20))
+        monkeypatch.setenv("TFR_REMOTE_BLOCK_BYTES", str(block))
         monkeypatch.setenv("TFR_REMOTE_PREFETCH_DEPTH", "4")
-        slow = self._latency_fs(fs, per_read_s=0.04)
+        slow = self._latency_fs(fs, per_read_s=0.1)
 
         def drain(fh):
             # drain at the SAME granularity the link charges latency per
@@ -351,7 +355,7 @@ class TestRemotePrefetch:
             # pipelined — a 4x gap with real margin for per-block overhead
             out = []
             while True:
-                chunk = fh.read(2 << 20)
+                chunk = fh.read(block)
                 if not chunk:
                     return b"".join(out)
                 out.append(chunk)

@@ -17,7 +17,10 @@ from tpu_tfrecord.models import (
     param_shardings,
     train_step,
 )
-from tpu_tfrecord.models.dlrm import batch_shardings
+from tpu_tfrecord.models.dlrm import (
+    batch_shardings,
+    dense_rowwise_adagrad_reference,
+)
 from tpu_tfrecord.tpu.mesh import create_mesh
 
 
@@ -85,33 +88,11 @@ class TestSparseTrainStep:
     CFG = DLRMConfig(num_dense=4, num_categorical=3, vocab_size=64, embed_dim=4,
                      bottom_mlp=(8, 4), top_mlp=(8, 1), dtype=jax.numpy.float32)
 
-    @staticmethod
-    def _dense_rowwise_adagrad_reference(params, opt_state, batch, cfg, tx,
-                                         embed_lr=0.01, embed_eps=1e-8):
-        """Oracle: full dense table gradient + row-wise AdaGrad applied
-        densely. With dedup-first duplicate semantics (r4) this is exact for
-        ANY index pattern — the dense gradient row IS the deduped sum
-        (barring exact float cancellation making a touched row read zero)."""
-        from tpu_tfrecord.models.dlrm import SparseEmbOptState
-
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch, cfg)
-        g_table = grads.pop("embeddings").astype(jax.numpy.float32)
-        updates, dense_state = tx.update(
-            grads, opt_state.dense, {k: v for k, v in params.items() if k != "embeddings"}
-        )
-        dense_params = jax.tree.map(
-            lambda p, u: p + u,
-            {k: v for k, v in params.items() if k != "embeddings"},
-            updates,
-        )
-        touched = (g_table != 0).any(axis=-1)                       # [F, V]
-        row_ms = (g_table * g_table).mean(axis=-1)                  # [F, V]
-        accum = opt_state.accum + jax.numpy.where(touched, row_ms, 0.0)
-        scale = embed_lr * jax.lax.rsqrt(accum + embed_eps)         # [F, V]
-        table = params["embeddings"] - jax.numpy.where(
-            touched[..., None], scale[..., None] * g_table, 0.0
-        )
-        return dict(dense_params, embeddings=table), SparseEmbOptState(dense_state, accum), loss
+    # the oracle (full dense table gradient + row-wise AdaGrad applied
+    # densely) lives beside the step it checks; chip_smoke.py shares it
+    _dense_rowwise_adagrad_reference = staticmethod(
+        dense_rowwise_adagrad_reference
+    )
 
     def test_matches_dense_reference_without_duplicates(self):
         from tpu_tfrecord.models import sparse_opt_init, sparse_train_step

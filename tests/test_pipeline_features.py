@@ -63,22 +63,17 @@ class TestMultiWorker:
         seq_all = collect_uids(TFRecordDataset(out, batch_size=5, schema=SCHEMA))
         assert first + rest == seq_all
 
-    @pytest.mark.skipif(
-        (os.cpu_count() or 1) < 4,
-        reason="needs >=4 cores to demonstrate decode scaling "
-        "(runs on CI's multi-core runners; the TPU bench box has 1 core)",
-    )
     def test_num_workers_scales_wall_clock(self, tmp_path):
-        """N-worker decode must beat 1-worker wall-clock on a multi-core
-        host — the native decoder releases the GIL, so shard decode is real
-        thread parallelism. Generous threshold (1.4x at 4 workers) to stay
-        CI-stable."""
+        """N workers must overlap N shards. Each shard open is made to
+        stall 100 ms (seeded chaos, a sleep — it needs no spare core), so
+        one worker pays 8 stalls in a row and four pay two: a ratio of two
+        timings taken here, which holds on any box however loaded, where
+        the old absolute CPU-scaling bar did not. Rows and the number of
+        shard opens must be the same either way."""
         import time
 
-        from tpu_tfrecord import _native
+        from tpu_tfrecord.faults import FaultPlan, FaultRule, install_chaos
 
-        if not _native.available():
-            pytest.skip("needs the native decoder (GIL-released decode)")
         schema = StructType(
             [StructField("uid", LongType())]
             + [StructField(f"I{i}", LongType()) for i in range(12)]
@@ -88,29 +83,35 @@ class TestMultiWorker:
         for s in range(8):
             rows = [
                 [int(v) for v in rng.integers(0, 1 << 30, size=13)]
-                for _ in range(4000)
+                for _ in range(500)
             ]
             tfio.write(rows, schema, out, mode="append")
 
-        def run(workers: int) -> float:
-            ds = TFRecordDataset(
-                out, batch_size=4000, schema=schema, num_workers=workers
+        def run(workers: int):
+            plan = FaultPlan(
+                [FaultRule(op="open", kind="stall", times=None, stall_ms=100)]
             )
-            with ds.batches() as it:
-                next(it)  # warm (file cache, lazy init)
-                t0 = time.perf_counter()
-                n = 0
-                for b in it:
-                    n += b.num_rows
-                dt = time.perf_counter() - t0
-            assert n >= 8 * 4000 - 2 * 4000
-            return dt
+            try:
+                with install_chaos(plan):
+                    ds = TFRecordDataset(
+                        out, batch_size=500, schema=schema, num_workers=workers
+                    )
+                    t0 = time.perf_counter()
+                    with ds.batches() as it:
+                        uids = [u for b in it for u in b["uid"].values.tolist()]
+                    dt = time.perf_counter() - t0
+                return dt, uids, len(plan.ledger)
+            finally:
+                plan.release()
 
-        t1 = min(run(1), run(1))
-        t4 = min(run(4), run(4))
+        t1, rows1, opens1 = run(1)
+        t4, rows4, opens4 = run(4)
+        assert rows4 == rows1 and len(rows1) == 8 * 500
+        assert opens1 == opens4 == 8  # every shard opened once, stalled once
+        assert t1 >= 8 * 0.1  # the stalls were really paid in a row
         assert t4 < t1 / 1.4, (
-            f"4-worker decode ({t4:.3f}s) not faster than 1-worker "
-            f"({t1:.3f}s) on a {os.cpu_count()}-core host"
+            f"4-worker decode ({t4:.3f}s) did not overlap the per-shard "
+            f"stalls a single worker pays in a row ({t1:.3f}s)"
         )
 
     def test_parallel_error_propagates(self, sandbox):

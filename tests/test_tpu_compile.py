@@ -1,0 +1,179 @@
+"""Ask the chip's compiler, without the chip (on-chip-measurement guide §2.3).
+
+The TPU compiler is installed here and compiles for a DESCRIBED ``v5e:2x2``
+topology: what it refuses (unaligned tiles, too much fast memory, a program
+that does not fit 16 GB, a kernel that cannot be partitioned) it refuses at
+no chip time. These are the programs ``chip_smoke.py`` runs, at their real
+widths. A compile that passes is not a chip run and is never reported as one.
+
+Rules of this file (the guide's): the topology is described INSIDE a
+module-scoped fixture that skips when it cannot be — never at import, in a
+``skipif``, a ``parametrize`` argument or conftest.py, and never in a child
+process (one process holds libtpu); everything lives in this ONE file so
+xdist's ``--dist loadfile`` hands it to one worker; the persistent
+compilation cache is off around it (a described-device executable is written
+to the cache but cannot be read back without a chip).
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import chip_smoke
+from tpu_tfrecord.models import (
+    init_params as dlrm_init, lm, sparse_opt_init, sparse_train_step,
+)
+from tpu_tfrecord.models import pipeline as pp
+from tpu_tfrecord.models.attention import ring_attention
+from tpu_tfrecord.models.interaction import dot_interaction, dot_interaction_pallas
+
+B = chip_smoke.FULL["batch"]            # 16,384
+HBM_BYTES = 16 * 10**9                  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu from describing it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shaped(tree, sharding):
+    """Shapes of ``tree`` (arrays or ShapeDtypeStructs), placed by
+    ``sharding`` (one for all leaves, or a matching pytree)."""
+    if not isinstance(sharding, (dict, list, tuple)):
+        sharding = jax.tree.map(lambda _: sharding, tree)
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        tree, sharding,
+    )
+
+
+class TestOneChip:
+    def test_device_kind_is_one_the_smoke_knows(self, topo):
+        assert topo.devices[0].platform == "tpu"
+        assert topo.devices[0].device_kind in chip_smoke.KNOWN_DEVICE_KINDS
+
+    def test_dot_interaction_pallas_forward(self, one_chip):
+        x = jax.ShapeDtypeStruct((B, 27, 32), jnp.bfloat16, sharding=one_chip)
+        hlo = jax.jit(dot_interaction_pallas).lower(x).compile().as_text()
+        assert "tpu_custom_call" in hlo
+
+    def test_dot_interaction_pallas_vjp(self, one_chip):
+        x = jax.ShapeDtypeStruct((B, 27, 32), jnp.float32, sharding=one_chip)
+
+        def loss(e):
+            return dot_interaction(e, True).sum()
+
+        hlo = jax.jit(jax.value_and_grad(loss)).lower(x).compile().as_text()
+        assert "tpu_custom_call" in hlo
+
+    def test_split_and_unpack_bits_at_the_wire_width(self, one_chip):
+        from tpu_tfrecord.tpu import packed_width
+
+        width = 1 + chip_smoke.NUM_DENSE + packed_width(
+            chip_smoke.NUM_CAT, chip_smoke.CAT_BITS
+        )
+        gb = {"wire": jax.ShapeDtypeStruct((B, width), jnp.int32, sharding=one_chip)}
+        split = jax.jit(
+            functools.partial(chip_smoke.split_wire, vocab=chip_smoke.HASH_BUCKETS)
+        )
+        out = split.lower(gb).compile().output_shardings
+        assert set(out) == {"label", "dense", "cat"}
+
+    def test_sparse_train_step_at_the_bench_config_fits_hbm(self, one_chip):
+        """The one long compile (~60 s): 26 x 2^20 x 32 f32 tables, B=16,384."""
+        cfg = chip_smoke._dlrm_config(chip_smoke.FULL["vocab"])
+        tx = optax.sgd(1e-3)
+        params = jax.eval_shape(lambda: dlrm_init(jax.random.key(0), cfg))
+        opt = jax.eval_shape(lambda p: sparse_opt_init(p, cfg, tx), params)
+        batch = {
+            "label": jax.ShapeDtypeStruct((B,), jnp.float32),
+            "dense": jax.ShapeDtypeStruct((B, chip_smoke.NUM_DENSE), jnp.float32),
+            "cat": jax.ShapeDtypeStruct((B, chip_smoke.NUM_CAT), jnp.int32),
+        }
+        step = jax.jit(
+            functools.partial(sparse_train_step, cfg=cfg, tx=tx),
+            donate_argnums=(0, 1),
+        )
+        compiled = step.lower(
+            _shaped(params, one_chip), _shaped(opt, one_chip),
+            _shaped(batch, one_chip),
+        ).compile()
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes > 26 * (1 << 20) * 32 * 4  # tables are in
+        assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+    def test_lm_train_step_on_dp(self, topo):
+        """examples/train_lm.py's widths on a one-device ``data`` mesh."""
+        mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+        cfg = lm.LMConfig(
+            vocab_size=256, d_model=64, n_heads=4, n_layers=2, max_len=64
+        )
+        tx = optax.adam(3e-3)
+        repl = NamedSharding(mesh, P())
+        params = jax.eval_shape(lambda: lm.init_params(jax.random.key(0), cfg))
+        opt = jax.eval_shape(tx.init, params)
+        toks = jax.ShapeDtypeStruct(
+            (32, 65), jnp.int32, sharding=NamedSharding(mesh, P("data", None))
+        )
+        step = jax.jit(functools.partial(
+            lm.train_step, cfg=cfg, tx=tx, mesh=mesh, data_axis="data"))
+        step.lower(_shaped(params, repl), _shaped(opt, repl), toks).compile()
+
+
+class TestFourChips:
+    """One program across the four described devices: the collectives the
+    compiler put in are the contract (ring = permutes, never a gather)."""
+
+    def test_pipeline_apply_two_stages(self, topo):
+        mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("pipe", "data"))
+
+        def stage(p, x):
+            return jnp.tanh(x @ p["w"])
+
+        w = jax.ShapeDtypeStruct(
+            (2, 128, 128), jnp.float32, sharding=NamedSharding(mesh, P("pipe"))
+        )
+        xs = jax.ShapeDtypeStruct(
+            (8, 16, 128), jnp.float32,
+            sharding=pp.microbatch_sharding(mesh, ndim=3, batch_spec=P("data")),
+        )
+        fn = jax.jit(lambda p, xs: pp.pipeline_apply(
+            stage, p, xs, mesh, batch_spec=P("data")))
+        hlo = fn.lower({"w": w}, xs).compile().as_text()
+        assert "collective-permute" in hlo
+        assert "all-gather" not in hlo
+
+    def test_zigzag_ring_attention_seq_two(self, topo):
+        mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "seq"))
+        sh = NamedSharding(mesh, P("data", "seq", None, None))
+        q = jax.ShapeDtypeStruct((4, 2048, 8, 64), jnp.float32, sharding=sh)
+        fn = jax.jit(lambda q, k, v: ring_attention(
+            q, k, v, mesh, seq_axis="seq", data_axis="data", causal=True,
+            zigzag=True))
+        hlo = fn.lower(q, q, q).compile().as_text()
+        assert "collective-permute" in hlo
+        assert "all-gather" not in hlo

@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpu_tfrecord.models._compat import axis_size, shard_map
 
 _NEG = jnp.float32(-1e30)  # mask value; avoids inf-inf NaNs for empty rows
 
@@ -119,7 +118,7 @@ def _ring_attention_local(
     fold path applies it — the zigzag half blocks are causally unmasked
     by construction but still cross document boundaries, so the segment
     mask is orthogonal to the causal one there."""
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     if zigzag:
         swap = [(j, p - 1 - j) for j in range(p)]
@@ -354,7 +353,7 @@ def _shard_map_attention(
             causal=causal, segments=sb, **local_kwargs,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=tuple(in_specs), out_specs=spec
     )
     return fn(*args)

@@ -302,6 +302,36 @@ def sparse_train_step(
     return params, SparseEmbOptState(new_dense_state, accum), loss
 
 
+def dense_rowwise_adagrad_reference(
+    params, opt_state: SparseEmbOptState, batch, cfg: DLRMConfig, tx,
+    embed_lr: float = 0.01, embed_eps: float = 1e-8,
+):
+    """The plain reference for ``sparse_train_step``: the FULL dense table
+    gradient ([F, V, D], via ``loss_fn`` on the table itself) and row-wise
+    AdaGrad applied densely. With dedup-first duplicate semantics it is
+    exact for any index pattern — the dense gradient row IS the deduped
+    sum (barring exact float cancellation making a touched row read zero).
+    Only for vocabularies small enough to hold that gradient; shared by
+    tests/test_model.py and chip_smoke.py."""
+    loss, grads = jax.value_and_grad(loss_fn)(params, batch, cfg)
+    g_table = grads.pop("embeddings").astype(jnp.float32)
+    dense_params = {k: v for k, v in params.items() if k != "embeddings"}
+    updates, dense_state = tx.update(grads, opt_state.dense, dense_params)
+    dense_params = jax.tree.map(lambda p, u: p + u, dense_params, updates)
+    touched = (g_table != 0).any(axis=-1)                       # [F, V]
+    row_ms = (g_table * g_table).mean(axis=-1)                  # [F, V]
+    accum = opt_state.accum + jnp.where(touched, row_ms, 0.0)
+    scale = embed_lr * jax.lax.rsqrt(accum + embed_eps)         # [F, V]
+    table = params["embeddings"] - jnp.where(
+        touched[..., None], scale[..., None] * g_table, 0.0
+    )
+    return (
+        dict(dense_params, embeddings=table),
+        SparseEmbOptState(dense_state, accum),
+        loss,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Shardings
 # ---------------------------------------------------------------------------

@@ -57,7 +57,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_tfrecord.models._compat import shard_map
 
 
 @dataclass(frozen=True)
@@ -444,12 +443,12 @@ def moe_apply_ep(
     }
     out_specs = (x_spec, P()) + ((diag_spec,) if diagnostics else ())
     if valid is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh, in_specs=(w_spec, x_spec),
             out_specs=out_specs,
         )
         return fn(params, x)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(w_spec, x_spec, v_spec),
         out_specs=out_specs,
     )

@@ -75,7 +75,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_tfrecord.models._compat import shard_map
 
 StageFn = Callable[[Any, jax.Array], jax.Array]
 
@@ -211,7 +210,9 @@ def _pipeline_local(
     (S-1)/(M+S-1) exactly and for the interleaved schedule
     (S-1)/(V·M+S-1) (both pinned by tests); it is identical on every
     device, so no collective is needed and the gather-free HLO pin
-    survives with the flag on.
+    survives with the flag on. Typed as varying over the pipe axis (it
+    reads the stage index), it leaves the body as a [1] array per stage —
+    (outbuf, useful[None]) — and `pipeline_apply` builds the diag dict.
     """
     params = jax.tree.map(lambda a: a[0], params_stk)
     s = jax.lax.axis_index(axis)
@@ -220,9 +221,17 @@ def _pipeline_local(
     mb_shape = xs_local.shape[1:]
     fwd = [(j, (j + 1) % n_stages) for j in range(n_stages)]
     back = [(j, (j - 1) % n_stages) for j in range(n_stages)]
-    zero = jnp.zeros(mb_shape, xs_local.dtype)
+    # every loop carry starts out typed as varying over the same mesh axes
+    # as the local stream (pipe, plus whatever batch_spec shards): the
+    # tick body makes them so, and fori_loop wants carry-in == carry-out
+    vma = tuple(jax.typeof(xs_local).vma)
+
+    def varying(a):
+        return jax.lax.pcast(a, vma, to="varying") if vma else a
+
+    zero = varying(jnp.zeros(mb_shape, xs_local.dtype))
     feed0, act0, ring0 = zero, zero, zero
-    outbuf0 = jnp.zeros((r_blk,) + mb_shape, xs_local.dtype)
+    outbuf0 = varying(jnp.zeros((r_blk,) + mb_shape, xs_local.dtype))
 
     def m_of(u):
         # microbatch index of per-device step u = r·V·S + v·S + i:
@@ -310,14 +319,14 @@ def _pipeline_local(
         outbuf = capture(t, ring, outbuf)
         return ring, outbuf
 
-    # the last real microbatch's final chunk is born on device S-1 at step
-    # u_last; the main loop must run THROUGH that birth tick
-    r_last, i_last = (n_micro - 1) // n_stages, (n_micro - 1) % n_stages
-    u_last = r_last * vs + (n_virtual - 1) * n_stages + i_last
-    t_end = u_last + n_stages  # exclusive: birth tick u_last + S - 1
+    # the main loop must run THROUGH the last real microbatch's birth tick
+    t_end = _total_ticks(n_micro, n_stages, n_virtual)
+    # the occupancy counter reads the stage index, so it is varying over
+    # the pipe axis like the rings (and only over it)
+    useful0 = jax.lax.pcast(jnp.float32(0.0), (axis,), to="varying")
     _, _, ring, outbuf, useful = jax.lax.fori_loop(
         0, t_end, tick,
-        (feed0, act0, ring0, outbuf0, jnp.float32(0.0)),
+        (feed0, act0, ring0, outbuf0, useful0),
     )
     if n_stages > 1:
         _, outbuf = jax.lax.fori_loop(
@@ -326,16 +335,18 @@ def _pipeline_local(
         )
     if not diagnostics:
         return outbuf
-    total = jnp.float32(t_end)
-    useful = jax.lax.stop_gradient(useful)
-    diag = {
-        "bubble_fraction": 1.0 - useful / total,
-        "useful_ticks": useful,
-        "total_ticks": total,
-    }
-    if n_virtual > 1:
-        diag["virtual_stages"] = jnp.float32(n_virtual)
-    return outbuf, diag
+    # one counter per stage, sharded on the pipe axis; pipeline_apply reads
+    # stage 0's (they are equal) and derives the rest outside the body
+    return outbuf, jax.lax.stop_gradient(useful)[None]
+
+
+def _total_ticks(n_micro: int, n_stages: int, n_virtual: int) -> int:
+    """Main-loop length (exclusive end tick): the last real microbatch's
+    final chunk is born on device S-1 at step u_last = r·V·S + (V-1)·S + i,
+    i.e. at tick u_last + S - 1."""
+    r_last, i_last = (n_micro - 1) // n_stages, (n_micro - 1) % n_stages
+    u_last = r_last * n_stages * n_virtual + (n_virtual - 1) * n_stages + i_last
+    return u_last + n_stages
 
 
 def pipeline_apply(
@@ -430,12 +441,7 @@ def pipeline_apply(
                     f"{pipe_axis!r} (stage weights shard on it); got "
                     f"{p_leaf}"
                 )
-    diag_spec = {
-        "bubble_fraction": P(), "useful_ticks": P(), "total_ticks": P(),
-    }
-    if n_virtual > 1:
-        diag_spec["virtual_stages"] = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _pipeline_local, stage_fn=stage_fn, n_micro=n_micro,
             n_stages=n_stages, n_virtual=n_virtual, block=block,
@@ -443,13 +449,24 @@ def pipeline_apply(
         ),
         mesh=mesh,
         in_specs=(param_spec, spec),
-        out_specs=(spec, diag_spec) if diagnostics else spec,
+        out_specs=(spec, P(pipe_axis)) if diagnostics else spec,
     )
-    if diagnostics:
-        out, diag = fn(stage_params, xs)
-        return (out[:n_micro] if padded != n_micro else out), diag
-    out = fn(stage_params, xs)
-    return out[:n_micro] if padded != n_micro else out
+    res = fn(stage_params, xs)
+    out = res[0] if diagnostics else res
+    if padded != n_micro:
+        out = out[:n_micro]
+    if not diagnostics:
+        return out
+    useful = res[1][0]  # [S] counters sharded on pipe, all equal
+    total = jnp.float32(_total_ticks(n_micro, n_stages, n_virtual))
+    diag = {
+        "bubble_fraction": 1.0 - useful / total,
+        "useful_ticks": useful,
+        "total_ticks": total,
+    }
+    if n_virtual > 1:
+        diag["virtual_stages"] = jnp.float32(n_virtual)
+    return out, diag
 
 
 # ---------------------------------------------------------------------------
@@ -529,7 +546,7 @@ class PipelineStream:
         self._params = stage_params
         self._vs = self._n_stages * n_virtual
         self._step = jax.jit(
-            shard_map(
+            jax.shard_map(
                 functools.partial(
                     _stream_tick_local, stage_fn=stage_fn,
                     n_stages=self._n_stages, n_virtual=n_virtual,

@@ -1065,9 +1065,11 @@ def run_server(
     install_signals: bool = True,
     ready_fh=None,
     trace_out: Optional[str] = None,
+    ready_info: Optional[Dict[str, Any]] = None,
 ) -> int:
     """Run a started server to completion: optionally announce readiness
-    (one JSON line: addr + pid), land per-request telemetry on the fleet
+    (one JSON line: addr + pid, plus ``ready_info`` — the CLI adds the
+    device the replica's mesh sits on), land per-request telemetry on the fleet
     spool, and on SIGTERM/SIGINT drain gracefully — stop admitting,
     finish in-flight requests, write the spool's ``final: true`` snapshot
     — then return 0. The scaler's drain RPC takes the same exit path.
@@ -1100,7 +1102,9 @@ def run_server(
 
     if ready_fh is not None:
         ready_fh.write(
-            json.dumps({"addr": server.addr, "pid": os.getpid()}) + "\n"
+            json.dumps(
+                {"addr": server.addr, "pid": os.getpid(), **(ready_info or {})}
+            ) + "\n"
         )
         ready_fh.flush()
     try:
@@ -1178,7 +1182,11 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                    "traces)")
     args = p.parse_args(argv)
 
+    from tpu_tfrecord import compile_cache
+
+    compile_cache.enable()
     params, cfg, mesh = _build_synthetic(args)
+    dev = mesh.devices.flat[0]
     policy = ServePolicy(
         mb=args.mb, max_queue=args.max_queue,
         default_deadline_s=args.default_deadline_s,
@@ -1195,6 +1203,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     return run_server(
         server, spool_dir=args.spool_dir, role=args.role,
         ready_fh=sys.stdout, trace_out=args.trace_out,
+        ready_info={"platform": dev.platform, "device_kind": dev.device_kind},
     )
 
 

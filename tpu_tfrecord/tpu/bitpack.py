@@ -3,8 +3,8 @@
 Hashed categorical features are bucket indices in ``[0, hash_buckets)`` —
 for the common 2**20-bucket embedding table that is 20 significant bits
 carried in a 32-bit lane: 37.5% of every transferred byte is zero padding.
-On TPU the host->device link (PCIe, or a forwarded tunnel in dev setups) is
-often the scarcest resource in an ingest pipeline, while on-device bit
+On TPU the host->device link (PCIe) can be
+the scarcest resource in an ingest pipeline, while on-device bit
 twiddling is effectively free once fused into the consumer's jit program.
 
 ``pack_bits`` packs the columns of an int32 matrix into ``bits``-wide lanes

@@ -685,12 +685,12 @@ class DeviceIterator:
 
     ``transfer_thread=True`` moves the transfer into a dedicated worker that
     BLOCKS each copy to completion behind a bounded queue of device-resident
-    batches. On platforms where the host-to-device copy is synchronous at
-    dispatch (a dispatched transfer makes no progress until some thread
-    blocks on it — true of network-tunneled devices, unlike PCIe PJRT's
-    async H2D engine), dispatch-ahead alone overlaps nothing; the worker
-    thread restores the overlap because it does its blocking while the
-    consumer thread sits inside the device step. Use ``close()`` (or a
+    batches, so the consumer only ever pops batches that are already there.
+    On a local v5e the copy is asynchronous at dispatch (chip_smoke.py's
+    transport probe: ``device_put`` of 256 MB returns in 0.5 ms and
+    completes in 43 ms), so dispatch-ahead alone already overlaps copy and
+    compute there; whether the worker thread still pays is ROADMAP D10's
+    question, to be answered from ledger rows. Use ``close()`` (or a
     ``with`` block) to release the worker."""
 
     def __init__(
