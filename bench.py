@@ -40,6 +40,9 @@ MEASURE_SECONDS = float(os.environ.get("TFR_BENCH_SECONDS", 6.0))
 SUSTAIN_SECONDS = float(os.environ.get("TFR_BENCH_SUSTAIN", 8.0))
 
 
+NUM_DENSE, NUM_CAT = 13, 26
+
+
 def criteo_schema():
     """Write-side schema (inference parity: ints are LongType)."""
     from tpu_tfrecord.schema import LongType, StringType, StructField, StructType
@@ -61,6 +64,53 @@ def criteo_read_schema():
     fields += [StructField(f"I{i}", IntegerType()) for i in range(1, 14)]
     fields += [StructField(f"C{i}", StringType()) for i in range(1, 27)]
     return StructType(fields)
+
+
+def criteo_reader_spec():
+    """(hash_buckets, pack) of the Criteo reader: categorical hashing to
+    2^20 buckets fused into the native decode, and ONE column group = one
+    [B, 40] i32 host matrix = ONE device transfer (the consumer jit splits
+    label/dense/cat on device, free under XLA fusion)."""
+    hash_buckets = {f"C{i}": HASH_BUCKETS for i in range(1, NUM_CAT + 1)}
+    pack = {
+        "packed": ["label"]
+        + [f"I{i}" for i in range(1, NUM_DENSE + 1)]
+        + [f"C{i}" for i in range(1, NUM_CAT + 1)],
+    }
+    return hash_buckets, pack
+
+
+def criteo_dlrm_config(vocab: int, top_mlp=(64, 1), **kw):
+    """The Criteo cell's DLRM: 26 tables x ``vocab`` x 32, bottom 64-32,
+    dot interaction (bf16 activations unless ``dtype`` is given)."""
+    from tpu_tfrecord.models import DLRMConfig
+
+    return DLRMConfig(
+        num_dense=NUM_DENSE, num_categorical=NUM_CAT, vocab_size=vocab,
+        embed_dim=32, bottom_mlp=(64, 32), top_mlp=top_mlp,
+        interaction="dot", **kw,
+    )
+
+
+def split_wire(gb, vocab: int):
+    """The consumer-side split of the bit-packed wire batch: label / 13
+    dense / 26 categorical indices, the 20-bit unpack fused into the
+    caller's jit (the train step is a separate program — its donated
+    params preclude merging here). Dense ints get the standard Criteo
+    log1p (examples/train_dlrm.py) so SGD steps stay finite; indices fold
+    only when the table is smaller than the hashed space (CPU smoke runs
+    shrink it)."""
+    import jax.numpy as jnp
+
+    from tpu_tfrecord.tpu import unpack_bits
+
+    m = gb["wire"]
+    cat = unpack_bits(m[:, 1 + NUM_DENSE:], NUM_CAT, CAT_BITS)
+    return {
+        "label": m[:, 0].astype(jnp.float32),
+        "dense": jnp.log1p(m[:, 1:1 + NUM_DENSE].astype(jnp.float32)),
+        "cat": cat % vocab if vocab < HASH_BUCKETS else cat,
+    }
 
 
 def ensure_dataset(data_dir: str) -> str:
@@ -2269,15 +2319,7 @@ def main() -> None:
     data_dir = os.environ.get("TFR_BENCH_DIR", "/tmp/tpu_tfrecord_bench_v2")
     data_dir = ensure_dataset(data_dir)
     schema = criteo_read_schema()
-    hash_buckets = {f"C{i}": HASH_BUCKETS for i in range(1, 27)}
-
-    # One group = one [B, 40] i32 host matrix = ONE device transfer; the
-    # consumer jit splits label/dense/cat on device (free under XLA fusion).
-    pack = {
-        "packed": ["label"]
-        + [f"I{i}" for i in range(1, 14)]
-        + [f"C{i}" for i in range(1, 27)],
-    }
+    hash_buckets, pack = criteo_reader_spec()
 
     # Device-free phases FIRST: they need no backend, so they complete
     # whatever the device does and ride along in the watchdog's error output.
@@ -2859,10 +2901,9 @@ def _train_duty_cycle(ds, mesh, hash_buckets, pack, top_mlp, seconds=6.0):
     import functools
 
     import jax
-    import jax.numpy as jnp
     import optax
 
-    from tpu_tfrecord.models import DLRMConfig, init_params, sparse_opt_init, sparse_train_step
+    from tpu_tfrecord.models import init_params, sparse_opt_init, sparse_train_step
     from tpu_tfrecord.tpu import DeviceIterator, HostPrefetcher, host_batch_from_columnar
     from tpu_tfrecord.tracing import DutyCycle
 
@@ -2870,15 +2911,7 @@ def _train_duty_cycle(ds, mesh, hash_buckets, pack, top_mlp, seconds=6.0):
     # (indices fold on device when it is below the hashed space); on the
     # real chip the default is the FULL 2^20 hashed vocabulary.
     vocab = int(os.environ.get("TFR_BENCH_VOCAB", HASH_BUCKETS))
-    cfg = DLRMConfig(
-        num_dense=13,
-        num_categorical=26,
-        vocab_size=vocab,
-        embed_dim=32,
-        bottom_mlp=(64, 32),
-        top_mlp=top_mlp,
-        interaction="dot",
-    )
+    cfg = criteo_dlrm_config(vocab, top_mlp=top_mlp)
     params = init_params(jax.random.key(0), cfg)
     tx = optax.sgd(1e-3)
     opt_state = sparse_opt_init(params, cfg, tx)
@@ -2886,23 +2919,10 @@ def _train_duty_cycle(ds, mesh, hash_buckets, pack, top_mlp, seconds=6.0):
         functools.partial(sparse_train_step, cfg=cfg, tx=tx), donate_argnums=(0, 1)
     )
 
-    from tpu_tfrecord.tpu import pack_mixed, unpack_bits
+    from tpu_tfrecord.tpu import pack_mixed
 
-    @jax.jit
-    def split(gb):
-        # consume the bit-packed wire form end-to-end: the 20-bit cat
-        # unpack fuses into THIS jit (the train step is a separate program —
-        # its donated params preclude merging here)
-        m = gb["wire"]
-        return {
-            "label": m[:, 0].astype(jnp.float32),
-            "dense": m[:, 1:14].astype(jnp.float32),
-            # no fold at the default vocab (the full hashed space); CPU
-            # smoke runs shrink the table via TFR_BENCH_VOCAB and fold
-            "cat": unpack_bits(m[:, 14:], 26, CAT_BITS) % vocab
-            if vocab < HASH_BUCKETS
-            else unpack_bits(m[:, 14:], 26, CAT_BITS),
-        }
+    # consume the bit-packed wire form end-to-end
+    split = jax.jit(functools.partial(split_wire, vocab=vocab))
 
     it = ds.batches()  # phase 1 closed its iterator; epochs are infinite
 

@@ -216,15 +216,10 @@ def main() -> None:
 
     # the serving surface may not drift from the trained graph: streamed
     # logits must equal the batch path (batch-mode pipeline_apply over
-    # the same slices) to LMStream.STREAM_BATCH_TOL — two XLA programs,
-    # so a few ulp apart, never more
+    # the same slices) BITWISE
     ref = stream.batch_reference(reqs)
-    tol = lm.LMStream.STREAM_BATCH_TOL
-    max_diff = max(float(np.abs(a - b).max()) for a, b in zip(outs, ref))
-    matches = all(
-        np.allclose(a, b, rtol=tol, atol=tol) for a, b in zip(outs, ref)
-    )
-    assert matches, f"streamed logits diverged from the batch path: {max_diff}"
+    identical = all(np.array_equal(a, b) for a, b in zip(outs, ref))
+    assert identical, "streamed logits diverged from the batch path"
 
     line = {
         "requests": len(reqs),
@@ -236,8 +231,7 @@ def main() -> None:
         "latency_ms_p99": round(
             float(np.percentile(lat, 99)) * 1e3, 2
         ),
-        "matches_batch": matches,
-        "max_abs_diff_vs_batch": max_diff,
+        "byte_identical_to_batch": identical,
         "ckpt_step": step,
         "shape": f"mb={args.mb} L={SEQ_LEN} S={args.pipe} V={args.virtual}",
     }

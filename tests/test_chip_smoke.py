@@ -55,6 +55,23 @@ class TestPhasesOnTheCpuMesh:
             tiny["data"], tiny["batch"], cmp_vocab=64, interpret=True
         )
 
+    def test_compare_fails_on_a_kernel_that_returns_zeros(self, tiny, monkeypatch):
+        """Most pair columns hold values far under the bf16 atol: the
+        per-column bound is what catches a kernel that wrote nothing."""
+        import jax.numpy as jnp
+
+        from tpu_tfrecord.models import interaction
+
+        def zeros(emb, **_):
+            p = emb.shape[1] * (emb.shape[1] - 1) // 2
+            return jnp.zeros((emb.shape[0], p), emb.dtype)
+
+        monkeypatch.setattr(interaction, "dot_interaction_pallas", zeros)
+        with pytest.raises(chip_smoke.SmokeFailure, match="pallas == XLA"):
+            chip_smoke.phase_compare(
+                tiny["data"], tiny["batch"], cmp_vocab=64, interpret=True
+            )
+
     def test_resume(self, tiny):
         chip_smoke.phase_resume(
             tiny["data"], tiny["batch"], str(tiny["root"] / "input_state")

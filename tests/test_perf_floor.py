@@ -11,7 +11,12 @@ the fast path to a conservative multiple of the oracle:
   column-group packing vs the pure-Python decoder (measures ~100x; floor
   ``NATIVE_OVER_ORACLE``);
 - SequenceExample shape: fused native pad + bf16 cast vs the numpy fallback
-  (measures 6-11x; floor ``FUSED_PAD_OVER_FALLBACK``).
+  (measures 7-12x; floor ``FUSED_PAD_OVER_FALLBACK``).
+
+These floors catch a fallback to the slow path or a lost fused stage. They
+do not catch the 30% decode regression the old absolute floors aimed at:
+no ratio against a 100x-slower oracle can, on a shared box. That guard
+comes back as a bound on a benchmark cell (ROADMAP S1).
 
 They used to be calibrated against a reference box's decode-per-microbench
 ratio; that failed under the driver's six-worker load with the code
@@ -67,9 +72,12 @@ def _write_criteo_shard(path: str, n: int) -> None:
 
 
 #: the native decode+hash+pack path must beat the pure-Python oracle by at
-#: least this factor on the same rows in the same test (it measures two
-#: orders of magnitude; 10x only has to tell "native" from "fell back")
-NATIVE_OVER_ORACLE = 10.0
+#: least this factor on the same rows in the same test. It measures 96-111x
+#: on an idle box and 124-228x with 8-16 busy processes beside it (the
+#: oracle suffers more), so 30x tells "native" from "fell back" or "lost a
+#: fused stage" with 3x to spare — NOT a 30% decode regression: that guard
+#: is owed by the benchmark (ROADMAP S1)
+NATIVE_OVER_ORACLE = 30.0
 
 
 @pytest.mark.perf
@@ -173,8 +181,9 @@ def _write_seq_shard(path: str, n: int) -> None:
 
 
 #: the fused native pad+cast must beat the numpy fallback by at least this
-#: factor on the same batches in the same test (it measures 6-11x)
-FUSED_PAD_OVER_FALLBACK = 2.5
+#: factor on the same batches in the same test (it measures 9-12x idle and
+#: 7-11x with 8-16 busy processes beside it)
+FUSED_PAD_OVER_FALLBACK = 4.0
 
 
 @pytest.mark.perf

@@ -76,8 +76,10 @@ class TestOneChip:
         assert topo.devices[0].platform == "tpu"
         assert topo.devices[0].device_kind in chip_smoke.KNOWN_DEVICE_KINDS
 
-    def test_dot_interaction_pallas_forward(self, one_chip):
-        x = jax.ShapeDtypeStruct((B, 27, 32), jnp.bfloat16, sharding=one_chip)
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    def test_dot_interaction_pallas_forward(self, one_chip, dtype):
+        # float32 takes the multi-pass selection matmuls (precision=HIGHEST)
+        x = jax.ShapeDtypeStruct((B, 27, 32), dtype, sharding=one_chip)
         hlo = jax.jit(dot_interaction_pallas).lower(x).compile().as_text()
         assert "tpu_custom_call" in hlo
 
@@ -105,7 +107,7 @@ class TestOneChip:
 
     def test_sparse_train_step_at_the_bench_config_fits_hbm(self, one_chip):
         """The one long compile (~60 s): 26 x 2^20 x 32 f32 tables, B=16,384."""
-        cfg = chip_smoke._dlrm_config(chip_smoke.FULL["vocab"])
+        cfg = chip_smoke.criteo_dlrm_config(chip_smoke.FULL["vocab"])
         tx = optax.sgd(1e-3)
         params = jax.eval_shape(lambda: dlrm_init(jax.random.key(0), cfg))
         opt = jax.eval_shape(lambda p: sparse_opt_init(p, cfg, tx), params)

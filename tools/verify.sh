@@ -755,13 +755,13 @@ assert srv.returncode == 0, (srv.returncode, srv.stdout[-2000:], srv.stderr[-100
 line = [l for l in srv.stdout.splitlines() if l.startswith("serve_lm OK:")]
 assert line, srv.stdout[-2000:]
 rep = json.loads(line[0].split("serve_lm OK:", 1)[1])
-assert rep["matches_batch"] is True, rep
+assert rep["byte_identical_to_batch"] is True, rep
 assert rep["requests"] == 12 and rep["requests_per_s"] > 0, rep
 assert rep["ckpt_step"] == 8, rep
 print("serving smoke OK:", json.dumps({
     "requests_per_s": rep["requests_per_s"],
     "latency_ms_p50": rep["latency_ms_p50"],
-    "matches_batch": rep["matches_batch"],
+    "byte_identical": rep["byte_identical_to_batch"],
 }))
 PY
 

@@ -79,7 +79,14 @@ def attention_reference(
     boundaries."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     k, v = _expand_kv(q, k), _expand_kv(q, v)
-    scores = jnp.einsum("blhd,bmhd->bhlm", q, k).astype(jnp.float32) * scale
+    # the scale goes onto q, before the contraction (L·D multiplies, not
+    # L·M). Left on the scores it sits next to the softmax's `- max` with
+    # only the mask select between them, and the CPU backend contracts
+    # the two into one fused multiply-add in one program and not in
+    # another (a causal mask hoisted out of a loop body as loop-invariant
+    # changes what LLVM sees): 1 ulp between the streamed and the batch
+    # pipeline on jax 0.9.0, which owe each other bitwise equality
+    scores = jnp.einsum("blhd,bmhd->bhlm", q * scale, k).astype(jnp.float32)
     if lengths is not None:
         valid = jnp.arange(k.shape[1])[None, :] < lengths[:, None]  # [B, M]
         scores = jnp.where(valid[:, None, None, :], scores, _NEG)

@@ -64,13 +64,22 @@ def _interaction_kernel(sel_rows_ref, sel_cols_ref, emb_ref, out_ref):
     # selection MATMULS do. R[tb,d,p] = E[tb, rows[p], d], same for C, then
     # the packed pairwise dots are an elementwise product reduced over D.
     contract = (((1,), (0,)), ((), ()))            # contract the F dim
+    # a selection has to be EXACT. bf16 input survives the MXU's default
+    # single bf16 pass unchanged; float32 input would be rounded to bf16
+    # by it (3.2e-3 of scale on the v5e), so it takes the multi-pass f32
+    # contraction
+    precision = (
+        jax.lax.Precision.HIGHEST
+        if emb_ref.dtype == jnp.float32
+        else jax.lax.Precision.DEFAULT
+    )
     r = jax.lax.dot_general(
         emb, sel_rows_ref[:], dimension_numbers=contract,
-        preferred_element_type=jnp.float32,
+        precision=precision, preferred_element_type=jnp.float32,
     )                                              # [TB, D, P]
     c = jax.lax.dot_general(
         emb, sel_cols_ref[:], dimension_numbers=contract,
-        preferred_element_type=jnp.float32,
+        precision=precision, preferred_element_type=jnp.float32,
     )
     out_ref[:] = jnp.sum(r * c, axis=1).astype(out_ref.dtype)
 
