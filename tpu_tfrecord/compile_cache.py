@@ -27,13 +27,19 @@ DEFAULT_DIR = os.path.join(
 def enable() -> str:
     """Turn the persistent compilation cache on; returns its directory.
 
-    With ``JAX_COMPILATION_CACHE_DIR`` set nothing is set in code (JAX's
-    own config reads the variable); otherwise ``jax_compilation_cache_dir``
-    becomes :data:`DEFAULT_DIR`."""
+    With ``JAX_COMPILATION_CACHE_DIR`` set the directory is not set in code
+    (JAX's own config reads the variable); otherwise
+    ``jax_compilation_cache_dir`` becomes :data:`DEFAULT_DIR`. Either way
+    the cache key includes the programs' metadata."""
+    import jax
+
+    # The programs' scope names (tracing.ANNOTATIONS) are metadata, which
+    # JAX leaves out of the cache key by default: an executable cached
+    # before a scope was added or moved would come back without it, and a
+    # profiler capture of that run would show the old names.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get(ENV_VAR)
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     return DEFAULT_DIR

@@ -50,6 +50,7 @@ from tpu_tfrecord.metrics import METRICS, log_salvage_event, timed
 from tpu_tfrecord.options import TFRecordOptions
 from tpu_tfrecord.retry import RetryPolicy
 from tpu_tfrecord.schema import StructType
+from tpu_tfrecord.tracing import STOPPED, get_or_wait, put_or_wait, trace
 from tpu_tfrecord.stall import (
     StallError,
     StallGuard,
@@ -545,8 +546,6 @@ class TFRecordDataset:
         shard — so IteratorState checkpoints resume interchangeably between
         cached and uncached reads; a mid-chunk resume slices the straddling
         chunk exactly like the decode paths start mid-slab."""
-        from tpu_tfrecord.tracing import trace
-
         dtype_of = self._cache_dtypes.__getitem__
         shard_path = self.shards[shard_idx].path
         for i in range(entry.num_chunks):
@@ -613,8 +612,6 @@ class TFRecordDataset:
         yield (chunk, epoch, pos, start) tuples, and advance the shared
         emitted-record cell — ONE owner for the skip/chunk/index accounting
         used by both the strict two-pass path and the salvage path."""
-        from tpu_tfrecord.tracing import trace
-
         chunk_records = max(self.batch_size, 2048)
         shard_path = self.shards[shard_idx].path
         base = 0
@@ -625,7 +622,7 @@ class TFRecordDataset:
                 continue
             for start in range(max(0, next_index[0] - base), n, chunk_records):
                 stop = min(start + chunk_records, n)
-                with timed("decode", METRICS) as t, trace("tfr:decode"), \
+                with timed("decode", METRICS) as t, trace("tfr:decode") as tr, \
                         telemetry.span("decode", shard=shard_path) as sp:
                     chunk = self._decode_chunk(
                         buf, offsets[start:stop], lengths[start:stop]
@@ -633,6 +630,7 @@ class TFRecordDataset:
                     t.records += chunk.num_rows
                     t.bytes += int(lengths[start:stop].sum())
                     sp.set(rows=chunk.num_rows)
+                    tr.set_metadata(rows=chunk.num_rows, bytes=t.bytes)
                 if self._partition_fields:
                     self._attach_partition_chunk(chunk, shard_idx)
                 yield chunk, epoch, pos, base + start
@@ -743,8 +741,6 @@ class TFRecordDataset:
         path."""
         import mmap
 
-        from tpu_tfrecord.tracing import trace
-
         chunk_records = max(self.batch_size, 2048)
         next_index = [skip]
         dec = self._native_decoder
@@ -777,7 +773,7 @@ class TFRecordDataset:
                     bpos = 0
                     while True:
                         hint(bpos)
-                        with timed("decode", METRICS) as t, trace("tfr:decode"), \
+                        with timed("decode", METRICS) as t, trace("tfr:decode") as tr, \
                                 telemetry.span("decode", shard=shard.path) as sp:
                             cb, n_sk, n_done, consumed = dec.scan_decode(
                                 buf, bpos, verify, to_skip, chunk_records,
@@ -787,6 +783,7 @@ class TFRecordDataset:
                             t.records += n_done
                             t.bytes += consumed - bpos
                             sp.set(rows=n_done)
+                            tr.set_metadata(rows=n_done, bytes=consumed - bpos)
                         to_skip -= n_sk
                         abs_idx += n_sk
                         bpos = consumed
@@ -824,8 +821,6 @@ class TFRecordDataset:
         chunk positions, retry semantics, and bounded tail-carry contract as
         the two-pass path."""
         from tpu_tfrecord import fs as _fs
-        from tpu_tfrecord.tracing import trace
-
         shard = self.shards[shard_idx]
         codec = wire.codec_from_path(shard.path)
         if self.use_mmap and codec is None and not _fs.has_scheme(shard.path):
@@ -873,7 +868,7 @@ class TFRecordDataset:
                     buf = scratch["buf"]
                     bpos = 0
                     while True:
-                        with timed("decode", METRICS) as t, trace("tfr:decode"), \
+                        with timed("decode", METRICS) as t, trace("tfr:decode") as tr, \
                                 telemetry.span("decode", shard=shard.path) as sp:
                             cb, n_sk, n_done, consumed = dec.scan_decode(
                                 buf, bpos, verify, to_skip, chunk_records,
@@ -883,6 +878,7 @@ class TFRecordDataset:
                             t.records += n_done
                             t.bytes += consumed - bpos
                             sp.set(rows=n_done)
+                            tr.set_metadata(rows=n_done, bytes=consumed - bpos)
                         to_skip -= n_sk
                         abs_idx += n_sk
                         bpos = consumed
@@ -1046,6 +1042,15 @@ class TFRecordDataset:
         return CheckpointableIterator(self, state or IteratorState())
 
 
+def _put_batch(out_queue: queue.Queue, item, stop: threading.Event) -> bool:
+    """Hand a batch to the consumer; False once ``stop`` is set. A full
+    queue means the consumer is behind: one ``read.backpressure_waits``
+    count and one ``tfr:blocked.batch`` span per blocked put, not per poll."""
+    if out_queue.full():
+        METRICS.count("read.backpressure_waits")
+    return put_or_wait(out_queue, item, stop, "tfr:blocked.batch")
+
+
 def _producer_loop(
     ds: TFRecordDataset,
     start: IteratorState,
@@ -1085,19 +1090,7 @@ def _producer_loop(
                 if entry[1] >= chunk.num_rows:
                     pending.pop(0)
             batch = concat_batches(slices)
-        blocked = False
-        while not stop.is_set():
-            try:
-                out_queue.put((batch, end_pos), timeout=0.1)
-                return True
-            except queue.Full:
-                if not blocked:
-                    # the consumer is behind (queue full): one count per
-                    # blocked put, not per 100ms poll
-                    blocked = True
-                    METRICS.count("read.backpressure_waits")
-                continue
-        return False
+        return _put_batch(out_queue, (batch, end_pos), stop)
 
     if ds.shuffle_window:
         _shuffled_producer_loop(ds, start, out_queue, stop, control)
@@ -1153,19 +1146,6 @@ def _shuffled_producer_loop(
     B = ds.batch_size
     target = ds.shuffle_window * B
 
-    def put(batch, pos) -> bool:
-        blocked = False
-        while not stop.is_set():
-            try:
-                out_queue.put((batch, pos), timeout=0.1)
-                return True
-            except queue.Full:
-                if not blocked:
-                    blocked = True
-                    METRICS.count("read.backpressure_waits")
-                continue
-        return False
-
     try:
         # Resume mid-window: rebuild from the stored window START; skip the
         # batches the consumer already saw.
@@ -1201,7 +1181,7 @@ def _shuffled_producer_loop(
                             window_emitted=k + 1,
                         )
                     )
-                    if not put(piece, pos):
+                    if not _put_batch(out_queue, (piece, pos), stop):
                         return False
             emit_skip = 0
             win = []
@@ -1623,19 +1603,14 @@ class CheckpointableIterator:
         self._occupancy.update(depth / q.maxsize)
         METRICS.gauge("prefetch.queue_depth", depth)
         t0_ns = time.perf_counter_ns()
-        while True:
-            if self._stop.is_set():
-                # close()d: iteration is over — the producer exits without
-                # enqueuing its None sentinel, so never block forever (and a
-                # batch racing into the queue during close() is not yielded).
-                self._finished = True
-                self._stop_pulse()
-                raise StopIteration
-            try:
-                item = self._queue.get(timeout=0.1)
-                break
-            except queue.Empty:
-                continue
+        item = get_or_wait(q, self._stop, "tfr:starved.batch")
+        if item is STOPPED:
+            # close()d: iteration is over — the producer exits without
+            # enqueuing its None sentinel, so never block forever (and a
+            # batch racing into the queue during close() is not yielded).
+            self._finished = True
+            self._stop_pulse()
+            raise StopIteration
         if item is None:
             self._finished = True
             self._stop.set()  # let any lingering pipeline threads exit

@@ -115,25 +115,31 @@ def forward(
     the table — see ``sparse_train_step``); ``params['embeddings']`` is not
     touched when it is given."""
     dt = cfg.dtype
-    dense = batch["dense"].astype(dt)
-    bottom_out = _mlp(params["bottom"], dense, dt)          # [B, H]
+    with jax.named_scope("tfr.bottom_mlp"):
+        dense = batch["dense"].astype(dt)
+        bottom_out = _mlp(params["bottom"], dense, dt)      # [B, H]
     if emb is None:
-        # [B, F] indices into [F, V, D] -> [B, F, D]
-        emb = jnp.take_along_axis(
-            params["embeddings"].astype(dt)[None],          # [1, F, V, D]
-            batch["cat"][:, :, None, None],                  # [B, F, 1, 1]
-            axis=2,
-        )[:, :, 0, :]
+        with jax.named_scope("tfr.table_cast"):
+            table = params["embeddings"].astype(dt)[None]   # [1, F, V, D]
+        with jax.named_scope("tfr.gather"):
+            # [B, F] indices into [F, V, D] -> [B, F, D]
+            emb = jnp.take_along_axis(
+                table,
+                batch["cat"][:, :, None, None],              # [B, F, 1, 1]
+                axis=2,
+            )[:, :, 0, :]
     else:
-        emb = emb.astype(dt)
-    if cfg.interaction == "dot":
-        from tpu_tfrecord.models.interaction import dot_interaction
+        with jax.named_scope("tfr.gather"):
+            emb = emb.astype(dt)
+    with jax.named_scope("tfr.interaction"):
+        if cfg.interaction == "dot":
+            from tpu_tfrecord.models.interaction import dot_interaction
 
-        stack = jnp.concatenate([bottom_out[:, None, :], emb], axis=1)
-        pairs = dot_interaction(stack)                       # [B, P]
-        feats = [bottom_out, pairs.astype(dt)]
-    else:
-        feats = [bottom_out, emb.reshape(emb.shape[0], -1)]
+            stack = jnp.concatenate([bottom_out[:, None, :], emb], axis=1)
+            pairs = dot_interaction(stack)                   # [B, P]
+            feats = [bottom_out, pairs.astype(dt)]
+        else:
+            feats = [bottom_out, emb.reshape(emb.shape[0], -1)]
     if cfg.seq_len:
         frames = batch["frames"].astype(dt)                  # [B, L, D_in]
         proj = _mlp([params["seq_proj"]], frames, dt)        # [B, L, D]
@@ -144,9 +150,10 @@ def forward(
             mask.sum(axis=1, keepdims=True), 1.0
         )
         feats.append(pooled)
-    x = jnp.concatenate(feats, axis=-1)
-    logits = _mlp(params["top"], x, dt)
-    return logits[:, 0].astype(jnp.float32)
+    with jax.named_scope("tfr.top_mlp"):
+        x = jnp.concatenate(feats, axis=-1)
+        logits = _mlp(params["top"], x, dt)
+        return logits[:, 0].astype(jnp.float32)
 
 
 def loss_fn(params, batch, cfg: DLRMConfig, emb: Optional[jax.Array] = None) -> jax.Array:
@@ -257,7 +264,8 @@ def sparse_train_step(
     table = params["embeddings"]                            # [F, V, D]
     idx = batch["cat"]                                      # [B, F]
     f_ix = jnp.arange(cfg.num_categorical)[None, :]         # [1, F]
-    rows = table[f_ix, idx]                                 # [B, F, D]
+    with jax.named_scope("tfr.gather"):
+        rows = table[f_ix, idx]                             # [B, F, D]
     dense_params = {k: v for k, v in params.items() if k != "embeddings"}
 
     def loss_of(dp, r):
@@ -266,38 +274,47 @@ def sparse_train_step(
     loss, (g_dense, g_rows) = jax.value_and_grad(loss_of, argnums=(0, 1))(
         dense_params, rows
     )
-    updates, new_dense_state = tx.update(g_dense, opt_state.dense, dense_params)
-    dense_params = jax.tree.map(lambda p, u: p + u, dense_params, updates)
-    g_rows = g_rows.astype(jnp.float32)
+    with jax.named_scope("tfr.dense_update"):
+        updates, new_dense_state = tx.update(g_dense, opt_state.dense, dense_params)
+        dense_params = jax.tree.map(lambda p, u: p + u, dense_params, updates)
     fdim, vocab = cfg.num_categorical, cfg.vocab_size
-    d = g_rows.shape[-1]
-    n = idx.shape[0] * fdim
-    f_flat = jnp.broadcast_to(f_ix, idx.shape).reshape(n)   # [N] feature id
-    v_flat = idx.reshape(n)                                 # [N] vocab row
-    order, sf, sv, run_start = _dedup_sort(
-        f_flat, v_flat, vocab, force_pairs=fdim * vocab > _FLAT_KEY_MAX
-    )
-    sg = g_rows.reshape(n, d)[order]
-    rid = jnp.cumsum(run_start) - 1                         # run id per element
-    # per-element view of its duplicate group's summed gradient and size
-    g_sum = jax.ops.segment_sum(
-        sg, rid, num_segments=n, indices_are_sorted=True
-    )[rid]                                                  # [N, D]
-    m = jax.ops.segment_sum(
-        jnp.ones((n,), jnp.float32), rid, num_segments=n, indices_are_sorted=True
-    )[rid]                                                  # [N]
-    inv_m = 1.0 / m
-    ms_share = jnp.mean(g_sum * g_sum, axis=-1) * inv_m     # sums to mean(G^2)
+    with jax.named_scope("tfr.dedup_sort"):
+        g_rows = g_rows.astype(jnp.float32)
+        d = g_rows.shape[-1]
+        n = idx.shape[0] * fdim
+        f_flat = jnp.broadcast_to(f_ix, idx.shape).reshape(n)   # [N] feature id
+        v_flat = idx.reshape(n)                                 # [N] vocab row
+        order, sf, sv, run_start = _dedup_sort(
+            f_flat, v_flat, vocab, force_pairs=fdim * vocab > _FLAT_KEY_MAX
+        )
+        sg = g_rows.reshape(n, d)[order]
+    with jax.named_scope("tfr.segment_sum"):
+        rid = jnp.cumsum(run_start) - 1                     # run id per element
+        # per-element view of its duplicate group's summed gradient and size
+        g_sum = jax.ops.segment_sum(
+            sg, rid, num_segments=n, indices_are_sorted=True
+        )[rid]                                              # [N, D]
+        m = jax.ops.segment_sum(
+            jnp.ones((n,), jnp.float32), rid, num_segments=n, indices_are_sorted=True
+        )[rid]                                              # [N]
+        inv_m = 1.0 / m
     # Scatter with (f, v) index PAIRS, never a flattened [F*V] view: the
     # table/accum keep their [F, V@model, D] layout, so GSPMD scatters into
     # the model-sharded V axis instead of all-gathering a reshaped table
     # (both _dedup_sort paths emit (f, v) in lexicographic order).
-    accum = opt_state.accum.at[sf, sv].add(ms_share, indices_are_sorted=True)
-    # post-accumulation scale, shared by a row's duplicates by construction
-    scale = embed_lr * jax.lax.rsqrt(accum[sf, sv] + embed_eps)     # [N]
-    table = table.at[sf, sv].add(
-        -(scale * inv_m)[:, None] * g_sum, indices_are_sorted=True
-    )
+    # The TPU compiler rewrites both scatters into fusions that carry no
+    # op_name of their own; only the arithmetic fused into them tells a
+    # trace reader which scope they belong to, so each scatter's operand is
+    # computed inside the scatter's scope.
+    with jax.named_scope("tfr.accum_update"):
+        ms_share = jnp.mean(g_sum * g_sum, axis=-1) * inv_m     # sums to mean(G^2)
+        accum = opt_state.accum.at[sf, sv].add(ms_share, indices_are_sorted=True)
+        # post-accumulation scale, shared by a row's duplicates by construction
+        scale = embed_lr * jax.lax.rsqrt(accum[sf, sv] + embed_eps)     # [N]
+    with jax.named_scope("tfr.table_scatter"):
+        table = table.at[sf, sv].add(
+            -(scale * inv_m)[:, None] * g_sum, indices_are_sorted=True
+        )
     params = dict(dense_params, embeddings=table)
     return params, SparseEmbOptState(new_dense_state, accum), loss
 

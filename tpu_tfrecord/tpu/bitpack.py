@@ -23,6 +23,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from tpu_tfrecord.tracing import trace
+
 __all__ = ["packed_width", "pack_bits", "pack_mixed", "unpack_bits"]
 
 _LANE = 32  # packing lane width: int32, the narrowest common transfer dtype
@@ -79,13 +81,21 @@ def pack_mixed(arr: np.ndarray, keep: int, bits: int) -> np.ndarray:
     keep:], bits)])`` with a native single-pass kernel on the hot path
     (csrc tfr_pack_mixed; numpy fallback is bit-identical, pinned in
     tests/test_bitpack.py). The consumer unpacks the tail with
-    ``unpack_bits(wire[:, keep:], C - keep, bits)``.
+    ``unpack_bits(wire[:, keep:], C - keep, bits)``. One ``tfr:pack`` span
+    (rows, bytes out) in a profiler capture.
     """
     if arr.ndim != 2:
         raise ValueError(f"pack_mixed expects [B, C], got shape {arr.shape}")
     if not 0 <= keep <= arr.shape[1]:
         raise ValueError(f"keep={keep} out of range for {arr.shape[1]} columns")
     packed_width(1, bits)  # validate bits BEFORE dispatching to the kernel
+    with trace("tfr:pack") as tr:
+        out = _pack_mixed(arr, keep, bits)
+        tr.set_metadata(rows=out.shape[0], bytes=out.nbytes)
+    return out
+
+
+def _pack_mixed(arr: np.ndarray, keep: int, bits: int) -> np.ndarray:
     if arr.dtype == np.int32:
         # hot path (decode emits int32 group matrices): single native pass,
         # sign validation rides the kernel loop — no extra numpy scan

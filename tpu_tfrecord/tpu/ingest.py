@@ -21,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tpu_tfrecord import wire
 from tpu_tfrecord.columnar import Column, ColumnarBatch, pad_ragged, pad_ragged2
 from tpu_tfrecord.metrics import METRICS, timed
+from tpu_tfrecord.tracing import STOPPED, get_or_wait, put_or_wait, trace
 from tpu_tfrecord.schema import (
     ArrayType,
     BinaryType,
@@ -210,10 +211,26 @@ def host_batch_from_columnar(
     ``cast`` maps column name -> output dtype (e.g. bfloat16 for float
     frames). For ragged columns the pad and the cast run fused in the native
     kernel — the f32->bf16 conversion never materializes an f32 dense batch.
+
+    The call is one ``tfr:pack`` span (rows, bytes out) in a profiler
+    capture.
     """
-    pad_to = pad_to or {}
-    hash_buckets = hash_buckets or {}
-    cast = cast or {}
+    with trace("tfr:pack") as tr:
+        out = _densify(
+            batch, schema, pad_to or {}, hash_buckets or {}, include_lengths,
+            pack, cast or {},
+        )
+        tr.set_metadata(
+            rows=batch.num_rows, bytes=sum(a.nbytes for a in out.values())
+        )
+    return out
+
+
+def _densify(
+    batch: ColumnarBatch, schema: StructType, pad_to: dict, hash_buckets: dict,
+    include_lengths: bool, pack: Optional[Dict[str, List[str]]], cast: dict,
+) -> Dict[str, np.ndarray]:
+    """The work of :func:`host_batch_from_columnar`."""
     _validate_cast(schema, cast)
     if cast and pack:
         # A pack group is ONE matrix with one dtype — a per-member cast
@@ -350,10 +367,8 @@ def make_global_batch(
     ``axis``. Each host contributes its local rows; across P processes the
     global batch dim is P * local_batch (jax.make_array_from_process_local_data
     — the BASELINE.json north-star assembly path)."""
-    from tpu_tfrecord.tracing import trace
-
     single_process = jax.process_count() == 1
-    with timed("h2d", METRICS) as t, trace("tfr:h2d"):
+    with timed("h2d", METRICS) as t, trace("tfr:h2d") as tr:
         if shardings is None:
             shardings = data_shardings(host_batch, mesh, axis)
         if single_process:
@@ -368,6 +383,7 @@ def make_global_batch(
         for arr in host_batch.values():
             t.bytes += arr.nbytes
         t.records += next(iter(host_batch.values())).shape[0] if host_batch else 0
+        tr.set_metadata(rows=t.records, bytes=t.bytes)
     return out
 
 
@@ -607,7 +623,12 @@ class HostPrefetcher:
 
     _DONE = object()
 
-    def __init__(self, host_batches: Iterable[Dict[str, np.ndarray]], depth: int = 2):
+    def __init__(
+        self,
+        host_batches: Iterable[Dict[str, np.ndarray]],
+        depth: int = 2,
+        name: str = "host",
+    ):
         import queue
         import threading
 
@@ -615,17 +636,14 @@ class HostPrefetcher:
         self._stop = threading.Event()
         self._empty = queue.Empty  # shutdown-safe binding (module may be gone)
         self._finished: Optional[object] = None
+        # the queue's name in a profiler capture: how long the worker sat on
+        # it full, and the consumer on it empty (tracing.ANNOTATIONS)
+        self._blocked, self._starved = f"tfr:blocked.{name}", f"tfr:starved.{name}"
 
         def _produce():
             try:
                 for hb in host_batches:
-                    while not self._stop.is_set():
-                        try:
-                            self._queue.put(hb, timeout=0.1)
-                            break
-                        except queue.Full:
-                            continue
-                    if self._stop.is_set():
+                    if not put_or_wait(self._queue, hb, self._stop, self._blocked):
                         return
                 self._queue.put(self._DONE)
             except BaseException as e:  # noqa: BLE001 — repropagated in consumer  # graftlint: swallow(exception forwarded to the consumer queue, repropagated)
@@ -645,9 +663,9 @@ class HostPrefetcher:
             if self._finished is self._DONE:
                 raise StopIteration
             raise self._finished
-        item = self._queue.get()
-        if item is self._DONE:
-            self._finished = item
+        item = get_or_wait(self._queue, self._stop, self._starved)
+        if item is self._DONE or item is STOPPED:
+            self._finished = self._DONE
             raise StopIteration
         if isinstance(item, BaseException):
             self._finished = item
@@ -725,11 +743,12 @@ class DeviceIterator:
                 for host in self._it:
                     t0 = time.perf_counter()
                     gb = self._transfer(host, _timed=False)
-                    jax.block_until_ready(gb)
+                    with trace("tfr:h2d_land"):
+                        jax.block_until_ready(gb)
                     self.transfer_seconds += time.perf_counter() - t0
                     yield gb
 
-            self._pf = HostPrefetcher(_transferred(), depth=depth)
+            self._pf = HostPrefetcher(_transferred(), depth=depth, name="device")
 
     def _transfer(
         self, host: Dict[str, np.ndarray], _timed: bool = True

@@ -1,15 +1,20 @@
-"""Profiling hooks: jax.profiler integration + device duty-cycle estimation.
+"""Profiling hooks: the program's own names on the profiler's trace, and
+a host-clock duty-cycle estimate.
 
 The reference has no observability of its own (SURVEY.md §5: tracing ABSENT
 — it rides on Spark's UI). Here the input pipeline is the product, so it can
 explain itself:
 
-- ``trace(name)``: annotates a host-side region so it shows up on the xprof
-  timeline next to device ops (no-op when jax/profiler is unavailable).
-  The flight recorder (tpu_tfrecord.telemetry) rides next to these: every
-  span-instrumented pipeline site also holds a ``trace`` annotation, so an
-  xprof capture shows the same regions the Chrome-trace export does.
-- ``start_trace/stop_trace``: wrap jax.profiler for a whole capture.
+- ``trace(name, **args)``: a ``jax.profiler.TraceAnnotation`` around a
+  host-side region, so it lands in a profiler capture on the same clock as
+  the device's operations (a shared no-op when jax/profiler is
+  unavailable). The flight recorder (tpu_tfrecord.telemetry) rides next to
+  these on the host's clock: the operator's view, not read on the chip.
+- ``put_or_wait`` / ``get_or_wait``: a bounded queue's hand-off, with the
+  time a thread sat blocked on it under one span.
+- ``ANNOTATIONS``: every ``tfr:*`` host span and ``tfr.*`` device scope
+  (``jax.named_scope`` in ``models/dlrm.py``) the package emits — the one
+  place the names live; ``benchmark/layer_metrics`` and PERF.md quote them.
 - ``DutyCycle``: estimates the BASELINE.md north-star secondary metric — the
   fraction of wall time the device spends computing vs waiting on input —
   from step/wait timestamps recorded in the training loop.
@@ -18,8 +23,43 @@ explain itself:
 from __future__ import annotations
 
 import contextlib
+import queue
 import time
 from typing import Optional
+
+#: name -> what it covers. ``tfr:`` names are host spans (one per batch or
+#: rarer, never per record); ``tfr.`` names are scopes inside the jitted
+#: DLRM programs, found in the ``op_name`` of the device's operations.
+#: ``<queue>`` is ``batch`` (the dataset's prefetch queue), ``host``
+#: (HostPrefetcher's) or ``device`` (DeviceIterator's transfer thread's).
+ANNOTATIONS = {
+    "tfr:open": "opening a shard",
+    "tfr:cache": "serving a chunk from the columnar cache",
+    "tfr:decode": "frame scan + CRC + decode + hash of one chunk; rows, bytes",
+    "tfr:pack": "host_batch_from_columnar or pack_mixed on one batch; rows, bytes out",
+    "tfr:h2d": "make_global_batch: the dispatch of one batch's copy; rows, bytes",
+    "tfr:h2d_land": "the transfer thread's wait for that copy to land",
+    "tfr:blocked.batch": "the decode thread's put waited on a full prefetch queue",
+    "tfr:starved.batch": "the dataset's consumer found its prefetch queue empty",
+    "tfr:blocked.host": "HostPrefetcher's thread waited on its full queue",
+    "tfr:starved.host": "HostPrefetcher's consumer found its queue empty",
+    "tfr:blocked.device": "the transfer thread waited on its full queue",
+    "tfr:starved.device": "DeviceIterator's consumer found its queue empty",
+    "tfr.write.encode": "writer: host span (named before the ':' convention) around encode",
+    "tfr.write.compress": "writer: host span around compress",
+    "tfr.write.io": "writer: host span around the file write",
+    "tfr.write.commit": "writer: host span around the commit",
+    "tfr.table_cast": "forward: the table cast to the activation dtype",
+    "tfr.gather": "the embedding rows gathered from the table",
+    "tfr.bottom_mlp": "bottom MLP (backward ops carry it inside transpose(jvp(..)))",
+    "tfr.interaction": "feature interaction",
+    "tfr.top_mlp": "top MLP to the logits",
+    "tfr.dense_update": "sparse_train_step: optimizer update of the MLPs",
+    "tfr.dedup_sort": "sparse_train_step: sort of the batch's keys, row gradients reordered",
+    "tfr.segment_sum": "sparse_train_step: duplicate keys' gradients and counts summed",
+    "tfr.accum_update": "sparse_train_step: AdaGrad accumulator scatter, gather, rsqrt",
+    "tfr.table_scatter": "sparse_train_step: the row updates scattered into the table",
+}
 
 
 _PROF = None
@@ -50,11 +90,14 @@ class _NullTrace:
     def __exit__(self, *exc) -> None:
         return None
 
+    def set_metadata(self, **args) -> None:
+        return None
+
 
 _NULL_TRACE = _NullTrace()
 
 
-def trace(name: str):
+def trace(name: str, **args):
     """Annotate a host-side region on the profiler timeline.
 
     Returns the profiler's TraceAnnotation directly (it IS a context
@@ -62,23 +105,57 @@ def trace(name: str):
     per-chunk hot paths (decode, cache serve, write stages), where the old
     ``@contextlib.contextmanager`` layer allocated a generator per call
     even with no profiler present. With jax unavailable a shared no-op is
-    returned: zero allocation per call."""
+    returned: zero allocation per call. ``args`` become the event's stats
+    in a capture; what is only known at the end of the region goes through
+    the returned object's ``set_metadata(**args)``."""
     prof = _profiler()
     if prof is None:
         return _NULL_TRACE
-    return prof.TraceAnnotation(name)
+    return prof.TraceAnnotation(name, **args)
 
 
-def start_trace(logdir: str) -> None:
-    prof = _profiler()
-    if prof is not None:
-        prof.start_trace(logdir)
+#: what ``get_or_wait`` returns once ``stop`` is set
+STOPPED = object()
 
 
-def stop_trace() -> None:
-    prof = _profiler()
-    if prof is not None:
-        prof.stop_trace()
+def put_or_wait(q: "queue.Queue", item, stop, span: str) -> bool:
+    """Put ``item`` on a bounded queue unless ``stop`` is (or gets) set;
+    False then. A put that finds room opens no span; one that finds the
+    queue full waits under ONE ``span``, however many polls it takes."""
+    if stop.is_set():
+        return False
+    try:
+        q.put_nowait(item)
+        return True
+    except queue.Full:
+        pass
+    with trace(span):
+        while not stop.is_set():
+            try:
+                q.put(item, timeout=0.1)
+                return True
+            except queue.Full:
+                continue
+    return False
+
+
+def get_or_wait(q: "queue.Queue", stop, span: str):
+    """Next item of a queue, or ``STOPPED`` once ``stop`` is set. An item
+    that is already there opens no span; an empty queue is waited on under
+    ONE ``span``."""
+    if stop.is_set():
+        return STOPPED
+    try:
+        return q.get_nowait()
+    except queue.Empty:
+        pass
+    with trace(span):
+        while not stop.is_set():
+            try:
+                return q.get(timeout=0.1)
+            except queue.Empty:
+                continue
+    return STOPPED
 
 
 class DutyCycle:
