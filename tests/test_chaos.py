@@ -331,6 +331,7 @@ class TestChaosDeterminism:
             on_corrupt="skip_record",
             use_mmap=False,
             retry_policy=_fast_retries(1),
+            num_workers=1,  # a ledger replays only if shards are read in one order
         )
         if checkpoint_at is None:
             rows, _ = _read_ids(out, **kw)

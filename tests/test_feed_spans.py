@@ -11,6 +11,7 @@ import functools
 import glob
 import os
 import queue
+import re
 import threading
 import time
 
@@ -182,7 +183,20 @@ def test_a_slow_producer_starves_the_queues_after_it(recorder, data_dir):
     assert recorder.names() <= set(tracing.ANNOTATIONS)
 
 
-def test_a_consumer_that_arrives_before_the_first_chunk_is_starved(recorder, data_dir):
+def test_a_consumer_that_arrives_before_the_first_chunk_is_starved(recorder, data_dir,
+                                                                   monkeypatch):
+    # the decode thread holds its first chunk back until the consumer waits: on a
+    # loaded runner it can otherwise have a batch queued before next() is reached
+    waiting, annotate = threading.Event(), recorder.TraceAnnotation
+
+    def annotation(name, **args):
+        if name == "tfr:starved.batch":
+            waiting.set()
+        elif name == "tfr:decode":
+            assert waiting.wait(timeout=30.0)
+        return annotate(name, **args)
+
+    monkeypatch.setattr(recorder, "TraceAnnotation", annotation)
     ds = TFRecordDataset(data_dir, batch_size=BATCH, schema=SCHEMA, num_epochs=1)
     with ds.batches() as it:
         assert next(it).num_rows == BATCH  # asked for before anything is decoded
@@ -247,22 +261,22 @@ def test_under_the_real_profiler_the_spans_land_on_the_host_plane(data_dir, tmp_
     assert set(found) <= set(tracing.ANNOTATIONS)
 
 
-FORWARD_SCOPES = {"tfr.bottom_mlp", "tfr.table_cast", "tfr.gather", "tfr.interaction",
-                  "tfr.top_mlp"}
-STEP_SCOPES = (FORWARD_SCOPES - {"tfr.table_cast"}) | {
+FORWARD_SCOPES = {"tfr.bottom_mlp", "tfr.gather", "tfr.interaction", "tfr.top_mlp"}
+STEP_SCOPES = FORWARD_SCOPES | {
     "tfr.dense_update", "tfr.dedup_sort", "tfr.segment_sum", "tfr.accum_update",
     "tfr.table_scatter"}
+
+
+def _small_dlrm():
+    cfg = DLRMConfig(vocab_size=64, embed_dim=16, bottom_mlp=(32, 16), top_mlp=(32, 1),
+                     interaction="dot")
+    return cfg, init_params(jax.random.PRNGKey(0), cfg), make_synthetic_batch(cfg, 32)
 
 
 @pytest.mark.parametrize("program,scopes", [("forward", FORWARD_SCOPES),
                                             ("sparse_train_step", STEP_SCOPES)])
 def test_the_compiled_program_holds_every_scope(program, scopes):
-    import re
-
-    cfg = DLRMConfig(vocab_size=64, embed_dim=16, bottom_mlp=(32, 16), top_mlp=(32, 1),
-                     interaction="dot")
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    batch = make_synthetic_batch(cfg, 32)
+    cfg, params, batch = _small_dlrm()
     if program == "forward":
         lowered = jax.jit(functools.partial(forward, cfg=cfg)).lower(params, batch)
     else:
@@ -276,3 +290,16 @@ def test_the_compiled_program_holds_every_scope(program, scopes):
     # backward operations keep the forward scope inside transpose(jvp(...))
     if program == "sparse_train_step":
         assert any("transpose(jvp(tfr.top_mlp))" in name for name in op_names)
+
+
+def test_forward_makes_no_value_of_the_tables_shape():
+    """``forward`` reads the rows a batch names out of the float32 table; a
+    cast, copy or view of all [F, V, D] of it (PR 24's trace: 70% of the
+    score step) must not come back. Parameters are the table itself."""
+    cfg, params, batch = _small_dlrm()
+    hlo = jax.jit(functools.partial(forward, cfg=cfg)).lower(params, batch).compile().as_text()
+    f, v, d = params["embeddings"].shape
+    produced = re.findall(rf"^\s*(?:ROOT )?%?\S+ = \w+\[(?:1,)?{f},{v},{d}\]\S* (\w[\w-]*)\(",
+                          hlo, flags=re.M)
+    assert "parameter" in produced          # the pattern does see the table
+    assert set(produced) == {"parameter"}, produced

@@ -18,6 +18,7 @@ from tpu_tfrecord.models import (
     train_step,
 )
 from tpu_tfrecord.models.dlrm import (
+    _gather_rows,
     batch_shardings,
     dense_rowwise_adagrad_reference,
 )
@@ -63,6 +64,88 @@ class TestDLRM:
         b2["frames"] = jax.numpy.asarray(frames)
         logits2 = forward(params, b2, cfg)
         np.testing.assert_allclose(np.asarray(logits), np.asarray(logits2), rtol=2e-2)
+
+
+def _lookup_case(dtype, interaction="dot"):
+    cfg = DLRMConfig(num_dense=4, num_categorical=5, vocab_size=32, embed_dim=8,
+                     bottom_mlp=(16, 8), top_mlp=(16, 1), interaction=interaction,
+                     dtype=dtype)
+    params = init_params(jax.random.key(7), cfg)
+    batch = {k: jax.numpy.asarray(v) for k, v in make_synthetic_batch(cfg, 64, seed=3).items()}
+    return cfg, params, batch
+
+
+class TestEmbeddingLookup:
+    """``forward`` reads the rows a batch names and rounds THEM, the lookup
+    ``sparse_train_step`` differentiates through: no table-sized copy, and
+    not one bit of a logit moved by it."""
+
+    @pytest.mark.parametrize("dtype", [jax.numpy.bfloat16, jax.numpy.float32])
+    @pytest.mark.parametrize("jit", [False, True])
+    def test_rows_gathered_then_rounded_equal_a_rounded_table_gathered(self, dtype, jit):
+        jnp = jax.numpy
+        cfg, params, batch = _lookup_case(dtype)
+        f_ix = jnp.arange(cfg.num_categorical)[None, :]
+
+        def by_table(p, b):
+            return forward(p, b, cfg)
+
+        def by_rows(p, b):
+            return forward(p, b, cfg, emb=p["embeddings"][f_ix, b["cat"]])
+
+        def as_before_pr25(p, b):
+            # the lookup forward had: the whole table cast to the activation
+            # dtype, then take_along_axis over a [1, F, V, D] view of it
+            table = p["embeddings"].astype(cfg.dtype)[None]
+            emb = jnp.take_along_axis(table, b["cat"][:, :, None, None], axis=2)[:, :, 0, :]
+            return forward(p, b, cfg, emb=emb)
+
+        fns = [by_table, by_rows, as_before_pr25]
+        if jit:
+            fns = [jax.jit(f) for f in fns]
+        got, rows, before = (np.asarray(f(params, batch)) for f in fns)
+        assert np.isfinite(got).all() and np.abs(got).max() > 0
+        np.testing.assert_array_equal(got, rows)
+        np.testing.assert_array_equal(got, before)
+
+    def test_an_index_outside_the_table_reads_the_same_row_scored_and_trained(self):
+        """Past the end: the feature's LAST row (``take_along_axis`` filled
+        NaN there before PR 25); negative: from the end. The same in
+        ``forward`` and in the gather ``sparse_train_step`` trains through."""
+        jnp = jax.numpy
+        cfg, params, batch = _lookup_case(jnp.float32)
+        v = cfg.vocab_size
+        cat = np.asarray(batch["cat"]).copy()
+        cat[0, :] = [v, v + 1000, -1, -3, 2**31 - 1]
+        same = cat.copy()
+        same[0, :] = [v - 1, v - 1, v - 1, v - 3, v - 1]
+        rows = np.asarray(_gather_rows(params["embeddings"], jnp.asarray(cat)))
+        table = np.asarray(params["embeddings"])
+        np.testing.assert_array_equal(rows[0], table[np.arange(5), same[0]])
+        np.testing.assert_array_equal(
+            rows, np.asarray(_gather_rows(params["embeddings"], jnp.asarray(same))))
+        fwd = jax.jit(functools.partial(forward, cfg=cfg))
+        wild = np.asarray(fwd(params, dict(batch, cat=jnp.asarray(cat))))
+        tame = np.asarray(fwd(params, dict(batch, cat=jnp.asarray(same))))
+        assert np.isfinite(wild).all()
+        np.testing.assert_array_equal(wild, tame)
+        by_rows = jax.jit(lambda p, b, r: forward(p, b, cfg, emb=r))
+        np.testing.assert_array_equal(
+            wild, np.asarray(by_rows(params, batch, jnp.asarray(rows))))
+
+    def test_the_table_gradient_is_the_scatter_of_the_row_gradients(self):
+        """``train_step``'s dense path: the transpose of the pair gather adds
+        each row's gradient into its table row, duplicates summed."""
+        jnp = jax.numpy
+        cfg, params, batch = _lookup_case(jnp.float32, interaction="cat")
+        batch["cat"] = batch["cat"].at[1].set(batch["cat"][0])   # duplicates
+        f_ix = jnp.arange(cfg.num_categorical)[None, :]
+        g_table = jax.grad(loss_fn)(params, batch, cfg)["embeddings"]
+        g_rows = jax.grad(lambda r: loss_fn(params, batch, cfg, emb=r))(
+            params["embeddings"][f_ix, batch["cat"]])
+        want = jnp.zeros_like(params["embeddings"]).at[f_ix, batch["cat"]].add(g_rows)
+        np.testing.assert_allclose(np.asarray(g_table), np.asarray(want), rtol=1e-6, atol=1e-9)
+        assert float(jnp.abs(g_table).max()) > 0
 
 
 class TestGraftEntry:

@@ -1,6 +1,8 @@
 """Tests for pipeline features: multi-worker decode, shard shuffle, retries."""
 
 import os
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -42,14 +44,57 @@ def collect_uids(ds, state=None):
 
 
 class TestMultiWorker:
-    @pytest.mark.parametrize("workers", [2, 4])
+    @pytest.mark.parametrize("workers", [None, 2, 4])
     def test_identical_to_sequential(self, sandbox, workers):
         out = write_shards(sandbox)
-        seq = collect_uids(TFRecordDataset(out, batch_size=5, schema=SCHEMA))
+        seq = collect_uids(
+            TFRecordDataset(out, batch_size=5, schema=SCHEMA, num_workers=1)
+        )
         par = collect_uids(
             TFRecordDataset(out, batch_size=5, schema=SCHEMA, num_workers=workers)
         )
         assert par == seq  # exact order, not just same multiset
+
+    @pytest.mark.parametrize(
+        "cores, workers", [(1, 1), (2, 1), (4, 2), (8, 4), (13, 4), (30, 4)]
+    )
+    def test_default_is_half_the_cores_at_most_four(self, monkeypatch, cores, workers):
+        from tpu_tfrecord.io import dataset
+
+        monkeypatch.setattr(os, "sched_getaffinity", lambda _pid: set(range(cores)))
+        assert dataset.default_num_workers() == workers
+
+    def test_who_gets_the_default(self, sandbox, monkeypatch):
+        """Not given: the default pool, and its shards decode on several
+        threads. Given: as given, at least 1. With the epoch cache on: one
+        shard at a time, because the cache commits an entry at its shard's end."""
+        from tpu_tfrecord.io import dataset
+
+        out = write_shards(sandbox)
+        monkeypatch.setattr(dataset, "default_num_workers", lambda: 3)
+        kw = dict(batch_size=5, schema=SCHEMA)
+        assert TFRecordDataset(out, **kw).num_workers == 3
+        assert TFRecordDataset(out, num_workers=2, **kw).num_workers == 2
+        assert TFRecordDataset(out, num_workers=0, **kw).num_workers == 1
+        cached = TFRecordDataset(
+            out, cache="auto", cache_dir=str(sandbox / "cache"), **kw
+        )
+        assert cached.num_workers == 1
+        assert TFRecordDataset(
+            out, num_workers=2, cache="auto", cache_dir=str(sandbox / "cache"), **kw
+        ).num_workers == 2
+        sequential = collect_uids(TFRecordDataset(out, num_workers=1, **kw))
+        decoders = set()
+        inner = TFRecordDataset._decode_shard
+
+        def seen(self, *task):
+            decoders.add(threading.current_thread().name)
+            time.sleep(0.05)  # long enough for the other workers to take a shard each
+            yield from inner(self, *task)
+
+        monkeypatch.setattr(TFRecordDataset, "_decode_shard", seen)
+        assert collect_uids(TFRecordDataset(out, **kw)) == sequential
+        assert len(decoders) == 3
 
     def test_parallel_resume(self, sandbox):
         out = write_shards(sandbox)

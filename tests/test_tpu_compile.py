@@ -27,7 +27,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 import chip_smoke
 from tpu_tfrecord.models import (
-    init_params as dlrm_init, lm, sparse_opt_init, sparse_train_step,
+    DLRMConfig, forward as dlrm_forward, init_params as dlrm_init, lm, sparse_opt_init,
+    sparse_train_step,
 )
 from tpu_tfrecord.models import pipeline as pp
 from tpu_tfrecord.models.attention import ring_attention
@@ -104,6 +105,27 @@ class TestOneChip:
         )
         out = split.lower(gb).compile().output_shardings
         assert set(out) == {"label", "dense", "cat"}
+
+    def test_forward_holds_no_copy_of_the_table(self, one_chip):
+        """MLPerf widths at 2^15 rows a table (436 MB of float32): scoring
+        reads the rows a batch names, so its temp is the size of the batch's
+        activations. Before PR 25 a bfloat16 copy of the table (half its
+        bytes; 3.49 GB of temp at the benchmark's 2^19 rows) was made per call."""
+        cfg = DLRMConfig(num_dense=13, num_categorical=26, vocab_size=1 << 15,
+                         embed_dim=128, bottom_mlp=(512, 256, 128),
+                         top_mlp=(1024, 1024, 512, 256, 1), interaction="dot")
+        params = jax.eval_shape(lambda: dlrm_init(jax.random.key(0), cfg))
+        batch = {
+            "dense": jax.ShapeDtypeStruct((2048, 13), jnp.float32),
+            "cat": jax.ShapeDtypeStruct((2048, 26), jnp.int32),
+        }
+        compiled = jax.jit(functools.partial(dlrm_forward, cfg=cfg)).lower(
+            _shaped(params, one_chip), _shaped(batch, one_chip)).compile()
+        table = params["embeddings"]
+        table_bytes = table.size * table.dtype.itemsize
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes > table_bytes         # the table is in
+        assert mem.temp_size_in_bytes < table_bytes // 8
 
     def test_sparse_train_step_at_the_bench_config_fits_hbm(self, one_chip):
         """The one long compile (~60 s): 26 x 2^20 x 32 f32 tables, B=16,384."""

@@ -102,6 +102,16 @@ def _mlp(layers, x, dtype):
     return x
 
 
+def _gather_rows(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """Rows [B, F, D] of the stacked table [F, V, D] that ``idx`` [B, F]
+    names, in the table's dtype: the one embedding lookup, shared by
+    ``forward`` and ``sparse_train_step`` so a scored row and a trained row
+    are the same row. Indexed with (feature, row) PAIRS, never a flattened
+    [F*V] view, so a [F, V@model, D] table is gathered along its sharded V
+    axis in place (see the scatter note in ``sparse_train_step``)."""
+    return table[jnp.arange(table.shape[0])[None, :], idx]
+
+
 def forward(
     params: Dict[str, Any],
     batch: Dict[str, jax.Array],
@@ -109,6 +119,11 @@ def forward(
     emb: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Logits [B]. bfloat16 activations, float32 output.
+
+    Only the rows ``batch['cat']`` names are read from the table and rounded
+    to the activation dtype. An index past the end reads the feature's last
+    row and a negative one counts from the end (NumPy indexing as JAX clamps
+    it; the same in ``sparse_train_step``) — readers fold keys into [0, V).
 
     ``emb`` optionally supplies the gathered embedding rows [B, F, D]
     directly (the sparse-update path differentiates w.r.t. the rows, not
@@ -118,19 +133,10 @@ def forward(
     with jax.named_scope("tfr.bottom_mlp"):
         dense = batch["dense"].astype(dt)
         bottom_out = _mlp(params["bottom"], dense, dt)      # [B, H]
-    if emb is None:
-        with jax.named_scope("tfr.table_cast"):
-            table = params["embeddings"].astype(dt)[None]   # [1, F, V, D]
-        with jax.named_scope("tfr.gather"):
-            # [B, F] indices into [F, V, D] -> [B, F, D]
-            emb = jnp.take_along_axis(
-                table,
-                batch["cat"][:, :, None, None],              # [B, F, 1, 1]
-                axis=2,
-            )[:, :, 0, :]
-    else:
-        with jax.named_scope("tfr.gather"):
-            emb = emb.astype(dt)
+    with jax.named_scope("tfr.gather"):
+        if emb is None:
+            emb = _gather_rows(params["embeddings"], batch["cat"])
+        emb = emb.astype(dt)                                # [B, F, D]
     with jax.named_scope("tfr.interaction"):
         if cfg.interaction == "dot":
             from tpu_tfrecord.models.interaction import dot_interaction
@@ -265,7 +271,7 @@ def sparse_train_step(
     idx = batch["cat"]                                      # [B, F]
     f_ix = jnp.arange(cfg.num_categorical)[None, :]         # [1, F]
     with jax.named_scope("tfr.gather"):
-        rows = table[f_ix, idx]                             # [B, F, D]
+        rows = _gather_rows(table, idx)                     # [B, F, D]
     dense_params = {k: v for k, v in params.items() if k != "embeddings"}
 
     def loss_of(dp, r):

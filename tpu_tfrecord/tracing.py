@@ -49,8 +49,9 @@ ANNOTATIONS = {
     "tfr.write.compress": "writer: host span around compress",
     "tfr.write.io": "writer: host span around the file write",
     "tfr.write.commit": "writer: host span around the commit",
-    "tfr.table_cast": "forward: the table cast to the activation dtype",
-    "tfr.gather": "the embedding rows gathered from the table",
+    "tfr.table_cast": "no program opens it since PR 25 (forward's table-sized cast is gone); "
+                      "the benchmark's recorded trace and step_ms.table_cast name it",
+    "tfr.gather": "the embedding rows gathered from the table, rounded to the activation dtype",
     "tfr.bottom_mlp": "bottom MLP (backward ops carry it inside transpose(jvp(..)))",
     "tfr.interaction": "feature interaction",
     "tfr.top_mlp": "top MLP to the logits",
