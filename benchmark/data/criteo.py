@@ -1,5 +1,7 @@
-"""Criteo-shaped TFRecord shards from ``--seed``, and what the reader must
-make of them.
+"""The data shape ``criteo``: Criteo-shaped TFRecord shards from ``--seed``,
+and what the reader must make of them. ``run.py`` finds this module by a
+configuration's ``"data"`` and asks it for :func:`write` and
+:func:`describe`; the rest is the generator.
 
 A copy of ``chip_smoke.write_dataset`` (commit 0c9422d) with two changes. A
 categorical column is not uniform over 16^8 values but draws a rank from
@@ -86,7 +88,9 @@ def expected_rows(label, dense, cats) -> np.ndarray:
 def write_dataset(data_dir: str, seed: int, shards: int, rows_per_shard: int,
                   cardinalities, exponent: float, positive_rate: float) -> np.ndarray:
     """Write the shards with the framework's columnar writer (one append
-    job a shard); returns the expected matrix in reading order."""
+    job a shard, the shard's number as its task: the reader walks sorted
+    names, so the seed fixes the order too, where the job's random id alone
+    would decide it); returns the expected matrix in reading order."""
     from tpu_tfrecord.columnar import Column, ColumnarBatch
     from tpu_tfrecord.io.writer import DatasetWriter
     from tpu_tfrecord.options import TFRecordOptions
@@ -113,9 +117,31 @@ def write_dataset(data_dir: str, seed: int, shards: int, rows_per_shard: int,
         before = set(os.listdir(data_dir)) if os.path.isdir(data_dir) else set()
         DatasetWriter(
             data_dir, schema, TFRecordOptions.from_map(), mode="append"
-        ).write_batches([ColumnarBatch(cols, n)])
+        ).write_batches([ColumnarBatch(cols, n)], task_id=shard)
         new = [f for f in set(os.listdir(data_dir)) - before if f.endswith(".tfrecord")]
         if len(new) != 1:
             raise RuntimeError(f"shard {shard}: the writer left {len(new)} new files")
         by_file[new[0]] = expected_rows(label, dense, cats)
     return np.concatenate([by_file[f] for f in sorted(by_file)], axis=0)
+
+
+def write(data_dir: str, seed: int, cfg: dict, mix: dict) -> np.ndarray:
+    """The seed's shards for a configuration and a mix; returns the expected
+    matrix (``env.expected``), which the Criteo loops hold the reader to."""
+    return write_dataset(data_dir, seed, mix["shards"], mix["rows_per_shard"],
+                         cfg["cardinalities"], cfg["key_law_exponent"],
+                         cfg["label_positive_rate"])
+
+
+def distinct_key_share(expected: np.ndarray, cfg: dict, batch: int) -> float:
+    """Distinct (table, row) keys of the first batch over all of its keys:
+    the step's sort, dedup and scatter take as long as the key law says."""
+    keep, n_v = 1 + cfg["num_dense"], cfg["rows_per_table"]
+    cat = expected[:batch, keep:].astype(np.int64) % n_v
+    keys = cat + np.arange(cat.shape[1], dtype=np.int64)[None, :] * n_v
+    return float(np.unique(keys).size / keys.size)
+
+
+def describe(expected: np.ndarray, cfg: dict, mix: dict) -> dict:
+    """This data shape's fields of the ``[data]`` line."""
+    return {"distinct_key_share": distinct_key_share(expected, cfg, mix["batch"])}

@@ -174,5 +174,6 @@ def judge(env, numbers: dict, limits: dict, notes: dict = None) -> bool:
         inside = bool(np.isfinite(value)) and value <= limits[name]
         extra = {"worst_leaf": notes[name]} if name in notes else {}
         env.info("compare", number=name, value=value, limit=limits[name], ok=inside, **extra)
+        env.compared[name] = [value, limits[name]]
         ok = ok and inside
     return ok

@@ -1,15 +1,23 @@
 #!/usr/bin/env python3
-"""One run of one benchmark cell, one process, one chip.
+"""One run of one benchmark cell, one process.
 
     python3 benchmark/run.py --workload W --seed N --seconds S --trace 0|1
 
-looks ``W`` up in BENCHMARK.json, loads ``configs/<config>.json`` and
-``traffic/<traffic>.json`` by name, imports ``models/<model>.py`` and
-``loops/<loop>.py`` by name, and with ``--trace 1`` evaluates every
-``layer_metrics/*.json`` whose mixes include the cell's through
-``readers/<kind>.py``. The last line of stdout is the result object;
-everything else (counts, compile seconds, each number compared beside its
-limit) goes on earlier lines. See benchmark/README.md.
+knows no model and no data set: it looks ``W`` up in BENCHMARK.json, loads
+the configuration's ``file`` and ``traffic/<traffic>.json``, and finds the
+rest by the names those two carry:
+
+    configuration "data"   data/<name>.py    write(data_dir, seed, cfg, mix) -> expected,
+                                             describe(expected, cfg, mix) -> [data] fields
+    configuration "model"  models/<name>.py  handed to the loop as env.model
+    mix "loop"             loops/<name>.py   run(env) -> what was measured and compared
+    "rehearsal" in either  the keys that file overrides under --rehearse
+
+With ``--trace 1`` it evaluates every ``layer_metrics/*.json`` whose mixes
+include the cell's through ``readers/<kind>.py``. The last line of stdout is
+the result object, the numbers compared beside their limits last in it and
+on stderr; counts and compile seconds go on earlier lines. See
+benchmark/README.md.
 """
 
 from __future__ import annotations
@@ -30,13 +38,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-#: what --rehearse shrinks, so that a CPU can walk the whole path
-REHEARSAL = {
-    "cfg": {"rows_per_table": 256},
-    "mix": {"batch": 256, "shards": 2, "rows_per_shard": 1024, "sample_rows": 512,
-            "warmup_steps": 8, "trace_seconds": 0.5},
-}
-
 
 class Refused(Exception):
     """The run cannot be a measurement; exit non-zero with no result line."""
@@ -45,6 +46,17 @@ class Refused(Exception):
 def load_json(*parts):
     with open(os.path.join(HERE, *parts)) as f:
         return json.load(f)
+
+
+def at_rehearsal_size(spec: dict) -> dict:
+    """A configuration or a mix with its own ``"rehearsal"`` laid over it:
+    the sizes at which a CPU can walk the whole path. A group (the limits)
+    is overridden key by key."""
+    spec = dict(spec)
+    for key, small in spec.pop("rehearsal", {}).items():
+        group = isinstance(small, dict) and isinstance(spec.get(key), dict)
+        spec[key] = {**spec[key], **small} if group else small
+    return spec
 
 
 def cpu_seconds() -> float:
@@ -60,6 +72,7 @@ class Env:
         self.__dict__.update(kw)
         self.setup_s = None
         self.traced = None
+        self.compared = {}  # {number: [value, limit]}, filled by window.judge
         self.memory_peak_bytes = 0
 
     def info(self, what: str, **fields) -> None:
@@ -106,17 +119,6 @@ class Env:
             "cpu_s": cpu1 - cpu0,
             "compiles_in_window": len(compiles),
         }
-
-
-def distinct_key_share(expected, cfg: dict, batch: int) -> float:
-    """Distinct (table, row) keys of the first batch over all of its keys:
-    the step's sort, dedup and scatter take as long as the key law says."""
-    import numpy as np
-
-    keep, n_v = 1 + cfg["num_dense"], cfg["rows_per_table"]
-    cat = expected[:batch, keep:].astype(np.int64) % n_v
-    keys = cat + np.arange(cat.shape[1], dtype=np.int64)[None, :] * n_v
-    return float(np.unique(keys).size / keys.size)
 
 
 def longest_gaps(measured: dict, count: int = 5) -> list:
@@ -202,11 +204,7 @@ def main(argv=None, bench_path: str = os.path.join(ROOT, "BENCHMARK.json")) -> i
         cfg = json.load(f)
     mix = load_json("traffic", workload["traffic"] + ".json")
     if args.rehearse:
-        cfg.update(REHEARSAL["cfg"])
-        mix.update({k: v for k, v in REHEARSAL["mix"].items() if k in mix})
-        # B = 256 averages 64 times fewer rows than the cell: the limits read
-        # on the chip at the cell's size do not hold its rounding noise
-        mix["limits"] = dict(mix["limits"], **mix.get("limits_at_rehearsal_size", {}))
+        cfg, mix = at_rehearsal_size(cfg), at_rehearsal_size(mix)
 
     marks = {"parsed": time.perf_counter() - T_START}
     import jax
@@ -226,15 +224,12 @@ def main(argv=None, bench_path: str = os.path.join(ROOT, "BENCHMARK.json")) -> i
     if not _native.available():
         raise Refused(f"native extension unavailable: {_native.load_error()}")
 
-    from benchmark.harness import gen_criteo
     from benchmark.harness.spans import Spans
 
     work = os.path.join(ROOT, ".bench_work", args.workload)
+    data = importlib.import_module("benchmark.data." + cfg["data"])
     t0 = time.perf_counter()
-    expected = gen_criteo.write_dataset(
-        os.path.join(work, "data"), args.seed, mix["shards"], mix["rows_per_shard"],
-        cfg["cardinalities"], cfg["key_law_exponent"], cfg["label_positive_rate"],
-    )
+    expected = data.write(os.path.join(work, "data"), args.seed, cfg, mix)
     env = Env(
         workload=workload, cfg=cfg, mix=mix, seed=args.seed, seconds=args.seconds,
         trace=bool(args.trace), rehearse=args.rehearse, device=device,
@@ -245,8 +240,8 @@ def main(argv=None, bench_path: str = os.path.join(ROOT, "BENCHMARK.json")) -> i
     )
     env.info("device", **found)
     env.info("start", native_checked=t0 - T_START, **marks)
-    env.info("data", seconds=time.perf_counter() - t0, rows=int(expected.shape[0]),
-             distinct_key_share=distinct_key_share(expected, cfg, mix["batch"]))
+    env.info("data", seconds=time.perf_counter() - t0, rows=len(expected),
+             **data.describe(expected, cfg, mix))
 
     loop = importlib.import_module("benchmark.loops." + mix["loop"])
     measured = loop.run(env)
@@ -277,6 +272,8 @@ def main(argv=None, bench_path: str = os.path.join(ROOT, "BENCHMARK.json")) -> i
     else:
         result["metrics"] = {k: {"value": v, "unit": u}
                              for k, (v, u) in values.items() if k in wanted}
+    result["compared"] = env.compared
+    print("compared [value, limit]: " + json.dumps(env.compared), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

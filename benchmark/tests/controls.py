@@ -26,7 +26,7 @@ import os
 
 import numpy as np
 
-from benchmark.harness import gen_criteo
+from benchmark.data import criteo
 from benchmark.loops import score as score_loop
 from benchmark.loops import train as train_loop
 from benchmark.models import dlrm
@@ -35,9 +35,9 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def expected_rows(cfg: dict, seed: int, rows: int) -> np.ndarray:
-    cols = gen_criteo.shard_columns(seed, 0, rows, cfg["cardinalities"],
+    cols = criteo.shard_columns(seed, 0, rows, cfg["cardinalities"],
                                     cfg["key_law_exponent"], cfg["label_positive_rate"])
-    return gen_criteo.expected_rows(*cols)
+    return criteo.expected_rows(*cols)
 
 
 def train_control(cfg: dict, mix: dict, seed: int, table_dtype="bfloat16") -> dict:

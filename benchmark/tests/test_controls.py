@@ -3,17 +3,17 @@ cell's limits, and the reference in its own place must pass them all."""
 
 import pytest
 
+from benchmark import run as bench_run
 from benchmark.tests import controls
 
 SEEDS = (11, 2 ** 31 + 12, 13)
-SMALL = {"batch": 256, "sample_rows": 512}
 
 
 def small_cell(workload):
+    """The cell at its files' own rehearsal sizes, its limits left the cell's."""
     cfg, mix = controls.load_cell(workload)
-    cfg["rows_per_table"] = 256
-    mix.update({k: v for k, v in SMALL.items() if k in mix})
-    return cfg, mix
+    sizes = {k: v for k, v in mix["rehearsal"].items() if k != "limits"}
+    return bench_run.at_rehearsal_size(cfg), {**mix, **sizes}
 
 
 @pytest.mark.parametrize("workload", ["criteo_mlperf.train", "criteo_mlperf.score"])
@@ -39,6 +39,6 @@ def test_the_stated_precision_is_correct(workload, same):
 @pytest.mark.parametrize("seed", SEEDS)
 def test_half_a_batch_left_out_moves_the_loss(seed):
     cfg, mix = small_cell("criteo_mlperf.train")
-    limits = dict(mix["limits"], **mix["limits_at_rehearsal_size"])
+    limits = dict(mix["limits"], **mix["rehearsal"]["limits"])
     numbers = controls.half_batch_fault(cfg, mix, seed)
     assert numbers["loss_gap_step1"] > limits["loss_gap_step1"], numbers
