@@ -1,0 +1,439 @@
+"""The pattern model (``models.lm.score``: softmax and gated delta-rule
+layers by a pattern, a share of the experts, packed rows) against the plain
+reference, at sizes a CPU walks in seconds: each layer kind and the period,
+a packed row against each of its documents alone, the chunked recurrence
+against the token-by-token one, the shares of the experts against the uncut
+layer, dropless routing under a skewed router, and the packer at L 8192."""
+
+import inspect
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_tfrecord.models import linear_attn, lm, moe, pattern_reference as ref
+from tpu_tfrecord.models.attention import attention_reference, blockwise_attention
+from tpu_tfrecord.tpu.ingest import TokenPacker
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: a configuration with the published names, tiny
+CFG = {
+    "hidden_size": 32, "num_hidden_layers": 4, "gqa_layers": [0],
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 8,
+    "linear_attn_config": {"num_heads": 4, "head_dim": 8, "short_conv_kernel_size": 4},
+    "n_routed_experts": 16, "n_routed_experts_held": 4, "held_offset": 4,
+    "num_experts_per_tok": 4, "moe_intermediate_size": 16, "n_shared_experts": 1,
+    "routed_scaling_factor": 1, "rms_norm_eps": 1e-5, "vocab_size": 64,
+}
+L = 48
+
+
+def program_cfg(cfg=CFG, dtype=jnp.float32, **cut):
+    lin = cfg["linear_attn_config"]
+    kinds = ref.layer_kinds(cfg)
+    cut = {"attn_block": 16, "kda_chunk": 8, "expert_tile": 8, "head_block": 32, **cut}
+    return lm.PatternLMConfig(
+        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"], layer_pattern=tuple(kinds),
+        n_heads=cfg["num_attention_heads"], n_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg["head_dim"], kda_heads=lin["num_heads"], kda_head_dim=lin["head_dim"],
+        conv_taps=lin["short_conv_kernel_size"], gate_rank=8,
+        n_experts=cfg["n_routed_experts"], experts_held=cfg["n_routed_experts_held"],
+        held_offset=cfg["held_offset"], top_k=cfg["num_experts_per_tok"],
+        d_expert=cfg["moe_intermediate_size"], n_shared=cfg["n_shared_experts"],
+        norm_eps=cfg["rms_norm_eps"], max_len=L, dtype=dtype, **cut)
+
+
+def flat(tree: dict) -> dict:
+    """The program's tree under the reference's flat names, float32."""
+    out = {}
+    for name, leaf in tree.items():
+        if isinstance(leaf, dict):
+            out.update({f"{name}.{k}": jnp.asarray(v, jnp.float32) for k, v in leaf.items()})
+        else:
+            out[name] = jnp.asarray(leaf, jnp.float32)
+    return out
+
+
+def reference_weights(params):
+    def weights(part):
+        if isinstance(part, int):
+            return flat(params["layers"][part])
+        return {k: jnp.asarray(params[k], jnp.float32) for k in ("embed", "head", "final_norm")}
+    return weights
+
+
+def packed_rows(seed=0, rows=2, eos=0):
+    """Rows of L + 1 tokens as the packer emits them, and their documents."""
+    rng = np.random.default_rng(seed)
+    docs = [rng.integers(1, CFG["vocab_size"], size=n).astype(np.int32)
+            for n in (20, 9, 13, 30, 11, 1, 3)]
+    packer = TokenPacker(rows, L, eos_id=eos, packing="best_fit")
+    packer.feed_docs(docs)
+    packer.flush()
+    batch = packer.pop()
+    assert batch is not None and packer.pop() is None
+    return batch, docs
+
+
+def documents_of(batch):
+    """[(row, start, tokens with the end-of-document id)] of a packed batch."""
+    out = []
+    for r, (toks, segs) in enumerate(zip(batch["tokens"], batch["segment_ids"])):
+        for s in range(1, segs.max() + 1):
+            at = np.flatnonzero(segs == s)
+            out.append((r, at[0], toks[at]))
+    return out
+
+
+@pytest.fixture(scope="module")
+def params():
+    return lm.pattern_init_params(jax.random.PRNGKey(3), program_cfg())
+
+
+@pytest.fixture(scope="module")
+def scored(params):
+    batch, _ = packed_rows()
+    cfg = program_cfg()
+    sample_at = jnp.asarray([[0, 5, 19, 25], [2, 8, 29, 40]], jnp.int32)
+    out = jax.jit(lambda p, t, s, a: lm.score(p, t, s, a, cfg))(
+        params, batch["tokens"], batch["segment_ids"], sample_at)
+    return batch, sample_at, jax.tree.map(np.asarray, out)
+
+
+def test_a_packed_row_scores_each_document_as_the_reference_scores_it_alone(params, scored):
+    batch, sample_at, out = scored
+    docs = documents_of(batch)
+    want = ref.reference_score(CFG, [d for _, _, d in docs], reference_weights(params))
+    covered = np.zeros_like(out["logprob"], bool)
+    for (r, start, doc), logp in zip(docs, want["logprob"]):
+        got = out["logprob"][r, start:start + len(doc) - 1]
+        np.testing.assert_allclose(got, logp, atol=2e-4)
+        covered[r, start:start + len(doc) - 1] = True
+    # pads and each document's last token score 0
+    assert (out["logprob"][~covered] == 0).all() and covered.sum() > 80
+    assert out["dropped"].sum() == 0 and (out["visits"].sum(axis=1) > 0).all()
+
+
+def test_sampled_logits_are_the_references(params, scored):
+    batch, sample_at, out = scored
+    docs = documents_of(batch)
+    at = [[int(p) - start for p in np.asarray(sample_at)[r]
+           if start <= p < start + len(doc) - 1] for r, start, doc in docs]
+    want = ref.reference_score(CFG, [d for _, _, d in docs], reference_weights(params), at)
+    seen = 0
+    for (r, start, _), places, logits in zip(docs, at, want["logits"]):
+        for p, w in zip(places, logits):
+            s = list(np.asarray(sample_at)[r]).index(p + start)
+            np.testing.assert_allclose(out["logits"][r, s], w, atol=3e-4)
+            seen += 1
+    assert seen >= 6
+
+
+@pytest.mark.parametrize("kind", ["gqa", "kda"])
+def test_a_layer_of_each_kind_against_the_reference(params, kind):
+    cfg = program_cfg()
+    layer = params["layers"][0 if kind == "gqa" else 1]
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((1, L, CFG["hidden_size"])), jnp.float32)
+    segs = jnp.ones((1, L), jnp.int32)
+    got = lm.gqa_mixer(layer, x, segs, cfg) if kind == "gqa" else lm.kda_mixer(layer, x, segs, cfg)[0]
+    with jax.default_matmul_precision("highest"):
+        p = flat(layer)
+        u = ref.ref_norm(x[0], p["attn_norm"], CFG["rms_norm_eps"])
+        want = ref.ref_gqa(p, u, CFG) if kind == "gqa" else ref.ref_kda(p, u, CFG)[0]
+    np.testing.assert_allclose(got[0], want, atol=2e-5)
+
+
+def test_bfloat16_stays_near_the_float32_program(params):
+    batch, _ = packed_rows()
+    sample_at = jnp.zeros((2, 1), jnp.int32)
+    outs = []
+    for dtype in (jnp.float32, jnp.bfloat16):
+        cfg = program_cfg(dtype=dtype)
+        # each leaf in the dtype the program's own table gives it
+        p = jax.tree.map(lambda a, s: a.astype(s[1]), params, lm.pattern_param_shapes(cfg))
+        outs.append(np.asarray(lm.score(p, batch["tokens"], batch["segment_ids"], sample_at,
+                                        cfg)["logprob"]))
+    assert 0 < np.abs(outs[0] - outs[1]).max() < 0.25
+
+
+def test_a_state_carried_across_a_boundary_is_seen(params, scored):
+    """The planted fault: the delta-rule layers start a document from the
+    last document's final state. The program agrees with the sound
+    reference, not with this one."""
+    batch, _, out = scored
+    docs = documents_of(batch)
+    row0 = [d for r, _, d in docs if r == 0]
+    assert len(row0) >= 2
+    sound = ref.reference_score(CFG, row0, reference_weights(params))["logprob"]
+    carried = ref.reference_score(CFG, row0, reference_weights(params), carry_state=True)["logprob"]
+    np.testing.assert_allclose(sound[0], carried[0], atol=1e-6)     # the first has no past
+    assert np.abs(sound[1] - carried[1]).max() > 1e-2
+    start = next(s for r, s, _ in docs[1:] if r == 0)
+    got = out["logprob"][0, start:start + len(row0[1]) - 1]
+    assert np.abs(got - sound[1]).max() < 2e-4 < np.abs(got - carried[1]).max()
+
+
+def test_the_probes_are_what_the_layers_were_given_and_gave(params):
+    """``score``'s probes: one head of the first delta-rule layer's recurrence,
+    walked again token by token from an empty state, document by document,
+    gives what the chunked form gave (a state kept in bfloat16, or carried
+    over from the document before, does not); the router's inputs at the
+    sampled positions give its experts and gates again."""
+    batch, _ = packed_rows()
+    cfg = program_cfg()
+    sample_at = jnp.asarray([[0, 5, 19, 25], [2, 8, 29, 40]], jnp.int32)
+    out = jax.tree.map(np.asarray, lm.score(params, batch["tokens"], batch["segment_ids"],
+                                            sample_at, cfg, jnp.int32(2)))
+    assert "scan" not in lm.score(params, batch["tokens"], batch["segment_ids"], sample_at,
+                                  cfg)["probes"]
+    scan, routed = out["probes"]["scan"], out["probes"]["router"]
+    scale, last, worst = cfg.kda_head_dim ** -0.5, None, 0.0
+    with jax.default_matmul_precision("highest"):
+        for r, start, doc in documents_of(batch):
+            n = len(doc) - 1
+            if n < 1:
+                continue
+            q, k, v, g, b = (jnp.asarray(scan[name][r, start:start + n])[:, None]
+                             for name in ("q", "k", "v", "log_decay", "beta"))
+            want, state = ref.ref_delta_rule(q, k, v, g, b, scale)
+            np.testing.assert_allclose(scan["o"][r, start:start + n], want[:, 0], atol=1e-5)
+            rounded, _ = ref.ref_delta_rule(q, k, v, g, b, scale, state_dtype=jnp.bfloat16)
+            worst = max(worst, float(np.abs(rounded - want).max()))
+            if last is not None and last[0] == r and n > 4:
+                carried, _ = ref.ref_delta_rule(q, k, v, g, b, scale, state0=last[1])
+                assert np.abs(carried - want).max() > 1e-3
+            last = (r, state)
+        assert worst > 1e-4
+        for i, layer in enumerate(params["layers"]):
+            u = jnp.asarray(routed["u"][i].reshape(-1, CFG["hidden_size"]))
+            chosen, gates = ref.ref_route(u, jnp.asarray(layer["router"], jnp.float32), CFG)
+            assert (np.asarray(chosen) == routed["experts"][i].reshape(-1, 4)).all()
+            np.testing.assert_allclose(gates, routed["gates"][i].reshape(-1, 4), atol=1e-6)
+
+
+def test_taps_stop_at_a_boundary():
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.standard_normal((1, 2, 20, 3)), jnp.float32)       # [B, H, L, D]
+    taps = jnp.asarray(rng.standard_normal((4, 2, 3)), jnp.float32)
+    segs = jnp.asarray([[1] * 7 + [2] * 9 + [0] * 4], jnp.int32)
+    flat_x = jnp.moveaxis(x[0], 1, 0).reshape(20, 6)                       # [L, H * D]
+    rows = lambda y: jnp.moveaxis(y[0], 1, 0).reshape(20, 6)  # noqa: E731
+    got = rows(linear_attn.short_conv(x, taps, segs))
+    np.testing.assert_allclose(got[:7], ref.ref_conv(flat_x[:7], taps.reshape(4, 6)), atol=1e-6)
+    np.testing.assert_allclose(got[7:16], ref.ref_conv(flat_x[7:16], taps.reshape(4, 6)), atol=1e-6)
+    # and without the boundary the first tokens of the second document differ
+    whole = rows(linear_attn.short_conv(x, taps, jnp.ones((1, 20), jnp.int32)))
+    assert np.abs(whole[7:10] - got[7:10]).max() > 1e-2
+
+
+def delta_rule_inputs(seed, length, near_parallel_keys=False, b=2, h=3, d=16):
+    r = np.random.default_rng(seed)
+    q, k, v = (r.standard_normal((b, h, length, d)) for _ in range(3))
+    if near_parallel_keys:
+        k = k * 0.1 + r.standard_normal((b, h, 1, d))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    log_decay = -np.exp(r.uniform(-6, 0.3, (b, h, length, d)))
+    beta = 2 / (1 + np.exp(-2 * r.standard_normal((b, h, length))))
+    segs = np.zeros((b, length), np.int32)
+    for row in range(b):
+        cuts = np.sort(r.choice(np.arange(1, length - 8), 4, replace=False))
+        for s, (a, z) in enumerate(zip([0, *cuts], [*cuts, length - 5])):
+            segs[row, a:z] = s + 1
+    return [jnp.asarray(a, jnp.float32) for a in (q, k, v, log_decay, beta)] + [jnp.asarray(segs)]
+
+
+@pytest.mark.parametrize("chunk", [1, 4, 16, 64, 128])
+@pytest.mark.parametrize("length", [150, 64, 37])
+def test_the_chunked_recurrence_is_the_token_by_token_one(chunk, length):
+    args = delta_rule_inputs(chunk + length, length)
+    want = linear_attn.delta_rule_recurrent(*args, scale=0.25)
+    got = linear_attn.delta_rule_chunked(*args, scale=0.25, chunk=chunk)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, atol=5e-6)
+
+
+@pytest.mark.parametrize("one_key", [False, True])
+def test_near_parallel_keys_do_not_blow_the_chunk_up(one_key):
+    """beta near 2 on keys that all but repeat, or do repeat (a document that
+    says one token over and over): the triangle's inverse by forward
+    substitution stays exact where a product of a block's powers cancelled
+    terms of 1e6 against each other (it was held to 1e-3 here)."""
+    q, k, v, log_decay, beta, segs = delta_rule_inputs(1, 150, near_parallel_keys=True)
+    if one_key:
+        k, beta = jnp.broadcast_to(k[:, :, :1], k.shape), jnp.full_like(beta, 1.98)
+    want = linear_attn.delta_rule_recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
+    got = linear_attn.delta_rule_chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=64)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("rate", [3.0, 5.0, 9.0])
+@pytest.mark.parametrize("chunk", [16, 64, 128])
+def test_fast_decays_do_not_overflow_the_chunk(rate, chunk):
+    """Channels that forget at ``rate`` a token, real tokens and the pads of
+    a row's tail alike: exp(+-sum of log-decay) around one reference point
+    for a whole chunk of 64 overflowed float32 at 2.5 a token, and a NaN
+    behind a zero of the triangle's inverse reached the document before."""
+    q, k, v, log_decay, beta, segs = delta_rule_inputs(3, 150)
+    fast = np.random.default_rng(4).random(log_decay.shape) < 0.3
+    log_decay = jnp.where(fast, -rate, log_decay)
+    segs = segs.at[:, 120:].set(0)
+    want = linear_attn.delta_rule_recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
+    got = linear_attn.delta_rule_chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=chunk)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_the_recurrent_oracle_is_the_references_scan(params):
+    """models.linear_attn's oracle and the plain reference walk one recurrence."""
+    layer, cfg = flat(params["layers"][1]), program_cfg()
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((1, 24, CFG["hidden_size"])), jnp.float32)
+    got = lm.kda_mixer(params["layers"][1], x, jnp.ones((1, 24), jnp.int32),
+                       lm.PatternLMConfig(**{**cfg.__dict__, "kda_chunk": 1}))[0]
+    with jax.default_matmul_precision("highest"):
+        u = ref.ref_norm(x[0], layer["attn_norm"], CFG["rms_norm_eps"])
+        want = ref.ref_kda(layer, u, CFG)[0]
+    np.testing.assert_allclose(got[0], want, atol=2e-5)
+
+
+@pytest.mark.parametrize("block", [64, 32, 48, 16, 256])
+def test_blockwise_attention_is_the_dense_oracle(block):
+    r = np.random.default_rng(0)
+    q = jnp.asarray(r.standard_normal((2, 200, 8, 16)), jnp.float32)
+    k, v = (jnp.asarray(r.standard_normal((2, 200, 2, 16)), jnp.float32) for _ in range(2))
+    segs = np.zeros((2, 200), np.int32)
+    segs[0, :90], segs[0, 90:130], segs[0, 130:195], segs[1] = 1, 2, 3, 1
+    want = attention_reference(q, k, v, causal=True, segments=jnp.asarray(segs))
+    got = blockwise_attention(q, k, v, jnp.asarray(segs), block=block)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize("block", [128, 256])
+def test_the_kernel_a_tpu_runs_is_blockwise_attention(block):
+    """``lm._attend`` runs JAX's Pallas flash kernel on a TPU and
+    ``blockwise_attention`` elsewhere: the kernel, interpreted here (called
+    on a chip, outside pytest's conftest, which pins the CPU, this function
+    runs it as it is), against the plain path on a packed row."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    r = np.random.default_rng(1)
+    q = jnp.asarray(r.standard_normal((1, 4, 512, 128)), jnp.float32)      # [B, H, L, D]
+    k, v = (jnp.asarray(r.standard_normal((1, 2, 512, 128)), jnp.float32) for _ in range(2))
+    segs = np.zeros((1, 512), np.int32)
+    segs[0, :100], segs[0, 100:130], segs[0, 130:400] = 1, 2, 3
+    segs = jnp.asarray(segs)
+    want = jnp.swapaxes(blockwise_attention(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segs, block=64), 1, 2)
+    if jax.default_backend() == "tpu":
+        got = lm._attend(q, k, v, segs, block)
+    else:
+        with pltpu.force_tpu_interpret_mode():
+            got = lm._flash_attend(q, k, v, segs, block)
+    real = np.asarray(segs[0] != 0)
+    # on a chip both paths multiply float32 at the default precision (one bfloat16
+    # pass): they agree to that rounding; a mask gone wrong moves the answer by 0.3 and more
+    np.testing.assert_allclose(np.asarray(got)[:, :, real], np.asarray(want)[:, :, real],
+                               atol=3e-2 if jax.default_backend() == "tpu" else 2e-5)
+
+
+def moe_layer(seed=0, t=96, skew=0.0):
+    cfg = {**CFG, "n_routed_experts_held": CFG["n_routed_experts"], "held_offset": 0}
+    p = lm.pattern_init_params(jax.random.PRNGKey(seed), program_cfg(cfg))["layers"][0]
+    p["router"] = p["router"].at[:, 5].add(skew)
+    x = np.random.default_rng(seed).standard_normal((t, CFG["hidden_size"]))
+    # under a skew every token's score for expert 5 saturates: positive rows
+    return cfg, p, jnp.asarray(np.abs(x) if skew else x, jnp.float32)
+
+
+def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
+    """Four chips of 4 experts each, the shared expert counted once, against
+    the reference told that it holds all 16."""
+    cfg, p, x = moe_layer()
+    with jax.default_matmul_precision("highest"):
+        whole, _, _ = ref.ref_moe(flat(p), x, cfg)
+        shared = ref.ref_ffn(x, *(jnp.asarray(p["shared"][k]) for k in ("w_gate", "w_up", "w_down")))
+    total, visits = -3 * shared, 0
+    for first in range(0, 16, 4):
+        share = {**p, **{k: p[k][first:first + 4] for k in ("w_gate", "w_up", "w_down")}}
+        y, n, dropped, _ = moe.held_experts_apply(share, x, held_offset=first, top_k=4, tile=8)
+        total, visits = total + y, visits + int(n.sum())
+        assert int(dropped) == 0
+    assert visits == x.shape[0] * 4
+    np.testing.assert_allclose(total, whole, atol=2e-5)
+
+
+@pytest.mark.parametrize("tile", [8, 32, 256])
+def test_a_skewed_router_costs_time_and_never_a_visit(tile):
+    """Every token picks expert 5: its run is many tiles long and none is lost."""
+    cfg, p, x = moe_layer(seed=1, skew=4.0)
+    share = {**p, **{k: p[k][4:8] for k in ("w_gate", "w_up", "w_down")}}
+    y, n, dropped, _ = jax.jit(lambda p, x: moe.held_experts_apply(
+        p, x, held_offset=4, top_k=4, tile=tile))(share, x)
+    assert int(n[1]) == x.shape[0] and int(dropped) == 0
+    with jax.default_matmul_precision("highest"):
+        want, _, _ = ref.ref_moe(flat(share), x, {**cfg, "n_routed_experts_held": 4, "held_offset": 4})
+    np.testing.assert_allclose(y, want, atol=2e-5)
+    # the reference under a capacity (the control) does lose them, and says so
+    _, lost, _ = ref.ref_moe(flat(share), x, {**cfg, "n_routed_experts_held": 4, "held_offset": 4},
+                             capacity=24)
+    assert int(lost) >= x.shape[0] - 24
+
+
+def test_the_moe_counters_sit_beside_pack_density():
+    from tpu_tfrecord.metrics import METRICS
+
+    before = METRICS.counter("moe.visits_dropped")
+    uneven = lm.record_moe_counters(np.array([[10, 30], [20, 20]]), np.array([0, 0]))
+    assert uneven == 1.5 and METRICS.gauge_value("moe.visits_max_over_mean") == 1.5
+    assert METRICS.counter("moe.visits_dropped") == before
+
+
+@pytest.mark.parametrize("packing", ["best_fit", "first_fit"])
+def test_the_packer_at_8192_gives_every_token_once(packing):
+    rng = np.random.default_rng(11)
+    lengths = np.clip(np.rint(np.exp(rng.normal(6.5, 1.2, 400))), 16, 8192).astype(int)
+    docs = [rng.integers(1, 24576, size=n).astype(np.int32) for n in lengths]
+    packer = TokenPacker(2, 8192, packing=packing)
+    seen = []
+    for at in range(0, len(docs), 16):
+        packer.feed_docs(docs[at:at + 16])
+        while (b := packer.pop()) is not None:
+            seen += [d[:-1] for _, _, d in documents_of(b)]
+            assert (b["tokens"][b["segment_ids"] == 0] == 0).all()
+    packer.flush()
+    while (b := packer.pop()) is not None:
+        seen += [d[:-1] for _, _, d in documents_of(b)]
+    key = lambda d: d.tobytes()  # noqa: E731
+    assert sorted(map(key, seen)) == sorted(map(key, docs))
+    assert 0.5 < packer.density() <= 1.0
+
+
+def test_a_restored_packer_keeps_its_running_fill():
+    a, b = TokenPacker(2, 32, packing="best_fit"), TokenPacker(2, 32, packing="best_fit")
+    docs = [np.arange(1, n) for n in (9, 20, 5, 7, 30, 3)]
+    a.feed_docs(docs[:3])
+    b.restore(a.state())
+    for p in (a, b):
+        p.feed_docs(docs[3:])
+        p.flush()
+    while (x := a.pop()) is not None:
+        y = b.pop()
+        assert (x["tokens"] == y["tokens"]).all() and (x["segment_ids"] == y["segment_ids"]).all()
+    assert b.pop() is None
+
+
+def test_the_benchmarks_copy_of_the_reference_is_this_one():
+    sys.path.insert(0, ROOT)
+    from benchmark.models import solar_open2 as copy
+
+    names = [n for n, f in inspect.getmembers(ref, inspect.isfunction)
+             if f.__module__ == ref.__name__]
+    assert "reference_score" in names and len(names) >= 10
+    for name in names:
+        assert inspect.getsource(getattr(ref, name)) == inspect.getsource(getattr(copy, name)), name

@@ -37,7 +37,9 @@ stripes internally (device i owns strip 2i AND its mirror 2p-1-2i) so every
 device holds the same number of unmasked (q, k) pairs — the standard
 balanced causal ring schedule — at the cost of one O(L*H*D) permute each
 way; callers keep the contiguous contract on both sides.
-`attention_reference` is the plain dense oracle used by the tests.
+`attention_reference` is the plain dense oracle used by the tests;
+`blockwise_attention` is the single-device causal path for packed rows too
+long for the oracle's `[B, H, L, L]` scores (models.lm's pattern model).
 """
 
 from __future__ import annotations
@@ -99,6 +101,54 @@ def attention_reference(
         scores = jnp.where(tri[None, None, :, :], scores, _NEG)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhlm,bmhd->blhd", probs, v.astype(jnp.float32)).astype(q.dtype)
+
+
+def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block: int = 1024):
+    """Causal softmax attention within ``segments``, by key blocks: the
+    same answer as ``attention_reference(causal=True, segments=...)``
+    without ever holding a ``[B, H, L, L]`` array. q [B, L, H, D], k/v
+    [B, L, Hkv, D] (grouped: each K/V head serves H/Hkv query heads,
+    never repeated in memory), segments [B, L] -> [B, L, H, D] in q's dtype.
+
+    For each block of queries the key blocks at or before it (causal: the
+    later ones are skipped when the program is built) are folded into a
+    running maximum, sum and weighted value in float32, flash-style; a
+    pair of ``block`` queries and ``block`` keys costs ``[B, H, block, block]``
+    float32 scores. A key block of other documents only leaves the running
+    sum untouched: every probability is multiplied by its mask, so a row
+    that has seen nothing yet carries zeros, not exp(0)."""
+    b, l, h, d = q.shape
+    hkv = k.shape[2]
+    if h % hkv:
+        raise ValueError(f"GQA needs num_heads % num_kv_heads == 0 (got H={h}, Hkv={hkv})")
+    scale = scale if scale is not None else d ** -0.5
+    qg = (q * jnp.asarray(scale, q.dtype)).reshape(b, l, hkv, h // hkv, d)
+    at = jnp.arange(l)
+    out = []
+    for q0 in range(0, l, block):
+        q1 = min(q0 + block, l)
+        qb, sq = qg[:, q0:q1], segments[:, q0:q1]
+        shape = (b, hkv, h // hkv, q1 - q0)
+        top = jnp.full(shape, _NEG)
+        total = jnp.zeros(shape, jnp.float32)
+        acc = jnp.zeros(shape + (d,), jnp.float32)
+        for k0 in range(0, q1, block):
+            k1 = min(k0 + block, l)
+            scores = jnp.einsum("bqkgd,bmkd->bkgqm", qb, k[:, k0:k1],
+                                preferred_element_type=jnp.float32)
+            mask = (sq[:, :, None] == segments[:, None, k0:k1]) & (
+                at[q0:q1, None] >= at[None, k0:k1])
+            mask = mask[:, None, None]
+            new_top = jnp.maximum(top, jnp.where(mask, scores, _NEG).max(axis=-1))
+            probs = jnp.where(mask, jnp.exp(scores - new_top[..., None]), 0.0)
+            keep = jnp.exp(top - new_top)
+            total = total * keep + probs.sum(axis=-1)
+            acc = acc * keep[..., None] + jnp.einsum(
+                "bkgqm,bmkd->bkgqd", probs.astype(v.dtype), v[:, k0:k1],
+                preferred_element_type=jnp.float32)
+            top = new_top
+        out.append(jnp.moveaxis(acc / total[..., None], 3, 1).reshape(b, q1 - q0, h, d))
+    return jnp.concatenate(out, axis=1).astype(q.dtype)
 
 
 def _ring_attention_local(

@@ -59,16 +59,19 @@ matmuls, one jit per train step, no data-dependent control flow.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpu_tfrecord.models import linear_attn as _la
 from tpu_tfrecord.models import moe as _moe
 from tpu_tfrecord.models import pipeline as _pipeline
-from tpu_tfrecord.models.attention import attention_reference, ring_attention
+from tpu_tfrecord.models.attention import (
+    attention_reference, blockwise_attention, ring_attention,
+)
 from tpu_tfrecord.models.long_doc import _rms_norm
 
 
@@ -756,3 +759,330 @@ def bigram_table(vocab: int, n_next: int, seed: int = 1234) -> np.ndarray:
     tests, the example generator, and the bench probe."""
     rng = np.random.default_rng(seed)
     return rng.integers(0, vocab, size=(vocab, n_next)).astype(np.int32)
+
+
+# ---------------------------------------------------------------------------
+# The pattern model: a layer pattern as data, scored over packed rows
+# ---------------------------------------------------------------------------
+#
+# `LMConfig` stacks ONE kind of block n_layers deep. Hybrid decoders
+# alternate kinds — a softmax layer, then a few linear-attention layers —
+# and put a sparse expert FFN behind each. `PatternLMConfig.layer_pattern`
+# names the mixer of each layer; the parameters are a list of per-layer
+# dicts (layers of different kinds do not stack). The model reads no
+# position table: order comes from the causal mask, the short convolution
+# and the recurrence. Policy: bfloat16 weights and activations; norms,
+# router, softmax, the recurrent state, logits and log-probabilities in
+# float32. The rows are TokenPacker's bin-mode batches as they are:
+# ``tokens`` and ``segment_ids`` [B, L+1]; every document comes out as it
+# would alone in a row (attention, taps and state all stop at a boundary).
+
+MIXERS = ("gqa", "kda")
+
+
+@dataclass(frozen=True)
+class PatternLMConfig:
+    vocab_size: int = 256          # rows of the embedding, columns of the head, held here
+    d_model: int = 64
+    layer_pattern: Tuple[str, ...] = ("gqa", "kda", "kda", "kda")
+    n_heads: int = 4               # softmax layer: query heads
+    n_kv_heads: int = 2
+    head_dim: int = 16
+    kda_heads: int = 4             # delta-rule layer: heads of d_k = d_v = kda_head_dim
+    kda_head_dim: int = 16
+    conv_taps: int = 4
+    gate_rank: int = 8             # rank of the delta-rule layer's decay and output gates
+    n_experts: int = 16            # the router's width: every expert of the layer
+    experts_held: int = 16         # how many of them this chip holds ...
+    held_offset: int = 0           # ... starting at this one
+    top_k: int = 2
+    d_expert: int = 32
+    n_shared: int = 1
+    routed_scale: float = 1.0
+    norm_eps: float = 1e-5
+    max_len: int = 64              # L: a row is L + 1 tokens
+    dtype: Any = jnp.bfloat16
+    # how the program cuts the work (no effect on the result beyond rounding)
+    attn_block: int = 1024         # query and key block of the softmax layer
+    kda_chunk: int = 64            # tokens a step of the chunked delta rule
+    expert_tile: int = 256         # visits a tile of the expert loop
+    head_block: int = 2048         # tokens a block of the head's logits
+
+
+def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
+    """{name: (shape, dtype)} as a pytree shaped like the parameters:
+    matrices in ``cfg.dtype``, vectors and the router in float32."""
+    d, dt, f32 = cfg.d_model, cfg.dtype, jnp.float32
+    hq, hkv, hd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim, cfg.head_dim
+    kd, r = cfg.kda_heads * cfg.kda_head_dim, cfg.gate_rank
+    fs = cfg.d_expert * cfg.n_shared
+    moe = {
+        "moe_norm": ((d,), f32),
+        "router": ((d, cfg.n_experts), f32),
+        "w_gate": ((cfg.experts_held, d, cfg.d_expert), dt),
+        "w_up": ((cfg.experts_held, d, cfg.d_expert), dt),
+        "w_down": ((cfg.experts_held, cfg.d_expert, d), dt),
+        "shared": {"w_gate": ((d, fs), dt), "w_up": ((d, fs), dt), "w_down": ((fs, d), dt)},
+    }
+    mixers = {
+        "gqa": {
+            "attn_norm": ((d,), f32), "wq": ((d, hq), dt), "wk": ((d, hkv), dt),
+            "wv": ((d, hkv), dt), "wg": ((d, hq), dt), "wo": ((hq, d), dt),
+        },
+        "kda": {
+            "attn_norm": ((d,), f32), "wq": ((d, kd), dt), "wk": ((d, kd), dt),
+            "wv": ((d, kd), dt), "conv_q": ((cfg.conv_taps, kd), f32),
+            "conv_k": ((cfg.conv_taps, kd), f32), "conv_v": ((cfg.conv_taps, kd), f32),
+            "f_down": ((d, r), dt), "f_up": ((r, kd), dt), "f_bias": ((kd,), f32),
+            "a_log": ((cfg.kda_heads,), f32), "w_beta": ((d, cfg.kda_heads), dt),
+            "g_down": ((d, r), dt), "g_up": ((r, kd), dt),
+            "o_norm": ((cfg.kda_head_dim,), f32), "wo": ((kd, d), dt),
+        },
+    }
+    for kind in cfg.layer_pattern:
+        if kind not in MIXERS:
+            raise ValueError(f"layer_pattern names {kind!r}; the mixers are {MIXERS}")
+    if cfg.n_shared < 1:
+        moe.pop("shared")
+    return {
+        "embed": ((cfg.vocab_size, d), dt), "head": ((d, cfg.vocab_size), dt),
+        "final_norm": ((d,), f32),
+        "layers": [{**mixers[kind], **moe} for kind in cfg.layer_pattern],
+    }
+
+
+def _is_shape(x) -> bool:
+    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
+
+
+def pattern_init_params(rng: jax.Array, cfg: PatternLMConfig) -> Dict[str, Any]:
+    """Random parameters for tests and examples: matrices normal(0, 1/fan_in),
+    norms 1, decays of 0.001 to 0.1 a token (``a_log`` 0, ``f_bias`` the
+    inverse softplus of a log-uniform rate), taps that favour the current token."""
+    shapes = pattern_param_shapes(cfg)
+    leaves, tree = jax.tree.flatten_with_path(shapes, is_leaf=_is_shape)
+    out = []
+    for i, (path, (shape, dtype)) in enumerate(leaves):
+        name, key = path[-1].key, jax.random.fold_in(rng, i)
+        if name.endswith("norm"):
+            value = jnp.ones(shape)
+        elif name == "a_log":
+            value = jnp.zeros(shape)
+        elif name == "f_bias":
+            rate = jnp.exp(jax.random.uniform(key, shape, minval=np.log(1e-3), maxval=np.log(1e-1)))
+            value = jnp.log(jnp.expm1(rate))
+        elif name.startswith("conv_"):
+            value = jax.random.normal(key, shape) * 0.2 + (jnp.arange(shape[0]) == 0)[:, None]
+        elif name == "embed":
+            value = jax.random.normal(key, shape)
+        else:
+            value = jax.random.normal(key, shape) * shape[-2] ** -0.5
+        out.append(value.astype(dtype))
+    return jax.tree.unflatten(tree, out)
+
+
+def weighted_rms_norm(x, weight, eps: float):
+    """``x / rms(x) * weight`` over the last axis, in float32, rounded to x's dtype."""
+    x32 = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return (x32 * scale * weight).astype(x.dtype)
+
+
+def _l2_norm(x, eps: float = 1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def _flash_attend(q, k, v, segments, block: int):
+    """JAX's Pallas TPU flash-attention kernel, causal inside ``segments``:
+    a block pair's scores stay on the chip (22 against 96 ms on a v5e for
+    2 x 8,192 tokens and 64 heads, where ``blockwise_attention`` writes
+    them to memory). Shapes as :func:`_attend`."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    rep = q.shape[1] // k.shape[1]
+    block = min(block, q.shape[2])
+    return fa.flash_attention(
+        q, jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1),
+        segment_ids=fa.SegmentIds(q=segments, kv=segments), causal=True,
+        sm_scale=q.shape[-1] ** -0.5,
+        block_sizes=fa.BlockSizes(block_q=block, block_k_major=block,
+                                  block_k=min(512, block), block_b=1))
+
+
+def _attend(q, k, v, segments, block: int):
+    """Causal attention inside each document; q [B, H, L, D], k/v [B, Hkv, L, D]
+    -> [B, H, L, D]. On a TPU the Pallas kernel (it exists for no other
+    backend, and asks for heads of 128 and rows of whole blocks of 128s);
+    elsewhere, and for shapes the kernel does not take,
+    ``attention.blockwise_attention``: plain JAX, the same mask, the same
+    answer (tests/test_pattern_lm.py holds the two to each other)."""
+    (l, d), tile = q.shape[2:], min(block, q.shape[2])
+    if jax.default_backend() == "tpu" and d % 128 == 0 and tile % 128 == 0 and l % tile == 0:
+        return _flash_attend(q, k, v, segments, block)
+    out = blockwise_attention(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segments,
+        block=block)
+    return jnp.swapaxes(out, 1, 2)
+
+
+def gqa_mixer(p, x, segments, cfg: PatternLMConfig):
+    """The softmax layer, without positions: grouped causal attention inside
+    each document, an elementwise sigmoid gate on its output. x [B, L, D].
+    Heads are written head-major ``[B, H, L, D]`` by the projections, which is
+    how the attention reads them: nothing is transposed."""
+    d, h, hkv, dh = x.shape[-1], cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    with jax.named_scope("tfr.gqa"):
+        u = weighted_rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        q = jnp.einsum("bld,dhk->bhlk", u, p["wq"].reshape(d, h, dh))
+        k = jnp.einsum("bld,dhk->bhlk", u, p["wk"].reshape(d, hkv, dh))
+        v = jnp.einsum("bld,dhk->bhlk", u, p["wv"].reshape(d, hkv, dh))
+        att = _attend(q, k, v, segments, cfg.attn_block)
+        gate = jax.nn.sigmoid(
+            jnp.einsum("bld,dhk->bhlk", u, p["wg"].reshape(d, h, dh)).astype(jnp.float32))
+        gated = (att.astype(jnp.float32) * gate).astype(x.dtype)
+        return jnp.einsum("bhlk,hkd->bld", gated, p["wo"].reshape(h, dh, d))
+
+
+def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
+    """The gated delta-rule layer (models.linear_attn has the equations):
+    projections, a 4-tap convolution and SiLU on q, k, v, unit-norm q and k,
+    a per-channel decay and a per-head beta in (0, 2), the chunked
+    recurrence, a per-head RMSNorm and a low-rank sigmoid gate. Everything
+    per head is head-major ``[B, H, L, D]`` from projection to projection.
+
+    Returns (y, probe). ``probe`` is None unless ``probe_head`` (an int32
+    scalar) names a head: then what the chunked recurrence was given and what
+    it gave for that head, ``q``, ``k``, ``v``, ``log_decay``, ``o``
+    [B, L, D] and ``beta`` [B, L], all float32, so that a caller can walk the same
+    inputs token by token and see what the state's precision cost."""
+    d, h, dh, f32 = x.shape[-1], cfg.kda_heads, cfg.kda_head_dim, jnp.float32
+
+    def heads(a, w):  # a [B, L, m] through w [m, H * dh] -> [B, H, L, dh]
+        return jnp.einsum("blm,mhk->bhlk", a, w.reshape(w.shape[0], h, dh))
+
+    with jax.named_scope("tfr.kda_proj"):
+        u = weighted_rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        q, k, v = heads(u, p["wq"]), heads(u, p["wk"]), heads(u, p["wv"])
+        rate = jax.nn.softplus(
+            heads(u @ p["f_down"], p["f_up"]).astype(f32) + p["f_bias"].reshape(h, 1, dh))
+        log_decay = -jnp.exp(p["a_log"])[:, None, None] * rate
+        beta = 2.0 * jax.nn.sigmoid(jnp.einsum("bld,dh->bhl", u, p["w_beta"]).astype(f32))
+        gate = jax.nn.sigmoid(heads(u @ p["g_down"], p["g_up"]).astype(f32))
+    with jax.named_scope("tfr.kda_conv"):
+        def conv_silu(a, taps):
+            taps = taps.reshape(taps.shape[0], h, dh)
+            return jax.nn.silu(_la.short_conv(a, taps, segments).astype(f32))
+
+        q = _l2_norm(conv_silu(q, p["conv_q"])).astype(x.dtype)
+        k = _l2_norm(conv_silu(k, p["conv_k"])).astype(x.dtype)
+        v = conv_silu(v, p["conv_v"]).astype(x.dtype)
+    with jax.named_scope("tfr.kda_scan"):
+        # float32 here, not inside: the probe has to hold the very values the
+        # recurrence consumes, and XLA drops a bfloat16 round trip where it can
+        # (the recurrence would see the unrounded q, the probe the rounded one)
+        q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+        o = _la.delta_rule_chunked(q, k, v, log_decay, beta, segments,
+                                   scale=dh ** -0.5, chunk=cfg.kda_chunk)
+    probe = None
+    if probe_head is not None:
+        probe = {name: jnp.take(a, probe_head, axis=1) for name, a in dict(
+            q=q, k=k, v=v, log_decay=log_decay, beta=beta, o=o).items()}
+    with jax.named_scope("tfr.kda_proj"):
+        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
+        o = (o * p["o_norm"] * gate).astype(x.dtype)
+        return jnp.einsum("bhlk,hkd->bld", o, p["wo"].reshape(h, dh, d)), probe
+
+
+def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=None,
+                   probe_head=None):
+    """tokens, segment_ids [B, L+1] -> (x [B, L, D] before the final norm,
+    visits [n_layers, experts_held], dropped [n_layers], probes).
+
+    ``probes`` is what a caller checks single layers by, on the layer's own
+    inputs: ``router`` (with ``sample_at`` [B, S]: at those positions of
+    every layer the router's input ``u`` [n_layers, B, S, D] and its
+    ``experts`` and ``gates`` [n_layers, B, S, top_k]) and ``scan`` (with
+    ``probe_head``: :func:`kda_mixer`'s probe of the first delta-rule layer)."""
+    l = tokens.shape[1] - 1
+    if l != cfg.max_len:
+        raise ValueError(
+            f"packed batch carries {l} input tokens but cfg.max_len is {cfg.max_len} "
+            f"(the packer's seq_len must match)")
+    segments = segment_ids[:, :-1]
+    with jax.named_scope("tfr.embed"):
+        x = params["embed"][tokens[:, :-1]]
+    b, _, d = x.shape
+    visits, dropped, routed, probes = [], [], [], {}
+    for kind, layer in zip(cfg.layer_pattern, params["layers"]):
+        if kind == "gqa":
+            x = x + gqa_mixer(layer, x, segments, cfg)
+        else:
+            y, scan = kda_mixer(layer, x, segments, cfg,
+                                None if "scan" in probes else probe_head)
+            x = x + y
+            if scan is not None:
+                probes["scan"] = scan
+        with jax.named_scope("tfr.moe_route"):
+            u = weighted_rms_norm(x, layer["moe_norm"], cfg.norm_eps)
+        y, n, lost, (experts, gates) = _moe.held_experts_apply(
+            layer, u.reshape(b * l, d), held_offset=cfg.held_offset, top_k=cfg.top_k,
+            routed_scale=cfg.routed_scale, tile=cfg.expert_tile,
+            valid=(segments != 0).reshape(b * l))
+        x = x + y.reshape(b, l, d)
+        visits.append(n)
+        dropped.append(lost)
+        if sample_at is not None:
+            at = sample_at[:, :, None]
+            routed.append({"u": jnp.take_along_axis(u, at, axis=1),
+                           "experts": jnp.take_along_axis(experts.reshape(b, l, -1), at, axis=1),
+                           "gates": jnp.take_along_axis(gates.reshape(b, l, -1), at, axis=1)})
+    if routed:
+        probes["router"] = {k: jnp.stack([r[k] for r in routed]) for k in routed[0]}
+    return x, jnp.stack(visits), jnp.stack(dropped), probes
+
+
+def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_head=None):
+    """The scoring step over one packed batch. Returns a dict:
+
+    ``logprob`` [B, L] float32: log p(tokens[:, t+1] | its document up to t)
+        over the vocabulary held here; 0 where t+1 is a pad or another document
+    ``logits``  [B, S, V] float32 at the positions ``sample_at`` [B, S]
+    ``visits``  [n_layers, experts_held] int32, ``dropped`` [n_layers] int32
+    ``probes``  :func:`pattern_hidden`'s: the router's inputs and choices at
+        ``sample_at``, and with ``probe_head`` that head's recurrence
+
+    The head's logits exist a block of ``cfg.head_block`` tokens at a time."""
+    x, visits, dropped, probes = pattern_hidden(params, tokens, segment_ids, cfg, sample_at,
+                                                probe_head)
+    b, l, d = x.shape
+    with jax.named_scope("tfr.lm_head"):
+        xn = weighted_rms_norm(x, params["final_norm"], cfg.norm_eps)
+        flat, targets = xn.reshape(b * l, d), tokens[:, 1:].reshape(b * l)
+        out = []
+        for t0 in range(0, b * l, cfg.head_block):
+            logits = jnp.dot(flat[t0:t0 + cfg.head_block], params["head"],
+                             preferred_element_type=jnp.float32)
+            picked = jnp.take_along_axis(
+                logits, targets[t0:t0 + cfg.head_block, None], axis=-1)[:, 0]
+            out.append(picked - jax.nn.logsumexp(logits, axis=-1))
+        scored = (segment_ids[:, 1:] == segment_ids[:, :-1]) & (segment_ids[:, :-1] != 0)
+        logprob = jnp.where(scored, jnp.concatenate(out).reshape(b, l), 0.0)
+        sampled = jnp.take_along_axis(xn, sample_at[:, :, None], axis=1)
+        sample_logits = jnp.einsum("bsd,dv->bsv", sampled, params["head"],
+                                   preferred_element_type=jnp.float32)
+    return {"logprob": logprob, "logits": sample_logits, "visits": visits, "dropped": dropped,
+            "probes": probes}
+
+
+def record_moe_counters(visits, dropped) -> float:
+    """A step's expert counters into ``metrics.METRICS``, beside the packer's
+    ``pack.density``: the gauge ``moe.visits_max_over_mean`` (the busiest
+    held expert of the step's most uneven layer against that layer's mean)
+    and the counter ``moe.visits_dropped`` (stays 0). Returns the gauge."""
+    from tpu_tfrecord.metrics import METRICS
+
+    visits = np.asarray(visits, np.float64)
+    uneven = float((visits.max(axis=1) / np.maximum(visits.mean(axis=1), 1e-30)).max())
+    METRICS.gauge("moe.visits_max_over_mean", round(uneven, 4))
+    METRICS.count("moe.visits_dropped", int(np.asarray(dropped).sum()))
+    return uneven

@@ -46,6 +46,14 @@ formulation, arXiv:2101.03961 §2.2, top-k per GShard arXiv:2006.16668):
 
 `moe_reference` is the per-token oracle used by the tests;
 `param_shardings` places the expert tensors on the EP axis.
+
+`held_experts_apply` is the other construction, for a chip that holds a
+SHARE of a layer's experts (``experts_held`` of ``n_experts``, from
+``held_offset``): it routes over all of them, computes the part of the
+result that its own experts give, and drops nothing. No capacity and no
+``[T, E, C]`` one-hots: the visits to held experts are sorted by expert
+and walked a tile at a time by a loop whose trip count is the batch's
+own, so a skewed router costs time, never a token.
 """
 
 from __future__ import annotations
@@ -531,3 +539,101 @@ def moe_reference(
         "gate_entropy": float(ent[vmask].sum() / n_valid),
     }
     return out, diag
+
+
+# ---------------------------------------------------------------------------
+# A share of the experts, dropless
+# ---------------------------------------------------------------------------
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def gated_ffn(x, w_gate, w_up, w_down):
+    """``w_down(silu(w_gate x) * w_up x)``: products accumulate in float32,
+    the hidden activation is rounded to x's dtype; returns float32."""
+    f32 = jnp.float32
+    hidden = jax.nn.silu(jnp.dot(x, w_gate, preferred_element_type=f32)) * jnp.dot(
+        x, w_up, preferred_element_type=f32)
+    return jnp.dot(hidden.astype(x.dtype), w_down, preferred_element_type=f32)
+
+
+def route_top_k(x, router, top_k: int, routed_scale: float = 1.0):
+    """Sigmoid scores over ALL experts in float32, the ``top_k`` largest,
+    their gates renormalised to sum to ``routed_scale``.
+    x [T, D], router [D, E] -> (experts [T, k] int32, gates [T, k] float32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST))
+    top, experts = jax.lax.top_k(scores, top_k)
+    return experts, top / top.sum(axis=-1, keepdims=True) * routed_scale
+
+
+def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: int,
+                       routed_scale: float = 1.0, tile: int = 256, valid=None):
+    """``shared(x) + sum of gate_e * expert_e(x)`` over the chosen experts
+    THIS chip holds; what the absent experts would add is left out.
+
+    params: ``router`` [D, E] (all E experts), ``w_gate`` / ``w_up``
+    [Eh, D, F], ``w_down`` [Eh, F, D] (the Eh experts held, numbers
+    ``held_offset`` .. ``held_offset + Eh``), optional ``shared`` with the
+    same three names un-stacked. x [T, D]; ``valid`` [T] bool: tokens that
+    are pads visit no expert (their rows get the shared expert only).
+    Returns (y [T, D] in x's dtype, visits [Eh] int32 to each held expert,
+    dropped: visits to held experts that were not computed, always 0,
+    (experts [T, top_k] int32, gates [T, top_k] float32): the routing).
+
+    The T * top_k visits are sorted by held expert (absent ones last) and
+    each expert's run is cut into tiles of ``tile`` visits. A loop over the
+    tiles there ARE gathers a tile's tokens, runs that expert's FFN and lays
+    the result down where the tile lies in the sorted order (a contiguous
+    write; a buffer for the worst case, every visit to a held expert, is
+    T * top_k rows of x's dtype). Each token then reads its own ``top_k``
+    rows back and sums them under its gates in float32: a gather, where a
+    scatter-add of the same rows costs three times as much a row on a TPU."""
+    t, d = x.shape
+    n_held = params["w_gate"].shape[0]
+    f32 = jnp.float32
+    with jax.named_scope("tfr.moe_route"):
+        experts, gates = route_top_k(x, params["router"], top_k, routed_scale)
+        local = experts - held_offset
+        held = (local >= 0) & (local < n_held)
+        if valid is not None:
+            held = held & valid[:, None]
+        key = jnp.where(held, local, n_held).reshape(-1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        visits = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
+        first = jnp.cumsum(visits) - visits                 # an expert's first sorted visit
+        n_tiles = (visits + tile - 1) // tile
+        tiles_to = jnp.cumsum(n_tiles)                      # tiles up to and with an expert
+        # where a visit's result will lie: its expert's first tile, then its place in the run
+        rank = jnp.zeros((t * top_k,), jnp.int32).at[order].set(
+            jnp.arange(t * top_k, dtype=jnp.int32))
+        safe = jnp.minimum(key, n_held - 1)
+        lies_at = (tiles_to[safe] - n_tiles[safe]) * tile + rank - first[safe]
+        max_tiles = -(-t * top_k // tile) + n_held
+        lies_at = jnp.where(held.reshape(-1), lies_at, max_tiles * tile).reshape(t, top_k)
+    with jax.named_scope("tfr.moe_experts"):
+        lane = jnp.arange(tile, dtype=jnp.int32)
+
+        def one_tile(j, carry):
+            laid, done = carry
+            e = jnp.searchsorted(tiles_to, j, side="right").astype(jnp.int32)
+            nth = j - (tiles_to[e] - n_tiles[e])
+            rows = first[e] + nth * tile + lane
+            real = rows < first[e] + visits[e]
+            token = order[jnp.minimum(rows, t * top_k - 1)] // top_k
+            y = gated_ffn(x[token], params["w_gate"][e], params["w_up"][e], params["w_down"][e])
+            laid = jax.lax.dynamic_update_slice(laid, y.astype(x.dtype), (j * tile, 0))
+            return laid, done + real.sum(dtype=jnp.int32)
+
+        # one spare row past the worst case stays zero: what a token reads for an absent expert
+        laid = jnp.zeros((max_tiles * tile + 1, d), x.dtype)
+        laid, done = jax.lax.fori_loop(0, tiles_to[-1], one_tile, (laid, jnp.int32(0)))
+        out = jnp.zeros((t, d), f32)
+        for slot in range(top_k):
+            out = out + jnp.where(held[:, slot], gates[:, slot], 0.0)[:, None] * laid[
+                lies_at[:, slot]].astype(f32)
+    if "shared" in params:
+        with jax.named_scope("tfr.moe_shared"):
+            sh = params["shared"]
+            out = out + gated_ffn(x, sh["w_gate"], sh["w_up"], sh["w_down"])
+    return out.astype(x.dtype), visits, visits.sum() - done, (experts, gates)

@@ -444,6 +444,7 @@ class TokenPacker:
         self._buf_len = 0
         # bin modes: open row-bins, each a list of document chunks
         self._bins: List[List[np.ndarray]] = []
+        self._fill: List[int] = []         # tokens placed in each open bin
         self._pending: List[Any] = []  # ready [B, L+1] batches / dicts
         # density accounting (bin modes; slice mode is 1.0 by construction)
         self._emitted_tokens = 0
@@ -465,35 +466,50 @@ class TokenPacker:
     def _feed_docs_bins(self, docs: Iterable[np.ndarray]) -> None:
         cap = self.seq_len + 1
         eos = np.asarray([self.eos_id], np.int32)
-        for doc in docs:
-            arr = np.asarray(doc).astype(np.int32, copy=False).reshape(-1)
-            arr = np.concatenate([arr, eos])
-            # long documents pre-split into cap-sized chunks; each chunk
-            # is its own attention segment (they cannot share a row and
-            # attend to each other anyway)
-            for at in range(0, arr.size, cap):
-                self._place_chunk(arr[at : at + cap])
+        n_docs, ready = 0, len(self._pending)
+        # tracing.ANNOTATIONS: one span a call (a reader batch), never a document
+        with trace("tfr:pack_tokens") as tr:
+            for doc in docs:
+                arr = np.asarray(doc).astype(np.int32, copy=False).reshape(-1)
+                arr = np.concatenate([arr, eos])
+                # long documents pre-split into cap-sized chunks; each chunk
+                # is its own attention segment (they cannot share a row and
+                # attend to each other anyway)
+                for at in range(0, arr.size, cap):
+                    self._place_chunk(arr[at : at + cap])
+                n_docs += 1
+            rows = (len(self._pending) - ready) * self.batch_size
+            tr.set_metadata(docs=n_docs, rows=rows, tokens=rows * cap)
 
     def _place_chunk(self, chunk: np.ndarray) -> None:
         cap = self.seq_len + 1
         fit = -1
         if self.packing == "best_fit":
             best_room = cap + 1
-            for i, b in enumerate(self._bins):
-                room = cap - sum(c.size for c in b)
+            for i, used in enumerate(self._fill):
+                room = cap - used
                 if chunk.size <= room < best_room:
                     fit, best_room = i, room
         else:  # first_fit — the greedy binning baseline
-            for i, b in enumerate(self._bins):
-                if chunk.size <= cap - sum(c.size for c in b):
+            for i, used in enumerate(self._fill):
+                if chunk.size <= cap - used:
                     fit = i
                     break
         if fit >= 0:
             self._bins[fit].append(chunk)
+            self._fill[fit] += chunk.size
             return
         if len(self._bins) == self.batch_size:
             self._close_bins()
         self._bins.append([chunk])
+        self._fill.append(chunk.size)
+
+    def flush(self) -> None:
+        """End of a finite stream (bin modes): close the open bins into one
+        last batch, rows that no bin reached all pad. ``slice`` mode keeps
+        its residual: a partial window is no batch."""
+        if self._bins:
+            self._close_bins()
 
     def _close_bins(self) -> None:
         """Flush the B open bins into one pending {tokens, segment_ids}
@@ -509,7 +525,7 @@ class TokenPacker:
                 segs[r, at : at + chunk.size] = s + 1
                 at += chunk.size
             nonpad += at
-        self._bins = []
+        self._bins, self._fill = [], []
         self._pending.append({"tokens": toks, "segment_ids": segs})
         self._emitted_tokens += self.batch_size * cap
         self._emitted_nonpad += nonpad
@@ -597,6 +613,7 @@ class TokenPacker:
             [np.asarray(c, np.int32) for c in b]
             for b in state.get("bins", [])
         ]
+        self._fill = [sum(c.size for c in b) for b in self._bins]
         self._pending = [
             {
                 "tokens": np.asarray(d["tokens"], np.int32),

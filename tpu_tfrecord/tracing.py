@@ -13,7 +13,8 @@ explain itself:
 - ``put_or_wait`` / ``get_or_wait``: a bounded queue's hand-off, with the
   time a thread sat blocked on it under one span.
 - ``ANNOTATIONS``: every ``tfr:*`` host span and ``tfr.*`` device scope
-  (``jax.named_scope`` in ``models/dlrm.py``) the package emits — the one
+  (``jax.named_scope`` in ``models/dlrm.py``, ``models/lm.py``,
+  ``models/moe.py``) the package emits — the one
   place the names live; ``benchmark/layer_metrics`` and PERF.md quote them.
 - ``DutyCycle``: estimates the BASELINE.md north-star secondary metric — the
   fraction of wall time the device spends computing vs waiting on input —
@@ -29,7 +30,8 @@ from typing import Optional
 
 #: name -> what it covers. ``tfr:`` names are host spans (one per batch or
 #: rarer, never per record); ``tfr.`` names are scopes inside the jitted
-#: DLRM programs, found in the ``op_name`` of the device's operations.
+#: programs (DLRM's, the pattern LM's), found in the ``op_name`` of the
+#: device's operations.
 #: ``<queue>`` is ``batch`` (the dataset's prefetch queue), ``host``
 #: (HostPrefetcher's) or ``device`` (DeviceIterator's transfer thread's).
 ANNOTATIONS = {
@@ -37,6 +39,8 @@ ANNOTATIONS = {
     "tfr:cache": "serving a chunk from the columnar cache",
     "tfr:decode": "frame scan + CRC + decode + hash of one chunk; rows, bytes",
     "tfr:pack": "host_batch_from_columnar or pack_mixed on one batch; rows, bytes out",
+    "tfr:pack_tokens": "TokenPacker (bin modes) placing one reader batch's documents; docs in, "
+                       "rows and tokens out",
     "tfr:h2d": "make_global_batch: the dispatch of one batch's copy; rows, bytes",
     "tfr:h2d_land": "the transfer thread's wait for that copy to land",
     "tfr:blocked.batch": "the decode thread's put waited on a full prefetch queue",
@@ -49,8 +53,8 @@ ANNOTATIONS = {
     "tfr.write.compress": "writer: host span around compress",
     "tfr.write.io": "writer: host span around the file write",
     "tfr.write.commit": "writer: host span around the commit",
-    "tfr.table_cast": "no program opens it since PR 25 (forward's table-sized cast is gone); "
-                      "the benchmark's recorded trace and step_ms.table_cast name it",
+    "tfr.table_cast": "no program opens it since PR 25; kept because the benchmark's recorded "
+                      "trace (benchmark/tests/test_trace_scope.py) holds its scopes to this table",
     "tfr.gather": "the embedding rows gathered from the table, rounded to the activation dtype",
     "tfr.bottom_mlp": "bottom MLP (backward ops carry it inside transpose(jvp(..)))",
     "tfr.interaction": "feature interaction",
@@ -60,6 +64,15 @@ ANNOTATIONS = {
     "tfr.segment_sum": "sparse_train_step: duplicate keys' gradients and counts summed",
     "tfr.accum_update": "sparse_train_step: AdaGrad accumulator scatter, gather, rsqrt",
     "tfr.table_scatter": "sparse_train_step: the row updates scattered into the table",
+    "tfr.embed": "pattern LM (models.lm.score): the token rows gathered from the embedding",
+    "tfr.gqa": "pattern LM: the softmax layer (norm, projections, blockwise attention, gate, out)",
+    "tfr.kda_proj": "pattern LM: the delta-rule layer's projections, decay, beta, gates, norm, out",
+    "tfr.kda_conv": "pattern LM: the short convolution, SiLU and unit norm of q, k, v",
+    "tfr.kda_scan": "pattern LM: the chunked gated delta rule (models.linear_attn)",
+    "tfr.moe_route": "held experts: pre-norm, scores over all experts, top-k, visits sorted by expert",
+    "tfr.moe_experts": "held experts: the loop over the tiles of visits to the experts held here",
+    "tfr.moe_shared": "held experts: the shared expert, every token",
+    "tfr.lm_head": "pattern LM: final norm, the head's logits by blocks, log-probabilities",
 }
 
 

@@ -63,6 +63,7 @@ COUNTERS: Dict[str, str] = {
     "read.retries": "transient read errors retried (incl. remote resume)",
     "read.skipped_shards": "shards dropped by on_corrupt/on_stall=skip_shard",
     "read.stalls": "reads converted to StallError by the deadline",
+    "moe.visits_dropped": "visits to held experts that were not computed (stays 0)",
     "read.deadline_misses": "per-read deadlines that fired",
     "read.hedges": "straggler hedge opens issued",
     "read.hedge_wins": "hedge backup finished before the primary",
@@ -194,6 +195,7 @@ GAUGES: Dict[str, str] = {
     "pack.density": "fraction of emitted packed tokens that are real (bin modes)",
     "lm.fsdp_param_bytes": "per-device at-rest param bytes under the fsdp layout",
     "moe.dropped_fraction": "latest per-step dropped-token fraction",
+    "moe.visits_max_over_mean": "held experts, latest step: the busiest over the mean (most uneven layer)",
     "moe.gate_entropy": "latest per-step router gate entropy",
     "moe.expert_imbalance": "latest per-step expert imbalance",
     "pipeline.bubble_fraction": "latest per-step pipeline bubble fraction",
