@@ -277,22 +277,37 @@ class TestSparseTrainStep:
         """ADVICE close-out: for F*V > 2^31, flat int32 dedup keys would
         silently wrap (int64 is unavailable with x64 disabled), so the
         step switches to a lexicographic (f, v) pair sort. Both paths are
-        stable sorts over the same total order, so the permutation — and
-        therefore every update — is identical; pinned at test scale by
-        shrinking the switch-over threshold."""
+        stable sorts over the same total order, so the permutation, each
+        element's slot, the slots' keys — and therefore every update — are
+        identical; pinned at test scale by shrinking the switch-over
+        threshold."""
         from tpu_tfrecord.models import sparse_opt_init, sparse_train_step
         from tpu_tfrecord.models import dlrm as dlrm_mod
 
         # the sort seam itself, on skewed duplicate-heavy indices
         rng = np.random.default_rng(31)
-        f_flat = jax.numpy.asarray(
-            np.repeat(np.arange(3), 32).astype(np.int32)
-        )
-        v_flat = jax.numpy.asarray(rng.integers(0, 6, 96).astype(np.int32))
-        flat = dlrm_mod._dedup_sort(f_flat, v_flat, 6, force_pairs=False)
-        pairs = dlrm_mod._dedup_sort(f_flat, v_flat, 6, force_pairs=True)
-        for got, want in zip(pairs, flat):
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        f_np = np.repeat(np.arange(3), 32).astype(np.int32)
+        v_np = rng.integers(0, 6, 96).astype(np.int32)
+        f_flat, v_flat = jax.numpy.asarray(f_np), jax.numpy.asarray(v_np)
+        flat = [np.asarray(a) for a in
+                dlrm_mod._dedup_sort(f_flat, v_flat, 6, force_pairs=False)]
+        pairs = [np.asarray(a) for a in
+                 dlrm_mod._dedup_sort(f_flat, v_flat, 6, force_pairs=True)]
+        want_order = np.lexsort((v_np, f_np))        # stable, as both sorts are
+        # the runs, columns in (f, v) order, and each sorted element's run
+        runs, run_of = np.unique(
+            np.stack([f_np, v_np])[:, want_order], axis=1, return_inverse=True)
+        empty = 96 - runs.shape[1]
+        for order, slot, uf, uv in (flat, pairs):
+            np.testing.assert_array_equal(order, want_order)
+            # the runs take the last slots in order; every slot before them
+            # indexes no table, even after NumPy's wrap-around
+            np.testing.assert_array_equal(slot, empty + run_of.reshape(-1))
+            np.testing.assert_array_equal(uf[empty:], runs[0])
+            np.testing.assert_array_equal(uv[empty:], runs[1])
+            assert (uf[:empty] + 3 < 0).all()
+        for got, want in zip(pairs[:2], flat[:2]):
+            np.testing.assert_array_equal(got, want)
 
         # and the full step end-to-end with the pair path forced
         cfg = self.CFG
@@ -313,6 +328,145 @@ class TestSparseTrainStep:
         np.testing.assert_array_equal(
             np.asarray(got_p["embeddings"]), np.asarray(want_p["embeddings"])
         )
+
+    @staticmethod
+    def _keys_case(case: str, cfg, batch_size: int) -> np.ndarray:
+        """[B, F] keys for the ends of the run compaction and its edges."""
+        n_f, n_v = cfg.num_categorical, cfg.vocab_size
+        rng = np.random.default_rng(41)
+        if case == "every_key_distinct":            # N runs: no empty slot
+            return np.stack(
+                [rng.choice(n_v, size=batch_size, replace=False) for _ in range(n_f)], axis=1)
+        if case == "one_key_a_column":              # F runs: all but F slots empty
+            return np.broadcast_to(rng.integers(0, n_v, size=n_f), (batch_size, n_f)).copy()
+        if case == "extreme_keys":      # row 0 of feature 0, row V-1 of feature F-1: real keys
+            cat = rng.integers(1, 6, size=(batch_size, n_f))
+            cat[::3, n_f - 1] = n_v - 1
+            cat[1::4, 0] = 0
+            cat[1, 0] = n_v - 1
+            return cat
+        assert case == "heavy_duplication"
+        return rng.integers(0, 3, size=(batch_size, n_f))
+
+    @pytest.mark.parametrize("sort_path", ["flat_keys", "pair_sort"])
+    @pytest.mark.parametrize(
+        "case", ["every_key_distinct", "one_key_a_column", "extreme_keys", "heavy_duplication"])
+    def test_runs_compacted_match_dense_oracle(self, case, sort_path, monkeypatch):
+        """What exists once per unique row is kept once per run, at the
+        run's slot, and the empty slots carry keys that index no table. The
+        two ends (no empty slot; all but F empty), the least and the largest
+        real key (neither may be taken for an empty slot's), and heavy
+        duplication, against the dense oracle; rows and accumulators no key
+        names equal the old ones bit for bit."""
+        from tpu_tfrecord.models import sparse_opt_init, sparse_train_step
+        from tpu_tfrecord.models import dlrm as dlrm_mod
+
+        if sort_path == "pair_sort":
+            monkeypatch.setattr(dlrm_mod, "_FLAT_KEY_MAX", 1)
+        cfg = self.CFG
+        params = init_params(jax.random.key(14), cfg)
+        host = make_synthetic_batch(cfg, 48, seed=43)
+        host["cat"] = self._keys_case(case, cfg, 48)
+        batch = {k: jax.numpy.asarray(v) for k, v in host.items()}
+        tx = optax.sgd(1e-2)
+        opt0 = sparse_opt_init(params, cfg, tx)
+        # a state that has been trained on: an untouched accumulator is not zero
+        opt0 = opt0._replace(accum=jax.random.uniform(
+            jax.random.key(15), opt0.accum.shape, jax.numpy.float32, 0.0, 1e-3))
+        got_p, got_s, got_l = jax.jit(
+            functools.partial(sparse_train_step, cfg=cfg, tx=tx)
+        )(params, opt0, batch)
+        want_p, want_s, want_l = self._dense_rowwise_adagrad_reference(
+            params, opt0, batch, cfg, tx
+        )
+        assert float(got_l) == pytest.approx(float(want_l), rel=1e-6)
+        np.testing.assert_allclose(got_s.accum, want_s.accum, rtol=2e-5, atol=1e-9)
+        np.testing.assert_allclose(
+            got_p["embeddings"], want_p["embeddings"], rtol=2e-5, atol=1e-7
+        )
+        touched = np.zeros((cfg.num_categorical, cfg.vocab_size), bool)
+        touched[np.arange(cfg.num_categorical)[None, :], host["cat"]] = True
+        if case == "every_key_distinct":
+            assert touched.sum() == 48 * cfg.num_categorical
+        if case == "one_key_a_column":
+            assert touched.sum() == cfg.num_categorical
+        if case == "extreme_keys":
+            assert touched[0, 0] and touched[-1, -1]
+        moved = np.asarray(got_s.accum) != np.asarray(opt0.accum)
+        np.testing.assert_array_equal(moved, touched)
+        np.testing.assert_array_equal(
+            np.asarray(got_p["embeddings"])[~touched],
+            np.asarray(params["embeddings"])[~touched])
+        assert (np.asarray(got_p["embeddings"])[touched]
+                != np.asarray(params["embeddings"])[touched]).any(axis=-1).all()
+
+    def test_an_index_outside_the_table_trains_the_row_it_reads(self):
+        """The dedup keys are built from the indices, so an index outside
+        [0, V) is folded first, as the lookup folds it: past the end the
+        feature's last row, negative from the end. The step is the step of
+        the folded batch, bit for bit."""
+        from tpu_tfrecord.models import sparse_opt_init, sparse_train_step
+
+        cfg = self.CFG
+        v = cfg.vocab_size
+        params = init_params(jax.random.key(16), cfg)
+        host = make_synthetic_batch(cfg, 16, seed=45)
+        wild, tame = host["cat"].copy(), host["cat"].copy()
+        wild[0, :] = [v, -1, 2**31 - 1]
+        tame[0, :] = [v - 1, v - 1, v - 1]
+        wild[1, :] = [-3, v + 1000, -v - 7]
+        tame[1, :] = [v - 3, v - 1, 0]
+        tx = optax.sgd(1e-2)
+        opt0 = sparse_opt_init(params, cfg, tx)
+        step = jax.jit(functools.partial(sparse_train_step, cfg=cfg, tx=tx))
+        got_p, got_s, got_l = step(
+            params, opt0, {k: jax.numpy.asarray(a) for k, a in dict(host, cat=wild).items()})
+        want_p, want_s, want_l = step(
+            params, opt0, {k: jax.numpy.asarray(a) for k, a in dict(host, cat=tame).items()})
+        assert float(got_l) == float(want_l)
+        np.testing.assert_array_equal(np.asarray(got_s.accum), np.asarray(want_s.accum))
+        np.testing.assert_array_equal(
+            np.asarray(got_p["embeddings"]), np.asarray(want_p["embeddings"]))
+        assert float(got_s.accum[0, v - 1]) > 0 and float(got_s.accum[2, 0]) > 0
+
+    @pytest.mark.parametrize("sort_path", ["flat_keys", "pair_sort"])
+    def test_the_step_gathers_and_scatters_only_what_the_update_needs(
+        self, sort_path, monkeypatch
+    ):
+        """The passes over the batch's N keys that only moved bookkeeping
+        stay deleted: the step's jaxpr holds three gathers (the lookup, the
+        row gradients into sorted order, the accumulators read back) and
+        three scatter-adds (the segment sum, the accumulator, the table),
+        and no gather reads an [N]-long array (the keys come out of sorts;
+        run lengths and per-element views of the run sums are gone)."""
+        from tpu_tfrecord.models import sparse_opt_init, sparse_train_step
+        from tpu_tfrecord.models import dlrm as dlrm_mod
+
+        if sort_path == "pair_sort":
+            monkeypatch.setattr(dlrm_mod, "_FLAT_KEY_MAX", 1)
+        cfg = self.CFG
+        params = init_params(jax.random.key(17), cfg)
+        batch = {k: jax.numpy.asarray(a) for k, a in make_synthetic_batch(cfg, 40, seed=47).items()}
+        tx = optax.sgd(1e-2)
+        opt0 = sparse_opt_init(params, cfg, tx)
+        n, d = 40 * cfg.num_categorical, cfg.embed_dim
+        jaxpr = jax.make_jaxpr(functools.partial(sparse_train_step, cfg=cfg, tx=tx))(
+            params, opt0, batch)
+
+        def equations(jp):
+            for eqn in jp.eqns:
+                yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from equations(sub)
+
+        eqns = list(equations(jaxpr.jaxpr))
+        gathers = [e.invars[0].aval.shape for e in eqns if e.primitive.name == "gather"]
+        scatters = [e.invars[0].aval.shape for e in eqns if e.primitive.name == "scatter-add"]
+        table, accum = params["embeddings"].shape, opt0.accum.shape
+        assert sorted(gathers) == sorted([table, (n, d), accum])
+        assert sorted(scatters) == sorted([(n, d), accum, table])
+        assert not [e for e in eqns if e.primitive.name == "scatter"]
+        assert sum(e.primitive.name == "sort" for e in eqns) == 2
 
     def test_sharded_sparse_step_matches_single_device(self):
         from tpu_tfrecord.models import sparse_opt_init, sparse_train_step

@@ -208,30 +208,54 @@ def sparse_opt_init(params, cfg: DLRMConfig, tx) -> SparseEmbOptState:
 # shrink it and pin both sort paths against each other at test scale.
 _FLAT_KEY_MAX = 2**31 - 1
 
+# The key of a slot that holds no run: before every real key, and as an
+# index (negative even after NumPy's wrap-around, F*V < 2^31) outside
+# every table, so a scatter drops it.
+_NO_KEY = np.iinfo(np.int32).min
+
 
 def _dedup_sort(f_flat, v_flat, vocab: int, force_pairs: bool = False):
     """Sorted grouping for the dedup-first embedding update: returns
-    (order, sf, sv, run_start) where ``order`` sorts the flattened (f, v)
-    element list lexicographically, ``sf``/``sv`` are the sorted index
-    pairs, and ``run_start`` marks each duplicate group's first element.
+    (order, slot, uf, uv). ``order`` sorts the flattened (f, v) element
+    list lexicographically. Per run of equal pairs in that order: ``slot``
+    is each sorted element's run, numbered so that the R runs take the LAST
+    R of N slots in order, and ``uf``/``uv`` hold each slot's pair; the
+    N - R slots before them hold ``_NO_KEY``, which indexes no table.
+    Empty slots come first because the TPU compiler rewrites an index out
+    of range to -1: behind the real keys that breaks the order a scatter
+    is promised, and it then writes wrong rows (PERF.md §6, PR 28). The
+    keys come out of sorts, never out of a gather through ``order`` (a sort
+    of [N] keys costs a tenth of a gather of them on a TPU): the sorted
+    keys beside the permutation, the runs' keys by sorting the run starts
+    to the back.
 
-    Two equivalent paths: flat int32 keys (one argsort — the fast common
-    case) while F*V fits int32, and a lexicographic (f, v) pair sort
-    beyond that — int32 flat keys would silently WRAP for F*V > 2^31,
-    merging unrelated rows into one dedup group and corrupting their
-    updates, and int64 keys are unavailable with x64 disabled. Both sorts
-    are stable over the same total order (v < vocab), so they produce the
-    identical permutation (pinned in tests/test_model.py)."""
+    Two equivalent paths: flat int32 keys (the fast common case) while F*V
+    fits int32, and a lexicographic (f, v) pair sort beyond that — int32
+    flat keys would silently WRAP for F*V > 2^31, merging unrelated rows
+    into one dedup group and corrupting their updates, and int64 keys are
+    unavailable with x64 disabled. Both sorts are stable over the same
+    total order (v < vocab), so they produce the identical permutation
+    (pinned in tests/test_model.py)."""
+    n = f_flat.shape[0]
+    iota = jnp.arange(n, dtype=jnp.int32)
+    first = jnp.ones((1,), bool)
     if force_pairs:
-        order = jnp.lexsort((v_flat, f_flat))
+        sf, sv, order = jax.lax.sort((f_flat, v_flat, iota), num_keys=2)
+        run_start = jnp.concatenate(
+            [first, (sf[1:] != sf[:-1]) | (sv[1:] != sv[:-1])]
+        )
+        # stable on the feature alone: the run starts keep their (f, v) order
+        uf, uv = jax.lax.sort(
+            (jnp.where(run_start, sf, _NO_KEY), jnp.where(run_start, sv, _NO_KEY)),
+            num_keys=1,
+        )
     else:
-        order = jnp.argsort(v_flat + f_flat * vocab)
-    sf = f_flat[order]
-    sv = v_flat[order]
-    run_start = jnp.concatenate(
-        [jnp.ones((1,), bool), (sf[1:] != sf[:-1]) | (sv[1:] != sv[:-1])]
-    )
-    return order, sf, sv, run_start
+        skey, order = jax.lax.sort((f_flat * vocab + v_flat, iota), num_keys=1)
+        run_start = jnp.concatenate([first, skey[1:] != skey[:-1]])
+        ukey = jnp.sort(jnp.where(run_start, skey, _NO_KEY))
+        uf, uv = ukey // vocab, ukey % vocab
+    runs = jnp.cumsum(run_start)                # 1-based run of each element
+    return order, runs + (n - 1 - runs[-1]), uf, uv
 
 
 def sparse_train_step(
@@ -260,17 +284,27 @@ def sparse_train_step(
     gradients, then the accumulator adds mean((sum g)^2) ONCE per unique
     row — exactly what dense row-wise AdaGrad on the full table gradient
     does (and what TF IndexedSlices consumers / torchrec do). The dedup is
-    a sort + segment-sum over the B*F (feature, row) keys — O(B*F log)
-    on-device, trivial next to the table gather/scatter — with each unique
-    row's single contribution split evenly over its duplicates so plain
-    scatter-adds apply it exactly once. Non-embedding params go through
-    the wrapped optax transform unchanged.
+    a sort + segment-sum over the N = B*F (feature, row) keys. What exists
+    once per unique row is kept once per RUN of the sorted keys, and nothing
+    is gathered back to the elements: the segment sum lays each run's
+    summed gradient at the run's slot of an [N, D] array, ``_dedup_sort``
+    lays its (f, v) pair at the same slot of the key arrays, so
+    mean((sum g)^2), the accumulator's new value, the scale and the row's
+    update are dense passes over the slots and reach the accumulator and
+    the table in one scatter each. The slots that hold no run hold zeros
+    under keys that index no table, which a scatter drops. A row no key
+    names is untouched bit for bit. Non-embedding params go through the
+    wrapped optax transform unchanged.
 
     Jit this whole function (donate params + opt_state)."""
     table = params["embeddings"]                            # [F, V, D]
-    idx = batch["cat"]                                      # [B, F]
-    f_ix = jnp.arange(cfg.num_categorical)[None, :]         # [1, F]
+    fdim, vocab = cfg.num_categorical, cfg.vocab_size
+    f_ix = jnp.arange(fdim)[None, :]                        # [1, F]
     with jax.named_scope("tfr.gather"):
+        # an index outside [0, V) trains the row the lookup reads for it
+        # (the dedup keys below are built from idx, so it has to name a row)
+        idx = batch["cat"]                                  # [B, F]
+        idx = jnp.clip(jnp.where(idx < 0, idx + vocab, idx), 0, vocab - 1)
         rows = _gather_rows(table, idx)                     # [B, F, D]
     dense_params = {k: v for k, v in params.items() if k != "embeddings"}
 
@@ -283,27 +317,20 @@ def sparse_train_step(
     with jax.named_scope("tfr.dense_update"):
         updates, new_dense_state = tx.update(g_dense, opt_state.dense, dense_params)
         dense_params = jax.tree.map(lambda p, u: p + u, dense_params, updates)
-    fdim, vocab = cfg.num_categorical, cfg.vocab_size
     with jax.named_scope("tfr.dedup_sort"):
         g_rows = g_rows.astype(jnp.float32)
         d = g_rows.shape[-1]
         n = idx.shape[0] * fdim
         f_flat = jnp.broadcast_to(f_ix, idx.shape).reshape(n)   # [N] feature id
         v_flat = idx.reshape(n)                                 # [N] vocab row
-        order, sf, sv, run_start = _dedup_sort(
+        order, slot, uf, uv = _dedup_sort(
             f_flat, v_flat, vocab, force_pairs=fdim * vocab > _FLAT_KEY_MAX
         )
         sg = g_rows.reshape(n, d)[order]
     with jax.named_scope("tfr.segment_sum"):
-        rid = jnp.cumsum(run_start) - 1                     # run id per element
-        # per-element view of its duplicate group's summed gradient and size
-        g_sum = jax.ops.segment_sum(
-            sg, rid, num_segments=n, indices_are_sorted=True
-        )[rid]                                              # [N, D]
-        m = jax.ops.segment_sum(
-            jnp.ones((n,), jnp.float32), rid, num_segments=n, indices_are_sorted=True
-        )[rid]                                              # [N]
-        inv_m = 1.0 / m
+        g_run = jax.ops.segment_sum(
+            sg, slot, num_segments=n, indices_are_sorted=True
+        )                                                   # [N, D]: a run a slot
     # Scatter with (f, v) index PAIRS, never a flattened [F*V] view: the
     # table/accum keep their [F, V@model, D] layout, so GSPMD scatters into
     # the model-sharded V axis instead of all-gathering a reshaped table
@@ -313,14 +340,12 @@ def sparse_train_step(
     # trace reader which scope they belong to, so each scatter's operand is
     # computed inside the scatter's scope.
     with jax.named_scope("tfr.accum_update"):
-        ms_share = jnp.mean(g_sum * g_sum, axis=-1) * inv_m     # sums to mean(G^2)
-        accum = opt_state.accum.at[sf, sv].add(ms_share, indices_are_sorted=True)
-        # post-accumulation scale, shared by a row's duplicates by construction
-        scale = embed_lr * jax.lax.rsqrt(accum[sf, sv] + embed_eps)     # [N]
+        ms_run = jnp.mean(g_run * g_run, axis=-1)           # [N], once per run
+        accum = opt_state.accum.at[uf, uv].add(ms_run, indices_are_sorted=True)
+        # post-accumulation scale, once per run (an empty slot reads some row's)
+        scale = embed_lr * jax.lax.rsqrt(accum[uf, uv] + embed_eps)     # [N]
     with jax.named_scope("tfr.table_scatter"):
-        table = table.at[sf, sv].add(
-            -(scale * inv_m)[:, None] * g_sum, indices_are_sorted=True
-        )
+        table = table.at[uf, uv].add(-scale[:, None] * g_run, indices_are_sorted=True)
     params = dict(dense_params, embeddings=table)
     return params, SparseEmbOptState(new_dense_state, accum), loss
 

@@ -60,8 +60,8 @@ ANNOTATIONS = {
     "tfr.interaction": "feature interaction",
     "tfr.top_mlp": "top MLP to the logits",
     "tfr.dense_update": "sparse_train_step: optimizer update of the MLPs",
-    "tfr.dedup_sort": "sparse_train_step: sort of the batch's keys, row gradients reordered",
-    "tfr.segment_sum": "sparse_train_step: duplicate keys' gradients and counts summed",
+    "tfr.dedup_sort": "sparse_train_step: keys sorted, each run's key sorted to its slot, row gradients reordered",
+    "tfr.segment_sum": "sparse_train_step: duplicate keys' gradients summed, each run's sum at its slot",
     "tfr.accum_update": "sparse_train_step: AdaGrad accumulator scatter, gather, rsqrt",
     "tfr.table_scatter": "sparse_train_step: the row updates scattered into the table",
     "tfr.embed": "pattern LM (models.lm.score): the token rows gathered from the embedding",
