@@ -3,16 +3,17 @@
 
 The materialization half of the BASELINE.md north-star: examples/sec
 serialized + framed (CRC32C) + codec-compressed + committed to disk through
-DatasetWriter.write_batches, for the same Criteo-shaped schema bench.py
-ingests (int64 label, 13 int64 dense, 26 categorical byte strings).
+DatasetWriter.write_batches, for the Criteo write schema of
+examples/criteo.py (int64 label, 13 int64 dense, 26 categorical byte strings).
 
 Measures the sequential legacy path (write_workers=1) and the parallel slab
 pipeline (write_workers=N, num_shards=S) for both uncompressed and zlib
-output, and prints ONE JSON line in bench.py's shape: {"metric", "value",
-"unit", "vs_baseline"} where value is the parallel rate for the default
-codec and vs_baseline is value / 1e6.
+output, and prints ONE JSON line: {"metric", "value", "unit",
+"vs_baseline"} where value is the parallel rate for the default codec and
+vs_baseline is value / 1e6. The writer has no cell in BENCHMARK.json yet:
+these are host-clock readings of whatever machine runs the script.
 
-Methodology (this is a SHARED box — same discipline as bench.py):
+Methodology (this is a SHARED box):
 - sequential and parallel reps are INTERLEAVED and each side reports its
   best-of (one-sided noise: other tenants only slow a rep down);
 - ``parallel_scaling_probe`` is measured first: the wall-clock scaling of
@@ -39,11 +40,13 @@ import tempfile
 import threading
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+sys.path.insert(0, os.path.join(HERE, "examples"))
 
 import numpy as np
 
-from bench import criteo_schema
+from criteo import criteo_schema, random_batch
 
 BATCH = int(os.environ.get("TFR_BENCH_WRITE_BATCH", 16384))
 N_BATCHES = int(os.environ.get("TFR_BENCH_WRITE_BATCHES", 6))
@@ -51,40 +54,13 @@ WORKERS = int(os.environ.get("TFR_BENCH_WRITE_WORKERS", 4))
 SHARDS = int(os.environ.get("TFR_BENCH_WRITE_SHARDS", 4))
 REPS = int(os.environ.get("TFR_BENCH_WRITE_REPS", 3))
 CODEC = os.environ.get("TFR_BENCH_WRITE_CODEC", "zlib")
-CAT_LEN = 8  # bytes per categorical value (matches bench.py's generator)
 
 
 def make_batches(schema):
     """Criteo-shaped ColumnarBatches built directly from numpy buffers (no
     per-row Python) so the benchmark measures the writer, not the setup."""
-    from tpu_tfrecord.columnar import Column, ColumnarBatch
-
     rng = np.random.default_rng(0)
-    batches = []
-    cat_offsets = np.arange(BATCH + 1, dtype=np.int64) * CAT_LEN
-    for _ in range(N_BATCHES):
-        cols = {}
-        cols["label"] = Column(
-            "label", schema["label"].data_type,
-            values=rng.integers(0, 2, size=BATCH, dtype=np.int64),
-        )
-        for i in range(1, 14):
-            name = f"I{i}"
-            cols[name] = Column(
-                name, schema[name].data_type,
-                values=rng.integers(0, 1 << 31, size=BATCH, dtype=np.int64),
-            )
-        for i in range(1, 27):
-            name = f"C{i}"
-            blob = (
-                rng.integers(0, 16, size=BATCH * CAT_LEN, dtype=np.uint8) + 97
-            ).tobytes()
-            cols[name] = Column(
-                name, schema[name].data_type,
-                blob=blob, blob_offsets=cat_offsets,
-            )
-        batches.append(ColumnarBatch(cols, BATCH))
-    return batches
+    return [random_batch(rng, schema, BATCH) for _ in range(N_BATCHES)]
 
 
 def parallel_scaling_probe() -> float:
@@ -161,8 +137,8 @@ def measure_pair(schema, batches, out_dir, codec):
 
 def tracing_overhead(schema, batches, out_dir, codec):
     """Flight-recorder overhead on the parallel write path: interleaved
-    trace-off/trace-on reps, best-of-each (one-sided noise — same argument
-    as the read bench). Returns the overhead pct (negative = in the
+    trace-off/trace-on reps, best-of-each (one-sided noise: other tenants
+    only slow a rep down). Returns the overhead pct (negative = in the
     noise)."""
     from tpu_tfrecord import telemetry as tm
 
@@ -228,7 +204,7 @@ def main() -> None:
         "metric": "criteo_tf_example_write_to_disk",
         "value": round(par, 1),
         "unit": "examples/sec/host",
-        # same normalization as bench.py's read-side headline (>=1M ex/s)
+        # BASELINE.md's >=1M examples/s, as a ratio
         "vs_baseline": round(par / 1_000_000, 4),
         "codec": None if headline == "none" else "deflate",
         "write_workers": WORKERS,

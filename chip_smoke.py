@@ -3,7 +3,7 @@
 
 Drives the system's own job once, through the entry points a user calls, on
 ONE TPU chip: TFRecord shards -> native decode -> fused hash -> pack -> H2D
--> DLRM sparse train step at the bench's full width (26 tables x 2^20 rows x
+-> DLRM sparse train step at the smoke's full width (26 tables x 2^20 rows x
 32 f32, B = 16,384), with resume; then the LM trainer and the serving
 replica as the processes they are. It checks what comes out by the repo's
 own references and fails loudly — non-zero exit, no result line — when JAX
@@ -41,20 +41,21 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
+sys.path.insert(0, os.path.join(HERE, "examples"))
 
 import numpy as np  # noqa: E402
 
 import tpu_tfrecord  # noqa: E402,F401  (no jax: the parent holds no chip)
-from bench import (  # noqa: E402  (no jax at import either)
+from criteo import (  # noqa: E402  (examples/criteo.py: no jax at import either)
     CAT_BITS, HASH_BUCKETS, NUM_CAT, NUM_DENSE, criteo_dlrm_config,
-    criteo_read_schema, criteo_reader_spec, criteo_schema, split_wire,
+    criteo_read_schema, criteo_reader_spec, split_wire, write_dataset,
 )
 
 #: Device kinds this smoke has been run on. A kind that is not here is an
 #: error, not a default: nothing is assumed about a chip nobody has seen.
 KNOWN_DEVICE_KINDS = ("TPU v5 lite",)
 
-#: The full-width run (bench.py's Criteo cell): 16 shards x 32,768 rows =
+#: The full-width run: 16 shards x 32,768 rows =
 #: 32 batches of 16,384 in ONE epoch, so rows consumed == rows written.
 FULL = dict(
     shards=16, rows_per_shard=32768, batch=16384, vocab=1 << 20, steps=32,
@@ -188,52 +189,13 @@ def phase_build(clean: bool = True) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Dataset (Criteo-shaped, bench.py's schema, from --seed)
+# The Criteo reader (examples/criteo.py's schema and spec)
 # ---------------------------------------------------------------------------
 
 
-def write_dataset(data_dir: str, seed: int, shards: int, rows_per_shard: int) -> int:
-    """Write ``shards`` TFRecord files of ``rows_per_shard`` Criteo-shaped
-    rows with the framework's columnar writer (one append job per shard, so
-    the layout holds at any size). Returns rows written."""
-    from tpu_tfrecord.columnar import Column, ColumnarBatch
-    from tpu_tfrecord.io.writer import DatasetWriter
-    from tpu_tfrecord.options import TFRecordOptions
-
-    schema = criteo_schema()
-    shutil.rmtree(data_dir, ignore_errors=True)
-    rng = np.random.default_rng(seed)
-    n = rows_per_shard
-    offsets = np.arange(n + 1, dtype=np.int64) * 8
-    for _ in range(shards):
-        cols = {
-            "label": Column(
-                "label", schema["label"].data_type,
-                values=rng.integers(0, 2, size=n, dtype=np.int64),
-            )
-        }
-        for i in range(1, NUM_DENSE + 1):
-            cols[f"I{i}"] = Column(
-                f"I{i}", schema[f"I{i}"].data_type,
-                values=rng.integers(0, 1 << 31, size=n, dtype=np.int64),
-            )
-        for i in range(1, NUM_CAT + 1):
-            blob = (rng.integers(0, 16, size=n * 8, dtype=np.uint8) + 97).tobytes()
-            cols[f"C{i}"] = Column(
-                f"C{i}", schema[f"C{i}"].data_type, blob=blob, blob_offsets=offsets,
-            )
-        DatasetWriter(
-            data_dir, schema, TFRecordOptions.from_map(), mode="append"
-        ).write_batches([ColumnarBatch(cols, n)])
-    files = [f for f in os.listdir(data_dir) if f.endswith(".tfrecord")]
-    if len(files) != shards:
-        raise SmokeFailure(f"wrote {len(files)} shard files, wanted {shards}")
-    return shards * rows_per_shard
-
-
 def _criteo_dataset(data_dir: str, batch: int, **kw):
-    """bench.py's Criteo reader (fused hash to 2^20 buckets, [B, 40] pack)
-    at this batch size."""
+    """The Criteo reader (fused hash to 2^20 buckets, [B, 40] pack) at this
+    batch size."""
     from tpu_tfrecord.io.dataset import TFRecordDataset
 
     hash_buckets, pack = criteo_reader_spec()
@@ -422,7 +384,7 @@ def phase_compare(
 ) -> None:
     """One REAL ingested batch through sparse_train_step and through the
     plain reference of the same semantics (full dense table gradient +
-    row-wise AdaGrad applied densely, models.dlrm), at the bench's feature
+    row-wise AdaGrad applied densely, models.dlrm), at the smoke's feature
     widths and B with indices folded to ``cmp_vocab`` so the dense gradient
     fits; then dot interaction XLA vs the compiled Pallas kernel.
 
@@ -702,7 +664,12 @@ def child_dlrm(args) -> dict:
     phase_build(clean=True)
     data_dir = os.path.join(args.workdir, "criteo")
     t0 = time.perf_counter()
-    rows = write_dataset(data_dir, args.seed, FULL["shards"], FULL["rows_per_shard"])
+    try:
+        rows = write_dataset(
+            data_dir, args.seed, FULL["shards"], FULL["rows_per_shard"]
+        )
+    except RuntimeError as e:
+        raise SmokeFailure(str(e)) from e
     info("dataset", rows=rows, shards=FULL["shards"],
          write_s=round(time.perf_counter() - t0, 2))
     phase_transport_probe()

@@ -59,7 +59,7 @@ def state_saver(ckpt_dir: str):
     thread and hands the fsync-then-rename write to the background commit
     thread (checkpoint.AsyncStateSaver), so the ``ckpt`` step phase
     measures microseconds instead of disk latency. ``TFR_CKPT_MODE=sync``
-    keeps the write inline — the measurement twin the bench/verify
+    keeps the write inline — the measurement twin verify.sh's
     throttle legs compare against. Callers must ``saver.close()`` in a
     ``finally`` so the last commit drains (and any commit failure
     surfaces) before the process exits."""
@@ -116,8 +116,7 @@ class StepPhases:
     the spool ships to the fleet, and what the verdict describes — the
     recent regime, not the lifetime average) plus a ``train.verdict``
     trace instant. Overhead: a few perf_counter pairs and one locked
-    Metrics add per phase per step — noise next to any real train step
-    (the bench's lm_step_breakdown leg measures the loop with this on).
+    Metrics add per phase per step — noise next to any real train step.
     """
 
     PHASES = telemetry.TRAIN_PHASES
@@ -260,8 +259,7 @@ def fold_model_diagnostics(diag, metrics=None) -> Dict[str, float]:
     import numpy as np
 
     # ONE transfer for the whole tiny pytree: per-field float() would pay
-    # a dispatch fence each (measured at >10% step overhead on the bench's
-    # small LM; one device_get keeps the A/B within the <=2% bar)
+    # a dispatch fence each (measured at >10% step overhead on a small LM)
     host = jax.device_get(diag)
     if "expert_tokens" in host:
         tokens = np.asarray(host["expert_tokens"], dtype=float)

@@ -3,8 +3,9 @@
 
 The dataset-prep half of the pipeline: raw Criteo TSV (label \\t 13 integer
 features \\t 26 hex categorical features, empty field = missing) becomes
-TFRecord shards written through the native columnar encoder — the same
-files bench.py and examples/train_dlrm.py then stream into the TPU.
+TFRecord shards written through the native columnar encoder, under the
+schema of examples/criteo.py — the files examples/train_dlrm.py then streams
+into the TPU.
 
 Usage:
     python examples/criteo_prepare.py [input.tsv] [output_dir]
@@ -18,23 +19,18 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import numpy as np
+
+from criteo import NUM_CAT, NUM_DENSE, criteo_schema
 
 from tpu_tfrecord.columnar import Column, ColumnarBatch
 from tpu_tfrecord.io.writer import DatasetWriter
 from tpu_tfrecord.options import TFRecordOptions
-from tpu_tfrecord.schema import LongType, StringType, StructField, StructType
+from tpu_tfrecord.schema import LongType, StringType
 
-NUM_DENSE, NUM_CAT = 13, 26
 CHUNK_ROWS = 50_000
-
-
-def criteo_schema() -> StructType:
-    fields = [StructField("label", LongType(), nullable=False)]
-    fields += [StructField(f"I{i}", LongType()) for i in range(1, NUM_DENSE + 1)]
-    fields += [StructField(f"C{i}", StringType()) for i in range(1, NUM_CAT + 1)]
-    return StructType(fields)
 
 
 def rows_to_batch(lines) -> ColumnarBatch:

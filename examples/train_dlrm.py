@@ -31,57 +31,25 @@ import optax
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import _harness
+from criteo import CAT_COLS, DENSE_COLS, NUM_CAT, NUM_DENSE, criteo_schema, write_dataset
 
 from tpu_tfrecord.io.dataset import TFRecordDataset
 from tpu_tfrecord.models import DLRMConfig, init_params, train_step
-from tpu_tfrecord.schema import LongType, StringType, StructField, StructType
-from tpu_tfrecord.serde import TFRecordSerializer, encode_row
-from tpu_tfrecord.options import RecordType
 from tpu_tfrecord.tpu import create_mesh, host_batch_from_columnar, make_global_batch
 
-NUM_DENSE, NUM_CAT = 13, 26
 VOCAB = 1 << 16
 BATCH = 1024
-
-
-def make_schema() -> StructType:
-    fields = [StructField("label", LongType(), nullable=False)]
-    fields += [StructField(f"I{i}", LongType()) for i in range(NUM_DENSE)]
-    fields += [StructField(f"C{i}", StringType()) for i in range(NUM_CAT)]
-    return StructType(fields)
-
-
-def generate(data_dir: str, shards: int = 4, rows: int = 4096) -> None:
-    if os.path.exists(os.path.join(data_dir, "_SUCCESS")):
-        return
-    schema = make_schema()
-    ser = TFRecordSerializer(schema)
-    rng = np.random.default_rng(0)
-
-    def all_rows():
-        for _ in range(shards * rows):
-            row = [int(rng.integers(0, 2))]
-            row += [int(v) for v in rng.integers(0, 1 << 20, size=NUM_DENSE)]
-            row += [f"v{int(v)}" for v in rng.integers(0, 5000, size=NUM_CAT)]
-            yield encode_row(ser, RecordType.EXAMPLE, row)
-
-    from tpu_tfrecord import wire
-
-    os.makedirs(data_dir, exist_ok=True)
-    it = all_rows()
-    for s in range(shards):
-        wire.write_records(
-            os.path.join(data_dir, f"part-{s:05d}-gen.tfrecord"),
-            (next(it) for _ in range(rows)),
-        )
-    open(os.path.join(data_dir, "_SUCCESS"), "wb").close()  # graftlint: allow(atomic-write: zero-byte marker; no content to tear)
 
 
 def main() -> None:
     data_dir = "/tmp/tpu_tfrecord_example/data"
     ckpt_dir = "/tmp/tpu_tfrecord_example/ckpt"
-    generate(data_dir)
-    schema = make_schema()
+    # the files examples/criteo_prepare.py writes from a real TSV are read
+    # the same way: one schema (examples/criteo.py). Kept across runs, so a
+    # rerun resumes against the same dataset fingerprint.
+    if not os.path.exists(os.path.join(data_dir, "_SUCCESS")):
+        write_dataset(data_dir, seed=0, shards=4, rows_per_shard=4096)
+    schema = criteo_schema()
 
     mesh = create_mesh()
     cfg = DLRMConfig(
@@ -104,11 +72,8 @@ def main() -> None:
         opt_state = tx.init(params)
         step_fn = jax.jit(functools.partial(train_step, cfg=cfg, tx=tx), donate_argnums=(0, 1))
 
-    hash_buckets = {f"C{i}": VOCAB for i in range(NUM_CAT)}
-    pack = {
-        "dense": [f"I{i}" for i in range(NUM_DENSE)],
-        "cat": [f"C{i}" for i in range(NUM_CAT)],
-    }
+    hash_buckets = {c: VOCAB for c in CAT_COLS}
+    pack = {"dense": DENSE_COLS, "cat": CAT_COLS}
 
     # NOTE: in a real job the input state is saved/restored TOGETHER with the
     # model checkpoint (params/opt_state) at the same step — here only the

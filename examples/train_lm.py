@@ -154,7 +154,7 @@ class LMCheckpoint:
     the same pairing guarantee the old single-file ``os.replace`` gave,
     plus durability (fsync) and an off-step-path disk. ``sync=True`` is
     the measurement twin: identical bytes, commit inline on the caller's
-    thread (what the bench A/B and verify.sh throttle legs compare).
+    thread (what verify.sh's throttle legs compare).
     Still numpy+stdlib on the persistence side — orbax stays optional.
     """
 

@@ -62,8 +62,7 @@ def per_device_argument_bytes(fn, *args) -> int:
 def compiled_memory_bytes(fn, *args) -> dict:
     """Per-device compiled-memory byte sizes from ``memory_analysis()``,
     labeled with the backend that compiled them — so a CPU-mesh number
-    (the MULTICHIP partial) and the eventual real-device round land in
-    the SAME fields (ROADMAP #4). Returns {} when the backend reports no
+    and a real-device one land in the SAME fields. Returns {} when the backend reports no
     memory analysis (some PJRT plugins)."""
     ma = compiled(fn, *args).memory_analysis()
     if ma is None:

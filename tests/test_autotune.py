@@ -660,7 +660,7 @@ class TestThrottledDecodeChaos:
         # Best-of, not mean-of: interference on this shared box is
         # one-sided — other tenants only slow an epoch down — so the min
         # epoch time is the noise-robust estimator (the same argument the
-        # bench and perf-floor tests document), and the injected stalls
+        # perf-floor tests document), and the injected stalls
         # dominate each epoch's floor, which is exactly what the worker
         # pool parallelizes.
         tail = max(2, len(tuned_times) // 2)

@@ -1,7 +1,7 @@
 """Transfer bit-packing: host pack / device unpack round-trip.
 
 No reference analog (the JVM rows never crossed a device link); this pins
-the TPU-first transfer-packing layer used by the ingest bench: hashed
+the TPU-first transfer-packing layer the Criteo feed uses: hashed
 bucket indices packed to their significant bits on the host, unpacked
 bit-exactly inside the consumer's jit.
 """
@@ -130,7 +130,7 @@ def test_pack_mixed_rejects_bad_args(dtype):
 
 
 def test_bench_style_mixed_layout():
-    """label+dense stay 32-bit, cats pack to 20 — the bench's [B,31] layout."""
+    """label+dense stay 32-bit, cats pack to 20 — the Criteo wire's [B,31] layout."""
     rng = np.random.default_rng(1)
     full = np.concatenate(
         [

@@ -101,6 +101,18 @@ class TestMainRefusesAnythingButTpu:
         with pytest.raises(chip_smoke.SmokeFailure, match="needs a TPU"):
             chip_smoke.require_tpu(1)
 
+    def test_the_parent_imports_neither_jax_nor_bench(self):
+        """A process that touched JAX holds the chip its children need, and
+        the smoke's Criteo definition is examples/criteo.py, nothing larger."""
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import sys, chip_smoke; "
+             "print(sorted({'jax', 'bench'} & set(sys.modules)), "
+             "sys.modules['criteo'].__file__)"],
+            cwd=REPO, capture_output=True, text=True, timeout=120, check=True,
+        ).stdout.split()
+        assert out == ["[]", os.path.join(REPO, "examples", "criteo.py")]
+
     @pytest.mark.parametrize("argv", [[], ["--chips", "4"]])
     def test_main_exits_nonzero_under_jax_platforms_cpu(self, tmp_path, argv):
         env = dict(os.environ, JAX_PLATFORMS="cpu")

@@ -762,82 +762,6 @@ class TestWorkerCli:
 
 
 # ---------------------------------------------------------------------------
-# Bench: vs_previous regressions are a first-class verdict
-# ---------------------------------------------------------------------------
-
-
-class TestBenchRegressionVerdict:
-    def test_regression_is_first_class_and_loud(self, monkeypatch, capsys):
-        import bench
-
-        monkeypatch.setattr(
-            bench, "_load_previous_artifact",
-            lambda: ("BENCH_r05.json", {"seq_host_value": 100.0}),
-        )
-        out = {"seq_host_value": 10.0}
-        bench._attach_regression_verdict(out)
-        assert out["regression_verdict"] == "regression"
-        assert out["vs_previous"]["regressions"] == ["seq_host_value"]
-        err = capsys.readouterr().err
-        assert "REGRESSION" in err and "seq_host_value" in err
-
-    def test_parity_append_survives_stripped_table(self, tmp_path):
-        import bench
-
-        parity = tmp_path / "PARITY.md"
-        # header survived a hand edit, the table didn't: the appender
-        # must rebuild the table, not die and cost the bench artifact
-        parity.write_text(
-            f"# P\n\n{bench._PARITY_SCALING_HEADER}\n\nprose only\n"
-        )
-        bench._append_parity_scaling_row(
-            {1: 100.0, 2: 200.0, 4: 400.0}, path=str(parity)
-        )
-        content = parity.read_text()
-        assert "| 100 | 200 | 400 | 2.00x | 4.00x |" in content
-        # and a second append lands in the (rebuilt) table
-        bench._append_parity_scaling_row(
-            {1: 110.0, 2: 220.0, 4: 440.0}, path=str(parity)
-        )
-        assert "| 110 | 220 | 440 |" in parity.read_text()
-
-    def test_parity_append_lands_below_separator(self, tmp_path):
-        import bench
-
-        parity = tmp_path / "PARITY.md"
-        # table stripped to header + separator: the new row must land
-        # BELOW the "|---|" separator, never between header and separator
-        parity.write_text(
-            f"{bench._PARITY_SCALING_HEADER}\n\n"
-            "| round | date | 1w ex/s | 2w ex/s | 4w ex/s | 2w/1w | 4w/1w |\n"
-            "|---|---|---|---|---|---|---|\n"
-        )
-        bench._append_parity_scaling_row(
-            {1: 100.0, 2: 200.0, 4: 400.0}, path=str(parity)
-        )
-        lines = parity.read_text().splitlines()
-        sep = next(i for i, l in enumerate(lines) if l.startswith("|---"))
-        row = next(i for i, l in enumerate(lines) if "| 100 |" in l)
-        assert row == sep + 1, lines
-
-    def test_ok_and_no_previous_are_quiet(self, monkeypatch, capsys):
-        import bench
-
-        monkeypatch.setattr(bench, "_load_previous_artifact", lambda: None)
-        out = {}
-        bench._attach_regression_verdict(out)
-        assert out["regression_verdict"] == "no_previous"
-        monkeypatch.setattr(
-            bench, "_load_previous_artifact",
-            lambda: ("BENCH_r05.json", {"seq_host_value": 100.0}),
-        )
-        out = {"seq_host_value": 101.0}
-        bench._attach_regression_verdict(out)
-        assert out["regression_verdict"] == "ok"
-        assert "REGRESSION" not in capsys.readouterr().err
-
-
-# ---------------------------------------------------------------------------
 # Chaos acceptance: grow + graceful drain + SIGKILL mid-drain, mid-epoch
 # ---------------------------------------------------------------------------
 

@@ -1,5 +1,6 @@
 """Pallas dot-interaction kernel vs the XLA reference (interpret mode on
-CPU; the real-TPU compile/run is exercised by __graft_entry__ and bench)."""
+CPU; the real-TPU compile/run is chip_smoke.py's comparison phase and
+tests/test_tpu_compile.py)."""
 
 import jax
 import jax.numpy as jnp

@@ -2,7 +2,7 @@
 inside each test (VERDICT r3 item 3).
 
 A decode regression must be caught by CI as a failing test, not discovered
-rounds later as a mysteriously degraded bench headline. Each test times the
+rounds later as a mysteriously slower loader. Each test times the
 fast path and its own plain oracle on the same rows, windows interleaved so
 both see the same load, checks that they yield the same bytes, and holds
 the fast path to a conservative multiple of the oracle:

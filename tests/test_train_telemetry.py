@@ -543,7 +543,7 @@ class TestLMDiagnostics:
 
     def test_dimensionless_hists_never_render_as_milliseconds(self):
         # the folded diagnostics are FRACTIONS: quantiles_ms (the one
-        # ms-renderer every pulse/bench/doctor line goes through) must
+        # ms-renderer every pulse/doctor line goes through) must
         # skip them — a dropped fraction of 0.02 printed as "20ms of
         # latency" on the fleet page would lie
         m = Metrics()
@@ -559,7 +559,7 @@ class TestLMDiagnostics:
         assert telemetry.is_latency_hist("train.step")
 
     def test_lm_compiled_memory_fields(self):
-        # the MULTICHIP-partial helper: per-device compiled-memory bytes
+        # per-device compiled-memory bytes
         # from the same compiled handle as the HLO pins, backend-labeled
         import optax
 

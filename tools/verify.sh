@@ -1,6 +1,8 @@
 #!/usr/bin/env bash
-# One-entry-point verification: the fast syntax gate plus the tier-1 test
-# command from ROADMAP.md (keep the pytest invocation in sync with it).
+# One-entry-point verification: the fast syntax gate, the smokes, then the
+# tier-1 tests in the shape the driver runs them (xdist, 6 workers by file,
+# 1470 s: the `commands` of the driver's last-run record; ROADMAP.md's
+# "Tier-1 verify" line is older and is not what is run).
 # Usage: tools/verify.sh  (from the repo root or anywhere)
 set -u
 cd "$(dirname "$0")/.."

@@ -56,8 +56,7 @@ Three pieces:
   ``PipelineControl``, a controller, and (if none was configured) a pulse
   at ``autotune_interval_s``; the controller runs as a pulse observer.
   ``tfrecord_doctor tune DATA_DIR`` runs the loop offline and prints the
-  converged knob set; ``bench.py`` reports an ``autotune`` block
-  (convergence trajectory + final knobs + throughput vs fixed-knob).
+  converged knob set.
 
 Everything here is opt-in: with ``autotune="off"`` (the default) no
 controller, no control object, and no extra per-batch work exists.
@@ -353,7 +352,7 @@ class AutotuneController:
         self.clock = clock
         self.interval_s = interval_s
         #: full decision log: one dict per adjustment (knob, from, to,
-        #: reason, tick) — the convergence trajectory bench/doctor report
+        #: reason, tick) — the convergence trajectory the doctor reports
         self.log: List[Dict[str, Any]] = []
         self._tick = 0
         # guard-rail bookkeeping (hysteresis streaks + cooldown) is shared
@@ -408,7 +407,7 @@ class AutotuneController:
 
     def snapshot(self, adjusted: Optional[List[Dict]] = None) -> Dict[str, Any]:
         """Current knob values (+ this tick's moves when given) — the
-        shape the pulse line, doctor ``tune``, and bench all emit."""
+        shape the pulse line and doctor ``tune`` emit."""
         c = self.control
         guard = c.guard
         out: Dict[str, Any] = {

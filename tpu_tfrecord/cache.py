@@ -1,10 +1,8 @@
 """Columnar epoch cache: decode a shard once, mmap every epoch after.
 
-BENCH_r05 shows warm-dataset ingest is decode-bound (cold_vs_bound 0.917 vs
-cold_vs_disk_bound 0.375): the disk could feed ~2.4x more than the CPU can
-protobuf-decode, and multi-epoch training re-pays the full tf.Example decode
-every epoch. tf.data's snapshot/materialization work shows the canonical
-fix — persist the DECODED representation once and serve later epochs from
+Warm-dataset ingest is decode-bound, and multi-epoch training re-pays the
+full tf.Example decode every epoch. tf.data's snapshot/materialization work
+shows the canonical fix — persist the DECODED representation once and serve later epochs from
 it. Our decoded representation (`ColumnarBatch`: dense values + offsets +
 blob buffers) is already an mmap-friendly flat layout, so the cache reload
 is near zero-cost: numpy views straight over one mmap of the cache file, no
@@ -689,7 +687,7 @@ def _registry_drop_path(path: str) -> None:
 def release_registry(cache_dir: Optional[str] = None) -> int:
     """Drop validated-entry registrations (all, or those under one cache
     dir), unpinning their mmaps — for callers that delete a cache dir
-    out-of-band (the bench's throwaway probe dir, tests): rmtree alone
+    out-of-band (a throwaway probe dir, tests): rmtree alone
     frees no disk while the registry still maps the inodes. Entries also
     held by live datasets stay alive through those references. Returns the
     number released."""

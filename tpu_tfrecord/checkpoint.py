@@ -296,11 +296,11 @@ class _CommitWorker:
     bounded AND observable — and re-raises any prior failure. ``submit``
     enqueues the next commit; the worker times it into the ``ckpt.commit``
     stage and counts the inflight gauge down. ``run_inline`` is the SYNC
-    twin: same throttle, same metrics, caller's thread — what the bench
-    A/B and ``sync=True`` checkpointers measure against.
+    twin: same throttle, same metrics, caller's thread — what
+    ``sync=True`` checkpointers run.
 
     ``commit_delay_s`` is the seeded slow-disk seam (env
-    ``TFR_CKPT_COMMIT_THROTTLE_S`` when unset): the bench/verify chaos
+    ``TFR_CKPT_COMMIT_THROTTLE_S`` when unset): the verify chaos
     legs throttle the commit path with it to force the sync twin into a
     ``ckpt_bound`` verdict while the async path stays compute_bound.
     """
@@ -443,8 +443,8 @@ class AsyncCheckpointer:
     hygiene; each removal counts ``ckpt.generations_swept``.
 
     ``sync=True`` is the measurement twin: identical bytes and layout,
-    commit executed inline on the caller's thread (what the bench A/B
-    pins the async win against).
+    commit executed inline on the caller's thread (the tests' reference
+    for the async path).
 
     Scope: single-controller and one-shard-per-process multihost jobs.
     On a multihost mesh pass ``barrier`` (e.g. a

@@ -649,7 +649,7 @@ def pad_ragged2(
     clipped_outer = np.minimum(outer_lengths, max_outer).astype(np.int32)
     if n and max_outer and max_inner:
         # Fully vectorized two-level pad (no per-row Python loop — that costs
-        # ~75 ms/batch at the long-doc bench shape): select the kept inner
+        # ~75 ms/batch at the long-doc shape): select the kept inner
         # lists row-major with their destination (row, slot), then apply the
         # one-level pad gather over just those lists and scatter into the
         # flattened [n * max_outer, max_inner] dense view.

@@ -1,6 +1,6 @@
 """Where XLA's persistent compilation cache lives — one owner.
 
-Every entry point that jits (``chip_smoke.py``, ``bench.py``, the
+Every entry point that jits (``chip_smoke.py``, ``benchmark/run.py``, the
 examples, ``serving.main``) calls :func:`enable` before its first compile.
 The directory is placed from OUTSIDE: where ``JAX_COMPILATION_CACHE_DIR``
 is set, JAX reads it by itself and this module sets nothing; where it is

@@ -2211,7 +2211,7 @@ int64_t tfr_pack_mixed(const int32_t* in, int64_t n_rows, int32_t n_cols,
 // The host tail of SequenceExample ingest (ref TFRecordDeserializer.scala:
 // 37-61's 2-D FeatureLists): the decoder produces ragged value buffers, the
 // device wants dense [B, Lo, Li] in the compute dtype. Doing pad + cast in
-// numpy costs ~75 ms/batch at the bench shape (per-row Python loop +
+// numpy costs ~75 ms/batch at the long-doc shape (per-row Python loop +
 // ml_dtypes cast); fused here it is a memset + per-list memcpy/convert.
 // in_kind: 0 = f32, 1 = i64. out_kind: 0 = f32, 1 = bf16 (from f32,
 // round-to-nearest-even), 2 = i64, 3 = i32 (from i64, two's-complement

@@ -18,7 +18,7 @@ The control loop is the autotuner's (PR 6) lifted one level up:
   as ``idle`` (offered load is zero).
 - **Actuator**: the dispatcher. Scale-up SPAWNS a decode-worker process
   (``spawn`` callable — ``subprocess_spawner`` in production, an
-  in-process factory in tests/bench). Scale-down picks a victim
+  in-process factory in tests). Scale-down picks a victim
   deterministically (last in sorted order among the active workers) and
   marks it **draining** via ``ServiceDispatcher.drain``: its unstarted
   leases are handed back for re-routing, new shards route around it, it

@@ -18,7 +18,7 @@ halves that meet over a REAL TCP connection:
   trusting a truncated read as EOF).
 
 - ``serve_directory`` / ``FaultingRangeServer``: a threaded stdlib HTTP
-  server over a local directory — the test/bench backend. Range support,
+  server over a local directory — the tests' backend. Range support,
   one thread per connection, and (when given a FaultPlan) seeded faults
   that fire at the SERVER side of the socket: connection RST mid-body,
   truncated bodies, 503/429 with ``Retry-After``, slow-trickle stalls,
@@ -619,7 +619,7 @@ class HttpFS:
 
 
 # ---------------------------------------------------------------------------
-# The test/bench backend: a threaded Range server with socket-level faults
+# The tests' backend: a threaded Range server with socket-level faults
 # ---------------------------------------------------------------------------
 
 
@@ -783,8 +783,8 @@ class _RangeHandler(BaseHTTPRequestHandler):
         if stall_s:
             plan.sleep(stall_s)
         if self.server.latency_s:
-            # simulated per-request link RTT for the bench depth sweep —
-            # still a real connection, the handler just answers late
+            # simulated per-request link RTT — still a real connection,
+            # the handler just answers late
             import time as _time
 
             _time.sleep(self.server.latency_s)
@@ -897,7 +897,7 @@ class FaultingRangeServer:
     ``plan`` may be None (clean serving), or a faults.FaultPlan whose
     ``op="http"`` rules fire on file GETs; fired faults land in the
     plan's replayable ledger. ``latency_s`` adds a fixed per-request
-    delay — the bench's simulated link RTT on top of real sockets.
+    delay — a simulated link RTT on top of real sockets.
     """
 
     def __init__(self, root: str, plan=None, latency_s: float = 0.0,
@@ -988,7 +988,7 @@ class FaultingRangeServer:
 def serve_directory(root: str, plan=None, latency_s: float = 0.0,
                     host: str = "127.0.0.1", port: int = 0) -> FaultingRangeServer:
     """Start a FaultingRangeServer over ``root`` on an ephemeral port and
-    return it (already serving). The one-liner the tests, bench, and
+    return it (already serving). The one-liner the tests and the
     verify smoke use::
 
         with serve_directory(local_dir, plan=plan) as srv:

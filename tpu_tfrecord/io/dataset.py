@@ -338,7 +338,7 @@ class TFRecordDataset:
         # the kernel fetches ahead ASYNCHRONOUSLY while the C++ decoder
         # chews the current chunk, so cold (non-page-cache-resident) reads
         # run at the store's streaming bandwidth instead of
-        # fault-per-page latency. Measured on the bench box: 152 MB/s
+        # fault-per-page latency. Measured on a development box: 152 MB/s
         # serial-faulting vs 1068 MB/s with WILLNEED issued ahead — the
         # difference between IO-bound and decode-bound cold ingest
         # (BASELINE.md configs[4], "read at line rate").

@@ -220,9 +220,9 @@ class Metrics:
     def snapshot(self, prefix: Optional[str] = None) -> Dict[str, Dict[str, float]]:
         """Per-stage throughput map; ``prefix`` filters to one stage family
         (e.g. ``'write'`` -> write, write.encode, write.compress, write.io
-        — the breakdown the write bench reports).
+        — the breakdown ``bench_write.py`` reports).
 
-        Key stability contract (bench/test consumers): stage entries keep
+        Key stability contract (``bench_write.py`` and the tests read these): stage entries keep
         the exact keys they always had (records_per_sec, bytes_per_sec,
         records, bytes, batches, seconds). Stages with a latency histogram
         additionally carry ``p50_s``/``p90_s``/``p99_s``/``hist_count``;

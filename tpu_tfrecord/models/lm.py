@@ -756,7 +756,7 @@ def make_synthetic_tokens(
 
 def bigram_table(vocab: int, n_next: int, seed: int = 1234) -> np.ndarray:
     """[V, n_next] successor table — the synthetic 'language' shared by
-    tests, the example generator, and the bench probe."""
+    tests and the example generator."""
     rng = np.random.default_rng(seed)
     return rng.integers(0, vocab, size=(vocab, n_next)).astype(np.int32)
 

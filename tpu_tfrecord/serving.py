@@ -352,7 +352,7 @@ class ServingEngine:
             self._metrics.observe(
                 "serve.latency", now - req.birth, exemplar=exemplar
             )
-            # the latency decomposition the bench probe reads: time spent
+            # the latency decomposition: time spent
             # waiting for a slot vs time being served (first pack ->
             # completion). Both on the engine clock, both exemplar-tagged.
             if req.first_pack is not None:
@@ -514,7 +514,7 @@ class ServingEngine:
 
     def run_until_idle(self) -> None:
         """Drive ticks until no request is queued, continuing, or in
-        flight — the synchronous mode tests and the bench probe use."""
+        flight — the synchronous mode the tests use."""
         while self.step() > 0:
             pass
 

@@ -32,7 +32,7 @@ threads accumulate only one per detected stall, never one per read.
 Fault-free overhead is one queue hand-off per underlying read; small
 (per-record) reads are amortized through an internal >= ``io_chunk``
 buffer, so the guarded row reader does not pay a hand-off per 8-byte
-header. bench.py's ``stall_guard_overhead_pct`` field tracks this.
+header.
 """
 
 from __future__ import annotations
@@ -109,7 +109,7 @@ class _OpWorker:
 class _WorkerPool:
     """Free-list of _OpWorkers. Shard opens happen ~continuously on small
     shards; paying a thread CREATE per open/stream measurably taxes a
-    fully-loaded host (the bench's stall_guard_overhead_pct field), while a
+    fully-loaded host, while a
     reused idle worker costs only the queue hand-off. Abandoned (wedged)
     workers are never checked back in; the idle list is bounded.
 

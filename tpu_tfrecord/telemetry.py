@@ -1026,7 +1026,7 @@ def quantiles_ms(source: Dict[str, Dict[str, float]]) -> Dict[str, Dict[str, flo
     """Convert a ``Metrics.quantiles()`` mapping — or any mapping whose
     entries carry ``p50_s``/``p90_s``/``p99_s`` (``snapshot()`` stage
     entries qualify) — into the shared milliseconds shape the pulse,
-    bench, and doctor lines all emit, so their field sets cannot drift
+    ``bench_write.py`` and doctor lines all emit, so their field sets cannot drift
     apart. Entries without quantiles are skipped, as are the
     DIMENSIONLESS diagnostic histograms (their values are fractions;
     rendering them as milliseconds would lie)."""
