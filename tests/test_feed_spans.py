@@ -267,16 +267,19 @@ STEP_SCOPES = FORWARD_SCOPES | {
     "tfr.table_scatter"}
 
 
-def _small_dlrm():
-    cfg = DLRMConfig(vocab_size=64, embed_dim=16, bottom_mlp=(32, 16), top_mlp=(32, 1),
-                     interaction="dot")
+def _small_dlrm(embed_dim=16):
+    cfg = DLRMConfig(vocab_size=64, embed_dim=embed_dim, bottom_mlp=(32, embed_dim),
+                     top_mlp=(32, 1), interaction="dot")
     return cfg, init_params(jax.random.PRNGKey(0), cfg), make_synthetic_batch(cfg, 32)
 
 
-@pytest.mark.parametrize("program,scopes", [("forward", FORWARD_SCOPES),
-                                            ("sparse_train_step", STEP_SCOPES)])
-def test_the_compiled_program_holds_every_scope(program, scopes):
-    cfg, params, batch = _small_dlrm()
+@pytest.mark.parametrize("program,scopes,embed_dim", [
+    ("forward", FORWARD_SCOPES, 16),
+    ("sparse_train_step", STEP_SCOPES, 16),
+    ("sparse_train_step", STEP_SCOPES, 128),    # the update in its loop over blocks of slots
+])
+def test_the_compiled_program_holds_every_scope(program, scopes, embed_dim):
+    cfg, params, batch = _small_dlrm(embed_dim)
     if program == "forward":
         lowered = jax.jit(functools.partial(forward, cfg=cfg)).lower(params, batch)
     else:

@@ -1,6 +1,7 @@
 """Tests for the flagship DLRM consumer + the driver entry points on the
 8-device CPU mesh (dp x tp x sp shardings compile and execute)."""
 
+import dataclasses
 import functools
 
 import jax
@@ -170,6 +171,10 @@ class TestSparseTrainStep:
 
     CFG = DLRMConfig(num_dense=4, num_categorical=3, vocab_size=64, embed_dim=4,
                      bottom_mlp=(8, 4), top_mlp=(8, 1), dtype=jax.numpy.float32)
+    #: rows by whether they fill whole 128-lane tiles: "wide" rows take the
+    #: update's loop over blocks of B slots, "narrow" ones one block of N
+    #: slots and no loop (dlrm._block_slots)
+    ROWS = {"narrow_rows": CFG, "wide_rows": dataclasses.replace(CFG, embed_dim=128)}
 
     # the oracle (full dense table gradient + row-wise AdaGrad applied
     # densely) lives beside the step it checks; chip_smoke.py shares it
@@ -298,7 +303,8 @@ class TestSparseTrainStep:
         runs, run_of = np.unique(
             np.stack([f_np, v_np])[:, want_order], axis=1, return_inverse=True)
         empty = 96 - runs.shape[1]
-        for order, slot, uf, uv in (flat, pairs):
+        for order, slot, uf, uv, n_runs in (flat, pairs):
+            assert n_runs == runs.shape[1]
             np.testing.assert_array_equal(order, want_order)
             # the runs take the last slots in order; every slot before them
             # indexes no table, even after NumPy's wrap-around
@@ -329,8 +335,26 @@ class TestSparseTrainStep:
             np.asarray(got_p["embeddings"]), np.asarray(want_p["embeddings"])
         )
 
-    @staticmethod
-    def _keys_case(case: str, cfg, batch_size: int) -> np.ndarray:
+    #: distinct keys a column (R = their sum) for the loop's edges at
+    #: B = 48, F = 3: the update walks blocks of B slots from the back
+    _RUNS_A_COLUMN = {
+        "runs_fill_one_block": (16, 16, 16),
+        "runs_fill_two_blocks": (32, 32, 32),
+        "one_run_past_a_block": (16, 16, 17),   # one real key behind B - 1 empty slots
+    }
+    #: case -> (R, blocks of B = 48 slots the update walks)
+    _KEYS_CASES = {
+        "every_key_distinct": (144, 3),
+        "one_key_a_column": (3, 1),
+        "extreme_keys": (None, None),
+        "heavy_duplication": (9, 1),
+        "runs_fill_one_block": (48, 1),
+        "runs_fill_two_blocks": (96, 2),
+        "one_run_past_a_block": (49, 2),
+    }
+
+    @classmethod
+    def _keys_case(cls, case: str, cfg, batch_size: int) -> np.ndarray:
         """[B, F] keys for the ends of the run compaction and its edges."""
         n_f, n_v = cfg.num_categorical, cfg.vocab_size
         rng = np.random.default_rng(41)
@@ -345,25 +369,32 @@ class TestSparseTrainStep:
             cat[1::4, 0] = 0
             cat[1, 0] = n_v - 1
             return cat
+        if case in cls._RUNS_A_COLUMN:              # every chosen key is drawn at least once
+            return np.stack(
+                [rng.permutation(np.resize(rng.choice(n_v, size=c, replace=False), batch_size))
+                 for c in cls._RUNS_A_COLUMN[case]], axis=1)
         assert case == "heavy_duplication"
         return rng.integers(0, 3, size=(batch_size, n_f))
 
+    @pytest.mark.parametrize("rows", list(ROWS))
     @pytest.mark.parametrize("sort_path", ["flat_keys", "pair_sort"])
-    @pytest.mark.parametrize(
-        "case", ["every_key_distinct", "one_key_a_column", "extreme_keys", "heavy_duplication"])
-    def test_runs_compacted_match_dense_oracle(self, case, sort_path, monkeypatch):
+    @pytest.mark.parametrize("case", list(_KEYS_CASES))
+    def test_runs_compacted_match_dense_oracle(self, case, sort_path, rows, monkeypatch):
         """What exists once per unique row is kept once per run, at the
         run's slot, and the empty slots carry keys that index no table. The
-        two ends (no empty slot; all but F empty), the least and the largest
-        real key (neither may be taken for an empty slot's), and heavy
-        duplication, against the dense oracle; rows and accumulators no key
-        names equal the old ones bit for bit."""
+        two ends (no empty slot: every block walked; all but F empty: one
+        block), the loop's edges between them (the runs fill one block, two
+        blocks, one block and one slot of the next), the least and the
+        largest real key (neither may be taken for an empty slot's), and
+        heavy duplication, against the dense oracle; rows and accumulators
+        no key names equal the old ones bit for bit. With wide rows through
+        the loop, with narrow rows through one block of all N slots."""
         from tpu_tfrecord.models import sparse_opt_init, sparse_train_step
         from tpu_tfrecord.models import dlrm as dlrm_mod
 
         if sort_path == "pair_sort":
             monkeypatch.setattr(dlrm_mod, "_FLAT_KEY_MAX", 1)
-        cfg = self.CFG
+        cfg = self.ROWS[rows]
         params = init_params(jax.random.key(14), cfg)
         host = make_synthetic_batch(cfg, 48, seed=43)
         host["cat"] = self._keys_case(case, cfg, 48)
@@ -372,7 +403,7 @@ class TestSparseTrainStep:
         opt0 = sparse_opt_init(params, cfg, tx)
         # a state that has been trained on: an untouched accumulator is not zero
         opt0 = opt0._replace(accum=jax.random.uniform(
-            jax.random.key(15), opt0.accum.shape, jax.numpy.float32, 0.0, 1e-3))
+            jax.random.key(15), opt0.accum.shape, jax.numpy.float32, 0.0, 1e-6))
         got_p, got_s, got_l = jax.jit(
             functools.partial(sparse_train_step, cfg=cfg, tx=tx)
         )(params, opt0, batch)
@@ -386,12 +417,11 @@ class TestSparseTrainStep:
         )
         touched = np.zeros((cfg.num_categorical, cfg.vocab_size), bool)
         touched[np.arange(cfg.num_categorical)[None, :], host["cat"]] = True
-        if case == "every_key_distinct":
-            assert touched.sum() == 48 * cfg.num_categorical
-        if case == "one_key_a_column":
-            assert touched.sum() == cfg.num_categorical
+        n_runs, _ = self._KEYS_CASES[case]
         if case == "extreme_keys":
             assert touched[0, 0] and touched[-1, -1]
+        else:
+            assert touched.sum() == n_runs
         moved = np.asarray(got_s.accum) != np.asarray(opt0.accum)
         np.testing.assert_array_equal(moved, touched)
         np.testing.assert_array_equal(
@@ -399,6 +429,55 @@ class TestSparseTrainStep:
             np.asarray(params["embeddings"])[~touched])
         assert (np.asarray(got_p["embeddings"])[touched]
                 != np.asarray(params["embeddings"])[touched]).any(axis=-1).all()
+
+    @pytest.mark.parametrize(
+        "case", [c for c, (n_runs, _) in _KEYS_CASES.items() if n_runs is not None])
+    def test_the_update_walks_only_the_blocks_that_hold_runs(self, case, monkeypatch):
+        """The loop over the table's update (the accumulators read back, the
+        scale, the row scatter) takes blocks of B slots (the batch's rows: F
+        of them make N) and as many as hold runs, ceil(R / B): F for a batch
+        of distinct keys, one when the runs just fill a block, two for one
+        run more."""
+        from tpu_tfrecord.models import sparse_opt_init, sparse_train_step
+        from tpu_tfrecord.models import dlrm as dlrm_mod
+
+        cfg, batch_size = self.ROWS["wide_rows"], 48
+        n_runs, n_blocks = self._KEYS_CASES[case]
+        host = make_synthetic_batch(cfg, batch_size, seed=43)
+        host["cat"] = self._keys_case(case, cfg, batch_size)
+        f_flat = jax.numpy.tile(jax.numpy.arange(cfg.num_categorical), batch_size)
+        got_runs = dlrm_mod._dedup_sort(
+            f_flat, jax.numpy.asarray(host["cat"]).reshape(-1), cfg.vocab_size)[-1]
+        assert int(got_runs) == n_runs
+        assert int(dlrm_mod._blocks_with_runs(got_runs, batch_size)) == n_blocks
+        assert n_blocks == -(-n_runs // batch_size)
+
+        asked = []
+        helper = dlrm_mod._blocks_with_runs
+
+        def recording(n_runs, block):
+            asked.append(block)
+            return helper(n_runs, block)
+
+        monkeypatch.setattr(dlrm_mod, "_blocks_with_runs", recording)
+        params = init_params(jax.random.key(14), cfg)
+        tx = optax.sgd(1e-2)
+        jax.make_jaxpr(functools.partial(sparse_train_step, cfg=cfg, tx=tx))(
+            params, sparse_opt_init(params, cfg, tx),
+            {k: jax.numpy.asarray(v) for k, v in host.items()})
+        assert asked == [batch_size]        # the step's trip count is the helper's
+
+    def test_a_block_is_the_batch_where_rows_fill_lane_tiles(self):
+        """The block is a function of the step's shapes alone: B slots where
+        a row is whole 128-lane tiles (the table lies row-major on a TPU and
+        the loop carries it in place), all N slots otherwise (the compiler
+        relayouts a table of narrower rows around every scatter call)."""
+        from tpu_tfrecord.models import dlrm as dlrm_mod
+
+        assert dlrm_mod._block_slots(16384, 16384 * 26, 128) == 16384
+        assert dlrm_mod._block_slots(48, 144, 256) == 48
+        for d in (4, 16, 32, 64, 96, 192):
+            assert dlrm_mod._block_slots(16384, 16384 * 26, d) == 16384 * 26
 
     def test_an_index_outside_the_table_trains_the_row_it_reads(self):
         """The dedup keys are built from the indices, so an index outside
@@ -429,14 +508,18 @@ class TestSparseTrainStep:
             np.asarray(got_p["embeddings"]), np.asarray(want_p["embeddings"]))
         assert float(got_s.accum[0, v - 1]) > 0 and float(got_s.accum[2, 0]) > 0
 
+    @pytest.mark.parametrize("rows", list(ROWS))
     @pytest.mark.parametrize("sort_path", ["flat_keys", "pair_sort"])
     def test_the_step_gathers_and_scatters_only_what_the_update_needs(
-        self, sort_path, monkeypatch
+        self, sort_path, rows, monkeypatch
     ):
         """The passes over the batch's N keys that only moved bookkeeping
         stay deleted: the step's jaxpr holds three gathers (the lookup, the
         row gradients into sorted order, the accumulators read back) and
-        three scatter-adds (the segment sum, the accumulator, the table),
+        three scatter-adds (the segment sum, the accumulator, the table;
+        with wide rows the read-back and the table's scatter in the body of
+        the one loop over the blocks of slots that hold runs, with narrow
+        rows no loop),
         and no gather reads an [N]-long array (the keys come out of sorts;
         run lengths and per-element views of the run sums are gone)."""
         from tpu_tfrecord.models import sparse_opt_init, sparse_train_step
@@ -444,7 +527,7 @@ class TestSparseTrainStep:
 
         if sort_path == "pair_sort":
             monkeypatch.setattr(dlrm_mod, "_FLAT_KEY_MAX", 1)
-        cfg = self.CFG
+        cfg = self.ROWS[rows]
         params = init_params(jax.random.key(17), cfg)
         batch = {k: jax.numpy.asarray(a) for k, a in make_synthetic_batch(cfg, 40, seed=47).items()}
         tx = optax.sgd(1e-2)
@@ -467,12 +550,14 @@ class TestSparseTrainStep:
         assert sorted(scatters) == sorted([(n, d), accum, table])
         assert not [e for e in eqns if e.primitive.name == "scatter"]
         assert sum(e.primitive.name == "sort" for e in eqns) == 2
+        assert sum(e.primitive.name == "while" for e in eqns) == (rows == "wide_rows")
 
-    def test_sharded_sparse_step_matches_single_device(self):
+    @pytest.mark.parametrize("rows", list(ROWS))
+    def test_sharded_sparse_step_matches_single_device(self, rows):
         from tpu_tfrecord.models import sparse_opt_init, sparse_train_step
         from tpu_tfrecord.models.dlrm import batch_shardings
 
-        cfg = self.CFG
+        cfg = self.ROWS[rows]
         params = init_params(jax.random.key(8), cfg)
         host = make_synthetic_batch(cfg, 16, seed=17)
         batch1 = {k: jax.numpy.asarray(v) for k, v in host.items()}

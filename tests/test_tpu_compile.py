@@ -127,9 +127,20 @@ class TestOneChip:
         assert mem.argument_size_in_bytes > table_bytes         # the table is in
         assert mem.temp_size_in_bytes < table_bytes // 8
 
-    def test_sparse_train_step_at_the_bench_config_fits_hbm(self, one_chip):
-        """The one long compile (~60 s): 26 x 2^20 x 32 f32 tables, B=16,384."""
-        cfg = chip_smoke.criteo_dlrm_config(chip_smoke.FULL["vocab"])
+    @pytest.mark.parametrize("config", ["chip_smoke", "mlperf_widths"])
+    def test_sparse_train_step_at_the_bench_config_fits_hbm(self, one_chip, config):
+        """The two long compiles (~30-60 s each), B=16,384: chip_smoke's
+        26 x 2^20 x 32 f32 tables, whose narrow rows keep the update in one
+        block (the compiler holds a relayout of such a table as a
+        temporary), and the benchmark's 26 x 2^19 x 128, where the loop over
+        the blocks of slots carries the donated table in place: the compiled
+        step holds no second one."""
+        if config == "chip_smoke":
+            cfg = chip_smoke.criteo_dlrm_config(chip_smoke.FULL["vocab"])
+        else:
+            cfg = DLRMConfig(num_dense=13, num_categorical=26, vocab_size=1 << 19,
+                             embed_dim=128, bottom_mlp=(512, 256, 128),
+                             top_mlp=(1024, 1024, 512, 256, 1), interaction="dot")
         tx = optax.sgd(1e-3)
         params = jax.eval_shape(lambda: dlrm_init(jax.random.key(0), cfg))
         opt = jax.eval_shape(lambda p: sparse_opt_init(p, cfg, tx), params)
@@ -147,8 +158,13 @@ class TestOneChip:
             _shaped(batch, one_chip),
         ).compile()
         mem = compiled.memory_analysis()
-        assert mem.argument_size_in_bytes > 26 * (1 << 20) * 32 * 4  # tables are in
+        table = params["embeddings"]
+        table_bytes = table.size * table.dtype.itemsize
+        assert mem.argument_size_in_bytes > table_bytes             # tables are in
         assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+        assert mem.alias_size_in_bytes >= table_bytes               # and donated
+        if config == "mlperf_widths":
+            assert mem.temp_size_in_bytes < table_bytes // 2
 
     def test_lm_train_step_on_dp(self, topo):
         """examples/train_lm.py's widths on a one-device ``data`` mesh."""

@@ -216,7 +216,7 @@ _NO_KEY = np.iinfo(np.int32).min
 
 def _dedup_sort(f_flat, v_flat, vocab: int, force_pairs: bool = False):
     """Sorted grouping for the dedup-first embedding update: returns
-    (order, slot, uf, uv). ``order`` sorts the flattened (f, v) element
+    (order, slot, uf, uv, R). ``order`` sorts the flattened (f, v) element
     list lexicographically. Per run of equal pairs in that order: ``slot``
     is each sorted element's run, numbered so that the R runs take the LAST
     R of N slots in order, and ``uf``/``uv`` hold each slot's pair; the
@@ -255,7 +255,26 @@ def _dedup_sort(f_flat, v_flat, vocab: int, force_pairs: bool = False):
         ukey = jnp.sort(jnp.where(run_start, skey, _NO_KEY))
         uf, uv = ukey // vocab, ukey % vocab
     runs = jnp.cumsum(run_start)                # 1-based run of each element
-    return order, runs + (n - 1 - runs[-1]), uf, uv
+    n_runs = runs[-1]
+    return order, runs + (n - 1 - n_runs), uf, uv, n_runs
+
+
+def _block_slots(batch: int, n: int, d: int) -> int:
+    """Slots a block of ``sparse_train_step``'s table update takes, from the
+    step's shapes alone: the batch's B rows (F blocks make the N slots)
+    where a table row fills whole 128-lane tiles. There the table lies
+    row-major on a TPU and the loop over the blocks carries it in place. A
+    narrower row (D = 16, 32, 64 compiled for a v5e: PERF.md §6, PR 30)
+    makes the compiler keep the table V-minor and relayout all of it around
+    a scatter, once a step around one scatter but once an ITERATION inside a
+    loop: those shapes keep one block of all N slots and no loop."""
+    return batch if d % 128 == 0 else n
+
+
+def _blocks_with_runs(n_runs, block: int):
+    """How many blocks of ``block`` slots, counted from the back of the
+    slot arrays, hold the ``n_runs`` runs ``_dedup_sort`` laid there."""
+    return (n_runs + block - 1) // block
 
 
 def sparse_train_step(
@@ -289,11 +308,21 @@ def sparse_train_step(
     is gathered back to the elements: the segment sum lays each run's
     summed gradient at the run's slot of an [N, D] array, ``_dedup_sort``
     lays its (f, v) pair at the same slot of the key arrays, so
-    mean((sum g)^2), the accumulator's new value, the scale and the row's
-    update are dense passes over the slots and reach the accumulator and
-    the table in one scatter each. The slots that hold no run hold zeros
-    under keys that index no table, which a scatter drops. A row no key
-    names is untouched bit for bit. Non-embedding params go through the
+    mean((sum g)^2) is a dense pass over the slots and reaches the
+    accumulator in one scatter; the scale and the row's update are dense
+    passes over a block of B slots and reach the table in one scatter a
+    block, inside the loop over the blocks that hold runs. The R runs lie
+    in the last R slots: the loop walks blocks from the back and stops after
+    ceil(R / B), and the scatter inside it promises no order, so it pays per
+    index OFFERED (written or dropped) where a promised one passes over the
+    whole table a call: the table's update costs per distinct row, not per
+    element (PERF.md §6, PR 30; rows narrower than a lane tile keep one
+    promised call over all N slots and no loop: ``_block_slots``). The loop
+    carries the table alone: a TPU's ``while`` that carries the accumulators
+    beside a 7 GB table loses the writes to both. The slots that hold no run
+    hold zeros under keys that index no table, which a scatter drops; only
+    the block that straddles the first run offers any to the table. A row no
+    key names is untouched bit for bit. Non-embedding params go through the
     wrapped optax transform unchanged.
 
     Jit this whole function (donate params + opt_state)."""
@@ -323,7 +352,7 @@ def sparse_train_step(
         n = idx.shape[0] * fdim
         f_flat = jnp.broadcast_to(f_ix, idx.shape).reshape(n)   # [N] feature id
         v_flat = idx.reshape(n)                                 # [N] vocab row
-        order, slot, uf, uv = _dedup_sort(
+        order, slot, uf, uv, n_runs = _dedup_sort(
             f_flat, v_flat, vocab, force_pairs=fdim * vocab > _FLAT_KEY_MAX
         )
         sg = g_rows.reshape(n, d)[order]
@@ -342,10 +371,31 @@ def sparse_train_step(
     with jax.named_scope("tfr.accum_update"):
         ms_run = jnp.mean(g_run * g_run, axis=-1)           # [N], once per run
         accum = opt_state.accum.at[uf, uv].add(ms_run, indices_are_sorted=True)
-        # post-accumulation scale, once per run (an empty slot reads some row's)
-        scale = embed_lr * jax.lax.rsqrt(accum[uf, uv] + embed_eps)     # [N]
-    with jax.named_scope("tfr.table_scatter"):
-        table = table.at[uf, uv].add(-scale[:, None] * g_run, indices_are_sorted=True)
+    block = _block_slots(idx.shape[0], n, d)
+    one_call = block == n
+
+    def update_block(i, table):
+        # Blocks count from the BACK, where the runs lie. Runs are distinct
+        # rows: no two blocks meet in a row, and a run's accumulator is final
+        # once the scatter above has run.
+        start = n - (i + 1) * block
+        with jax.named_scope("tfr.accum_update"):
+            bf = jax.lax.dynamic_slice_in_dim(uf, start, block)
+            bv = jax.lax.dynamic_slice_in_dim(uv, start, block)
+            # post-accumulation scale, once per run (an empty slot reads some row's)
+            scale = embed_lr * jax.lax.rsqrt(accum[bf, bv] + embed_eps)     # [block]
+        with jax.named_scope("tfr.table_scatter"):
+            g_blk = jax.lax.dynamic_slice_in_dim(g_run, start, block)
+            # The promise of sorted indices buys a scatter that passes over
+            # the whole table once a call; without it a call pays per index
+            # offered (PERF.md §6, PR 30): one call over all N slots keeps
+            # the promise, a block of them does not make it.
+            return table.at[bf, bv].add(-scale[:, None] * g_blk, indices_are_sorted=one_call)
+
+    if one_call:
+        table = update_block(0, table)
+    else:
+        table = jax.lax.fori_loop(0, _blocks_with_runs(n_runs, block), update_block, table)
     params = dict(dense_params, embeddings=table)
     return params, SparseEmbOptState(new_dense_state, accum), loss
 

@@ -62,8 +62,11 @@ ANNOTATIONS = {
     "tfr.dense_update": "sparse_train_step: optimizer update of the MLPs",
     "tfr.dedup_sort": "sparse_train_step: keys sorted, each run's key sorted to its slot, row gradients reordered",
     "tfr.segment_sum": "sparse_train_step: duplicate keys' gradients summed, each run's sum at its slot",
-    "tfr.accum_update": "sparse_train_step: AdaGrad accumulator scatter, gather, rsqrt",
-    "tfr.table_scatter": "sparse_train_step: the row updates scattered into the table",
+    "tfr.accum_update": "sparse_train_step: AdaGrad accumulator scatter over all slots; its "
+                        "read-back and rsqrt per block of slots, inside the loop over the "
+                        "blocks that hold runs",
+    "tfr.table_scatter": "sparse_train_step: the row updates scattered into the table, per block "
+                         "of slots, inside the loop over the blocks that hold runs",
     "tfr.embed": "pattern LM (models.lm.score): the token rows gathered from the embedding",
     "tfr.gqa": "pattern LM: the softmax layer (norm, projections, blockwise attention, gate, out)",
     "tfr.kda_proj": "pattern LM: the delta-rule layer's projections, decay, beta, gates, norm, out",
