@@ -1,0 +1,343 @@
+"""The latent-attention decoder (``models.lm.score`` with ``mla`` mixers, a
+leading dense layer and a router whose bias picks and never weighs) against
+its plain reference, at sizes a CPU walks in seconds: the mixer, the dense
+layer, the biased router and the 1 + 2-layer model; a packed row against
+each of its documents alone and the positions that restart at a boundary;
+the Pallas kernel for 192-wide keys against 128-wide values, interpreted,
+against the plain path; the shares of 64 experts held as 8 x 8 against the
+uncut layer; and the pattern model's older configurations left as they were."""
+
+import hashlib
+import inspect
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_tfrecord.models import lm, mla_reference as ref, moe
+from tpu_tfrecord.models.attention import blockwise_attention, flash_attention_widths
+
+from test_pattern_lm import documents_of, flat, packed_rows as older_rows, reference_weights
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: a configuration with the published names, tiny: one dense layer, two expert layers
+CFG = {
+    "hidden_size": 32, "num_hidden_layers": 3, "first_k_dense_replace": 1,
+    "num_attention_heads": 4, "qk_nope_head_dim": 8, "qk_rope_head_dim": 4, "v_head_dim": 8,
+    "kv_lora_rank": 16, "rope_theta": 800000, "intermediate_size": 48,
+    "n_routed_experts": 16, "n_routed_experts_held": 16, "held_offset": 0,
+    "num_experts_per_tok": 3, "moe_intermediate_size": 16, "n_shared_experts": 2,
+    "routed_scaling_factor": 2.446, "rms_norm_eps": 1e-5, "vocab_size": 64,
+}
+L = 48
+
+
+def program_cfg(cfg=CFG, dtype=jnp.float32, **cut):
+    cut = {"attn_block": 16, "expert_tile": 8, "head_block": 32, **cut}
+    n = cfg["num_hidden_layers"]
+    return lm.PatternLMConfig(
+        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"], layer_pattern=("mla",) * n,
+        ffn_pattern=tuple(ref.ffn_kinds(cfg)), n_heads=cfg["num_attention_heads"],
+        qk_nope_dim=cfg["qk_nope_head_dim"], qk_rope_dim=cfg["qk_rope_head_dim"],
+        v_head_dim=cfg["v_head_dim"], kv_rank=cfg["kv_lora_rank"],
+        rope_theta=float(cfg["rope_theta"]), d_dense=cfg["intermediate_size"],
+        n_experts=cfg["n_routed_experts"], experts_held=cfg["n_routed_experts_held"],
+        held_offset=cfg["held_offset"], top_k=cfg["num_experts_per_tok"],
+        d_expert=cfg["moe_intermediate_size"], n_shared=cfg["n_shared_experts"],
+        routed_scale=cfg["routed_scaling_factor"], router_bias=True,
+        norm_eps=cfg["rms_norm_eps"], max_len=L, dtype=dtype, **cut)
+
+
+def packed_rows():
+    return older_rows()[0]
+
+
+@pytest.fixture(scope="module")
+def params():
+    p = lm.pattern_init_params(jax.random.PRNGKey(3), program_cfg())
+    for layer in p["layers"][1:]:  # a bias large enough to change who is chosen
+        layer["router_bias"] = layer["router_bias"] * 4.0
+    return p
+
+
+@pytest.fixture(scope="module")
+def scored(params):
+    batch, cfg = packed_rows(), program_cfg()
+    sample_at = jnp.asarray([[0, 5, 19, 25], [2, 8, 29, 40]], jnp.int32)
+    out = jax.jit(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
+        params, batch["tokens"], batch["segment_ids"], sample_at, jnp.int32(1))
+    return batch, sample_at, jax.tree.map(np.asarray, out)
+
+
+def test_the_parameters_are_the_models(params):
+    first, later = params["layers"][0], params["layers"][1]
+    assert set(first) == {"attn_norm", "wq", "wkv_a", "kv_norm", "wkv_b", "wo", "ffn_norm", "dense"}
+    assert {"router", "router_bias", "moe_norm", "shared", "w_gate"} <= set(later)
+    assert first["wq"].shape == (32, 4 * 12) and first["wkv_a"].shape == (32, 16 + 4)
+    assert first["wkv_b"].shape == (16, 4 * 16) and first["wo"].shape == (4 * 8, 32)
+    assert first["dense"]["w_gate"].shape == (32, 48)
+    assert later["shared"]["w_gate"].shape == (32, 2 * 16)      # two shared experts, one unit
+    assert later["router_bias"].shape == (16,) and later["router_bias"].dtype == jnp.float32
+
+
+def test_a_packed_row_scores_each_document_as_the_reference_scores_it_alone(params, scored):
+    batch, sample_at, out = scored
+    docs = documents_of(batch)
+    at = [[int(p) - start for p in np.asarray(sample_at)[r]
+           if start <= p < start + len(doc) - 1] for r, start, doc in docs]
+    want = ref.reference_score(CFG, [d for _, _, d in docs], reference_weights(params), at)
+    covered, seen = np.zeros_like(out["logprob"], bool), 0
+    for (r, start, doc), logp, places, logits in zip(docs, want["logprob"], at, want["logits"]):
+        np.testing.assert_allclose(out["logprob"][r, start:start + len(doc) - 1], logp, atol=2e-4)
+        covered[r, start:start + len(doc) - 1] = True
+        for p, w in zip(places, logits):
+            s = list(np.asarray(sample_at)[r]).index(p + start)
+            np.testing.assert_allclose(out["logits"][r, s], w, atol=3e-4)
+            seen += 1
+    assert (out["logprob"][~covered] == 0).all() and covered.sum() > 80 and seen >= 6
+    # two expert layers report, the dense one has no experts to visit
+    assert out["visits"].shape == (2, 16) and out["dropped"].sum() == 0
+    real = int((batch["segment_ids"][:, :-1] != 0).sum())
+    assert (out["visits"].sum(axis=1) == real * CFG["num_experts_per_tok"]).all()
+    assert out["probes"]["scan"] == {} and out["probes"]["router"]["u"].shape == (2, 2, 4, 32)
+
+
+def test_the_mixer_against_the_reference(params):
+    cfg, layer = program_cfg(), params["layers"][1]
+    x = jnp.asarray(np.random.default_rng(5).standard_normal((1, L, 32)), jnp.float32)
+    got = lm.mla_mixer(layer, x, jnp.ones((1, L), jnp.int32), cfg)
+    with jax.default_matmul_precision("highest"):
+        p = flat(layer)
+        want = ref.ref_mla(p, ref.ref_norm(x[0], p["attn_norm"], 1e-5), CFG)
+    np.testing.assert_allclose(got[0], want, atol=2e-5)
+
+
+def test_positions_restart_at_every_document():
+    segs = jnp.asarray([[1, 1, 1, 2, 2, 3, 0, 0], [1, 2, 2, 2, 2, 2, 2, 0]], jnp.int32)
+    got = np.asarray(lm.segment_positions(segs))
+    assert got.tolist() == [[0, 1, 2, 0, 1, 0, 0, 0], [0, 0, 1, 2, 3, 4, 5, 0]]
+    assert (got != np.arange(8)).any()                 # not the index in the row
+
+
+def test_a_document_in_a_row_is_the_document_alone_and_its_keys_count_from_its_start(params):
+    """A row of three documents through the mixer against each alone; rotary
+    scores depend on the DIFFERENCE of two positions, so a document whose
+    positions are all shifted reads the same to rounding, and the fault that
+    shows is a restart on one side only (the reference's ``key_start``)."""
+    cfg, layer = program_cfg(), params["layers"][0]
+    x = jnp.asarray(np.random.default_rng(6).standard_normal((1, L, 32)), jnp.float32)
+    segs = jnp.asarray([[1] * 17 + [2] * 20 + [3] * 9 + [0] * 2], jnp.int32)
+    row = lm.mla_mixer(layer, x, segs, cfg)[0]
+    shifted = lm.mla_mixer(layer, x[:, 17:37], jnp.ones((1, 20), jnp.int32), cfg)[0]
+    np.testing.assert_allclose(row[17:37], shifted, atol=2e-5)
+    with jax.default_matmul_precision("highest"):
+        p = flat(layer)
+        u = ref.ref_norm(x[0, 17:37], p["attn_norm"], 1e-5)
+        alone, one_sided = ref.ref_mla(p, u, CFG), ref.ref_mla(p, u, CFG, key_start=17)
+    np.testing.assert_allclose(row[17:37], alone, atol=2e-5)
+    assert np.abs(one_sided - alone).max() > 1e-2
+    # and a mask that let the second document see the first would show too
+    whole = lm.mla_mixer(layer, x, jnp.ones((1, L), jnp.int32), cfg)[0]
+    assert np.abs(whole[17:37] - row[17:37]).max() > 1e-2
+
+
+def test_rotary_pairs_turn_by_the_position_times_the_frequency():
+    x = jnp.asarray(np.random.default_rng(2).standard_normal((1, 2, 5, 8)), jnp.float32)
+    at = jnp.asarray([[0, 1, 2, 7, 100]], jnp.int32)
+    got = np.asarray(lm.rotary(x, at, 800000.0))
+    np.testing.assert_allclose(got[:, :, 0], x[:, :, 0], atol=1e-7)       # position 0: unturned
+    for i in range(4):
+        angle = np.asarray(at[0], np.float64) * 800000.0 ** (-i / 4)
+        a, b = np.asarray(x[0, 1, :, i], np.float64), np.asarray(x[0, 1, :, i + 4], np.float64)
+        np.testing.assert_allclose(got[0, 1, :, i], a * np.cos(angle) - b * np.sin(angle), atol=2e-5)
+        np.testing.assert_allclose(got[0, 1, :, i + 4], b * np.cos(angle) + a * np.sin(angle), atol=2e-5)
+    want = ref.ref_rope(jnp.swapaxes(x[0], 0, 1), at[0], 800000.0)
+    np.testing.assert_allclose(jnp.swapaxes(got[0], 0, 1), want, atol=1e-6)
+
+
+def test_the_dense_layer_against_the_reference(params):
+    """Layer 0 whole: the mixer, then the dense gated unit, and no expert."""
+    cfg = lm.PatternLMConfig(**{**program_cfg().__dict__, "layer_pattern": ("mla",),
+                                "ffn_pattern": ("dense",)})
+    one = {**params, "layers": params["layers"][:1]}
+    tokens = jnp.asarray(np.random.default_rng(8).integers(1, 64, (1, L + 1)), jnp.int32)
+    x, visits, dropped, probes = lm.pattern_hidden(one, tokens, jnp.ones_like(tokens), cfg)
+    assert visits.shape == (0, 16) and dropped.shape == (0,) and "router" not in probes
+    with jax.default_matmul_precision("highest"):
+        x0 = jnp.asarray(params["embed"], jnp.float32)[tokens[0, :-1]]
+        want, u = ref.ref_layer_front("dense", flat(params["layers"][0]), x0, CFG)
+    assert u is None
+    np.testing.assert_allclose(x[0], want, atol=3e-5)
+
+
+@pytest.mark.parametrize("flip", [False, True])
+def test_the_bias_picks_and_never_weighs(flip):
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.standard_normal((40, 32)), jnp.float32)
+    router = jnp.asarray(rng.standard_normal((32, 16)) * 32 ** -0.5, jnp.float32)
+    plain_e, plain_g = moe.route_top_k(x, router, 3, 2.446)
+    scores = np.asarray(jax.nn.sigmoid(jnp.dot(x, router, precision="highest")), np.float64)
+    bias = np.zeros(16, np.float32)
+    if flip:  # lift each token's fourth choice of the first ten tokens over its third
+        order = np.argsort(-scores, axis=1)
+        gap = scores[np.arange(40), order[:, 2]] - scores[np.arange(40), order[:, 3]]
+        bias[order[0, 3]] = gap[0] + 1e-3
+    experts, gates = moe.route_top_k(x, router, 3, 2.446, jnp.asarray(bias))
+    if not flip:  # a zero bias chooses as no bias does, and weighs the same
+        assert (np.asarray(experts) == np.asarray(plain_e)).all()
+        np.testing.assert_allclose(gates, plain_g, atol=1e-7)
+        return
+    assert set(np.asarray(experts)[0]) != set(np.asarray(plain_e)[0])
+    assert order[0, 3] in np.asarray(experts)[0]
+    # the gates are the chosen experts' SCORES renormalised: the bias is not in them
+    chosen = np.take_along_axis(scores, np.asarray(experts), axis=1)
+    np.testing.assert_allclose(gates, chosen / chosen.sum(axis=1, keepdims=True) * 2.446, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(gates).sum(axis=1), 2.446, atol=1e-5)
+    want_e, want_g = ref.ref_route_biased(x, router, jnp.asarray(bias), CFG)
+    assert (np.asarray(want_e) == np.asarray(experts)).all()
+    np.testing.assert_allclose(gates, want_g, atol=1e-6)
+
+
+def test_the_references_router_takes_its_controls():
+    """Without the bias another expert is chosen somewhere; in bfloat16 the gates move."""
+    rng = np.random.default_rng(9)
+    u = jnp.asarray(rng.standard_normal((64, 32)), jnp.float32)
+    router = jnp.asarray(rng.standard_normal((32, 16)) * 32 ** -0.5, jnp.float32)
+    bias = jnp.asarray(rng.standard_normal(16) * 0.2, jnp.float32)
+    sound_e, sound_g = ref.ref_route_biased(u, router, bias, CFG)
+    plain_e, _ = ref.ref_route_biased(u, router, jnp.zeros(16), CFG)
+    low_e, low_g = ref.ref_route_biased(u, router, bias, CFG, jnp.bfloat16)
+    assert (np.asarray(sound_e) != np.asarray(plain_e)).any()
+    same = (np.asarray(sound_e) == np.asarray(low_e)).all(axis=1)
+    assert same.sum() > 32 and 1e-4 < np.abs(np.asarray(sound_g) - np.asarray(low_g))[same].max() < 0.05
+
+
+def test_bfloat16_stays_near_the_float32_program(params):
+    batch, sample_at, outs = packed_rows(), jnp.zeros((2, 1), jnp.int32), []
+    for dtype in (jnp.float32, jnp.bfloat16):
+        cfg = program_cfg(dtype=dtype)
+        p = jax.tree.map(lambda a, s: a.astype(s[1]), params, lm.pattern_param_shapes(cfg))
+        outs.append(np.asarray(lm.score(p, batch["tokens"], batch["segment_ids"], sample_at,
+                                        cfg)["logprob"]))
+    assert 0 < np.abs(outs[0] - outs[1]).max() < 0.25
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (512, 256)])
+def test_the_kernel_for_wide_keys_is_blockwise_attention(blocks):
+    """``lm._attend`` runs ``flash_attention_widths`` on a TPU for 192-wide
+    queries and keys against 128-wide values, and ``blockwise_attention``
+    elsewhere: the kernel, interpreted here (on a chip, outside pytest's
+    conftest, this function runs it as it is), against the plain path on a
+    packed row whose one rotary key head serves every query head."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    r = np.random.default_rng(1)
+    q = jnp.asarray(r.standard_normal((1, 4, 512, 192)), jnp.float32)      # [B, H, L, D]
+    k = jnp.asarray(r.standard_normal((1, 2, 512, 192)), jnp.float32)
+    v = jnp.asarray(r.standard_normal((1, 2, 512, 128)), jnp.float32)
+    segs = np.zeros((1, 512), np.int32)
+    segs[0, :100], segs[0, 100:130], segs[0, 130:400] = 1, 2, 3
+    segs = jnp.asarray(segs)
+    want = jnp.swapaxes(blockwise_attention(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segs, block=64), 1, 2)
+    assert want.shape == (1, 4, 512, 128)
+    if jax.default_backend() == "tpu":
+        got = lm._attend(q, k, v, segs, blocks[0])
+    else:
+        with pltpu.force_tpu_interpret_mode():
+            got = flash_attention_widths(q, k, v, segs, 192 ** -0.5, *blocks)
+    real = np.asarray(segs[0] != 0)
+    np.testing.assert_allclose(np.asarray(got)[:, :, real], np.asarray(want)[:, :, real],
+                               atol=3e-2 if jax.default_backend() == "tpu" else 2e-5)
+
+
+def test_the_kernel_refuses_rows_that_are_not_whole_blocks():
+    q = jnp.zeros((1, 2, 384, 192))
+    with pytest.raises(ValueError, match="whole blocks"):
+        flash_attention_widths(q, q, jnp.zeros((1, 2, 384, 128)), jnp.ones((1, 384), jnp.int32),
+                               1.0, 256, 128)
+
+
+def test_the_shares_of_64_experts_held_8_by_8_add_up_to_the_uncut_layer():
+    """Eight chips of 8 experts each under the biased router, the two shared
+    experts counted once, against the reference told that it holds all 64."""
+    cfg = {**CFG, "n_routed_experts": 64, "n_routed_experts_held": 64, "num_experts_per_tok": 6}
+    p = lm.pattern_init_params(jax.random.PRNGKey(1), program_cfg(cfg))["layers"][1]
+    p["router_bias"] = p["router_bias"] * 4.0
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((96, 32)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        whole, _, (chosen, _) = ref.ref_moe_biased(flat(p), x, cfg)
+        unbiased, _, (plain, _) = ref.ref_moe_biased(flat(p), x, cfg, no_bias=True)
+        shared = ref.ref_ffn(x, *(jnp.asarray(p["shared"][k]) for k in ("w_gate", "w_up", "w_down")))
+    assert (np.asarray(chosen) != np.asarray(plain)).any()      # the bias is at work
+    total, visits = -7 * shared, 0
+    for first in range(0, 64, 8):
+        share = {**p, **{k: p[k][first:first + 8] for k in ("w_gate", "w_up", "w_down")}}
+        y, n, dropped, _ = moe.held_experts_apply(share, x, held_offset=first, top_k=6,
+                                                  routed_scale=2.446, tile=8)
+        total, visits = total + y, visits + int(n.sum())
+        assert int(dropped) == 0
+    assert visits == x.shape[0] * 6
+    np.testing.assert_allclose(total, whole, atol=3e-5)
+    assert np.abs(np.asarray(total) - np.asarray(unbiased)).max() > 1e-2
+
+
+def test_an_unknown_mixer_or_feed_forward_part_is_named_with_the_ones_there_are():
+    with pytest.raises(ValueError, match=r"\('gqa', 'kda', 'mla'\)"):
+        lm.pattern_param_shapes(lm.PatternLMConfig(layer_pattern=("mla", "rope")))
+    with pytest.raises(ValueError, match=r"\('moe', 'dense'\)"):
+        lm.pattern_param_shapes(lm.PatternLMConfig(layer_pattern=("mla",), ffn_pattern=("ffn",)))
+    with pytest.raises(ValueError, match="each of the 2 layers"):
+        lm.pattern_param_shapes(lm.PatternLMConfig(layer_pattern=("mla", "gqa"), ffn_pattern=("moe",)))
+
+
+#: sha256 of ``str(jax.make_jaxpr(lm.score))`` for test_pattern_lm's program at the
+#: commit before the latent-attention layer came (PR 30): the softmax / delta-rule
+#: pattern's program has to stay that one, operation for operation. A PR that
+#: means to change that program records its own.
+PATTERN_PROGRAM = "80e42af17080e7b14f19cef6c96e5055d12c4ba2668df85bfa2314a54e851896"
+
+
+def test_the_older_patterns_program_is_the_one_it_was():
+    import test_pattern_lm as older
+
+    cfg = older.program_cfg()
+    assert lm.ffn_kinds(cfg) == ("moe",) * 4 and not cfg.router_bias
+    params = lm.pattern_init_params(jax.random.PRNGKey(3), cfg)
+    assert all("router_bias" not in layer and "dense" not in layer for layer in params["layers"])
+    batch, _ = older.packed_rows()
+    at = jnp.asarray([[0, 5, 19, 25], [2, 8, 29, 40]], jnp.int32)
+    program = jax.make_jaxpr(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
+        params, batch["tokens"], batch["segment_ids"], at, jnp.int32(2))
+    assert hashlib.sha256(str(program).encode()).hexdigest() == PATTERN_PROGRAM
+
+
+def test_the_benchmarks_copy_of_the_reference_is_this_one():
+    sys.path.insert(0, ROOT)
+    from benchmark.models import kimi_vl_lm as copy
+
+    names = [n for n, f in inspect.getmembers(ref, inspect.isfunction)
+             if f.__module__ == ref.__name__]
+    assert "reference_score" in names and len(names) >= 8
+    for name in names:
+        assert inspect.getsource(getattr(ref, name)) == inspect.getsource(getattr(copy, name)), name
+    assert copy.HEAD_ROWS == ref.HEAD_ROWS
+
+
+def test_the_compiled_program_holds_every_scope(params):
+    import re
+
+    from tpu_tfrecord import tracing
+
+    batch, cfg = packed_rows(), program_cfg()
+    lowered = jax.jit(lambda p, t, s, a: lm.score(p, t, s, a, cfg)).lower(
+        params, batch["tokens"], batch["segment_ids"], jnp.zeros((2, 1), jnp.int32))
+    op_names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    held = {tok for name in op_names for tok in re.findall(r"tfr\.\w+", name)}
+    assert held == {"tfr.embed", "tfr.mla_proj", "tfr.mla_attn", "tfr.dense_ffn", "tfr.moe_route",
+                    "tfr.moe_experts", "tfr.moe_shared", "tfr.lm_head"}
+    assert held <= set(tracing.ANNOTATIONS)

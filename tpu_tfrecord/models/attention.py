@@ -39,11 +39,13 @@ balanced causal ring schedule — at the cost of one O(L*H*D) permute each
 way; callers keep the contiguous contract on both sides.
 `attention_reference` is the plain dense oracle used by the tests;
 `blockwise_attention` is the single-device causal path for packed rows too
-long for the oracle's `[B, H, L, L]` scores (models.lm's pattern model).
+long for the oracle's `[B, H, L, L]` scores (models.lm's pattern model), and
+`flash_attention_widths` its Pallas TPU kernel for keys wider than values.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -108,7 +110,8 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
     same answer as ``attention_reference(causal=True, segments=...)``
     without ever holding a ``[B, H, L, L]`` array. q [B, L, H, D], k/v
     [B, L, Hkv, D] (grouped: each K/V head serves H/Hkv query heads,
-    never repeated in memory), segments [B, L] -> [B, L, H, D] in q's dtype.
+    never repeated in memory; v may be of another width Dv than q and k),
+    segments [B, L] -> [B, L, H, Dv] in q's dtype.
 
     For each block of queries the key blocks at or before it (causal: the
     later ones are skipped when the program is built) are folded into a
@@ -118,7 +121,7 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
     sum untouched: every probability is multiplied by its mask, so a row
     that has seen nothing yet carries zeros, not exp(0)."""
     b, l, h, d = q.shape
-    hkv = k.shape[2]
+    hkv, dv = k.shape[2], v.shape[-1]
     if h % hkv:
         raise ValueError(f"GQA needs num_heads % num_kv_heads == 0 (got H={h}, Hkv={hkv})")
     scale = scale if scale is not None else d ** -0.5
@@ -131,7 +134,7 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
         shape = (b, hkv, h // hkv, q1 - q0)
         top = jnp.full(shape, _NEG)
         total = jnp.zeros(shape, jnp.float32)
-        acc = jnp.zeros(shape + (d,), jnp.float32)
+        acc = jnp.zeros(shape + (dv,), jnp.float32)
         for k0 in range(0, q1, block):
             k1 = min(k0 + block, l)
             scores = jnp.einsum("bqkgd,bmkd->bkgqm", qb, k[:, k0:k1],
@@ -147,8 +150,128 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
                 "bkgqm,bmkd->bkgqd", probs.astype(v.dtype), v[:, k0:k1],
                 preferred_element_type=jnp.float32)
             top = new_top
-        out.append(jnp.moveaxis(acc / total[..., None], 3, 1).reshape(b, q1 - q0, h, d))
+        out.append(jnp.moveaxis(acc / total[..., None], 3, 1).reshape(b, q1 - q0, h, dv))
     return jnp.concatenate(out, axis=1).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Packed rows on one TPU: a flash kernel whose keys are wider than its values
+# ---------------------------------------------------------------------------
+
+_LANES, _SUBLANES = 128, 8
+_MASKED = -1e30  # _NEG as a Python number: a kernel captures no array
+
+
+def _flash_widths_kernel(lo_ref, hi_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref, o_ref,
+                         m_ref, l_ref, acc_ref, *, scale: float, block_q: int, block_k: int):
+    """One (query block, key block) pair of one head: scores stay on the
+    chip, the running maximum and sum are kept 128 lanes wide (every lane
+    the same), the weighted values are divided by the sum once, at the last
+    key block. A key block wholly after the query block, or one that holds
+    no document of the query block's (``lo_ref`` / ``hi_ref``: the least and
+    the largest segment id of each block of ``block_k`` tokens), is skipped."""
+    from jax.experimental import pallas as pl
+
+    bi, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _first():
+        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(_pair_needed(lo_ref, hi_ref, bi, qi, ki, block_q, block_k))
+    def _pair():
+        scores = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32) * scale
+        rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+        cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        same = jnp.tile(qseg_ref[0], (1, block_k // _LANES)) == kseg_ref[0, :1]
+        # a row that has met no key of its document yet weighs what it sees by
+        # exp(0); the first real score sends that to exp(-1e30) = 0
+        scores = jnp.where(same & (cols <= rows), scores, _MASKED)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, scores.max(axis=1)[:, None])
+        probs = jnp.exp(scores - jnp.tile(m_next, (1, block_k // _LANES)))
+        keep = jnp.exp(m_prev - m_next)
+        l_ref[...] = l_ref[...] * keep + probs.sum(axis=1)[:, None]
+        m_ref[...] = m_next
+        v = v_ref[0, 0]
+        acc_ref[...] = acc_ref[...] * jnp.tile(keep, (1, v.shape[-1] // _LANES)) + jnp.dot(
+            probs.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _last():
+        total = jnp.tile(l_ref[...], (1, acc_ref.shape[-1] // _LANES))
+        o_ref[0, 0] = (acc_ref[...] / total).astype(o_ref.dtype)
+
+
+def _pair_needed(lo_ref, hi_ref, bi, qi, ki, block_q: int, block_k: int):
+    """Does key block ``ki`` hold a key some query of block ``qi`` may see:
+    one at or before the block's last query, of a document the block holds?
+    Two blocks whose ranges of segment ids are disjoint share no document,
+    whatever the order of the ids; a query's own key block always passes."""
+    per = block_q // block_k  # key-sized blocks a query block spans
+    q_lo, q_hi = lo_ref[bi, qi * per], hi_ref[bi, qi * per]
+    for j in range(1, per):
+        q_lo = jnp.minimum(q_lo, lo_ref[bi, qi * per + j])
+        q_hi = jnp.maximum(q_hi, hi_ref[bi, qi * per + j])
+    return (ki * block_k < (qi + 1) * block_q) & (lo_ref[bi, ki] <= q_hi) & (hi_ref[bi, ki] >= q_lo)
+
+
+def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
+                           block_k: int = 1024):
+    """Causal attention inside ``segments`` as a Pallas TPU kernel, for
+    queries and keys of one width and values of another (latent attention:
+    192 against 128; JAX's own flash kernel takes one width, and only 128s).
+    q [B, H, L, D], k [B, Hkv, L, D], v [B, Hkv, L, Dv], segments [B, L]
+    -> [B, H, L, Dv] in q's dtype. L is whole blocks, ``block_q`` whole
+    ``block_k``s; ``block_k`` and Dv are whole 128s. A pair of blocks that
+    share no document costs a grid step and nothing else: in packed rows of
+    many documents most pairs under the diagonal do not. Forward only."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, l, d = q.shape
+    rep, dv = h // k.shape[1], v.shape[-1]
+    block_q, block_k = min(block_q, l), min(block_k, l)
+    if l % block_q or block_q % block_k or block_k % _LANES or dv % _LANES:
+        raise ValueError(f"rows of {l} in blocks of {block_q} x {block_k}, values of {dv}: "
+                         f"the kernel wants whole blocks and whole {_LANES}s")
+    by_block = segments.astype(jnp.int32).reshape(b, l // block_k, block_k)
+    lo, hi = by_block.min(axis=-1), by_block.max(axis=-1)
+
+    def key_block(bi, qi, ki, lo_ref, hi_ref):
+        """A skipped pair asks for the query block's own last key block, which
+        a later pair needs: nothing is copied for it."""
+        last = ((qi + 1) * block_q - 1) // block_k
+        return jnp.where(_pair_needed(lo_ref, hi_ref, bi, qi, ki, block_q, block_k), ki, last)
+
+    kernel = functools.partial(_flash_widths_kernel, scale=scale, block_q=block_q, block_k=block_k)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, h, l // block_q, l // block_k),
+            in_specs=[
+                pl.BlockSpec((1, block_q, _LANES), lambda bi, hi, qi, ki, *_: (bi, qi, 0)),
+                pl.BlockSpec((1, _SUBLANES, block_k),
+                             lambda bi, hi, qi, ki, *r: (bi, 0, key_block(bi, qi, ki, *r))),
+                pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki, *_: (bi, hi, qi, 0)),
+                pl.BlockSpec((1, 1, block_k, d),
+                             lambda bi, hi, qi, ki, *r: (bi, hi // rep, key_block(bi, qi, ki, *r), 0)),
+                pl.BlockSpec((1, 1, block_k, dv),
+                             lambda bi, hi, qi, ki, *r: (bi, hi // rep, key_block(bi, qi, ki, *r), 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki, *_: (bi, hi, qi, 0)),
+            scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((block_q, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, l, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+    )(lo, hi, jnp.broadcast_to(segments[:, :, None], (b, l, _LANES)),
+      jnp.broadcast_to(segments[:, None, :], (b, _SUBLANES, l)), q, k, v)
 
 
 def _ring_attention_local(

@@ -70,7 +70,7 @@ from tpu_tfrecord.models import linear_attn as _la
 from tpu_tfrecord.models import moe as _moe
 from tpu_tfrecord.models import pipeline as _pipeline
 from tpu_tfrecord.models.attention import (
-    attention_reference, blockwise_attention, ring_attention,
+    attention_reference, blockwise_attention, flash_attention_widths, ring_attention,
 )
 from tpu_tfrecord.models.long_doc import _rms_norm
 
@@ -768,16 +768,21 @@ def bigram_table(vocab: int, n_next: int, seed: int = 1234) -> np.ndarray:
 # `LMConfig` stacks ONE kind of block n_layers deep. Hybrid decoders
 # alternate kinds — a softmax layer, then a few linear-attention layers —
 # and put a sparse expert FFN behind each. `PatternLMConfig.layer_pattern`
-# names the mixer of each layer; the parameters are a list of per-layer
-# dicts (layers of different kinds do not stack). The model reads no
-# position table: order comes from the causal mask, the short convolution
-# and the recurrence. Policy: bfloat16 weights and activations; norms,
-# router, softmax, the recurrent state, logits and log-probabilities in
-# float32. The rows are TokenPacker's bin-mode batches as they are:
-# ``tokens`` and ``segment_ids`` [B, L+1]; every document comes out as it
-# would alone in a row (attention, taps and state all stop at a boundary).
+# names the mixer of each layer and `ffn_pattern` its feed-forward part
+# (experts, or a dense gated unit: leading dense layers are data); the
+# parameters are a list of per-layer dicts (layers of different kinds do
+# not stack). The model reads no position table: order comes from the
+# causal mask, the short convolution and the recurrence, and in a latent-
+# attention layer from rotary angles of each token's index in its own
+# document, derived on the device from ``segment_ids``. Policy: bfloat16
+# weights and activations; norms, router, rotary angles, softmax, the
+# recurrent state, logits and log-probabilities in float32. The rows are
+# TokenPacker's bin-mode batches as they are: ``tokens`` and
+# ``segment_ids`` [B, L+1]; every document comes out as it would alone in
+# a row (attention, positions, taps and state all stop at a boundary).
 
-MIXERS = ("gqa", "kda")
+MIXERS = ("gqa", "kda", "mla")
+FFNS = ("moe", "dense")
 
 
 @dataclass(frozen=True)
@@ -785,13 +790,20 @@ class PatternLMConfig:
     vocab_size: int = 256          # rows of the embedding, columns of the head, held here
     d_model: int = 64
     layer_pattern: Tuple[str, ...] = ("gqa", "kda", "kda", "kda")
-    n_heads: int = 4               # softmax layer: query heads
+    ffn_pattern: Tuple[str, ...] = ()  # "moe" or "dense" for each layer; () = experts in every one
+    n_heads: int = 4               # softmax and latent-attention layers: query heads
     n_kv_heads: int = 2
     head_dim: int = 16
     kda_heads: int = 4             # delta-rule layer: heads of d_k = d_v = kda_head_dim
     kda_head_dim: int = 16
     conv_taps: int = 4
     gate_rank: int = 8             # rank of the delta-rule layer's decay and output gates
+    qk_nope_dim: int = 16          # latent-attention layer: a head's query/key width without positions,
+    qk_rope_dim: int = 8           # ... its rotary width (the key's is one head shared by all),
+    v_head_dim: int = 16           # ... its value width,
+    kv_rank: int = 32              # ... the rank of the latent that keys and values are expanded from
+    rope_theta: float = 10000.0
+    d_dense: int = 64              # width of a dense feed-forward part
     n_experts: int = 16            # the router's width: every expert of the layer
     experts_held: int = 16         # how many of them this chip holds ...
     held_offset: int = 0           # ... starting at this one
@@ -799,24 +811,39 @@ class PatternLMConfig:
     d_expert: int = 32
     n_shared: int = 1
     routed_scale: float = 1.0
+    router_bias: bool = False      # a per-expert bias beside the router: it picks, it never weighs
     norm_eps: float = 1e-5
     max_len: int = 64              # L: a row is L + 1 tokens
     dtype: Any = jnp.bfloat16
     # how the program cuts the work (no effect on the result beyond rounding)
-    attn_block: int = 1024         # query and key block of the softmax layer
+    attn_block: int = 1024         # query and key block of the softmax and latent-attention layers
     kda_chunk: int = 64            # tokens a step of the chunked delta rule
     expert_tile: int = 256         # visits a tile of the expert loop
     head_block: int = 2048         # tokens a block of the head's logits
 
 
+def ffn_kinds(cfg: PatternLMConfig) -> Tuple[str, ...]:
+    """Each layer's feed-forward part: ``cfg.ffn_pattern``, or experts everywhere."""
+    kinds = cfg.ffn_pattern or ("moe",) * len(cfg.layer_pattern)
+    if len(kinds) != len(cfg.layer_pattern) or set(kinds) - set(FFNS):
+        raise ValueError(f"ffn_pattern {kinds} has to name one of {FFNS} for each of the "
+                         f"{len(cfg.layer_pattern)} layers")
+    return kinds
+
+
 def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
     """{name: (shape, dtype)} as a pytree shaped like the parameters:
-    matrices in ``cfg.dtype``, vectors and the router in float32."""
+    matrices in ``cfg.dtype``, vectors, the router and its bias in float32."""
     d, dt, f32 = cfg.d_model, cfg.dtype, jnp.float32
-    hq, hkv, hd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim, cfg.head_dim
+    hq, hkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
     kd, r = cfg.kda_heads * cfg.kda_head_dim, cfg.gate_rank
     fs = cfg.d_expert * cfg.n_shared
-    moe = {
+    ffns = {"dense": {
+        "ffn_norm": ((d,), f32),
+        "dense": {"w_gate": ((d, cfg.d_dense), dt), "w_up": ((d, cfg.d_dense), dt),
+                  "w_down": ((cfg.d_dense, d), dt)},
+    }}
+    ffns["moe"] = moe = {
         "moe_norm": ((d,), f32),
         "router": ((d, cfg.n_experts), f32),
         "w_gate": ((cfg.experts_held, d, cfg.d_expert), dt),
@@ -838,16 +865,26 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
             "g_down": ((d, r), dt), "g_up": ((r, kd), dt),
             "o_norm": ((cfg.kda_head_dim,), f32), "wo": ((kd, d), dt),
         },
+        "mla": {
+            "attn_norm": ((d,), f32),
+            "wq": ((d, cfg.n_heads * (cfg.qk_nope_dim + cfg.qk_rope_dim)), dt),
+            "wkv_a": ((d, cfg.kv_rank + cfg.qk_rope_dim), dt), "kv_norm": ((cfg.kv_rank,), f32),
+            "wkv_b": ((cfg.kv_rank, cfg.n_heads * (cfg.qk_nope_dim + cfg.v_head_dim)), dt),
+            "wo": ((cfg.n_heads * cfg.v_head_dim, d), dt),
+        },
     }
     for kind in cfg.layer_pattern:
         if kind not in MIXERS:
             raise ValueError(f"layer_pattern names {kind!r}; the mixers are {MIXERS}")
     if cfg.n_shared < 1:
         moe.pop("shared")
+    if cfg.router_bias:
+        moe["router_bias"] = ((cfg.n_experts,), f32)
     return {
         "embed": ((cfg.vocab_size, d), dt), "head": ((d, cfg.vocab_size), dt),
         "final_norm": ((d,), f32),
-        "layers": [{**mixers[kind], **moe} for kind in cfg.layer_pattern],
+        "layers": [{**mixers[kind], **ffns[ffn]}
+                   for kind, ffn in zip(cfg.layer_pattern, ffn_kinds(cfg))],
     }
 
 
@@ -858,7 +895,8 @@ def _is_shape(x) -> bool:
 def pattern_init_params(rng: jax.Array, cfg: PatternLMConfig) -> Dict[str, Any]:
     """Random parameters for tests and examples: matrices normal(0, 1/fan_in),
     norms 1, decays of 0.001 to 0.1 a token (``a_log`` 0, ``f_bias`` the
-    inverse softplus of a log-uniform rate), taps that favour the current token."""
+    inverse softplus of a log-uniform rate), taps that favour the current
+    token, a router bias normal(0, 0.05)."""
     shapes = pattern_param_shapes(cfg)
     leaves, tree = jax.tree.flatten_with_path(shapes, is_leaf=_is_shape)
     out = []
@@ -875,6 +913,8 @@ def pattern_init_params(rng: jax.Array, cfg: PatternLMConfig) -> Dict[str, Any]:
             value = jax.random.normal(key, shape) * 0.2 + (jnp.arange(shape[0]) == 0)[:, None]
         elif name == "embed":
             value = jax.random.normal(key, shape)
+        elif name == "router_bias":
+            value = jax.random.normal(key, shape) * 0.05
         else:
             value = jax.random.normal(key, shape) * shape[-2] ** -0.5
         out.append(value.astype(dtype))
@@ -910,15 +950,21 @@ def _flash_attend(q, k, v, segments, block: int):
 
 
 def _attend(q, k, v, segments, block: int):
-    """Causal attention inside each document; q [B, H, L, D], k/v [B, Hkv, L, D]
-    -> [B, H, L, D]. On a TPU the Pallas kernel (it exists for no other
-    backend, and asks for heads of 128 and rows of whole blocks of 128s);
-    elsewhere, and for shapes the kernel does not take,
+    """Causal attention inside each document; q [B, H, L, D], k [B, Hkv, L, D],
+    v [B, Hkv, L, Dv] -> [B, H, L, Dv], scores scaled by D ** -0.5. On a TPU,
+    for rows of whole blocks of 128s, a Pallas kernel: JAX's own where q, k
+    and v share a width of whole 128s (it takes no other), and
+    ``attention.flash_attention_widths`` where the values are narrower than
+    the keys (latent attention: 192 against 128). Elsewhere (the kernels
+    exist for no other backend), and for shapes neither takes,
     ``attention.blockwise_attention``: plain JAX, the same mask, the same
-    answer (tests/test_pattern_lm.py holds the two to each other)."""
-    (l, d), tile = q.shape[2:], min(block, q.shape[2])
-    if jax.default_backend() == "tpu" and d % 128 == 0 and tile % 128 == 0 and l % tile == 0:
-        return _flash_attend(q, k, v, segments, block)
+    answer (tests/test_pattern_lm.py and tests/test_mla_lm.py hold each
+    kernel to it)."""
+    (l, d), dv, tile = q.shape[2:], v.shape[-1], min(block, q.shape[2])
+    if jax.default_backend() == "tpu" and dv % 128 == 0 and tile % 128 == 0 and l % tile == 0:
+        if d == dv:
+            return _flash_attend(q, k, v, segments, block)
+        return flash_attention_widths(q, k, v, segments, d ** -0.5, block, block)
     out = blockwise_attention(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segments,
         block=block)
@@ -941,6 +987,55 @@ def gqa_mixer(p, x, segments, cfg: PatternLMConfig):
             jnp.einsum("bld,dhk->bhlk", u, p["wg"].reshape(d, h, dh)).astype(jnp.float32))
         gated = (att.astype(jnp.float32) * gate).astype(x.dtype)
         return jnp.einsum("bhlk,hkd->bld", gated, p["wo"].reshape(h, dh, d))
+
+
+def segment_positions(segments):
+    """Each token's index in its own document, [B, L] int32 from ``segments``
+    [B, L]: its index in the row less the index of its segment's first token
+    (a running maximum over the boundaries' indices); pads get 0."""
+    at = jnp.arange(segments.shape[1], dtype=jnp.int32)[None]
+    starts = jnp.concatenate(
+        [jnp.ones_like(segments[:, :1], bool), segments[:, 1:] != segments[:, :-1]], axis=1)
+    first = jax.lax.cummax(jnp.where(starts, at, 0), axis=1)
+    return jnp.where(segments != 0, at - first, 0)
+
+
+def rotary(x, positions, theta: float):
+    """x [B, H, L, R] turned by its tokens' ``positions`` [B, L]: the pair
+    (i, i + R/2) by the angle ``position * theta ** (-2i / R)``, angles and
+    products in float32, rounded to x's dtype."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[:, None, :, None] * freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
+def mla_mixer(p, x, segments, cfg: PatternLMConfig):
+    """The latent-attention layer in its expanded (prefill) form: queries
+    projected whole, keys and values expanded per head from one normed
+    latent of ``kv_rank``, a rotary part on every query head and ONE rotary
+    key head shared by all, positions that restart at every document;
+    causal softmax inside each document over ``qk_nope_dim + qk_rope_dim``
+    wide queries and keys against ``v_head_dim`` wide values. x [B, L, D].
+    Heads are written head-major ``[B, H, L, .]`` by the projections."""
+    (b, l, d), h = x.shape, cfg.n_heads
+    dn, dr, dv, rank = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, cfg.kv_rank
+    with jax.named_scope("tfr.mla_proj"):
+        u = weighted_rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        q = jnp.einsum("bld,dhk->bhlk", u, p["wq"].reshape(d, h, dn + dr))
+        latent = u @ p["wkv_a"]                                          # [B, L, rank + dr]
+        c = weighted_rms_norm(latent[..., :rank], p["kv_norm"], cfg.norm_eps)
+        kv = jnp.einsum("blr,rhk->bhlk", c, p["wkv_b"].reshape(rank, h, dn + dv))
+        at = segment_positions(segments)
+        k_pe = rotary(latent[:, None, :, rank:], at, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :dn], rotary(q[..., dn:], at, cfg.rope_theta)], axis=-1)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (b, h, l, dr))], axis=-1)
+    with jax.named_scope("tfr.mla_attn"):
+        att = _attend(q, k, kv[..., dn:], segments, cfg.attn_block)
+    with jax.named_scope("tfr.mla_proj"):
+        return jnp.einsum("bhlk,hkd->bld", att, p["wo"].reshape(h, dv, d))
 
 
 def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
@@ -996,11 +1091,12 @@ def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
 def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=None,
                    probe_head=None):
     """tokens, segment_ids [B, L+1] -> (x [B, L, D] before the final norm,
-    visits [n_layers, experts_held], dropped [n_layers], probes).
+    visits [n_layers, experts_held], dropped [n_layers], probes);
+    ``n_layers`` counts the layers that have experts.
 
     ``probes`` is what a caller checks single layers by, on the layer's own
     inputs: ``router`` (with ``sample_at`` [B, S]: at those positions of
-    every layer the router's input ``u`` [n_layers, B, S, D] and its
+    every expert layer the router's input ``u`` [n_layers, B, S, D] and its
     ``experts`` and ``gates`` [n_layers, B, S, top_k]) and ``scan`` (with
     ``probe_head``: :func:`kda_mixer`'s probe of the first delta-rule layer)."""
     l = tokens.shape[1] - 1
@@ -1013,15 +1109,23 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
         x = params["embed"][tokens[:, :-1]]
     b, _, d = x.shape
     visits, dropped, routed, probes = [], [], [], {}
-    for kind, layer in zip(cfg.layer_pattern, params["layers"]):
+    for kind, ffn, layer in zip(cfg.layer_pattern, ffn_kinds(cfg), params["layers"]):
         if kind == "gqa":
             x = x + gqa_mixer(layer, x, segments, cfg)
+        elif kind == "mla":
+            x = x + mla_mixer(layer, x, segments, cfg)
         else:
             y, scan = kda_mixer(layer, x, segments, cfg,
                                 None if "scan" in probes else probe_head)
             x = x + y
             if scan is not None:
                 probes["scan"] = scan
+        if ffn == "dense":
+            with jax.named_scope("tfr.dense_ffn"):
+                u = weighted_rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+                w = layer["dense"]
+                x = x + _moe.gated_ffn(u, w["w_gate"], w["w_up"], w["w_down"]).astype(x.dtype)
+            continue
         with jax.named_scope("tfr.moe_route"):
             u = weighted_rms_norm(x, layer["moe_norm"], cfg.norm_eps)
         y, n, lost, (experts, gates) = _moe.held_experts_apply(
@@ -1038,6 +1142,8 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
                            "gates": jnp.take_along_axis(gates.reshape(b, l, -1), at, axis=1)})
     if routed:
         probes["router"] = {k: jnp.stack([r[k] for r in routed]) for k in routed[0]}
+    if not visits:  # no layer has experts
+        return x, jnp.zeros((0, cfg.experts_held), jnp.int32), jnp.zeros((0,), jnp.int32), probes
     return x, jnp.stack(visits), jnp.stack(dropped), probes
 
 
@@ -1050,10 +1156,13 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     ``visits``  [n_layers, experts_held] int32, ``dropped`` [n_layers] int32
     ``probes``  :func:`pattern_hidden`'s: the router's inputs and choices at
         ``sample_at``, and with ``probe_head`` that head's recurrence
+        (``scan`` is ``{}`` where the pattern has no delta-rule layer)
 
     The head's logits exist a block of ``cfg.head_block`` tokens at a time."""
     x, visits, dropped, probes = pattern_hidden(params, tokens, segment_ids, cfg, sample_at,
                                                 probe_head)
+    if "kda" not in cfg.layer_pattern:
+        probes["scan"] = {}
     b, l, d = x.shape
     with jax.named_scope("tfr.lm_head"):
         xn = weighted_rms_norm(x, params["final_norm"], cfg.norm_eps)

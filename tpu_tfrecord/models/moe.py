@@ -557,13 +557,20 @@ def gated_ffn(x, w_gate, w_up, w_down):
     return jnp.dot(hidden.astype(x.dtype), w_down, preferred_element_type=f32)
 
 
-def route_top_k(x, router, top_k: int, routed_scale: float = 1.0):
+def route_top_k(x, router, top_k: int, routed_scale: float = 1.0, bias=None):
     """Sigmoid scores over ALL experts in float32, the ``top_k`` largest,
-    their gates renormalised to sum to ``routed_scale``.
+    their gates renormalised to sum to ``routed_scale``. With ``bias`` [E]
+    (a correction the trainer balances the load by) the experts are the
+    ``top_k`` largest of ``scores + bias`` and the gates still come from the
+    scores alone: the bias picks and never weighs.
     x [T, D], router [D, E] -> (experts [T, k] int32, gates [T, k] float32)."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST))
-    top, experts = jax.lax.top_k(scores, top_k)
+    if bias is None:
+        top, experts = jax.lax.top_k(scores, top_k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias, top_k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
     return experts, top / top.sum(axis=-1, keepdims=True) * routed_scale
 
 
@@ -572,10 +579,12 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     """``shared(x) + sum of gate_e * expert_e(x)`` over the chosen experts
     THIS chip holds; what the absent experts would add is left out.
 
-    params: ``router`` [D, E] (all E experts), ``w_gate`` / ``w_up``
-    [Eh, D, F], ``w_down`` [Eh, F, D] (the Eh experts held, numbers
-    ``held_offset`` .. ``held_offset + Eh``), optional ``shared`` with the
-    same three names un-stacked. x [T, D]; ``valid`` [T] bool: tokens that
+    params: ``router`` [D, E] (all E experts), optional ``router_bias`` [E]
+    (:func:`route_top_k`'s), ``w_gate`` / ``w_up`` [Eh, D, F], ``w_down``
+    [Eh, F, D] (the Eh experts held, numbers ``held_offset`` ..
+    ``held_offset + Eh``), optional ``shared`` with the same three names
+    un-stacked (several shared experts are one unit of their summed width).
+    x [T, D]; ``valid`` [T] bool: tokens that
     are pads visit no expert (their rows get the shared expert only).
     Returns (y [T, D] in x's dtype, visits [Eh] int32 to each held expert,
     dropped: visits to held experts that were not computed, always 0,
@@ -593,7 +602,8 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     n_held = params["w_gate"].shape[0]
     f32 = jnp.float32
     with jax.named_scope("tfr.moe_route"):
-        experts, gates = route_top_k(x, params["router"], top_k, routed_scale)
+        experts, gates = route_top_k(x, params["router"], top_k, routed_scale,
+                                     params.get("router_bias"))
         local = experts - held_offset
         held = (local >= 0) & (local < n_held)
         if valid is not None:

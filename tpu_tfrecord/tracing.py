@@ -72,6 +72,11 @@ ANNOTATIONS = {
     "tfr.kda_proj": "pattern LM: the delta-rule layer's projections, decay, beta, gates, norm, out",
     "tfr.kda_conv": "pattern LM: the short convolution, SiLU and unit norm of q, k, v",
     "tfr.kda_scan": "pattern LM: the chunked gated delta rule (models.linear_attn)",
+    "tfr.mla_proj": "pattern LM: the latent-attention layer's norm, query projection, latent "
+                    "projection and norm, expansion to keys and values, rotary turns, out",
+    "tfr.mla_attn": "pattern LM: the latent-attention layer's attention call alone (192-wide "
+                    "queries and keys against 128-wide values inside each document)",
+    "tfr.dense_ffn": "pattern LM: a layer's dense gated feed-forward part (pre-norm, gate, up, down)",
     "tfr.moe_route": "held experts: pre-norm, scores over all experts, top-k, visits sorted by expert",
     "tfr.moe_experts": "held experts: the loop over the tiles of visits to the experts held here",
     "tfr.moe_shared": "held experts: the shared expert, every token",
