@@ -295,25 +295,61 @@ def test_an_unknown_mixer_or_feed_forward_part_is_named_with_the_ones_there_are(
         lm.pattern_param_shapes(lm.PatternLMConfig(layer_pattern=("mla", "gqa"), ffn_pattern=("moe",)))
 
 
-#: sha256 of ``str(jax.make_jaxpr(lm.score))`` for test_pattern_lm's program at the
-#: commit before the latent-attention layer came (PR 30): the softmax / delta-rule
-#: pattern's program has to stay that one, operation for operation. A PR that
-#: means to change that program records its own.
-PATTERN_PROGRAM = "80e42af17080e7b14f19cef6c96e5055d12c4ba2668df85bfa2314a54e851896"
+#: sha256 of ``str(jax.make_jaxpr(f))`` for the programs a PR that means to change
+#: ONE pattern's program must leave as they were, operation for operation:
+#: ``solar`` test_pattern_lm's softmax / delta-rule program (recorded at PR 30, before
+#: the latent-attention layer came; off a TPU the delta rule runs its plain form, so
+#: PR 32's kernel leaves it alone too), and as PR 31 left them ``kimi`` this file's
+#: latent-attention program and the recommender's ``forward`` and ``sparse_train_step``.
+#: A PR that means to change one of them records its own.
+OLDER_PROGRAMS = {
+    "solar": "80e42af17080e7b14f19cef6c96e5055d12c4ba2668df85bfa2314a54e851896",
+    "kimi": "0ba72f9be56926b787a2266609187fe94c649a9a7c779dc136a73074251c044b",
+    "dlrm_forward": "74937f331a59e45e91ba132bca04da279ac57e7cdc91574931627704728350cc",
+    "sparse_train_step": "ea35280a10973a3d8af6c8c0a1a8b17679edde3e8d4005f6012a10f3f8360d9d",
+}
 
 
-def test_the_older_patterns_program_is_the_one_it_was():
-    import test_pattern_lm as older
+def older_program(name):
+    import functools
 
-    cfg = older.program_cfg()
-    assert lm.ffn_kinds(cfg) == ("moe",) * 4 and not cfg.router_bias
-    params = lm.pattern_init_params(jax.random.PRNGKey(3), cfg)
-    assert all("router_bias" not in layer and "dense" not in layer for layer in params["layers"])
-    batch, _ = older.packed_rows()
+    import optax
+
+    from tpu_tfrecord.models import dlrm
+
     at = jnp.asarray([[0, 5, 19, 25], [2, 8, 29, 40]], jnp.int32)
-    program = jax.make_jaxpr(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
-        params, batch["tokens"], batch["segment_ids"], at, jnp.int32(2))
-    assert hashlib.sha256(str(program).encode()).hexdigest() == PATTERN_PROGRAM
+    if name == "solar":
+        import test_pattern_lm as older
+
+        cfg = older.program_cfg()
+        assert lm.ffn_kinds(cfg) == ("moe",) * 4 and not cfg.router_bias
+        params = lm.pattern_init_params(jax.random.PRNGKey(3), cfg)
+        assert all("router_bias" not in layer and "dense" not in layer for layer in params["layers"])
+        batch, _ = older.packed_rows()
+        return jax.make_jaxpr(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
+            params, batch["tokens"], batch["segment_ids"], at, jnp.int32(2))
+    if name == "kimi":
+        cfg, batch = program_cfg(), packed_rows()
+        params = lm.pattern_init_params(jax.random.PRNGKey(3), cfg)
+        return jax.make_jaxpr(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
+            params, batch["tokens"], batch["segment_ids"], at, jnp.int32(1))
+    cfg = dlrm.DLRMConfig(num_dense=13, num_categorical=4, vocab_size=64, embed_dim=128,
+                          bottom_mlp=(32, 128), top_mlp=(32, 1), interaction="dot")
+    params = dlrm.init_params(jax.random.key(0), cfg)
+    batch = {"label": jnp.zeros((16,), jnp.float32), "dense": jnp.ones((16, 13), jnp.float32),
+             "cat": jnp.arange(64, dtype=jnp.int32).reshape(16, 4) % 7}
+    if name == "dlrm_forward":
+        return jax.make_jaxpr(functools.partial(dlrm.forward, cfg=cfg))(
+            params, {k: batch[k] for k in ("dense", "cat")})
+    tx = optax.sgd(1e-2)
+    return jax.make_jaxpr(functools.partial(dlrm.sparse_train_step, cfg=cfg, tx=tx))(
+        params, dlrm.sparse_opt_init(params, cfg, tx), batch)
+
+
+@pytest.mark.parametrize("name", list(OLDER_PROGRAMS))
+def test_the_older_patterns_program_is_the_one_it_was(name):
+    program = older_program(name)
+    assert hashlib.sha256(str(program).encode()).hexdigest() == OLDER_PROGRAMS[name]
 
 
 def test_the_benchmarks_copy_of_the_reference_is_this_one():

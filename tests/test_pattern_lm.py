@@ -248,6 +248,16 @@ def delta_rule_inputs(seed, length, near_parallel_keys=False, b=2, h=3, d=16):
     return [jnp.asarray(a, jnp.float32) for a in (q, k, v, log_decay, beta)] + [jnp.asarray(segs)]
 
 
+def interpreted_kernel(q, k, v, log_decay, beta, segs, scale, tile=128):
+    """The Pallas kernel a TPU runs for chunks of 64 at width 128, interpreted."""
+    return linear_attn._delta_rule_fused(q, k, v, log_decay, beta, segs, scale, tile, interpret=True)
+
+
+def kernel_inputs(seed, length, **kw):
+    """:func:`delta_rule_inputs` at the width the kernel takes, two heads of one row."""
+    return delta_rule_inputs(seed, length, b=1, h=2, d=128, **kw)
+
+
 @pytest.mark.parametrize("chunk", [1, 4, 16, 64, 128])
 @pytest.mark.parametrize("length", [150, 64, 37])
 def test_the_chunked_recurrence_is_the_token_by_token_one(chunk, length):
@@ -258,33 +268,136 @@ def test_the_chunked_recurrence_is_the_token_by_token_one(chunk, length):
     np.testing.assert_allclose(got, want, atol=5e-6)
 
 
+@pytest.mark.parametrize("length,tile,heads", [(128, 128, 2), (256, 128, 2), (256, 256, 2), (512, 256, 2),
+                                               (768, 256, 3)])
+def test_the_kernel_is_the_token_by_token_recurrence_and_the_plain_form(length, tile, heads):
+    """Four boundaries a row at random places and a pad tail of segment 0:
+    documents start and end inside a chunk, inside a pair of chunks and
+    inside a grid step, and the state crosses from one grid step to the next.
+    An even number of heads goes two to a grid step, an odd number one."""
+    args = delta_rule_inputs(length + tile, length, b=1, h=heads, d=128)
+    segs = np.asarray(args[-1])
+    assert (segs[:, -1] == 0).all() and (np.diff(segs) != 0).sum() >= 4
+    want = linear_attn.delta_rule_recurrent(*args, scale=0.25)
+    plain = linear_attn.delta_rule_chunked(*args, scale=0.25, chunk=64)
+    got = interpreted_kernel(*args, 0.25, tile)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    np.testing.assert_allclose(got, plain, atol=5e-6)
+
+
+def test_a_boundary_at_every_edge_the_kernel_has():
+    """Documents that end exactly where a 16-token block, a chunk, a pair of
+    chunks and a grid step end, one token long, and one across three steps."""
+    q, k, v, log_decay, beta, _ = kernel_inputs(8, 768)
+    segs = np.zeros((1, 768), np.int32)
+    for s, (a, z) in enumerate([(0, 16), (16, 64), (64, 128), (128, 129), (129, 256), (256, 700)]):
+        segs[0, a:z] = s + 1
+    segs = jnp.asarray(segs)
+    want = linear_attn.delta_rule_recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
+    for tile in (128, 256):
+        got = interpreted_kernel(q, k, v, log_decay, beta, segs, 0.25, tile)
+        np.testing.assert_allclose(got, want, atol=5e-6)
+
+
+def test_off_a_tpu_and_at_other_shapes_the_plain_form_runs(monkeypatch):
+    """The dispatch reads the backend and the shape, nothing else: here (the
+    CPU) every shape takes the plain form and ``kda.fused_layers`` reads 0;
+    told that the backend is a TPU it takes whole pairs of chunks of 64 at
+    widths of whole 128s, in the largest tile that divides the row (a row
+    that is no whole number of pairs falls back: it is not padded)."""
+    from tpu_tfrecord.metrics import METRICS
+
+    cell = (2, 64, 8192, 128)
+    assert jax.default_backend() != "tpu" and linear_attn.fused_tile(cell, 64) is None
+    called = []
+    monkeypatch.setattr(linear_attn, "_delta_rule_fused", lambda *a, **k: called.append(a))
+    for d in (8, 16):
+        args = delta_rule_inputs(d, 128, d=d)
+        linear_attn.delta_rule_chunked(*args, scale=0.25, chunk=64)
+    batch, _ = packed_rows()
+    cfg = program_cfg()
+    lm.score(lm.pattern_init_params(jax.random.PRNGKey(3), cfg), batch["tokens"],
+             batch["segment_ids"], jnp.zeros((2, 1), jnp.int32), cfg)
+    assert not called and METRICS.gauge_value("kda.fused_layers") == 0
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert linear_attn.fused_tile(cell, 64) == 256
+    assert linear_attn.fused_tile((1, 3, 768, 128), 64) == 256
+    assert linear_attn.fused_tile((1, 2, 384, 256), 64) == 128
+    for shape, chunk in [((1, 2, 8192, 16), 64), ((1, 2, 8192, 8), 64), ((1, 2, 8192, 192), 64),
+                         ((1, 2, 150, 128), 64), ((1, 2, 8192 + 64, 128), 64)] + [
+                             (cell, c) for c in (1, 4, 16, 128)]:
+        assert linear_attn.fused_tile(shape, chunk) is None, (shape, chunk)
+    args = kernel_inputs(0, 256)
+    linear_attn.delta_rule_chunked(*args, scale=0.25, chunk=64)
+    assert len(called) == 1 and called[0][-1] == 256             # the tile
+
+
+def test_a_layer_that_takes_the_kernel_is_the_layer_and_is_counted(monkeypatch):
+    """``kda_mixer`` at the kernel's width with the dispatch answering as it
+    would on a TPU and Pallas interpreting: the layer the plain form gives,
+    and ``score`` counts the pattern's three delta-rule layers as fused."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tpu_tfrecord.metrics import METRICS
+
+    wide = {**CFG, "linear_attn_config": {"num_heads": 2, "head_dim": 128, "short_conv_kernel_size": 4}}
+    cfg = lm.PatternLMConfig(**{**program_cfg(wide, kda_chunk=64, attn_block=32).__dict__,
+                                "max_len": 128})
+    params = lm.pattern_init_params(jax.random.PRNGKey(5), cfg)
+    rng = np.random.default_rng(6)
+    tokens = jnp.asarray(rng.integers(1, 64, (1, 129)), jnp.int32)
+    segs = jnp.asarray([[1] * 50 + [2] * 70 + [0] * 9], jnp.int32)
+    at = jnp.zeros((1, 1), jnp.int32)
+    plain = lm.score(params, tokens, segs, at, cfg)["logprob"]
+    assert METRICS.gauge_value("kda.fused_layers") == 0
+    monkeypatch.setattr(linear_attn, "fused_tile",
+                        lambda shape, chunk: 128 if shape[-1] == 128 and chunk == 64 else None)
+    with pltpu.force_tpu_interpret_mode():
+        fused = lm.score(params, tokens, segs, at, cfg)["logprob"]
+    assert METRICS.gauge_value("kda.fused_layers") == 3
+    np.testing.assert_allclose(fused, plain, atol=2e-5)
+    assert np.abs(np.asarray(plain)).max() > 1
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
 @pytest.mark.parametrize("one_key", [False, True])
-def test_near_parallel_keys_do_not_blow_the_chunk_up(one_key):
+def test_near_parallel_keys_do_not_blow_the_chunk_up(one_key, form):
     """beta near 2 on keys that all but repeat, or do repeat (a document that
     says one token over and over): the triangle's inverse by forward
-    substitution stays exact where a product of a block's powers cancelled
-    terms of 1e6 against each other (it was held to 1e-3 here)."""
-    q, k, v, log_decay, beta, segs = delta_rule_inputs(1, 150, near_parallel_keys=True)
+    substitution (the plain form) or by doubling from single rows (the
+    kernel) stays exact where a product of a block's powers cancelled terms
+    of 1e6 against each other (it was held to 1e-3 here)."""
+    q, k, v, log_decay, beta, segs = (
+        delta_rule_inputs(1, 150, near_parallel_keys=True) if form == "plain"
+        else kernel_inputs(1, 256, near_parallel_keys=True))
     if one_key:
         k, beta = jnp.broadcast_to(k[:, :, :1], k.shape), jnp.full_like(beta, 1.98)
     want = linear_attn.delta_rule_recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
-    got = linear_attn.delta_rule_chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=64)
+    if form == "plain":
+        got = linear_attn.delta_rule_chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=64)
+    else:
+        got = interpreted_kernel(q, k, v, log_decay, beta, segs, 0.25)
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
 @pytest.mark.parametrize("rate", [3.0, 5.0, 9.0])
-@pytest.mark.parametrize("chunk", [16, 64, 128])
+@pytest.mark.parametrize("chunk", [16, 64, 128, "kernel"])
 def test_fast_decays_do_not_overflow_the_chunk(rate, chunk):
     """Channels that forget at ``rate`` a token, real tokens and the pads of
     a row's tail alike: exp(+-sum of log-decay) around one reference point
     for a whole chunk of 64 overflowed float32 at 2.5 a token, and a NaN
-    behind a zero of the triangle's inverse reached the document before."""
-    q, k, v, log_decay, beta, segs = delta_rule_inputs(3, 150)
+    behind a zero of the triangle's inverse reached the document before.
+    ``kernel``: the interpreted kernel (chunks of 64) at its width."""
+    q, k, v, log_decay, beta, segs = delta_rule_inputs(3, 150) if chunk != "kernel" else kernel_inputs(3, 256)
     fast = np.random.default_rng(4).random(log_decay.shape) < 0.3
     log_decay = jnp.where(fast, -rate, log_decay)
     segs = segs.at[:, 120:].set(0)
     want = linear_attn.delta_rule_recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
-    got = linear_attn.delta_rule_chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=chunk)
+    if chunk == "kernel":
+        got = interpreted_kernel(q, k, v, log_decay, beta, segs, 0.25)
+    else:
+        got = linear_attn.delta_rule_chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=chunk)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, atol=1e-5)
 

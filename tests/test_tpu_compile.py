@@ -166,6 +166,61 @@ class TestOneChip:
         if config == "mlperf_widths":
             assert mem.temp_size_in_bytes < table_bytes // 2
 
+    def test_the_delta_rule_kernel_at_the_cells_shape(self, one_chip):
+        """``solar_open2_ep8.score``'s delta-rule layer, [2, 64, 8192, 128] in
+        chunks of 64 and grid steps of two heads' 256 tokens: the kernel fits VMEM (the
+        compiler refuses one that does not), and nothing of q's size exists
+        beside its four inputs and its output."""
+        from tpu_tfrecord.models import linear_attn
+
+        shape = (2, 64, 8192, 128)
+        x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+        beta = jax.ShapeDtypeStruct(shape[:3], jnp.float32, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(lambda q, k, v, g, b, s: linear_attn._delta_rule_fused(
+            q, k, v, g, b, s, 128 ** -0.5, linear_attn._TILES[0])).lower(x, x, x, x, beta, segs).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        assert mem.output_size_in_bytes == 4 * np.prod(shape)
+        assert mem.temp_size_in_bytes < 4 * np.prod(shape) // 8
+
+    def test_the_solar_patterns_score_holds_no_loop_under_the_scan(self, one_chip, monkeypatch):
+        """``lm.score`` for the softmax / delta-rule period at the cell's row
+        shape and delta-rule widths (the rest narrow: this is about one
+        scope), steered onto the TPU's paths as a described chip cannot steer
+        it: under ``tfr.kda_scan`` the compiled program holds the kernel
+        once a layer and no ``while`` (the plain form's loops over head
+        groups and chunks), and of float32 arrays of q's size only the
+        kernel's q, k, v and its output (the log-decay is ``tfr.kda_proj``'s)."""
+        import re
+
+        from tpu_tfrecord.models import linear_attn
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = lm.PatternLMConfig(
+            vocab_size=2048, d_model=256, layer_pattern=("gqa", "kda", "kda", "kda"),
+            n_heads=2, n_kv_heads=1, head_dim=128, kda_heads=64, kda_head_dim=128, conv_taps=4,
+            gate_rank=32, n_experts=8, experts_held=8, top_k=2, d_expert=128, n_shared=1,
+            max_len=8192, dtype=jnp.bfloat16, attn_block=1024, kda_chunk=64, expert_tile=256,
+            head_block=2048)
+        q_shape = (2, cfg.kda_heads, cfg.max_len, cfg.kda_head_dim)
+        assert linear_attn.fused_tile(q_shape, cfg.kda_chunk) == linear_attn._TILES[0]
+        params = jax.tree.map(lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip),
+                              lm.pattern_param_shapes(cfg),
+                              is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple))
+        rows = jax.ShapeDtypeStruct((2, cfg.max_len + 1), jnp.int32, sharding=one_chip)
+        at = jax.ShapeDtypeStruct((2, 4), jnp.int32, sharding=one_chip)
+        head = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        hlo = jax.jit(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h)).lower(
+            params, rows, rows, at, head).compile().as_text()
+        entry = hlo[hlo.index("\nENTRY "):]                  # fused computations repeat their roots' names
+        scan = [line for line in entry[:entry.index("\n}")].splitlines()
+                if re.search(r'op_name="[^"]*tfr\.kda_scan', line)]
+        assert sum("custom_call_target=\"tpu_custom_call\"" in line for line in scan) == 3
+        assert not [line for line in scan if re.search(r"= \S+ while\(", line)]
+        q_sized = "f32[" + ",".join(map(str, q_shape)) + "]"
+        assert sum(bool(re.match(rf"\s*(ROOT )?%?\S+ = {re.escape(q_sized)}", line)) for line in scan) <= 4 * 3
+
     def test_lm_train_step_on_dp(self, topo):
         """examples/train_lm.py's widths on a one-device ``data`` mesh."""
         mesh = Mesh(np.array(topo.devices[:1]), ("data",))

@@ -38,22 +38,33 @@ for a whole chunk of 64 overflowed float32 at 2.5.
 
 ``carry`` is 1 for the tokens whose document began before the chunk did:
 a token of a document that starts inside the chunk never sees the state
-that came in. T, A and the masked products do not depend on S and are
-made for all chunks at once; only the last three lines run in sequence
-(``lax.scan`` over the chunks). The state and everything that touches it
+that came in. T, A and the masked products do not depend on S; only the
+last three lines run in sequence. The state and everything that touches it
 are float32, and their matrix products run at ``HIGHEST`` precision (on a
 TPU a float32 product otherwise rounds its inputs to bfloat16, which is
 the state kept in bfloat16 by another name).
+
+Two forms of it. On a TPU, for heads of whole 128s and rows of whole pairs
+of chunks of 64, one Pallas kernel (``_delta_rule_fused``): a grid step
+takes two heads' tile of a row from q, k, v, log_decay, beta and segments
+to o with every intermediate in VMEM and the state in a scratch carried
+from tile to tile. Everywhere else (the CPU, other widths and chunks) the
+plain JAX form (``_delta_rule_plain``), which lays the same intermediates
+out in memory a group of ``_HEADS`` heads at a time and loops over the
+chunks; the tests hold the interpreted kernel to it and both to the
+token-by-token recurrence.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _BLOCK = 16  # tokens of the blocks that the triangle's inverse and the decayed pairs bottom out in
-_HEADS = 16  # heads of a row whose chunked form is laid out in memory at a time
+_HEADS = 16  # heads of a row whose chunked form the plain form lays out in memory at a time
 
 
 def short_conv(x, taps, segments):
@@ -193,18 +204,274 @@ def _chunked_heads(q, k, v, g, beta, seg, scale):
     return jax.lax.fori_loop(0, n, step, (jnp.zeros((h, d, d), q.dtype), jnp.zeros_like(tv)))[1]
 
 
-def delta_rule_chunked(q, k, v, log_decay, beta, segments, scale, chunk: int = 64):
-    """The same recurrence ``chunk`` tokens at a time (module docstring).
-    Shapes as :func:`delta_rule_recurrent`, head-major ``[B, H, L, D]`` as a
-    projection writes them; L need not be a multiple of ``chunk`` (the tail
-    is padded with a segment of its own). A row is cut into
-    ``[B, H, n, C, D]`` by a reshape and the loop over the n chunks slices
-    that axis where it lies: nothing is transposed. At most ``_HEADS`` heads
-    of a row are worked at a time (``lax.map`` over groups of heads, again a
-    reshape): what the chunked form lays out, a dozen float32 arrays the
+# ---------------------------------------------------------------------------
+# One TPU: the chunked form as a Pallas kernel, every intermediate in VMEM
+# ---------------------------------------------------------------------------
+
+_PAIR = 128           # tokens laid out at a time: two chunks side by side, one matrix-unit tile
+_KERNEL_CHUNK = 64    # the chunk the kernel computes in
+_TILES = (256, 128)   # tokens of a grid step, the larger that divides the row
+
+
+def fused_tile(shape, chunk: int):
+    """Tokens a grid step of the kernel holds for q of ``shape`` [B, H, L, D]
+    cut in ``chunk``s, or None where the plain form runs: off a TPU (the
+    kernel exists for no other backend), at a head width that is not whole
+    lanes of 128, at another chunk than the kernel's, and for rows that are
+    not whole pairs of chunks (those fall back, they are not padded)."""
+    l, d = shape[2:]
+    if jax.default_backend() != "tpu" or chunk != _KERNEL_CHUNK or d % 128:
+        return None
+    return next((t for t in _TILES if l % t == 0), None)
+
+
+def _dot(a, b, contract=((1,), (0,))):
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _roll(x, shift: int, axis: int = 0):
+    """x's rows (columns) moved ``shift`` down (right), around the end."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.roll(x, shift, axis)
+
+
+def _held(x, every: int, at: int):
+    """x [n, D] with each run of ``every`` rows replaced by its row ``at``."""
+    n, d = x.shape
+    picked = x.reshape(n // every, every, d)[:, at:at + 1]
+    return jnp.broadcast_to(picked, (n // every, every, d)).reshape(n, d)
+
+
+def _upper(x, half: int):
+    """The rows of x [n, .] whose index has the bit ``half`` set, [n / 2, .]."""
+    n, w = x.shape
+    return x.reshape(n // (2 * half), 2, half, w)[:, 1].reshape(n // 2, w)
+
+
+def _back(y, half: int):
+    """:func:`_upper`'s rows where they came from, zero rows between."""
+    m, w = y.shape
+    y = y.reshape(m // half, 1, half, w)
+    return jnp.concatenate([jnp.zeros_like(y), y], axis=1).reshape(2 * m, w)
+
+
+def _pair_of_chunks(q, k, v, g, beta_row, seg_col, seg_row, before, scale):
+    """What two chunks of 64 tokens need that does not touch the state
+    (module docstring's G, A, P, T and the factors against the state):
+    q, k, v, g [128, D]; beta_row, seg_row [1, 128]; seg_col, before
+    [128, D] int32 (a token's segment and the id before its chunk, the same
+    in every lane). Returns qs, tk, tv [128, D], p [128, 128] (zero between
+    the chunks), keep^T per chunk [D, 64] and each chunk's decay of the
+    state [D, 1].
+
+    A generator: it yields after each matrix product, so that the kernel can
+    emit several pairs' work in turn (:func:`_in_turn`).
+
+    A pair's decay is split as ``_decayed_pairs`` splits it (16-token blocks
+    on the diagonal around their own middle, the block pairs of a half and
+    of the chunk around the boundary between them): three products, of all
+    256 rows of [q; k] for the diagonal blocks and of the 128 rows after the
+    boundary for the other two. The triangle's inverse doubles from single
+    rows: [[A, 0], [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1, B^-1]] for every
+    block pair of a level at once, ``X - X (N_off X)`` on the whole
+    [128, 128]: as exact on keys that repeat as substitution in 16s joined
+    by halves (tests). Blocks of 2 and 4 are joined on the vector unit (the
+    inverse's d-th subdiagonal weighs ``N_off``'s columns d to the right,
+    then the result's rows d above); from 8 on, the 64 rows of the lower
+    blocks go through the matrix unit. The matrix unit takes a float32
+    product as six passes of 128 rows a tile whatever the other two sizes,
+    so what a product costs is the rows it streams."""
+    n, d = q.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    tok = jax.lax.broadcasted_iota(jnp.int32, (n, d), 0)
+
+    # the running sum of the log-decay inside each chunk
+    for s in (1, 2, 4, 8, 16, 32):
+        g = g + jnp.where((tok & (_KERNEL_CHUNK - 1)) >= s, _roll(g, s), 0.0)
+
+    # [q; k] against k with the decay from key to query, level by level: [256, 128]
+    both = jax.lax.broadcasted_iota(jnp.int32, (2 * n, n), 0) & (n - 1)         # q's rows, then k's
+    across = jax.lax.broadcasted_iota(jnp.int32, (2 * n, n), 1)
+    ref = _held(g, _BLOCK, (_BLOCK - 1) // 2)
+    raw = _dot(jnp.concatenate([q, k]) * jnp.tile(jnp.exp(g - ref), (2, 1)), k * jnp.exp(ref - g),
+               ((1,), (1,)))
+    raw = jnp.where(both >> 4 == across >> 4, raw, 0.0)
+    for shift in (5, 6):                   # a half's two blocks; a chunk's two halves
+        half = 1 << (shift - 1)
+        ref = _held(g, 2 * half, half - 1)
+        after = (tok & half) != 0
+        e = jnp.exp(jnp.where(after, g - ref, ref - g))                     # both at most 1
+        out = _dot(jnp.concatenate([_upper(q * e, half), _upper(k * e, half)]),
+                   k * jnp.where(after, 0.0, e), ((1,), (1,)))
+        # zero but for rows after and columns before a boundary: of those, one run's own
+        raw = raw + jnp.where(both >> shift == across >> shift, _back(out, half), 0.0)
+
+    yield
+    same = seg_col[:, :n] == seg_row
+    eye = row == col
+    p = jnp.where(same & (col <= row), raw[:n], 0.0) * scale
+    beta = jnp.sum(jnp.where(eye, beta_row, 0.0), axis=1, keepdims=True)        # [128, 1]
+    low = jnp.where(same & (col < row), raw[n:], 0.0) * beta                    # diag(b) A
+
+    def between(shift):
+        """``low`` where the row is in the upper half and the column in the
+        lower half of one block of 2 << shift."""
+        return jnp.where(((row >> (shift + 1)) == (col >> (shift + 1)))
+                         & (((row >> shift) & 1) == 1) & (((col >> shift) & 1) == 0), low, 0.0)
+
+    inv = jnp.where(eye, 1.0, 0.0) - between(0)
+    for shift in (1, 2):
+        bands = [jnp.where(row == col + dist, inv, 0.0) for dist in range(1, 1 << shift)]
+        right = left = between(shift)
+        for dist, band in enumerate(bands, 1):
+            right = right + _roll(left, n - dist, 1) * jnp.sum(band, axis=0, keepdims=True)
+        out = right
+        for dist, band in enumerate(bands, 1):
+            out = out + jnp.sum(band, axis=1, keepdims=True) * _roll(right, dist)
+        inv = inv - out
+    for shift in (3, 4, 5):
+        half = 1 << shift
+        right = _dot(_upper(between(shift), half), inv)
+        yield
+        inv = inv - _back(_dot(_upper(inv, half), _back(right, half)), half)
+        yield
+
+    carry = seg_col == before
+    since_start = jnp.where(carry, jnp.exp(g), 0.0)
+    tv = _dot(inv, v * beta)
+    yield
+    tk = _dot(inv, k * since_start * beta)
+    yield
+    qs = q * since_start * scale
+    total = _held(g, _KERNEL_CHUNK, _KERNEL_CHUNK - 1)
+    in_last = seg_col == _held(seg_col, _KERNEL_CHUNK, _KERNEL_CHUNK - 1)
+    keep = jnp.where(in_last, k * jnp.exp(total - g), 0.0)
+    ends = (_KERNEL_CHUNK - 1, n - 1)
+    # the state lies [D_k, D_v]: a chunk's decay scales its rows
+    in_d = (jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1))
+    decay = [jnp.sum(jnp.where(in_d & carry[at:at + 1], jnp.exp(g[at:at + 1]), 0.0), axis=1,
+                     keepdims=True) for at in ends]
+    return qs, tk, tv, p, [keep[:_KERNEL_CHUNK].T, keep[_KERNEL_CHUNK:].T], decay
+
+
+def _chunks_in_order(laid, state, o_ref, head: int):
+    """The chunks of one head's tile against the state [D_k, D_v], in order:
+    ``laid`` is :func:`_pair_of_chunks`' results for the tile's pairs.
+    Writes ``o_ref[0, head]``, returns the state after the tile. A generator
+    as :func:`_pair_of_chunks` is: two heads' chains go in turn."""
+    half = _KERNEL_CHUNK
+    for i, (qs, tk, tv, p, keep_t, decay) in enumerate(laid):
+        u = jnp.zeros(qs.shape, jnp.float32)
+        for c in range(2):
+            own = slice(c * half, (c + 1) * half)
+            seen = _dot(jnp.concatenate([qs[own], tk[own]]), state)                   # [128, D_v]
+            yield
+            u_c = tv[own] - seen[half:]
+            state = state * decay[c] + _dot(keep_t[c], u_c)
+            yield
+            # p is zero between the chunks: the other chunk's rows of u count for nothing
+            u = jnp.concatenate([u_c, u[half:]]) if c == 0 else jnp.concatenate([u[:half], u_c])
+            o_ref[0, head, i * _PAIR + c * half: i * _PAIR + (c + 1) * half] = seen[:half] + _dot(p[own], u)
+            yield
+    return state
+
+
+def _in_turn(generators):
+    """Run the generators a step of each in turn; their return values. The
+    TPU compiler schedules a kernel's straight line about in the order it
+    is written: a pair's products wait on each other, and what fills the
+    matrix unit meanwhile has to stand next to them (a layer of 2 x 64 heads
+    of 8,192 tokens on a v5e: 33.3 ms one pair after the other, 23.0 four in
+    turn, 18.7 with two heads' chains in turn as well)."""
+    out, live = [None] * len(generators), list(enumerate(generators))
+    while live:
+        for i, gen in list(live):
+            try:
+                next(gen)
+            except StopIteration as done:
+                out[i] = done.value
+                live.remove((i, gen))
+    return out
+
+
+def _delta_rule_kernel(seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
+                       state_ref, before_ref, *, scale: float):
+    """One tile of the heads of a grid step: every pair of chunks laid out in
+    VMEM, then each head's chunks in order against its state, which a
+    scratch carries from tile to tile."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first():
+        state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
+        before_ref[...] = jnp.full(before_ref.shape, -2, jnp.int32)
+
+    heads, tile, d = q_ref.shape[1:]
+    half = _KERNEL_CHUNK
+    pairs, last = [], before_ref[:1]
+    for at in range(0, tile, _PAIR):
+        rows = slice(at, at + _PAIR)
+        seg_col = seg_col_ref[0, rows]
+        before = jnp.concatenate([jnp.broadcast_to(last, (half, d)),
+                                  jnp.broadcast_to(seg_col[half - 1:half], (half, d))])
+        pairs += [_pair_of_chunks(
+            q_ref[0, h, rows], k_ref[0, h, rows], v_ref[0, h, rows], g_ref[0, h, rows],
+            beta_ref[0, h, 0, :, rows], seg_col, seg_row_ref[0, :1, rows], before, scale)
+            for h in range(heads)]
+        last = seg_col[_PAIR - 1:_PAIR]
+    laid = _in_turn(pairs)
+    states = _in_turn([_chunks_in_order(laid[h::heads], state_ref[h], o_ref, h)
+                       for h in range(heads)])
+    for h in range(heads):
+        state_ref[h] = states[h]
+    before_ref[...] = jnp.broadcast_to(last, before_ref.shape)
+
+
+def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, interpret=False):
+    """:func:`delta_rule_chunked` in chunks of 64 as one Pallas TPU kernel:
+    grid (rows, pairs of heads, tiles of ``tile`` tokens), a head's tiles in
+    order. Reads q, k, v, log_decay and beta once, writes o once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, l, d = q.shape
+    f32 = jnp.float32
+    heads = 2 - h % 2          # two chains in turn keep the matrix unit busier than one
+    segments = segments.astype(jnp.int32)
+    per_head = pl.BlockSpec((1, heads, tile, d), lambda bi, hi, ti: (bi, hi, ti, 0))
+    return pl.pallas_call(
+        functools.partial(_delta_rule_kernel, scale=scale),
+        grid=(b, h // heads, l // tile),
+        in_specs=[
+            pl.BlockSpec((1, tile, d), lambda bi, hi, ti: (bi, ti, 0)),
+            pl.BlockSpec((1, 8, tile), lambda bi, hi, ti: (bi, 0, ti)),
+            per_head, per_head, per_head, per_head,
+            pl.BlockSpec((1, heads, 1, 1, tile), lambda bi, hi, ti: (bi, hi, ti, 0, 0)),
+        ],
+        out_specs=per_head,
+        scratch_shapes=[pltpu.VMEM((heads, d, d), f32), pltpu.VMEM((8, d), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((b, h, l, d), f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.broadcast_to(segments[:, :, None], (b, l, d)),
+      jnp.broadcast_to(segments[:, None, :], (b, 8, l)),
+      q.astype(f32), k.astype(f32), v.astype(f32), log_decay.astype(f32),
+      beta.astype(f32).reshape(b, h, l // tile, 1, tile))
+
+
+def _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk: int):
+    """:func:`delta_rule_chunked` in plain JAX, for any chunk that is a power
+    of two and any L (the tail is padded with a segment of its own). A row is
+    cut into ``[B, H, n, C, D]`` by a reshape and the loop over the n chunks
+    slices that axis where it lies: nothing is transposed. At most ``_HEADS``
+    heads of a row are worked at a time (``lax.map`` over groups of heads,
+    again a reshape): what this form lays out, a dozen float32 arrays the
     size of q, is that many heads', whatever the batch."""
-    if chunk & (chunk - 1):
-        raise ValueError(f"chunk must be a power of two, got {chunk}")
     b, h, l, d = q.shape
     f32 = jnp.float32
     pad = -l % chunk
@@ -224,3 +491,18 @@ def delta_rule_chunked(q, k, v, log_decay, beta, segments, scale, chunk: int = 6
     o = jax.lax.map(lambda xs: _chunked_heads(*xs, scale),
                     (cut(q), cut(k), cut(v), cut(log_decay), cut(beta), seg))
     return o.reshape(b, h, n * chunk, d)[:, :, :l]
+
+
+def delta_rule_chunked(q, k, v, log_decay, beta, segments, scale, chunk: int = 64):
+    """The same recurrence ``chunk`` tokens at a time (module docstring).
+    Shapes as :func:`delta_rule_recurrent`, head-major ``[B, H, L, D]`` as a
+    projection writes them. On a TPU, for shapes the kernel takes
+    (:func:`fused_tile`), one Pallas kernel; elsewhere the plain JAX form,
+    the same algorithm with its intermediates in memory, which the tests
+    hold the interpreted kernel to."""
+    if chunk & (chunk - 1):
+        raise ValueError(f"chunk must be a power of two, got {chunk}")
+    tile = fused_tile(q.shape, chunk)
+    if tile is not None:
+        return _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile)
+    return _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk)

@@ -1159,10 +1159,17 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
         (``scan`` is ``{}`` where the pattern has no delta-rule layer)
 
     The head's logits exist a block of ``cfg.head_block`` tokens at a time."""
+    from tpu_tfrecord.metrics import METRICS
+
     x, visits, dropped, probes = pattern_hidden(params, tokens, segment_ids, cfg, sample_at,
                                                 probe_head)
     if "kda" not in cfg.layer_pattern:
         probes["scan"] = {}
+    # every delta-rule layer of a program has one shape, so one answer of the
+    # function that decides the dispatch (as the program is traced, not as it runs)
+    fused = _la.fused_tile((tokens.shape[0], cfg.kda_heads, cfg.max_len, cfg.kda_head_dim),
+                           cfg.kda_chunk) is not None
+    METRICS.gauge("kda.fused_layers", cfg.layer_pattern.count("kda") if fused else 0)
     b, l, d = x.shape
     with jax.named_scope("tfr.lm_head"):
         xn = weighted_rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -196,6 +196,7 @@ GAUGES: Dict[str, str] = {
     "lm.fsdp_param_bytes": "per-device at-rest param bytes under the fsdp layout",
     "moe.dropped_fraction": "latest per-step dropped-token fraction",
     "moe.visits_max_over_mean": "held experts, latest step: the busiest over the mean (most uneven layer)",
+    "kda.fused_layers": "pattern LM, the score program last traced: delta-rule layers whose recurrence took the Pallas kernel (0 off a TPU)",
     "moe.gate_entropy": "latest per-step router gate entropy",
     "moe.expert_imbalance": "latest per-step expert imbalance",
     "pipeline.bubble_fraction": "latest per-step pipeline bubble fraction",
