@@ -221,6 +221,41 @@ class TestOneChip:
         q_sized = "f32[" + ",".join(map(str, q_shape)) + "]"
         assert sum(bool(re.match(rf"\s*(ROOT )?%?\S+ = {re.escape(q_sized)}", line)) for line in scan) <= 4 * 3
 
+    def test_the_selection_kernel_at_the_cells_shape(self, one_chip):
+        """``deepseek_v32_exp_ep16.score``'s selection, 64 index heads of 128
+        over one row of 16,384 tokens, 2,048 keys a query: the kernel fits
+        VMEM with a block's scores as its scratch, and nothing but the mask
+        (one byte a pair) and the counts leaves it: no float32 score reaches
+        the chip's main memory."""
+        from tpu_tfrecord.models import sparse_attn
+
+        l = 16384
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for shape, dtype in (
+            ((1, 64, l, 128), jnp.bfloat16), ((1, l, 128), jnp.bfloat16),
+            ((1, l, 64), jnp.float32), ((1, l), jnp.int32))]
+        compiled = jax.jit(lambda q, k, w, s: sparse_attn._select_fused(
+            q, k, w, s, 2048, (sparse_attn._BLOCK_Q, sparse_attn._BLOCK_K))).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        assert l * l + l * 4 <= mem.output_size_in_bytes < l * l + l * 4 + 4096   # the mask, the counts
+        assert mem.temp_size_in_bytes < l * l // 8
+
+    def test_the_attention_kernel_under_a_selection_at_the_cells_shape(self, one_chip):
+        """Latent attention's 192-wide keys against 128-wide values over 16,384
+        tokens with the mask as a fifth input, one [1024, 1024] block of it a
+        pair of blocks (4 heads here: the grid only repeats over the 128)."""
+        from tpu_tfrecord.models.attention import flash_attention_widths
+
+        l = 16384
+        q = jax.ShapeDtypeStruct((1, 4, l, 192), jnp.bfloat16, sharding=one_chip)
+        v = jax.ShapeDtypeStruct((1, 4, l, 128), jnp.bfloat16, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((1, l), jnp.int32, sharding=one_chip)
+        keep = jax.ShapeDtypeStruct((1, l, l), jnp.int8, sharding=one_chip)
+        compiled = jax.jit(lambda q, k, v, s, m: flash_attention_widths(
+            q, k, v, s, 0.135, 1024, 1024, keep=m)).lower(q, q, v, segs, keep).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.memory_analysis().output_size_in_bytes == 2 * 4 * l * 128
+
     def test_lm_train_step_on_dp(self, topo):
         """examples/train_lm.py's widths on a one-device ``data`` mesh."""
         mesh = Mesh(np.array(topo.devices[:1]), ("data",))

@@ -105,7 +105,8 @@ def attention_reference(
     return jnp.einsum("bhlm,bmhd->blhd", probs, v.astype(jnp.float32)).astype(q.dtype)
 
 
-def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block: int = 1024):
+def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block: int = 1024,
+                        keep=None):
     """Causal softmax attention within ``segments``, by key blocks: the
     same answer as ``attention_reference(causal=True, segments=...)``
     without ever holding a ``[B, H, L, L]`` array. q [B, L, H, D], k/v
@@ -119,7 +120,9 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
     pair of ``block`` queries and ``block`` keys costs ``[B, H, block, block]``
     float32 scores. A key block of other documents only leaves the running
     sum untouched: every probability is multiplied by its mask, so a row
-    that has seen nothing yet carries zeros, not exp(0)."""
+    that has seen nothing yet carries zeros, not exp(0). ``keep`` [B, L, L]
+    (non-zero = query row may see key column) narrows the mask further: a
+    learned selection (``sparse_attn.select_keys``)."""
     b, l, h, d = q.shape
     hkv, dv = k.shape[2], v.shape[-1]
     if h % hkv:
@@ -141,12 +144,14 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
                                 preferred_element_type=jnp.float32)
             mask = (sq[:, :, None] == segments[:, None, k0:k1]) & (
                 at[q0:q1, None] >= at[None, k0:k1])
+            if keep is not None:
+                mask = mask & (keep[:, q0:q1, k0:k1] != 0)
             mask = mask[:, None, None]
             new_top = jnp.maximum(top, jnp.where(mask, scores, _NEG).max(axis=-1))
             probs = jnp.where(mask, jnp.exp(scores - new_top[..., None]), 0.0)
-            keep = jnp.exp(top - new_top)
-            total = total * keep + probs.sum(axis=-1)
-            acc = acc * keep[..., None] + jnp.einsum(
+            fade = jnp.exp(top - new_top)
+            total = total * fade + probs.sum(axis=-1)
+            acc = acc * fade[..., None] + jnp.einsum(
                 "bkgqm,bmkd->bkgqd", probs.astype(v.dtype), v[:, k0:k1],
                 preferred_element_type=jnp.float32)
             top = new_top
@@ -163,13 +168,16 @@ _MASKED = -1e30  # _NEG as a Python number: a kernel captures no array
 
 
 def _flash_widths_kernel(lo_ref, hi_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, scale: float, block_q: int, block_k: int):
+                         m_ref, l_ref, acc_ref, *, scale: float, block_q: int, block_k: int,
+                         keep_ref=None):
     """One (query block, key block) pair of one head: scores stay on the
     chip, the running maximum and sum are kept 128 lanes wide (every lane
     the same), the weighted values are divided by the sum once, at the last
     key block. A key block wholly after the query block, or one that holds
     no document of the query block's (``lo_ref`` / ``hi_ref``: the least and
-    the largest segment id of each block of ``block_k`` tokens), is skipped."""
+    the largest segment id of each block of ``block_k`` tokens), is skipped.
+    ``keep_ref`` [1, block_q, block_k] int8, where given, narrows the mask to
+    the pairs it marks non-zero."""
     from jax.experimental import pallas as pl
 
     bi, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
@@ -189,7 +197,10 @@ def _flash_widths_kernel(lo_ref, hi_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref
         same = jnp.tile(qseg_ref[0], (1, block_k // _LANES)) == kseg_ref[0, :1]
         # a row that has met no key of its document yet weighs what it sees by
         # exp(0); the first real score sends that to exp(-1e30) = 0
-        scores = jnp.where(same & (cols <= rows), scores, _MASKED)
+        seen = same & (cols <= rows)
+        if keep_ref is not None:
+            seen = seen & (keep_ref[0].astype(jnp.int32) != 0)
+        scores = jnp.where(seen, scores, _MASKED)
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, scores.max(axis=1)[:, None])
         probs = jnp.exp(scores - jnp.tile(m_next, (1, block_k // _LANES)))
@@ -206,6 +217,13 @@ def _flash_widths_kernel(lo_ref, hi_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref
         o_ref[0, 0] = (acc_ref[...] / total).astype(o_ref.dtype)
 
 
+def _flash_widths_kept_kernel(lo_ref, hi_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref, keep_ref,
+                              o_ref, m_ref, l_ref, acc_ref, **cut):
+    """:func:`_flash_widths_kernel` with a selection: ``keep_ref`` is its last input."""
+    _flash_widths_kernel(lo_ref, hi_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
+                         l_ref, acc_ref, keep_ref=keep_ref, **cut)
+
+
 def _pair_needed(lo_ref, hi_ref, bi, qi, ki, block_q: int, block_k: int):
     """Does key block ``ki`` hold a key some query of block ``qi`` may see:
     one at or before the block's last query, of a document the block holds?
@@ -220,7 +238,7 @@ def _pair_needed(lo_ref, hi_ref, bi, qi, ki, block_q: int, block_k: int):
 
 
 def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
-                           block_k: int = 1024):
+                           block_k: int = 1024, keep=None):
     """Causal attention inside ``segments`` as a Pallas TPU kernel, for
     queries and keys of one width and values of another (latent attention:
     192 against 128; JAX's own flash kernel takes one width, and only 128s).
@@ -228,7 +246,10 @@ def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
     -> [B, H, L, Dv] in q's dtype. L is whole blocks, ``block_q`` whole
     ``block_k``s; ``block_k`` and Dv are whole 128s. A pair of blocks that
     share no document costs a grid step and nothing else: in packed rows of
-    many documents most pairs under the diagonal do not. Forward only."""
+    many documents most pairs under the diagonal do not. ``keep`` [B, L, L]
+    int8 tells the kernel which keys a query may see beside that (a learned
+    selection: a pair it marks 0 is masked; one block of it is read a pair
+    of blocks, for every head). Forward only."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -247,7 +268,11 @@ def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
         last = ((qi + 1) * block_q - 1) // block_k
         return jnp.where(_pair_needed(lo_ref, hi_ref, bi, qi, ki, block_q, block_k), ki, last)
 
-    kernel = functools.partial(_flash_widths_kernel, scale=scale, block_q=block_q, block_k=block_k)
+    kernel = functools.partial(
+        _flash_widths_kernel if keep is None else _flash_widths_kept_kernel,
+        scale=scale, block_q=block_q, block_k=block_k)
+    selection = [] if keep is None else [(keep, pl.BlockSpec(
+        (1, block_q, block_k), lambda bi, hi, qi, ki, *r: (bi, qi, key_block(bi, qi, ki, *r))))]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -262,6 +287,7 @@ def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
                              lambda bi, hi, qi, ki, *r: (bi, hi // rep, key_block(bi, qi, ki, *r), 0)),
                 pl.BlockSpec((1, 1, block_k, dv),
                              lambda bi, hi, qi, ki, *r: (bi, hi // rep, key_block(bi, qi, ki, *r), 0)),
+                *[spec for _, spec in selection],
             ],
             out_specs=pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki, *_: (bi, hi, qi, 0)),
             scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -271,7 +297,8 @@ def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
     )(lo, hi, jnp.broadcast_to(segments[:, :, None], (b, l, _LANES)),
-      jnp.broadcast_to(segments[:, None, :], (b, _SUBLANES, l)), q, k, v)
+      jnp.broadcast_to(segments[:, None, :], (b, _SUBLANES, l)), q, k, v,
+      *[a for a, _ in selection])
 
 
 def _ring_attention_local(

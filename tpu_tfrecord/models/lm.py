@@ -69,6 +69,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tpu_tfrecord.models import linear_attn as _la
 from tpu_tfrecord.models import moe as _moe
 from tpu_tfrecord.models import pipeline as _pipeline
+from tpu_tfrecord.models import sparse_attn as _sa
 from tpu_tfrecord.models.attention import (
     attention_reference, blockwise_attention, flash_attention_widths, ring_attention,
 )
@@ -802,7 +803,12 @@ class PatternLMConfig:
     qk_rope_dim: int = 8           # ... its rotary width (the key's is one head shared by all),
     v_head_dim: int = 16           # ... its value width,
     kv_rank: int = 32              # ... the rank of the latent that keys and values are expanded from
+    q_rank: int = 0                # ... and of the normed latent its queries are expanded from (0: one matrix)
     rope_theta: float = 10000.0
+    rope_scaling: Tuple[float, ...] = ()  # YaRN: (factor, original length, beta_fast, beta_slow); () = none
+    index_heads: int = 0           # latent-attention layer's indexer: heads of ``index_dim`` a query,
+    index_dim: int = 0             # ... against ONE key of that width a token,
+    index_topk: int = 0            # ... and the keys a query attends: its best so many (0: every key, no indexer)
     d_dense: int = 64              # width of a dense feed-forward part
     n_experts: int = 16            # the router's width: every expert of the layer
     experts_held: int = 16         # how many of them this chip holds ...
@@ -812,6 +818,8 @@ class PatternLMConfig:
     n_shared: int = 1
     routed_scale: float = 1.0
     router_bias: bool = False      # a per-expert bias beside the router: it picks, it never weighs
+    n_group: int = 1               # the router's group limit: the experts in so many equal runs,
+    topk_group: int = 1            # ... of which a token's choice may touch so many (moe.route_top_k)
     norm_eps: float = 1e-5
     max_len: int = 64              # L: a row is L + 1 tokens
     dtype: Any = jnp.bfloat16
@@ -876,6 +884,17 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
     for kind in cfg.layer_pattern:
         if kind not in MIXERS:
             raise ValueError(f"layer_pattern names {kind!r}; the mixers are {MIXERS}")
+    if cfg.q_rank:  # the query through a normed latent, as the keys and values go
+        wq = mixers["mla"].pop("wq")[0]
+        mixers["mla"].update({"wq_a": ((d, cfg.q_rank), dt), "q_norm": ((cfg.q_rank,), f32),
+                              "wq_b": ((cfg.q_rank, wq[1]), dt)})
+    if cfg.index_topk:
+        if not cfg.q_rank:
+            raise ValueError("the indexer's queries come from the query latent: index_topk needs q_rank")
+        mixers["mla"].update({
+            "wq_idx": ((cfg.q_rank, cfg.index_heads * cfg.index_dim), dt),
+            "wk_idx": ((d, cfg.index_dim), dt), "k_idx_norm": ((cfg.index_dim,), f32),
+            "k_idx_bias": ((cfg.index_dim,), f32), "w_idx": ((d, cfg.index_heads), dt)})
     if cfg.n_shared < 1:
         moe.pop("shared")
     if cfg.router_bias:
@@ -915,6 +934,8 @@ def pattern_init_params(rng: jax.Array, cfg: PatternLMConfig) -> Dict[str, Any]:
             value = jax.random.normal(key, shape)
         elif name == "router_bias":
             value = jax.random.normal(key, shape) * 0.05
+        elif name == "k_idx_bias":
+            value = jax.random.normal(key, shape) * 0.1
         else:
             value = jax.random.normal(key, shape) * shape[-2] ** -0.5
         out.append(value.astype(dtype))
@@ -949,9 +970,12 @@ def _flash_attend(q, k, v, segments, block: int):
                                   block_k=min(512, block), block_b=1))
 
 
-def _attend(q, k, v, segments, block: int):
+def _attend(q, k, v, segments, block: int, scale=None, keep=None):
     """Causal attention inside each document; q [B, H, L, D], k [B, Hkv, L, D],
-    v [B, Hkv, L, Dv] -> [B, H, L, Dv], scores scaled by D ** -0.5. On a TPU,
+    v [B, Hkv, L, Dv] -> [B, H, L, Dv], scores scaled by ``scale`` (D ** -0.5
+    where none is given), over the keys ``keep`` [B, L, L] marks non-zero
+    (every key of the document at or before the query where none is given:
+    ``sparse_attn.select_keys`` makes one). On a TPU,
     for rows of whole blocks of 128s, a Pallas kernel: JAX's own where q, k
     and v share a width of whole 128s (it takes no other), and
     ``attention.flash_attention_widths`` where the values are narrower than
@@ -962,12 +986,12 @@ def _attend(q, k, v, segments, block: int):
     kernel to it)."""
     (l, d), dv, tile = q.shape[2:], v.shape[-1], min(block, q.shape[2])
     if jax.default_backend() == "tpu" and dv % 128 == 0 and tile % 128 == 0 and l % tile == 0:
-        if d == dv:
+        if d == dv and scale is None and keep is None:
             return _flash_attend(q, k, v, segments, block)
-        return flash_attention_widths(q, k, v, segments, d ** -0.5, block, block)
+        return flash_attention_widths(q, k, v, segments, scale or d ** -0.5, block, block, keep=keep)
     out = blockwise_attention(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segments,
-        block=block)
+        scale=scale, block=block, keep=keep)
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -1000,12 +1024,16 @@ def segment_positions(segments):
     return jnp.where(segments != 0, at - first, 0)
 
 
-def rotary(x, positions, theta: float):
+def rotary(x, positions, theta: float, scaling=()):
     """x [B, H, L, R] turned by its tokens' ``positions`` [B, L]: the pair
     (i, i + R/2) by the angle ``position * theta ** (-2i / R)``, angles and
-    products in float32, rounded to x's dtype."""
+    products in float32, rounded to x's dtype. ``scaling``: YaRN's four
+    numbers, which slow the frequencies that turn too rarely over the
+    original length to have been learnt (``sparse_attn.yarn_blend``)."""
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if scaling:
+        freq = freq * _sa.yarn_blend(half, theta, scaling)
     angle = positions.astype(jnp.float32)[:, None, :, None] * freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
@@ -1014,28 +1042,95 @@ def rotary(x, positions, theta: float):
 
 def mla_mixer(p, x, segments, cfg: PatternLMConfig):
     """The latent-attention layer in its expanded (prefill) form: queries
-    projected whole, keys and values expanded per head from one normed
+    projected whole (or, with ``cfg.q_rank``, expanded from a normed latent
+    of that rank), keys and values expanded per head from one normed
     latent of ``kv_rank``, a rotary part on every query head and ONE rotary
-    key head shared by all, positions that restart at every document;
+    key head shared by all, positions that restart at every document (YaRN's
+    frequencies and softmax gain with ``cfg.rope_scaling``);
     causal softmax inside each document over ``qk_nope_dim + qk_rope_dim``
-    wide queries and keys against ``v_head_dim`` wide values. x [B, L, D].
-    Heads are written head-major ``[B, H, L, .]`` by the projections."""
+    wide queries and keys against ``v_head_dim`` wide values; with
+    ``cfg.index_topk`` over the keys an indexer chose (:func:`index_select`).
+    x [B, L, D]. Heads are written head-major ``[B, H, L, .]`` by the projections."""
+    return mla_mixer_probed(p, x, segments, cfg)[0]
+
+
+def mla_mixer_probed(p, x, segments, cfg: PatternLMConfig, sample_at=None):
+    """(:func:`mla_mixer`'s y, :func:`index_select`'s record of the selection
+    or None where the layer has no indexer)."""
     (b, l, d), h = x.shape, cfg.n_heads
     dn, dr, dv, rank = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, cfg.kv_rank
+    yarn = (tuple(cfg.rope_scaling),) if cfg.rope_scaling else ()
+
+    def turn(a):  # by the tokens' positions in their own documents
+        return rotary(a, at, cfg.rope_theta, *yarn)
+
     with jax.named_scope("tfr.mla_proj"):
         u = weighted_rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("bld,dhk->bhlk", u, p["wq"].reshape(d, h, dn + dr))
+        if cfg.q_rank:
+            c_q = weighted_rms_norm(u @ p["wq_a"], p["q_norm"], cfg.norm_eps)
+            q = jnp.einsum("blr,rhk->bhlk", c_q, p["wq_b"].reshape(cfg.q_rank, h, dn + dr))
+        else:
+            q = jnp.einsum("bld,dhk->bhlk", u, p["wq"].reshape(d, h, dn + dr))
         latent = u @ p["wkv_a"]                                          # [B, L, rank + dr]
         c = weighted_rms_norm(latent[..., :rank], p["kv_norm"], cfg.norm_eps)
         kv = jnp.einsum("blr,rhk->bhlk", c, p["wkv_b"].reshape(rank, h, dn + dv))
         at = segment_positions(segments)
-        k_pe = rotary(latent[:, None, :, rank:], at, cfg.rope_theta)
-        q = jnp.concatenate([q[..., :dn], rotary(q[..., dn:], at, cfg.rope_theta)], axis=-1)
+        k_pe = turn(latent[:, None, :, rank:])
+        q = jnp.concatenate([q[..., :dn], turn(q[..., dn:])], axis=-1)
         k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (b, h, l, dr))], axis=-1)
+    chosen, index = {}, None
+    if yarn:
+        chosen["scale"] = (dn + dr) ** -0.5 * _sa.yarn_softmax_gain(cfg.rope_scaling)
+    if cfg.index_topk:
+        chosen["keep"], index = index_select(p, u, c_q, turn, at, segments, cfg, sample_at)
     with jax.named_scope("tfr.mla_attn"):
-        att = _attend(q, k, kv[..., dn:], segments, cfg.attn_block)
+        att = _attend(q, k, kv[..., dn:], segments, cfg.attn_block, **chosen)
     with jax.named_scope("tfr.mla_proj"):
-        return jnp.einsum("bhlk,hkd->bld", att, p["wo"].reshape(h, dv, d))
+        return jnp.einsum("bhlk,hkd->bld", att, p["wo"].reshape(h, dv, d)), index
+
+
+def index_select(p, u, c_q, turn, at, segments, cfg: PatternLMConfig, sample_at=None):
+    """The lightning indexer of a latent-attention layer and its selection:
+    ``index_heads`` queries of ``index_dim`` from the query latent c_q
+    [B, L, q_rank], ONE key a token from the normed input u [B, L, D] through
+    a LayerNorm, the layer's rotary ``turn`` on the first ``qk_rope_dim`` columns
+    of both (``at``: the positions it turns by), a weight a head from u; then
+    ``sparse_attn.select_keys``: every query's ``index_topk`` best keys inside
+    its own document. Returns (keep [B, L, L] int8, record): ``record["counts"]``
+    int32 [2], the keys kept by and the candidates of the row's real queries;
+    with ``sample_at`` [B, S] also ``record["scan"]`` = {"k_index" [B, L, Di]}
+    and ``record["router"]`` = what the selection was made from at those
+    positions, float32: "q_index" [B, S, Hi, Di], "w_index" [B, S, Hi], "kept"
+    [B, S, L] int8 (the mask's rows), "index_pos" and "index_start" [B, S] (a
+    position's index in its document and its document's first index in the row)."""
+    hi, di, dr, f32 = cfg.index_heads, cfg.index_dim, cfg.qk_rope_dim, jnp.float32
+    with jax.named_scope("tfr.dsa_proj"):
+        q_idx = jnp.einsum("blr,rhk->bhlk", c_q, p["wq_idx"].reshape(cfg.q_rank, hi, di))
+        k32 = (u @ p["wk_idx"]).astype(f32)
+        k32 = k32 - k32.mean(axis=-1, keepdims=True)
+        k32 = k32 * jax.lax.rsqrt(jnp.mean(jnp.square(k32), axis=-1, keepdims=True) + cfg.norm_eps)
+        k_idx = (k32 * p["k_idx_norm"] + p["k_idx_bias"]).astype(u.dtype)[:, None]
+        q_idx = jnp.concatenate([turn(q_idx[..., :dr]), q_idx[..., dr:]], axis=-1)
+        k_idx = jnp.concatenate([turn(k_idx[..., :dr]), k_idx[..., dr:]], axis=-1)[:, 0]
+        w = jnp.dot(u, p["w_idx"], preferred_element_type=f32) * (hi ** -0.5 * di ** -0.5)
+        # the selection and the record of it read these very arrays (see pattern_hidden's note)
+        q_idx, k_idx, w = jax.lax.optimization_barrier((q_idx, k_idx, w))
+    with jax.named_scope("tfr.dsa_index"):
+        keep, kept = _sa.select_keys(q_idx, k_idx, w, segments, cfg.index_topk, cfg.attn_block)
+        real = segments != 0
+        record = {"counts": jnp.stack([jnp.where(real, kept, 0).sum(),
+                                       jnp.where(real, at + 1, 0).sum()])}
+    if sample_at is not None:
+        def take(a):
+            return jnp.take_along_axis(a, sample_at.reshape(sample_at.shape + (1,) * (a.ndim - 2)),
+                                       axis=1)
+
+        pos = take(at)
+        record["scan"] = {"k_index": k_idx.astype(f32)}
+        record["router"] = {"q_index": take(jnp.swapaxes(q_idx, 1, 2)).astype(f32),
+                            "w_index": take(w), "kept": take(keep), "index_pos": pos,
+                            "index_start": sample_at - pos}
+    return keep, record
 
 
 def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
@@ -1098,7 +1193,11 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
     inputs: ``router`` (with ``sample_at`` [B, S]: at those positions of
     every expert layer the router's input ``u`` [n_layers, B, S, D] and its
     ``experts`` and ``gates`` [n_layers, B, S, top_k]) and ``scan`` (with
-    ``probe_head``: :func:`kda_mixer`'s probe of the first delta-rule layer)."""
+    ``probe_head``: :func:`kda_mixer`'s probe of the first delta-rule layer).
+    Where latent-attention layers have an indexer, ``selected`` [layers, 2]
+    (:func:`index_select`'s counts) and, with ``sample_at``, the first expert
+    layer's selection: its keys under ``scan`` and the rest beside the
+    router's entries, each with a leading axis of 1."""
     l = tokens.shape[1] - 1
     if l != cfg.max_len:
         raise ValueError(
@@ -1108,10 +1207,17 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
     with jax.named_scope("tfr.embed"):
         x = params["embed"][tokens[:, :-1]]
     b, _, d = x.shape
-    visits, dropped, routed, probes = [], [], [], {}
+    visits, dropped, routed, probes, selected, selection = [], [], [], {}, [], None
     for kind, ffn, layer in zip(cfg.layer_pattern, ffn_kinds(cfg), params["layers"]):
         if kind == "gqa":
             x = x + gqa_mixer(layer, x, segments, cfg)
+        elif kind == "mla" and cfg.index_topk:
+            probed = selection is None and ffn == "moe"
+            y, index = mla_mixer_probed(layer, x, segments, cfg, sample_at if probed else None)
+            x = x + y
+            selected.append(index["counts"])
+            if probed and sample_at is not None:
+                selection, probes["scan"] = index["router"], index["scan"]
         elif kind == "mla":
             x = x + mla_mixer(layer, x, segments, cfg)
         else:
@@ -1128,10 +1234,18 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
             continue
         with jax.named_scope("tfr.moe_route"):
             u = weighted_rms_norm(x, layer["moe_norm"], cfg.norm_eps)
+            if cfg.n_group > 1:
+                # ONE array for the router and for the probe of it. Left alone, the compiler
+                # computes the norm once for each reader, the two fusions round a few
+                # elements in a thousand to different bfloat16 neighbours, and the probe held
+                # the router to inputs it never saw (gates 2.6e-4 apart on the chip where
+                # float32 reads 2e-7). Patterns without a group limit keep the program they
+                # had (tests/test_mla_lm.py holds their jaxprs).
+                u = jax.lax.optimization_barrier(u)
         y, n, lost, (experts, gates) = _moe.held_experts_apply(
             layer, u.reshape(b * l, d), held_offset=cfg.held_offset, top_k=cfg.top_k,
             routed_scale=cfg.routed_scale, tile=cfg.expert_tile,
-            valid=(segments != 0).reshape(b * l))
+            valid=(segments != 0).reshape(b * l), n_group=cfg.n_group, topk_group=cfg.topk_group)
         x = x + y.reshape(b, l, d)
         visits.append(n)
         dropped.append(lost)
@@ -1142,6 +1256,10 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
                            "gates": jnp.take_along_axis(gates.reshape(b, l, -1), at, axis=1)})
     if routed:
         probes["router"] = {k: jnp.stack([r[k] for r in routed]) for k in routed[0]}
+    if selected:
+        probes["selected"] = jnp.stack(selected)
+    if selection is not None:
+        probes["router"].update({k: a[None] for k, a in selection.items()})
     if not visits:  # no layer has experts
         return x, jnp.zeros((0, cfg.experts_held), jnp.int32), jnp.zeros((0,), jnp.int32), probes
     return x, jnp.stack(visits), jnp.stack(dropped), probes
@@ -1156,7 +1274,10 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     ``visits``  [n_layers, experts_held] int32, ``dropped`` [n_layers] int32
     ``probes``  :func:`pattern_hidden`'s: the router's inputs and choices at
         ``sample_at``, and with ``probe_head`` that head's recurrence
-        (``scan`` is ``{}`` where the pattern has no delta-rule layer)
+        (``scan`` is ``{}`` where the pattern has no delta-rule layer and no
+        indexer; with an indexer it and ``router`` carry a layer's selection)
+    ``selected`` [layers, 2] int32, only where layers have an indexer: the keys
+        kept by and the candidates of the real queries (:func:`record_selected`)
 
     The head's logits exist a block of ``cfg.head_block`` tokens at a time."""
     from tpu_tfrecord.metrics import METRICS
@@ -1164,12 +1285,16 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     x, visits, dropped, probes = pattern_hidden(params, tokens, segment_ids, cfg, sample_at,
                                                 probe_head)
     if "kda" not in cfg.layer_pattern:
-        probes["scan"] = {}
+        probes.setdefault("scan", {})
     # every delta-rule layer of a program has one shape, so one answer of the
     # function that decides the dispatch (as the program is traced, not as it runs)
     fused = _la.fused_tile((tokens.shape[0], cfg.kda_heads, cfg.max_len, cfg.kda_head_dim),
                            cfg.kda_chunk) is not None
     METRICS.gauge("kda.fused_layers", cfg.layer_pattern.count("kda") if fused else 0)
+    # likewise the selection: one shape for every layer that has an indexer
+    in_kernel = cfg.index_topk and _sa.select_tile(
+        (tokens.shape[0], cfg.index_heads, cfg.max_len, cfg.index_dim), cfg.index_topk) is not None
+    METRICS.gauge("dsa.kernel_layers", cfg.layer_pattern.count("mla") if in_kernel else 0)
     b, l, d = x.shape
     with jax.named_scope("tfr.lm_head"):
         xn = weighted_rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -1186,8 +1311,11 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
         sampled = jnp.take_along_axis(xn, sample_at[:, :, None], axis=1)
         sample_logits = jnp.einsum("bsd,dv->bsv", sampled, params["head"],
                                    preferred_element_type=jnp.float32)
-    return {"logprob": logprob, "logits": sample_logits, "visits": visits, "dropped": dropped,
-            "probes": probes}
+    out = {"logprob": logprob, "logits": sample_logits, "visits": visits, "dropped": dropped,
+           "probes": probes}
+    if "selected" in probes:
+        out["selected"] = probes.pop("selected")
+    return out
 
 
 def record_moe_counters(visits, dropped) -> float:
@@ -1202,3 +1330,17 @@ def record_moe_counters(visits, dropped) -> float:
     METRICS.gauge("moe.visits_max_over_mean", round(uneven, 4))
     METRICS.count("moe.visits_dropped", int(np.asarray(dropped).sum()))
     return uneven
+
+
+def record_selected(selected) -> float:
+    """A step's selection counters (``score``'s ``selected``) into
+    ``metrics.METRICS``: the gauge ``dsa.selected_share``, the keys the
+    indexers kept over the candidates they chose from (a step's mean over its
+    layers and real queries; 1.0 while no document is longer than
+    ``index_topk``). Returns the gauge."""
+    from tpu_tfrecord.metrics import METRICS
+
+    kept, candidates = np.asarray(selected, np.float64).sum(axis=0)
+    share = float(kept / max(candidates, 1.0))
+    METRICS.gauge("dsa.selected_share", round(share, 6))
+    return share

@@ -557,16 +557,30 @@ def gated_ffn(x, w_gate, w_up, w_down):
     return jnp.dot(hidden.astype(x.dtype), w_down, preferred_element_type=f32)
 
 
-def route_top_k(x, router, top_k: int, routed_scale: float = 1.0, bias=None):
+def route_top_k(x, router, top_k: int, routed_scale: float = 1.0, bias=None, n_group: int = 1,
+                topk_group: int = 1):
     """Sigmoid scores over ALL experts in float32, the ``top_k`` largest,
     their gates renormalised to sum to ``routed_scale``. With ``bias`` [E]
     (a correction the trainer balances the load by) the experts are the
     ``top_k`` largest of ``scores + bias`` and the gates still come from the
-    scores alone: the bias picks and never weighs.
+    scores alone: the bias picks and never weighs. With ``n_group`` > 1 the
+    choice is group-limited: the experts lie in ``n_group`` equal runs, a
+    run's score is the sum of its two largest ``scores + bias`` (its largest
+    score where there is no bias), only the ``topk_group`` best runs stay,
+    and the ``top_k`` are chosen inside them.
     x [T, D], router [D, E] -> (experts [T, k] int32, gates [T, k] float32)."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST))
-    if bias is None:
+    if n_group > 1:
+        picking = scores if bias is None else scores + bias
+        runs = picking.reshape(scores.shape[0], n_group, -1)
+        run_score = runs.max(axis=-1) if bias is None else jax.lax.top_k(runs, 2)[0].sum(axis=-1)
+        _, best = jax.lax.top_k(run_score, topk_group)
+        stays = jnp.zeros(run_score.shape, bool).at[jnp.arange(scores.shape[0])[:, None], best].set(True)
+        picking = jnp.where(stays[:, :, None], runs, -jnp.inf).reshape(scores.shape)
+        _, experts = jax.lax.top_k(picking, top_k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
+    elif bias is None:
         top, experts = jax.lax.top_k(scores, top_k)
     else:
         _, experts = jax.lax.top_k(scores + bias, top_k)
@@ -575,7 +589,8 @@ def route_top_k(x, router, top_k: int, routed_scale: float = 1.0, bias=None):
 
 
 def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: int,
-                       routed_scale: float = 1.0, tile: int = 256, valid=None):
+                       routed_scale: float = 1.0, tile: int = 256, valid=None,
+                       n_group: int = 1, topk_group: int = 1):
     """``shared(x) + sum of gate_e * expert_e(x)`` over the chosen experts
     THIS chip holds; what the absent experts would add is left out.
 
@@ -585,7 +600,8 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     ``held_offset + Eh``), optional ``shared`` with the same three names
     un-stacked (several shared experts are one unit of their summed width).
     x [T, D]; ``valid`` [T] bool: tokens that
-    are pads visit no expert (their rows get the shared expert only).
+    are pads visit no expert (their rows get the shared expert only);
+    ``n_group`` / ``topk_group``: :func:`route_top_k`'s group limit.
     Returns (y [T, D] in x's dtype, visits [Eh] int32 to each held expert,
     dropped: visits to held experts that were not computed, always 0,
     (experts [T, top_k] int32, gates [T, top_k] float32): the routing).
@@ -597,13 +613,24 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     write; a buffer for the worst case, every visit to a held expert, is
     T * top_k rows of x's dtype). Each token then reads its own ``top_k``
     rows back and sums them under its gates in float32: a gather, where a
-    scatter-add of the same rows costs three times as much a row on a TPU."""
+    scatter-add of the same rows costs three times as much a row on a TPU.
+
+    Where this chip holds less than an eighth of the experts there is no
+    buffer and no read-back: each tile's rows are added to their tokens as the
+    tile is computed, under their gates, in float32 (a scatter-add of ``tile``
+    rows; a token is in a tile once, so the sum has one order). The read-back
+    gathers ``top_k`` rows a token whatever the share, a row of 7,168 costs
+    0.12 microseconds to gather and 1.1 to scatter-add on a v5e, so the two
+    cross at a share of a ninth: with 16 of 256 held, 16,384 tokens make 8,192
+    visits a layer and the read-back would gather 131,072 rows (nearly all of
+    them the zero row) out of a 2 GB buffer sized for the worst case."""
     t, d = x.shape
     n_held = params["w_gate"].shape[0]
     f32 = jnp.float32
     with jax.named_scope("tfr.moe_route"):
+        grouped = {} if n_group == 1 else {"n_group": n_group, "topk_group": topk_group}
         experts, gates = route_top_k(x, params["router"], top_k, routed_scale,
-                                     params.get("router_bias"))
+                                     params.get("router_bias"), **grouped)
         local = experts - held_offset
         held = (local >= 0) & (local < n_held)
         if valid is not None:
@@ -624,24 +651,45 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     with jax.named_scope("tfr.moe_experts"):
         lane = jnp.arange(tile, dtype=jnp.int32)
 
-        def one_tile(j, carry):
-            laid, done = carry
+        def tile_of(j):
+            """Tile j: (the visits it holds, which of its rows are real, their expert's output)."""
             e = jnp.searchsorted(tiles_to, j, side="right").astype(jnp.int32)
             nth = j - (tiles_to[e] - n_tiles[e])
             rows = first[e] + nth * tile + lane
             real = rows < first[e] + visits[e]
-            token = order[jnp.minimum(rows, t * top_k - 1)] // top_k
-            y = gated_ffn(x[token], params["w_gate"][e], params["w_up"][e], params["w_down"][e])
+            visit = order[jnp.minimum(rows, t * top_k - 1)]
+            y = gated_ffn(x[visit // top_k], params["w_gate"][e], params["w_up"][e],
+                          params["w_down"][e])
+            return visit, real, y
+
+        def one_tile(j, carry):
+            laid, done = carry
+            _, real, y = tile_of(j)
             laid = jax.lax.dynamic_update_slice(laid, y.astype(x.dtype), (j * tile, 0))
             return laid, done + real.sum(dtype=jnp.int32)
 
-        # one spare row past the worst case stays zero: what a token reads for an absent expert
-        laid = jnp.zeros((max_tiles * tile + 1, d), x.dtype)
-        laid, done = jax.lax.fori_loop(0, tiles_to[-1], one_tile, (laid, jnp.int32(0)))
-        out = jnp.zeros((t, d), f32)
-        for slot in range(top_k):
-            out = out + jnp.where(held[:, slot], gates[:, slot], 0.0)[:, None] * laid[
-                lies_at[:, slot]].astype(f32)
+        if n_held * 8 >= params["router"].shape[1]:
+            # one spare row past the worst case stays zero: what a token reads for an absent expert
+            laid = jnp.zeros((max_tiles * tile + 1, d), x.dtype)
+            laid, done = jax.lax.fori_loop(0, tiles_to[-1], one_tile, (laid, jnp.int32(0)))
+            out = jnp.zeros((t, d), f32)
+            for slot in range(top_k):
+                out = out + jnp.where(held[:, slot], gates[:, slot], 0.0)[:, None] * laid[
+                    lies_at[:, slot]].astype(f32)
+        else:
+            flat_gates = gates.reshape(-1)
+
+            def add_tile(j, carry):
+                out, done = carry
+                visit, real, y = tile_of(j)
+                gate = jnp.where(real, flat_gates[visit], 0.0)
+                # a row past the run's end goes nowhere (index t is dropped)
+                out = out.at[jnp.where(real, visit // top_k, t)].add(
+                    gate[:, None] * y.astype(x.dtype).astype(f32), mode="drop")
+                return out, done + real.sum(dtype=jnp.int32)
+
+            out, done = jax.lax.fori_loop(0, tiles_to[-1], add_tile,
+                                          (jnp.zeros((t, d), f32), jnp.int32(0)))
     if "shared" in params:
         with jax.named_scope("tfr.moe_shared"):
             sh = params["shared"]

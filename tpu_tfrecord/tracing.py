@@ -76,6 +76,10 @@ ANNOTATIONS = {
                     "projection and norm, expansion to keys and values, rotary turns, out",
     "tfr.mla_attn": "pattern LM: the latent-attention layer's attention call alone (192-wide "
                     "queries and keys against 128-wide values inside each document)",
+    "tfr.dsa_proj": "pattern LM: a latent-attention layer's indexer: its queries from the query "
+                    "latent, its one key a token through a LayerNorm, rotary turns, the heads' weights",
+    "tfr.dsa_index": "pattern LM: the indexer's scores over every causal pair of a document, each "
+                     "query's exact k-th largest, and the mask of the keys it keeps",
     "tfr.dense_ffn": "pattern LM: a layer's dense gated feed-forward part (pre-norm, gate, up, down)",
     "tfr.moe_route": "held experts: pre-norm, scores over all experts, top-k, visits sorted by expert",
     "tfr.moe_experts": "held experts: the loop over the tiles of visits to the experts held here",

@@ -197,6 +197,8 @@ GAUGES: Dict[str, str] = {
     "moe.dropped_fraction": "latest per-step dropped-token fraction",
     "moe.visits_max_over_mean": "held experts, latest step: the busiest over the mean (most uneven layer)",
     "kda.fused_layers": "pattern LM, the score program last traced: delta-rule layers whose recurrence took the Pallas kernel (0 off a TPU)",
+    "dsa.kernel_layers": "pattern LM, the score program last traced: latent-attention layers whose selection took the Pallas kernel (0 off a TPU and without an indexer)",
+    "dsa.selected_share": "pattern LM, latest step recorded: keys the indexers kept over the causal candidates they chose from (lm.record_selected)",
     "moe.gate_entropy": "latest per-step router gate entropy",
     "moe.expert_imbalance": "latest per-step expert imbalance",
     "pipeline.bubble_fraction": "latest per-step pipeline bubble fraction",
