@@ -23,6 +23,7 @@ from tpu_tfrecord.metrics import METRICS
 from tpu_tfrecord.models import dsa_reference as ref, lm, moe, sparse_attn
 from tpu_tfrecord.models.attention import blockwise_attention, flash_attention_widths
 
+from test_mla_lm import kernel_inputs, plain_path
 from test_pattern_lm import documents_of, flat, packed_rows as older_rows, reference_weights
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -257,6 +258,54 @@ def test_the_attention_kernel_interpreted_under_a_selection_is_the_plain_path():
     dense = blockwise_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
                                 jnp.swapaxes(v, 1, 2), segs, scale=0.11, block=64)
     assert np.abs(np.asarray(want) - np.asarray(dense)).max() > 0.1
+
+
+def a_selection(segs, seed=6, share=0.3):
+    """keep [1, L, L] int8: a query's own key, and ``share`` of the keys before
+    it in its document, at random."""
+    segs = np.asarray(segs)[0]
+    at = np.arange(len(segs))
+    inside = (segs[:, None] == segs[None, :]) & (at[None, :] <= at[:, None])
+    keep = inside & (np.random.default_rng(seed).random(inside.shape) < share)
+    return (keep | np.eye(len(segs), dtype=bool))[None].astype(np.int8)
+
+
+#: selections that force each kind of pair on the kernel under a mask of kept keys:
+#: (lengths of the row's documents, row length, blocks, what to do to the selection)
+KEPT_KINDS = {
+    "one document over three blocks": ([768], 768, (256, 256), None),
+    "a boundary inside a block and one on an edge": ([300, 212, 256], 768, (256, 256), None),
+    "a strip of keys empty for some rows": ([768], 768, (256, 256), "strip"),
+    "rows that keep a single key": ([400, 368], 768, (256, 256), "single"),
+    "a query block of two passes": ([1024], 1024, (512, 512), "strip"),
+    "a query block of two key blocks": ([600, 424], 1024, (512, 256), None),
+}
+
+
+@pytest.mark.parametrize("case", KEPT_KINDS)
+def test_each_kind_of_pair_under_a_selection_is_the_plain_path(case):
+    """The kernel's kinds of pair (tests/test_mla_lm.py has them without a
+    selection) apply the kept keys beside their own mask: under the diagonal
+    no position is compared, on it a pass takes the keys its rows reach."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lengths, l, blocks, change = KEPT_KINDS[case]
+    q, k, v, segs = kernel_inputs(lengths, l, seed=8)
+    keep = a_selection(segs)
+    if change == "strip":       # rows 300-339 and 600-700 keep nothing of keys 128-255; one block's rows none of another's
+        keep[0, 300:340, 128:256] = keep[0, 600:701, 128:256] = 0
+        keep[0, 512:768, 0:256] = 0
+    if change == "single":      # a document's last rows and a block's first see themselves alone
+        for row in (*range(390, 400), *range(512, 530), 767):
+            keep[0, row] = 0
+            keep[0, row, row] = 1
+    keep = jnp.asarray(keep)
+    with pltpu.force_tpu_interpret_mode():
+        got = flash_attention_widths(q, k, v, segs, 0.09, *blocks, keep=keep)
+    want = plain_path(q, k, v, segs, 0.09, keep=keep)
+    real = np.asarray(segs[0] != 0)
+    np.testing.assert_allclose(np.asarray(got)[:, :, real], np.asarray(want)[:, :, real], atol=2e-5)
+    assert np.abs(np.asarray(want) - np.asarray(plain_path(q, k, v, segs, 0.09))).max() > 0.1
 
 
 def test_yarn_against_the_published_formula():

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from tpu_tfrecord.models import lm, mla_reference as ref, moe
-from tpu_tfrecord.models.attention import blockwise_attention, flash_attention_widths
+from tpu_tfrecord.models.attention import blockwise_attention, flash_attention_widths, pair_kinds
 
 from test_pattern_lm import documents_of, flat, packed_rows as older_rows, reference_weights
 
@@ -253,6 +253,87 @@ def test_the_kernel_for_wide_keys_is_blockwise_attention(blocks):
     real = np.asarray(segs[0] != 0)
     np.testing.assert_allclose(np.asarray(got)[:, :, real], np.asarray(want)[:, :, real],
                                atol=3e-2 if jax.default_backend() == "tpu" else 2e-5)
+
+
+def kernel_inputs(lengths, l, seed=4, heads=2):
+    """q, k, v [1, heads, l, 192 / 128] float32 and a row of documents of
+    ``lengths`` (ids from 1; pads, id 0, to the row's end)."""
+    r = np.random.default_rng(seed)
+    q, k = (jnp.asarray(r.standard_normal((1, heads, l, 192)), jnp.float32) for _ in range(2))
+    v = jnp.asarray(r.standard_normal((1, heads, l, 128)), jnp.float32)
+    ids = np.repeat(np.arange(1, len(lengths) + 1), lengths)
+    return q, k, v, jnp.asarray(np.pad(ids, (0, l - len(ids)))[None].astype(np.int32))
+
+
+def plain_path(q, k, v, segs, scale, keep=None):
+    return jnp.swapaxes(blockwise_attention(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segs, scale=scale,
+        block=64, keep=keep), 1, 2)
+
+
+def kinds_by_the_dense_mask(segs, block_q, block_k):
+    """(skipped, plain, masked) of the block pairs at or under the diagonal,
+    from the mask written out: none of a pair's elements seen, all, some."""
+    segs = np.asarray(segs)
+    at = np.arange(segs.shape[1])
+    count = [0, 0, 0]
+    for row in segs:
+        seen = (row[:, None] == row[None, :]) & (at[None, :] <= at[:, None])
+        for q0 in range(0, len(row), block_q):
+            for k0 in range(0, q0 + block_q, block_k):
+                part = seen[q0:q0 + block_q, k0:k0 + block_k]
+                count[0 if not part.any() else 1 if part.all() else 2] += 1
+    return tuple(count)
+
+
+#: rows that force each kind of pair on the kernel, with the kinds they must hold
+#: (lengths of the row's documents, row length, blocks, (skipped, plain, masked))
+KINDS = {
+    "one document over three blocks": ([768], 768, (256, 256), (0, 3, 3)),
+    "a boundary inside a block": ([300, 468], 768, (256, 256), (1, 0, 5)),
+    "a boundary on a block's edge": ([256, 512], 768, (256, 256), (2, 1, 3)),
+    "short documents, most pairs skipped": ([100, 30, 270, 40, 200, 128], 768, (128, 128), None),
+    "a query block of two passes": ([1024], 1024, (512, 512), (0, 1, 2)),
+    "a query block of two key blocks": ([600, 424], 1024, (512, 256), None),
+    "pads to the row's end": ([256, 300], 768, (256, 256), None),
+}
+
+
+@pytest.mark.parametrize("case", KINDS)
+def test_each_kind_of_pair_is_blockwise_attention(case):
+    """The kernel decides from scalars what a pair of blocks costs (skipped;
+    under the diagonal by segment ids alone, plain where they all agree; on
+    the diagonal by positions too, over the keys its rows reach): each, interpreted, against
+    the plain path, and ``pair_kinds`` against the mask written out."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lengths, l, blocks, kinds = KINDS[case]
+    q, k, v, segs = kernel_inputs(lengths, l)
+    if kinds is not None:
+        assert pair_kinds(segs, *blocks) == kinds
+    if sum(lengths) == l:   # a row's pads widen its last block's range of ids: more is computed than seen
+        assert pair_kinds(segs, *blocks) == kinds_by_the_dense_mask(segs, *blocks)
+    else:
+        assert pair_kinds(segs, *blocks)[0] <= kinds_by_the_dense_mask(segs, *blocks)[0]
+    with pltpu.force_tpu_interpret_mode():
+        got = flash_attention_widths(q, k, v, segs, 0.09, *blocks)
+    real = np.asarray(segs[0] != 0)
+    np.testing.assert_allclose(np.asarray(got)[:, :, real],
+                               np.asarray(plain_path(q, k, v, segs, 0.09))[:, :, real], atol=2e-5)
+
+
+def test_the_plain_share_of_one_long_document_is_120_of_136():
+    """``deepseek_v32_exp_ep16.score``'s rows: one document of 16,384 tokens in
+    blocks of 1,024; and a packed row of short documents, where few pairs are plain."""
+    from tpu_tfrecord.metrics import METRICS
+
+    cfg = program_cfg(attn_block=1024)
+    assert pair_kinds(np.ones((1, 16384), np.int32)) == (0, 120, 16)
+    share = lm.record_pair_kinds(np.ones((1, 16385), np.int32), cfg)
+    assert round(share, 3) == 0.882 and METRICS.gauge_value("mla.plain_pair_share") == round(120 / 136, 6)
+    packed = np.repeat(np.arange(1, 26), 656)[None, :16385]
+    assert lm.record_pair_kinds(packed, cfg) == 0.0
+    assert METRICS.gauge_value("mla.plain_pair_share") == 0.0
 
 
 def test_the_kernel_refuses_rows_that_are_not_whole_blocks():

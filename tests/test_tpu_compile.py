@@ -240,21 +240,25 @@ class TestOneChip:
         assert l * l + l * 4 <= mem.output_size_in_bytes < l * l + l * 4 + 4096   # the mask, the counts
         assert mem.temp_size_in_bytes < l * l // 8
 
-    def test_the_attention_kernel_under_a_selection_at_the_cells_shape(self, one_chip):
-        """Latent attention's 192-wide keys against 128-wide values over 16,384
-        tokens with the mask as a fifth input, one [1024, 1024] block of it a
-        pair of blocks (4 heads here: the grid only repeats over the 128)."""
+    @pytest.mark.parametrize("rows, heads, l, selection", [(1, 4, 16384, True), (2, 16, 8192, False)],
+                             ids=["under_a_selection", "packed_rows"])
+    def test_the_attention_kernel_at_the_cells_shape(self, one_chip, rows, heads, l, selection):
+        """Latent attention's 192-wide keys against 128-wide values: one row of
+        16,384 tokens with the mask of kept keys as a fifth input, one [1024,
+        1024] block of it a pair of blocks (4 heads here: the grid only repeats
+        over the 128), and ``kimi_vl_a3b_lm.score``'s two packed rows of 8,192
+        without one. Each kind of pair is a body of its own in the one kernel:
+        it fits VMEM, and a layer's call is one custom call."""
         from tpu_tfrecord.models.attention import flash_attention_widths
 
-        l = 16384
-        q = jax.ShapeDtypeStruct((1, 4, l, 192), jnp.bfloat16, sharding=one_chip)
-        v = jax.ShapeDtypeStruct((1, 4, l, 128), jnp.bfloat16, sharding=one_chip)
-        segs = jax.ShapeDtypeStruct((1, l), jnp.int32, sharding=one_chip)
-        keep = jax.ShapeDtypeStruct((1, l, l), jnp.int8, sharding=one_chip)
-        compiled = jax.jit(lambda q, k, v, s, m: flash_attention_widths(
-            q, k, v, s, 0.135, 1024, 1024, keep=m)).lower(q, q, v, segs, keep).compile()
-        assert "tpu_custom_call" in compiled.as_text()
-        assert compiled.memory_analysis().output_size_in_bytes == 2 * 4 * l * 128
+        q = jax.ShapeDtypeStruct((rows, heads, l, 192), jnp.bfloat16, sharding=one_chip)
+        v = jax.ShapeDtypeStruct((rows, heads, l, 128), jnp.bfloat16, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((rows, l), jnp.int32, sharding=one_chip)
+        keep = [jax.ShapeDtypeStruct((rows, l, l), jnp.int8, sharding=one_chip)] * selection
+        compiled = jax.jit(lambda q, k, v, s, *m: flash_attention_widths(
+            q, k, v, s, 0.135, 1024, 1024, *m)).lower(q, q, v, segs, *keep).compile()
+        assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") == 1
+        assert compiled.memory_analysis().output_size_in_bytes == 2 * rows * heads * l * 128
 
     def test_lm_train_step_on_dp(self, topo):
         """examples/train_lm.py's widths on a one-device ``data`` mesh."""

@@ -50,6 +50,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -165,22 +166,60 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
 
 _LANES, _SUBLANES = 128, 8
 _MASKED = -1e30  # _NEG as a Python number: a kernel captures no array
+# Queries a pass of the pair body takes, and query heads a grid step takes (the first that
+# divides). One layer of 128 heads over 16,384 tokens under a selection on a v5e, alone, read
+# while a pass still walked its keys in strips of 128 (PERF.md section 6, PR 35): passes of
+# 128 / 256 / 512 queries 86.6 / 86.6 / 89.0 ms; one, two, four heads a step 90.3 / 86.6 / 185
+# (a step costs 0.4 microseconds and its mask's block 1 MB); the parent 113.2
+_ROWS, _HEADS = 256, (2, 1)
+_VMEM_LIMIT = 64 * 2 ** 20  # two heads' blocks, twice, and their scratch: 11 MB beside a pass's scores
 
 
-def _flash_widths_kernel(lo_ref, hi_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, scale: float, block_q: int, block_k: int,
-                         keep_ref=None):
-    """One (query block, key block) pair of one head: scores stay on the
-    chip, the running maximum and sum are kept 128 lanes wide (every lane
-    the same), the weighted values are divided by the sum once, at the last
-    key block. A key block wholly after the query block, or one that holds
-    no document of the query block's (``lo_ref`` / ``hi_ref``: the least and
-    the largest segment id of each block of ``block_k`` tokens), is skipped.
-    ``keep_ref`` [1, block_q, block_k] int8, where given, narrows the mask to
-    the pairs it marks non-zero."""
+def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref,
+                         o_ref, m_ref, l_ref, acc_ref, *, scale: float, block_q: int,
+                         block_k: int, keep_ref=None):
+    """One (query block, key block) pair of the grid step's heads: scores
+    stay on the chip, the running maximum and sum are kept 128 lanes wide
+    (every lane the same), the weighted values are divided by the sum once,
+    at the query block's last key block. The grid walks the pairs at or
+    under the diagonal and no others (``qi_ref`` / ``ki_ref``: the blocks of
+    the step's pair).
+
+    What a pair costs is decided from scalars, before any vector work
+    (``lo_ref`` / ``hi_ref``: the least and the largest segment id of each
+    block of ``block_k`` tokens). *Skipped*: a key block that holds no
+    document of the query block's. *Under the diagonal*: every key lies
+    before every query, so no position is compared: segment ids alone (and
+    where both blocks lie inside one document, :func:`pair_kinds`' plain
+    pairs, that compare changes nothing: it rides under the products, 6,440
+    bundles a pair with it and 6,537 without, so it has no body of its
+    own). *On the diagonal*: a pass of ``_ROWS`` queries takes the keys up
+    to its last query and no others (a square block computes 10 of its 16
+    [256, 256] tiles) and compares positions too. ``keep_ref`` [1, block_q,
+    block_k] int8, where given, narrows either kind's mask to the pairs it
+    marks non-zero: one block of it serves the step's heads.
+
+    Inside a kind, a pass takes ``_ROWS`` queries: their product with the
+    keys they need, the scale and the mask; then the rows' maximum, the
+    subtraction, ``exp``, sum, cast and product with the values; no
+    [block_q, block_k] float32 array exists. A pass's second half is
+    written after the first half of the next: the TPU compiler schedules a
+    kernel's straight line about in the order it is written, and those
+    products fill the matrix unit while the vector unit is at the
+    soft-max. Scores, maximum, sum and accumulator are float32, the scale
+    multiplies float32 scores, the probabilities enter the second product
+    in the values' type: every element's answer is what one pass over the
+    whole pair gives, up to the order of the float32 additions inside a
+    row's sum. The body is kept small in operations (whole passes, not
+    strips of them; the step's heads in a loop): on the chip's host every
+    program that holds the kernel pays its trace and lowering again."""
+    from jax import lax
     from jax.experimental import pallas as pl
 
-    bi, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    bi, qi, ki = pl.program_id(0), qi_ref[pl.program_id(2)], ki_ref[pl.program_id(2)]
+    per = block_q // block_k  # key-sized blocks a query block spans
+    rows, heads = min(_ROWS, block_q), q_ref.shape[1]
+    needed, _ = _pair_kind(lo_ref, hi_ref, bi, qi, ki, per)
 
     @pl.when(ki == 0)
     def _first():
@@ -188,53 +227,127 @@ def _flash_widths_kernel(lo_ref, hi_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(_pair_needed(lo_ref, hi_ref, bi, qi, ki, block_q, block_k))
-    def _pair():
-        scores = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32) * scale
-        rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-        cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        same = jnp.tile(qseg_ref[0], (1, block_k // _LANES)) == kseg_ref[0, :1]
-        # a row that has met no key of its document yet weighs what it sees by
-        # exp(0); the first real score sends that to exp(-1e30) = 0
-        seen = same & (cols <= rows)
-        if keep_ref is not None:
-            seen = seen & (keep_ref[0].astype(jnp.int32) != 0)
-        scores = jnp.where(seen, scores, _MASKED)
-        m_prev = m_ref[...]
-        m_next = jnp.maximum(m_prev, scores.max(axis=1)[:, None])
-        probs = jnp.exp(scores - jnp.tile(m_next, (1, block_k // _LANES)))
-        keep = jnp.exp(m_prev - m_next)
-        l_ref[...] = l_ref[...] * keep + probs.sum(axis=1)[:, None]
-        m_ref[...] = m_next
-        v = v_ref[0, 0]
-        acc_ref[...] = acc_ref[...] * jnp.tile(keep, (1, v.shape[-1] // _LANES)) + jnp.dot(
-            probs.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    def held(head):
+        """The key head a query head of the step attends over."""
+        share = heads // k_ref.shape[1]
+        return head if share == 1 else lax.div(head, share)
 
-    @pl.when(ki == pl.num_programs(3) - 1)
+    def pair(origin):
+        """The body of one kind of pair. ``origin``: where the key block starts,
+        counted from the query block's first row (None: wholly before it, and
+        no position is compared)."""
+        passes = [(at, block_k if origin is None else min(block_k, at + rows - origin))
+                  for at in range(0, block_q, rows)]
+        passes = [(at, keys) for at, keys in passes if keys > 0]    # rows before the block's first key
+
+        def scores(head, at, keys):
+            """A pass's first half: a head's ``rows`` queries from ``at`` against
+            the block's first ``keys`` keys, scaled and masked. (``lax`` by name
+            in the two halves: a ``jnp`` function is a jitted one, and tracing
+            hundreds of them is seconds of every program's set-up on the chip's host.)"""
+            out = lax.mul(lax.dot_general(
+                q_ref[0, head, at:at + rows], k_ref[0, held(head), :keys],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32), scale)
+            seen = [jnp.tile(qseg_ref[0, at:at + rows], (1, keys // _LANES)) == kseg_ref[0, :1, :keys]]
+            if origin is not None:    # the pass's last keys are its own rows
+                seen.append(lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+                            - lax.broadcasted_iota(jnp.int32, (rows, keys), 0) <= at - origin)
+            if keep_ref is not None:
+                seen.append(keep_ref[0, at:at + rows, :keys].astype(jnp.int32) != 0)
+            # a row that has met no key of its document yet weighs what it sees by
+            # exp(0); the first real score sends that to exp(-1e30) = 0
+            return lax.select(functools.reduce(jnp.logical_and, seen), out,
+                              lax.full_like(out, _MASKED))
+
+        def fold(head, at, keys, masked):
+            """A pass's second half: the rows' maximum, the probabilities against
+            the values, the running sum and the accumulator brought up to date."""
+            at = pl.ds(at, rows)
+            m_prev = m_ref[head, at]
+            m_next = jnp.maximum(m_prev, masked.max(axis=1, keepdims=True))
+            probs = lax.exp(lax.sub(masked, jnp.tile(m_next, (1, keys // _LANES))))
+            v = v_ref[0, held(head), :keys]
+            weighted = lax.dot_general(lax.convert_element_type(probs, v.dtype), v,
+                                       (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            fade = jnp.exp(m_prev - m_next)
+            l_ref[head, at] = l_ref[head, at] * fade + probs.sum(axis=1, keepdims=True)
+            m_ref[head, at] = m_next
+            acc_ref[head, at] = (acc_ref[head, at] * jnp.tile(fade, (1, weighted.shape[-1] // _LANES))
+                                 + weighted)
+
+        def one_head(head, carried):
+            # a pass's second half is written after the next pass's first: its products fill
+            # the matrix unit while the vector unit is at the soft-max
+            ahead = scores(head, *passes[0])
+            for now, later in zip(passes, passes[1:] + [None]):
+                masked, ahead = ahead, later and scores(head, *later)
+                fold(head, *now, masked)
+            return carried
+
+        # the step's heads one after the other, in a loop of the kernel's: one body to trace,
+        # lower and compile whatever their number
+        lax.fori_loop(0, heads, one_head, 0)
+
+    pl.when(needed & (ki < qi * per))(lambda: pair(None))      # every key before every query
+    for j in range(per):    # the key blocks a query block's own rows cross
+        pl.when(ki == qi * per + j)(functools.partial(pair, j * block_k))
+
+    @pl.when(ki == (qi + 1) * per - 1)
     def _last():
-        total = jnp.tile(l_ref[...], (1, acc_ref.shape[-1] // _LANES))
-        o_ref[0, 0] = (acc_ref[...] / total).astype(o_ref.dtype)
+        total = jnp.tile(l_ref[...], (1, 1, acc_ref.shape[-1] // _LANES))
+        o_ref[0] = (acc_ref[...] / total).astype(o_ref.dtype)
 
 
-def _flash_widths_kept_kernel(lo_ref, hi_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref, keep_ref,
-                              o_ref, m_ref, l_ref, acc_ref, **cut):
+def _flash_widths_kept_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_ref, k_ref,
+                              v_ref, keep_ref, o_ref, m_ref, l_ref, acc_ref, **cut):
     """:func:`_flash_widths_kernel` with a selection: ``keep_ref`` is its last input."""
-    _flash_widths_kernel(lo_ref, hi_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
-                         l_ref, acc_ref, keep_ref=keep_ref, **cut)
+    _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref,
+                         o_ref, m_ref, l_ref, acc_ref, keep_ref=keep_ref, **cut)
 
 
-def _pair_needed(lo_ref, hi_ref, bi, qi, ki, block_q: int, block_k: int):
-    """Does key block ``ki`` hold a key some query of block ``qi`` may see:
-    one at or before the block's last query, of a document the block holds?
-    Two blocks whose ranges of segment ids are disjoint share no document,
-    whatever the order of the ids; a query's own key block always passes."""
-    per = block_q // block_k  # key-sized blocks a query block spans
-    q_lo, q_hi = lo_ref[bi, qi * per], hi_ref[bi, qi * per]
+def _pair_kind(lo, hi, bi, qi, ki, per: int):
+    """(needed, one_document) of the pair of query block ``qi`` (``per``
+    key-sized blocks) and key block ``ki``, from each key-sized block's least
+    and largest segment id ``lo`` / ``hi`` [B, blocks] (refs in the kernel,
+    arrays in :func:`pair_kinds`). Needed: the key block holds a key some
+    query may see: one at or before the block's last query, of a document
+    the query block holds. Two blocks whose ranges of segment ids are
+    disjoint share no document, whatever the order of the ids; a query's own
+    key block always passes. One document: every token of both blocks
+    carries the same id."""
+    q_lo, q_hi = lo[bi, qi * per], hi[bi, qi * per]
     for j in range(1, per):
-        q_lo = jnp.minimum(q_lo, lo_ref[bi, qi * per + j])
-        q_hi = jnp.maximum(q_hi, hi_ref[bi, qi * per + j])
-    return (ki * block_k < (qi + 1) * block_q) & (lo_ref[bi, ki] <= q_hi) & (hi_ref[bi, ki] >= q_lo)
+        q_lo = jnp.minimum(q_lo, lo[bi, qi * per + j])
+        q_hi = jnp.maximum(q_hi, hi[bi, qi * per + j])
+    k_lo, k_hi = lo[bi, ki], hi[bi, ki]
+    needed = (ki < (qi + 1) * per) & (k_lo <= q_hi) & (k_hi >= q_lo)
+    return needed, (q_lo == q_hi) & (k_lo == k_hi) & (q_lo == k_lo)
+
+
+def _grid_pairs(l: int, block_q: int, block_k: int):
+    """[pairs, 2] int32: the (query block, key block) pairs at or under the
+    diagonal, a query block's in order: what the kernel's grid walks."""
+    per = block_q // block_k
+    return np.array([(qi, ki) for qi in range(l // block_q) for ki in range((qi + 1) * per)],
+                    np.int32)
+
+
+def pair_kinds(segments, block_q: int = 1024, block_k: int = 1024):
+    """(skipped, plain, masked): how many of the block pairs at or under the
+    diagonal of ``segments`` [B, L] the kernel skips, computes with every key
+    seen (*plain*: wholly under the diagonal, one document; no compare is
+    needed there) and computes under a mask that hides something (the
+    diagonal's, or several documents'), by the rule its scalars apply."""
+    segments = np.asarray(segments, np.int32)
+    b, l = segments.shape
+    block_q, block_k = min(block_q, l), min(block_k, l)
+    by_block = segments.reshape(b, l // block_k, block_k)
+    per = block_q // block_k
+    qi, ki = _grid_pairs(l, block_q, block_k).T
+    needed, one_document = _pair_kind(by_block.min(axis=-1), by_block.max(axis=-1),
+                                      np.arange(b)[:, None], qi, ki, per)
+    plain = needed & one_document & (ki < qi * per)
+    return tuple(int(np.sum(n)) for n in (~needed, plain, needed & ~plain))
 
 
 def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
@@ -244,59 +357,79 @@ def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
     192 against 128; JAX's own flash kernel takes one width, and only 128s).
     q [B, H, L, D], k [B, Hkv, L, D], v [B, Hkv, L, Dv], segments [B, L]
     -> [B, H, L, Dv] in q's dtype. L is whole blocks, ``block_q`` whole
-    ``block_k``s; ``block_k`` and Dv are whole 128s. A pair of blocks that
-    share no document costs a grid step and nothing else: in packed rows of
-    many documents most pairs under the diagonal do not. ``keep`` [B, L, L]
-    int8 tells the kernel which keys a query may see beside that (a learned
-    selection: a pair it marks 0 is masked; one block of it is read a pair
-    of blocks, for every head). Forward only."""
+    ``block_k``s; ``block_k`` and Dv are whole 128s. The grid walks the
+    block pairs at or under the diagonal; what a pair costs follows what it
+    holds (:func:`_flash_widths_kernel`: nothing where the blocks share no
+    document, as most pairs under the diagonal of packed rows of many
+    documents; segment ids alone under the diagonal, no position; the seen
+    half on the diagonal). ``keep`` [B, L, L] int8 tells the kernel which keys a
+    query may see beside that (a learned selection: a pair it marks 0 is
+    masked; one block of it is read a pair of blocks and grid step).
+    Forward only. One trace and one lowering for a program's calls of one
+    shape: the call sits in a jitted function."""
+    l, dv = q.shape[2], v.shape[-1]
+    block_q, block_k = min(block_q, l), min(block_k, l)
+    if l % block_q or block_q % block_k or block_k % _LANES or dv % _LANES:
+        raise ValueError(f"rows of {l} in blocks of {block_q} x {block_k}, values of {dv}: "
+                         f"the kernel wants whole blocks and whole {_LANES}s")
+    return _flash_widths_call(q, k, v, segments, keep, scale=float(scale), block_q=block_q,
+                              block_k=block_k)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k"))
+def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, block_k: int):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, l, d = q.shape
     rep, dv = h // k.shape[1], v.shape[-1]
-    block_q, block_k = min(block_q, l), min(block_k, l)
-    if l % block_q or block_q % block_k or block_k % _LANES or dv % _LANES:
-        raise ValueError(f"rows of {l} in blocks of {block_q} x {block_k}, values of {dv}: "
-                         f"the kernel wants whole blocks and whole {_LANES}s")
     by_block = segments.astype(jnp.int32).reshape(b, l // block_k, block_k)
     lo, hi = by_block.min(axis=-1), by_block.max(axis=-1)
+    per = block_q // block_k
+    heads = next(n for n in _HEADS if h % n == 0 and (rep % n == 0 or n % rep == 0))
+    held = max(1, heads // rep)     # key heads a grid step's query heads attend over
+    pairs = _grid_pairs(l, block_q, block_k)     # the grid's third axis
 
-    def key_block(bi, qi, ki, lo_ref, hi_ref):
+    def key_block(bi, t, lo_ref, hi_ref, qi_ref, ki_ref):
         """A skipped pair asks for the query block's own last key block, which
         a later pair needs: nothing is copied for it."""
-        last = ((qi + 1) * block_q - 1) // block_k
-        return jnp.where(_pair_needed(lo_ref, hi_ref, bi, qi, ki, block_q, block_k), ki, last)
+        qi, ki = qi_ref[t], ki_ref[t]
+        return jnp.where(_pair_kind(lo_ref, hi_ref, bi, qi, ki, per)[0], ki, (qi + 1) * per - 1)
+
+    def queries(bi, hi, t, lo_ref, hi_ref, qi_ref, ki_ref):
+        return bi, hi, qi_ref[t], 0
+
+    def keys(bi, hi, t, *tables):
+        return bi, hi * heads // rep // held, key_block(bi, t, *tables), 0
 
     kernel = functools.partial(
         _flash_widths_kernel if keep is None else _flash_widths_kept_kernel,
         scale=scale, block_q=block_q, block_k=block_k)
     selection = [] if keep is None else [(keep, pl.BlockSpec(
-        (1, block_q, block_k), lambda bi, hi, qi, ki, *r: (bi, qi, key_block(bi, qi, ki, *r))))]
+        (1, block_q, block_k), lambda bi, hi, t, *r: (bi, r[2][t], key_block(bi, t, *r))))]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, l // block_q, l // block_k),
+            num_scalar_prefetch=4,
+            grid=(b, h // heads, len(pairs)),
             in_specs=[
-                pl.BlockSpec((1, block_q, _LANES), lambda bi, hi, qi, ki, *_: (bi, qi, 0)),
+                pl.BlockSpec((1, block_q, _LANES), lambda bi, hi, t, *r: (bi, r[2][t], 0)),
                 pl.BlockSpec((1, _SUBLANES, block_k),
-                             lambda bi, hi, qi, ki, *r: (bi, 0, key_block(bi, qi, ki, *r))),
-                pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki, *_: (bi, hi, qi, 0)),
-                pl.BlockSpec((1, 1, block_k, d),
-                             lambda bi, hi, qi, ki, *r: (bi, hi // rep, key_block(bi, qi, ki, *r), 0)),
-                pl.BlockSpec((1, 1, block_k, dv),
-                             lambda bi, hi, qi, ki, *r: (bi, hi // rep, key_block(bi, qi, ki, *r), 0)),
+                             lambda bi, hi, t, *r: (bi, 0, key_block(bi, t, *r))),
+                pl.BlockSpec((1, heads, block_q, d), queries),
+                pl.BlockSpec((1, held, block_k, d), keys),
+                pl.BlockSpec((1, held, block_k, dv), keys),
                 *[spec for _, spec in selection],
             ],
-            out_specs=pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki, *_: (bi, hi, qi, 0)),
-            scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
-                            pltpu.VMEM((block_q, _LANES), jnp.float32),
-                            pltpu.VMEM((block_q, dv), jnp.float32)]),
+            out_specs=pl.BlockSpec((1, heads, block_q, dv), queries),
+            scratch_shapes=[pltpu.VMEM((heads, block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((heads, block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((heads, block_q, dv), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, h, l, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
-    )(lo, hi, jnp.broadcast_to(segments[:, :, None], (b, l, _LANES)),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+    )(lo, hi, pairs[:, 0], pairs[:, 1], jnp.broadcast_to(segments[:, :, None], (b, l, _LANES)),
       jnp.broadcast_to(segments[:, None, :], (b, _SUBLANES, l)), q, k, v,
       *[a for a, _ in selection])
 

@@ -71,7 +71,7 @@ from tpu_tfrecord.models import moe as _moe
 from tpu_tfrecord.models import pipeline as _pipeline
 from tpu_tfrecord.models import sparse_attn as _sa
 from tpu_tfrecord.models.attention import (
-    attention_reference, blockwise_attention, flash_attention_widths, ring_attention,
+    attention_reference, blockwise_attention, flash_attention_widths, pair_kinds, ring_attention,
 )
 from tpu_tfrecord.models.long_doc import _rms_norm
 
@@ -979,11 +979,17 @@ def _attend(q, k, v, segments, block: int, scale=None, keep=None):
     for rows of whole blocks of 128s, a Pallas kernel: JAX's own where q, k
     and v share a width of whole 128s (it takes no other), and
     ``attention.flash_attention_widths`` where the values are narrower than
-    the keys (latent attention: 192 against 128). Elsewhere (the kernels
-    exist for no other backend), and for shapes neither takes,
-    ``attention.blockwise_attention``: plain JAX, the same mask, the same
-    answer (tests/test_pattern_lm.py and tests/test_mla_lm.py hold each
-    kernel to it)."""
+    the keys (latent attention: 192 against 128). That kernel sees from two
+    block indices and four segment ids what a pair of blocks needs: nothing;
+    segment ids and no position (under the diagonal: 120 of the 136 pairs of
+    a 16,384-token document, where every key is seen and the compare is
+    hidden under the products); or positions too and half the keys (on the
+    diagonal); ``record_pair_kinds`` counts them for a step's rows. Float32
+    scores, maximum, sum and accumulator and ``block`` are the same in
+    every kind. Elsewhere (the kernels exist for no other backend), and for
+    shapes neither takes, ``attention.blockwise_attention``: plain JAX, the
+    same mask, the same answer (tests/test_pattern_lm.py,
+    tests/test_mla_lm.py and tests/test_dsa_lm.py hold each kernel to it)."""
     (l, d), dv, tile = q.shape[2:], v.shape[-1], min(block, q.shape[2])
     if jax.default_backend() == "tpu" and dv % 128 == 0 and tile % 128 == 0 and l % tile == 0:
         if d == dv and scale is None and keep is None:
@@ -1343,4 +1349,18 @@ def record_selected(selected) -> float:
     kept, candidates = np.asarray(selected, np.float64).sum(axis=0)
     share = float(kept / max(candidates, 1.0))
     METRICS.gauge("dsa.selected_share", round(share, 6))
+    return share
+
+
+def record_pair_kinds(segment_ids, cfg: PatternLMConfig) -> float:
+    """What the latent-attention kernel makes of a step's rows (``score``'s
+    ``segment_ids`` [B, L+1]) into ``metrics.METRICS``: the gauge
+    ``mla.plain_pair_share``, of the block pairs it computes those it
+    computes with every key seen (``attention.pair_kinds``: 120 of 136 for one
+    document of 16,384 tokens in blocks of 1,024). Returns the gauge."""
+    from tpu_tfrecord.metrics import METRICS
+
+    _, plain, masked = pair_kinds(np.asarray(segment_ids)[:, :-1], cfg.attn_block, cfg.attn_block)
+    share = plain / max(plain + masked, 1)
+    METRICS.gauge("mla.plain_pair_share", round(share, 6))
     return share

@@ -199,6 +199,7 @@ GAUGES: Dict[str, str] = {
     "kda.fused_layers": "pattern LM, the score program last traced: delta-rule layers whose recurrence took the Pallas kernel (0 off a TPU)",
     "dsa.kernel_layers": "pattern LM, the score program last traced: latent-attention layers whose selection took the Pallas kernel (0 off a TPU and without an indexer)",
     "dsa.selected_share": "pattern LM, latest step recorded: keys the indexers kept over the causal candidates they chose from (lm.record_selected)",
+    "mla.plain_pair_share": "pattern LM, latest step recorded: of the block pairs the latent-attention kernel computes, those wholly under the diagonal of one document, where every key is seen (lm.record_pair_kinds)",
     "moe.gate_entropy": "latest per-step router gate entropy",
     "moe.expert_imbalance": "latest per-step expert imbalance",
     "pipeline.bubble_fraction": "latest per-step pipeline bubble fraction",
