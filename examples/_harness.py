@@ -27,13 +27,14 @@ importing this module.
 from __future__ import annotations
 
 import contextlib
+import json
 import os
 import time
 from typing import Callable, Dict, Iterator, Optional, Tuple
 
 import jax
 
-from tpu_tfrecord import checkpoint, telemetry
+from tpu_tfrecord import checkpoint, compile_cache, telemetry
 from tpu_tfrecord.metrics import METRICS
 from tpu_tfrecord.tracing import DutyCycle, trace
 
@@ -353,6 +354,25 @@ def release_trainer_spool(spool) -> None:
         fleet.release_spool(spool.spool_dir)
 
 
+def log_setup_summary() -> Optional[dict]:
+    """One structured line once the first step has been dispatched, when
+    every program of the run has been built: what ``compile_cache``'s log
+    says the start was spent on (seconds by phase with each moment once,
+    cache hits and misses, the three programs that cost most). A slow
+    start reads off it: ``backend`` seconds with misses is a cold compile
+    cache, ``lower`` seconds are the kernels' lowerings (no cache holds
+    them), ``trace`` seconds are Python. Silent where no entry point called
+    ``compile_cache.enable()``."""
+    found = compile_cache.summary()
+    if found is not None:
+        line = {"event": "setup", "hits": found["hits"], "misses": found["misses"],
+                "seconds": {k: round(v, 3) for k, v in found["seconds"].items()},
+                "largest": [{k: round(v, 3) if isinstance(v, float) else v for k, v in row.items()}
+                            for row in found["programs"][:3]]}
+        print("setup " + json.dumps(line, sort_keys=True), flush=True)
+    return found
+
+
 def run_train_loop(
     it,
     produce: Callable,
@@ -387,6 +407,9 @@ def run_train_loop(
       is constructed when the caller passes none — pass your own to read
       shares()/verdict() after the run).
 
+    After the first step one ``setup {...}`` line says what building the
+    programs cost (:func:`log_setup_summary`).
+
     Every completed step records a ``train.step`` flight-recorder span
     (Chrome trace, when tracing is on — exactly one per counted step) and
     is wrapped in a ``tracing.trace`` xprof annotation, so profiler
@@ -417,6 +440,8 @@ def run_train_loop(
                 rec.abort_step()
                 break
             step += 1
+            if step == 1:
+                log_setup_summary()
             # blocking on THIS step's freshly dispatched loss (the
             # on_step/log paths) is device-step wall time: it must land
             # in the compute phase, or an instrumented run (--diagnostics

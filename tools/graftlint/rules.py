@@ -570,7 +570,7 @@ class VocabularyRule(Rule):
     def _call_kind(self, node: ast.Call) -> Optional[str]:
         fn = node.func
         if isinstance(fn, ast.Name):
-            if fn.id == "timed":
+            if fn.id in ("timed", "kernel_trace"):  # compile_cache.kernel_trace is a timed
                 return "stage"
             if fn.id in self._SPAN_FUNCS:
                 return "span"

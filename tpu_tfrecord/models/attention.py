@@ -53,6 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from tpu_tfrecord.compile_cache import kernel_trace
+
 
 _NEG = jnp.float32(-1e30)  # mask value; avoids inf-inf NaNs for empty rows
 
@@ -407,7 +409,7 @@ def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, b
         scale=scale, block_q=block_q, block_k=block_k)
     selection = [] if keep is None else [(keep, pl.BlockSpec(
         (1, block_q, block_k), lambda bi, hi, t, *r: (bi, r[2][t], key_block(bi, t, *r))))]
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -429,9 +431,12 @@ def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, b
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
-    )(lo, hi, pairs[:, 0], pairs[:, 1], jnp.broadcast_to(segments[:, :, None], (b, l, _LANES)),
-      jnp.broadcast_to(segments[:, None, :], (b, _SUBLANES, l)), q, k, v,
-      *[a for a, _ in selection])
+    )
+    with kernel_trace("kernel.trace.mla_attn"):  # the body's trace, as a program is traced
+        return call(
+            lo, hi, pairs[:, 0], pairs[:, 1], jnp.broadcast_to(segments[:, :, None], (b, l, _LANES)),
+            jnp.broadcast_to(segments[:, None, :], (b, _SUBLANES, l)), q, k, v,
+            *[a for a, _ in selection])
 
 
 def _ring_attention_local(

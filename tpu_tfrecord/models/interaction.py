@@ -45,6 +45,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpu_tfrecord.compile_cache import kernel_trace
+
 
 def _tril_indices(f: int):
     rows, cols = np.tril_indices(f, k=-1)
@@ -140,7 +142,7 @@ def dot_interaction_pallas(
     sel_rows[rows, np.arange(p)] = 1.0
     sel_cols = np.zeros((f, p_pad), dtype=np.float32)
     sel_cols[cols, np.arange(p)] = 1.0
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         _interaction_kernel,
         out_shape=jax.ShapeDtypeStruct((b, p_pad), emb.dtype),
         grid=(b // block_b, p_pad // block_p),
@@ -155,7 +157,9 @@ def dot_interaction_pallas(
             (block_b, block_p), lambda i, j: (i, j), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
-    )(jnp.asarray(sel_rows), jnp.asarray(sel_cols), emb)
+    )
+    with kernel_trace("kernel.trace.interaction"):  # the body's trace, as a program is traced
+        out = call(jnp.asarray(sel_rows), jnp.asarray(sel_cols), emb)
     return out[:, :p] if p_pad != p else out
 
 

@@ -62,6 +62,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from tpu_tfrecord.compile_cache import kernel_trace
+
 _HIGHEST = jax.lax.Precision.HIGHEST
 _BLOCK = 16  # tokens of the blocks that the triangle's inverse and the decayed pairs bottom out in
 _HEADS = 16  # heads of a row whose chunked form the plain form lays out in memory at a time
@@ -443,7 +445,7 @@ def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, inte
     heads = 2 - h % 2          # two chains in turn keep the matrix unit busier than one
     segments = segments.astype(jnp.int32)
     per_head = pl.BlockSpec((1, heads, tile, d), lambda bi, hi, ti: (bi, hi, ti, 0))
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_delta_rule_kernel, scale=scale),
         grid=(b, h // heads, l // tile),
         in_specs=[
@@ -458,10 +460,13 @@ def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, inte
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(jnp.broadcast_to(segments[:, :, None], (b, l, d)),
-      jnp.broadcast_to(segments[:, None, :], (b, 8, l)),
-      q.astype(f32), k.astype(f32), v.astype(f32), log_decay.astype(f32),
-      beta.astype(f32).reshape(b, h, l // tile, 1, tile))
+    )
+    with kernel_trace("kernel.trace.kda_scan"):  # the body's trace, as a program is traced
+        return call(
+            jnp.broadcast_to(segments[:, :, None], (b, l, d)),
+            jnp.broadcast_to(segments[:, None, :], (b, 8, l)),
+            q.astype(f32), k.astype(f32), v.astype(f32), log_decay.astype(f32),
+            beta.astype(f32).reshape(b, h, l // tile, 1, tile))
 
 
 def _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk: int):

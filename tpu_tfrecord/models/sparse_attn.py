@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_tfrecord.compile_cache import kernel_trace
+
 _LANES, _SUBLANES = 128, 8
 _INT_MIN = -(1 << 31)
 _BLOCK_Q, _BLOCK_K = 256, 512  # the kernel's queries a grid step and keys a score block
@@ -181,7 +183,7 @@ def _select_fused(q_idx, k_idx, w, segments, topk: int, tile: Tuple[int, int],
         return jnp.minimum(ki, ((qi + 1) * block_q - 1) // block_k)
 
     kernel = functools.partial(_select_kernel, topk=topk, block_q=block_q, block_k=block_k)
-    keep, kept = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(b, l // block_q, n_blocks),
         in_specs=[
@@ -201,8 +203,11 @@ def _select_fused(q_idx, k_idx, w, segments, topk: int, tile: Tuple[int, int],
             # the block's scores (4 bytes a pair), its row of the mask twice, its queries twice
             vmem_limit_bytes=block_q * l * 8 + 4 * h * block_q * d * 2 + (16 << 20)),
         interpret=interpret,
-    )(jnp.broadcast_to(segments[:, :, None], (b, l, _LANES)),
-      jnp.broadcast_to(segments[:, None, :], (b, _SUBLANES, l)), q_idx, k_idx, w)
+    )
+    with kernel_trace("kernel.trace.dsa_index"):  # the body's trace, as a program is traced
+        keep, kept = call(
+            jnp.broadcast_to(segments[:, :, None], (b, l, _LANES)),
+            jnp.broadcast_to(segments[:, None, :], (b, _SUBLANES, l)), q_idx, k_idx, w)
     return keep, kept[..., 0]
 
 

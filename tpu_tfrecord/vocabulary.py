@@ -133,6 +133,9 @@ COUNTERS: Dict[str, str] = {
     "serve.ticks": "continuous-batching scheduler ticks (microbatches packed)",
     "serve.errors": "serving engine ticks / completion callbacks that raised",
     "elastic.replicas_lost": "serving replicas that died undrained (SIGKILL/crash)",
+    # -- set-up: the compile log (compile_cache.enable)
+    "compile.cache_hits": "programs whose executable came out of the persistent compile cache",
+    "compile.cache_misses": "programs looked up in the persistent compile cache, not found, and compiled",
 }
 
 #: Throughput stages (``Metrics.add``/``timed``) and observe-only histogram
@@ -173,6 +176,15 @@ STAGES: Dict[str, str] = {
     "serve.latency": "one serving request, admission -> last token",
     "serve.queue_wait": "one request's admission queue wait, admission -> first pack",
     "serve.service": "one request's service time, first pack -> last token",
+    # set-up: the compile log (compile_cache.enable); seconds count each moment once
+    "compile.trace": "a jitted function traced to a jaxpr (a function traced inside another adds no seconds of its own)",
+    "compile.lower": "a program lowered to an MLIR module (every Pallas body's lowering to Mosaic is in here)",
+    "compile.backend": "a program's backend compile, or the cache read and deserialisation that stood in for it",
+    "compile.cache_read": "the persistent cache read inside a compile.backend that hit",
+    "kernel.trace.mla_attn": "the latent-attention Pallas kernel built while a program is traced (attention._flash_widths_call)",
+    "kernel.trace.kda_scan": "the delta-rule Pallas kernel built while a program is traced (linear_attn._delta_rule_fused)",
+    "kernel.trace.dsa_index": "the selection Pallas kernel built while a program is traced (sparse_attn._select_fused)",
+    "kernel.trace.interaction": "the dot-interaction Pallas kernel built while a program is traced (interaction.dot_interaction_pallas)",
 }
 
 #: Instantaneous gauges (``Metrics.gauge``): last write wins.
@@ -244,6 +256,15 @@ SPANS: Dict[str, str] = {
     "serve.deadline_expired": "a request's deadline fired (instant)",
     "service.lease": "one consumer shard lease, route -> eof (root span)",
     "service.route": "dispatcher routed a shard to a worker (instant, lease-linked)",
+    # set-up, recorded after the fact by the compile log (attr fun= the program)
+    "compile.trace": "one jitted function's trace",
+    "compile.lower": "one program's lowering",
+    "compile.backend": "one program's backend compile or cache read",
+    "compile.cache_read": "one persistent cache read",
+    "kernel.trace.mla_attn": "one build of the latent-attention kernel",
+    "kernel.trace.kda_scan": "one build of the delta-rule kernel",
+    "kernel.trace.dsa_index": "one build of the selection kernel",
+    "kernel.trace.interaction": "one build of the dot-interaction kernel",
 }
 
 #: Prefixes under which names are formed at runtime and cannot be
