@@ -16,12 +16,17 @@ def make_qkv(b=2, l=32, h=2, d=8, seed=0, dtype=jnp.float32):
     return mk(), mk(), mk()
 
 
+#: ``ring_attention`` as one program a mesh, mode and shape (bare, it runs
+#: primitive by primitive, each primitive a compile)
+ring = jax.jit(ring_attention, static_argnums=3, static_argnames=("data_axis", "causal", "zigzag"))
+
+
 class TestRingAttention:
     def test_matches_dense_oracle_8way(self):
         mesh = create_mesh({"seq": 8})
         q, k, v = make_qkv()
         want = attention_reference(q, k, v)
-        got = jax.jit(lambda q, k, v: ring_attention(q, k, v, mesh))(q, k, v)
+        got = ring(q, k, v, mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
     def test_matches_with_data_and_seq_axes(self):
@@ -33,20 +38,20 @@ class TestRingAttention:
 
         sh = NamedSharding(mesh, P("data", "seq", None, None))
         q, k, v = (jax.device_put(x, sh) for x in (q, k, v))
-        got = jax.jit(lambda q, k, v: ring_attention(q, k, v, mesh))(q, k, v)
+        got = ring(q, k, v, mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
     def test_single_device_axis_degenerates(self):
         mesh = create_mesh({"seq": 1, "data": 8})
         q, k, v = make_qkv(l=8)
         want = attention_reference(q, k, v)
-        got = ring_attention(q, k, v, mesh)
+        got = ring(q, k, v, mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
     def test_bf16_inputs(self):
         mesh = create_mesh({"seq": 4, "data": 2})
         q, k, v = make_qkv(l=16, dtype=jnp.bfloat16)
-        got = ring_attention(q, k, v, mesh)
+        got = ring(q, k, v, mesh)
         assert got.dtype == jnp.bfloat16
         want = attention_reference(q.astype(jnp.float32), k.astype(jnp.float32),
                                    v.astype(jnp.float32))
@@ -64,7 +69,7 @@ class TestRingAttention:
         g = jax.jit(jax.grad(loss))(q, k, v)
         assert np.isfinite(np.asarray(g)).all()
         # oracle gradient agreement
-        g_ref = jax.grad(lambda q, k, v: attention_reference(q, k, v).sum())(q, k, v)
+        g_ref = jax.jit(jax.grad(lambda q, k, v: attention_reference(q, k, v).sum()))(q, k, v)
         np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), rtol=1e-4, atol=1e-5)
 
 
@@ -74,20 +79,18 @@ class TestRingAttentionMaskAndSharding:
         q, k, v = make_qkv(b=3, l=32)
         lengths = jnp.asarray([32, 10, 1], dtype=jnp.int32)
         want = attention_reference(q, k, v, lengths=lengths)
-        got = jax.jit(
-            lambda q, k, v, le: ring_attention(q, k, v, mesh, lengths=le)
-        )(q, k, v, lengths)
+        got = ring(q, k, v, mesh, lengths=lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
     def test_mask_actually_excludes_pad_keys(self):
         mesh = create_mesh({"seq": 4}, jax.devices()[:4])
         q, k, v = make_qkv(b=1, l=16)
         lengths = jnp.asarray([5], dtype=jnp.int32)
-        base = ring_attention(q, k, v, mesh, lengths=lengths)
+        base = ring(q, k, v, mesh, lengths=lengths)
         # garbage in the padded K/V region must not change the output
         k2 = k.at[:, 5:].set(999.0)
         v2 = v.at[:, 5:].set(-999.0)
-        got = ring_attention(q, k2, v2, mesh, lengths=lengths)
+        got = ring(q, k2, v2, mesh, lengths=lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(base), rtol=1e-6)
 
     def test_data_axis_keeps_batch_sharded(self):
@@ -129,9 +132,7 @@ class TestUlyssesAttention:
         got_u = jax.jit(
             lambda q, k, v, le: ulysses_attention(q, k, v, mesh, lengths=le)
         )(q, k, v, lengths)
-        got_r = jax.jit(
-            lambda q, k, v, le: ring_attention(q, k, v, mesh, lengths=le)
-        )(q, k, v, lengths)
+        got_r = ring(q, k, v, mesh, lengths=lengths)
         np.testing.assert_allclose(np.asarray(got_u), np.asarray(want), rtol=2e-5, atol=2e-6)
         np.testing.assert_allclose(np.asarray(got_u), np.asarray(got_r), rtol=2e-5, atol=2e-6)
 
@@ -143,7 +144,7 @@ class TestUlyssesAttention:
         g = jax.jit(
             jax.grad(lambda q, k, v: ulysses_attention(q, k, v, mesh).sum())
         )(q, k, v)
-        g_ref = jax.grad(lambda q, k, v: attention_reference(q, k, v).sum())(q, k, v)
+        g_ref = jax.jit(jax.grad(lambda q, k, v: attention_reference(q, k, v).sum()))(q, k, v)
         np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), rtol=1e-4, atol=1e-5)
 
     def test_heads_must_cover_axis(self):
@@ -171,7 +172,7 @@ class TestUlyssesAttention:
 
         mesh = create_mesh({"seq": 4, "data": 2})
         q, k, v = make_qkv(l=16, h=4, dtype=jnp.bfloat16)
-        got = ulysses_attention(q, k, v, mesh)
+        got = jax.jit(lambda q, k, v: ulysses_attention(q, k, v, mesh))(q, k, v)
         assert got.dtype == jnp.bfloat16
         want = attention_reference(
             q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32)
@@ -206,7 +207,7 @@ class TestGQA:
         mesh = create_mesh({"seq": 8})
         q, k, v = self.make_gqa()
         want = self.oracle(q, k, v)
-        got = jax.jit(lambda q, k, v: ring_attention(q, k, v, mesh))(q, k, v)
+        got = ring(q, k, v, mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
     def test_ulysses_gqa_matches_oracle_and_ring(self):
@@ -219,9 +220,7 @@ class TestGQA:
         got_u = jax.jit(
             lambda q, k, v, le: ulysses_attention(q, k, v, mesh, lengths=le)
         )(q, k, v, lengths)
-        got_r = jax.jit(
-            lambda q, k, v, le: ring_attention(q, k, v, mesh, lengths=le)
-        )(q, k, v, lengths)
+        got_r = ring(q, k, v, mesh, lengths=lengths)
         np.testing.assert_allclose(np.asarray(got_u), np.asarray(want), rtol=2e-5, atol=2e-6)
         np.testing.assert_allclose(np.asarray(got_r), np.asarray(want), rtol=2e-5, atol=2e-6)
 
@@ -231,9 +230,9 @@ class TestGQA:
         g = jax.jit(
             jax.grad(lambda q, k, v: ring_attention(q, k, v, mesh).sum(), argnums=(0, 1, 2))
         )(q, k, v)
-        g_ref = jax.grad(
+        g_ref = jax.jit(jax.grad(
             lambda q, k, v: self.oracle(q, k, v).sum(), argnums=(0, 1, 2)
-        )(q, k, v)
+        ))(q, k, v)
         for a, b in zip(g, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
 
@@ -242,7 +241,7 @@ class TestGQA:
         mesh = create_mesh({"seq": 4}, jax.devices()[:4])
         q, k, v = self.make_gqa(h=4, hkv=1, l=16)
         want = self.oracle(q, k, v)
-        got = ring_attention(q, k, v, mesh)
+        got = ring(q, k, v, mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
     def test_indivisible_heads_rejected(self):
@@ -261,9 +260,7 @@ class TestCausal:
         mesh = create_mesh({"seq": 8})
         q, k, v = make_qkv()
         want = attention_reference(q, k, v, causal=True)
-        got = jax.jit(
-            lambda q, k, v: ring_attention(q, k, v, mesh, causal=True)
-        )(q, k, v)
+        got = ring(q, k, v, mesh, causal=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
     def test_ulysses_causal_matches_oracle(self):
@@ -287,9 +284,7 @@ class TestCausal:
             q, jnp.repeat(kv[0], g, axis=2), jnp.repeat(kv[1], g, axis=2),
             lengths=lengths, causal=True,
         )
-        got = jax.jit(
-            lambda q, k, v, le: ring_attention(q, k, v, mesh, lengths=le, causal=True)
-        )(q, kv[0], kv[1], lengths)
+        got = ring(q, kv[0], kv[1], mesh, lengths=lengths, causal=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
     def test_future_keys_are_inert(self):
@@ -297,11 +292,11 @@ class TestCausal:
         query's output (the operational meaning of causal)."""
         mesh = create_mesh({"seq": 4}, jax.devices()[:4])
         q, k, v = make_qkv(b=1, l=16)
-        base = ring_attention(q, k, v, mesh, causal=True)
+        base = ring(q, k, v, mesh, causal=True)
         # poison the second half; queries in the FIRST half must not move
         k2 = k.at[:, 8:].set(777.0)
         v2 = v.at[:, 8:].set(-777.0)
-        got = ring_attention(q, k2, v2, mesh, causal=True)
+        got = ring(q, k2, v2, mesh, causal=True)
         np.testing.assert_allclose(
             np.asarray(got)[:, :8], np.asarray(base)[:, :8], rtol=1e-6
         )
@@ -313,10 +308,10 @@ class TestCausal:
             jax.grad(lambda q, k, v: ring_attention(q, k, v, mesh, causal=True).sum(),
                      argnums=(0, 1, 2))
         )(q, k, v)
-        g_ref = jax.grad(
+        g_ref = jax.jit(jax.grad(
             lambda q, k, v: attention_reference(q, k, v, causal=True).sum(),
             argnums=(0, 1, 2),
-        )(q, k, v)
+        ))(q, k, v)
         for a, b in zip(g, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
 
@@ -329,12 +324,8 @@ class TestZigzagCausal:
         mesh = create_mesh({"seq": 8})
         q, k, v = make_qkv()
         want = attention_reference(q, k, v, causal=True)
-        plain = jax.jit(
-            lambda q, k, v: ring_attention(q, k, v, mesh, causal=True)
-        )(q, k, v)
-        zz = jax.jit(
-            lambda q, k, v: ring_attention(q, k, v, mesh, causal=True, zigzag=True)
-        )(q, k, v)
+        plain = ring(q, k, v, mesh, causal=True)
+        zz = ring(q, k, v, mesh, causal=True, zigzag=True)
         np.testing.assert_allclose(np.asarray(zz), np.asarray(want), rtol=2e-5, atol=2e-6)
         np.testing.assert_allclose(np.asarray(zz), np.asarray(plain), rtol=2e-5, atol=2e-6)
 
@@ -348,12 +339,7 @@ class TestZigzagCausal:
             q, jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2),
             lengths=lengths, causal=True,
         )
-        got = jax.jit(
-            lambda q, k, v, le: ring_attention(
-                q, k, v, mesh, data_axis="data", lengths=le,
-                causal=True, zigzag=True,
-            )
-        )(q, k, v, lengths)
+        got = ring(q, k, v, mesh, data_axis="data", lengths=lengths, causal=True, zigzag=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
     def test_grads_match_oracle(self):
@@ -367,10 +353,10 @@ class TestZigzagCausal:
                 argnums=(0, 1, 2),
             )
         )(q, k, v)
-        g_ref = jax.grad(
+        g_ref = jax.jit(jax.grad(
             lambda q, k, v: attention_reference(q, k, v, causal=True).sum(),
             argnums=(0, 1, 2),
-        )(q, k, v)
+        ))(q, k, v)
         for a, b in zip(g, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
 
@@ -429,7 +415,7 @@ class TestZigzagCausal:
         mesh = create_mesh({"seq": 1, "data": 8})
         q, k, v = make_qkv(l=8)
         want = attention_reference(q, k, v, causal=True)
-        got = ring_attention(q, k, v, mesh, causal=True, zigzag=True)
+        got = ring(q, k, v, mesh, causal=True, zigzag=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
     def test_zigzag_requires_causal_and_divisibility(self):

@@ -86,7 +86,12 @@ class TestPhasesOnTheCpuMesh:
             )
         )
 
-    def test_multichip_phase_on_four_virtual_devices(self):
+    def test_multichip_phase_on_four_virtual_devices(self, monkeypatch):
+        """At 'highest' only: on the CPU both of the phase's precisions are
+        one float32 computation compiled under two cache keys, and 'highest'
+        holds the tighter of the two bounds."""
+        monkeypatch.setattr(chip_smoke, "MULTICHIP_TOL", {
+            "highest": chip_smoke.MULTICHIP_TOL["highest"]})
         chip_smoke.phase_multichip(4, steps=2)
 
     def test_serving_one_stage_answers_like_the_sequential_reference(self, tiny):

@@ -1,0 +1,147 @@
+"""The gated delta rule (``models.linear_attn``) at sizes a CPU walks in
+seconds: the chunked recurrence against the token-by-token one, the Pallas
+kernel a TPU runs, interpreted, against both, a boundary at every edge the
+kernel has, keys that all but repeat, decays that overflow a naive chunk, and
+the short convolution's taps at a boundary. The model these layers sit in is
+tests/test_pattern_lm.py's."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_tfrecord.models import linear_attn, pattern_reference as ref
+
+#: One program a shape for the process: cases that differ in their data find
+#: it built (called bare, a scan or a kernel is compiled anew at every call).
+recurrent = jax.jit(linear_attn.delta_rule_recurrent, static_argnames="scale")
+chunked = jax.jit(linear_attn.delta_rule_chunked, static_argnames=("scale", "chunk"))
+
+
+def test_taps_stop_at_a_boundary():
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.standard_normal((1, 2, 20, 3)), jnp.float32)       # [B, H, L, D]
+    taps = jnp.asarray(rng.standard_normal((4, 2, 3)), jnp.float32)
+    segs = jnp.asarray([[1] * 7 + [2] * 9 + [0] * 4], jnp.int32)
+    flat_x = jnp.moveaxis(x[0], 1, 0).reshape(20, 6)                       # [L, H * D]
+    rows = lambda y: jnp.moveaxis(y[0], 1, 0).reshape(20, 6)  # noqa: E731
+    got = rows(linear_attn.short_conv(x, taps, segs))
+    np.testing.assert_allclose(got[:7], ref.ref_conv(flat_x[:7], taps.reshape(4, 6)), atol=1e-6)
+    np.testing.assert_allclose(got[7:16], ref.ref_conv(flat_x[7:16], taps.reshape(4, 6)), atol=1e-6)
+    # and without the boundary the first tokens of the second document differ
+    whole = rows(linear_attn.short_conv(x, taps, jnp.ones((1, 20), jnp.int32)))
+    assert np.abs(whole[7:10] - got[7:10]).max() > 1e-2
+
+
+def delta_rule_inputs(seed, length, near_parallel_keys=False, b=2, h=3, d=16):
+    r = np.random.default_rng(seed)
+    q, k, v = (r.standard_normal((b, h, length, d)) for _ in range(3))
+    if near_parallel_keys:
+        k = k * 0.1 + r.standard_normal((b, h, 1, d))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    log_decay = -np.exp(r.uniform(-6, 0.3, (b, h, length, d)))
+    beta = 2 / (1 + np.exp(-2 * r.standard_normal((b, h, length))))
+    segs = np.zeros((b, length), np.int32)
+    for row in range(b):
+        cuts = np.sort(r.choice(np.arange(1, length - 8), 4, replace=False))
+        for s, (a, z) in enumerate(zip([0, *cuts], [*cuts, length - 5])):
+            segs[row, a:z] = s + 1
+    return [jnp.asarray(a, jnp.float32) for a in (q, k, v, log_decay, beta)] + [jnp.asarray(segs)]
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7))
+def interpreted_kernel(q, k, v, log_decay, beta, segs, scale, tile=128):
+    """The Pallas kernel a TPU runs for chunks of 64 at width 128, interpreted."""
+    return linear_attn._delta_rule_fused(q, k, v, log_decay, beta, segs, scale, tile, interpret=True)
+
+
+def kernel_inputs(seed, length, **kw):
+    """:func:`delta_rule_inputs` at the width the kernel takes, two heads of one row."""
+    return delta_rule_inputs(seed, length, b=1, h=2, d=128, **kw)
+
+
+@pytest.mark.parametrize("chunk", [1, 4, 16, 64, 128])
+@pytest.mark.parametrize("length", [150, 64, 37])
+def test_the_chunked_recurrence_is_the_token_by_token_one(chunk, length):
+    args = delta_rule_inputs(chunk + length, length)
+    want = recurrent(*args, scale=0.25)
+    got = chunked(*args, scale=0.25, chunk=chunk)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, atol=5e-6)
+
+
+@pytest.mark.parametrize("length,tile,heads", [(128, 128, 2), (256, 128, 2), (256, 256, 2), (512, 256, 2),
+                                               (768, 256, 3)])
+def test_the_kernel_is_the_token_by_token_recurrence_and_the_plain_form(length, tile, heads):
+    """Four boundaries a row at random places and a pad tail of segment 0:
+    documents start and end inside a chunk, inside a pair of chunks and
+    inside a grid step, and the state crosses from one grid step to the next.
+    An even number of heads goes two to a grid step, an odd number one."""
+    args = delta_rule_inputs(length + tile, length, b=1, h=heads, d=128)
+    segs = np.asarray(args[-1])
+    assert (segs[:, -1] == 0).all() and (np.diff(segs) != 0).sum() >= 4
+    want = recurrent(*args, scale=0.25)
+    plain = chunked(*args, scale=0.25, chunk=64)
+    got = interpreted_kernel(*args, 0.25, tile)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    np.testing.assert_allclose(got, plain, atol=5e-6)
+
+
+def test_a_boundary_at_every_edge_the_kernel_has():
+    """Documents that end exactly where a 16-token block, a chunk, a pair of
+    chunks and a grid step end, one token long, and one across three steps."""
+    q, k, v, log_decay, beta, _ = kernel_inputs(8, 768)
+    segs = np.zeros((1, 768), np.int32)
+    for s, (a, z) in enumerate([(0, 16), (16, 64), (64, 128), (128, 129), (129, 256), (256, 700)]):
+        segs[0, a:z] = s + 1
+    segs = jnp.asarray(segs)
+    want = recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
+    for tile in (128, 256):
+        got = interpreted_kernel(q, k, v, log_decay, beta, segs, 0.25, tile)
+        np.testing.assert_allclose(got, want, atol=5e-6)
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+@pytest.mark.parametrize("one_key", [False, True])
+def test_near_parallel_keys_do_not_blow_the_chunk_up(one_key, form):
+    """beta near 2 on keys that all but repeat, or do repeat (a document that
+    says one token over and over): the triangle's inverse by forward
+    substitution (the plain form) or by doubling from single rows (the
+    kernel) stays exact where a product of a block's powers cancelled terms
+    of 1e6 against each other (it was held to 1e-3 here)."""
+    q, k, v, log_decay, beta, segs = (
+        delta_rule_inputs(1, 150, near_parallel_keys=True) if form == "plain"
+        else kernel_inputs(1, 256, near_parallel_keys=True))
+    if one_key:
+        k, beta = jnp.broadcast_to(k[:, :, :1], k.shape), jnp.full_like(beta, 1.98)
+    want = recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
+    if form == "plain":
+        got = chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=64)
+    else:
+        got = interpreted_kernel(q, k, v, log_decay, beta, segs, 0.25)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("rate", [3.0, 5.0, 9.0])
+@pytest.mark.parametrize("chunk", [16, 64, 128, "kernel"])
+def test_fast_decays_do_not_overflow_the_chunk(rate, chunk):
+    """Channels that forget at ``rate`` a token, real tokens and the pads of
+    a row's tail alike: exp(+-sum of log-decay) around one reference point
+    for a whole chunk of 64 overflowed float32 at 2.5 a token, and a NaN
+    behind a zero of the triangle's inverse reached the document before.
+    ``kernel``: the interpreted kernel (chunks of 64) at its width."""
+    q, k, v, log_decay, beta, segs = delta_rule_inputs(3, 150) if chunk != "kernel" else kernel_inputs(3, 256)
+    fast = np.random.default_rng(4).random(log_decay.shape) < 0.3
+    log_decay = jnp.where(fast, -rate, log_decay)
+    segs = segs.at[:, 120:].set(0)
+    want = recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
+    if chunk == "kernel":
+        got = interpreted_kernel(q, k, v, log_decay, beta, segs, 0.25)
+    else:
+        got = chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=chunk)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=1e-5)

@@ -9,7 +9,6 @@ against a loop-written router; 16 shares of 16 experts against the uncut
 layer; both kernels, interpreted, against their plain forms; and the cut's
 parameter count against ISSUE 33's arithmetic."""
 
-import inspect
 import math
 import os
 import sys
@@ -21,10 +20,10 @@ import pytest
 
 from tpu_tfrecord.metrics import METRICS
 from tpu_tfrecord.models import dsa_reference as ref, lm, moe, sparse_attn
-from tpu_tfrecord.models.attention import blockwise_attention, flash_attention_widths
 
-from test_mla_lm import kernel_inputs, plain_path
-from test_pattern_lm import documents_of, flat, packed_rows as older_rows, reference_weights
+from test_mla_lm import interpreted_kernel, kernel_inputs, mixer, plain_path, scopes_held
+from test_pattern_lm import (SAMPLE_AT, documents_of, flat, held_experts, init_params,
+                             packed_rows as older_rows, reference_weights, score, the_benchmarks_copy)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -70,7 +69,7 @@ def packed_rows():
 
 @pytest.fixture(scope="module")
 def params():
-    p = lm.pattern_init_params(jax.random.PRNGKey(5), program_cfg())
+    p = init_params(jax.random.PRNGKey(5), program_cfg())
     for layer in p["layers"][1:]:  # a bias large enough to change who is chosen
         layer["router_bias"] = layer["router_bias"] * 4.0
     return p
@@ -78,11 +77,9 @@ def params():
 
 @pytest.fixture(scope="module")
 def scored(params):
-    batch, cfg = packed_rows(), program_cfg()
-    sample_at = jnp.asarray([[0, 5, 19, 25], [2, 8, 29, 40]], jnp.int32)
-    out = jax.jit(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
-        params, batch["tokens"], batch["segment_ids"], sample_at, jnp.int32(1))
-    return batch, sample_at, jax.tree.map(np.asarray, out)
+    batch = packed_rows()
+    out = score(params, batch["tokens"], batch["segment_ids"], SAMPLE_AT, program_cfg(), jnp.int32(1))
+    return batch, SAMPLE_AT, jax.tree.map(np.asarray, out)
 
 
 def test_the_parameters_are_the_models(params):
@@ -152,13 +149,16 @@ def test_the_probes_carry_the_first_expert_layers_selection(params, scored):
 def test_the_mixer_against_the_reference(params):
     cfg, layer = program_cfg(), params["layers"][1]
     x = jnp.asarray(np.random.default_rng(5).standard_normal((1, L, 32)), jnp.float32)
-    got = lm.mla_mixer(layer, x, jnp.ones((1, L), jnp.int32), cfg)
+    got = mixer(layer, x, jnp.ones((1, L), jnp.int32), cfg)
+
+    def reference(**lower):  # one program a control
+        return jax.jit(lambda p, x: ref.ref_dsa(p, ref.ref_norm(x, p["attn_norm"], 1e-6), CFG, lower))(
+            flat(layer), x[0])
+
     with jax.default_matmul_precision("highest"):
-        p = flat(layer)
-        u = ref.ref_norm(x[0], p["attn_norm"], 1e-6)
-        want, record = ref.ref_dsa(p, u, CFG)
-        every, _ = ref.ref_dsa(p, u, CFG, {"no_selection": True})
-        unscaled, _ = ref.ref_dsa(p, u, CFG, {"no_yarn": True})
+        want, record = reference()
+        every, _ = reference(no_selection=True)
+        unscaled, _ = reference(no_yarn=True)
     np.testing.assert_allclose(got[0], want, atol=3e-5)
     kept = np.asarray(record["kept"]).sum(axis=1)
     assert (kept >= np.minimum(np.arange(L) + 1, 6)).all() and kept.sum() <= 6 * L - 15 + 6
@@ -178,12 +178,12 @@ def test_a_document_shorter_than_index_topk_reads_like_latent_attention_without_
     none = dataclasses.replace(loose, index_topk=0, index_heads=0, index_dim=0)
     bare = {**params, "layers": [{k: v for k, v in layer.items() if "idx" not in k}
                                  for layer in params["layers"]]}
-    got = lm.score(params, tokens, segs, at, loose)
-    want = lm.score(bare, tokens, segs, at, none)
+    got = score(params, tokens, segs, at, loose)
+    want = score(bare, tokens, segs, at, none)
     np.testing.assert_array_equal(got["logprob"], want["logprob"])
     assert "selected" not in want and (np.asarray(got["selected"])[:, 0]
                                        == np.asarray(got["selected"])[:, 1]).all()
-    tight = lm.score(params, tokens, segs, at, program_cfg())
+    tight = score(params, tokens, segs, SAMPLE_AT, program_cfg(), jnp.int32(1))  # the fixture's program
     assert np.abs(np.asarray(tight["logprob"]) - np.asarray(want["logprob"])).max() > 1e-3
 
 
@@ -198,9 +198,12 @@ def selection_inputs(seed=0, b=2, l=256, h=4, d=128, dtype=jnp.bfloat16):
     return q, k, w, jnp.asarray(segs)
 
 
+select_keys = jax.jit(sparse_attn.select_keys, static_argnums=4, static_argnames="block")
+
+
 def test_no_key_of_another_document_is_ever_kept():
     q, k, w, segs = selection_inputs()
-    keep, kept = map(np.asarray, sparse_attn.select_keys(q, k, w, segs, 16, block=64))
+    keep, kept = map(np.asarray, select_keys(q, k, w, segs, 16, block=64))
     s = np.asarray(segs)
     same = s[:, :, None] == s[:, None, :]
     causal = np.tril(np.ones((256, 256), bool))
@@ -220,8 +223,7 @@ def test_no_key_of_another_document_is_ever_kept():
 def test_ties_at_the_threshold_are_all_kept():
     q = jnp.ones((1, 1, 8, 128), jnp.bfloat16)
     k = jnp.ones((1, 8, 128), jnp.bfloat16)
-    keep, kept = sparse_attn.select_keys(q, k, jnp.ones((1, 8, 1)), jnp.ones((1, 8), jnp.int32), 3,
-                                         block=4)
+    keep, kept = select_keys(q, k, jnp.ones((1, 8, 1)), jnp.ones((1, 8), jnp.int32), 3, block=4)
     assert (np.asarray(keep[0]) == np.tril(np.ones((8, 8), np.int8))).all()
     assert (np.asarray(kept[0]) == np.arange(1, 9)).all()
 
@@ -232,16 +234,14 @@ def test_the_selection_kernel_interpreted_is_the_plain_form(topk):
 
     q, k, w, segs = selection_inputs(seed=topk)
     with pltpu.force_tpu_interpret_mode():
-        keep, kept = sparse_attn._select_fused(q, k, w, segs, topk, (64, 128))
-    want_keep, want_kept = sparse_attn._select_plain(q, k, w, segs, topk, 64)
+        keep, kept = jax.jit(lambda *a: sparse_attn._select_fused(*a, topk, (64, 128)))(q, k, w, segs)
+    want_keep, want_kept = jax.jit(lambda *a: sparse_attn._select_plain(*a, topk, 64))(q, k, w, segs)
     np.testing.assert_array_equal(np.asarray(keep), np.asarray(want_keep))
     np.testing.assert_array_equal(np.asarray(kept), np.asarray(want_kept))
     assert sparse_attn.select_tile(q.shape, topk) is None       # off a TPU
 
 
 def test_the_attention_kernel_interpreted_under_a_selection_is_the_plain_path():
-    from jax.experimental.pallas import tpu as pltpu
-
     rng = np.random.default_rng(3)
     b, h, l = 1, 2, 256
     q = jnp.asarray(rng.standard_normal((b, h, l, 192)), jnp.float32)
@@ -249,14 +249,11 @@ def test_the_attention_kernel_interpreted_under_a_selection_is_the_plain_path():
     v = jnp.asarray(rng.standard_normal((b, h, l, 128)), jnp.float32)
     qi, ki, w, _ = selection_inputs(seed=9, b=1)
     segs = jnp.asarray(np.repeat([1, 2], [160, 96])[None].astype(np.int32))
-    keep, _ = sparse_attn.select_keys(qi, ki, w, segs, 24, block=64)
-    with pltpu.force_tpu_interpret_mode():
-        got = flash_attention_widths(q, k, v, segs, 0.11, 128, 128, keep=keep)
-    want = blockwise_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
-                               segs, scale=0.11, block=64, keep=keep)
-    np.testing.assert_allclose(got, jnp.swapaxes(want, 1, 2), atol=2e-5)
-    dense = blockwise_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                                jnp.swapaxes(v, 1, 2), segs, scale=0.11, block=64)
+    keep, _ = select_keys(qi, ki, w, segs, 24, block=64)
+    got = interpreted_kernel(q, k, v, segs, 0.11, 128, 128, keep=keep)
+    want = plain_path(q, k, v, segs, 0.11, keep=keep)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    dense = plain_path(q, k, v, segs, 0.11)
     assert np.abs(np.asarray(want) - np.asarray(dense)).max() > 0.1
 
 
@@ -287,8 +284,6 @@ def test_each_kind_of_pair_under_a_selection_is_the_plain_path(case):
     """The kernel's kinds of pair (tests/test_mla_lm.py has them without a
     selection) apply the kept keys beside their own mask: under the diagonal
     no position is compared, on it a pass takes the keys its rows reach."""
-    from jax.experimental.pallas import tpu as pltpu
-
     lengths, l, blocks, change = KEPT_KINDS[case]
     q, k, v, segs = kernel_inputs(lengths, l, seed=8)
     keep = a_selection(segs)
@@ -300,8 +295,7 @@ def test_each_kind_of_pair_under_a_selection_is_the_plain_path(case):
             keep[0, row] = 0
             keep[0, row, row] = 1
     keep = jnp.asarray(keep)
-    with pltpu.force_tpu_interpret_mode():
-        got = flash_attention_widths(q, k, v, segs, 0.09, *blocks, keep=keep)
+    got = interpreted_kernel(q, k, v, segs, 0.09, *blocks, keep=keep)
     want = plain_path(q, k, v, segs, 0.09, keep=keep)
     real = np.asarray(segs[0] != 0)
     np.testing.assert_allclose(np.asarray(got)[:, :, real], np.asarray(want)[:, :, real], atol=2e-5)
@@ -385,8 +379,8 @@ def test_the_shares_of_256_experts_held_16_by_16_add_up_to_the_uncut_layer():
     of 32, 4 stay, 8 chosen), the shared expert counted once, against the
     reference told that it holds all 256."""
     cfg = {**CFG, "n_routed_experts": 256, "n_routed_experts_held": 256, "num_experts_per_tok": 8,
-           "n_group": 8, "topk_group": 4}
-    p = lm.pattern_init_params(jax.random.PRNGKey(1), program_cfg(cfg))["layers"][1]
+           "n_group": 8, "topk_group": 4, "num_hidden_layers": 2}    # up to the layer taken
+    p = init_params(jax.random.PRNGKey(1), program_cfg(cfg))["layers"][1]
     p["router_bias"] = p["router_bias"] * 4.0
     x = jnp.asarray(np.random.default_rng(1).standard_normal((96, 32)), jnp.float32)
     with jax.default_matmul_precision("highest"):
@@ -398,8 +392,8 @@ def test_the_shares_of_256_experts_held_16_by_16_add_up_to_the_uncut_layer():
     total, visits = -15 * shared, 0
     for first in range(0, 256, 16):
         share = {**p, **{k: p[k][first:first + 16] for k in ("w_gate", "w_up", "w_down")}}
-        y, n, dropped, _ = moe.held_experts_apply(share, x, held_offset=first, top_k=8,
-                                                  routed_scale=2.5, tile=8, n_group=8, topk_group=4)
+        y, n, dropped, _ = held_experts(share, x, held_offset=first, top_k=8,
+                                        routed_scale=2.5, tile=8, n_group=8, topk_group=4)
         total, visits = total + y, visits + int(n.sum())
         assert int(dropped) == 0
     assert visits == x.shape[0] * 8
@@ -413,19 +407,20 @@ def test_tiles_added_to_their_tokens_are_the_read_back():
     buffer back: the same sums, here 2 of 32 experts against the same two
     told that the router has 16 outputs' worth of company (both paths see the
     same router, the same visits)."""
-    cfg = program_cfg({**CFG, "n_routed_experts": 32, "n_routed_experts_held": 32})
-    p = lm.pattern_init_params(jax.random.PRNGKey(2), cfg)["layers"][1]
+    cfg = program_cfg({**CFG, "n_routed_experts": 32, "n_routed_experts_held": 32,
+                       "num_hidden_layers": 2})                 # up to the layer taken
+    p = init_params(jax.random.PRNGKey(2), cfg)["layers"][1]
     x = jnp.asarray(np.random.default_rng(4).standard_normal((96, 32)), jnp.float32)
     valid = jnp.arange(96) % 7 != 0
     kw = dict(top_k=3, routed_scale=2.5, valid=valid, n_group=4, topk_group=2)
-    whole, visits, dropped, _ = moe.held_experts_apply(p, x, held_offset=0, tile=8, **kw)  # read back
+    whole, visits, dropped, _ = held_experts(p, x, held_offset=0, tile=8, **kw)  # read back
     assert int(dropped) == 0 and int(visits.sum()) == int(valid.sum()) * 3
     shared = moe.gated_ffn(x, *(p["shared"][k] for k in ("w_gate", "w_up", "w_down")))
     for tile in (8, 32):
         total, seen = -15 * shared, 0
         for first in range(0, 32, 2):                              # 2 of 32: added as they come
             share = {**p, **{k: p[k][first:first + 2] for k in ("w_gate", "w_up", "w_down")}}
-            y, n, lost, _ = moe.held_experts_apply(share, x, held_offset=first, tile=tile, **kw)
+            y, n, lost, _ = held_experts(share, x, held_offset=first, tile=tile, **kw)
             total, seen = total + y, seen + int(n.sum())
             assert int(lost) == 0 and (np.asarray(n) == np.asarray(visits)[first:first + 2]).all()
         assert seen == int(visits.sum())
@@ -507,27 +502,14 @@ def test_naming_a_groups_experts_anew_changes_no_tokens_routing(seed):
 
 
 def test_the_benchmarks_copy_of_the_reference_is_this_one():
-    sys.path.insert(0, ROOT)
-    from benchmark.models import deepseek_v32 as copy
-
-    names = [n for n, f in inspect.getmembers(ref, inspect.isfunction)
-             if f.__module__ == ref.__name__]
-    assert "reference_score" in names and len(names) >= 9
-    for name in names:
-        assert inspect.getsource(getattr(ref, name)) == inspect.getsource(getattr(copy, name)), name
+    copy = the_benchmarks_copy(ref, "deepseek_v32", 9)
     assert copy.SORT_ROWS == ref.SORT_ROWS and copy.HEAD_ROWS == ref.HEAD_ROWS
 
 
 def test_the_compiled_program_holds_every_scope(params):
-    import re
-
     from tpu_tfrecord import tracing
 
-    batch, cfg = packed_rows(), program_cfg()
-    lowered = jax.jit(lambda p, t, s, a: lm.score(p, t, s, a, cfg)).lower(
-        params, batch["tokens"], batch["segment_ids"], jnp.zeros((2, 1), jnp.int32))
-    op_names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
-    held = {tok for name in op_names for tok in re.findall(r"tfr\.\w+", name)}
+    held = scopes_held(params, packed_rows(), program_cfg())
     assert held == {"tfr.embed", "tfr.mla_proj", "tfr.mla_attn", "tfr.dsa_proj", "tfr.dsa_index",
                     "tfr.dense_ffn", "tfr.moe_route", "tfr.moe_experts", "tfr.moe_shared",
                     "tfr.lm_head"}

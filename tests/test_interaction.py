@@ -124,7 +124,7 @@ class TestDLRMDotInteraction:
         tx = optax.adam(1e-2)
         opt_state = tx.init(params)
         step = _jax.jit(functools.partial(train_step, cfg=cfg, tx=tx))
-        first = float(loss_fn(params, batch, cfg))
+        first = float(_jax.jit(loss_fn, static_argnums=2)(params, batch, cfg))
         for _ in range(15):
             params, opt_state, loss = step(params, opt_state, batch)
         assert float(loss) < first

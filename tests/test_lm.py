@@ -18,6 +18,14 @@ from tpu_tfrecord.tpu import TokenPacker, create_mesh
 
 CFG = lm.LMConfig(vocab_size=64, d_model=16, n_heads=2, n_layers=2, max_len=16)
 
+#: the dense reference, one program a configuration (bare, it runs primitive
+#: by primitive, each primitive a compile)
+dense_forward = jax.jit(lm.forward, static_argnums=2)
+
+
+def sharded_forward(cfg, mesh, **axes):
+    return jax.jit(functools.partial(lm.forward, cfg=cfg, mesh=mesh, **axes))
+
 
 def batch(cfg=CFG, b=8, seed=0):
     return jnp.asarray(lm.make_synthetic_tokens(cfg, b, seed=seed))
@@ -31,15 +39,10 @@ class TestForwardParity:
         mesh = create_mesh({"data": 2, "seq": 4})
         params = lm.init_params(jax.random.key(0), CFG)
         toks = batch()
-        want, _ = lm.forward(params, toks, CFG)
+        want, _ = dense_forward(params, toks, CFG)
         sh = lm.batch_shardings(mesh)
         toks_sh = jax.device_put(toks, sh["tokens"])
-        got, _ = jax.jit(
-            functools.partial(
-                lm.forward, cfg=CFG, mesh=mesh, data_axis="data",
-                seq_axis="seq",
-            )
-        )(params, toks_sh)
+        got, _ = sharded_forward(CFG, mesh, data_axis="data", seq_axis="seq")(params, toks_sh)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
         )
@@ -53,16 +56,11 @@ class TestForwardParity:
         mesh = create_mesh({"pipe": 4, "data": 2})
         params = lm.init_params(jax.random.key(0), cfg)
         toks = batch(cfg)
-        want, _ = lm.forward(params, toks, cfg)
+        want, _ = dense_forward(params, toks, cfg)
         p_sh = jax.device_put(
             params, lm.param_shardings(mesh, params, pipe_axis="pipe")
         )
-        got, _ = jax.jit(
-            functools.partial(
-                lm.forward, cfg=cfg, mesh=mesh, data_axis="data",
-                pipe_axis="pipe",
-            )
-        )(p_sh, toks)
+        got, _ = sharded_forward(cfg, mesh, data_axis="data", pipe_axis="pipe")(p_sh, toks)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
         )
@@ -78,16 +76,11 @@ class TestForwardParity:
         mesh = create_mesh({"pipe": 2, "data": 2}, jax.devices()[:4])
         params = lm.init_params(jax.random.key(0), cfg)
         toks = batch(cfg)
-        want, _ = lm.forward(params, toks, cfg)
+        want, _ = dense_forward(params, toks, cfg)
         p_sh = jax.device_put(
             params, lm.param_shardings(mesh, params, pipe_axis="pipe")
         )
-        got, _, diag = jax.jit(
-            functools.partial(
-                lm.forward, cfg=cfg, mesh=mesh, data_axis="data",
-                pipe_axis="pipe", diagnostics=True,
-            )
-        )(p_sh, toks)
+        got, _, diag = sharded_forward(cfg, mesh, data_axis="data", pipe_axis="pipe", diagnostics=True)(p_sh, toks)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
         )
@@ -120,16 +113,11 @@ class TestForwardParity:
         mesh = create_mesh({"data": 2, "expert": 4})
         params = lm.init_params(jax.random.key(0), cfg)
         toks = batch(cfg)
-        want, aux_want = lm.forward(params, toks, cfg)
+        want, aux_want = dense_forward(params, toks, cfg)
         p_sh = jax.device_put(
             params, lm.param_shardings(mesh, params, expert_axis="expert")
         )
-        got, aux = jax.jit(
-            functools.partial(
-                lm.forward, cfg=cfg, mesh=mesh, data_axis="data",
-                expert_axis="expert",
-            )
-        )(p_sh, toks)
+        got, aux = sharded_forward(cfg, mesh, data_axis="data", expert_axis="expert")(p_sh, toks)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
         )
@@ -240,7 +228,7 @@ class TestLMStream:
             vocab_size=64, d_model=16, n_heads=2, n_layers=4, max_len=16
         )
         for got, r in zip(outs, reqs):
-            want, _ = lm.forward(params, jnp.asarray(r), dense_cfg)
+            want, _ = dense_forward(params, jnp.asarray(r), dense_cfg)
             np.testing.assert_allclose(
                 got, np.asarray(want), rtol=2e-4, atol=2e-4
             )

@@ -28,6 +28,12 @@ CFG4 = lm.LMConfig(
 _PLACEMENT_AXES = ("pipe_axis", "expert_axis", "fsdp_axis")
 
 
+#: the dense references, one program a configuration and shape (bare, they
+#: run primitive by primitive, each primitive a compile)
+dense_forward = jax.jit(lm.forward, static_argnums=2)
+dense_loss = jax.jit(lm.loss_fn, static_argnums=2)
+
+
 def batch(cfg=CFG, b=8, seed=0):
     return jnp.asarray(lm.make_synthetic_tokens(cfg, b, seed=seed))
 
@@ -36,16 +42,26 @@ def place(params, mesh, **axes):
     return jax.device_put(params, lm.param_shardings(mesh, params, **axes))
 
 
+TX = optax.adam(3e-3)
+
+
+@functools.cache
+def train_step(cfg, mesh=None, **axes):
+    """The jitted ``lm.train_step`` of a configuration, mesh and layout, one
+    for the module: a trajectory and the run it is compared with, in one
+    test or in two, share the program."""
+    return jax.jit(
+        functools.partial(lm.train_step, cfg=cfg, tx=TX, mesh=mesh, **axes)
+    )
+
+
 def trajectory(cfg, mesh=None, steps=6, **axes):
     params = lm.init_params(jax.random.key(0), cfg)
     if mesh is not None:
         pl = {k: axes[k] for k in _PLACEMENT_AXES if axes.get(k)}
         params = place(params, mesh, **pl)
-    tx = optax.adam(3e-3)
-    opt = tx.init(params)
-    step = jax.jit(
-        functools.partial(lm.train_step, cfg=cfg, tx=tx, mesh=mesh, **axes)
-    )
+    opt = TX.init(params)
+    step = train_step(cfg, mesh, **axes)
     losses = []
     for i in range(steps):
         params, opt, loss = step(params, opt, batch(cfg, b=8, seed=100 + i))
@@ -109,17 +125,11 @@ class TestFSDPMemory:
         mesh = create_mesh(mesh_axes)
         params = lm.init_params(jax.random.key(0), cfg)
         params = place(params, mesh, fsdp_axis=fsdp_axis)
-        tx = optax.adam(3e-3)
-        opt = tx.init(params)
+        opt = TX.init(params)
         toks = jax.device_put(
             batch(cfg), NamedSharding(mesh, P("data", None))
         )
-        step = jax.jit(
-            functools.partial(
-                lm.train_step, cfg=cfg, tx=tx, mesh=mesh,
-                data_axis="data", fsdp_axis=fsdp_axis,
-            )
-        )
+        step = train_step(cfg, mesh, data_axis="data", fsdp_axis=fsdp_axis)
         mem = step.lower(params, opt, toks).compile().memory_analysis()
         return mem.argument_size_in_bytes
 
@@ -150,9 +160,9 @@ class TestCheckpointInterchange:
     def _host(self, tree):
         return jax.tree.map(np.asarray, jax.device_get(tree))
 
-    def _place_state(self, mesh, params, opt, tx, **axes):
+    def _place_state(self, mesh, params, opt, **axes):
         p_sh = place(params, mesh, **axes)
-        tmpl = tx.init(p_sh)  # zeros_like: inherits the sharded layout
+        tmpl = TX.init(p_sh)  # zeros_like: inherits the sharded layout
         repl = NamedSharding(mesh, P())
 
         def put(t, v):
@@ -162,10 +172,8 @@ class TestCheckpointInterchange:
         opt_sh = jax.tree.map(put, tmpl, opt)
         return p_sh, opt_sh
 
-    def _run(self, cfg, params, opt, tx, mesh, steps, seed0, **axes):
-        step = jax.jit(
-            functools.partial(lm.train_step, cfg=cfg, tx=tx, mesh=mesh, **axes)
-        )
+    def _run(self, cfg, params, opt, mesh, steps, seed0, **axes):
+        step = train_step(cfg, mesh, **axes)
         losses = []
         for i in range(steps):
             params, opt, loss = step(
@@ -176,11 +184,10 @@ class TestCheckpointInterchange:
 
     def test_save_dp_restore_fsdp_and_fsdp_pp(self, tmp_path):
         cfg = CFG4
-        tx = optax.adam(3e-3)
         ref = trajectory(cfg)
         params = lm.init_params(jax.random.key(0), cfg)
-        opt = tx.init(params)
-        params, opt, head = self._run(cfg, params, opt, tx, None, 3, 100)
+        opt = TX.init(params)
+        params, opt, head = self._run(cfg, params, opt, None, 3, 100)
         np.testing.assert_allclose(head, ref[:3], rtol=1e-6)
         saved_host = self._host({"params": params, "opt": opt})
         with AsyncCheckpointer(str(tmp_path / "dp")) as ckpt:
@@ -188,7 +195,7 @@ class TestCheckpointInterchange:
             ckpt.wait()
             fresh = lm.init_params(jax.random.key(1), cfg)
             step_no, state, _ = ckpt.restore(
-                {"params": fresh, "opt": tx.init(fresh)}
+                {"params": fresh, "opt": TX.init(fresh)}
             )
         assert step_no == 3
         jax.tree.map(
@@ -203,24 +210,22 @@ class TestCheckpointInterchange:
             mesh = create_mesh(mesh_axes)
             pl = {k: axes[k] for k in _PLACEMENT_AXES if axes.get(k)}
             p_sh, opt_sh = self._place_state(
-                mesh, state["params"], state["opt"], tx, **pl
+                mesh, state["params"], state["opt"], **pl
             )
             _, _, tail = self._run(
-                cfg, p_sh, opt_sh, tx, mesh, 3, 103, **axes
+                cfg, p_sh, opt_sh, mesh, 3, 103, **axes
             )
             np.testing.assert_allclose(tail, ref[3:], rtol=1e-5, atol=1e-6)
 
     def test_save_fsdp_restore_dp(self, tmp_path):
         cfg = CFG4
-        tx = optax.adam(3e-3)
         mesh = create_mesh({"data": 2, "fsdp": 4})
         axes = dict(data_axis="data", fsdp_axis="fsdp")
         full = trajectory(cfg, mesh=mesh, **axes)
         params = place(lm.init_params(jax.random.key(0), cfg), mesh,
                        fsdp_axis="fsdp")
-        opt = tx.init(params)
-        params, opt, head = self._run(cfg, params, opt, tx, mesh, 3, 100,
-                                      **axes)
+        opt = TX.init(params)
+        params, opt, head = self._run(cfg, params, opt, mesh, 3, 100, **axes)
         np.testing.assert_allclose(head, full[:3], rtol=1e-6)
         saved_host = self._host({"params": params, "opt": opt})
         with AsyncCheckpointer(str(tmp_path / "fsdp")) as ckpt:
@@ -228,11 +233,11 @@ class TestCheckpointInterchange:
             ckpt.wait()
             fresh = lm.init_params(jax.random.key(1), cfg)
             _, state, _ = ckpt.restore(
-                {"params": fresh, "opt": tx.init(fresh)}
+                {"params": fresh, "opt": TX.init(fresh)}
             )
         jax.tree.map(np.testing.assert_array_equal, state, saved_host)
         _, _, tail = self._run(
-            cfg, state["params"], state["opt"], tx, None, 3, 103
+            cfg, state["params"], state["opt"], None, 3, 103
         )
         np.testing.assert_allclose(tail, full[3:], rtol=1e-5, atol=1e-6)
 
@@ -272,15 +277,15 @@ class TestSegmentOracle:
         # no open bin and closes the batch
         toks, segs = _pack_batch(_oracle_docs(rng, [9, 6, 12, 4]))
         params = lm.init_params(jax.random.key(0), CFG)
-        packed, _ = lm.forward(params, jnp.asarray(toks), CFG,
-                               segments=jnp.asarray(segs))
+        packed, _ = dense_forward(params, jnp.asarray(toks), CFG,
+                                  segments=jnp.asarray(segs))
         packed = np.asarray(packed)
         L = packed.shape[1]
         checked = 0
         for r in range(toks.shape[0]):
             for s in np.unique(segs[r][segs[r] > 0]):
                 a_toks, a_segs, at, n = self._alone(toks, segs, r, s)
-                alone, _ = lm.forward(
+                alone, _ = dense_forward(
                     params, jnp.asarray(a_toks), CFG,
                     segments=jnp.asarray(a_segs),
                 )
@@ -299,7 +304,7 @@ class TestSegmentOracle:
         rng = np.random.default_rng(7)
         toks, segs = _pack_batch(_oracle_docs(rng, [9, 6, 12, 4]))
         params = lm.init_params(jax.random.key(0), CFG)
-        packed = float(lm.loss_fn(params, jnp.asarray(toks), CFG,
+        packed = float(dense_loss(params, jnp.asarray(toks), CFG,
                                   segments=jnp.asarray(segs)))
         num = den = 0.0
         for r in range(toks.shape[0]):
@@ -307,7 +312,7 @@ class TestSegmentOracle:
                 a_toks, a_segs, _, n = TestSegmentOracle._alone(
                     self, toks, segs, r, s
                 )
-                l_d = float(lm.loss_fn(params, jnp.asarray(a_toks), CFG,
+                l_d = float(dense_loss(params, jnp.asarray(a_toks), CFG,
                                        segments=jnp.asarray(a_segs)))
                 num += l_d * (n - 1)
                 den += n - 1
@@ -321,8 +326,8 @@ class TestSegmentOracle:
                 for n in rng.integers(3, 15, size=60)]
         toks, segs = _pack_batch(docs, b=8)
         params = lm.init_params(jax.random.key(0), CFG)
-        want, _ = lm.forward(params, jnp.asarray(toks), CFG,
-                             segments=jnp.asarray(segs))
+        want, _ = dense_forward(params, jnp.asarray(toks), CFG,
+                                segments=jnp.asarray(segs))
         mesh = create_mesh({"data": 2, "seq": 2, "fsdp": 2})
         p_sh = place(params, mesh, fsdp_axis="fsdp")
         got, _ = jax.jit(
@@ -355,11 +360,8 @@ class TestSegmentOracle:
             params = lm.init_params(jax.random.key(0), CFG)
             if mesh is not None:
                 params = place(params, mesh, fsdp_axis=axes["fsdp_axis"])
-            tx = optax.adam(3e-3)
-            opt = tx.init(params)
-            step = jax.jit(functools.partial(
-                lm.train_step, cfg=CFG, tx=tx, mesh=mesh, **axes
-            ))
+            opt = TX.init(params)
+            step = train_step(CFG, mesh, **axes)  # traced anew with segments
             losses = []
             for hb in batches:
                 params, opt, loss = step(

@@ -18,6 +18,14 @@ CFG = long_doc.LongDocConfig(
 )
 
 
+#: the dense reference and the loss, one program a configuration and mesh
+#: (bare, they run primitive by primitive, each primitive a compile)
+forward = jax.jit(
+    long_doc.forward, static_argnums=(2, 3), static_argnames=("data_axis", "with_aux")
+)
+loss_fn = jax.jit(long_doc.loss_fn, static_argnums=(2, 3), static_argnames="data_axis")
+
+
 def _mesh(data=2, seq=4):
     return create_mesh({"data": data, "seq": seq}, jax.devices()[: data * seq])
 
@@ -30,16 +38,12 @@ class TestForward:
         params = long_doc.init_params(jax.random.key(0), CFG)
         hb = long_doc.make_synthetic_batch(CFG, 8, seed=1)
         batch = {k: jnp.asarray(v) for k, v in hb.items()}
-        want = long_doc.forward(params, batch, CFG)  # dense reference
+        want = forward(params, batch, CFG)  # dense reference
         sh = long_doc.batch_shardings(mesh, hb)
         sharded = {
             k: jax.device_put(v, sh[k]) for k, v in batch.items()
         }
-        got = jax.jit(
-            functools.partial(
-                long_doc.forward, cfg=CFG, mesh=mesh, data_axis="data"
-            )
-        )(params, sharded)
+        got = forward(params, sharded, CFG, mesh, data_axis="data")
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
         )
@@ -54,13 +58,13 @@ class TestForward:
         params = long_doc.init_params(jax.random.key(0), cfg)
         hb = long_doc.make_synthetic_batch(cfg, 8, seed=5)
         batch = {k: jnp.asarray(v) for k, v in hb.items()}
-        base, aux_base = long_doc.forward(params, batch, cfg, with_aux=True)
+        base, aux_base = forward(params, batch, cfg, with_aux=True)
         frames = np.asarray(batch["frames"]).copy()
         lengths = np.asarray(batch["frames_len"])
         for i, n in enumerate(lengths):
             frames[i, n:] = 1e3  # garbage in every padded position
         poisoned = dict(batch, frames=jnp.asarray(frames))
-        got, aux_got = long_doc.forward(params, poisoned, cfg, with_aux=True)
+        got, aux_got = forward(params, poisoned, cfg, with_aux=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(base), rtol=1e-5)
         np.testing.assert_allclose(float(aux_got), float(aux_base), rtol=1e-6)
 
@@ -70,13 +74,13 @@ class TestForward:
         hb = long_doc.make_synthetic_batch(CFG, 4, seed=2)
         hb["frames_len"] = np.minimum(hb["frames_len"], CFG.max_len // 2)
         batch = {k: jnp.asarray(v) for k, v in hb.items()}
-        base = long_doc.forward(params, batch, CFG)
+        base = forward(params, batch, CFG)
         hb2 = dict(hb)
         frames2 = hb["frames"].copy()
         frames2[:, CFG.max_len // 2 :] = 99.0  # garbage in the padding
         hb2["frames"] = frames2
         batch2 = {k: jnp.asarray(v) for k, v in hb2.items()}
-        out2 = long_doc.forward(params, batch2, CFG)
+        out2 = forward(params, batch2, CFG)
         np.testing.assert_allclose(np.asarray(base), np.asarray(out2), rtol=1e-5)
 
 
@@ -103,7 +107,7 @@ class TestTraining:
             donate_argnums=(0, 1),
         )
         first = float(
-            long_doc.loss_fn(
+            loss_fn(
                 jax.device_put(long_doc.init_params(jax.random.key(0), CFG), p_sh),
                 batch, CFG, mesh, data_axis="data",
             )
@@ -177,8 +181,8 @@ class TestTraining:
         hb = long_doc.make_synthetic_batch(CFG, 8, seed=4)
         batch = {k: jnp.asarray(v) for k, v in hb.items()}
         cfg_r = dataclasses.replace(CFG, remat=True)
-        g_plain = jax.grad(lambda p: long_doc.loss_fn(p, batch, CFG))(params)
-        g_remat = jax.grad(lambda p: long_doc.loss_fn(p, batch, cfg_r))(params)
+        g_plain, g_remat = (
+            jax.jit(jax.grad(lambda p: long_doc.loss_fn(p, batch, cfg)))(params) for cfg in (CFG, cfg_r))
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
@@ -199,16 +203,9 @@ class TestTraining:
         sh = long_doc.batch_shardings(mesh, hb)
         batch = {k: jax.device_put(jnp.asarray(v), sh[k]) for k, v in hb.items()}
         cfg_r = dataclasses.replace(CFG, remat=True)
-        g_plain = jax.jit(
-            jax.grad(
-                lambda p: long_doc.loss_fn(p, batch, CFG, mesh, data_axis="data")
-            )
-        )(params)
-        g_remat = jax.jit(
-            jax.grad(
-                lambda p: long_doc.loss_fn(p, batch, cfg_r, mesh, data_axis="data")
-            )
-        )(params)
+        g_plain, g_remat = (
+            jax.jit(jax.grad(lambda p: long_doc.loss_fn(p, batch, cfg, mesh, data_axis="data")))(params)
+            for cfg in (CFG, cfg_r))
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5
@@ -250,12 +247,10 @@ class TestUlyssesFlavor:
         params = long_doc.init_params(jax.random.key(0), cfg)
         hb = long_doc.make_synthetic_batch(cfg, 8, seed=1)
         batch = {k: jnp.asarray(v) for k, v in hb.items()}
-        want = long_doc.forward(params, batch, cfg)  # dense reference
+        want = forward(params, batch, cfg)  # dense reference
         sh = long_doc.batch_shardings(mesh, hb)
         sharded = {k: jax.device_put(v, sh[k]) for k, v in batch.items()}
-        got = jax.jit(
-            functools.partial(long_doc.forward, cfg=cfg, mesh=mesh, data_axis="data")
-        )(params, sharded)
+        got = forward(params, sharded, cfg, mesh, data_axis="data")
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
         )
@@ -292,12 +287,12 @@ class TestMoEFlavor:
         assert "moe" in params["layers"][0] and "mlp_in" not in params["layers"][0]
         hb = long_doc.make_synthetic_batch(cfg, 8, seed=1)
         batch = {k: jnp.asarray(v) for k, v in hb.items()}
-        logits, aux = long_doc.forward(params, batch, cfg, with_aux=True)
+        logits, aux = forward(params, batch, cfg, with_aux=True)
         assert logits.shape == (8, cfg.n_classes)
         assert float(aux) > 0  # load-balance loss accumulated across layers
         # dense flavor reports exactly zero aux
         dp = long_doc.init_params(jax.random.key(0), CFG)
-        _, aux0 = long_doc.forward(dp, batch, CFG, with_aux=True)
+        _, aux0 = forward(dp, batch, CFG, with_aux=True)
         assert float(aux0) == 0.0
 
     def test_ep_sharded_params_match_replicated(self):
@@ -308,16 +303,14 @@ class TestMoEFlavor:
         params = long_doc.init_params(jax.random.key(0), cfg)
         hb = long_doc.make_synthetic_batch(cfg, 8, seed=2)
         batch = {k: jnp.asarray(v) for k, v in hb.items()}
-        want = long_doc.forward(params, batch, cfg)
+        want = forward(params, batch, cfg)
         sh = moe_mod.param_shardings(mesh, expert_axis="expert")
         p_sh = dict(params)
         p_sh["layers"] = [
             {**layer, "moe": {k: jax.device_put(v, sh[k]) for k, v in layer["moe"].items()}}
             for layer in params["layers"]
         ]
-        got = jax.jit(
-            functools.partial(long_doc.forward, cfg=cfg)
-        )(p_sh, batch)
+        got = forward(p_sh, batch, cfg)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
         )
@@ -373,14 +366,10 @@ class TestGQAFlavor:
             params = long_doc.init_params(jax.random.key(0), cfg)
             hb = long_doc.make_synthetic_batch(cfg, 8, seed=4)
             batch = {k: jnp.asarray(v) for k, v in hb.items()}
-            want = long_doc.forward(params, batch, cfg)
+            want = forward(params, batch, cfg)
             sh = long_doc.batch_shardings(mesh, hb)
             sharded = {k: jax.device_put(v, sh[k]) for k, v in batch.items()}
-            got = jax.jit(
-                functools.partial(
-                    long_doc.forward, cfg=cfg, mesh=mesh, data_axis="data"
-                )
-            )(params, sharded)
+            got = forward(params, sharded, cfg, mesh, data_axis="data")
             np.testing.assert_allclose(
                 np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
             )

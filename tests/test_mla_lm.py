@@ -7,10 +7,8 @@ the Pallas kernel for 192-wide keys against 128-wide values, interpreted,
 against the plain path; the shares of 64 experts held as 8 x 8 against the
 uncut layer; and the pattern model's older configurations left as they were."""
 
+import functools
 import hashlib
-import inspect
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -20,9 +18,8 @@ import pytest
 from tpu_tfrecord.models import lm, mla_reference as ref, moe
 from tpu_tfrecord.models.attention import blockwise_attention, flash_attention_widths, pair_kinds
 
-from test_pattern_lm import documents_of, flat, packed_rows as older_rows, reference_weights
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from test_pattern_lm import (SAMPLE_AT, documents_of, flat, held_experts, init_params,
+                             packed_rows as older_rows, reference_weights, score, the_benchmarks_copy)
 
 #: a configuration with the published names, tiny: one dense layer, two expert layers
 CFG = {
@@ -58,7 +55,7 @@ def packed_rows():
 
 @pytest.fixture(scope="module")
 def params():
-    p = lm.pattern_init_params(jax.random.PRNGKey(3), program_cfg())
+    p = init_params(jax.random.PRNGKey(3), program_cfg())
     for layer in p["layers"][1:]:  # a bias large enough to change who is chosen
         layer["router_bias"] = layer["router_bias"] * 4.0
     return p
@@ -66,11 +63,9 @@ def params():
 
 @pytest.fixture(scope="module")
 def scored(params):
-    batch, cfg = packed_rows(), program_cfg()
-    sample_at = jnp.asarray([[0, 5, 19, 25], [2, 8, 29, 40]], jnp.int32)
-    out = jax.jit(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
-        params, batch["tokens"], batch["segment_ids"], sample_at, jnp.int32(1))
-    return batch, sample_at, jax.tree.map(np.asarray, out)
+    batch = packed_rows()
+    out = score(params, batch["tokens"], batch["segment_ids"], SAMPLE_AT, program_cfg(), jnp.int32(1))
+    return batch, SAMPLE_AT, jax.tree.map(np.asarray, out)
 
 
 def test_the_parameters_are_the_models(params):
@@ -106,14 +101,22 @@ def test_a_packed_row_scores_each_document_as_the_reference_scores_it_alone(para
     assert out["probes"]["scan"] == {} and out["probes"]["router"]["u"].shape == (2, 2, 4, 32)
 
 
+#: ``mla_mixer`` as one program a configuration and shape (bare, it runs primitive by primitive)
+mixer = jax.jit(lm.mla_mixer, static_argnums=3)
+
+
+def reference_mixer(layer, x, **kw):
+    """The reference's mixer of one document's normed inputs, as one program."""
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(lambda p, x: ref.ref_mla(p, ref.ref_norm(x, p["attn_norm"], 1e-5), CFG, **kw))(
+            flat(layer), x)
+
+
 def test_the_mixer_against_the_reference(params):
     cfg, layer = program_cfg(), params["layers"][1]
     x = jnp.asarray(np.random.default_rng(5).standard_normal((1, L, 32)), jnp.float32)
-    got = lm.mla_mixer(layer, x, jnp.ones((1, L), jnp.int32), cfg)
-    with jax.default_matmul_precision("highest"):
-        p = flat(layer)
-        want = ref.ref_mla(p, ref.ref_norm(x[0], p["attn_norm"], 1e-5), CFG)
-    np.testing.assert_allclose(got[0], want, atol=2e-5)
+    got = mixer(layer, x, jnp.ones((1, L), jnp.int32), cfg)
+    np.testing.assert_allclose(got[0], reference_mixer(layer, x[0]), atol=2e-5)
 
 
 def test_positions_restart_at_every_document():
@@ -131,17 +134,14 @@ def test_a_document_in_a_row_is_the_document_alone_and_its_keys_count_from_its_s
     cfg, layer = program_cfg(), params["layers"][0]
     x = jnp.asarray(np.random.default_rng(6).standard_normal((1, L, 32)), jnp.float32)
     segs = jnp.asarray([[1] * 17 + [2] * 20 + [3] * 9 + [0] * 2], jnp.int32)
-    row = lm.mla_mixer(layer, x, segs, cfg)[0]
-    shifted = lm.mla_mixer(layer, x[:, 17:37], jnp.ones((1, 20), jnp.int32), cfg)[0]
+    row = mixer(layer, x, segs, cfg)[0]
+    shifted = mixer(layer, x[:, 17:37], jnp.ones((1, 20), jnp.int32), cfg)[0]
     np.testing.assert_allclose(row[17:37], shifted, atol=2e-5)
-    with jax.default_matmul_precision("highest"):
-        p = flat(layer)
-        u = ref.ref_norm(x[0, 17:37], p["attn_norm"], 1e-5)
-        alone, one_sided = ref.ref_mla(p, u, CFG), ref.ref_mla(p, u, CFG, key_start=17)
+    alone, one_sided = reference_mixer(layer, x[0, 17:37]), reference_mixer(layer, x[0, 17:37], key_start=17)
     np.testing.assert_allclose(row[17:37], alone, atol=2e-5)
     assert np.abs(one_sided - alone).max() > 1e-2
     # and a mask that let the second document see the first would show too
-    whole = lm.mla_mixer(layer, x, jnp.ones((1, L), jnp.int32), cfg)[0]
+    whole = mixer(layer, x, jnp.ones((1, L), jnp.int32), cfg)[0]
     assert np.abs(whole[17:37] - row[17:37]).max() > 1e-2
 
 
@@ -165,11 +165,13 @@ def test_the_dense_layer_against_the_reference(params):
                                 "ffn_pattern": ("dense",)})
     one = {**params, "layers": params["layers"][:1]}
     tokens = jnp.asarray(np.random.default_rng(8).integers(1, 64, (1, L + 1)), jnp.int32)
-    x, visits, dropped, probes = lm.pattern_hidden(one, tokens, jnp.ones_like(tokens), cfg)
+    x, visits, dropped, probes = jax.jit(lambda p, t: lm.pattern_hidden(p, t, jnp.ones_like(t), cfg))(
+        one, tokens)
     assert visits.shape == (0, 16) and dropped.shape == (0,) and "router" not in probes
     with jax.default_matmul_precision("highest"):
         x0 = jnp.asarray(params["embed"], jnp.float32)[tokens[0, :-1]]
-        want, u = ref.ref_layer_front("dense", flat(params["layers"][0]), x0, CFG)
+        want, u = jax.jit(lambda p, x: ref.ref_layer_front("dense", p, x, CFG))(
+            flat(params["layers"][0]), x0)
     assert u is None
     np.testing.assert_allclose(x[0], want, atol=3e-5)
 
@@ -217,12 +219,12 @@ def test_the_references_router_takes_its_controls():
 
 
 def test_bfloat16_stays_near_the_float32_program(params):
-    batch, sample_at, outs = packed_rows(), jnp.zeros((2, 1), jnp.int32), []
+    batch, outs = packed_rows(), []
     for dtype in (jnp.float32, jnp.bfloat16):
         cfg = program_cfg(dtype=dtype)
         p = jax.tree.map(lambda a, s: a.astype(s[1]), params, lm.pattern_param_shapes(cfg))
-        outs.append(np.asarray(lm.score(p, batch["tokens"], batch["segment_ids"], sample_at,
-                                        cfg)["logprob"]))
+        outs.append(np.asarray(score(p, batch["tokens"], batch["segment_ids"], SAMPLE_AT,
+                                     cfg)["logprob"]))
     assert 0 < np.abs(outs[0] - outs[1]).max() < 0.25
 
 
@@ -233,8 +235,6 @@ def test_the_kernel_for_wide_keys_is_blockwise_attention(blocks):
     elsewhere: the kernel, interpreted here (on a chip, outside pytest's
     conftest, this function runs it as it is), against the plain path on a
     packed row whose one rotary key head serves every query head."""
-    from jax.experimental.pallas import tpu as pltpu
-
     r = np.random.default_rng(1)
     q = jnp.asarray(r.standard_normal((1, 4, 512, 192)), jnp.float32)      # [B, H, L, D]
     k = jnp.asarray(r.standard_normal((1, 2, 512, 192)), jnp.float32)
@@ -242,17 +242,24 @@ def test_the_kernel_for_wide_keys_is_blockwise_attention(blocks):
     segs = np.zeros((1, 512), np.int32)
     segs[0, :100], segs[0, 100:130], segs[0, 130:400] = 1, 2, 3
     segs = jnp.asarray(segs)
-    want = jnp.swapaxes(blockwise_attention(
-        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segs, block=64), 1, 2)
+    want = plain_path(q, k, v, segs, None)
     assert want.shape == (1, 4, 512, 128)
     if jax.default_backend() == "tpu":
         got = lm._attend(q, k, v, segs, blocks[0])
     else:
-        with pltpu.force_tpu_interpret_mode():
-            got = flash_attention_widths(q, k, v, segs, 192 ** -0.5, *blocks)
+        got = interpreted_kernel(q, k, v, segs, 192 ** -0.5, *blocks)
     real = np.asarray(segs[0] != 0)
     np.testing.assert_allclose(np.asarray(got)[:, :, real], np.asarray(want)[:, :, real],
                                atol=3e-2 if jax.default_backend() == "tpu" else 2e-5)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def interpreted_kernel(q, k, v, segs, scale, block_q, block_k, keep=None):
+    """``flash_attention_widths`` interpreted, one program a shape."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    with pltpu.force_tpu_interpret_mode():
+        return flash_attention_widths(q, k, v, segs, scale, block_q, block_k, keep=keep)
 
 
 def kernel_inputs(lengths, l, seed=4, heads=2):
@@ -265,6 +272,7 @@ def kernel_inputs(lengths, l, seed=4, heads=2):
     return q, k, v, jnp.asarray(np.pad(ids, (0, l - len(ids)))[None].astype(np.int32))
 
 
+@functools.partial(jax.jit, static_argnames="scale")
 def plain_path(q, k, v, segs, scale, keep=None):
     return jnp.swapaxes(blockwise_attention(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segs, scale=scale,
@@ -305,8 +313,6 @@ def test_each_kind_of_pair_is_blockwise_attention(case):
     under the diagonal by segment ids alone, plain where they all agree; on
     the diagonal by positions too, over the keys its rows reach): each, interpreted, against
     the plain path, and ``pair_kinds`` against the mask written out."""
-    from jax.experimental.pallas import tpu as pltpu
-
     lengths, l, blocks, kinds = KINDS[case]
     q, k, v, segs = kernel_inputs(lengths, l)
     if kinds is not None:
@@ -315,8 +321,7 @@ def test_each_kind_of_pair_is_blockwise_attention(case):
         assert pair_kinds(segs, *blocks) == kinds_by_the_dense_mask(segs, *blocks)
     else:
         assert pair_kinds(segs, *blocks)[0] <= kinds_by_the_dense_mask(segs, *blocks)[0]
-    with pltpu.force_tpu_interpret_mode():
-        got = flash_attention_widths(q, k, v, segs, 0.09, *blocks)
+    got = interpreted_kernel(q, k, v, segs, 0.09, *blocks)
     real = np.asarray(segs[0] != 0)
     np.testing.assert_allclose(np.asarray(got)[:, :, real],
                                np.asarray(plain_path(q, k, v, segs, 0.09))[:, :, real], atol=2e-5)
@@ -346,8 +351,9 @@ def test_the_kernel_refuses_rows_that_are_not_whole_blocks():
 def test_the_shares_of_64_experts_held_8_by_8_add_up_to_the_uncut_layer():
     """Eight chips of 8 experts each under the biased router, the two shared
     experts counted once, against the reference told that it holds all 64."""
-    cfg = {**CFG, "n_routed_experts": 64, "n_routed_experts_held": 64, "num_experts_per_tok": 6}
-    p = lm.pattern_init_params(jax.random.PRNGKey(1), program_cfg(cfg))["layers"][1]
+    cfg = {**CFG, "n_routed_experts": 64, "n_routed_experts_held": 64, "num_experts_per_tok": 6,
+           "num_hidden_layers": 2}                              # up to the layer taken
+    p = init_params(jax.random.PRNGKey(1), program_cfg(cfg))["layers"][1]
     p["router_bias"] = p["router_bias"] * 4.0
     x = jnp.asarray(np.random.default_rng(1).standard_normal((96, 32)), jnp.float32)
     with jax.default_matmul_precision("highest"):
@@ -358,8 +364,8 @@ def test_the_shares_of_64_experts_held_8_by_8_add_up_to_the_uncut_layer():
     total, visits = -7 * shared, 0
     for first in range(0, 64, 8):
         share = {**p, **{k: p[k][first:first + 8] for k in ("w_gate", "w_up", "w_down")}}
-        y, n, dropped, _ = moe.held_experts_apply(share, x, held_offset=first, top_k=6,
-                                                  routed_scale=2.446, tile=8)
+        y, n, dropped, _ = held_experts(share, x, held_offset=first, top_k=6,
+                                        routed_scale=2.446, tile=8)
         total, visits = total + y, visits + int(n.sum())
         assert int(dropped) == 0
     assert visits == x.shape[0] * 6
@@ -391,9 +397,12 @@ OLDER_PROGRAMS = {
 }
 
 
-def older_program(name):
-    import functools
+def shapes_of_params(cfg):
+    """The parameters' shapes and dtypes, which is all that a trace reads of them."""
+    return jax.eval_shape(lambda: lm.pattern_init_params(jax.random.PRNGKey(3), cfg))
 
+
+def older_program(name):
     import optax
 
     from tpu_tfrecord.models import dlrm
@@ -404,14 +413,14 @@ def older_program(name):
 
         cfg = older.program_cfg()
         assert lm.ffn_kinds(cfg) == ("moe",) * 4 and not cfg.router_bias
-        params = lm.pattern_init_params(jax.random.PRNGKey(3), cfg)
+        params = shapes_of_params(cfg)
         assert all("router_bias" not in layer and "dense" not in layer for layer in params["layers"])
         batch, _ = older.packed_rows()
         return jax.make_jaxpr(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
             params, batch["tokens"], batch["segment_ids"], at, jnp.int32(2))
     if name == "kimi":
         cfg, batch = program_cfg(), packed_rows()
-        params = lm.pattern_init_params(jax.random.PRNGKey(3), cfg)
+        params = shapes_of_params(cfg)
         return jax.make_jaxpr(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
             params, batch["tokens"], batch["segment_ids"], at, jnp.int32(1))
     cfg = dlrm.DLRMConfig(num_dense=13, num_categorical=4, vocab_size=64, embed_dim=128,
@@ -434,27 +443,22 @@ def test_the_older_patterns_program_is_the_one_it_was(name):
 
 
 def test_the_benchmarks_copy_of_the_reference_is_this_one():
-    sys.path.insert(0, ROOT)
-    from benchmark.models import kimi_vl_lm as copy
+    assert the_benchmarks_copy(ref, "kimi_vl_lm", 8).HEAD_ROWS == ref.HEAD_ROWS
 
-    names = [n for n, f in inspect.getmembers(ref, inspect.isfunction)
-             if f.__module__ == ref.__name__]
-    assert "reference_score" in names and len(names) >= 8
-    for name in names:
-        assert inspect.getsource(getattr(ref, name)) == inspect.getsource(getattr(copy, name)), name
-    assert copy.HEAD_ROWS == ref.HEAD_ROWS
+
+def scopes_held(params, batch, cfg):
+    """The ``tfr.*`` scopes that the compiled score program's operations are named under."""
+    import re
+
+    compiled = score.lower(params, batch["tokens"], batch["segment_ids"], SAMPLE_AT, cfg).compile()
+    op_names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    return {tok for name in op_names for tok in re.findall(r"tfr\.\w+", name)}
 
 
 def test_the_compiled_program_holds_every_scope(params):
-    import re
-
     from tpu_tfrecord import tracing
 
-    batch, cfg = packed_rows(), program_cfg()
-    lowered = jax.jit(lambda p, t, s, a: lm.score(p, t, s, a, cfg)).lower(
-        params, batch["tokens"], batch["segment_ids"], jnp.zeros((2, 1), jnp.int32))
-    op_names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
-    held = {tok for name in op_names for tok in re.findall(r"tfr\.\w+", name)}
+    held = scopes_held(params, packed_rows(), program_cfg())
     assert held == {"tfr.embed", "tfr.mla_proj", "tfr.mla_attn", "tfr.dense_ffn", "tfr.moe_route",
                     "tfr.moe_experts", "tfr.moe_shared", "tfr.lm_head"}
     assert held <= set(tracing.ANNOTATIONS)

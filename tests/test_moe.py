@@ -94,7 +94,7 @@ class TestMoE:
             return (y**2).sum() + 0.01 * aux
 
         g = jax.jit(jax.grad(loss))(p_sh, x)
-        g_ref = jax.grad(loss)(params, x)
+        g_ref = jax.jit(jax.grad(loss))(params, x)  # the unsharded program
         for k in g:
             np.testing.assert_allclose(
                 np.asarray(g[k]), np.asarray(g_ref[k]), rtol=1e-4, atol=1e-5
@@ -103,7 +103,7 @@ class TestMoE:
     def test_bf16_compute(self):
         cfg = moe.MoEConfig(d_model=16, d_ff=32, n_experts=4, dtype=jnp.bfloat16)
         params, x = setup(cfg=cfg)
-        y, _ = moe.moe_apply(params, x, cfg)
+        y, _ = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg))(params, x)
         assert y.dtype == x.dtype  # output in the input dtype
         want = moe.moe_reference(params, x, cfg)
         np.testing.assert_allclose(np.asarray(y), want, rtol=5e-2, atol=5e-2)

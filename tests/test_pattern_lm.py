@@ -1,10 +1,12 @@
 """The pattern model (``models.lm.score``: softmax and gated delta-rule
 layers by a pattern, a share of the experts, packed rows) against the plain
 reference, at sizes a CPU walks in seconds: each layer kind and the period,
-a packed row against each of its documents alone, the chunked recurrence
-against the token-by-token one, the shares of the experts against the uncut
-layer, dropless routing under a skewed router, and the packer at L 8192."""
+a packed row against each of its documents alone, which form of the delta
+rule a layer takes (the rule itself is tests/test_delta_rule.py's), the shares
+of the experts against the uncut layer, dropless routing under a skewed
+router, and the packer at L 8192."""
 
+import functools
 import inspect
 import os
 import sys
@@ -18,7 +20,17 @@ from tpu_tfrecord.models import linear_attn, lm, moe, pattern_reference as ref
 from tpu_tfrecord.models.attention import attention_reference, blockwise_attention
 from tpu_tfrecord.tpu.ingest import TokenPacker
 
+from test_delta_rule import delta_rule_inputs, kernel_inputs
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: One program a shape for the process: cases that differ in their data find it
+#: built (bare, these run primitive by primitive, each a compile). A case that reads
+#: what a TRACE leaves behind (a gauge, a patched dispatch) wraps the function anew.
+init_params = jax.jit(lm.pattern_init_params, static_argnums=1)
+score = jax.jit(lm.score, static_argnums=4)
+held_experts = jax.jit(moe.held_experts_apply, static_argnames=(
+    "top_k", "routed_scale", "tile", "n_group", "topk_group"))  # ``held_offset`` is data: one program for every share
 
 #: a configuration with the published names, tiny
 CFG = {
@@ -91,17 +103,17 @@ def documents_of(batch):
 
 @pytest.fixture(scope="module")
 def params():
-    return lm.pattern_init_params(jax.random.PRNGKey(3), program_cfg())
+    return init_params(jax.random.PRNGKey(3), program_cfg())
+
+
+SAMPLE_AT = jnp.asarray([[0, 5, 19, 25], [2, 8, 29, 40]], jnp.int32)
 
 
 @pytest.fixture(scope="module")
 def scored(params):
     batch, _ = packed_rows()
-    cfg = program_cfg()
-    sample_at = jnp.asarray([[0, 5, 19, 25], [2, 8, 29, 40]], jnp.int32)
-    out = jax.jit(lambda p, t, s, a: lm.score(p, t, s, a, cfg))(
-        params, batch["tokens"], batch["segment_ids"], sample_at)
-    return batch, sample_at, jax.tree.map(np.asarray, out)
+    out = score(params, batch["tokens"], batch["segment_ids"], SAMPLE_AT, program_cfg())
+    return batch, SAMPLE_AT, jax.tree.map(np.asarray, out)
 
 
 def test_a_packed_row_scores_each_document_as_the_reference_scores_it_alone(params, scored):
@@ -133,6 +145,15 @@ def test_sampled_logits_are_the_references(params, scored):
     assert seen >= 6
 
 
+def reference_mixer(kind, layer, x):
+    """The reference's mixer of one document's normed inputs, as one program."""
+    def mixer(p, x):
+        u = ref.ref_norm(x, p["attn_norm"], CFG["rms_norm_eps"])
+        return ref.ref_gqa(p, u, CFG) if kind == "gqa" else ref.ref_kda(p, u, CFG)[0]
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(mixer)(flat(layer), x)
+
+
 @pytest.mark.parametrize("kind", ["gqa", "kda"])
 def test_a_layer_of_each_kind_against_the_reference(params, kind):
     cfg = program_cfg()
@@ -140,24 +161,20 @@ def test_a_layer_of_each_kind_against_the_reference(params, kind):
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.standard_normal((1, L, CFG["hidden_size"])), jnp.float32)
     segs = jnp.ones((1, L), jnp.int32)
-    got = lm.gqa_mixer(layer, x, segs, cfg) if kind == "gqa" else lm.kda_mixer(layer, x, segs, cfg)[0]
-    with jax.default_matmul_precision("highest"):
-        p = flat(layer)
-        u = ref.ref_norm(x[0], p["attn_norm"], CFG["rms_norm_eps"])
-        want = ref.ref_gqa(p, u, CFG) if kind == "gqa" else ref.ref_kda(p, u, CFG)[0]
-    np.testing.assert_allclose(got[0], want, atol=2e-5)
+    got = jax.jit(lambda p, x: lm.gqa_mixer(p, x, segs, cfg) if kind == "gqa"
+                  else lm.kda_mixer(p, x, segs, cfg)[0])(layer, x)
+    np.testing.assert_allclose(got[0], reference_mixer(kind, layer, x[0]), atol=2e-5)
 
 
 def test_bfloat16_stays_near_the_float32_program(params):
     batch, _ = packed_rows()
-    sample_at = jnp.zeros((2, 1), jnp.int32)
     outs = []
     for dtype in (jnp.float32, jnp.bfloat16):
         cfg = program_cfg(dtype=dtype)
         # each leaf in the dtype the program's own table gives it
         p = jax.tree.map(lambda a, s: a.astype(s[1]), params, lm.pattern_param_shapes(cfg))
-        outs.append(np.asarray(lm.score(p, batch["tokens"], batch["segment_ids"], sample_at,
-                                        cfg)["logprob"]))
+        outs.append(np.asarray(score(p, batch["tokens"], batch["segment_ids"], SAMPLE_AT,
+                                     cfg)["logprob"]))
     assert 0 < np.abs(outs[0] - outs[1]).max() < 0.25
 
 
@@ -186,11 +203,10 @@ def test_the_probes_are_what_the_layers_were_given_and_gave(params):
     sampled positions give its experts and gates again."""
     batch, _ = packed_rows()
     cfg = program_cfg()
-    sample_at = jnp.asarray([[0, 5, 19, 25], [2, 8, 29, 40]], jnp.int32)
-    out = jax.tree.map(np.asarray, lm.score(params, batch["tokens"], batch["segment_ids"],
-                                            sample_at, cfg, jnp.int32(2)))
-    assert "scan" not in lm.score(params, batch["tokens"], batch["segment_ids"], sample_at,
-                                  cfg)["probes"]
+    out = jax.tree.map(np.asarray, score(params, batch["tokens"], batch["segment_ids"],
+                                         SAMPLE_AT, cfg, jnp.int32(2)))
+    assert "scan" not in score(params, batch["tokens"], batch["segment_ids"], SAMPLE_AT,
+                               cfg)["probes"]
     scan, routed = out["probes"]["scan"], out["probes"]["router"]
     scale, last, worst = cfg.kda_head_dim ** -0.5, None, 0.0
     with jax.default_matmul_precision("highest"):
@@ -216,91 +232,7 @@ def test_the_probes_are_what_the_layers_were_given_and_gave(params):
             np.testing.assert_allclose(gates, routed["gates"][i].reshape(-1, 4), atol=1e-6)
 
 
-def test_taps_stop_at_a_boundary():
-    rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.standard_normal((1, 2, 20, 3)), jnp.float32)       # [B, H, L, D]
-    taps = jnp.asarray(rng.standard_normal((4, 2, 3)), jnp.float32)
-    segs = jnp.asarray([[1] * 7 + [2] * 9 + [0] * 4], jnp.int32)
-    flat_x = jnp.moveaxis(x[0], 1, 0).reshape(20, 6)                       # [L, H * D]
-    rows = lambda y: jnp.moveaxis(y[0], 1, 0).reshape(20, 6)  # noqa: E731
-    got = rows(linear_attn.short_conv(x, taps, segs))
-    np.testing.assert_allclose(got[:7], ref.ref_conv(flat_x[:7], taps.reshape(4, 6)), atol=1e-6)
-    np.testing.assert_allclose(got[7:16], ref.ref_conv(flat_x[7:16], taps.reshape(4, 6)), atol=1e-6)
-    # and without the boundary the first tokens of the second document differ
-    whole = rows(linear_attn.short_conv(x, taps, jnp.ones((1, 20), jnp.int32)))
-    assert np.abs(whole[7:10] - got[7:10]).max() > 1e-2
-
-
-def delta_rule_inputs(seed, length, near_parallel_keys=False, b=2, h=3, d=16):
-    r = np.random.default_rng(seed)
-    q, k, v = (r.standard_normal((b, h, length, d)) for _ in range(3))
-    if near_parallel_keys:
-        k = k * 0.1 + r.standard_normal((b, h, 1, d))
-    q /= np.linalg.norm(q, axis=-1, keepdims=True)
-    k /= np.linalg.norm(k, axis=-1, keepdims=True)
-    log_decay = -np.exp(r.uniform(-6, 0.3, (b, h, length, d)))
-    beta = 2 / (1 + np.exp(-2 * r.standard_normal((b, h, length))))
-    segs = np.zeros((b, length), np.int32)
-    for row in range(b):
-        cuts = np.sort(r.choice(np.arange(1, length - 8), 4, replace=False))
-        for s, (a, z) in enumerate(zip([0, *cuts], [*cuts, length - 5])):
-            segs[row, a:z] = s + 1
-    return [jnp.asarray(a, jnp.float32) for a in (q, k, v, log_decay, beta)] + [jnp.asarray(segs)]
-
-
-def interpreted_kernel(q, k, v, log_decay, beta, segs, scale, tile=128):
-    """The Pallas kernel a TPU runs for chunks of 64 at width 128, interpreted."""
-    return linear_attn._delta_rule_fused(q, k, v, log_decay, beta, segs, scale, tile, interpret=True)
-
-
-def kernel_inputs(seed, length, **kw):
-    """:func:`delta_rule_inputs` at the width the kernel takes, two heads of one row."""
-    return delta_rule_inputs(seed, length, b=1, h=2, d=128, **kw)
-
-
-@pytest.mark.parametrize("chunk", [1, 4, 16, 64, 128])
-@pytest.mark.parametrize("length", [150, 64, 37])
-def test_the_chunked_recurrence_is_the_token_by_token_one(chunk, length):
-    args = delta_rule_inputs(chunk + length, length)
-    want = linear_attn.delta_rule_recurrent(*args, scale=0.25)
-    got = linear_attn.delta_rule_chunked(*args, scale=0.25, chunk=chunk)
-    assert got.shape == want.shape
-    np.testing.assert_allclose(got, want, atol=5e-6)
-
-
-@pytest.mark.parametrize("length,tile,heads", [(128, 128, 2), (256, 128, 2), (256, 256, 2), (512, 256, 2),
-                                               (768, 256, 3)])
-def test_the_kernel_is_the_token_by_token_recurrence_and_the_plain_form(length, tile, heads):
-    """Four boundaries a row at random places and a pad tail of segment 0:
-    documents start and end inside a chunk, inside a pair of chunks and
-    inside a grid step, and the state crosses from one grid step to the next.
-    An even number of heads goes two to a grid step, an odd number one."""
-    args = delta_rule_inputs(length + tile, length, b=1, h=heads, d=128)
-    segs = np.asarray(args[-1])
-    assert (segs[:, -1] == 0).all() and (np.diff(segs) != 0).sum() >= 4
-    want = linear_attn.delta_rule_recurrent(*args, scale=0.25)
-    plain = linear_attn.delta_rule_chunked(*args, scale=0.25, chunk=64)
-    got = interpreted_kernel(*args, 0.25, tile)
-    assert got.shape == want.shape and got.dtype == jnp.float32
-    np.testing.assert_allclose(got, want, atol=5e-6)
-    np.testing.assert_allclose(got, plain, atol=5e-6)
-
-
-def test_a_boundary_at_every_edge_the_kernel_has():
-    """Documents that end exactly where a 16-token block, a chunk, a pair of
-    chunks and a grid step end, one token long, and one across three steps."""
-    q, k, v, log_decay, beta, _ = kernel_inputs(8, 768)
-    segs = np.zeros((1, 768), np.int32)
-    for s, (a, z) in enumerate([(0, 16), (16, 64), (64, 128), (128, 129), (129, 256), (256, 700)]):
-        segs[0, a:z] = s + 1
-    segs = jnp.asarray(segs)
-    want = linear_attn.delta_rule_recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
-    for tile in (128, 256):
-        got = interpreted_kernel(q, k, v, log_decay, beta, segs, 0.25, tile)
-        np.testing.assert_allclose(got, want, atol=5e-6)
-
-
-def test_off_a_tpu_and_at_other_shapes_the_plain_form_runs(monkeypatch):
+def test_off_a_tpu_and_at_other_shapes_the_plain_form_runs(monkeypatch, params):
     """The dispatch reads the backend and the shape, nothing else: here (the
     CPU) every shape takes the plain form and ``kda.fused_layers`` reads 0;
     told that the backend is a TPU it takes whole pairs of chunks of 64 at
@@ -317,8 +249,8 @@ def test_off_a_tpu_and_at_other_shapes_the_plain_form_runs(monkeypatch):
         linear_attn.delta_rule_chunked(*args, scale=0.25, chunk=64)
     batch, _ = packed_rows()
     cfg = program_cfg()
-    lm.score(lm.pattern_init_params(jax.random.PRNGKey(3), cfg), batch["tokens"],
-             batch["segment_ids"], jnp.zeros((2, 1), jnp.int32), cfg)
+    # traced anew under the patch: the gauge is set as a program is traced
+    jax.jit(lambda *a: lm.score(*a, cfg))(params, batch["tokens"], batch["segment_ids"], SAMPLE_AT)
     assert not called and METRICS.gauge_value("kda.fused_layers") == 0
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert linear_attn.fused_tile(cell, 64) == 256
@@ -336,94 +268,62 @@ def test_off_a_tpu_and_at_other_shapes_the_plain_form_runs(monkeypatch):
 def test_a_layer_that_takes_the_kernel_is_the_layer_and_is_counted(monkeypatch):
     """``kda_mixer`` at the kernel's width with the dispatch answering as it
     would on a TPU and Pallas interpreting: the layer the plain form gives,
-    and ``score`` counts the pattern's three delta-rule layers as fused."""
+    and ``score`` counts the pattern's delta-rule layers as fused (two here,
+    three until PR 38: a count of two is no more a flag's 1 than three is,
+    and the interpreted kernel is compiled a layer)."""
     from jax.experimental.pallas import tpu as pltpu
 
     from tpu_tfrecord.metrics import METRICS
 
-    wide = {**CFG, "linear_attn_config": {"num_heads": 2, "head_dim": 128, "short_conv_kernel_size": 4}}
+    wide = {**CFG, "num_hidden_layers": 3,
+            "linear_attn_config": {"num_heads": 2, "head_dim": 128, "short_conv_kernel_size": 4}}
     cfg = lm.PatternLMConfig(**{**program_cfg(wide, kda_chunk=64, attn_block=32).__dict__,
                                 "max_len": 128})
-    params = lm.pattern_init_params(jax.random.PRNGKey(5), cfg)
+    params = init_params(jax.random.PRNGKey(5), cfg)
     rng = np.random.default_rng(6)
     tokens = jnp.asarray(rng.integers(1, 64, (1, 129)), jnp.int32)
     segs = jnp.asarray([[1] * 50 + [2] * 70 + [0] * 9], jnp.int32)
     at = jnp.zeros((1, 1), jnp.int32)
-    plain = lm.score(params, tokens, segs, at, cfg)["logprob"]
+
+    def traced_anew():  # the dispatch and the gauge are read as a program is traced
+        return jax.jit(lambda *a: lm.score(*a, cfg))(params, tokens, segs, at)["logprob"]
+
+    plain = traced_anew()
     assert METRICS.gauge_value("kda.fused_layers") == 0
     monkeypatch.setattr(linear_attn, "fused_tile",
                         lambda shape, chunk: 128 if shape[-1] == 128 and chunk == 64 else None)
     with pltpu.force_tpu_interpret_mode():
-        fused = lm.score(params, tokens, segs, at, cfg)["logprob"]
-    assert METRICS.gauge_value("kda.fused_layers") == 3
+        fused = traced_anew()
+    assert METRICS.gauge_value("kda.fused_layers") == 2
     np.testing.assert_allclose(fused, plain, atol=2e-5)
     assert np.abs(np.asarray(plain)).max() > 1
 
 
-@pytest.mark.parametrize("form", ["plain", "kernel"])
-@pytest.mark.parametrize("one_key", [False, True])
-def test_near_parallel_keys_do_not_blow_the_chunk_up(one_key, form):
-    """beta near 2 on keys that all but repeat, or do repeat (a document that
-    says one token over and over): the triangle's inverse by forward
-    substitution (the plain form) or by doubling from single rows (the
-    kernel) stays exact where a product of a block's powers cancelled terms
-    of 1e6 against each other (it was held to 1e-3 here)."""
-    q, k, v, log_decay, beta, segs = (
-        delta_rule_inputs(1, 150, near_parallel_keys=True) if form == "plain"
-        else kernel_inputs(1, 256, near_parallel_keys=True))
-    if one_key:
-        k, beta = jnp.broadcast_to(k[:, :, :1], k.shape), jnp.full_like(beta, 1.98)
-    want = linear_attn.delta_rule_recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
-    if form == "plain":
-        got = linear_attn.delta_rule_chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=64)
-    else:
-        got = interpreted_kernel(q, k, v, log_decay, beta, segs, 0.25)
-    np.testing.assert_allclose(got, want, atol=1e-5)
-
-
-@pytest.mark.parametrize("rate", [3.0, 5.0, 9.0])
-@pytest.mark.parametrize("chunk", [16, 64, 128, "kernel"])
-def test_fast_decays_do_not_overflow_the_chunk(rate, chunk):
-    """Channels that forget at ``rate`` a token, real tokens and the pads of
-    a row's tail alike: exp(+-sum of log-decay) around one reference point
-    for a whole chunk of 64 overflowed float32 at 2.5 a token, and a NaN
-    behind a zero of the triangle's inverse reached the document before.
-    ``kernel``: the interpreted kernel (chunks of 64) at its width."""
-    q, k, v, log_decay, beta, segs = delta_rule_inputs(3, 150) if chunk != "kernel" else kernel_inputs(3, 256)
-    fast = np.random.default_rng(4).random(log_decay.shape) < 0.3
-    log_decay = jnp.where(fast, -rate, log_decay)
-    segs = segs.at[:, 120:].set(0)
-    want = linear_attn.delta_rule_recurrent(q, k, v, log_decay, beta, segs, scale=0.25)
-    if chunk == "kernel":
-        got = interpreted_kernel(q, k, v, log_decay, beta, segs, 0.25)
-    else:
-        got = linear_attn.delta_rule_chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=chunk)
-    assert np.isfinite(np.asarray(got)).all()
-    np.testing.assert_allclose(got, want, atol=1e-5)
-
-
 def test_the_recurrent_oracle_is_the_references_scan(params):
     """models.linear_attn's oracle and the plain reference walk one recurrence."""
-    layer, cfg = flat(params["layers"][1]), program_cfg()
+    cfg = lm.PatternLMConfig(**{**program_cfg().__dict__, "kda_chunk": 1})
     rng = np.random.default_rng(7)
     x = jnp.asarray(rng.standard_normal((1, 24, CFG["hidden_size"])), jnp.float32)
-    got = lm.kda_mixer(params["layers"][1], x, jnp.ones((1, 24), jnp.int32),
-                       lm.PatternLMConfig(**{**cfg.__dict__, "kda_chunk": 1}))[0]
-    with jax.default_matmul_precision("highest"):
-        u = ref.ref_norm(x[0], layer["attn_norm"], CFG["rms_norm_eps"])
-        want = ref.ref_kda(layer, u, CFG)[0]
-    np.testing.assert_allclose(got[0], want, atol=2e-5)
+    got = jax.jit(lambda p, x: lm.kda_mixer(p, x, jnp.ones((1, 24), jnp.int32), cfg)[0])(
+        params["layers"][1], x)
+    np.testing.assert_allclose(got[0], reference_mixer("kda", params["layers"][1], x[0]), atol=2e-5)
+
+
+dense_attention = jax.jit(lambda q, k, v, segs: attention_reference(q, k, v, causal=True, segments=segs))
 
 
 @pytest.mark.parametrize("block", [64, 32, 48, 16, 256])
 def test_blockwise_attention_is_the_dense_oracle(block):
+    """Rows of 100 tokens (200 until PR 38): still one block and seven, blocks
+    that divide the row and that do not, boundaries inside a block and a pad
+    tail; the program of 16-token blocks unrolls 28 pairs where it unrolled 91."""
     r = np.random.default_rng(0)
-    q = jnp.asarray(r.standard_normal((2, 200, 8, 16)), jnp.float32)
-    k, v = (jnp.asarray(r.standard_normal((2, 200, 2, 16)), jnp.float32) for _ in range(2))
-    segs = np.zeros((2, 200), np.int32)
-    segs[0, :90], segs[0, 90:130], segs[0, 130:195], segs[1] = 1, 2, 3, 1
-    want = attention_reference(q, k, v, causal=True, segments=jnp.asarray(segs))
-    got = blockwise_attention(q, k, v, jnp.asarray(segs), block=block)
+    q = jnp.asarray(r.standard_normal((2, 100, 8, 16)), jnp.float32)
+    k, v = (jnp.asarray(r.standard_normal((2, 100, 2, 16)), jnp.float32) for _ in range(2))
+    segs = np.zeros((2, 100), np.int32)
+    segs[0, :45], segs[0, 45:65], segs[0, 65:97], segs[1] = 1, 2, 3, 1
+    want = dense_attention(q, k, v, jnp.asarray(segs))
+    got = jax.jit(functools.partial(blockwise_attention, block=block))(q, k, v, jnp.asarray(segs))
     np.testing.assert_allclose(got, want, atol=2e-6)
 
 
@@ -441,13 +341,14 @@ def test_the_kernel_a_tpu_runs_is_blockwise_attention(block):
     segs = np.zeros((1, 512), np.int32)
     segs[0, :100], segs[0, 100:130], segs[0, 130:400] = 1, 2, 3
     segs = jnp.asarray(segs)
-    want = jnp.swapaxes(blockwise_attention(
-        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segs, block=64), 1, 2)
+    want = jax.jit(lambda q, k, v, segs: jnp.swapaxes(blockwise_attention(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segs, block=64), 1, 2))(
+            q, k, v, segs)
     if jax.default_backend() == "tpu":
         got = lm._attend(q, k, v, segs, block)
     else:
         with pltpu.force_tpu_interpret_mode():
-            got = lm._flash_attend(q, k, v, segs, block)
+            got = jax.jit(functools.partial(lm._flash_attend, block=block))(q, k, v, segs)
     real = np.asarray(segs[0] != 0)
     # on a chip both paths multiply float32 at the default precision (one bfloat16
     # pass): they agree to that rounding; a mask gone wrong moves the answer by 0.3 and more
@@ -456,8 +357,9 @@ def test_the_kernel_a_tpu_runs_is_blockwise_attention(block):
 
 
 def moe_layer(seed=0, t=96, skew=0.0):
-    cfg = {**CFG, "n_routed_experts_held": CFG["n_routed_experts"], "held_offset": 0}
-    p = lm.pattern_init_params(jax.random.PRNGKey(seed), program_cfg(cfg))["layers"][0]
+    cfg = {**CFG, "n_routed_experts_held": CFG["n_routed_experts"], "held_offset": 0,
+           "num_hidden_layers": 1}                              # the one layer taken
+    p = init_params(jax.random.PRNGKey(seed), program_cfg(cfg))["layers"][0]
     p["router"] = p["router"].at[:, 5].add(skew)
     x = np.random.default_rng(seed).standard_normal((t, CFG["hidden_size"]))
     # under a skew every token's score for expert 5 saturates: positive rows
@@ -474,7 +376,7 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
     total, visits = -3 * shared, 0
     for first in range(0, 16, 4):
         share = {**p, **{k: p[k][first:first + 4] for k in ("w_gate", "w_up", "w_down")}}
-        y, n, dropped, _ = moe.held_experts_apply(share, x, held_offset=first, top_k=4, tile=8)
+        y, n, dropped, _ = held_experts(share, x, held_offset=first, top_k=4, tile=8)
         total, visits = total + y, visits + int(n.sum())
         assert int(dropped) == 0
     assert visits == x.shape[0] * 4
@@ -486,8 +388,7 @@ def test_a_skewed_router_costs_time_and_never_a_visit(tile):
     """Every token picks expert 5: its run is many tiles long and none is lost."""
     cfg, p, x = moe_layer(seed=1, skew=4.0)
     share = {**p, **{k: p[k][4:8] for k in ("w_gate", "w_up", "w_down")}}
-    y, n, dropped, _ = jax.jit(lambda p, x: moe.held_experts_apply(
-        p, x, held_offset=4, top_k=4, tile=tile))(share, x)
+    y, n, dropped, _ = held_experts(share, x, held_offset=4, top_k=4, tile=tile)
     assert int(n[1]) == x.shape[0] and int(dropped) == 0
     with jax.default_matmul_precision("highest"):
         want, _, _ = ref.ref_moe(flat(share), x, {**cfg, "n_routed_experts_held": 4, "held_offset": 4})
@@ -541,12 +442,19 @@ def test_a_restored_packer_keeps_its_running_fill():
     assert b.pop() is None
 
 
-def test_the_benchmarks_copy_of_the_reference_is_this_one():
-    sys.path.insert(0, ROOT)
-    from benchmark.models import solar_open2 as copy
+def the_benchmarks_copy(ref, of: str, least: int):
+    """``benchmark.models.<of>``, holding each of ``ref``'s functions line for line."""
+    import importlib
 
+    sys.path.insert(0, ROOT)
+    copy = importlib.import_module(f"benchmark.models.{of}")
     names = [n for n, f in inspect.getmembers(ref, inspect.isfunction)
              if f.__module__ == ref.__name__]
-    assert "reference_score" in names and len(names) >= 10
+    assert "reference_score" in names and len(names) >= least
     for name in names:
         assert inspect.getsource(getattr(ref, name)) == inspect.getsource(getattr(copy, name)), name
+    return copy
+
+
+def test_the_benchmarks_copy_of_the_reference_is_this_one():
+    the_benchmarks_copy(ref, "solar_open2", 10)

@@ -53,6 +53,21 @@ def interleaved_bubble(n_stages, n_virtual, m):
     return 1.0 - m * n_virtual / (u_last + n_stages)
 
 
+def sequential(stage_fn, params, xs, n_virtual=1):
+    """The sequential oracle as one program (bare, ``vmap`` runs it primitive
+    by primitive, each primitive a compile)."""
+    return jax.jit(
+        lambda p, xs: pipeline.pipeline_reference(stage_fn, p, xs, n_virtual)
+    )(params, xs)
+
+
+def pipelined(stage_fn, params, xs, mesh, **kw):
+    """``pipeline_apply`` as one program, as its callers run it."""
+    return jax.jit(
+        lambda p, xs: pipeline.pipeline_apply(stage_fn, p, xs, mesh, **kw)
+    )(params, xs)
+
+
 class TestPipeline:
     def test_matches_sequential_oracle(self):
         mesh = create_mesh({"pipe": 4}, jax.devices()[:4])
@@ -60,10 +75,8 @@ class TestPipeline:
         xs = jnp.asarray(
             np.random.default_rng(1).normal(size=(6, 2, 8)), jnp.float32
         )
-        want = pipeline.pipeline_reference(stage_fn, params, xs)
-        got = jax.jit(
-            lambda p, xs: pipeline.pipeline_apply(stage_fn, p, xs, mesh)
-        )(params, xs)
+        want = sequential(stage_fn, params, xs)
+        got = pipelined(stage_fn, params, xs, mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
 
     def test_eight_stages_single_microbatch_edge(self):
@@ -74,8 +87,8 @@ class TestPipeline:
             xs = jnp.asarray(
                 np.random.default_rng(m).normal(size=(m, 3, 8)), jnp.float32
             )
-            want = pipeline.pipeline_reference(stage_fn, params, xs)
-            got = pipeline.pipeline_apply(stage_fn, params, xs, mesh)
+            want = sequential(stage_fn, params, xs)
+            got = pipelined(stage_fn, params, xs, mesh)
             np.testing.assert_allclose(
                 np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6
             )
@@ -94,7 +107,7 @@ class TestPipeline:
             return (pipeline.pipeline_reference(stage_fn, p, xs) ** 2).sum()
 
         g = jax.jit(jax.grad(loss_p))(params, xs)
-        g_ref = jax.grad(loss_r)(params, xs)
+        g_ref = jax.jit(jax.grad(loss_r))(params, xs)
         for k in g:
             np.testing.assert_allclose(
                 np.asarray(g[k]), np.asarray(g_ref[k]), rtol=1e-4, atol=1e-5
@@ -182,10 +195,8 @@ class TestScaleShape:
         xs = jnp.asarray(
             np.random.default_rng(7).normal(size=(7, 2, 8)), jnp.float32
         )
-        got = jax.jit(
-            lambda p, xs: pipeline.pipeline_apply(stage_fn, p, xs, mesh)
-        )(params, xs)
-        want = pipeline.pipeline_reference(stage_fn, params, xs)
+        got = pipelined(stage_fn, params, xs, mesh)
+        want = sequential(stage_fn, params, xs)
         assert got.shape == (7, 2, 8)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6
@@ -202,7 +213,7 @@ class TestDpPpComposition:
         xs = jnp.asarray(
             np.random.default_rng(3).normal(size=(8, 4, 8)), jnp.float32
         )
-        want = pipeline.pipeline_reference(stage_fn, params, xs)
+        want = sequential(stage_fn, params, xs)
         p_sh = jax.device_put(params, NamedSharding(mesh, P("pipe")))
         xs_sh = jax.device_put(
             xs,
@@ -210,11 +221,7 @@ class TestDpPpComposition:
                 mesh, ndim=xs.ndim, batch_spec=P("data")
             ),
         )
-        got = jax.jit(
-            lambda p, xs: pipeline.pipeline_apply(
-                stage_fn, p, xs, mesh, batch_spec=P("data")
-            )
-        )(p_sh, xs_sh)
+        got = pipelined(stage_fn, p_sh, xs_sh, mesh, batch_spec=P("data"))
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6
         )
@@ -238,7 +245,7 @@ class TestDpPpComposition:
             return (pipeline.pipeline_reference(stage_fn, p, xs) ** 2).sum()
 
         g = jax.jit(jax.grad(loss_p))(params, xs)
-        g_ref = jax.grad(loss_r)(params, xs)
+        g_ref = jax.jit(jax.grad(loss_r))(params, xs)
         for k in g:
             np.testing.assert_allclose(
                 np.asarray(g[k]), np.asarray(g_ref[k]), rtol=1e-4, atol=1e-5
@@ -275,20 +282,14 @@ class TestInterleaved:
         xs = jnp.asarray(
             np.random.default_rng(m).normal(size=(m, 2, 8)), jnp.float32
         )
-        want = pipeline.pipeline_reference(
-            stage_fn, params, xs, n_virtual=n_virtual
-        )
+        want = sequential(stage_fn, params, xs, n_virtual)
         if m % n_stages == 0:
             p_sh, xs_sh = sharded_args(mesh, params, xs)
         else:
             # a ragged stream arrives unsharded; pipeline_apply pads it
             # into the block layout internally
             p_sh, xs_sh = params, xs
-        got = jax.jit(
-            lambda p, x: pipeline.pipeline_apply(
-                stage_fn, p, x, mesh, n_virtual=n_virtual
-            )
-        )(p_sh, xs_sh)
+        got = pipelined(stage_fn, p_sh, xs_sh, mesh, n_virtual=n_virtual)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6
         )
@@ -317,7 +318,7 @@ class TestInterleaved:
             ).sum()
 
         g = jax.jit(jax.grad(loss_p))(params, xs)
-        g_ref = jax.grad(loss_r)(params, xs)
+        g_ref = jax.jit(jax.grad(loss_r))(params, xs)
         for k in g:
             np.testing.assert_allclose(
                 np.asarray(g[k]), np.asarray(g_ref[k]), rtol=1e-4, atol=1e-5
@@ -336,16 +337,12 @@ class TestInterleaved:
             xs = jnp.asarray(
                 np.random.default_rng(0).normal(size=(m, 2, 8)), jnp.float32
             )
-            out, diag = pipeline.pipeline_apply(
+            out, diag = pipelined(
                 stage_fn, params, xs, mesh, n_virtual=v, diagnostics=True
             )
             np.testing.assert_allclose(
                 np.asarray(out),
-                np.asarray(
-                    pipeline.pipeline_reference(
-                        stage_fn, params, xs, n_virtual=v
-                    )
-                ),
+                np.asarray(sequential(stage_fn, params, xs, v)),
                 rtol=1e-5, atol=1e-6,
             )
             measured[v] = float(diag["bubble_fraction"])
@@ -366,7 +363,7 @@ class TestInterleaved:
         xs = jnp.asarray(
             np.random.default_rng(3).normal(size=(m, 2, 8)), jnp.float32
         )
-        _, diag = pipeline.pipeline_apply(
+        _, diag = pipelined(
             stage_fn, params, xs, mesh, n_virtual=v, diagnostics=True
         )
         assert float(diag["bubble_fraction"]) == pytest.approx(
@@ -446,7 +443,8 @@ class TestEpUnderV:
         )
         s, v = 2, 2
         keys = jax.random.split(jax.random.key(0), s * v)
-        layers = [moe.init_params(k, cfg) for k in keys]
+        init = jax.jit(moe.init_params, static_argnums=1)
+        layers = [init(k, cfg) for k in keys]
         # chunk order k = v·S + s -> stacked[s][v]
         stacked = jax.tree.map(
             lambda *xs: jnp.stack(xs)
@@ -477,16 +475,18 @@ class TestEpUnderV:
             "w_in": P("pipe", None, "expert", None, None),
             "w_out": P("pipe", None, "expert", None, None),
         }
-        got = pipeline.pipeline_apply(
+        got = pipelined(
             stage_fn, stacked, xs, mesh, batch_spec=P(None, "expert"),
             n_virtual=2, param_spec=param_spec,
         )
         mesh_e = create_mesh({"expert": 4}, jax.devices()[:4])
-        want = xs
+        layer = jax.jit(  # one program serves the four layers
+            lambda p, flat: flat + moe.moe_apply_ep(p, flat, cfg, mesh_e)[0]
+        )
+        want = xs.reshape(m * mb, t, 16)
         for k in range(4):
-            flat = want.reshape(m * mb, t, 16)
-            y, _ = moe.moe_apply_ep(layers[k], flat, cfg, mesh_e)
-            want = (flat + y).reshape(m, mb, t, 16)
+            want = layer(layers[k], want)
+        want = want.reshape(m, mb, t, 16)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5
         )

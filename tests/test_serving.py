@@ -522,7 +522,8 @@ class TestServeServer:
             results: dict = {}
 
             def client(i):
-                c = ServeClient([srv.addr])
+                # the victim learns of the drop when its read times out (30 s by default)
+                c = ServeClient([srv.addr], timeout_s=5.0)
                 try:
                     results[i] = c.generate(ws[i], n_new=2)
                 finally:
@@ -576,7 +577,8 @@ class TestServeServer:
         """Scale-down's goodbye: drain stops admission, finishes what was
         admitted, and flips the drained latch."""
         srv, _ = server
-        c = ServeClient([srv.addr])
+        # a request that meets the drained server as it closes ends by the read's timeout
+        c = ServeClient([srv.addr], timeout_s=5.0)
         try:
             w = _windows(1, seed=9)[0]
             got = c.generate(w, n_new=2)

@@ -9,6 +9,7 @@ integration tests run the real ``examples/train_lm.py`` trainer and the
 doctor CLI as subprocesses.
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -36,6 +37,7 @@ sys.path.insert(0, os.path.join(REPO, "examples"))
 import _harness  # noqa: E402
 
 from hlo_util import compiled_memory_bytes  # noqa: E402
+from test_pipeline_parallel import pipelined, sequential  # noqa: E402 - each one program
 from tools.graftlint import hlo_contracts  # noqa: E402
 
 
@@ -229,6 +231,22 @@ def _moe_setup(top_k, capacity_factor=1.0, seed=0):
     return cfg, params, x
 
 
+def _same_counts(diag, rdiag):
+    """The in-jit counters against the routing oracle's."""
+    np.testing.assert_allclose(
+        np.asarray(diag["expert_tokens"]), rdiag["expert_tokens"]
+    )
+    np.testing.assert_allclose(
+        np.asarray(diag["expert_kept"]), rdiag["expert_kept"]
+    )
+    assert float(diag["dropped_fraction"]) == pytest.approx(
+        rdiag["dropped_fraction"], abs=1e-6
+    )
+    assert float(diag["gate_entropy"]) == pytest.approx(
+        rdiag["gate_entropy"], abs=1e-4
+    )
+
+
 class TestMoEDiagnostics:
     @pytest.mark.parametrize("top_k", [1, 2])
     def test_dense_counts_pin_against_oracle(self, top_k):
@@ -237,23 +255,12 @@ class TestMoEDiagnostics:
             lambda p, x: moe.moe_apply(p, x, cfg, diagnostics=True)
         )(params, x)
         ref, rdiag = moe.moe_reference(params, x, cfg, return_diag=True)
-        np.testing.assert_allclose(
-            np.asarray(diag["expert_tokens"]), rdiag["expert_tokens"]
-        )
-        np.testing.assert_allclose(
-            np.asarray(diag["expert_kept"]), rdiag["expert_kept"]
-        )
-        assert float(diag["dropped_fraction"]) == pytest.approx(
-            rdiag["dropped_fraction"], abs=1e-6
-        )
-        assert float(diag["gate_entropy"]) == pytest.approx(
-            rdiag["gate_entropy"], abs=1e-4
-        )
+        _same_counts(diag, rdiag)
         # routed assignments always sum to tokens * top_k
         assert float(diag["expert_tokens"].sum()) == 16 * top_k
         # the output itself is unchanged by the flag (different compiled
         # program -> float-association noise only)
-        y2, aux2 = moe.moe_apply(params, x, cfg)
+        y2, aux2 = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg))(params, x)
         np.testing.assert_allclose(
             np.asarray(y), np.asarray(y2), atol=1e-6
         )
@@ -270,26 +277,15 @@ class TestMoEDiagnostics:
         )
         np.testing.assert_allclose(np.asarray(y), ref, atol=1e-5)
         # psum'd GLOBAL counts == the oracle's cross-block tallies
-        np.testing.assert_allclose(
-            np.asarray(diag["expert_tokens"]), rdiag["expert_tokens"]
-        )
-        np.testing.assert_allclose(
-            np.asarray(diag["expert_kept"]), rdiag["expert_kept"]
-        )
-        assert float(diag["dropped_fraction"]) == pytest.approx(
-            rdiag["dropped_fraction"], abs=1e-6
-        )
-        assert float(diag["gate_entropy"]) == pytest.approx(
-            rdiag["gate_entropy"], abs=1e-4
-        )
+        _same_counts(diag, rdiag)
         assert float(diag["expert_tokens"].sum()) == 16 * top_k
 
     def test_valid_mask_excludes_padding_from_counts(self):
         cfg, params, x = _moe_setup(2)
         valid = jnp.asarray([True] * 10 + [False] * 6)
-        y, aux, diag = moe.moe_apply(
-            params, x, cfg, valid=valid, diagnostics=True
-        )
+        y, aux, diag = jax.jit(lambda p, x, valid: moe.moe_apply(
+            p, x, cfg, valid=valid, diagnostics=True
+        ))(params, x, valid)
         ref, rdiag = moe.moe_reference(
             params, x, cfg, valid=np.asarray(valid), return_diag=True
         )
@@ -310,7 +306,9 @@ class TestMoEDiagnostics:
         x = jnp.asarray(
             np.random.default_rng(1).normal(size=(32, 8)), jnp.float32
         )
-        _, _, diag = moe.moe_apply(params, x, cfg, diagnostics=True)
+        _, _, diag = jax.jit(
+            lambda p, x: moe.moe_apply(p, x, cfg, diagnostics=True)
+        )(params, x)
         _, rdiag = moe.moe_reference(params, x, cfg, return_diag=True)
         assert float(diag["dropped_fraction"]) > 0
         assert float(diag["dropped_fraction"]) == pytest.approx(
@@ -334,8 +332,8 @@ class TestMoEDiagnostics:
             y, aux, diag = moe.moe_apply(p, x, cfg, diagnostics=True)
             return jnp.sum(y**2) + aux
 
-        g1 = jax.grad(loss_plain)(params)
-        g2 = jax.grad(loss_diag)(params)
+        g1 = jax.jit(jax.grad(loss_plain))(params)
+        g2 = jax.jit(jax.grad(loss_diag))(params)
         for k in g1:
             np.testing.assert_allclose(
                 np.asarray(g1[k]), np.asarray(g2[k]), atol=1e-6
@@ -371,10 +369,8 @@ class TestPipelineBubble:
         xs = jnp.asarray(
             np.random.default_rng(1).normal(size=(m, 4, 8)), jnp.float32
         )
-        out, diag = pipeline.pipeline_apply(
-            stage_fn, params, xs, mesh, diagnostics=True
-        )
-        ref = pipeline.pipeline_reference(stage_fn, params, xs)
+        out, diag = pipelined(stage_fn, params, xs, mesh, diagnostics=True)
+        ref = sequential(stage_fn, params, xs)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
         analytic = (n_stages - 1) / (m + n_stages - 1)
         assert float(diag["bubble_fraction"]) == pytest.approx(
@@ -388,12 +384,10 @@ class TestPipelineBubble:
         xs = jnp.asarray(
             np.random.default_rng(2).normal(size=(7, 4, 8)), jnp.float32
         )
-        out, diag = pipeline.pipeline_apply(
-            stage_fn, params, xs, mesh, diagnostics=True
-        )
+        out, diag = pipelined(stage_fn, params, xs, mesh, diagnostics=True)
         np.testing.assert_allclose(
             np.asarray(out),
-            np.asarray(pipeline.pipeline_reference(stage_fn, params, xs)),
+            np.asarray(sequential(stage_fn, params, xs)),
             atol=1e-5,
         )
         # n_micro=7, S=4: analytic over the REAL stream
@@ -410,10 +404,8 @@ class TestPipelineBubble:
         xs = jnp.asarray(
             np.random.default_rng(3).normal(size=(8, 4, 8)), jnp.float32
         )
-        on, _ = pipeline.pipeline_apply(
-            stage_fn, params, xs, mesh, diagnostics=True
-        )
-        off = pipeline.pipeline_apply(stage_fn, params, xs, mesh)
+        on, _ = pipelined(stage_fn, params, xs, mesh, diagnostics=True)
+        off = pipelined(stage_fn, params, xs, mesh)
         np.testing.assert_allclose(
             np.asarray(on), np.asarray(off), atol=1e-6
         )
@@ -430,7 +422,7 @@ class TestPipelineBubble:
             )
             return jnp.sum(out**2)
 
-        g = jax.grad(loss)(params)
+        g = jax.jit(jax.grad(loss))(params)
         assert np.isfinite(np.asarray(g["w"])).all()
         assert np.abs(np.asarray(g["w"])).sum() > 0
 
@@ -440,22 +432,37 @@ class TestPipelineBubble:
 # ---------------------------------------------------------------------------
 
 
+def _lm_setup(mesh_axes, batch, **cfg_kw):
+    """``lm.train_step`` on a tiny model and mesh, and its arguments."""
+    import optax
+
+    mesh = create_mesh(mesh_axes)
+    cfg = lm.LMConfig(
+        vocab_size=64, d_model=16, n_heads=2, max_len=16, **cfg_kw
+    )
+    params = lm.init_params(jax.random.key(0), cfg)
+    tx = optax.adam(1e-3)
+    opt = tx.init(params)
+    toks = jnp.asarray(lm.make_synthetic_tokens(cfg, batch, seed=0))
+    step = functools.partial(
+        lm.train_step, cfg=cfg, tx=tx, mesh=mesh, data_axis="data",
+        pipe_axis="pipe" if "pipe" in mesh_axes else None,
+    )
+    return step, params, opt, toks
+
+
+def _lm_step(mesh_axes, batch, **cfg_kw):
+    """One step with diagnostics, jitted as ``examples/`` jit it (called bare
+    the step runs primitive by primitive: a thousand compiles where this is
+    one). Returns (the step's outputs, :func:`_lm_setup`'s)."""
+    step, *args = _lm_setup(mesh_axes, batch, **cfg_kw)
+    return jax.jit(functools.partial(step, diagnostics=True))(*args), (step, *args)
+
+
 class TestLMDiagnostics:
     def test_moe_lm_step_returns_diag_and_folds(self):
-        import optax
-
-        mesh = create_mesh({"data": 8})
-        cfg = lm.LMConfig(
-            vocab_size=64, d_model=16, n_heads=2, n_layers=2, max_len=16,
-            moe_experts=4, moe_top_k=2,
-        )
-        params = lm.init_params(jax.random.key(0), cfg)
-        tx = optax.adam(1e-3)
-        opt = tx.init(params)
-        toks = jnp.asarray(lm.make_synthetic_tokens(cfg, 8, seed=0))
-        p2, o2, loss, diag = lm.train_step(
-            params, opt, toks, cfg=cfg, tx=tx, mesh=mesh, data_axis="data",
-            diagnostics=True,
+        (p2, o2, loss, diag), (step, params, opt, toks) = _lm_step(
+            {"data": 8}, 8, n_layers=2, moe_experts=4, moe_top_k=2
         )
         # counts sum to n_layers * tokens * top_k (every layer routes the
         # full stream)
@@ -470,26 +477,12 @@ class TestLMDiagnostics:
             "moe.expert_imbalance", "moe.dropped_fraction", "moe.gate_entropy"
         }
         # loss identical to the plain step
-        _, _, loss_plain = lm.train_step(
-            params, opt, toks, cfg=cfg, tx=tx, mesh=mesh, data_axis="data",
-        )
+        _, _, loss_plain = jax.jit(step)(params, opt, toks)
         assert float(loss) == pytest.approx(float(loss_plain), abs=1e-6)
 
     def test_pipeline_lm_step_reports_bubble(self):
-        import optax
-
-        mesh = create_mesh({"pipe": 4, "data": 2})
-        cfg = lm.LMConfig(
-            vocab_size=64, d_model=16, n_heads=2, n_layers=4, max_len=16,
-            n_micro=8,
-        )
-        params = lm.init_params(jax.random.key(0), cfg)
-        tx = optax.adam(1e-3)
-        opt = tx.init(params)
-        toks = jnp.asarray(lm.make_synthetic_tokens(cfg, 16, seed=0))
-        _, _, loss, diag = lm.train_step(
-            params, opt, toks, cfg=cfg, tx=tx, mesh=mesh, data_axis="data",
-            pipe_axis="pipe", diagnostics=True,
+        (_, _, loss, diag), _ = _lm_step(
+            {"pipe": 4, "data": 2}, 16, n_layers=4, n_micro=8
         )
         # M=8, S=4 -> (S-1)/(M+S-1) = 3/11
         assert float(diag["bubble_fraction"]) == pytest.approx(
@@ -505,20 +498,8 @@ class TestLMDiagnostics:
         """V>1 diag carries virtual_stages and folds the interleaved
         number under its own gauge (pipeline.bubble_fraction_v) next to
         the shared pipeline.bubble_fraction."""
-        import optax
-
-        mesh = create_mesh({"pipe": 2, "data": 4})
-        cfg = lm.LMConfig(
-            vocab_size=64, d_model=16, n_heads=2, n_layers=4, max_len=16,
-            n_micro=8, n_virtual=2,
-        )
-        params = lm.init_params(jax.random.key(0), cfg)
-        tx = optax.adam(1e-3)
-        opt = tx.init(params)
-        toks = jnp.asarray(lm.make_synthetic_tokens(cfg, 32, seed=0))
-        _, _, _, diag = lm.train_step(
-            params, opt, toks, cfg=cfg, tx=tx, mesh=mesh, data_axis="data",
-            pipe_axis="pipe", diagnostics=True,
+        (_, _, _, diag), _ = _lm_step(
+            {"pipe": 2, "data": 4}, 32, n_layers=4, n_micro=8, n_virtual=2
         )
         # M=8, S=2, V=2 -> (S-1)/(V·M+S-1) = 1/17, below 1F1B's 1/9
         assert float(diag["bubble_fraction"]) == pytest.approx(
@@ -561,21 +542,7 @@ class TestLMDiagnostics:
     def test_lm_compiled_memory_fields(self):
         # per-device compiled-memory bytes
         # from the same compiled handle as the HLO pins, backend-labeled
-        import optax
-
-        mesh = create_mesh({"data": 8})
-        cfg = lm.LMConfig(
-            vocab_size=64, d_model=16, n_heads=2, n_layers=2, max_len=16
-        )
-        params = lm.init_params(jax.random.key(0), cfg)
-        tx = optax.adam(1e-3)
-        opt = tx.init(params)
-        toks = jnp.asarray(lm.make_synthetic_tokens(cfg, 8, seed=0))
-        import functools
-
-        fn = functools.partial(
-            lm.train_step, cfg=cfg, tx=tx, mesh=mesh, data_axis="data"
-        )
+        fn, params, opt, toks = _lm_setup({"data": 8}, 8, n_layers=2)
         mem = compiled_memory_bytes(fn, params, opt, toks)
         assert mem["backend"] == "cpu"
         assert mem["argument_bytes"] > 0
