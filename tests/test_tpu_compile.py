@@ -260,6 +260,24 @@ class TestOneChip:
         assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") == 1
         assert compiled.memory_analysis().output_size_in_bytes == 2 * rows * heads * l * 128
 
+    def test_the_windowed_attention_kernel_at_the_cells_shape(self, one_chip):
+        """``trinity_large_ep8.score``'s sliding layers: 48 query heads on 8
+        key-value heads of 128 over one row of 32,768 tokens under 4,096 keys.
+        The grid is the band's 150 block pairs of the row's 528, the trailing
+        block's body beside the three kinds there were: it fits VMEM, a layer's
+        call is one custom call, and K and V are read as the 8 heads they are."""
+        from tpu_tfrecord.models.attention import _grid_pairs, flash_attention_widths
+
+        l = 32768
+        q = jax.ShapeDtypeStruct((1, 48, l, 128), jnp.bfloat16, sharding=one_chip)
+        kv = jax.ShapeDtypeStruct((1, 8, l, 128), jnp.bfloat16, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((1, l), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(lambda q, k, v, s: flash_attention_widths(
+            q, k, v, s, 128 ** -0.5, 1024, 1024, window=4096)).lower(q, kv, kv, segs).compile()
+        assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") == 1
+        assert compiled.memory_analysis().output_size_in_bytes == 2 * 48 * l * 128
+        assert (len(_grid_pairs(l, 1024, 1024, 4096)), len(_grid_pairs(l, 1024, 1024))) == (150, 528)
+
     def test_lm_train_step_on_dp(self, topo):
         """examples/train_lm.py's widths on a one-device ``data`` mesh."""
         mesh = Mesh(np.array(topo.devices[:1]), ("data",))

@@ -74,7 +74,7 @@ def _expand_kv(q, kv):
 
 def attention_reference(
     q, k, v, lengths=None, scale: Optional[float] = None, causal: bool = False,
-    segments=None,
+    segments=None, window: Optional[int] = None,
 ):
     """Dense softmax attention oracle. q [B, L, H, D], k/v [B, L, Hkv, D]
     with Hkv == H (MHA) or H % Hkv == 0 (GQA/MQA: each K/V head serves
@@ -83,7 +83,8 @@ def attention_reference(
     the mask block-diagonal within the causal triangle: position i attends
     to j only when segments[b, i] == segments[b, j], so documents packed
     into one row (TokenPacker's bin modes) never leak mass across their
-    boundaries."""
+    boundaries. ``window`` (with ``causal``) keeps of those the keys j with
+    i - j < window: the query's own and the ``window - 1`` before it."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     k, v = _expand_kv(q, k), _expand_kv(q, v)
     # the scale goes onto q, before the contraction (L·D multiplies, not
@@ -103,13 +104,15 @@ def attention_reference(
     if causal:
         l, m = q.shape[1], k.shape[1]
         tri = jnp.arange(m)[None, :] <= jnp.arange(l)[:, None]    # [L, M]
+        if window is not None:
+            tri = tri & (jnp.arange(l)[:, None] - jnp.arange(m)[None, :] < window)
         scores = jnp.where(tri[None, None, :, :], scores, _NEG)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhlm,bmhd->blhd", probs, v.astype(jnp.float32)).astype(q.dtype)
 
 
 def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block: int = 1024,
-                        keep=None):
+                        keep=None, window: Optional[int] = None):
     """Causal softmax attention within ``segments``, by key blocks: the
     same answer as ``attention_reference(causal=True, segments=...)``
     without ever holding a ``[B, H, L, L]`` array. q [B, L, H, D], k/v
@@ -125,7 +128,10 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
     sum untouched: every probability is multiplied by its mask, so a row
     that has seen nothing yet carries zeros, not exp(0). ``keep`` [B, L, L]
     (non-zero = query row may see key column) narrows the mask further: a
-    learned selection (``sparse_attn.select_keys``)."""
+    learned selection (``sparse_attn.select_keys``); ``window`` keeps of a
+    query's keys its own and the ``window - 1`` before it, and the key
+    blocks wholly behind a query block's window are skipped like the later
+    ones."""
     b, l, h, d = q.shape
     hkv, dv = k.shape[2], v.shape[-1]
     if h % hkv:
@@ -141,12 +147,15 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
         top = jnp.full(shape, _NEG)
         total = jnp.zeros(shape, jnp.float32)
         acc = jnp.zeros(shape + (dv,), jnp.float32)
-        for k0 in range(0, q1, block):
+        behind = 0 if window is None else max(0, q0 - window + 1) // block * block
+        for k0 in range(behind, q1, block):
             k1 = min(k0 + block, l)
             scores = jnp.einsum("bqkgd,bmkd->bkgqm", qb, k[:, k0:k1],
                                 preferred_element_type=jnp.float32)
             mask = (sq[:, :, None] == segments[:, None, k0:k1]) & (
                 at[q0:q1, None] >= at[None, k0:k1])
+            if window is not None and q1 - 1 - k0 >= window:  # the block reaches behind some query's window
+                mask = mask & (at[q0:q1, None] - at[None, k0:k1] < window)
             if keep is not None:
                 mask = mask & (keep[:, q0:q1, k0:k1] != 0)
             mask = mask[:, None, None]
@@ -179,7 +188,7 @@ _VMEM_LIMIT = 64 * 2 ** 20  # two heads' blocks, twice, and their scratch: 11 MB
 
 def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref,
                          o_ref, m_ref, l_ref, acc_ref, *, scale: float, block_q: int,
-                         block_k: int, keep_ref=None):
+                         block_k: int, keep_ref=None, window: Optional[int] = None):
     """One (query block, key block) pair of the grid step's heads: scores
     stay on the chip, the running maximum and sum are kept 128 lanes wide
     (every lane the same), the weighted values are divided by the sum once,
@@ -199,7 +208,13 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
     to its last query and no others (a square block computes 10 of its 16
     [256, 256] tiles) and compares positions too. ``keep_ref`` [1, block_q,
     block_k] int8, where given, narrows either kind's mask to the pairs it
-    marks non-zero: one block of it serves the step's heads.
+    marks non-zero: one block of it serves the step's heads. With a
+    ``window`` (a query sees its own key and the ``window - 1`` before it)
+    the grid walks the band alone (:func:`_grid_pairs`) and a fourth kind
+    joins: *the band's trailing blocks*, which reach behind some row's
+    window: positions are compared against it, and a pass takes the keys
+    from its first row's window on (10 of 16 tiles where the window is whole
+    blocks); the blocks between those and the diagonal stay what they were.
 
     Inside a kind, a pass takes ``_ROWS`` queries: their product with the
     keys they need, the scale and the mask; then the rows' maximum, the
@@ -223,7 +238,8 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
     rows, heads = min(_ROWS, block_q), q_ref.shape[1]
     needed, _ = _pair_kind(lo_ref, hi_ref, bi, qi, ki, per)
 
-    @pl.when(ki == 0)
+    # the query block's first pair: key block 0, or the first that its first row's window reaches
+    @pl.when(ki == (0 if window is None else lax.div(lax.max(qi * block_q - (window - 1), 0), block_k)))
     def _first():
         m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
@@ -234,41 +250,49 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
         share = heads // k_ref.shape[1]
         return head if share == 1 else lax.div(head, share)
 
-    def pair(origin):
+    def pair(origin, far=None):
         """The body of one kind of pair. ``origin``: where the key block starts,
         counted from the query block's first row (None: wholly before it, and
-        no position is compared)."""
-        passes = [(at, block_k if origin is None else min(block_k, at + rows - origin))
+        no position is compared). ``far``: how far before that row it starts,
+        where it reaches behind some row's window (None: it does not)."""
+        whole = min(rows, block_k)    # a pass's keys start on a whole one of these
+        passes = [(at, 0 if far is None else max(0, far + at - window + 1) // whole * whole,
+                   block_k if origin is None else min(block_k, at + rows - origin))
                   for at in range(0, block_q, rows)]
-        passes = [(at, keys) for at, keys in passes if keys > 0]    # rows before the block's first key
+        # rows before the block's first key, or whose windows end after its last
+        passes = [(at, skip, keys) for at, skip, keys in passes if keys > skip]
 
-        def scores(head, at, keys):
+        def scores(head, at, skip, keys):
             """A pass's first half: a head's ``rows`` queries from ``at`` against
-            the block's first ``keys`` keys, scaled and masked. (``lax`` by name
+            the block's keys ``skip`` to ``keys``, scaled and masked. (``lax`` by name
             in the two halves: a ``jnp`` function is a jitted one, and tracing
             hundreds of them is seconds of every program's set-up on the chip's host.)"""
             out = lax.mul(lax.dot_general(
-                q_ref[0, head, at:at + rows], k_ref[0, held(head), :keys],
+                q_ref[0, head, at:at + rows], k_ref[0, held(head), skip:keys],
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32), scale)
-            seen = [jnp.tile(qseg_ref[0, at:at + rows], (1, keys // _LANES)) == kseg_ref[0, :1, :keys]]
+            wide = keys - skip
+            seen = [jnp.tile(qseg_ref[0, at:at + rows], (1, wide // _LANES)) == kseg_ref[0, :1, skip:keys]]
             if origin is not None:    # the pass's last keys are its own rows
-                seen.append(lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
-                            - lax.broadcasted_iota(jnp.int32, (rows, keys), 0) <= at - origin)
+                seen.append(lax.broadcasted_iota(jnp.int32, (rows, wide), 1)
+                            - lax.broadcasted_iota(jnp.int32, (rows, wide), 0) <= at - origin - skip)
+            if far is not None:       # its first keys lie behind its last rows' windows
+                seen.append(lax.broadcasted_iota(jnp.int32, (rows, wide), 1)
+                            - lax.broadcasted_iota(jnp.int32, (rows, wide), 0) > far + at - skip - window)
             if keep_ref is not None:
-                seen.append(keep_ref[0, at:at + rows, :keys].astype(jnp.int32) != 0)
+                seen.append(keep_ref[0, at:at + rows, skip:keys].astype(jnp.int32) != 0)
             # a row that has met no key of its document yet weighs what it sees by
             # exp(0); the first real score sends that to exp(-1e30) = 0
             return lax.select(functools.reduce(jnp.logical_and, seen), out,
                               lax.full_like(out, _MASKED))
 
-        def fold(head, at, keys, masked):
+        def fold(head, at, skip, keys, masked):
             """A pass's second half: the rows' maximum, the probabilities against
             the values, the running sum and the accumulator brought up to date."""
             at = pl.ds(at, rows)
             m_prev = m_ref[head, at]
             m_next = jnp.maximum(m_prev, masked.max(axis=1, keepdims=True))
-            probs = lax.exp(lax.sub(masked, jnp.tile(m_next, (1, keys // _LANES))))
-            v = v_ref[0, held(head), :keys]
+            probs = lax.exp(lax.sub(masked, jnp.tile(m_next, (1, (keys - skip) // _LANES))))
+            v = v_ref[0, held(head), skip:keys]
             weighted = lax.dot_general(lax.convert_element_type(probs, v.dtype), v,
                                        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
             fade = jnp.exp(m_prev - m_next)
@@ -290,9 +314,17 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
         # lower and compile whatever their number
         lax.fori_loop(0, heads, one_head, 0)
 
-    pl.when(needed & (ki < qi * per))(lambda: pair(None))      # every key before every query
+    under = needed & (ki < qi * per)                           # every key before every query
+    if window is not None:
+        behind = qi * block_q - ki * block_k                   # ... its first key so far before the first
+        under = under & (behind + block_q - 1 < window)        # ... and every key inside every row's window
+        for far in range(block_k, window + block_k - 1, block_k):  # the band's trailing blocks
+            if far + block_q - 1 >= window:
+                pl.when(needed & (behind == far))(functools.partial(pair, None, far))
+    pl.when(under)(lambda: pair(None))
     for j in range(per):    # the key blocks a query block's own rows cross
-        pl.when(ki == qi * per + j)(functools.partial(pair, j * block_k))
+        far = None if window is None or block_q - 1 - j * block_k < window else -j * block_k
+        pl.when(ki == qi * per + j)(functools.partial(pair, j * block_k, far))
 
     @pl.when(ki == (qi + 1) * per - 1)
     def _last():
@@ -326,34 +358,42 @@ def _pair_kind(lo, hi, bi, qi, ki, per: int):
     return needed, (q_lo == q_hi) & (k_lo == k_hi) & (q_lo == k_lo)
 
 
-def _grid_pairs(l: int, block_q: int, block_k: int):
+def _grid_pairs(l: int, block_q: int, block_k: int, window: Optional[int] = None):
     """[pairs, 2] int32: the (query block, key block) pairs at or under the
-    diagonal, a query block's in order: what the kernel's grid walks."""
+    diagonal, a query block's in order: what the kernel's grid walks. With a
+    ``window`` the band alone: no key block wholly behind the window of a
+    query block's first row."""
     per = block_q // block_k
-    return np.array([(qi, ki) for qi in range(l // block_q) for ki in range((qi + 1) * per)],
+    reach = l if window is None else window - 1     # how far behind its first row a query block sees
+    return np.array([(qi, ki) for qi in range(l // block_q)
+                     for ki in range(max(0, qi * block_q - reach) // block_k, (qi + 1) * per)],
                     np.int32)
 
 
-def pair_kinds(segments, block_q: int = 1024, block_k: int = 1024):
+def pair_kinds(segments, block_q: int = 1024, block_k: int = 1024, window: Optional[int] = None):
     """(skipped, plain, masked): how many of the block pairs at or under the
-    diagonal of ``segments`` [B, L] the kernel skips, computes with every key
-    seen (*plain*: wholly under the diagonal, one document; no compare is
+    diagonal of ``segments`` [B, L] (with a ``window``: of its band) the
+    kernel skips, computes with every key seen (*plain*: wholly under the
+    diagonal and inside every row's window, one document; no compare is
     needed there) and computes under a mask that hides something (the
-    diagonal's, or several documents'), by the rule its scalars apply."""
+    diagonal's, the window's, or several documents'), by the rule its
+    scalars apply."""
     segments = np.asarray(segments, np.int32)
     b, l = segments.shape
     block_q, block_k = min(block_q, l), min(block_k, l)
     by_block = segments.reshape(b, l // block_k, block_k)
     per = block_q // block_k
-    qi, ki = _grid_pairs(l, block_q, block_k).T
+    qi, ki = _grid_pairs(l, block_q, block_k, window).T
     needed, one_document = _pair_kind(by_block.min(axis=-1), by_block.max(axis=-1),
                                       np.arange(b)[:, None], qi, ki, per)
     plain = needed & one_document & (ki < qi * per)
+    if window is not None:
+        plain = plain & ((qi + 1) * block_q - 1 - ki * block_k < window)
     return tuple(int(np.sum(n)) for n in (~needed, plain, needed & ~plain))
 
 
 def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
-                           block_k: int = 1024, keep=None):
+                           block_k: int = 1024, keep=None, window: Optional[int] = None):
     """Causal attention inside ``segments`` as a Pallas TPU kernel, for
     queries and keys of one width and values of another (latent attention:
     192 against 128; JAX's own flash kernel takes one width, and only 128s).
@@ -367,6 +407,9 @@ def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
     half on the diagonal). ``keep`` [B, L, L] int8 tells the kernel which keys a
     query may see beside that (a learned selection: a pair it marks 0 is
     masked; one block of it is read a pair of blocks and grid step).
+    ``window``: a query sees its own key and the ``window - 1`` before it,
+    and the grid walks that band of block pairs alone (150 of a 32,768-token
+    document's 528 at 4,096 keys); none: the program it always was.
     Forward only. One trace and one lowering for a program's calls of one
     shape: the call sits in a jitted function."""
     l, dv = q.shape[2], v.shape[-1]
@@ -374,12 +417,15 @@ def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
     if l % block_q or block_q % block_k or block_k % _LANES or dv % _LANES:
         raise ValueError(f"rows of {l} in blocks of {block_q} x {block_k}, values of {dv}: "
                          f"the kernel wants whole blocks and whole {_LANES}s")
+    if window is not None and window < 1:
+        raise ValueError(f"a window of {window} keys: a query sees at least its own")
     return _flash_widths_call(q, k, v, segments, keep, scale=float(scale), block_q=block_q,
-                              block_k=block_k)
+                              block_k=block_k, window=window)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k"))
-def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, block_k: int):
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k", "window"))
+def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, block_k: int,
+                       window: Optional[int] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -390,7 +436,7 @@ def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, b
     per = block_q // block_k
     heads = next(n for n in _HEADS if h % n == 0 and (rep % n == 0 or n % rep == 0))
     held = max(1, heads // rep)     # key heads a grid step's query heads attend over
-    pairs = _grid_pairs(l, block_q, block_k)     # the grid's third axis
+    pairs = _grid_pairs(l, block_q, block_k, window)     # the grid's third axis
 
     def key_block(bi, t, lo_ref, hi_ref, qi_ref, ki_ref):
         """A skipped pair asks for the query block's own last key block, which
@@ -406,7 +452,7 @@ def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, b
 
     kernel = functools.partial(
         _flash_widths_kernel if keep is None else _flash_widths_kept_kernel,
-        scale=scale, block_q=block_q, block_k=block_k)
+        scale=scale, block_q=block_q, block_k=block_k, window=window)
     selection = [] if keep is None else [(keep, pl.BlockSpec(
         (1, block_q, block_k), lambda bi, hi, t, *r: (bi, r[2][t], key_block(bi, t, *r))))]
     call = pl.pallas_call(

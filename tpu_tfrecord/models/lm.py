@@ -774,15 +774,15 @@ def bigram_table(vocab: int, n_next: int, seed: int = 1234) -> np.ndarray:
 # parameters are a list of per-layer dicts (layers of different kinds do
 # not stack). The model reads no position table: order comes from the
 # causal mask, the short convolution and the recurrence, and in a latent-
-# attention layer from rotary angles of each token's index in its own
-# document, derived on the device from ``segment_ids``. Policy: bfloat16
+# attention or sliding-window layer from rotary angles of each token's index
+# in its own document, derived on the device from ``segment_ids``. Policy: bfloat16
 # weights and activations; norms, router, rotary angles, softmax, the
 # recurrent state, logits and log-probabilities in float32. The rows are
 # TokenPacker's bin-mode batches as they are: ``tokens`` and
 # ``segment_ids`` [B, L+1]; every document comes out as it would alone in
 # a row (attention, positions, taps and state all stop at a boundary).
 
-MIXERS = ("gqa", "kda", "mla")
+MIXERS = ("gqa", "kda", "mla", "swa")
 FFNS = ("moe", "dense")
 
 
@@ -795,6 +795,8 @@ class PatternLMConfig:
     n_heads: int = 4               # softmax and latent-attention layers: query heads
     n_kv_heads: int = 2
     head_dim: int = 16
+    window: int = 0                # sliding-window layer ("swa"): the keys a query sees, its own among them
+    qk_norm: bool = False          # softmax and sliding-window layers: an RMSNorm over each head of q and of k
     kda_heads: int = 4             # delta-rule layer: heads of d_k = d_v = kda_head_dim
     kda_head_dim: int = 16
     conv_taps: int = 4
@@ -821,6 +823,8 @@ class PatternLMConfig:
     n_group: int = 1               # the router's group limit: the experts in so many equal runs,
     topk_group: int = 1            # ... of which a token's choice may touch so many (moe.route_top_k)
     norm_eps: float = 1e-5
+    branch_norms: bool = False     # sandwich: x + norm(branch(norm(x))), the mixer's and the feed-forward's
+    embed_scale: bool = False      # the embedding's rows times sqrt(d_model)
     max_len: int = 64              # L: a row is L + 1 tokens
     dtype: Any = jnp.bfloat16
     # how the program cuts the work (no effect on the result beyond rounding)
@@ -846,13 +850,14 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
     hq, hkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
     kd, r = cfg.kda_heads * cfg.kda_head_dim, cfg.gate_rank
     fs = cfg.d_expert * cfg.n_shared
+    after = {"post_ffn_norm": ((d,), f32)} if cfg.branch_norms else {}
     ffns = {"dense": {
-        "ffn_norm": ((d,), f32),
+        **after, "ffn_norm": ((d,), f32),
         "dense": {"w_gate": ((d, cfg.d_dense), dt), "w_up": ((d, cfg.d_dense), dt),
                   "w_down": ((cfg.d_dense, d), dt)},
     }}
     ffns["moe"] = moe = {
-        "moe_norm": ((d,), f32),
+        **after, "moe_norm": ((d,), f32),
         "router": ((d, cfg.n_experts), f32),
         "w_gate": ((cfg.experts_held, d, cfg.d_expert), dt),
         "w_up": ((cfg.experts_held, d, cfg.d_expert), dt),
@@ -881,9 +886,17 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
             "wo": ((cfg.n_heads * cfg.v_head_dim, d), dt),
         },
     }
+    mixers["swa"] = mixers["gqa"]  # a softmax layer under a window, with rotary positions
     for kind in cfg.layer_pattern:
         if kind not in MIXERS:
             raise ValueError(f"layer_pattern names {kind!r}; the mixers are {MIXERS}")
+    if "swa" in cfg.layer_pattern and cfg.window < 1:
+        raise ValueError("a sliding-window layer needs cfg.window: the keys a query sees")
+    if cfg.qk_norm:  # one weight for all heads
+        mixers["gqa"].update({"q_norm": ((cfg.head_dim,), f32), "k_norm": ((cfg.head_dim,), f32)})
+    if cfg.branch_norms:
+        for mixer in mixers.values():
+            mixer["post_attn_norm"] = ((d,), f32)
     if cfg.q_rank:  # the query through a normed latent, as the keys and values go
         wq = mixers["mla"].pop("wq")[0]
         mixers["mla"].update({"wq_a": ((d, cfg.q_rank), dt), "q_norm": ((cfg.q_rank,), f32),
@@ -970,16 +983,31 @@ def _flash_attend(q, k, v, segments, block: int):
                                   block_k=min(512, block), block_b=1))
 
 
-def _attend(q, k, v, segments, block: int, scale=None, keep=None):
+def _takes_kernel(l: int, dv: int, block: int) -> bool:
+    """Whether :func:`_attend` runs a Pallas kernel for rows of ``l`` tokens
+    and values ``dv`` wide: on a TPU, whole blocks of whole 128s."""
+    tile = min(block, l)
+    return jax.default_backend() == "tpu" and dv % 128 == 0 and tile % 128 == 0 and l % tile == 0
+
+
+def _attend(q, k, v, segments, block: int, scale=None, keep=None, window=None):
     """Causal attention inside each document; q [B, H, L, D], k [B, Hkv, L, D],
     v [B, Hkv, L, Dv] -> [B, H, L, Dv], scores scaled by ``scale`` (D ** -0.5
     where none is given), over the keys ``keep`` [B, L, L] marks non-zero
     (every key of the document at or before the query where none is given:
-    ``sparse_attn.select_keys`` makes one). On a TPU,
+    ``sparse_attn.select_keys`` makes one) and, with a ``window``, of those
+    the query's own and the ``window - 1`` before it. On a TPU,
     for rows of whole blocks of 128s, a Pallas kernel: JAX's own where q, k
-    and v share a width of whole 128s (it takes no other), and
-    ``attention.flash_attention_widths`` where the values are narrower than
-    the keys (latent attention: 192 against 128). That kernel sees from two
+    and v share a width of whole 128s (it takes no other) and nothing but
+    the document narrows the keys (it has no window and no selection, and
+    is handed K and V copied once a query head), and
+    ``attention.flash_attention_widths`` otherwise: where the values are
+    narrower than the keys (latent attention: 192 against 128), under a
+    selection, and under a window, where its grid walks the band of block
+    pairs alone (a 4,096-key window over 32,768 tokens in blocks of 1,024:
+    150 pairs of 528, of a query block's five the oldest compared against
+    the window, the newest against the diagonal, the three between
+    neither; grouped heads read their one K and V). That kernel sees from two
     block indices and four segment ids what a pair of blocks needs: nothing;
     segment ids and no position (under the diagonal: 120 of the 136 pairs of
     a 16,384-token document, where every key is seen and the compare is
@@ -990,33 +1018,74 @@ def _attend(q, k, v, segments, block: int, scale=None, keep=None):
     shapes neither takes, ``attention.blockwise_attention``: plain JAX, the
     same mask, the same answer (tests/test_pattern_lm.py,
     tests/test_mla_lm.py and tests/test_dsa_lm.py hold each kernel to it)."""
-    (l, d), dv, tile = q.shape[2:], v.shape[-1], min(block, q.shape[2])
-    if jax.default_backend() == "tpu" and dv % 128 == 0 and tile % 128 == 0 and l % tile == 0:
-        if d == dv and scale is None and keep is None:
+    (l, d), dv = q.shape[2:], v.shape[-1]
+    if _takes_kernel(l, dv, block):
+        if d == dv and scale is None and keep is None and window is None:
             return _flash_attend(q, k, v, segments, block)
-        return flash_attention_widths(q, k, v, segments, scale or d ** -0.5, block, block, keep=keep)
+        return flash_attention_widths(q, k, v, segments, scale or d ** -0.5, block, block, keep=keep,
+                                      window=window)
     out = blockwise_attention(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segments,
-        scale=scale, block=block, keep=keep)
+        scale=scale, block=block, keep=keep, window=window)
     return jnp.swapaxes(out, 1, 2)
 
 
-def gqa_mixer(p, x, segments, cfg: PatternLMConfig):
-    """The softmax layer, without positions: grouped causal attention inside
-    each document, an elementwise sigmoid gate on its output. x [B, L, D].
-    Heads are written head-major ``[B, H, L, D]`` by the projections, which is
-    how the attention reads them: nothing is transposed."""
+def gqa_mixer(p, x, segments, cfg: PatternLMConfig, sliding: bool = False):
+    """The softmax layer: grouped causal attention inside each document, an
+    elementwise sigmoid gate on its output; with ``cfg.qk_norm`` an RMSNorm
+    over each head of q and of k. Without positions, every key of the
+    document before the query; ``sliding`` (the "swa" layers): rotary turns
+    over the whole head by each token's index in its own document, and of
+    those keys the query's own and the ``cfg.window - 1`` before it. x
+    [B, L, D]. Heads are written head-major ``[B, H, L, D]`` by the
+    projections, which is how the attention reads them: nothing is transposed."""
+    return gqa_mixer_probed(p, x, segments, cfg, sliding)[0]
+
+
+def gqa_mixer_probed(p, x, segments, cfg: PatternLMConfig, sliding: bool = False, sample_at=None,
+                     probe_head=None):
+    """(:func:`gqa_mixer`'s y, a record of one head's attention or None).
+    With ``sample_at`` [B, S] and ``probe_head`` (an int32 scalar naming a
+    query head) what the attention call was given and gave, float32, so that
+    a caller can walk the same inputs and see which keys a query saw:
+    ``record["scan"]`` = that head's keys and values ``k_swa``, ``v_swa``
+    [B, L, Dh], ``record["router"]`` = at the sampled positions its queries
+    and outputs ``q_swa``, ``att_swa`` [B, S, Dh] and ``swa_pos`` [B, S], a
+    position's index in its own document."""
     d, h, hkv, dh = x.shape[-1], cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    with jax.named_scope("tfr.gqa"):
+    probed = sample_at is not None and probe_head is not None
+    around, call = ("tfr.swa_proj", "tfr.swa_attn") if sliding else ("tfr.gqa", "tfr.gqa")
+    with jax.named_scope(around):
         u = weighted_rms_norm(x, p["attn_norm"], cfg.norm_eps)
         q = jnp.einsum("bld,dhk->bhlk", u, p["wq"].reshape(d, h, dh))
         k = jnp.einsum("bld,dhk->bhlk", u, p["wk"].reshape(d, hkv, dh))
         v = jnp.einsum("bld,dhk->bhlk", u, p["wv"].reshape(d, hkv, dh))
-        att = _attend(q, k, v, segments, cfg.attn_block)
+        if cfg.qk_norm:
+            q = weighted_rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = weighted_rms_norm(k, p["k_norm"], cfg.norm_eps)
+        at = segment_positions(segments) if sliding or probed else None
+        if sliding:
+            q, k = rotary(q, at, cfg.rope_theta), rotary(k, at, cfg.rope_theta)
+        if probed:  # the call and the record of it read these very arrays (see pattern_hidden's note)
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
+    with jax.named_scope(call):
+        att = _attend(q, k, v, segments, cfg.attn_block, window=cfg.window if sliding else None)
+    record = None
+    if probed:
+        def sampled(a):  # [B, H, L, Dh] -> the probed head's rows at sample_at, [B, S, Dh]
+            return jnp.take_along_axis(jnp.take(a, probe_head, axis=1), sample_at[:, :, None],
+                                       axis=1).astype(jnp.float32)
+
+        held = probe_head // (h // hkv)
+        record = {"scan": {"k_swa": jnp.take(k, held, axis=1).astype(jnp.float32),
+                           "v_swa": jnp.take(v, held, axis=1).astype(jnp.float32)},
+                  "router": {"q_swa": sampled(q), "att_swa": sampled(att),
+                             "swa_pos": jnp.take_along_axis(at, sample_at, axis=1)}}
+    with jax.named_scope(around):
         gate = jax.nn.sigmoid(
             jnp.einsum("bld,dhk->bhlk", u, p["wg"].reshape(d, h, dh)).astype(jnp.float32))
         gated = (att.astype(jnp.float32) * gate).astype(x.dtype)
-        return jnp.einsum("bhlk,hkd->bld", gated, p["wo"].reshape(h, dh, d))
+        return jnp.einsum("bhlk,hkd->bld", gated, p["wo"].reshape(h, dh, d)), record
 
 
 def segment_positions(segments):
@@ -1189,6 +1258,20 @@ def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
         return jnp.einsum("bhlk,hkd->bld", o, p["wo"].reshape(h, dh, d)), probe
 
 
+#: where a mixer's branch norm is counted: with the layer's other projections
+_BRANCH_SCOPE = {"gqa": "tfr.gqa", "swa": "tfr.swa_proj", "mla": "tfr.mla_proj", "kda": "tfr.kda_proj"}
+
+
+def _joined(x, y, weight, cfg: PatternLMConfig, scope: str):
+    """The residual stream after a branch: ``x + y``, or under
+    ``cfg.branch_norms`` (sandwich norms: ``weight`` is the branch's own)
+    ``x + rms(y; weight)``."""
+    if weight is None:
+        return x + y
+    with jax.named_scope(scope):
+        return x + weighted_rms_norm(y, weight, cfg.norm_eps)
+
+
 def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=None,
                    probe_head=None):
     """tokens, segment_ids [B, L+1] -> (x [B, L, D] before the final norm,
@@ -1203,7 +1286,9 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
     Where latent-attention layers have an indexer, ``selected`` [layers, 2]
     (:func:`index_select`'s counts) and, with ``sample_at``, the first expert
     layer's selection: its keys under ``scan`` and the rest beside the
-    router's entries, each with a leading axis of 1."""
+    router's entries, each with a leading axis of 1. Likewise, with both
+    ``sample_at`` and ``probe_head``, the first sliding-window layer's record
+    of that head's attention (:func:`gqa_mixer_probed`)."""
     l = tokens.shape[1] - 1
     if l != cfg.max_len:
         raise ValueError(
@@ -1212,47 +1297,53 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
     segments = segment_ids[:, :-1]
     with jax.named_scope("tfr.embed"):
         x = params["embed"][tokens[:, :-1]]
+        if cfg.embed_scale:
+            x = (x.astype(jnp.float32) * cfg.d_model ** 0.5).astype(x.dtype)
     b, _, d = x.shape
     visits, dropped, routed, probes, selected, selection = [], [], [], {}, [], None
     for kind, ffn, layer in zip(cfg.layer_pattern, ffn_kinds(cfg), params["layers"]):
-        if kind == "gqa":
-            x = x + gqa_mixer(layer, x, segments, cfg)
+        if kind == "swa" and "scan" not in probes:  # the first sliding layer, one head probed
+            y, window = gqa_mixer_probed(layer, x, segments, cfg, True, sample_at, probe_head)
+            if window is not None:
+                selection, probes["scan"] = window["router"], window["scan"]
+        elif kind in ("gqa", "swa"):
+            y = gqa_mixer(layer, x, segments, cfg, sliding=kind == "swa")
         elif kind == "mla" and cfg.index_topk:
             probed = selection is None and ffn == "moe"
             y, index = mla_mixer_probed(layer, x, segments, cfg, sample_at if probed else None)
-            x = x + y
             selected.append(index["counts"])
             if probed and sample_at is not None:
                 selection, probes["scan"] = index["router"], index["scan"]
         elif kind == "mla":
-            x = x + mla_mixer(layer, x, segments, cfg)
+            y = mla_mixer(layer, x, segments, cfg)
         else:
             y, scan = kda_mixer(layer, x, segments, cfg,
                                 None if "scan" in probes else probe_head)
-            x = x + y
             if scan is not None:
                 probes["scan"] = scan
+        x = _joined(x, y, layer.get("post_attn_norm"), cfg, _BRANCH_SCOPE[kind])
         if ffn == "dense":
             with jax.named_scope("tfr.dense_ffn"):
                 u = weighted_rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
                 w = layer["dense"]
-                x = x + _moe.gated_ffn(u, w["w_gate"], w["w_up"], w["w_down"]).astype(x.dtype)
+                y = _moe.gated_ffn(u, w["w_gate"], w["w_up"], w["w_down"]).astype(x.dtype)
+                x = _joined(x, y, layer.get("post_ffn_norm"), cfg, "tfr.dense_ffn")
             continue
         with jax.named_scope("tfr.moe_route"):
             u = weighted_rms_norm(x, layer["moe_norm"], cfg.norm_eps)
-            if cfg.n_group > 1:
+            if cfg.n_group > 1 or cfg.branch_norms:
                 # ONE array for the router and for the probe of it. Left alone, the compiler
                 # computes the norm once for each reader, the two fusions round a few
                 # elements in a thousand to different bfloat16 neighbours, and the probe held
                 # the router to inputs it never saw (gates 2.6e-4 apart on the chip where
-                # float32 reads 2e-7). Patterns without a group limit keep the program they
-                # had (tests/test_mla_lm.py holds their jaxprs).
+                # float32 reads 2e-7; under sandwich norms 5.3e-4). The patterns that came before
+                # either keep the program they had (tests/test_mla_lm.py holds their jaxprs).
                 u = jax.lax.optimization_barrier(u)
         y, n, lost, (experts, gates) = _moe.held_experts_apply(
             layer, u.reshape(b * l, d), held_offset=cfg.held_offset, top_k=cfg.top_k,
             routed_scale=cfg.routed_scale, tile=cfg.expert_tile,
             valid=(segments != 0).reshape(b * l), n_group=cfg.n_group, topk_group=cfg.topk_group)
-        x = x + y.reshape(b, l, d)
+        x = _joined(x, y.reshape(b, l, d), layer.get("post_ffn_norm"), cfg, "tfr.moe_experts")
         visits.append(n)
         dropped.append(lost)
         if sample_at is not None:
@@ -1265,7 +1356,7 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
     if selected:
         probes["selected"] = jnp.stack(selected)
     if selection is not None:
-        probes["router"].update({k: a[None] for k, a in selection.items()})
+        probes.setdefault("router", {}).update({k: a[None] for k, a in selection.items()})
     if not visits:  # no layer has experts
         return x, jnp.zeros((0, cfg.experts_held), jnp.int32), jnp.zeros((0,), jnp.int32), probes
     return x, jnp.stack(visits), jnp.stack(dropped), probes
@@ -1301,6 +1392,13 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     in_kernel = cfg.index_topk and _sa.select_tile(
         (tokens.shape[0], cfg.index_heads, cfg.max_len, cfg.index_dim), cfg.index_topk) is not None
     METRICS.gauge("dsa.kernel_layers", cfg.layer_pattern.count("mla") if in_kernel else 0)
+    if "swa" in cfg.layer_pattern:  # and the window: one shape for every sliding layer
+        in_kernel = _takes_kernel(cfg.max_len, cfg.head_dim, cfg.attn_block)
+        METRICS.gauge("swa.kernel_layers", cfg.layer_pattern.count("swa") if in_kernel else 0)
+        tile = min(cfg.attn_block, cfg.max_len)
+        one_document = np.ones((1, -(-cfg.max_len // tile) * tile), np.int32)
+        band, triangle = (sum(pair_kinds(one_document, tile, tile, w)) for w in (cfg.window, None))
+        METRICS.gauge("swa.pairs_walked_share", round(band / triangle, 6))
     b, l, d = x.shape
     with jax.named_scope("tfr.lm_head"):
         xn = weighted_rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -68,7 +68,12 @@ ANNOTATIONS = {
     "tfr.table_scatter": "sparse_train_step: the row updates scattered into the table, per block "
                          "of slots, inside the loop over the blocks that hold runs",
     "tfr.embed": "pattern LM (models.lm.score): the token rows gathered from the embedding",
-    "tfr.gqa": "pattern LM: the softmax layer (norm, projections, blockwise attention, gate, out)",
+    "tfr.gqa": "pattern LM: the softmax layer without a window (norm, projections, Q/K norm, the "
+               "attention call: a flash kernel on a TPU, blockwise attention elsewhere; gate, out, branch norm)",
+    "tfr.swa_proj": "pattern LM: a sliding-window layer's norm, five projections, Q/K norm, rotary "
+                    "turns, gate, out, branch norm",
+    "tfr.swa_attn": "pattern LM: a sliding-window layer's attention call alone (the band of block "
+                    "pairs inside the window, inside each document)",
     "tfr.kda_proj": "pattern LM: the delta-rule layer's projections, decay, beta, gates, norm, out",
     "tfr.kda_conv": "pattern LM: the short convolution, SiLU and unit norm of q, k, v",
     "tfr.kda_scan": "pattern LM: the chunked gated delta rule (models.linear_attn)",

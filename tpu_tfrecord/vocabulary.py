@@ -212,6 +212,8 @@ GAUGES: Dict[str, str] = {
     "dsa.kernel_layers": "pattern LM, the score program last traced: latent-attention layers whose selection took the Pallas kernel (0 off a TPU and without an indexer)",
     "dsa.selected_share": "pattern LM, latest step recorded: keys the indexers kept over the causal candidates they chose from (lm.record_selected)",
     "mla.plain_pair_share": "pattern LM, latest step recorded: of the block pairs the latent-attention kernel computes, those wholly under the diagonal of one document, where every key is seen (lm.record_pair_kinds)",
+    "swa.kernel_layers": "pattern LM, the score program last traced: sliding-window layers whose attention took the Pallas kernel under a window (0 off a TPU)",
+    "swa.pairs_walked_share": "pattern LM, the score program last traced: the block pairs a sliding-window layer walks (the band) over the pairs at or under the diagonal of a row",
     "moe.gate_entropy": "latest per-step router gate entropy",
     "moe.expert_imbalance": "latest per-step expert imbalance",
     "pipeline.bubble_fraction": "latest per-step pipeline bubble fraction",
