@@ -244,8 +244,9 @@ def kernel_programs():
     segs = ones((1, 256), jnp.int32)
     heads = ones((1, 2, 256, 128), f32)
     return {
-        "mla_attn": (lambda q, k, v: attention.flash_attention_widths(q, k, v, segs, 0.1, 128, 128),
-                     (ones((1, 2, 256, 192), f32), ones((1, 1, 256, 192), f32), ones((1, 1, 256, 128), f32))),
+        "mla_attn": (lambda q, k, v, q_rope, k_rope: attention.flash_attention_widths(
+            q, k, v, segs, 0.1, 128, 128, q_rope=q_rope, k_rope=k_rope),
+                     (heads, heads, heads, ones((1, 2, 256, 64), f32), ones((1, 1, 256, 64), f32))),
         "kda_scan": (lambda q, g, beta: linear_attn._delta_rule_fused(q, q, q, g, beta, segs, 0.1, 128),
                      (heads, -heads, ones((1, 2, 256), f32))),
         "dsa_index": (lambda q, k, w: sparse_attn._select_fused(q, k, w, segs, 16, (64, 128)),

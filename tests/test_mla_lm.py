@@ -3,8 +3,8 @@ leading dense layer and a router whose bias picks and never weighs) against
 its plain reference, at sizes a CPU walks in seconds: the mixer, the dense
 layer, the biased router and the 1 + 2-layer model; a packed row against
 each of its documents alone and the positions that restart at a boundary;
-the Pallas kernel for 192-wide keys against 128-wide values, interpreted,
-against the plain path; the shares of 64 experts held as 8 x 8 against the
+the Pallas kernel for 192-wide keys against 128-wide values, joined and in
+their two parts, interpreted, against the plain path; the shares of 64 experts held as 8 x 8 against the
 uncut layer; and the pattern model's older configurations left as they were."""
 
 import functools
@@ -228,38 +228,122 @@ def test_bfloat16_stays_near_the_float32_program(params):
     assert 0 < np.abs(outs[0] - outs[1]).max() < 0.25
 
 
+def joined(q, k, q_rope, k_rope):
+    """The two parts of q and of k as one array each, the rotary key head copied to every head."""
+    wide = jnp.broadcast_to(k_rope, k.shape[:1] + (k.shape[1],) + k_rope.shape[2:])
+    return jnp.concatenate([q, q_rope], axis=-1), jnp.concatenate([k, wide], axis=-1)
+
+
 @pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (512, 256)])
 def test_the_kernel_for_wide_keys_is_blockwise_attention(blocks):
-    """``lm._attend`` runs ``flash_attention_widths`` on a TPU for 192-wide
-    queries and keys against 128-wide values, and ``blockwise_attention``
-    elsewhere: the kernel, interpreted here (on a chip, outside pytest's
-    conftest, this function runs it as it is), against the plain path on a
-    packed row whose one rotary key head serves every query head."""
+    """``lm._attend`` hands ``flash_attention_widths`` 128 plain and 64 rotary
+    columns of the queries and keys as arrays of their own on a TPU, against
+    128-wide values, and joins them for ``blockwise_attention`` elsewhere: the
+    kernel, interpreted here (on a chip, outside pytest's conftest, this
+    function runs it as it is), against the plain path on a packed row whose
+    one rotary key head serves every query head."""
     r = np.random.default_rng(1)
-    q = jnp.asarray(r.standard_normal((1, 4, 512, 192)), jnp.float32)      # [B, H, L, D]
-    k = jnp.asarray(r.standard_normal((1, 2, 512, 192)), jnp.float32)
+    q = jnp.asarray(r.standard_normal((1, 4, 512, 128)), jnp.float32)      # [B, H, L, D]
+    k = jnp.asarray(r.standard_normal((1, 2, 512, 128)), jnp.float32)
+    q_rope = jnp.asarray(r.standard_normal((1, 4, 512, 64)), jnp.float32)
+    k_rope = jnp.asarray(r.standard_normal((1, 1, 512, 64)), jnp.float32)  # one head, for all
     v = jnp.asarray(r.standard_normal((1, 2, 512, 128)), jnp.float32)
     segs = np.zeros((1, 512), np.int32)
     segs[0, :100], segs[0, 100:130], segs[0, 130:400] = 1, 2, 3
     segs = jnp.asarray(segs)
-    want = plain_path(q, k, v, segs, None)
+    want = plain_path(*joined(q, k, q_rope, k_rope), v, segs, None)
     assert want.shape == (1, 4, 512, 128)
     if jax.default_backend() == "tpu":
-        got = lm._attend(q, k, v, segs, blocks[0])
+        got = lm._attend((q, q_rope), (k, k_rope), v, segs, blocks[0])
     else:
-        got = interpreted_kernel(q, k, v, segs, 192 ** -0.5, *blocks)
+        got = interpreted_kernel(q, k, v, segs, 192 ** -0.5, *blocks, q_rope=q_rope, k_rope=k_rope)
     real = np.asarray(segs[0] != 0)
     np.testing.assert_allclose(np.asarray(got)[:, :, real], np.asarray(want)[:, :, real],
                                atol=3e-2 if jax.default_backend() == "tpu" else 2e-5)
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6))
-def interpreted_kernel(q, k, v, segs, scale, block_q, block_k, keep=None):
+def interpreted_kernel(q, k, v, segs, scale, block_q, block_k, keep=None, q_rope=None, k_rope=None):
     """``flash_attention_widths`` interpreted, one program a shape."""
     from jax.experimental.pallas import tpu as pltpu
 
     with pltpu.force_tpu_interpret_mode():
-        return flash_attention_widths(q, k, v, segs, scale, block_q, block_k, keep=keep)
+        return flash_attention_widths(q, k, v, segs, scale, block_q, block_k, keep=keep,
+                                      q_rope=q_rope, k_rope=k_rope)
+
+
+@pytest.mark.parametrize("documents", ["one document", "packed rows"])
+@pytest.mark.parametrize("rotary_keys", ["one shared head", "one a head"])
+@pytest.mark.parametrize("selection", ["no selection", "a selection"])
+def test_the_kernel_handed_parts_is_blockwise_attention_on_their_concatenation(
+        selection, rotary_keys, documents):
+    """What a latent-attention layer hands the kernel on a TPU: plain queries and
+    keys, rotary queries, and the rotary keys of one head for all (or of one a
+    key head), never joined: the score is the sum of the two products in float32.
+    Interpreted, against the plain path on the joined arrays; a row of one
+    document (every pair under the diagonal plain) and packed rows of several
+    (skipped, masked and diagonal pairs), with and without a mask of kept keys."""
+    r = np.random.default_rng(5)
+    b, h, l = (1, 2, 512) if documents == "one document" else (2, 2, 512)
+    q, k, v = (jnp.asarray(r.standard_normal((b, h, l, 128)), jnp.float32) for _ in range(3))
+    q_rope = jnp.asarray(r.standard_normal((b, h, l, 64)), jnp.float32)
+    k_rope = jnp.asarray(r.standard_normal((b, 1 if rotary_keys == "one shared head" else h, l, 64)),
+                         jnp.float32)
+    rows = [[512]] if documents == "one document" else [[100, 30, 270, 112], [256, 200]]
+    segs = jnp.asarray([np.pad(np.repeat(np.arange(1, len(n) + 1), n), (0, l - sum(n))) for n in rows],
+                       jnp.int32)
+    keep = None
+    if selection == "a selection":   # a third of the keys and every query's own
+        keep = jnp.asarray((r.random((b, l, l)) < 0.33) | np.eye(l, dtype=bool)[None], jnp.int8)
+    got = interpreted_kernel(q, k, v, segs, 0.08, 128, 128, keep=keep, q_rope=q_rope, k_rope=k_rope)
+    want = plain_path(*joined(q, k, q_rope, k_rope), v, segs, 0.08, keep=keep)
+    real = np.asarray(segs != 0)[:, None, :, None]
+    np.testing.assert_allclose(np.where(real, got, 0), np.where(real, want, 0), atol=2e-5)
+    # the second part is at work: without it another answer
+    bare = interpreted_kernel(q, k, v, segs, 0.08, 128, 128, keep=keep)
+    assert np.abs(np.where(real, bare - want, 0)).max() > 1e-2
+
+
+def test_a_tpu_hands_the_kernel_the_parts_and_nothing_joined(monkeypatch):
+    """On a TPU ``_attend`` passes a latent-attention layer's four arrays and its
+    one rotary key head through to the kernel as they are; elsewhere it joins
+    them, the rotary key head copied to every head there and only there. The
+    kernel stubbed here; the dispatch and the gauge read."""
+    from tpu_tfrecord.metrics import METRICS
+
+    seen = []
+
+    def stub(q, k, v, segs, scale, bq, bk, keep=None, window=None, q_rope=None, k_rope=None):
+        seen.append((q.shape, k.shape, v.shape, q_rope.shape, k_rope.shape, round(scale, 6)))
+        return jnp.zeros(q.shape[:3] + v.shape[-1:], q.dtype)
+
+    monkeypatch.setattr(lm, "flash_attention_widths", stub)
+    monkeypatch.setattr(lm, "_flash_attend", lambda *a: pytest.fail("JAX's kernel takes no second part"))
+    cfg = lm.PatternLMConfig(
+        vocab_size=64, d_model=32, layer_pattern=("mla",) * 3, ffn_pattern=("dense",) * 3, n_heads=2,
+        qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128, kv_rank=16, d_dense=16, max_len=256,
+        attn_block=128, head_block=256, dtype=jnp.float32)
+    p = jax.eval_shape(lambda: lm.pattern_init_params(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((1, 257), jnp.int32)
+
+    def trace():
+        jax.eval_shape(lambda p, t: lm.score(p, t, t, jnp.zeros((1, 2), jnp.int32), cfg), p, tokens)
+
+    trace()     # off a TPU: the plain path on the joined arrays, the kernel never asked
+    assert seen == [] and METRICS.gauge_value("mla.split_layers") == 0
+    monkeypatch.setattr(lm.jax, "default_backend", lambda: "tpu")
+    trace()
+    part, rope = (1, 2, 256, 128), (1, 2, 256, 64)
+    assert seen == [(part, part, part, rope, (1, 1, 256, 64), round(192 ** -0.5, 6))] * 3
+    assert METRICS.gauge_value("mla.split_layers") == 3
+    # and what the plain path is given elsewhere: one array as wide as both parts, every head its own copy
+    monkeypatch.undo()
+    given = []
+    monkeypatch.setattr(lm, "blockwise_attention", lambda q, k, v, segs, **kw:
+                        given.append((q.shape, k.shape, v.shape)) or jnp.zeros(q.shape[:3] + v.shape[-1:], q.dtype))
+    q, k_rope = jnp.zeros(part), jnp.zeros((1, 1, 256, 64))
+    lm._attend((q, jnp.zeros(rope)), (q, k_rope), q, jnp.ones((1, 256), jnp.int32), 128)
+    assert given == [((1, 256, 2, 192), (1, 256, 2, 192), (1, 256, 2, 128))]
 
 
 def kernel_inputs(lengths, l, seed=4, heads=2):
@@ -348,6 +432,17 @@ def test_the_kernel_refuses_rows_that_are_not_whole_blocks():
                                1.0, 256, 128)
 
 
+@pytest.mark.parametrize("k_rope_heads", [None, 3])
+def test_the_kernel_refuses_a_second_part_of_the_queries_without_the_keys(k_rope_heads):
+    """Rotary queries alone, or rotary keys of a number of heads that is neither
+    one for all nor the keys' own."""
+    q = jnp.zeros((1, 6, 256, 128))
+    k_rope = None if k_rope_heads is None else jnp.zeros((1, k_rope_heads, 256, 64))
+    with pytest.raises(ValueError, match="second part"):
+        flash_attention_widths(q, q, q, jnp.ones((1, 256), jnp.int32), 1.0, 128, 128,
+                               q_rope=jnp.zeros((1, 6, 256, 64)), k_rope=k_rope)
+
+
 def test_the_shares_of_64_experts_held_8_by_8_add_up_to_the_uncut_layer():
     """Eight chips of 8 experts each under the biased router, the two shared
     experts counted once, against the reference told that it holds all 64."""
@@ -386,12 +481,13 @@ def test_an_unknown_mixer_or_feed_forward_part_is_named_with_the_ones_there_are(
 #: ONE pattern's program must leave as they were, operation for operation:
 #: ``solar`` test_pattern_lm's softmax / delta-rule program (recorded at PR 30, before
 #: the latent-attention layer came; off a TPU the delta rule runs its plain form, so
-#: PR 32's kernel leaves it alone too), and as PR 31 left them ``kimi`` this file's
-#: latent-attention program and the recommender's ``forward`` and ``sparse_train_step``.
+#: PR 32's kernel leaves it alone too), as PR 31 left them the recommender's ``forward``
+#: and ``sparse_train_step``, and as PR 40 left it ``kimi``, this file's latent-attention
+#: program (its mixer's four projections each an array of its own, joined off a TPU alone).
 #: A PR that means to change one of them records its own.
 OLDER_PROGRAMS = {
     "solar": "80e42af17080e7b14f19cef6c96e5055d12c4ba2668df85bfa2314a54e851896",
-    "kimi": "0ba72f9be56926b787a2266609187fe94c649a9a7c779dc136a73074251c044b",
+    "kimi": "4d0e48d35253802a8d47e7bee4c761f0ef2730b67b87e565d57d91f97bede802",
     "dlrm_forward": "74937f331a59e45e91ba132bca04da279ac57e7cdc91574931627704728350cc",
     "sparse_train_step": "ea35280a10973a3d8af6c8c0a1a8b17679edde3e8d4005f6012a10f3f8360d9d",
 }

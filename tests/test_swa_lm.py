@@ -270,6 +270,41 @@ def test_the_band_of_one_long_document_is_150_pairs_of_528():
                                window=0)
 
 
+def operations(jaxpr):
+    """The equations of ``jaxpr`` and of every jaxpr inside them."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    count += operations(inner)
+    return count
+
+
+@pytest.mark.parametrize("window, at_the_parent, with_parts", [(None, 187, 202), (300, 336, 366)])
+def test_without_a_rotary_part_the_kernels_body_is_the_one_it_was(window, at_the_parent, with_parts):
+    """The kernel takes latent attention's queries and keys in two parts since
+    PR 40; handed one, as by this file's layers and by every caller before, its
+    traced body has the operations it had at PR 39 (counted there, on this call:
+    the same text too, by its hash then), and the second product only with a
+    second part: five operations a pass more (two reads, the key head's index,
+    the product, the sum), in each of the three kinds of pair here and of the
+    six under a window."""
+    q, k, v, segs = window_inputs([512], 512)
+
+    def body(**parts):
+        program = jax.make_jaxpr(lambda q, k, v: flash_attention_widths(
+            q, k, v, segs, 0.09, 256, 128, window=window, **parts))(q, k, v).jaxpr
+        (call,) = program.eqns       # the jitted call site
+        (kernel,) = [e for e in call.params["jaxpr"].jaxpr.eqns if e.primitive.name == "pallas_call"]
+        return operations(kernel.params["jaxpr"])
+
+    assert body() == at_the_parent
+    assert body(q_rope=q[..., :64], k_rope=k[:, :1, :, :64]) == with_parts
+
+
 def test_the_kernel_is_what_a_tpu_runs_under_a_window(monkeypatch, params):
     """On a TPU ``_attend`` hands a windowed layer to the repo's kernel and a full
     one, as before, to JAX's; both stubbed here, the dispatch and the gauge read."""

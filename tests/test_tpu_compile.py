@@ -17,6 +17,7 @@ to the cache but cannot be read back without a chip).
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -240,25 +241,58 @@ class TestOneChip:
         assert l * l + l * 4 <= mem.output_size_in_bytes < l * l + l * 4 + 4096   # the mask, the counts
         assert mem.temp_size_in_bytes < l * l // 8
 
-    @pytest.mark.parametrize("rows, heads, l, selection", [(1, 4, 16384, True), (2, 16, 8192, False)],
+    @pytest.mark.parametrize("rows, heads, l, selection", [(1, 128, 16384, True), (2, 16, 8192, False)],
                              ids=["under_a_selection", "packed_rows"])
     def test_the_attention_kernel_at_the_cells_shape(self, one_chip, rows, heads, l, selection):
-        """Latent attention's 192-wide keys against 128-wide values: one row of
-        16,384 tokens with the mask of kept keys as a fifth input, one [1024,
-        1024] block of it a pair of blocks (4 heads here: the grid only repeats
-        over the 128), and ``kimi_vl_a3b_lm.score``'s two packed rows of 8,192
-        without one. Each kind of pair is a body of its own in the one kernel:
-        it fits VMEM, and a layer's call is one custom call."""
+        """Latent attention's queries and keys in their two parts, 128 plain and 64
+        rotary columns (the rotary keys one head for all), against 128-wide
+        values: ``deepseek_v32_exp_ep16.score``'s one row of 16,384 tokens and 128
+        heads with the mask of kept keys as a further input, one [1024, 1024]
+        block of it a pair of blocks, and ``kimi_vl_a3b_lm.score``'s two packed
+        rows of 8,192 without one. Each kind of pair is a body of its own in the
+        one kernel, the 64-wide product beside the 128-wide one in each: it fits
+        VMEM, and a layer's call is one custom call."""
         from tpu_tfrecord.models.attention import flash_attention_widths
 
-        q = jax.ShapeDtypeStruct((rows, heads, l, 192), jnp.bfloat16, sharding=one_chip)
-        v = jax.ShapeDtypeStruct((rows, heads, l, 128), jnp.bfloat16, sharding=one_chip)
+        q, q_rope, k_rope = (jax.ShapeDtypeStruct((rows, h, l, d), jnp.bfloat16, sharding=one_chip)
+                             for h, d in ((heads, 128), (heads, 64), (1, 64)))
         segs = jax.ShapeDtypeStruct((rows, l), jnp.int32, sharding=one_chip)
         keep = [jax.ShapeDtypeStruct((rows, l, l), jnp.int8, sharding=one_chip)] * selection
-        compiled = jax.jit(lambda q, k, v, s, *m: flash_attention_widths(
-            q, k, v, s, 0.135, 1024, 1024, *m)).lower(q, q, v, segs, *keep).compile()
+        compiled = jax.jit(lambda q, k, v, s, q_rope, k_rope, *m: flash_attention_widths(
+            q, k, v, s, 0.135, 1024, 1024, *m, q_rope=q_rope, k_rope=k_rope)).lower(
+                q, q, q, segs, q_rope, k_rope, *keep).compile()
         assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") == 1
         assert compiled.memory_analysis().output_size_in_bytes == 2 * rows * heads * l * 128
+
+    @pytest.mark.parametrize("rows, l, widths", [
+        (1, 16384, dict(d_model=7168, n_heads=128, q_rank=1536, rope_theta=10000.0,
+                        rope_scaling=(40.0, 4096.0, 32.0, 1.0))),
+        (2, 8192, dict(d_model=2048, n_heads=16, rope_theta=800000.0))],
+        ids=["deepseek_v32_exp_ep16", "kimi_vl_a3b_lm"])
+    def test_a_latent_attention_layer_joins_nothing_in_memory(self, one_chip, monkeypatch, rows, l, widths):
+        """The mixer alone at the two cells' shapes (compressed queries and YaRN, and
+        neither), compiled for the chip as a TPU runs it: each of the five arrays
+        the attention reads is written by its projection and handed to the kernel
+        as it is. No four-axis array as wide as both parts of q and k (192, laid
+        out 256 wide) or as the keys beside the values (256) is in the program, no
+        rotary key copied to every head, and the kernel is there."""
+        monkeypatch.setattr(lm.jax, "default_backend", lambda: "tpu")     # the described chip's branch
+        cfg = lm.PatternLMConfig(
+            vocab_size=256, layer_pattern=("mla",), ffn_pattern=("dense",), qk_nope_dim=128,
+            qk_rope_dim=64, v_head_dim=128, kv_rank=512, max_len=l, attn_block=1024,
+            dtype=jnp.bfloat16, **widths)
+        layer = lm.pattern_param_shapes(cfg)["layers"][0]
+        p = {name: jax.ShapeDtypeStruct(*layer[name], sharding=one_chip)
+             for name in ("attn_norm", "wq_a", "q_norm", "wq_b", "wq", "wkv_a", "kv_norm", "wkv_b", "wo")
+             if name in layer}
+        x = jax.ShapeDtypeStruct((rows, l, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((rows, l), jnp.int32, sharding=one_chip)
+        text = jax.jit(lambda p, x, s: lm.mla_mixer(p, x, s, cfg)).lower(p, x, segs).compile().as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+        heads = rf"\[{rows},{cfg.n_heads},{l},"
+        assert not re.findall(heads + r"(192|256)\]", text)
+        assert re.findall(heads + r"128\]", text) and re.findall(heads + r"64\]", text)
+        assert re.findall(rf"\[{rows},1,{l},64\]", text)                 # the rotary keys: one head
 
     def test_the_windowed_attention_kernel_at_the_cells_shape(self, one_chip):
         """``trinity_large_ep8.score``'s sliding layers: 48 query heads on 8

@@ -188,7 +188,8 @@ _VMEM_LIMIT = 64 * 2 ** 20  # two heads' blocks, twice, and their scratch: 11 MB
 
 def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref,
                          o_ref, m_ref, l_ref, acc_ref, *, scale: float, block_q: int,
-                         block_k: int, keep_ref=None, window: Optional[int] = None):
+                         block_k: int, keep_ref=None, window: Optional[int] = None,
+                         q_rope_ref=None, k_rope_ref=None):
     """One (query block, key block) pair of the grid step's heads: scores
     stay on the chip, the running maximum and sum are kept 128 lanes wide
     (every lane the same), the weighted values are divided by the sum once,
@@ -208,7 +209,13 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
     to its last query and no others (a square block computes 10 of its 16
     [256, 256] tiles) and compares positions too. ``keep_ref`` [1, block_q,
     block_k] int8, where given, narrows either kind's mask to the pairs it
-    marks non-zero: one block of it serves the step's heads. With a
+    marks non-zero: one block of it serves the step's heads.
+    ``q_rope_ref`` / ``k_rope_ref``, where given, are a second part of the
+    queries and keys (latent attention's rotary part, the keys' of one head
+    for all or of one a key head): a pass's scores are the product over the
+    first part plus the product over the second, float32 both, before the
+    scale and the mask; without them the body is what it was, operation for
+    operation. With a
     ``window`` (a query sees its own key and the ``window - 1`` before it)
     the grid walks the band alone (:func:`_grid_pairs`) and a fourth kind
     joins: *the band's trailing blocks*, which reach behind some row's
@@ -245,9 +252,9 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def held(head):
-        """The key head a query head of the step attends over."""
-        share = heads // k_ref.shape[1]
+    def held(head, ref=k_ref):
+        """The key head of ``ref`` a query head of the step attends over."""
+        share = heads // ref.shape[1]
         return head if share == 1 else lax.div(head, share)
 
     def pair(origin, far=None):
@@ -267,9 +274,14 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
             the block's keys ``skip`` to ``keys``, scaled and masked. (``lax`` by name
             in the two halves: a ``jnp`` function is a jitted one, and tracing
             hundreds of them is seconds of every program's set-up on the chip's host.)"""
-            out = lax.mul(lax.dot_general(
+            out = lax.dot_general(
                 q_ref[0, head, at:at + rows], k_ref[0, held(head), skip:keys],
-                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32), scale)
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            if q_rope_ref is not None:    # the two parts' products, summed in float32
+                out = lax.add(out, lax.dot_general(
+                    q_rope_ref[0, head, at:at + rows], k_rope_ref[0, held(head, k_rope_ref), skip:keys],
+                    (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32))
+            out = lax.mul(out, scale)
             wide = keys - skip
             seen = [jnp.tile(qseg_ref[0, at:at + rows], (1, wide // _LANES)) == kseg_ref[0, :1, skip:keys]]
             if origin is not None:    # the pass's last keys are its own rows
@@ -332,11 +344,14 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
         o_ref[0] = (acc_ref[...] / total).astype(o_ref.dtype)
 
 
-def _flash_widths_kept_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_ref, k_ref,
-                              v_ref, keep_ref, o_ref, m_ref, l_ref, acc_ref, **cut):
-    """:func:`_flash_widths_kernel` with a selection: ``keep_ref`` is its last input."""
-    _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_ref, l_ref, acc_ref, keep_ref=keep_ref, **cut)
+def _flash_widths_kernel_with(*names):
+    """:func:`_flash_widths_kernel` with the inputs ``names`` (of ``q_rope_ref``,
+    ``k_rope_ref``, ``keep_ref``) after ``v_ref``, in that order."""
+    def kernel(*refs, **cut):
+        # the four tables and five inputs every call has; these; the output and the scratch
+        fixed, more, rest = refs[:9], refs[9:9 + len(names)], refs[9 + len(names):]
+        _flash_widths_kernel(*fixed, *rest, **dict(zip(names, more)), **cut)
+    return kernel
 
 
 def _pair_kind(lo, hi, bi, qi, ki, per: int):
@@ -393,13 +408,21 @@ def pair_kinds(segments, block_q: int = 1024, block_k: int = 1024, window: Optio
 
 
 def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
-                           block_k: int = 1024, keep=None, window: Optional[int] = None):
+                           block_k: int = 1024, keep=None, window: Optional[int] = None,
+                           q_rope=None, k_rope=None):
     """Causal attention inside ``segments`` as a Pallas TPU kernel, for
-    queries and keys of one width and values of another (latent attention:
-    192 against 128; JAX's own flash kernel takes one width, and only 128s).
+    queries and keys of one width and values of another (JAX's own flash
+    kernel takes one width, and only 128s).
     q [B, H, L, D], k [B, Hkv, L, D], v [B, Hkv, L, Dv], segments [B, L]
     -> [B, H, L, Dv] in q's dtype. L is whole blocks, ``block_q`` whole
-    ``block_k``s; ``block_k`` and Dv are whole 128s. The grid walks the
+    ``block_k``s; ``block_k`` and Dv are whole 128s. ``q_rope`` [B, H, L, R]
+    and ``k_rope`` [B, 1 or Hkv, L, R] are a second part of the queries and
+    keys (latent attention: 128 plain columns in ``q`` and ``k``, 64 rotary
+    ones here, the keys' the same for every head): a score is the product
+    over ``D`` plus the product over ``R``, summed in float32, so the parts
+    are never joined in memory, the one rotary key head is never copied to
+    H, and each operand is read as the projection wrote it; ``scale``
+    multiplies the sum. The grid walks the
     block pairs at or under the diagonal; what a pair costs follows what it
     holds (:func:`_flash_widths_kernel`: nothing where the blocks share no
     document, as most pairs under the diagonal of packed rows of many
@@ -419,13 +442,17 @@ def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
                          f"the kernel wants whole blocks and whole {_LANES}s")
     if window is not None and window < 1:
         raise ValueError(f"a window of {window} keys: a query sees at least its own")
-    return _flash_widths_call(q, k, v, segments, keep, scale=float(scale), block_q=block_q,
-                              block_k=block_k, window=window)
+    if (q_rope is None) != (k_rope is None) or (
+            k_rope is not None and k_rope.shape[1] not in (1, k.shape[1])):
+        raise ValueError("a second part of the queries comes with one of the keys, of one head "
+                         f"or of the keys' {k.shape[1]}")
+    return _flash_widths_call(q, k, v, segments, keep, q_rope, k_rope, scale=float(scale),
+                              block_q=block_q, block_k=block_k, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k", "window"))
-def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, block_k: int,
-                       window: Optional[int] = None):
+def _flash_widths_call(q, k, v, segments, keep, q_rope=None, k_rope=None, *, scale: float,
+                       block_q: int, block_k: int, window: Optional[int] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -435,7 +462,6 @@ def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, b
     lo, hi = by_block.min(axis=-1), by_block.max(axis=-1)
     per = block_q // block_k
     heads = next(n for n in _HEADS if h % n == 0 and (rep % n == 0 or n % rep == 0))
-    held = max(1, heads // rep)     # key heads a grid step's query heads attend over
     pairs = _grid_pairs(l, block_q, block_k, window)     # the grid's third axis
 
     def key_block(bi, t, lo_ref, hi_ref, qi_ref, ki_ref):
@@ -447,14 +473,24 @@ def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, b
     def queries(bi, hi, t, lo_ref, hi_ref, qi_ref, ki_ref):
         return bi, hi, qi_ref[t], 0
 
-    def keys(bi, hi, t, *tables):
-        return bi, hi * heads // rep // held, key_block(bi, t, *tables), 0
+    def keys_of(a):
+        """The block of ``a`` [B, heads of its own, L, .] a grid step's query heads
+        attend over: one head for all, or one a group of them."""
+        share = h // a.shape[1]
+        held = max(1, heads // share)     # key heads a grid step's query heads attend over
+        return pl.BlockSpec((1, held, block_k, a.shape[-1]), lambda bi, hi, t, *tables: (
+            bi, hi * heads // share // held, key_block(bi, t, *tables), 0))
 
+    more = {}     # the inputs a call may have beside q, k and v
+    if q_rope is not None:
+        more["q_rope_ref"] = (q_rope, pl.BlockSpec((1, heads, block_q, q_rope.shape[-1]), queries))
+        more["k_rope_ref"] = (k_rope, keys_of(k_rope))
+    if keep is not None:
+        more["keep_ref"] = (keep, pl.BlockSpec(
+            (1, block_q, block_k), lambda bi, hi, t, *r: (bi, r[2][t], key_block(bi, t, *r))))
     kernel = functools.partial(
-        _flash_widths_kernel if keep is None else _flash_widths_kept_kernel,
+        _flash_widths_kernel_with(*more) if more else _flash_widths_kernel,
         scale=scale, block_q=block_q, block_k=block_k, window=window)
-    selection = [] if keep is None else [(keep, pl.BlockSpec(
-        (1, block_q, block_k), lambda bi, hi, t, *r: (bi, r[2][t], key_block(bi, t, *r))))]
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -465,9 +501,9 @@ def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, b
                 pl.BlockSpec((1, _SUBLANES, block_k),
                              lambda bi, hi, t, *r: (bi, 0, key_block(bi, t, *r))),
                 pl.BlockSpec((1, heads, block_q, d), queries),
-                pl.BlockSpec((1, held, block_k, d), keys),
-                pl.BlockSpec((1, held, block_k, dv), keys),
-                *[spec for _, spec in selection],
+                keys_of(k),
+                keys_of(v),
+                *[spec for _, spec in more.values()],
             ],
             out_specs=pl.BlockSpec((1, heads, block_q, dv), queries),
             scratch_shapes=[pltpu.VMEM((heads, block_q, _LANES), jnp.float32),
@@ -482,7 +518,7 @@ def _flash_widths_call(q, k, v, segments, keep, *, scale: float, block_q: int, b
         return call(
             lo, hi, pairs[:, 0], pairs[:, 1], jnp.broadcast_to(segments[:, :, None], (b, l, _LANES)),
             jnp.broadcast_to(segments[:, None, :], (b, _SUBLANES, l)), q, k, v,
-            *[a for a, _ in selection])
+            *[a for a, _ in more.values()])
 
 
 def _ring_attention_local(

@@ -992,22 +992,27 @@ def _takes_kernel(l: int, dv: int, block: int) -> bool:
 
 def _attend(q, k, v, segments, block: int, scale=None, keep=None, window=None):
     """Causal attention inside each document; q [B, H, L, D], k [B, Hkv, L, D],
-    v [B, Hkv, L, Dv] -> [B, H, L, Dv], scores scaled by ``scale`` (D ** -0.5
-    where none is given), over the keys ``keep`` [B, L, L] marks non-zero
-    (every key of the document at or before the query where none is given:
-    ``sparse_attn.select_keys`` makes one) and, with a ``window``, of those
-    the query's own and the ``window - 1`` before it. On a TPU,
+    v [B, Hkv, L, Dv] -> [B, H, L, Dv], over the keys ``keep`` [B, L, L] marks
+    non-zero (every key of the document at or before the query where none is
+    given: ``sparse_attn.select_keys`` makes one) and, with a ``window``, of
+    those the query's own and the ``window - 1`` before it. ``q`` and ``k`` may
+    each be a pair of arrays, a plain part and a second part (latent attention's
+    rotary part: (q [B, H, L, D], q_rope [B, H, L, R]) and (k [B, Hkv, L, D],
+    k_rope [B, 1, L, R]), the keys' one head shared by all): a score is the
+    product over D plus the product over R. Scores are scaled by ``scale``
+    ((D + R) ** -0.5 where none is given). On a TPU,
     for rows of whole blocks of 128s, a Pallas kernel: JAX's own where q, k
     and v share a width of whole 128s (it takes no other) and nothing but
-    the document narrows the keys (it has no window and no selection, and
-    is handed K and V copied once a query head), and
-    ``attention.flash_attention_widths`` otherwise: where the values are
-    narrower than the keys (latent attention: 192 against 128), under a
-    selection, and under a window, where its grid walks the band of block
-    pairs alone (a 4,096-key window over 32,768 tokens in blocks of 1,024:
-    150 pairs of 528, of a query block's five the oldest compared against
-    the window, the newest against the diagonal, the three between
-    neither; grouped heads read their one K and V). That kernel sees from two
+    the document narrows the keys (it has no window, no selection and no
+    second part, and is handed K and V copied once a query head), and
+    ``attention.flash_attention_widths`` otherwise: with a second part, which
+    it is handed as it is, so that no array as wide as both parts exists and
+    the one rotary key head is read by every head's block from where it
+    lies; under a selection; and under a window, where its grid walks the
+    band of block pairs alone (a 4,096-key window over 32,768 tokens in
+    blocks of 1,024: 150 pairs of 528, of a query block's five the oldest
+    compared against the window, the newest against the diagonal, the three
+    between neither; grouped heads read their one K and V). That kernel sees from two
     block indices and four segment ids what a pair of blocks needs: nothing;
     segment ids and no position (under the diagonal: 120 of the 136 pairs of
     a 16,384-token document, where every key is seen and the compare is
@@ -1017,13 +1022,23 @@ def _attend(q, k, v, segments, block: int, scale=None, keep=None, window=None):
     every kind. Elsewhere (the kernels exist for no other backend), and for
     shapes neither takes, ``attention.blockwise_attention``: plain JAX, the
     same mask, the same answer (tests/test_pattern_lm.py,
-    tests/test_mla_lm.py and tests/test_dsa_lm.py hold each kernel to it)."""
+    tests/test_mla_lm.py and tests/test_dsa_lm.py hold each kernel to it);
+    there, and only there, the two parts are joined and the rotary key head
+    is copied to every head."""
+    parts = {}
+    if isinstance(q, tuple):
+        (q, parts["q_rope"]), (k, parts["k_rope"]) = q, k
     (l, d), dv = q.shape[2:], v.shape[-1]
     if _takes_kernel(l, dv, block):
-        if d == dv and scale is None and keep is None and window is None:
+        if d == dv and scale is None and keep is None and window is None and not parts:
             return _flash_attend(q, k, v, segments, block)
-        return flash_attention_widths(q, k, v, segments, scale or d ** -0.5, block, block, keep=keep,
-                                      window=window)
+        width = d + (parts["q_rope"].shape[-1] if parts else 0)
+        return flash_attention_widths(q, k, v, segments, scale or width ** -0.5, block, block,
+                                      keep=keep, window=window, **parts)
+    if parts:
+        q_rope, k_rope = parts["q_rope"], parts["k_rope"]
+        q = jnp.concatenate([q, q_rope], axis=-1)
+        k = jnp.concatenate([k, jnp.broadcast_to(k_rope, k.shape[:3] + k_rope.shape[3:])], axis=-1)
     out = blockwise_attention(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segments,
         scale=scale, block=block, keep=keep, window=window)
@@ -1125,14 +1140,23 @@ def mla_mixer(p, x, segments, cfg: PatternLMConfig):
     causal softmax inside each document over ``qk_nope_dim + qk_rope_dim``
     wide queries and keys against ``v_head_dim`` wide values; with
     ``cfg.index_topk`` over the keys an indexer chose (:func:`index_select`).
-    x [B, L, D]. Heads are written head-major ``[B, H, L, .]`` by the projections."""
+    x [B, L, D]. Heads are written head-major ``[B, H, L, .]`` by the
+    projections, and each of the four things the attention reads is an array
+    of its own: the plain queries and keys (``qk_nope_dim``), the values, the
+    rotary queries and the one rotary key head (``qk_rope_dim``), from the
+    plain and the rotary columns of ``wq_b`` (or ``wq``) and the key and the
+    value columns of ``wkv_b``, taken as views of the weights. A score is
+    ``q_nope . k_nope + q_pe . k_pe``, so nothing is gained by joining them
+    first: a joined array would be written, read and written again around the
+    rotary turn, the shared rotary key stored once a head, and the values cut
+    out of a wider array by a copy (:func:`_attend` hands the parts on)."""
     return mla_mixer_probed(p, x, segments, cfg)[0]
 
 
 def mla_mixer_probed(p, x, segments, cfg: PatternLMConfig, sample_at=None):
     """(:func:`mla_mixer`'s y, :func:`index_select`'s record of the selection
     or None where the layer has no indexer)."""
-    (b, l, d), h = x.shape, cfg.n_heads
+    d, h = x.shape[-1], cfg.n_heads
     dn, dr, dv, rank = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, cfg.kv_rank
     yarn = (tuple(cfg.rope_scaling),) if cfg.rope_scaling else ()
 
@@ -1143,23 +1167,25 @@ def mla_mixer_probed(p, x, segments, cfg: PatternLMConfig, sample_at=None):
         u = weighted_rms_norm(x, p["attn_norm"], cfg.norm_eps)
         if cfg.q_rank:
             c_q = weighted_rms_norm(u @ p["wq_a"], p["q_norm"], cfg.norm_eps)
-            q = jnp.einsum("blr,rhk->bhlk", c_q, p["wq_b"].reshape(cfg.q_rank, h, dn + dr))
+            q_in, wq = c_q, p["wq_b"].reshape(cfg.q_rank, h, dn + dr)
         else:
-            q = jnp.einsum("bld,dhk->bhlk", u, p["wq"].reshape(d, h, dn + dr))
+            q_in, wq = u, p["wq"].reshape(d, h, dn + dr)
         latent = u @ p["wkv_a"]                                          # [B, L, rank + dr]
         c = weighted_rms_norm(latent[..., :rank], p["kv_norm"], cfg.norm_eps)
-        kv = jnp.einsum("blr,rhk->bhlk", c, p["wkv_b"].reshape(rank, h, dn + dv))
+        wkv = p["wkv_b"].reshape(rank, h, dn + dv)
+        k = jnp.einsum("blr,rhk->bhlk", c, wkv[..., :dn])
+        v = jnp.einsum("blr,rhk->bhlk", c, wkv[..., dn:])
         at = segment_positions(segments)
-        k_pe = turn(latent[:, None, :, rank:])
-        q = jnp.concatenate([q[..., :dn], turn(q[..., dn:])], axis=-1)
-        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (b, h, l, dr))], axis=-1)
+        k_pe = turn(latent[:, None, :, rank:])                           # one head, for all
+        q = jnp.einsum("bld,dhk->bhlk", q_in, wq[..., :dn])
+        q_pe = turn(jnp.einsum("bld,dhk->bhlk", q_in, wq[..., dn:]))
     chosen, index = {}, None
     if yarn:
         chosen["scale"] = (dn + dr) ** -0.5 * _sa.yarn_softmax_gain(cfg.rope_scaling)
     if cfg.index_topk:
         chosen["keep"], index = index_select(p, u, c_q, turn, at, segments, cfg, sample_at)
     with jax.named_scope("tfr.mla_attn"):
-        att = _attend(q, k, kv[..., dn:], segments, cfg.attn_block, **chosen)
+        att = _attend((q, q_pe), (k, k_pe), v, segments, cfg.attn_block, **chosen)
     with jax.named_scope("tfr.mla_proj"):
         return jnp.einsum("bhlk,hkd->bld", att, p["wo"].reshape(h, dv, d)), index
 
@@ -1392,6 +1418,9 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     in_kernel = cfg.index_topk and _sa.select_tile(
         (tokens.shape[0], cfg.index_heads, cfg.max_len, cfg.index_dim), cfg.index_topk) is not None
     METRICS.gauge("dsa.kernel_layers", cfg.layer_pattern.count("mla") if in_kernel else 0)
+    # and the attention call of every latent-attention layer: handed q and k in their two parts
+    in_kernel = _takes_kernel(cfg.max_len, cfg.v_head_dim, cfg.attn_block)
+    METRICS.gauge("mla.split_layers", cfg.layer_pattern.count("mla") if in_kernel else 0)
     if "swa" in cfg.layer_pattern:  # and the window: one shape for every sliding layer
         in_kernel = _takes_kernel(cfg.max_len, cfg.head_dim, cfg.attn_block)
         METRICS.gauge("swa.kernel_layers", cfg.layer_pattern.count("swa") if in_kernel else 0)

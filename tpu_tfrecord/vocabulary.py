@@ -210,6 +210,7 @@ GAUGES: Dict[str, str] = {
     "moe.visits_max_over_mean": "held experts, latest step: the busiest over the mean (most uneven layer)",
     "kda.fused_layers": "pattern LM, the score program last traced: delta-rule layers whose recurrence took the Pallas kernel (0 off a TPU)",
     "dsa.kernel_layers": "pattern LM, the score program last traced: latent-attention layers whose selection took the Pallas kernel (0 off a TPU and without an indexer)",
+    "mla.split_layers": "pattern LM, the score program last traced: latent-attention layers whose attention kernel was handed q and k in their two parts, plain and rotary, never joined in memory (0 off a TPU)",
     "dsa.selected_share": "pattern LM, latest step recorded: keys the indexers kept over the causal candidates they chose from (lm.record_selected)",
     "mla.plain_pair_share": "pattern LM, latest step recorded: of the block pairs the latent-attention kernel computes, those wholly under the diagonal of one document, where every key is seen (lm.record_pair_kinds)",
     "swa.kernel_layers": "pattern LM, the score program last traced: sliding-window layers whose attention took the Pallas kernel under a window (0 off a TPU)",
