@@ -145,3 +145,82 @@ def test_fast_decays_do_not_overflow_the_chunk(rate, chunk):
         got = chunked(q, k, v, log_decay, beta, segs, scale=0.25, chunk=chunk)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# One decay a head and token, key heads shared by their value heads
+# ---------------------------------------------------------------------------
+
+
+def grouped_scalar_inputs(seed, length, h=4, group=2, d=16, b=2, rates=None):
+    """:func:`delta_rule_inputs` with q and k at ``h / group`` heads and one
+    log-decay a head and token ([B, H, L]); ``rates``: the decays a token, drawn
+    from these where given (near 0: exp(-9); near 1: exp(-1e-4))."""
+    q, k, v, log_decay, beta, segs = delta_rule_inputs(seed, length, b=b, h=h, d=d)
+    scalar = log_decay[..., 0]
+    if rates is not None:
+        picks = np.random.default_rng(seed).integers(len(rates), size=scalar.shape)
+        scalar = -jnp.asarray(np.asarray(rates, np.float32)[picks])
+    return q[:, : h // group], k[:, : h // group], v, scalar, beta, segs
+
+
+def short_documents(length, seed=0):
+    """A row of documents of 1 to 40 tokens (shorter than a chunk of 64, so
+    that boundaries fall inside every chunk), then a pad tail."""
+    rng, segs, at, s = np.random.default_rng(seed), np.zeros((1, length), np.int32), 0, 1
+    while at < length - 9:
+        n = int(rng.integers(1, 41))
+        segs[0, at:at + n] = s
+        at, s = min(at + n, length - 9), s + 1
+    segs[0, length - 9:] = 0
+    return jnp.asarray(segs)
+
+
+@pytest.mark.parametrize("chunk", [4, 16, 64])
+@pytest.mark.parametrize("h,group", [(4, 2), (6, 3), (4, 1), (32, 2)])
+def test_one_decay_a_token_and_shared_key_heads_are_the_recurrence_and_the_broadcast(h, group, chunk):
+    """The plain form handed a decay [B, H, L] and q, k at H / group heads: the
+    token-by-token recurrence, and what the per-channel form gives when both
+    broadcasts are written out for it."""
+    q, k, v, scalar, beta, segs = grouped_scalar_inputs(h + chunk, 150, h=h, group=group)
+    want = recurrent(q, k, v, scalar, beta, segs, scale=0.25)
+    got = chunked(q, k, v, scalar, beta, segs, scale=0.25, chunk=chunk)
+    assert got.shape == v.shape
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    spread = chunked(jnp.repeat(q, group, axis=1), jnp.repeat(k, group, axis=1), v,
+                     jnp.broadcast_to(scalar[..., None], v.shape), beta, segs, scale=0.25, chunk=chunk)
+    np.testing.assert_allclose(got, spread, atol=5e-6)
+
+
+@pytest.mark.parametrize("length,tile,h,group", [(256, 128, 2, 2), (512, 256, 4, 2), (256, 128, 3, 1),
+                                                 (256, 256, 4, 4), (384, 128, 2, 1)])
+def test_the_kernel_under_one_decay_a_token_is_the_recurrence_and_the_plain_form(length, tile, h, group):
+    """The interpreted kernel in its other form: the decay laid out as beta
+    is, a grid step's two value heads reading one key head (or each its own,
+    or four one), q, k and v handed over in bfloat16 as a convolution writes
+    them."""
+    q, k, v, scalar, beta, segs = grouped_scalar_inputs(length + h, length, h=h, group=group, d=128, b=1)
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
+    want = recurrent(q, k, v, scalar, beta, segs, scale=0.25)
+    plain = chunked(q, k, v, scalar, beta, segs, scale=0.25, chunk=64)
+    got = interpreted_kernel(q, k, v, scalar, beta, segs, 0.25, tile)
+    assert got.shape == v.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    np.testing.assert_allclose(got, plain, atol=5e-6)
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+@pytest.mark.parametrize("rates", [(9.0, 1e-4), (1e-4,), (9.0, 3.0)], ids=["both", "near_one", "near_zero"])
+def test_short_documents_under_decays_near_0_and_near_1(rates, form):
+    """Documents shorter than a chunk (a boundary inside every chunk, several
+    inside most), tokens that forget everything (a decay of e^-9) beside
+    tokens that forget nothing (e^-0.0001): one exponent a pair, never positive."""
+    d, b = (16, 2) if form == "plain" else (128, 1)
+    q, k, v, scalar, beta, _ = grouped_scalar_inputs(9, 256, h=4, group=2, d=d, b=b, rates=rates)
+    segs = jnp.concatenate([short_documents(256, seed=s) for s in range(b)])
+    assert (np.diff(np.asarray(segs)) != 0).sum() >= 8 * b
+    want = recurrent(q, k, v, scalar, beta, segs, scale=0.25)
+    got = (chunked(q, k, v, scalar, beta, segs, scale=0.25, chunk=64) if form == "plain"
+           else interpreted_kernel(q, k, v, scalar, beta, segs, 0.25, 256))
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=1e-5)

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_tfrecord.models import lm, mla_reference as ref, moe
+from tpu_tfrecord.models import linear_attn, lm, mla_reference as ref, moe
 from tpu_tfrecord.models.attention import blockwise_attention, flash_attention_widths, pair_kinds
 
 from test_pattern_lm import (SAMPLE_AT, documents_of, flat, held_experts, init_params,
@@ -469,7 +469,7 @@ def test_the_shares_of_64_experts_held_8_by_8_add_up_to_the_uncut_layer():
 
 
 def test_an_unknown_mixer_or_feed_forward_part_is_named_with_the_ones_there_are():
-    with pytest.raises(ValueError, match=r"\('gqa', 'kda', 'mla', 'swa'\)"):
+    with pytest.raises(ValueError, match=r"\('gqa', 'kda', 'mla', 'swa', 'gdn'\)"):
         lm.pattern_param_shapes(lm.PatternLMConfig(layer_pattern=("mla", "rope")))
     with pytest.raises(ValueError, match=r"\('moe', 'dense'\)"):
         lm.pattern_param_shapes(lm.PatternLMConfig(layer_pattern=("mla",), ffn_pattern=("ffn",)))
@@ -536,6 +536,29 @@ def older_program(name):
 def test_the_older_patterns_program_is_the_one_it_was(name):
     program = older_program(name)
     assert hashlib.sha256(str(program).encode()).hexdigest() == OLDER_PROGRAMS[name]
+
+
+#: sha256 of ``str(jax.make_jaxpr(f))`` for ``linear_attn._delta_rule_fused`` handed Solar's
+#: operands (a decay a channel, as many key heads as value heads, float32): two heads a grid step
+#: at tiles of 256, one at 128, recorded at PR 40, before the kernel took its second form. The
+#: whole call: the body, the block specs and their index maps.
+SOLARS_KERNEL = {
+    (2, 256, 512): "976ccc2265517c85ad1fdcf51a5a667a3b2d0d3a74262538325ef19e95940c57",
+    (3, 128, 256): "dcaac5e29124947d8dd8f63f5da1fff96080f848bb2927216c166adc0869ade9",
+}
+
+
+@pytest.mark.parametrize("heads,tile,length", list(SOLARS_KERNEL))
+def test_solars_kernel_is_the_one_it_was(heads, tile, length):
+    """A decay of one number a token and shared key heads are static properties
+    of the operands: handed a decay a channel and its own key head for every
+    value head, the kernel is traced operation for operation as it was."""
+    x = jax.ShapeDtypeStruct((1, heads, length, 128), jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, heads, length), jnp.float32)
+    segs = jax.ShapeDtypeStruct((1, length), jnp.int32)
+    program = jax.make_jaxpr(lambda q, k, v, g, b, s: linear_attn._delta_rule_fused(
+        q, k, v, g, b, s, 0.25, tile, interpret=True))(x, x, x, x, beta, segs)
+    assert hashlib.sha256(str(program).encode()).hexdigest() == SOLARS_KERNEL[heads, tile, length]
 
 
 def test_the_benchmarks_copy_of_the_reference_is_this_one():

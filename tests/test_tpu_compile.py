@@ -185,6 +185,31 @@ class TestOneChip:
         assert mem.output_size_in_bytes == 4 * np.prod(shape)
         assert mem.temp_size_in_bytes < 4 * np.prod(shape) // 8
 
+    def test_the_delta_rule_kernel_under_one_decay_a_token_at_the_cells_shape(self, one_chip):
+        """``gigachat35_ep16.score``'s delta-net layer: v [2, 64, 8192, 128] over q
+        and k at 32 heads, all three bfloat16 as the convolution writes them, a
+        decay and a beta [2, 64, 8192]. The chip's compiler takes the kernel's
+        other form (lane rolls of a row, bfloat16 blocks, a key head's block
+        index), and the program's arguments are what the mechanism has: no
+        copy of q or k to 64 heads, no decay of v's shape, nothing float32 of
+        v's size but the output."""
+        from tpu_tfrecord.models import linear_attn
+
+        b, h, hk, l, d = 2, 64, 32, 8192, 128
+        keys = jax.ShapeDtypeStruct((b, hk, l, d), jnp.bfloat16, sharding=one_chip)
+        values = jax.ShapeDtypeStruct((b, h, l, d), jnp.bfloat16, sharding=one_chip)
+        by_token = jax.ShapeDtypeStruct((b, h, l), jnp.float32, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((b, l), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(lambda q, k, v, g, bt, s: linear_attn._delta_rule_fused(
+            q, k, v, g, bt, s, d ** -0.5, linear_attn._TILES[0])).lower(
+                keys, keys, values, by_token, by_token, segs).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        assert mem.output_size_in_bytes == 4 * b * h * l * d
+        operands = 2 * (2 * b * hk * l * d + b * h * l * d) + 2 * 4 * b * h * l + 4 * b * l
+        assert mem.argument_size_in_bytes == operands          # 545 MB where the broadcasts are 2.1 GB
+        assert mem.temp_size_in_bytes < 4 * b * h * l * d // 8
+
     def test_the_solar_patterns_score_holds_no_loop_under_the_scan(self, one_chip, monkeypatch):
         """``lm.score`` for the softmax / delta-rule period at the cell's row
         shape and delta-rule widths (the rest narrow: this is about one

@@ -13,8 +13,15 @@ Per head (d_k = d_v = head_dim), for the tokens of ONE document::
     S_t = (I - b_t k_t k_t^T) diag(a_t) S_{t-1} + b_t k_t v_t^T     S_0 = 0
     o_t = S_t^T q_t * scale
 
-with a per-channel decay ``a_t = exp(log_decay_t)`` in (0, 1] and
-``b_t`` in (0, 2) (a negative eigenvalue of the transition is allowed).
+with a decay ``a_t = exp(log_decay_t)`` in (0, 1] and ``b_t`` in (0, 2) (a
+negative eigenvalue of the transition is allowed). The decay comes in two
+forms, told apart by ``log_decay``'s shape: one number a CHANNEL of the key
+(``[B, H, L, D]``, the equations as written) or one number a head and token
+(``[B, H, L]``: ``diag(a_t)`` is ``a_t I``), laid out as ``beta`` is and never
+spread over the channels in memory. Key heads may be fewer than value heads:
+q and k ``[B, Hk, L, D]`` with ``Hk`` dividing v's ``H``, value head h reading
+key head ``h // (H / Hk)`` from where it lies (a block index in the kernel, a
+view of a group's heads in the plain form), never copied ``H / Hk`` times.
 ``delta_rule_recurrent`` walks that recurrence token by token (the oracle
 the tests hold the chunked form to); ``delta_rule_chunked`` computes the
 same thing ``chunk`` tokens at a time:
@@ -34,7 +41,14 @@ of pairs between the two halves of a chunk at the boundary between them
 blocks of 16 tokens refer to their own middle, where a factor reaches
 e^(8 * max|log_decay|): the form holds for rates up to 10 a token (a decay
 of e^-10 a token leaves nothing to remember), where one reference point
-for a whole chunk of 64 overflowed float32 at 2.5.
+for a whole chunk of 64 overflowed float32 at 2.5. With one decay a head and
+token nothing has to be split: a pair's weight is the one number
+``e^(G_i - G_j)``, the exponent never positive for j <= i, times one product
+over the channels, ``(x k^T) * e^(G_i - G_j)``; of the 1,792 rows of float32
+products the kernel streams through the matrix unit for a pair of chunks and
+a head, the three reference levels are 512, and this form streams 256 in their
+place, once for the value heads that share a key head (1,536 a head alone,
+1,408 where two share: the kernel streams fewer rows for the scalar form).
 
 ``carry`` is 1 for the tokens whose document began before the chunk did:
 a token of a document that starts inside the chunk never sees the state
@@ -86,11 +100,17 @@ def short_conv(x, taps, segments):
 
 
 def delta_rule_recurrent(q, k, v, log_decay, beta, segments, scale):
-    """The recurrence token by token. q, k, v, log_decay [B, H, L, D],
-    beta [B, H, L], segments [B, L] -> o [B, H, L, D] float32. The state
-    is zeroed wherever ``segments`` changes."""
-    b, h, l, d = q.shape
+    """The recurrence token by token. v [B, H, L, D], q, k [B, Hk, L, D]
+    (``Hk`` divides ``H``), log_decay [B, H, L, D] or, one a head and token,
+    [B, H, L], beta [B, H, L], segments [B, L] -> o [B, H, L, D] float32. The
+    state is zeroed wherever ``segments`` changes. The oracle: it writes out
+    both broadcasts (module docstring) that the chunked form does without."""
+    b, h, l, d = v.shape
     f32 = jnp.float32
+    if q.shape[1] != h:
+        q, k = (jnp.repeat(a, h // a.shape[1], axis=1) for a in (q, k))
+    if log_decay.ndim == 3:
+        log_decay = log_decay[..., None]
     starts = jnp.concatenate(
         [jnp.ones((b, 1), bool), segments[:, 1:] != segments[:, :-1]], axis=1)
 
@@ -143,8 +163,14 @@ def _decayed_pairs(x, k, g):
     The block between the second half's rows and the first half's columns
     refers both sides to the first half's last token, so that both factors
     are at most 1; each half is treated the same way; a block of ``_BLOCK``
-    on the diagonal refers to its own middle (module docstring)."""
+    on the diagonal refers to its own middle (module docstring). With one
+    decay a token (g [..., C, 1]) nothing is split: the product over the
+    channels times ``exp(g_i - g_j)``, the exponent held at 0 above the
+    diagonal, where nothing is read."""
     c = x.shape[-2]
+    if g.shape[-1] == 1 and x.shape[-1] != 1:
+        weight = jnp.exp(jnp.minimum(g - jnp.swapaxes(g, -1, -2), 0.0))
+        return jnp.einsum("...ik,...jk->...ij", x, k, precision=_HIGHEST) * weight
 
     def product(rows, cols, at):
         ref = g[..., at: at + 1, :]
@@ -167,9 +193,12 @@ def _running_sum(g):
 
 
 def _chunked_heads(q, k, v, g, beta, seg, scale):
-    """The chunked form for some heads of ONE row: q, k, v, g (log-decay)
-    [H, n, C, D], beta [H, n, C], seg [n, C] -> o [H, n, C, D]."""
-    h, n, chunk, d = q.shape
+    """The chunked form for some heads of ONE row: v [H, n, C, D], q, k
+    [Hk, n, C, D] (a key head's value heads lie together), g (log-decay)
+    [H, n, C, D] or [H, n, C, 1], beta [H, n, C], seg [n, C] -> o [H, n, C, D]."""
+    h, n, chunk, d = v.shape
+    if q.shape[0] != h:  # these heads' keys, once a value head: what this form lays out anyway
+        q, k = (jnp.repeat(a, h // a.shape[0], axis=0) for a in (q, k))
     before = jnp.concatenate([jnp.full((1,), -2, seg.dtype), seg[:-1, -1]])   # the id before each chunk
     carry = seg == before[:, None]                                     # [n, C]
     same = seg[:, :, None] == seg[:, None, :]                          # [n, C, C]
@@ -259,14 +288,16 @@ def _back(y, half: int):
     return jnp.concatenate([jnp.zeros_like(y), y], axis=1).reshape(2 * m, w)
 
 
-def _pair_of_chunks(q, k, v, g, beta_row, seg_col, seg_row, before, scale):
+def _pair_of_chunks(q, k, v, g, beta_row, seg_col, seg_row, before, scale, qk=None):
     """What two chunks of 64 tokens need that does not touch the state
     (module docstring's G, A, P, T and the factors against the state):
     q, k, v, g [128, D]; beta_row, seg_row [1, 128]; seg_col, before
     [128, D] int32 (a token's segment and the id before its chunk, the same
     in every lane). Returns qs, tk, tv [128, D], p [128, 128] (zero between
     the chunks), keep^T per chunk [D, 64] and each chunk's decay of the
-    state [D, 1].
+    state [D, 1]. With one decay a token, g [1, 128] as ``beta_row`` lies and
+    ``qk`` = [q; k] k^T [256, 128], the product that every value head of a key
+    head shares: a pair's weight is one exponent (no levels, no product here).
 
     A generator: it yields after each matrix product, so that the kernel can
     emit several pairs' work in turn (:func:`_in_turn`).
@@ -290,26 +321,41 @@ def _pair_of_chunks(q, k, v, g, beta_row, seg_col, seg_row, before, scale):
     col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
     tok = jax.lax.broadcasted_iota(jnp.int32, (n, d), 0)
 
-    # the running sum of the log-decay inside each chunk
-    for s in (1, 2, 4, 8, 16, 32):
-        g = g + jnp.where((tok & (_KERNEL_CHUNK - 1)) >= s, _roll(g, s), 0.0)
+    if qk is None:
+        # the running sum of the log-decay inside each chunk
+        for s in (1, 2, 4, 8, 16, 32):
+            g = g + jnp.where((tok & (_KERNEL_CHUNK - 1)) >= s, _roll(g, s), 0.0)
 
-    # [q; k] against k with the decay from key to query, level by level: [256, 128]
-    both = jax.lax.broadcasted_iota(jnp.int32, (2 * n, n), 0) & (n - 1)         # q's rows, then k's
-    across = jax.lax.broadcasted_iota(jnp.int32, (2 * n, n), 1)
-    ref = _held(g, _BLOCK, (_BLOCK - 1) // 2)
-    raw = _dot(jnp.concatenate([q, k]) * jnp.tile(jnp.exp(g - ref), (2, 1)), k * jnp.exp(ref - g),
-               ((1,), (1,)))
-    raw = jnp.where(both >> 4 == across >> 4, raw, 0.0)
-    for shift in (5, 6):                   # a half's two blocks; a chunk's two halves
-        half = 1 << (shift - 1)
-        ref = _held(g, 2 * half, half - 1)
-        after = (tok & half) != 0
-        e = jnp.exp(jnp.where(after, g - ref, ref - g))                     # both at most 1
-        out = _dot(jnp.concatenate([_upper(q * e, half), _upper(k * e, half)]),
-                   k * jnp.where(after, 0.0, e), ((1,), (1,)))
-        # zero but for rows after and columns before a boundary: of those, one run's own
-        raw = raw + jnp.where(both >> shift == across >> shift, _back(out, half), 0.0)
+        # [q; k] against k with the decay from key to query, level by level: [256, 128]
+        both = jax.lax.broadcasted_iota(jnp.int32, (2 * n, n), 0) & (n - 1)         # q's rows, then k's
+        across = jax.lax.broadcasted_iota(jnp.int32, (2 * n, n), 1)
+        ref = _held(g, _BLOCK, (_BLOCK - 1) // 2)
+        raw = _dot(jnp.concatenate([q, k]) * jnp.tile(jnp.exp(g - ref), (2, 1)), k * jnp.exp(ref - g),
+                   ((1,), (1,)))
+        raw = jnp.where(both >> 4 == across >> 4, raw, 0.0)
+        for shift in (5, 6):                   # a half's two blocks; a chunk's two halves
+            half = 1 << (shift - 1)
+            ref = _held(g, 2 * half, half - 1)
+            after = (tok & half) != 0
+            e = jnp.exp(jnp.where(after, g - ref, ref - g))                     # both at most 1
+            out = _dot(jnp.concatenate([_upper(q * e, half), _upper(k * e, half)]),
+                       k * jnp.where(after, 0.0, e), ((1,), (1,)))
+            # zero but for rows after and columns before a boundary: of those, one run's own
+            raw = raw + jnp.where(both >> shift == across >> shift, _back(out, half), 0.0)
+    else:
+        # the running sum along the lanes, where the tokens of a row lie; then the same
+        # sum down the rows (the diagonal of its own copy in every row), and from there
+        # on g is what a per-channel decay's is: [128, D], here the same in every lane
+        lane = jax.lax.broadcasted_iota(jnp.int32, (8, n), 1)
+        g = jnp.broadcast_to(g, (8, n))
+        for s in (1, 2, 4, 8, 16, 32):
+            g = g + jnp.where((lane & (_KERNEL_CHUNK - 1)) >= s, _roll(g, s, 1), 0.0)
+        along = jnp.broadcast_to(g[:1], (n, n))                                 # G_j in column j
+        g = jnp.sum(jnp.where(row == col, along, 0.0), axis=1, keepdims=True)   # G_i in row i
+        # e^(G_i - G_j) inside a chunk; the exponent is held at 0 above the diagonal
+        weight = jnp.where((row ^ col) < _KERNEL_CHUNK, jnp.exp(jnp.minimum(g - along, 0.0)), 0.0)
+        raw = qk * jnp.concatenate([weight, weight])
+        g = jnp.broadcast_to(g, (n, d))
 
     yield
     same = seg_col[:, :n] == seg_row
@@ -404,7 +450,9 @@ def _delta_rule_kernel(seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, bet
                        state_ref, before_ref, *, scale: float):
     """One tile of the heads of a grid step: every pair of chunks laid out in
     VMEM, then each head's chunks in order against its state, which a
-    scratch carries from tile to tile."""
+    scratch carries from tile to tile. ``q_ref`` and ``k_ref`` hold the key
+    heads these value heads read (as many, or one for all); ``g_ref`` lies as
+    ``v_ref`` does (a decay a channel) or as ``beta_ref`` (one a token)."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
@@ -412,17 +460,27 @@ def _delta_rule_kernel(seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, bet
         state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
         before_ref[...] = jnp.full(before_ref.shape, -2, jnp.int32)
 
-    heads, tile, d = q_ref.shape[1:]
-    half = _KERNEL_CHUNK
+    heads, tile, d = v_ref.shape[1:]
+    shared = heads // q_ref.shape[1]          # value heads of this step that read one key head
+    scalar = len(g_ref.shape) == 5
+    f32, half = jnp.float32, _KERNEL_CHUNK
     pairs, last = [], before_ref[:1]
     for at in range(0, tile, _PAIR):
         rows = slice(at, at + _PAIR)
         seg_col = seg_col_ref[0, rows]
         before = jnp.concatenate([jnp.broadcast_to(last, (half, d)),
                                   jnp.broadcast_to(seg_col[half - 1:half], (half, d))])
+        qk = {}
+        if scalar:  # [q; k] k^T once a key head: a pair's decay multiplies it afterwards
+            for kh in range(q_ref.shape[1]):
+                q, k = q_ref[0, kh, rows].astype(f32), k_ref[0, kh, rows].astype(f32)
+                qk[kh] = _dot(jnp.concatenate([q, k]), k, ((1,), (1,)))
+        # q, k and v float32 from here on, whatever they lie as in memory
         pairs += [_pair_of_chunks(
-            q_ref[0, h, rows], k_ref[0, h, rows], v_ref[0, h, rows], g_ref[0, h, rows],
-            beta_ref[0, h, 0, :, rows], seg_col, seg_row_ref[0, :1, rows], before, scale)
+            q_ref[0, h // shared, rows].astype(f32), k_ref[0, h // shared, rows].astype(f32),
+            v_ref[0, h, rows].astype(f32), g_ref[0, h, 0, :, rows] if scalar else g_ref[0, h, rows],
+            beta_ref[0, h, 0, :, rows], seg_col, seg_row_ref[0, :1, rows], before, scale,
+            qk.get(h // shared))
             for h in range(heads)]
         last = seg_col[_PAIR - 1:_PAIR]
     laid = _in_turn(pairs)
@@ -436,23 +494,35 @@ def _delta_rule_kernel(seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, bet
 def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, interpret=False):
     """:func:`delta_rule_chunked` in chunks of 64 as one Pallas TPU kernel:
     grid (rows, pairs of heads, tiles of ``tile`` tokens), a head's tiles in
-    order. Reads q, k, v, log_decay and beta once, writes o once."""
+    order. Reads q, k, v, log_decay and beta once, writes o once: q, k and v
+    in the dtype they come in (float32 in VMEM), a grid step's key heads by
+    their block index where they are fewer than its value heads, a decay of
+    one number a token in the layout ``beta`` has."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, l, d = q.shape
+    b, h, l, d = v.shape
     f32 = jnp.float32
     heads = 2 - h % 2          # two chains in turn keep the matrix unit busier than one
     segments = segments.astype(jnp.int32)
     per_head = pl.BlockSpec((1, heads, tile, d), lambda bi, hi, ti: (bi, hi, ti, 0))
+    per_token = pl.BlockSpec((1, heads, 1, 1, tile), lambda bi, hi, ti: (bi, hi, ti, 0, 0))
+    group = h // q.shape[1]    # value heads a key head
+    key_heads = max(1, heads // group)
+    per_key_head = per_head if group == 1 else pl.BlockSpec(
+        (1, key_heads, tile, d), lambda bi, hi, ti: (bi, hi * heads // (group * key_heads), ti, 0))
+    scalar = log_decay.ndim == 3
+
+    def by_token(a):  # [B, H, L] a tile of tokens along the lanes
+        return a.astype(f32).reshape(b, h, l // tile, 1, tile)
+
     call = pl.pallas_call(
         functools.partial(_delta_rule_kernel, scale=scale),
         grid=(b, h // heads, l // tile),
         in_specs=[
             pl.BlockSpec((1, tile, d), lambda bi, hi, ti: (bi, ti, 0)),
             pl.BlockSpec((1, 8, tile), lambda bi, hi, ti: (bi, 0, ti)),
-            per_head, per_head, per_head, per_head,
-            pl.BlockSpec((1, heads, 1, 1, tile), lambda bi, hi, ti: (bi, hi, ti, 0, 0)),
+            per_key_head, per_key_head, per_head, per_token if scalar else per_head, per_token,
         ],
         out_specs=per_head,
         scratch_shapes=[pltpu.VMEM((heads, d, d), f32), pltpu.VMEM((8, d), jnp.int32)],
@@ -465,8 +535,7 @@ def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, inte
         return call(
             jnp.broadcast_to(segments[:, :, None], (b, l, d)),
             jnp.broadcast_to(segments[:, None, :], (b, 8, l)),
-            q.astype(f32), k.astype(f32), v.astype(f32), log_decay.astype(f32),
-            beta.astype(f32).reshape(b, h, l // tile, 1, tile))
+            q, k, v, by_token(log_decay) if scalar else log_decay.astype(f32), by_token(beta))
 
 
 def _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk: int):
@@ -475,10 +544,14 @@ def _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk: int):
     cut into ``[B, H, n, C, D]`` by a reshape and the loop over the n chunks
     slices that axis where it lies: nothing is transposed. At most ``_HEADS``
     heads of a row are worked at a time (``lax.map`` over groups of heads,
-    again a reshape): what this form lays out, a dozen float32 arrays the
-    size of q, is that many heads', whatever the batch."""
-    b, h, l, d = q.shape
+    again a reshape; whole key heads' worth where key heads are fewer): what
+    this form lays out, a dozen float32 arrays the size of v, is that many
+    heads', whatever the batch."""
+    b, h, l, d = v.shape
     f32 = jnp.float32
+    group = h // q.shape[1]    # value heads a key head
+    if log_decay.ndim == 3:
+        log_decay = log_decay[..., None]
     pad = -l % chunk
     if pad:
         q, k, v, log_decay = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
@@ -486,11 +559,11 @@ def _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk: int):
         beta = jnp.pad(beta, ((0, 0), (0, 0), (0, pad)))
         segments = jnp.pad(segments, ((0, 0), (0, pad)), constant_values=-1)
     n = (l + pad) // chunk
-    heads = max(m for m in range(1, min(h, _HEADS) + 1) if h % m == 0)
+    heads = max(m for m in range(group, max(group, min(h, _HEADS)) + 1, group) if h % m == 0)
     groups = b * (h // heads)
 
-    def cut(x):
-        return x.astype(f32).reshape(groups, heads, n, chunk, *x.shape[3:])
+    def cut(x):  # a group's heads together: ``heads`` of them, or the key heads those read
+        return x.astype(f32).reshape(groups, x.shape[1] * heads // h, n, chunk, *x.shape[3:])
 
     seg = jnp.repeat(segments.reshape(b, n, chunk), h // heads, axis=0)
     o = jax.lax.map(lambda xs: _chunked_heads(*xs, scale),
@@ -500,14 +573,15 @@ def _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk: int):
 
 def delta_rule_chunked(q, k, v, log_decay, beta, segments, scale, chunk: int = 64):
     """The same recurrence ``chunk`` tokens at a time (module docstring).
-    Shapes as :func:`delta_rule_recurrent`, head-major ``[B, H, L, D]`` as a
-    projection writes them. On a TPU, for shapes the kernel takes
+    Shapes as :func:`delta_rule_recurrent` (either form of the decay, key
+    heads as many as value heads or a divisor), head-major ``[B, H, L, D]`` as
+    a projection writes them. On a TPU, for shapes the kernel takes
     (:func:`fused_tile`), one Pallas kernel; elsewhere the plain JAX form,
     the same algorithm with its intermediates in memory, which the tests
     hold the interpreted kernel to."""
     if chunk & (chunk - 1):
         raise ValueError(f"chunk must be a power of two, got {chunk}")
-    tile = fused_tile(q.shape, chunk)
+    tile = fused_tile(v.shape, chunk)
     if tile is not None:
         return _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile)
     return _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk)

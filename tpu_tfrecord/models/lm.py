@@ -782,7 +782,7 @@ def bigram_table(vocab: int, n_next: int, seed: int = 1234) -> np.ndarray:
 # ``segment_ids`` [B, L+1]; every document comes out as it would alone in
 # a row (attention, positions, taps and state all stop at a boundary).
 
-MIXERS = ("gqa", "kda", "mla", "swa")
+MIXERS = ("gqa", "kda", "mla", "swa", "gdn")
 FFNS = ("moe", "dense")
 
 
@@ -797,15 +797,17 @@ class PatternLMConfig:
     head_dim: int = 16
     window: int = 0                # sliding-window layer ("swa"): the keys a query sees, its own among them
     qk_norm: bool = False          # softmax and sliding-window layers: an RMSNorm over each head of q and of k
-    kda_heads: int = 4             # delta-rule layer: heads of d_k = d_v = kda_head_dim
+    kda_heads: int = 4             # delta-rule layers ("kda", "gdn"): heads of d_k = d_v = kda_head_dim
     kda_head_dim: int = 16
     conv_taps: int = 4
-    gate_rank: int = 8             # rank of the delta-rule layer's decay and output gates
+    gate_rank: int = 8             # rank of the "kda" layer's decay and output gates
+    gdn_key_heads: int = 0         # "gdn" layer: key heads, a divisor of its kda_heads value heads (0: as many)
     qk_nope_dim: int = 16          # latent-attention layer: a head's query/key width without positions,
     qk_rope_dim: int = 8           # ... its rotary width (the key's is one head shared by all),
     v_head_dim: int = 16           # ... its value width,
     kv_rank: int = 32              # ... the rank of the latent that keys and values are expanded from
     q_rank: int = 0                # ... and of the normed latent its queries are expanded from (0: one matrix)
+    attn_gate: bool = False        # ... and a sigmoid gate from the layer's input on its output, a head and channel
     rope_theta: float = 10000.0
     rope_scaling: Tuple[float, ...] = ()  # YaRN: (factor, original length, beta_fast, beta_slow); () = none
     index_heads: int = 0           # latent-attention layer's indexer: heads of ``index_dim`` a query,
@@ -823,6 +825,8 @@ class PatternLMConfig:
     n_group: int = 1               # the router's group limit: the experts in so many equal runs,
     topk_group: int = 1            # ... of which a token's choice may touch so many (moe.route_top_k)
     norm_eps: float = 1e-5
+    centred_norms: bool = False    # a norm's gain is 2 sigmoid(w), 1 at w = 0, where it is w
+    swiglu_limit: float = 0.0      # every gated unit clipped at it before it multiplies (moe.gated_ffn; 0: not)
     branch_norms: bool = False     # sandwich: x + norm(branch(norm(x))), the mixer's and the feed-forward's
     embed_scale: bool = False      # the embedding's rows times sqrt(d_model)
     max_len: int = 64              # L: a row is L + 1 tokens
@@ -887,11 +891,21 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
         },
     }
     mixers["swa"] = mixers["gqa"]  # a softmax layer under a window, with rotary positions
+    kk = (cfg.gdn_key_heads or cfg.kda_heads) * cfg.kda_head_dim
+    mixers["gdn"] = {  # "kda" with full-rank gates, one decay a head and token, fewer key heads
+        "attn_norm": ((d,), f32), "wq": ((d, kk), dt), "wk": ((d, kk), dt), "wv": ((d, kd), dt),
+        "wz": ((d, kd), dt), "conv_q": ((cfg.conv_taps, kk), f32), "conv_k": ((cfg.conv_taps, kk), f32),
+        "conv_v": ((cfg.conv_taps, kd), f32), "w_a": ((d, cfg.kda_heads), dt),
+        "dt_bias": ((cfg.kda_heads,), f32), "a_log": ((cfg.kda_heads,), f32),
+        "w_beta": ((d, cfg.kda_heads), dt), "o_norm": ((cfg.kda_head_dim,), f32), "wo": ((kd, d), dt),
+    }
     for kind in cfg.layer_pattern:
         if kind not in MIXERS:
             raise ValueError(f"layer_pattern names {kind!r}; the mixers are {MIXERS}")
     if "swa" in cfg.layer_pattern and cfg.window < 1:
         raise ValueError("a sliding-window layer needs cfg.window: the keys a query sees")
+    if cfg.kda_heads % (cfg.gdn_key_heads or 1):
+        raise ValueError(f"gdn_key_heads {cfg.gdn_key_heads} has to divide the {cfg.kda_heads} value heads")
     if cfg.qk_norm:  # one weight for all heads
         mixers["gqa"].update({"q_norm": ((cfg.head_dim,), f32), "k_norm": ((cfg.head_dim,), f32)})
     if cfg.branch_norms:
@@ -901,6 +915,8 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
         wq = mixers["mla"].pop("wq")[0]
         mixers["mla"].update({"wq_a": ((d, cfg.q_rank), dt), "q_norm": ((cfg.q_rank,), f32),
                               "wq_b": ((cfg.q_rank, wq[1]), dt)})
+    if cfg.attn_gate:
+        mixers["mla"]["wg"] = ((d, cfg.n_heads * cfg.v_head_dim), dt)
     if cfg.index_topk:
         if not cfg.q_rank:
             raise ValueError("the indexer's queries come from the query latent: index_topk needs q_rank")
@@ -926,19 +942,23 @@ def _is_shape(x) -> bool:
 
 def pattern_init_params(rng: jax.Array, cfg: PatternLMConfig) -> Dict[str, Any]:
     """Random parameters for tests and examples: matrices normal(0, 1/fan_in),
-    norms 1, decays of 0.001 to 0.1 a token (``a_log`` 0, ``f_bias`` the
-    inverse softplus of a log-uniform rate), taps that favour the current
-    token, a router bias normal(0, 0.05)."""
+    norms 1 (under ``cfg.centred_norms`` normal(0, 0.2): gains of 1 +- 0.1;
+    a delta-rule head's own ``o_norm`` is a plain gain either way), decays of
+    0.001 to 0.1 a token (``a_log`` 0, ``f_bias`` / ``dt_bias`` the inverse
+    softplus of a log-uniform rate), taps that favour the current token, a
+    router bias normal(0, 0.05)."""
     shapes = pattern_param_shapes(cfg)
     leaves, tree = jax.tree.flatten_with_path(shapes, is_leaf=_is_shape)
     out = []
     for i, (path, (shape, dtype)) in enumerate(leaves):
         name, key = path[-1].key, jax.random.fold_in(rng, i)
-        if name.endswith("norm"):
+        if name.endswith("norm") and cfg.centred_norms and name != "o_norm":
+            value = jax.random.normal(key, shape) * 0.2
+        elif name.endswith("norm"):
             value = jnp.ones(shape)
         elif name == "a_log":
             value = jnp.zeros(shape)
-        elif name == "f_bias":
+        elif name in ("f_bias", "dt_bias"):
             rate = jnp.exp(jax.random.uniform(key, shape, minval=np.log(1e-3), maxval=np.log(1e-1)))
             value = jnp.log(jnp.expm1(rate))
         elif name.startswith("conv_"):
@@ -960,6 +980,15 @@ def weighted_rms_norm(x, weight, eps: float):
     x32 = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
     return (x32 * scale * weight).astype(x.dtype)
+
+
+def _norm(x, weight, cfg: "PatternLMConfig"):
+    """:func:`weighted_rms_norm` as ``cfg`` has it: the gain ``weight`` itself,
+    or under ``cfg.centred_norms`` ``2 sigmoid(weight)``, a gain in (0, 2)
+    that is 1 where the weight is 0."""
+    if cfg.centred_norms:
+        weight = 2.0 * jax.nn.sigmoid(weight)
+    return weighted_rms_norm(x, weight, cfg.norm_eps)
 
 
 def _l2_norm(x, eps: float = 1e-6):
@@ -1071,13 +1100,13 @@ def gqa_mixer_probed(p, x, segments, cfg: PatternLMConfig, sliding: bool = False
     probed = sample_at is not None and probe_head is not None
     around, call = ("tfr.swa_proj", "tfr.swa_attn") if sliding else ("tfr.gqa", "tfr.gqa")
     with jax.named_scope(around):
-        u = weighted_rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        u = _norm(x, p["attn_norm"], cfg)
         q = jnp.einsum("bld,dhk->bhlk", u, p["wq"].reshape(d, h, dh))
         k = jnp.einsum("bld,dhk->bhlk", u, p["wk"].reshape(d, hkv, dh))
         v = jnp.einsum("bld,dhk->bhlk", u, p["wv"].reshape(d, hkv, dh))
         if cfg.qk_norm:
-            q = weighted_rms_norm(q, p["q_norm"], cfg.norm_eps)
-            k = weighted_rms_norm(k, p["k_norm"], cfg.norm_eps)
+            q = _norm(q, p["q_norm"], cfg)
+            k = _norm(k, p["k_norm"], cfg)
         at = segment_positions(segments) if sliding or probed else None
         if sliding:
             q, k = rotary(q, at, cfg.rope_theta), rotary(k, at, cfg.rope_theta)
@@ -1149,7 +1178,10 @@ def mla_mixer(p, x, segments, cfg: PatternLMConfig):
     ``q_nope . k_nope + q_pe . k_pe``, so nothing is gained by joining them
     first: a joined array would be written, read and written again around the
     rotary turn, the shared rotary key stored once a head, and the values cut
-    out of a wider array by a copy (:func:`_attend` hands the parts on)."""
+    out of a wider array by a copy (:func:`_attend` hands the parts on). With
+    ``cfg.attn_gate`` the attention's output is weighed, a head and channel, by
+    a sigmoid of the layer's own normed input (``wg``) before ``wo``, as the
+    softmax layer's is."""
     return mla_mixer_probed(p, x, segments, cfg)[0]
 
 
@@ -1164,14 +1196,14 @@ def mla_mixer_probed(p, x, segments, cfg: PatternLMConfig, sample_at=None):
         return rotary(a, at, cfg.rope_theta, *yarn)
 
     with jax.named_scope("tfr.mla_proj"):
-        u = weighted_rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        u = _norm(x, p["attn_norm"], cfg)
         if cfg.q_rank:
-            c_q = weighted_rms_norm(u @ p["wq_a"], p["q_norm"], cfg.norm_eps)
+            c_q = _norm(u @ p["wq_a"], p["q_norm"], cfg)
             q_in, wq = c_q, p["wq_b"].reshape(cfg.q_rank, h, dn + dr)
         else:
             q_in, wq = u, p["wq"].reshape(d, h, dn + dr)
         latent = u @ p["wkv_a"]                                          # [B, L, rank + dr]
-        c = weighted_rms_norm(latent[..., :rank], p["kv_norm"], cfg.norm_eps)
+        c = _norm(latent[..., :rank], p["kv_norm"], cfg)
         wkv = p["wkv_b"].reshape(rank, h, dn + dv)
         k = jnp.einsum("blr,rhk->bhlk", c, wkv[..., :dn])
         v = jnp.einsum("blr,rhk->bhlk", c, wkv[..., dn:])
@@ -1187,6 +1219,10 @@ def mla_mixer_probed(p, x, segments, cfg: PatternLMConfig, sample_at=None):
     with jax.named_scope("tfr.mla_attn"):
         att = _attend((q, q_pe), (k, k_pe), v, segments, cfg.attn_block, **chosen)
     with jax.named_scope("tfr.mla_proj"):
+        if cfg.attn_gate:
+            gate = jax.nn.sigmoid(
+                jnp.einsum("bld,dhk->bhlk", u, p["wg"].reshape(d, h, dv)).astype(jnp.float32))
+            att = (att.astype(jnp.float32) * gate).astype(x.dtype)
         return jnp.einsum("bhlk,hkd->bld", att, p["wo"].reshape(h, dv, d)), index
 
 
@@ -1234,6 +1270,13 @@ def index_select(p, u, c_q, turn, at, segments, cfg: PatternLMConfig, sample_at=
     return keep, record
 
 
+def _conv_silu(a, taps, segments):
+    """SiLU of the delta-rule layers' short convolution, float32: a [B, H, L, Dh]
+    under ``taps`` [K, H * Dh] that stop at a document's start."""
+    taps = taps.reshape(taps.shape[0], a.shape[1], a.shape[3])
+    return jax.nn.silu(_la.short_conv(a, taps, segments).astype(jnp.float32))
+
+
 def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
     """The gated delta-rule layer (models.linear_attn has the equations):
     projections, a 4-tap convolution and SiLU on q, k, v, unit-norm q and k,
@@ -1252,7 +1295,7 @@ def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
         return jnp.einsum("blm,mhk->bhlk", a, w.reshape(w.shape[0], h, dh))
 
     with jax.named_scope("tfr.kda_proj"):
-        u = weighted_rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        u = _norm(x, p["attn_norm"], cfg)
         q, k, v = heads(u, p["wq"]), heads(u, p["wk"]), heads(u, p["wv"])
         rate = jax.nn.softplus(
             heads(u @ p["f_down"], p["f_up"]).astype(f32) + p["f_bias"].reshape(h, 1, dh))
@@ -1260,17 +1303,15 @@ def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
         beta = 2.0 * jax.nn.sigmoid(jnp.einsum("bld,dh->bhl", u, p["w_beta"]).astype(f32))
         gate = jax.nn.sigmoid(heads(u @ p["g_down"], p["g_up"]).astype(f32))
     with jax.named_scope("tfr.kda_conv"):
-        def conv_silu(a, taps):
-            taps = taps.reshape(taps.shape[0], h, dh)
-            return jax.nn.silu(_la.short_conv(a, taps, segments).astype(f32))
-
-        q = _l2_norm(conv_silu(q, p["conv_q"])).astype(x.dtype)
-        k = _l2_norm(conv_silu(k, p["conv_k"])).astype(x.dtype)
-        v = conv_silu(v, p["conv_v"]).astype(x.dtype)
+        q = _l2_norm(_conv_silu(q, p["conv_q"], segments)).astype(x.dtype)
+        k = _l2_norm(_conv_silu(k, p["conv_k"], segments)).astype(x.dtype)
+        v = _conv_silu(v, p["conv_v"], segments).astype(x.dtype)
     with jax.named_scope("tfr.kda_scan"):
         # float32 here, not inside: the probe has to hold the very values the
         # recurrence consumes, and XLA drops a bfloat16 round trip where it can
-        # (the recurrence would see the unrounded q, the probe the rounded one)
+        # (the recurrence would see the unrounded q, the probe the rounded one).
+        # :func:`gdn_mixer` shows the cheaper form: the rounded arrays behind a
+        # barrier as they are, float32 for the probed head alone.
         q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
         o = _la.delta_rule_chunked(q, k, v, log_decay, beta, segments,
                                    scale=dh ** -0.5, chunk=cfg.kda_chunk)
@@ -1284,8 +1325,61 @@ def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
         return jnp.einsum("bhlk,hkd->bld", o, p["wo"].reshape(h, dh, d)), probe
 
 
+def gdn_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
+    """The gated delta-net layer: :func:`kda_mixer`'s convolution, unit norms
+    and recurrence with ONE decay a head and token,
+    ``exp(-exp(a_log) softplus(u w_a + dt_bias))``, a beta in (0, 1),
+    ``cfg.gdn_key_heads`` key heads under ``cfg.kda_heads`` value heads (value
+    head h reads key head ``h // (kda_heads / gdn_key_heads)``), and a
+    full-rank output gate ``2 sigmoid(u wz)`` on the per-head RMSNorm. The
+    recurrence is handed what the mechanism has: q and k at their own heads,
+    v, and a decay and a beta ``[B, H, L]``, q, k and v in the dtype the
+    convolution wrote them (``linear_attn`` widens a tile at a time); nothing
+    as large as v is float32 before the recurrence's own output.
+
+    Returns (y, probe) as :func:`kda_mixer` does: with ``probe_head`` (a value
+    head) that head's ``v``, ``o`` [B, L, D], ``log_decay``, ``beta`` [B, L]
+    and its key head's ``q``, ``k`` [B, L, D], float32 for that head alone."""
+    d, h, dh, f32 = x.shape[-1], cfg.kda_heads, cfg.kda_head_dim, jnp.float32
+    hk = cfg.gdn_key_heads or h
+
+    def heads(a, w, n):  # a [B, L, m] through w [m, n * dh] -> [B, n, L, dh]
+        return jnp.einsum("blm,mhk->bhlk", a, w.reshape(w.shape[0], n, dh))
+
+    def by_head(a, w):  # a [B, L, m] through w [m, H] -> [B, H, L] float32
+        return jnp.einsum("bld,dh->bhl", a, w).astype(f32)
+
+    with jax.named_scope("tfr.gdn_proj"):
+        u = _norm(x, p["attn_norm"], cfg)
+        q, k, v = heads(u, p["wq"], hk), heads(u, p["wk"], hk), heads(u, p["wv"], h)
+        rate = jax.nn.softplus(by_head(u, p["w_a"]) + p["dt_bias"][:, None])
+        log_decay = -jnp.exp(p["a_log"])[:, None] * rate
+        beta = jax.nn.sigmoid(by_head(u, p["w_beta"]))
+    with jax.named_scope("tfr.gdn_conv"):
+        q = _l2_norm(_conv_silu(q, p["conv_q"], segments)).astype(x.dtype)
+        k = _l2_norm(_conv_silu(k, p["conv_k"], segments)).astype(x.dtype)
+        v = _conv_silu(v, p["conv_v"], segments).astype(x.dtype)
+        # the recurrence and the probe of it read these very arrays, rounded as they are
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
+    with jax.named_scope("tfr.gdn_scan"):
+        o = _la.delta_rule_chunked(q, k, v, log_decay, beta, segments,
+                                   scale=dh ** -0.5, chunk=cfg.kda_chunk)
+    probe = None
+    if probe_head is not None:
+        key_head = probe_head // (h // hk)
+        probe = {name: jnp.take(a, at, axis=1).astype(f32) for name, (a, at) in dict(
+            q=(q, key_head), k=(k, key_head), v=(v, probe_head), log_decay=(log_decay, probe_head),
+            beta=(beta, probe_head), o=(o, probe_head)).items()}
+    with jax.named_scope("tfr.gdn_proj"):
+        gate = 2.0 * jax.nn.sigmoid(heads(u, p["wz"], h).astype(f32))
+        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
+        o = (o * p["o_norm"] * gate).astype(x.dtype)
+        return jnp.einsum("bhlk,hkd->bld", o, p["wo"].reshape(h, dh, d)), probe
+
+
 #: where a mixer's branch norm is counted: with the layer's other projections
-_BRANCH_SCOPE = {"gqa": "tfr.gqa", "swa": "tfr.swa_proj", "mla": "tfr.mla_proj", "kda": "tfr.kda_proj"}
+_BRANCH_SCOPE = {"gqa": "tfr.gqa", "swa": "tfr.swa_proj", "mla": "tfr.mla_proj", "kda": "tfr.kda_proj",
+                 "gdn": "tfr.gdn_proj"}
 
 
 def _joined(x, y, weight, cfg: PatternLMConfig, scope: str):
@@ -1295,7 +1389,7 @@ def _joined(x, y, weight, cfg: PatternLMConfig, scope: str):
     if weight is None:
         return x + y
     with jax.named_scope(scope):
-        return x + weighted_rms_norm(y, weight, cfg.norm_eps)
+        return x + _norm(y, weight, cfg)
 
 
 def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=None,
@@ -1308,7 +1402,8 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
     inputs: ``router`` (with ``sample_at`` [B, S]: at those positions of
     every expert layer the router's input ``u`` [n_layers, B, S, D] and its
     ``experts`` and ``gates`` [n_layers, B, S, top_k]) and ``scan`` (with
-    ``probe_head``: :func:`kda_mixer`'s probe of the first delta-rule layer).
+    ``probe_head``: :func:`kda_mixer`'s or :func:`gdn_mixer`'s probe of the
+    first delta-rule layer).
     Where latent-attention layers have an indexer, ``selected`` [layers, 2]
     (:func:`index_select`'s counts) and, with ``sample_at``, the first expert
     layer's selection: its keys under ``scan`` and the rest beside the
@@ -1326,6 +1421,7 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
         if cfg.embed_scale:
             x = (x.astype(jnp.float32) * cfg.d_model ** 0.5).astype(x.dtype)
     b, _, d = x.shape
+    limit = cfg.swiglu_limit or None
     visits, dropped, routed, probes, selected, selection = [], [], [], {}, [], None
     for kind, ffn, layer in zip(cfg.layer_pattern, ffn_kinds(cfg), params["layers"]):
         if kind == "swa" and "scan" not in probes:  # the first sliding layer, one head probed
@@ -1343,20 +1439,20 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
         elif kind == "mla":
             y = mla_mixer(layer, x, segments, cfg)
         else:
-            y, scan = kda_mixer(layer, x, segments, cfg,
-                                None if "scan" in probes else probe_head)
+            y, scan = (kda_mixer if kind == "kda" else gdn_mixer)(
+                layer, x, segments, cfg, None if "scan" in probes else probe_head)
             if scan is not None:
                 probes["scan"] = scan
         x = _joined(x, y, layer.get("post_attn_norm"), cfg, _BRANCH_SCOPE[kind])
         if ffn == "dense":
             with jax.named_scope("tfr.dense_ffn"):
-                u = weighted_rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+                u = _norm(x, layer["ffn_norm"], cfg)
                 w = layer["dense"]
-                y = _moe.gated_ffn(u, w["w_gate"], w["w_up"], w["w_down"]).astype(x.dtype)
+                y = _moe.gated_ffn(u, w["w_gate"], w["w_up"], w["w_down"], limit).astype(x.dtype)
                 x = _joined(x, y, layer.get("post_ffn_norm"), cfg, "tfr.dense_ffn")
             continue
         with jax.named_scope("tfr.moe_route"):
-            u = weighted_rms_norm(x, layer["moe_norm"], cfg.norm_eps)
+            u = _norm(x, layer["moe_norm"], cfg)
             if cfg.n_group > 1 or cfg.branch_norms:
                 # ONE array for the router and for the probe of it. Left alone, the compiler
                 # computes the norm once for each reader, the two fusions round a few
@@ -1368,7 +1464,8 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
         y, n, lost, (experts, gates) = _moe.held_experts_apply(
             layer, u.reshape(b * l, d), held_offset=cfg.held_offset, top_k=cfg.top_k,
             routed_scale=cfg.routed_scale, tile=cfg.expert_tile,
-            valid=(segments != 0).reshape(b * l), n_group=cfg.n_group, topk_group=cfg.topk_group)
+            valid=(segments != 0).reshape(b * l), n_group=cfg.n_group, topk_group=cfg.topk_group,
+            limit=limit)
         x = _joined(x, y.reshape(b, l, d), layer.get("post_ffn_norm"), cfg, "tfr.moe_experts")
         visits.append(n)
         dropped.append(lost)
@@ -1407,13 +1504,16 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
 
     x, visits, dropped, probes = pattern_hidden(params, tokens, segment_ids, cfg, sample_at,
                                                 probe_head)
-    if "kda" not in cfg.layer_pattern:
+    if not {"kda", "gdn"} & set(cfg.layer_pattern):
         probes.setdefault("scan", {})
     # every delta-rule layer of a program has one shape, so one answer of the
     # function that decides the dispatch (as the program is traced, not as it runs)
     fused = _la.fused_tile((tokens.shape[0], cfg.kda_heads, cfg.max_len, cfg.kda_head_dim),
                            cfg.kda_chunk) is not None
     METRICS.gauge("kda.fused_layers", cfg.layer_pattern.count("kda") if fused else 0)
+    if "gdn" in cfg.layer_pattern:  # the same kernel under its other decay, and how the key heads are shared
+        METRICS.gauge("gdn.fused_layers", cfg.layer_pattern.count("gdn") if fused else 0)
+        METRICS.gauge("gdn.key_group", cfg.kda_heads // (cfg.gdn_key_heads or cfg.kda_heads))
     # likewise the selection: one shape for every layer that has an indexer
     in_kernel = cfg.index_topk and _sa.select_tile(
         (tokens.shape[0], cfg.index_heads, cfg.max_len, cfg.index_dim), cfg.index_topk) is not None
@@ -1430,7 +1530,7 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
         METRICS.gauge("swa.pairs_walked_share", round(band / triangle, 6))
     b, l, d = x.shape
     with jax.named_scope("tfr.lm_head"):
-        xn = weighted_rms_norm(x, params["final_norm"], cfg.norm_eps)
+        xn = _norm(x, params["final_norm"], cfg)
         flat, targets = xn.reshape(b * l, d), tokens[:, 1:].reshape(b * l)
         out = []
         for t0 in range(0, b * l, cfg.head_block):

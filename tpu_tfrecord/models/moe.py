@@ -548,12 +548,17 @@ def moe_reference(
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def gated_ffn(x, w_gate, w_up, w_down):
+def gated_ffn(x, w_gate, w_up, w_down, limit=None):
     """``w_down(silu(w_gate x) * w_up x)``: products accumulate in float32,
-    the hidden activation is rounded to x's dtype; returns float32."""
+    the hidden activation is rounded to x's dtype; returns float32. With a
+    ``limit`` the unit is clipped before it multiplies: the gate's
+    pre-activation from above, ``silu(min(w_gate x, limit))``, the other
+    factor on both sides, ``clip(w_up x, -limit, limit)``."""
     f32 = jnp.float32
-    hidden = jax.nn.silu(jnp.dot(x, w_gate, preferred_element_type=f32)) * jnp.dot(
-        x, w_up, preferred_element_type=f32)
+    above = (lambda a: a) if limit is None else (lambda a: jnp.minimum(a, limit))
+    both = (lambda a: a) if limit is None else (lambda a: jnp.clip(a, -limit, limit))
+    hidden = jax.nn.silu(above(jnp.dot(x, w_gate, preferred_element_type=f32))) * both(jnp.dot(
+        x, w_up, preferred_element_type=f32))
     return jnp.dot(hidden.astype(x.dtype), w_down, preferred_element_type=f32)
 
 
@@ -590,7 +595,7 @@ def route_top_k(x, router, top_k: int, routed_scale: float = 1.0, bias=None, n_g
 
 def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: int,
                        routed_scale: float = 1.0, tile: int = 256, valid=None,
-                       n_group: int = 1, topk_group: int = 1):
+                       n_group: int = 1, topk_group: int = 1, limit=None):
     """``shared(x) + sum of gate_e * expert_e(x)`` over the chosen experts
     THIS chip holds; what the absent experts would add is left out.
 
@@ -601,7 +606,8 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     un-stacked (several shared experts are one unit of their summed width).
     x [T, D]; ``valid`` [T] bool: tokens that
     are pads visit no expert (their rows get the shared expert only);
-    ``n_group`` / ``topk_group``: :func:`route_top_k`'s group limit.
+    ``n_group`` / ``topk_group``: :func:`route_top_k`'s group limit;
+    ``limit``: :func:`gated_ffn`'s clip, in every held expert and the shared one.
     Returns (y [T, D] in x's dtype, visits [Eh] int32 to each held expert,
     dropped: visits to held experts that were not computed, always 0,
     (experts [T, top_k] int32, gates [T, top_k] float32): the routing).
@@ -659,7 +665,7 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
             real = rows < first[e] + visits[e]
             visit = order[jnp.minimum(rows, t * top_k - 1)]
             y = gated_ffn(x[visit // top_k], params["w_gate"][e], params["w_up"][e],
-                          params["w_down"][e])
+                          params["w_down"][e], limit)
             return visit, real, y
 
         def one_tile(j, carry):
@@ -693,5 +699,5 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     if "shared" in params:
         with jax.named_scope("tfr.moe_shared"):
             sh = params["shared"]
-            out = out + gated_ffn(x, sh["w_gate"], sh["w_up"], sh["w_down"])
+            out = out + gated_ffn(x, sh["w_gate"], sh["w_up"], sh["w_down"], limit)
     return out.astype(x.dtype), visits, visits.sum() - done, (experts, gates)

@@ -77,8 +77,16 @@ ANNOTATIONS = {
     "tfr.kda_proj": "pattern LM: the delta-rule layer's projections, decay, beta, gates, norm, out",
     "tfr.kda_conv": "pattern LM: the short convolution, SiLU and unit norm of q, k, v",
     "tfr.kda_scan": "pattern LM: the chunked gated delta rule (models.linear_attn)",
+    "tfr.gdn_proj": "pattern LM: the gated delta-net layer's norm, six projections (q, k, v, the "
+                    "output gate z, the decay's and beta's one a head), decay, beta, head norm, "
+                    "gate, out, branch norm",
+    "tfr.gdn_conv": "pattern LM: the gated delta-net layer's short convolution, SiLU and unit norm "
+                    "of q and k at their own heads and of v",
+    "tfr.gdn_scan": "pattern LM: the gated delta-net layer's recurrence call alone (models.linear_attn "
+                    "under one decay a head and token, key heads shared by their value heads)",
     "tfr.mla_proj": "pattern LM: the latent-attention layer's norm, query projection, latent "
-                    "projection and norm, expansion to keys and values, rotary turns, out",
+                    "projection and norm, expansion to keys and values, rotary turns, the output's "
+                    "sigmoid gate where the layer has one, out",
     "tfr.mla_attn": "pattern LM: the latent-attention layer's attention call alone (192-wide "
                     "queries and keys against 128-wide values inside each document)",
     "tfr.dsa_proj": "pattern LM: a latent-attention layer's indexer: its queries from the query "

@@ -182,7 +182,7 @@ STAGES: Dict[str, str] = {
     "compile.backend": "a program's backend compile, or the cache read and deserialisation that stood in for it",
     "compile.cache_read": "the persistent cache read inside a compile.backend that hit",
     "kernel.trace.mla_attn": "the latent-attention Pallas kernel built while a program is traced (attention._flash_widths_call)",
-    "kernel.trace.kda_scan": "the delta-rule Pallas kernel built while a program is traced (linear_attn._delta_rule_fused)",
+    "kernel.trace.kda_scan": "the delta-rule Pallas kernel built while a program is traced (linear_attn._delta_rule_fused, under either form of the decay)",
     "kernel.trace.dsa_index": "the selection Pallas kernel built while a program is traced (sparse_attn._select_fused)",
     "kernel.trace.interaction": "the dot-interaction Pallas kernel built while a program is traced (interaction.dot_interaction_pallas)",
 }
@@ -209,6 +209,8 @@ GAUGES: Dict[str, str] = {
     "moe.dropped_fraction": "latest per-step dropped-token fraction",
     "moe.visits_max_over_mean": "held experts, latest step: the busiest over the mean (most uneven layer)",
     "kda.fused_layers": "pattern LM, the score program last traced: delta-rule layers whose recurrence took the Pallas kernel (0 off a TPU)",
+    "gdn.fused_layers": "pattern LM, the score program last traced: gated delta-net layers whose recurrence took the Pallas kernel, one decay a head and token (0 off a TPU)",
+    "gdn.key_group": "pattern LM, the score program last traced: value heads of a gated delta-net layer that read one key head, from where it lies",
     "dsa.kernel_layers": "pattern LM, the score program last traced: latent-attention layers whose selection took the Pallas kernel (0 off a TPU and without an indexer)",
     "mla.split_layers": "pattern LM, the score program last traced: latent-attention layers whose attention kernel was handed q and k in their two parts, plain and rotary, never joined in memory (0 off a TPU)",
     "dsa.selected_share": "pattern LM, latest step recorded: keys the indexers kept over the causal candidates they chose from (lm.record_selected)",
