@@ -73,14 +73,18 @@ def test_the_chunked_recurrence_is_the_token_by_token_one(chunk, length):
     np.testing.assert_allclose(got, want, atol=5e-6)
 
 
+@pytest.mark.parametrize("operands", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("length,tile,heads", [(128, 128, 2), (256, 128, 2), (256, 256, 2), (512, 256, 2),
                                                (768, 256, 3)])
-def test_the_kernel_is_the_token_by_token_recurrence_and_the_plain_form(length, tile, heads):
+def test_the_kernel_is_the_token_by_token_recurrence_and_the_plain_form(length, tile, heads, operands):
     """Four boundaries a row at random places and a pad tail of segment 0:
     documents start and end inside a chunk, inside a pair of chunks and
     inside a grid step, and the state crosses from one grid step to the next.
-    An even number of heads goes two to a grid step, an odd number one."""
-    args = delta_rule_inputs(length + tile, length, b=1, h=heads, d=128)
+    An even number of heads goes two to a grid step, an odd number one. Under
+    this decay a channel too q, k and v may come in bfloat16, as a convolution
+    writes them (the decay stays float32): the kernel widens a tile at a time."""
+    q, k, v, *rest = delta_rule_inputs(length + tile, length, b=1, h=heads, d=128)
+    args = [a.astype(operands) for a in (q, k, v)] + rest
     segs = np.asarray(args[-1])
     assert (segs[:, -1] == 0).all() and (np.diff(segs) != 0).sum() >= 4
     want = recurrent(*args, scale=0.25)

@@ -479,14 +479,18 @@ def test_an_unknown_mixer_or_feed_forward_part_is_named_with_the_ones_there_are(
 
 #: sha256 of ``str(jax.make_jaxpr(f))`` for the programs a PR that means to change
 #: ONE pattern's program must leave as they were, operation for operation:
-#: ``solar`` test_pattern_lm's softmax / delta-rule program (recorded at PR 30, before
-#: the latent-attention layer came; off a TPU the delta rule runs its plain form, so
-#: PR 32's kernel leaves it alone too), as PR 31 left them the recommender's ``forward``
-#: and ``sparse_train_step``, and as PR 40 left it ``kimi``, this file's latent-attention
+#: ``solar`` test_pattern_lm's softmax / delta-rule program (as PR 42 left it: its
+#: delta-rule layers hand q, k and v over behind a barrier in the dtype they were
+#: written, as ``gigachat``'s did already; off a TPU the delta rule runs its plain
+#: form), ``gigachat`` test_gdn_lm's delta-net / latent-attention program in bfloat16
+#: (recorded at PR 42 from PR 41's tree: the shared hand-over left it operation for
+#: operation), as PR 31 left them the recommender's ``forward`` and
+#: ``sparse_train_step``, and as PR 40 left it ``kimi``, this file's latent-attention
 #: program (its mixer's four projections each an array of its own, joined off a TPU alone).
 #: A PR that means to change one of them records its own.
 OLDER_PROGRAMS = {
-    "solar": "80e42af17080e7b14f19cef6c96e5055d12c4ba2668df85bfa2314a54e851896",
+    "solar": "c2d1f66a567aa6a4e8b2558c821d20ad80395c2014c71aeba6e98e3dd190cdab",
+    "gigachat": "d36b4cb8034c876ee4bb3f4a243c8c63ef2d23a177013e5ed13b483926e3def6",
     "kimi": "4d0e48d35253802a8d47e7bee4c761f0ef2730b67b87e565d57d91f97bede802",
     "dlrm_forward": "74937f331a59e45e91ba132bca04da279ac57e7cdc91574931627704728350cc",
     "sparse_train_step": "ea35280a10973a3d8af6c8c0a1a8b17679edde3e8d4005f6012a10f3f8360d9d",
@@ -514,6 +518,13 @@ def older_program(name):
         batch, _ = older.packed_rows()
         return jax.make_jaxpr(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
             params, batch["tokens"], batch["segment_ids"], at, jnp.int32(2))
+    if name == "gigachat":
+        import test_gdn_lm as newer
+
+        cfg = newer.program_cfg(dtype=jnp.bfloat16)
+        rows = jax.ShapeDtypeStruct((2, cfg.max_len + 1), jnp.int32)
+        return jax.make_jaxpr(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h))(
+            shapes_of_params(cfg), rows, rows, at, jnp.int32(1))
     if name == "kimi":
         cfg, batch = program_cfg(), packed_rows()
         params = shapes_of_params(cfg)

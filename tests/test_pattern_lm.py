@@ -232,6 +232,65 @@ def test_the_probes_are_what_the_layers_were_given_and_gave(params):
             np.testing.assert_allclose(gates, routed["gates"][i].reshape(-1, 4), atol=1e-6)
 
 
+def test_what_reaches_the_recurrence_is_what_the_mechanism_has(monkeypatch, params):
+    """q, k and v in the dtype the convolution wrote them, the decay float32 of
+    v's shape (it is not bfloat16-exact), beta one number a head and token:
+    nothing widened outside the recurrence (tests/test_gdn_lm.py's twin)."""
+    seen = []
+
+    def rule(q, k, v, log_decay, beta, segments, scale, chunk):
+        seen.append([(a.shape, a.dtype) for a in (q, k, v, log_decay, beta)])
+        return jnp.zeros(v.shape, jnp.float32)
+
+    monkeypatch.setattr(lm._la, "delta_rule_chunked", rule)
+    cfg = program_cfg(dtype=jnp.bfloat16)
+    layer = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(a.shape, s[1]), params["layers"][1],
+                         lm.pattern_param_shapes(cfg)["layers"][1])
+    x = jax.ShapeDtypeStruct((2, L, 32), jnp.bfloat16)
+    _, probe = jax.eval_shape(
+        lambda p, x: lm.kda_mixer(p, x, jnp.ones((2, L), jnp.int32), cfg, jnp.int32(1)), layer, x)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert seen == [[((2, 4, L, 8), bf16)] * 3 + [((2, 4, L, 8), f32), ((2, 4, L), f32)]]
+    assert {name: (a.shape, a.dtype) for name, a in probe.items()} == {
+        **{name: ((2, L, 8), f32) for name in ("q", "k", "v", "log_decay", "o")}, "beta": ((2, L), f32)}
+
+
+def the_parents_three_lines(p, q, k, v, segments):
+    """The hand-over as both mixers spelled it until PR 42: the oracle of the one there is."""
+    def conv_silu(a, taps):
+        taps = taps.reshape(taps.shape[0], a.shape[1], a.shape[3])
+        return jax.nn.silu(linear_attn.short_conv(a, taps, segments).astype(jnp.float32))
+
+    q = lm._l2_norm(conv_silu(q, p["conv_q"])).astype(q.dtype)
+    k = lm._l2_norm(conv_silu(k, p["conv_k"])).astype(k.dtype)
+    return q, k, conv_silu(v, p["conv_v"]).astype(v.dtype)
+
+
+@pytest.mark.parametrize("key_heads", [4, 2], ids=["a_key_head_a_value_head", "shared_key_heads"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+def test_the_hand_over_is_the_parents_three_lines_bit_for_bit(dtype, key_heads):
+    """Packed rows with a boundary at every tap distance (documents of 1, 2, 3
+    and 4 tokens, then longer ones, then pads): the shared preparation gives q,
+    k and v as the three lines it replaced gave them, to the bit, in the dtype
+    they came in, with key heads as many as value heads or half."""
+    rng = np.random.default_rng(7)
+    lengths = [1, 2, 3, 4, 1, 1, 2, 5, 9, 3, 17]
+    row = np.repeat(np.arange(1, len(lengths) + 1), lengths)
+    segs = jnp.asarray(np.stack([np.r_[row, np.zeros(64 - len(row), np.int32)],
+                                 np.r_[np.ones(30, np.int32), row[:30] + 1, np.zeros(4, np.int32)]]), jnp.int32)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, h, 64, 8)), dtype) for h in (key_heads, key_heads, 4))
+    p = {name: jnp.asarray(rng.standard_normal((4, h * 8)), jnp.float32)
+         for name, h in (("conv_q", key_heads), ("conv_k", key_heads), ("conv_v", 4))}
+    got = jax.jit(lm._handed_over)(p, q, k, v, segs)
+    want = jax.jit(the_parents_three_lines)(p, q, k, v, segs)
+    for name, a, b in zip("qkv", got, want):
+        assert a.dtype == b.dtype == dtype and a.shape == b.shape, name
+        bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+        assert np.array_equal(np.asarray(a).view(bits), np.asarray(b).view(bits)), name
+    np.testing.assert_allclose(np.linalg.norm(np.asarray(got[0], np.float32), axis=-1), 1, atol=1e-2)
+    assert np.abs(np.asarray(got[2], np.float32)).max() > 1
+
+
 def test_off_a_tpu_and_at_other_shapes_the_plain_form_runs(monkeypatch, params):
     """The dispatch reads the backend and the shape, nothing else: here (the
     CPU) every shape takes the plain form and ``kda.fused_layers`` reads 0;

@@ -73,6 +73,13 @@ def _shaped(tree, sharding):
     )
 
 
+def _entry_lines(hlo: str) -> list:
+    """The instructions of the compiled program's ENTRY computation (fused
+    computations repeat their roots' names, so a count reads these alone)."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    return entry[:entry.index("\n}")].splitlines()
+
+
 class TestOneChip:
     def test_device_kind_is_one_the_smoke_knows(self, topo):
         assert topo.devices[0].platform == "tpu"
@@ -169,20 +176,24 @@ class TestOneChip:
 
     def test_the_delta_rule_kernel_at_the_cells_shape(self, one_chip):
         """``solar_open2_ep8.score``'s delta-rule layer, [2, 64, 8192, 128] in
-        chunks of 64 and grid steps of two heads' 256 tokens: the kernel fits VMEM (the
-        compiler refuses one that does not), and nothing of q's size exists
-        beside its four inputs and its output."""
+        chunks of 64 and grid steps of two heads' 256 tokens, q, k and v bfloat16
+        as the convolution writes them and the decay a channel float32: the kernel
+        fits VMEM (the compiler refuses one that does not), the program's
+        arguments are those operands as they are, and nothing of q's size exists
+        beside them and the output."""
         from tpu_tfrecord.models import linear_attn
 
         shape = (2, 64, 8192, 128)
-        x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+        decay = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
         beta = jax.ShapeDtypeStruct(shape[:3], jnp.float32, sharding=one_chip)
         segs = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
         compiled = jax.jit(lambda q, k, v, g, b, s: linear_attn._delta_rule_fused(
-            q, k, v, g, b, s, 128 ** -0.5, linear_attn._TILES[0])).lower(x, x, x, x, beta, segs).compile()
+            q, k, v, g, b, s, 128 ** -0.5, linear_attn._TILES[0])).lower(x, x, x, decay, beta, segs).compile()
         assert "tpu_custom_call" in compiled.as_text()
         mem = compiled.memory_analysis()
         assert mem.output_size_in_bytes == 4 * np.prod(shape)
+        assert mem.argument_size_in_bytes == (3 * 2 + 4) * np.prod(shape) + 4 * np.prod(shape[:3]) + 4 * 2 * 8192
         assert mem.temp_size_in_bytes < 4 * np.prod(shape) // 8
 
     def test_the_delta_rule_kernel_under_one_decay_a_token_at_the_cells_shape(self, one_chip):
@@ -217,9 +228,8 @@ class TestOneChip:
         it: under ``tfr.kda_scan`` the compiled program holds the kernel
         once a layer and no ``while`` (the plain form's loops over head
         groups and chunks), and of float32 arrays of q's size only the
-        kernel's q, k, v and its output (the log-decay is ``tfr.kda_proj``'s)."""
-        import re
-
+        kernel's output (q, k and v come in bfloat16 from ``tfr.kda_conv``, the
+        log-decay is ``tfr.kda_proj``'s)."""
         from tpu_tfrecord.models import linear_attn
 
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -239,13 +249,57 @@ class TestOneChip:
         head = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
         hlo = jax.jit(lambda p, t, s, a, h: lm.score(p, t, s, a, cfg, h)).lower(
             params, rows, rows, at, head).compile().as_text()
-        entry = hlo[hlo.index("\nENTRY "):]                  # fused computations repeat their roots' names
-        scan = [line for line in entry[:entry.index("\n}")].splitlines()
-                if re.search(r'op_name="[^"]*tfr\.kda_scan', line)]
+        scan = [line for line in _entry_lines(hlo) if re.search(r'op_name="[^"]*tfr\.kda_scan', line)]
         assert sum("custom_call_target=\"tpu_custom_call\"" in line for line in scan) == 3
         assert not [line for line in scan if re.search(r"= \S+ while\(", line)]
         q_sized = "f32[" + ",".join(map(str, q_shape)) + "]"
-        assert sum(bool(re.match(rf"\s*(ROOT )?%?\S+ = {re.escape(q_sized)}", line)) for line in scan) <= 4 * 3
+        assert sum(bool(re.match(rf"\s*(ROOT )?%?\S+ = {re.escape(q_sized)}", line)) for line in scan) == 3
+
+    @pytest.mark.parametrize("kind, widths", [
+        ("kda", dict(d_model=4096, gate_rank=128)),
+        ("gdn", dict(d_model=7168, gdn_key_heads=32, centred_norms=True))],
+        ids=["solar_open2_ep8", "gigachat35_ep16"])
+    def test_a_delta_rule_layer_hands_its_kernel_what_the_convolution_wrote(self, one_chip, monkeypatch, kind, widths):
+        """One delta-rule layer of each cell alone at the cell's shape, compiled for
+        the chip as a TPU runs it: under the conv scope no float32 array of v's
+        size (or of q's, where key heads are fewer) exists and five bfloat16 ones
+        are written, v once and q and k twice (the rounded convolution beside its
+        sum of squares, then the normalised array the kernel reads: the compiler
+        stores the former, and on the chip that is faster than reading four
+        shifted windows of the projection again, PERF.md PR 42); under the scan
+        scope the kernel and nothing of that size but its output."""
+        from tpu_tfrecord.models import linear_attn
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = lm.PatternLMConfig(
+            vocab_size=256, layer_pattern=(kind,), ffn_pattern=("dense",), kda_heads=64, kda_head_dim=128,
+            conv_taps=4, max_len=8192, kda_chunk=64, dtype=jnp.bfloat16, **widths)
+        assert linear_attn.fused_tile((2, 64, 8192, 128), cfg.kda_chunk) == linear_attn._TILES[0]
+        layer = lm.pattern_param_shapes(cfg)["layers"][0]
+        p = {name: jax.ShapeDtypeStruct(*sd, sharding=one_chip) for name, sd in layer.items()
+             if name not in ("dense", "ffn_norm")}
+        x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+        head = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        mixer = {"kda": lm.kda_mixer, "gdn": lm.gdn_mixer}[kind]
+        entry = _entry_lines(
+            jax.jit(lambda p, x, s, h: mixer(p, x, s, cfg, h)).lower(p, x, segs, head).compile().as_text())
+
+        def written(scope, dtype, heads):  # arrays [2, heads, 8192, 128] of dtype that operations under scope write
+            sized, count = f"{dtype}[2,{heads},8192,128]", 0
+            for line in entry:
+                if not re.search(rf'op_name="[^"]*tfr\.{kind}_{scope}', line):
+                    continue
+                result, op = re.match(r"\s*(?:ROOT )?\S+ = (.*?) ([a-z][\w\-]*)\(", re.sub(r"\{[^{}]*\}", "", line)).groups()
+                if op not in ("get-tuple-element", "tuple", "bitcast"):     # those name an array again
+                    count += result.count(sized)
+            return count
+
+        sizes = {64, cfg.gdn_key_heads or 64}      # v's heads, and q's and k's where they are fewer
+        assert not any(written("conv", "f32", heads) for heads in sizes)
+        assert sum(written("conv", "bf16", heads) for heads in sizes) <= 2 + 2 + 1
+        assert written("scan", "f32", 64) == 1 and written("scan", "bf16", 64) == 0
+        assert sum("custom_call_target=\"tpu_custom_call\"" in line for line in entry) == 1
 
     def test_the_selection_kernel_at_the_cells_shape(self, one_chip):
         """``deepseek_v32_exp_ep16.score``'s selection, 64 index heads of 128

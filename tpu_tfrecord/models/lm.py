@@ -1270,25 +1270,39 @@ def index_select(p, u, c_q, turn, at, segments, cfg: PatternLMConfig, sample_at=
     return keep, record
 
 
-def _conv_silu(a, taps, segments):
-    """SiLU of the delta-rule layers' short convolution, float32: a [B, H, L, Dh]
-    under ``taps`` [K, H * Dh] that stop at a document's start."""
-    taps = taps.reshape(taps.shape[0], a.shape[1], a.shape[3])
-    return jax.nn.silu(_la.short_conv(a, taps, segments).astype(jnp.float32))
+def _handed_over(p, q, k, v, segments):
+    """What a delta-rule layer hands its recurrence, the one hand-over of both
+    mixers: q, k (at their own heads) and v ``[B, H, L, Dh]`` as the projections
+    wrote them, each under its taps ``p["conv_*"]`` [K, H * Dh] that stop at a
+    document's start (``linear_attn.short_conv``, rounded), SiLU, for q and k a
+    unit norm over the head's channels, rounded once more to the dtype they came
+    in: float32 arithmetic, and nothing float32 as large as v kept. The three
+    stand behind a barrier, so the recurrence and a probe of it read these very
+    arrays, rounded as they are (``linear_attn`` widens a tile at a time)."""
+    def prepared(a, taps, unit: bool):
+        taps = taps.reshape(taps.shape[0], a.shape[1], a.shape[3])
+        s = jax.nn.silu(_la.short_conv(a, taps, segments).astype(jnp.float32))
+        return (_l2_norm(s) if unit else s).astype(a.dtype)
+
+    return jax.lax.optimization_barrier((prepared(q, p["conv_q"], True), prepared(k, p["conv_k"], True),
+                                         prepared(v, p["conv_v"], False)))
 
 
 def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
     """The gated delta-rule layer (models.linear_attn has the equations):
-    projections, a 4-tap convolution and SiLU on q, k, v, unit-norm q and k,
-    a per-channel decay and a per-head beta in (0, 2), the chunked
-    recurrence, a per-head RMSNorm and a low-rank sigmoid gate. Everything
-    per head is head-major ``[B, H, L, D]`` from projection to projection.
+    projections, a 4-tap convolution and SiLU on q, k, v, unit-norm q and k
+    (:func:`_handed_over`: q, k and v reach the recurrence in the dtype the
+    convolution wrote them), a per-channel decay (float32, of v's shape) and
+    a per-head beta in (0, 2), the chunked recurrence, a per-head RMSNorm and
+    a low-rank sigmoid gate. Everything per head is head-major
+    ``[B, H, L, D]`` from projection to projection.
 
     Returns (y, probe). ``probe`` is None unless ``probe_head`` (an int32
     scalar) names a head: then what the chunked recurrence was given and what
     it gave for that head, ``q``, ``k``, ``v``, ``log_decay``, ``o``
-    [B, L, D] and ``beta`` [B, L], all float32, so that a caller can walk the same
-    inputs token by token and see what the state's precision cost."""
+    [B, L, D] and ``beta`` [B, L], float32 for that head alone, so that a
+    caller can walk the same inputs token by token and see what the state's
+    precision cost."""
     d, h, dh, f32 = x.shape[-1], cfg.kda_heads, cfg.kda_head_dim, jnp.float32
 
     def heads(a, w):  # a [B, L, m] through w [m, H * dh] -> [B, H, L, dh]
@@ -1303,21 +1317,13 @@ def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
         beta = 2.0 * jax.nn.sigmoid(jnp.einsum("bld,dh->bhl", u, p["w_beta"]).astype(f32))
         gate = jax.nn.sigmoid(heads(u @ p["g_down"], p["g_up"]).astype(f32))
     with jax.named_scope("tfr.kda_conv"):
-        q = _l2_norm(_conv_silu(q, p["conv_q"], segments)).astype(x.dtype)
-        k = _l2_norm(_conv_silu(k, p["conv_k"], segments)).astype(x.dtype)
-        v = _conv_silu(v, p["conv_v"], segments).astype(x.dtype)
+        q, k, v = _handed_over(p, q, k, v, segments)
     with jax.named_scope("tfr.kda_scan"):
-        # float32 here, not inside: the probe has to hold the very values the
-        # recurrence consumes, and XLA drops a bfloat16 round trip where it can
-        # (the recurrence would see the unrounded q, the probe the rounded one).
-        # :func:`gdn_mixer` shows the cheaper form: the rounded arrays behind a
-        # barrier as they are, float32 for the probed head alone.
-        q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
         o = _la.delta_rule_chunked(q, k, v, log_decay, beta, segments,
                                    scale=dh ** -0.5, chunk=cfg.kda_chunk)
     probe = None
     if probe_head is not None:
-        probe = {name: jnp.take(a, probe_head, axis=1) for name, a in dict(
+        probe = {name: jnp.take(a, probe_head, axis=1).astype(f32) for name, a in dict(
             q=q, k=k, v=v, log_decay=log_decay, beta=beta, o=o).items()}
     with jax.named_scope("tfr.kda_proj"):
         o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
@@ -1332,10 +1338,10 @@ def gdn_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
     ``cfg.gdn_key_heads`` key heads under ``cfg.kda_heads`` value heads (value
     head h reads key head ``h // (kda_heads / gdn_key_heads)``), and a
     full-rank output gate ``2 sigmoid(u wz)`` on the per-head RMSNorm. The
-    recurrence is handed what the mechanism has: q and k at their own heads,
-    v, and a decay and a beta ``[B, H, L]``, q, k and v in the dtype the
-    convolution wrote them (``linear_attn`` widens a tile at a time); nothing
-    as large as v is float32 before the recurrence's own output.
+    recurrence is handed what the mechanism has: q and k at their own heads
+    and v as :func:`_handed_over` leaves them, a decay and a beta
+    ``[B, H, L]``; nothing as large as v is float32 before the recurrence's
+    own output.
 
     Returns (y, probe) as :func:`kda_mixer` does: with ``probe_head`` (a value
     head) that head's ``v``, ``o`` [B, L, D], ``log_decay``, ``beta`` [B, L]
@@ -1356,11 +1362,7 @@ def gdn_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
         log_decay = -jnp.exp(p["a_log"])[:, None] * rate
         beta = jax.nn.sigmoid(by_head(u, p["w_beta"]))
     with jax.named_scope("tfr.gdn_conv"):
-        q = _l2_norm(_conv_silu(q, p["conv_q"], segments)).astype(x.dtype)
-        k = _l2_norm(_conv_silu(k, p["conv_k"], segments)).astype(x.dtype)
-        v = _conv_silu(v, p["conv_v"], segments).astype(x.dtype)
-        # the recurrence and the probe of it read these very arrays, rounded as they are
-        q, k, v = jax.lax.optimization_barrier((q, k, v))
+        q, k, v = _handed_over(p, q, k, v, segments)
     with jax.named_scope("tfr.gdn_scan"):
         o = _la.delta_rule_chunked(q, k, v, log_decay, beta, segments,
                                    scale=dh ** -0.5, chunk=cfg.kda_chunk)
