@@ -318,7 +318,6 @@ def test_a_tpu_hands_the_kernel_the_parts_and_nothing_joined(monkeypatch):
         return jnp.zeros(q.shape[:3] + v.shape[-1:], q.dtype)
 
     monkeypatch.setattr(lm, "flash_attention_widths", stub)
-    monkeypatch.setattr(lm, "_flash_attend", lambda *a: pytest.fail("JAX's kernel takes no second part"))
     cfg = lm.PatternLMConfig(
         vocab_size=64, d_model=32, layer_pattern=("mla",) * 3, ffn_pattern=("dense",) * 3, n_heads=2,
         qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128, kv_rank=16, d_dense=16, max_len=256,

@@ -17,7 +17,9 @@ import numpy as np
 import pytest
 
 from tpu_tfrecord.models import linear_attn, lm, moe, pattern_reference as ref
-from tpu_tfrecord.models.attention import attention_reference, blockwise_attention
+from tpu_tfrecord.models.attention import (
+    attention_reference, blockwise_attention, flash_attention_widths, pair_kinds,
+)
 from tpu_tfrecord.tpu.ingest import TokenPacker
 
 from test_delta_rule import delta_rule_inputs, kernel_inputs
@@ -386,33 +388,109 @@ def test_blockwise_attention_is_the_dense_oracle(block):
     np.testing.assert_allclose(got, want, atol=2e-6)
 
 
-@pytest.mark.parametrize("block", [128, 256])
-def test_the_kernel_a_tpu_runs_is_blockwise_attention(block):
-    """``lm._attend`` runs JAX's Pallas flash kernel on a TPU and
-    ``blockwise_attention`` elsewhere: the kernel, interpreted here (called
-    on a chip, outside pytest's conftest, which pins the CPU, this function
-    runs it as it is), against the plain path on a packed row."""
+@functools.partial(jax.jit, static_argnums=(4,))
+def interpreted_kernel(q, k, v, segs, block):
+    """What ``lm._attend`` runs on a TPU, ``flash_attention_widths`` as it calls it,
+    interpreted: one program a shape."""
     from jax.experimental.pallas import tpu as pltpu
 
-    r = np.random.default_rng(1)
-    q = jnp.asarray(r.standard_normal((1, 4, 512, 128)), jnp.float32)      # [B, H, L, D]
-    k, v = (jnp.asarray(r.standard_normal((1, 2, 512, 128)), jnp.float32) for _ in range(2))
-    segs = np.zeros((1, 512), np.int32)
-    segs[0, :100], segs[0, 100:130], segs[0, 130:400] = 1, 2, 3
-    segs = jnp.asarray(segs)
-    want = jax.jit(lambda q, k, v, segs: jnp.swapaxes(blockwise_attention(
-        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segs, block=64), 1, 2))(
-            q, k, v, segs)
-    if jax.default_backend() == "tpu":
-        got = lm._attend(q, k, v, segs, block)
-    else:
-        with pltpu.force_tpu_interpret_mode():
-            got = jax.jit(functools.partial(lm._flash_attend, block=block))(q, k, v, segs)
+    with pltpu.force_tpu_interpret_mode():
+        return flash_attention_widths(q, k, v, segs, q.shape[-1] ** -0.5, block, block)
+
+
+plain_attention = jax.jit(lambda q, k, v, segs: jnp.swapaxes(blockwise_attention(
+    jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segs, block=64), 1, 2))
+
+
+def held_to_the_plain_path(q, k, v, segs, block):
+    """The kernel (interpreted here; called on a chip, outside pytest's conftest,
+    which pins the CPU, ``lm._attend`` runs it as it is) against the plain path."""
+    want = plain_attention(q, k, v, segs)
+    got = (lm._attend(q, k, v, segs, block) if jax.default_backend() == "tpu"
+           else interpreted_kernel(q, k, v, segs, block))
     real = np.asarray(segs[0] != 0)
     # on a chip both paths multiply float32 at the default precision (one bfloat16
     # pass): they agree to that rounding; a mask gone wrong moves the answer by 0.3 and more
     np.testing.assert_allclose(np.asarray(got)[:, :, real], np.asarray(want)[:, :, real],
                                atol=3e-2 if jax.default_backend() == "tpu" else 2e-5)
+
+
+@pytest.mark.parametrize("block", [128, 256])
+def test_the_kernel_a_tpu_runs_is_blockwise_attention(block):
+    """``lm._attend`` runs the repo's Pallas kernel on a TPU, the full softmax
+    layer's grouped heads on the K/V heads as they lie, and
+    ``blockwise_attention`` elsewhere: the kernel against the plain path on a
+    packed row: 4 query heads on 2 K/V heads, three documents and a pad tail."""
+    r = np.random.default_rng(1)
+    q = jnp.asarray(r.standard_normal((1, 4, 512, 128)), jnp.float32)      # [B, H, L, D]
+    k, v = (jnp.asarray(r.standard_normal((1, 2, 512, 128)), jnp.float32) for _ in range(2))
+    segs = np.zeros((1, 512), np.int32)
+    segs[0, :100], segs[0, 100:130], segs[0, 130:400] = 1, 2, 3
+    held_to_the_plain_path(q, k, v, jnp.asarray(segs), block)
+
+
+def test_eight_query_heads_read_one_key_head_and_disjoint_blocks_are_skipped():
+    """The grouping at its widest (8 query heads on ONE K/V head: a grid step's two
+    heads read the same key block) on a row whose second half is a document of its
+    own: the block pairs that hold the second document's queries and the first's
+    keys share no document and are skipped (4 of the 10 at or under the diagonal
+    in blocks of 128), and the answer is the plain path's."""
+    r = np.random.default_rng(2)
+    q = jnp.asarray(r.standard_normal((1, 8, 512, 128)), jnp.float32)
+    k, v = (jnp.asarray(r.standard_normal((1, 1, 512, 128)), jnp.float32) for _ in range(2))
+    rows = np.repeat(np.array([1, 1, 2, 2], np.int32), 128)[None]
+    # query blocks 2 and 3 against key blocks 0 and 1: four pairs skipped; the second document's
+    # one pair under the diagonal and the first's are plain; the four on the diagonal masked
+    assert pair_kinds(rows, 128, 128) == (4, 2, 4)
+    segs = jnp.asarray(rows)
+    held_to_the_plain_path(q, k, v, segs, 128)
+    # the skipping is at work: a pair computed and masked would weigh the first document's
+    # values by 0, and 0 x NaN is NaN; a pair skipped reads none of them
+    nan = jnp.full((1, 1, 256, 128), jnp.nan, jnp.float32)
+    poisoned = interpreted_kernel(q, k.at[:, :, :256].set(nan), v.at[:, :, :256].set(nan), segs, 128)
+    np.testing.assert_allclose(np.asarray(poisoned)[:, :, 256:],
+                               np.asarray(plain_attention(q, k, v, segs))[:, :, 256:], atol=2e-5)
+
+
+def equations(jaxpr):
+    """The equations of ``jaxpr`` and of every jaxpr inside them."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from equations(inner)
+
+
+def test_a_tpu_hands_the_kernel_the_key_heads_as_they_lie(monkeypatch):
+    """The full softmax layer as a TPU traces it (the backend stubbed): one Pallas
+    call, handed K and V as the 2 heads the projections wrote under 8 query heads.
+    No operation but the query's and the gate's projections, the gate and the
+    kernel itself makes an array of the query heads' shape: no ``repeat`` of K or
+    V (a ``broadcast_in_dim`` and a reshape), no copy a query head."""
+    monkeypatch.setattr(lm.jax, "default_backend", lambda: "tpu")
+    cfg = lm.PatternLMConfig(
+        vocab_size=64, d_model=32, layer_pattern=("gqa",), ffn_pattern=("dense",), n_heads=8,
+        n_kv_heads=2, head_dim=128, d_dense=16, max_len=256, attn_block=128, dtype=jnp.float32)
+    p = jax.eval_shape(lambda: lm.pattern_init_params(jax.random.PRNGKey(0), cfg))["layers"][0]
+    x, segs = jax.ShapeDtypeStruct((1, 256, 32), jnp.float32), jax.ShapeDtypeStruct((1, 256), jnp.int32)
+    eqns = list(equations(jax.make_jaxpr(lambda p, x, s: lm.gqa_mixer(p, x, s, cfg))(p, x, segs).jaxpr))
+    (kernel,) = [e for e in eqns if e.primitive.name == "pallas_call"]
+    heads, held = (1, 8, 256, 128), (1, 2, 256, 128)
+    assert [v.aval.shape for v in kernel.invars[-3:]] == [heads, held, held]
+    # what is as large as the query heads (or a repeat's [1, 2, 4, 256, 128] on its way there): q
+    # and the gate, each transposed from its product; the kernel's output and its jitted call
+    # site; the gate's sigmoid and its product with the output
+    wide = sorted(e.primitive.name for e in eqns for out in e.outvars
+                  if getattr(out.aval, "shape", ()) in (heads, (1, 2, 4, 256, 128)))
+    assert wide == ["jit", "logistic", "mul", "pallas_call", "transpose", "transpose"]
+    # and K and V go from their projections to the call and nowhere else
+    (site,) = [e for e in eqns if e.primitive.name == "jit" and e.outvars[0].aval.shape == heads]
+    for operand in site.invars[1:3]:
+        assert operand.aval.shape == held
+        assert [e for e in eqns if operand in e.invars] == [site]
+        assert [e.primitive.name for e in eqns if operand in e.outvars] == ["transpose"]
 
 
 def moe_layer(seed=0, t=96, skew=0.0):

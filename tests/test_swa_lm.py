@@ -23,7 +23,7 @@ from tpu_tfrecord.models import lm, swa_reference as ref
 from tpu_tfrecord.models.attention import (
     attention_reference, blockwise_attention, flash_attention_widths, pair_kinds)
 
-from test_pattern_lm import (SAMPLE_AT, documents_of, flat, held_experts, init_params,
+from test_pattern_lm import (SAMPLE_AT, documents_of, equations, flat, held_experts, init_params,
                              packed_rows as older_rows, reference_weights, score, the_benchmarks_copy)
 
 #: a configuration with the published names, tiny: published layers 1-5 of a (sliding x 3,
@@ -134,7 +134,7 @@ def test_a_packed_row_scores_each_document_as_the_reference_scores_it_alone(para
     assert (out["visits"].sum(axis=1) == real * CFG["num_experts_per_tok"]).all()
     assert routed["u"].shape == (4, 2, 4, 32) and routed["q_swa"].shape == (1, 2, 4, 8)
     # off a TPU no layer takes the kernel; rows of 48 in blocks of 16 against 8 keys: 5 of 6 pairs
-    assert METRICS.gauge_value("swa.kernel_layers") == 0
+    assert METRICS.gauge_value("swa.kernel_layers") == 0 and METRICS.gauge_value("gqa.kernel_layers") == 0
     assert METRICS.gauge_value("swa.pairs_walked_share") == round(5 / 6, 6)
 
 
@@ -271,16 +271,8 @@ def test_the_band_of_one_long_document_is_150_pairs_of_528():
 
 
 def operations(jaxpr):
-    """The equations of ``jaxpr`` and of every jaxpr inside them."""
-    count = 0
-    for eqn in jaxpr.eqns:
-        count += 1
-        for value in eqn.params.values():
-            for inner in value if isinstance(value, (list, tuple)) else [value]:
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    count += operations(inner)
-    return count
+    """How many equations ``jaxpr`` and every jaxpr inside them hold."""
+    return sum(1 for _ in equations(jaxpr))
 
 
 @pytest.mark.parametrize("window, at_the_parent, with_parts", [(None, 187, 202), (300, 336, 366)])
@@ -306,15 +298,14 @@ def test_without_a_rotary_part_the_kernels_body_is_the_one_it_was(window, at_the
 
 
 def test_the_kernel_is_what_a_tpu_runs_under_a_window(monkeypatch, params):
-    """On a TPU ``_attend`` hands a windowed layer to the repo's kernel and a full
-    one, as before, to JAX's; both stubbed here, the dispatch and the gauge read."""
+    """On a TPU ``_attend`` hands a windowed layer and, since PR 43, a full one to the
+    repo's one kernel, the full one without a window; stubbed here, the dispatch and
+    the gauges read."""
     seen = []
     monkeypatch.setattr(lm.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(lm, "flash_attention_widths",
                         lambda q, k, v, segs, scale, bq, bk, keep=None, window=None:
                         seen.append(("own", window)) or jnp.zeros(q.shape[:3] + v.shape[-1:], q.dtype))
-    monkeypatch.setattr(lm, "_flash_attend", lambda q, k, v, segs, block:
-                        seen.append(("jax", None)) or jnp.zeros_like(q))
     cfg = lm.PatternLMConfig(
         vocab_size=64, d_model=32, layer_pattern=("swa", "gqa", "swa"), ffn_pattern=("dense",) * 3,
         n_heads=2, n_kv_heads=1, head_dim=128, window=256, qk_norm=True, branch_norms=True,
@@ -322,8 +313,9 @@ def test_the_kernel_is_what_a_tpu_runs_under_a_window(monkeypatch, params):
     p = jax.eval_shape(lambda: lm.pattern_init_params(jax.random.PRNGKey(0), cfg))
     tokens = jax.ShapeDtypeStruct((1, 513), jnp.int32)
     jax.eval_shape(lambda p, t: lm.score(p, t, t, jnp.zeros((1, 2), jnp.int32), cfg), p, tokens)
-    assert seen == [("own", 256), ("jax", None), ("own", 256)]
+    assert seen == [("own", 256), ("own", None), ("own", 256)]
     assert METRICS.gauge_value("swa.kernel_layers") == 2
+    assert METRICS.gauge_value("gqa.kernel_layers") == 1
     assert METRICS.gauge_value("swa.pairs_walked_share") == round(9 / 10, 6)
 
 

@@ -373,6 +373,47 @@ class TestOneChip:
         assert re.findall(heads + r"128\]", text) and re.findall(heads + r"64\]", text)
         assert re.findall(rf"\[{rows},1,{l},64\]", text)                 # the rotary keys: one head
 
+    @pytest.mark.parametrize("rows, l, pairs, widths", [
+        (2, 8192, 36, dict(d_model=4096, n_heads=64)),
+        (1, 32768, 528, dict(d_model=3072, n_heads=48, qk_norm=True))],
+        ids=["solar_open2_ep8", "trinity_large_ep8"])
+    def test_a_full_softmax_layer_reads_its_key_heads_as_they_lie(self, one_chip, monkeypatch, rows, l,
+                                                                 pairs, widths):
+        """The two cells' full layers (``gqa_mixer`` alone), compiled for the chip as a
+        TPU runs them since PR 43: the repo's kernel without a window under the
+        kernel's own limit of fast memory (the compiler refuses a kernel that
+        passes ``_VMEM_LIMIT``), its grid the row's block pairs at or under the
+        diagonal, and K and V handed to it as the 8 heads the projections wrote:
+        of the query heads' shape the program holds q and the kernel's output and
+        nothing else: no K or V copied 8 or 6 times, a quarter and more of the
+        layer's temporaries when JAX's kernel was handed them."""
+        from tpu_tfrecord.models.attention import _grid_pairs
+
+        monkeypatch.setattr(lm.jax, "default_backend", lambda: "tpu")     # the described chip's branch
+        cfg = lm.PatternLMConfig(
+            vocab_size=256, layer_pattern=("gqa",), ffn_pattern=("dense",), n_kv_heads=8, head_dim=128,
+            max_len=l, attn_block=1024, dtype=jnp.bfloat16, **widths)
+        layer = lm.pattern_param_shapes(cfg)["layers"][0]
+        p = {name: jax.ShapeDtypeStruct(*sd, sharding=one_chip) for name, sd in layer.items()
+             if name not in ("dense", "ffn_norm")}
+        x = jax.ShapeDtypeStruct((rows, l, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((rows, l), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(lambda p, x, s: lm.gqa_mixer(p, x, s, cfg)).lower(p, x, segs).compile()
+        text = compiled.as_text()
+        entry = _entry_lines(text)
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+        (call,) = [line for line in entry if "tpu_custom_call" in line]
+        heads, held = f"bf16[{rows},{cfg.n_heads},{l},128]", f"bf16[{rows},8,{l},128]"
+        made, read = ([line.split(" = ")[0].strip() for line in entry if f" = {shape}" in line]
+                      for shape in (heads, held))
+        assert len(made) == 2 and call.split(" = ")[0].strip() in made        # q, and what the kernel wrote
+        assert len(read) == 2 and all(f"{name}," in call or f"{name})" in call for name in read)   # k and v
+        assert not re.findall(re.escape(heads) + r"[^ ]* (broadcast|copy)\(", text)
+        # the grid's third axis: the two tables of block indices, a pair an entry
+        assert len(_grid_pairs(l, 1024, 1024)) == pairs and text.count(f"s32[{pairs}]") >= 2
+        q_bytes = 2 * rows * cfg.n_heads * l * 128
+        assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * q_bytes   # q, the gate, the output's share
+
     def test_the_windowed_attention_kernel_at_the_cells_shape(self, one_chip):
         """``trinity_large_ep8.score``'s sliding layers: 48 query heads on 8
         key-value heads of 128 over one row of 32,768 tokens under 4,096 keys.

@@ -995,25 +995,8 @@ def _l2_norm(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
 
 
-def _flash_attend(q, k, v, segments, block: int):
-    """JAX's Pallas TPU flash-attention kernel, causal inside ``segments``:
-    a block pair's scores stay on the chip (22 against 96 ms on a v5e for
-    2 x 8,192 tokens and 64 heads, where ``blockwise_attention`` writes
-    them to memory). Shapes as :func:`_attend`."""
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
-
-    rep = q.shape[1] // k.shape[1]
-    block = min(block, q.shape[2])
-    return fa.flash_attention(
-        q, jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1),
-        segment_ids=fa.SegmentIds(q=segments, kv=segments), causal=True,
-        sm_scale=q.shape[-1] ** -0.5,
-        block_sizes=fa.BlockSizes(block_q=block, block_k_major=block,
-                                  block_k=min(512, block), block_b=1))
-
-
 def _takes_kernel(l: int, dv: int, block: int) -> bool:
-    """Whether :func:`_attend` runs a Pallas kernel for rows of ``l`` tokens
+    """Whether :func:`_attend` runs its Pallas kernel for rows of ``l`` tokens
     and values ``dv`` wide: on a TPU, whole blocks of whole 128s."""
     tile = min(block, l)
     return jax.default_backend() == "tpu" and dv % 128 == 0 and tile % 128 == 0 and l % tile == 0
@@ -1029,29 +1012,30 @@ def _attend(q, k, v, segments, block: int, scale=None, keep=None, window=None):
     rotary part: (q [B, H, L, D], q_rope [B, H, L, R]) and (k [B, Hkv, L, D],
     k_rope [B, 1, L, R]), the keys' one head shared by all): a score is the
     product over D plus the product over R. Scores are scaled by ``scale``
-    ((D + R) ** -0.5 where none is given). On a TPU,
-    for rows of whole blocks of 128s, a Pallas kernel: JAX's own where q, k
-    and v share a width of whole 128s (it takes no other) and nothing but
-    the document narrows the keys (it has no window, no selection and no
-    second part, and is handed K and V copied once a query head), and
-    ``attention.flash_attention_widths`` otherwise: with a second part, which
-    it is handed as it is, so that no array as wide as both parts exists and
-    the one rotary key head is read by every head's block from where it
-    lies; under a selection; and under a window, where its grid walks the
-    band of block pairs alone (a 4,096-key window over 32,768 tokens in
-    blocks of 1,024: 150 pairs of 528, of a query block's five the oldest
-    compared against the window, the newest against the diagonal, the three
-    between neither; grouped heads read their one K and V). That kernel sees from two
-    block indices and four segment ids what a pair of blocks needs: nothing;
-    segment ids and no position (under the diagonal: 120 of the 136 pairs of
-    a 16,384-token document, where every key is seen and the compare is
-    hidden under the products); or positions too and half the keys (on the
-    diagonal); ``record_pair_kinds`` counts them for a step's rows. Float32
-    scores, maximum, sum and accumulator and ``block`` are the same in
-    every kind. Elsewhere (the kernels exist for no other backend), and for
-    shapes neither takes, ``attention.blockwise_attention``: plain JAX, the
-    same mask, the same answer (tests/test_pattern_lm.py,
-    tests/test_mla_lm.py and tests/test_dsa_lm.py hold each kernel to it);
+    ((D + R) ** -0.5 where none is given). On a TPU, for rows of whole blocks
+    of 128s, every layer takes ONE Pallas kernel,
+    ``attention.flash_attention_widths``: the full softmax layer, whose
+    grouped K and V heads it reads where the projections wrote them (8 heads
+    under 48 or 64: no copy a query head exists); a second part, which it is
+    handed as it is, so that no array as wide as both parts exists and the
+    one rotary key head is read by every head's block from where it lies; a
+    selection; and a window, where its grid walks the band of block pairs
+    alone (a 4,096-key window over 32,768 tokens in blocks of 1,024: 150
+    pairs of 528, of a query block's five the oldest compared against the
+    window, the newest against the diagonal, the three between neither).
+    That kernel sees from two block indices and four segment ids what a pair
+    of blocks needs: nothing (the blocks share no document: 28 in 100 of the
+    pairs of packed rows of 8,192 tokens, counted); segment ids and no position (under the
+    diagonal: 120 of the 136 pairs of a 16,384-token document, where every
+    key is seen and the compare is hidden under the products); or positions
+    too and half the keys (on the diagonal: 10 of a pair's 16 tiles);
+    ``record_pair_kinds`` counts them for a step's rows. Float32 scores,
+    maximum, sum and accumulator, probabilities in the values' type into the
+    second product, and ``block`` are the same in every kind. Elsewhere (the
+    kernel exists for no other backend), and for shapes it does not take,
+    ``attention.blockwise_attention``: plain JAX, the same mask, the same
+    answer (tests/test_pattern_lm.py, tests/test_mla_lm.py,
+    tests/test_swa_lm.py and tests/test_dsa_lm.py hold the kernel to it);
     there, and only there, the two parts are joined and the rotary key head
     is copied to every head."""
     parts = {}
@@ -1059,8 +1043,6 @@ def _attend(q, k, v, segments, block: int, scale=None, keep=None, window=None):
         (q, parts["q_rope"]), (k, parts["k_rope"]) = q, k
     (l, d), dv = q.shape[2:], v.shape[-1]
     if _takes_kernel(l, dv, block):
-        if d == dv and scale is None and keep is None and window is None and not parts:
-            return _flash_attend(q, k, v, segments, block)
         width = d + (parts["q_rope"].shape[-1] if parts else 0)
         return flash_attention_widths(q, k, v, segments, scale or width ** -0.5, block, block,
                                       keep=keep, window=window, **parts)
@@ -1523,8 +1505,10 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     # and the attention call of every latent-attention layer: handed q and k in their two parts
     in_kernel = _takes_kernel(cfg.max_len, cfg.v_head_dim, cfg.attn_block)
     METRICS.gauge("mla.split_layers", cfg.layer_pattern.count("mla") if in_kernel else 0)
-    if "swa" in cfg.layer_pattern:  # and the window: one shape for every sliding layer
-        in_kernel = _takes_kernel(cfg.max_len, cfg.head_dim, cfg.attn_block)
+    # and of every softmax layer, full or under a window: grouped K/V heads read as they lie
+    in_kernel = _takes_kernel(cfg.max_len, cfg.head_dim, cfg.attn_block)
+    METRICS.gauge("gqa.kernel_layers", cfg.layer_pattern.count("gqa") if in_kernel else 0)
+    if "swa" in cfg.layer_pattern:  # one shape for every sliding layer
         METRICS.gauge("swa.kernel_layers", cfg.layer_pattern.count("swa") if in_kernel else 0)
         tile = min(cfg.attn_block, cfg.max_len)
         one_document = np.ones((1, -(-cfg.max_len // tile) * tile), np.int32)

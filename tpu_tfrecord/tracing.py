@@ -69,7 +69,8 @@ ANNOTATIONS = {
                          "of slots, inside the loop over the blocks that hold runs",
     "tfr.embed": "pattern LM (models.lm.score): the token rows gathered from the embedding",
     "tfr.gqa": "pattern LM: the softmax layer without a window (norm, projections, Q/K norm, the "
-               "attention call: a flash kernel on a TPU, blockwise attention elsewhere; gate, out, branch norm)",
+               "attention call: on a TPU attention.flash_attention_widths, the 8 K/V heads read as they lie and "
+               "block pairs that share no document skipped, blockwise attention elsewhere; gate, out, branch norm)",
     "tfr.swa_proj": "pattern LM: a sliding-window layer's norm, five projections, Q/K norm, rotary "
                     "turns, gate, out, branch norm",
     "tfr.swa_attn": "pattern LM: a sliding-window layer's attention call alone (the band of block "

@@ -181,7 +181,7 @@ STAGES: Dict[str, str] = {
     "compile.lower": "a program lowered to an MLIR module (every Pallas body's lowering to Mosaic is in here)",
     "compile.backend": "a program's backend compile, or the cache read and deserialisation that stood in for it",
     "compile.cache_read": "the persistent cache read inside a compile.backend that hit",
-    "kernel.trace.mla_attn": "the latent-attention Pallas kernel built while a program is traced (attention._flash_widths_call)",
+    "kernel.trace.mla_attn": "the attention Pallas kernel built while a program is traced (attention._flash_widths_call: a latent, a windowed or, since PR 43, a full softmax layer's; the name is its first user's)",
     "kernel.trace.kda_scan": "the delta-rule Pallas kernel built while a program is traced (linear_attn._delta_rule_fused, under either form of the decay)",
     "kernel.trace.dsa_index": "the selection Pallas kernel built while a program is traced (sparse_attn._select_fused)",
     "kernel.trace.interaction": "the dot-interaction Pallas kernel built while a program is traced (interaction.dot_interaction_pallas)",
@@ -215,6 +215,7 @@ GAUGES: Dict[str, str] = {
     "mla.split_layers": "pattern LM, the score program last traced: latent-attention layers whose attention kernel was handed q and k in their two parts, plain and rotary, never joined in memory (0 off a TPU)",
     "dsa.selected_share": "pattern LM, latest step recorded: keys the indexers kept over the causal candidates they chose from (lm.record_selected)",
     "mla.plain_pair_share": "pattern LM, latest step recorded: of the block pairs the latent-attention kernel computes, those wholly under the diagonal of one document, where every key is seen (lm.record_pair_kinds)",
+    "gqa.kernel_layers": "pattern LM, the score program last traced: full softmax layers whose attention took the Pallas kernel, grouped K/V heads read as the projections wrote them, never copied to the query heads (0 off a TPU)",
     "swa.kernel_layers": "pattern LM, the score program last traced: sliding-window layers whose attention took the Pallas kernel under a window (0 off a TPU)",
     "swa.pairs_walked_share": "pattern LM, the score program last traced: the block pairs a sliding-window layer walks (the band) over the pairs at or under the diagonal of a row",
     "moe.gate_entropy": "latest per-step router gate entropy",
@@ -266,7 +267,7 @@ SPANS: Dict[str, str] = {
     "compile.lower": "one program's lowering",
     "compile.backend": "one program's backend compile or cache read",
     "compile.cache_read": "one persistent cache read",
-    "kernel.trace.mla_attn": "one build of the latent-attention kernel",
+    "kernel.trace.mla_attn": "one build of the attention kernel (a latent, a windowed or a full softmax layer's)",
     "kernel.trace.kda_scan": "one build of the delta-rule kernel",
     "kernel.trace.dsa_index": "one build of the selection kernel",
     "kernel.trace.interaction": "one build of the dot-interaction kernel",
