@@ -12,7 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_tfrecord.models import linear_attn, pattern_reference as ref
+from benchmark.models import solar_open2 as ref
+from tpu_tfrecord.models import linear_attn
 
 #: One program a shape for the process: cases that differ in their data find
 #: it built (called bare, a scan or a kernel is compiled anew at every call).

@@ -11,19 +11,19 @@ parameter count against ISSUE 33's arithmetic."""
 
 import math
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.models import deepseek_v32 as ref
 from tpu_tfrecord.metrics import METRICS
-from tpu_tfrecord.models import dsa_reference as ref, lm, moe, sparse_attn
+from tpu_tfrecord.models import lm, moe, sparse_attn
 
 from test_mla_lm import interpreted_kernel, kernel_inputs, mixer, plain_path, scopes_held
 from test_pattern_lm import (SAMPLE_AT, documents_of, flat, held_experts, init_params,
-                             packed_rows as older_rows, reference_weights, score, the_benchmarks_copy)
+                             packed_rows as older_rows, reference_weights, score)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -437,11 +437,8 @@ def published():
 def test_the_cut_holds_the_parameters_issue_33_counted():
     """``pattern_param_shapes`` of the benchmark's configuration: every norm
     and bias counted; no difference from the issue's arithmetic."""
-    sys.path.insert(0, ROOT)
-    from benchmark.models import deepseek_v32 as copy
-
     cfg = published()
-    shapes = lm.pattern_param_shapes(copy.program(cfg, {"row_tokens": 16384}))
+    shapes = lm.pattern_param_shapes(ref.program(cfg, {"row_tokens": 16384}))
 
     def count(tree):
         return sum(int(np.prod(shape)) for shape, _ in jax.tree.leaves(tree, is_leaf=lm._is_shape))
@@ -456,7 +453,7 @@ def test_the_cut_holds_the_parameters_issue_33_counted():
     assert len(shapes["layers"]) == 5 and count(shapes) == 4_635_518_208
     # the benchmark's own count of the same cut
     assert sum(int(np.prod(shape)) for part in ["embed", "head", *range(5)]
-               for shape, *_ in copy.weight_specs(cfg, part).values()) == 4_635_518_208
+               for shape, *_ in ref.weight_specs(cfg, part).values()) == 4_635_518_208
 
 
 @pytest.mark.parametrize("loads, held, cap, target, want", [
@@ -473,10 +470,7 @@ def test_the_cut_holds_the_parameters_issue_33_counted():
     ([0, 0, 7, 16, 0, 0], 3, 15, 12, [0, 2, 3]),
 ])
 def test_the_benchmarks_placement_picks_light_experts_near_the_share(loads, held, cap, target, want):
-    sys.path.insert(0, ROOT)
-    from benchmark.models import deepseek_v32 as copy
-
-    assert copy.pick_experts(loads, held, cap, target) == want
+    assert ref.pick_experts(loads, held, cap, target) == want
 
 
 @pytest.mark.parametrize("seed", [5, 6])
@@ -499,11 +493,6 @@ def test_naming_a_groups_experts_anew_changes_no_tokens_routing(seed):
         a, b = np.argsort(np.asarray(was[0][t])), np.argsort(named[t])
         assert (np.asarray(was[0][t])[a] == named[t][b]).all()
         np.testing.assert_allclose(np.asarray(was[1][t])[a], np.asarray(now[1][t])[b], rtol=1e-6)
-
-
-def test_the_benchmarks_copy_of_the_reference_is_this_one():
-    copy = the_benchmarks_copy(ref, "deepseek_v32", 9)
-    assert copy.SORT_ROWS == ref.SORT_ROWS and copy.HEAD_ROWS == ref.HEAD_ROWS
 
 
 def test_the_compiled_program_holds_every_scope(params):

@@ -15,11 +15,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.models import gigachat35 as ref
+from benchmark.models.solar_open2 import ref_delta_rule
 from tpu_tfrecord.metrics import METRICS
-from tpu_tfrecord.models import gdn_reference as ref, linear_attn, lm
+from tpu_tfrecord.models import linear_attn, lm
 
 from test_pattern_lm import (SAMPLE_AT, documents_of, flat, held_experts, init_params,
-                             packed_rows as older_rows, reference_weights, score, the_benchmarks_copy)
+                             packed_rows as older_rows, reference_weights, score)
 
 #: a configuration with the published names, tiny: published layers 2-6 of a (linear x 3,
 #: full) period, the first of them dense; 2 key heads under 4 value heads
@@ -179,8 +181,6 @@ def test_a_state_carried_across_a_boundary_is_not_what_the_program_gives(params,
 def test_the_probed_recurrence_walked_again_is_what_the_layer_gave(scored):
     """One value head's probe: the rule token by token from an empty state over
     the very q, k, v, decay and beta it was given, document by document."""
-    from tpu_tfrecord.models.pattern_reference import ref_delta_rule
-
     _, batch, out = scored
     scan, checked = out["probes"]["scan"], 0
     with jax.default_matmul_precision("highest"):
@@ -274,10 +274,6 @@ def test_the_sixteen_shares_of_64_experts_add_up_to_the_uncut_layer(limit):
     assert visits == x.shape[0] * 8
     np.testing.assert_allclose(total, whole, atol=5e-5)
     assert (float(np.abs(np.asarray(whole) - np.asarray(loose)).max()) > 1e-2) == (limit < 1)
-
-
-def test_the_benchmarks_copy_of_the_reference_is_this_one():
-    assert the_benchmarks_copy(ref, "gigachat35", 12).HEAD_ROWS == ref.HEAD_ROWS
 
 
 def test_the_compiled_program_holds_every_scope(params):

@@ -15,11 +15,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_tfrecord.models import linear_attn, lm, mla_reference as ref, moe
+from benchmark.models import kimi_vl_lm as ref
+from tpu_tfrecord.models import linear_attn, lm, moe
 from tpu_tfrecord.models.attention import blockwise_attention, flash_attention_widths, pair_kinds
 
 from test_pattern_lm import (SAMPLE_AT, documents_of, flat, held_experts, init_params,
-                             packed_rows as older_rows, reference_weights, score, the_benchmarks_copy)
+                             packed_rows as older_rows, reference_weights, score)
 
 #: a configuration with the published names, tiny: one dense layer, two expert layers
 CFG = {
@@ -569,10 +570,6 @@ def test_solars_kernel_is_the_one_it_was(heads, tile, length):
     program = jax.make_jaxpr(lambda q, k, v, g, b, s: linear_attn._delta_rule_fused(
         q, k, v, g, b, s, 0.25, tile, interpret=True))(x, x, x, x, beta, segs)
     assert hashlib.sha256(str(program).encode()).hexdigest() == SOLARS_KERNEL[heads, tile, length]
-
-
-def test_the_benchmarks_copy_of_the_reference_is_this_one():
-    assert the_benchmarks_copy(ref, "kimi_vl_lm", 8).HEAD_ROWS == ref.HEAD_ROWS
 
 
 def scopes_held(params, batch, cfg):

@@ -7,24 +7,20 @@ of the experts against the uncut layer, dropless routing under a skewed
 router, and the packer at L 8192."""
 
 import functools
-import inspect
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_tfrecord.models import linear_attn, lm, moe, pattern_reference as ref
+from benchmark.models import solar_open2 as ref
+from tpu_tfrecord.models import linear_attn, lm, moe
 from tpu_tfrecord.models.attention import (
     attention_reference, blockwise_attention, flash_attention_widths, pair_kinds,
 )
 from tpu_tfrecord.tpu.ingest import TokenPacker
 
 from test_delta_rule import delta_rule_inputs, kernel_inputs
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: One program a shape for the process: cases that differ in their data find it
 #: built (bare, these run primitive by primitive, each a compile). A case that reads
@@ -577,21 +573,3 @@ def test_a_restored_packer_keeps_its_running_fill():
         y = b.pop()
         assert (x["tokens"] == y["tokens"]).all() and (x["segment_ids"] == y["segment_ids"]).all()
     assert b.pop() is None
-
-
-def the_benchmarks_copy(ref, of: str, least: int):
-    """``benchmark.models.<of>``, holding each of ``ref``'s functions line for line."""
-    import importlib
-
-    sys.path.insert(0, ROOT)
-    copy = importlib.import_module(f"benchmark.models.{of}")
-    names = [n for n, f in inspect.getmembers(ref, inspect.isfunction)
-             if f.__module__ == ref.__name__]
-    assert "reference_score" in names and len(names) >= least
-    for name in names:
-        assert inspect.getsource(getattr(ref, name)) == inspect.getsource(getattr(copy, name)), name
-    return copy
-
-
-def test_the_benchmarks_copy_of_the_reference_is_this_one():
-    the_benchmarks_copy(ref, "solar_open2", 10)

@@ -18,13 +18,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.models import trinity_large as ref
 from tpu_tfrecord.metrics import METRICS
-from tpu_tfrecord.models import lm, swa_reference as ref
+from tpu_tfrecord.models import lm
 from tpu_tfrecord.models.attention import (
     attention_reference, blockwise_attention, flash_attention_widths, pair_kinds)
 
 from test_pattern_lm import (SAMPLE_AT, documents_of, equations, flat, held_experts, init_params,
-                             packed_rows as older_rows, reference_weights, score, the_benchmarks_copy)
+                             packed_rows as older_rows, reference_weights, score)
 
 #: a configuration with the published names, tiny: published layers 1-5 of a (sliding x 3,
 #: full) period, the first of them dense; documents of up to 30 tokens against 8 keys
@@ -383,10 +384,6 @@ def test_branch_norms_join_every_kind_of_mixer():
         layer["post_attn_norm"], layer["post_ffn_norm"] = (jnp.zeros_like(layer[k]) for k in (
             "post_attn_norm", "post_ffn_norm"))
     np.testing.assert_array_equal(hidden(p), p["embed"][batch["tokens"][:, :-1]])
-
-
-def test_the_benchmarks_copy_of_the_reference_is_this_one():
-    assert the_benchmarks_copy(ref, "trinity_large", 6).QUERY_ROWS == ref.QUERY_ROWS
 
 
 def test_the_compiled_program_holds_every_scope(params):

@@ -1,28 +1,28 @@
-"""Reference consumers of the ingestion pipeline.
+"""The models that consume the ingestion pipeline: what runs on the chip.
 
 The reference framework ships no models (SURVEY.md §2: model-side parallelism
-N/A) — its output is consumed by TensorFlow training jobs. Here two model
-families are in-tree:
+N/A) — its output is consumed by TensorFlow training jobs. Two families run
+in the benchmark's cells (``BENCHMARK.json``), the rest in tests and examples:
 
-- ``dlrm``: a Criteo-style DLRM (the BASELINE.md north-star workload is
-  Criteo-1TB ingest) whose training step exercises batch on 'data' (DP),
-  embedding tables and hidden layers on 'model' (TP), and padded sequence
-  features on 'seq' (SP).
-- ``long_doc``: a transformer-style long-document classifier whose
-  attention runs sequence-parallel over the 'seq' axis (ring or Ulysses
-  all-to-all, ``LongDocConfig.sp_attention``) — the long-context consumer
-  of SequenceExample ingestion (``frames``/``frames_len``).
-- ``moe``: a Switch-style Mixture-of-Experts FFN with expert parallelism
-  (expert-stacked weights sharded over a mesh axis, static-shape one-hot
-  dispatch/combine).
-- ``pipeline``: GPipe-style pipeline parallelism (stage weights sharded
-  one-per-device on a 'pipe' axis, microbatches hop via ppermute;
-  scale-shaped — the stream is sharded on the pipe axis and per-device
-  input is O(mb)).
-- ``lm``: a causal (decoder) language model — the end-to-end consumer
-  proving zigzag causal ring attention, the pipelined blocks, and the
-  all-to-all MoE inside one jitted, checkpointed train step
-  (examples/train_lm.py).
+- ``dlrm``: a Criteo-style DLRM (batch on 'data', embedding tables and
+  hidden layers on 'model', padded sequence features on 'seq'), with
+  ``interaction`` its dot-interaction: the two Criteo cells.
+- the pattern model, the five token cells: ``lm.PatternLMConfig`` holds the
+  layer pattern as data (``gqa | swa | mla | kda | gdn`` mixers, dense or
+  expert feed-forward parts by layer) and ``lm.score`` scores packed
+  documents. Its pieces: ``attention.flash_attention_widths`` (the one
+  softmax kernel, on a TPU) and ``attention.blockwise_attention``
+  (elsewhere); ``linear_attn`` (the delta rule in chunks, a kernel on a
+  TPU); ``sparse_attn`` (YaRN's blend, the indexer's exact top-k);
+  ``moe.route_top_k`` and ``moe.held_experts_apply`` (a chip's share of the
+  experts). Its plain float32 references are the benchmark's, one an
+  architecture (``benchmark/models/<name>.py``); the package holds none.
+- in no cell: ``long_doc`` (a long-document classifier, ring or Ulysses
+  attention over 'seq'), ``moe.moe_apply`` / ``moe_apply_ep`` (Switch-style
+  experts sharded over a mesh axis), ``pipeline`` (GPipe over 'pipe') and
+  the homogeneous ``lm.LMConfig`` trainer that joins them in one jitted,
+  checkpointed train step (examples/train_lm.py; ``lm.LMStream`` serves it,
+  examples/serve_lm.py).
 
 Together the families exercise dp, tp, sp, ep, and pp on one mesh design
 (all five run inside ``__graft_entry__.dryrun_multichip``).
