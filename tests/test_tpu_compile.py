@@ -301,6 +301,64 @@ class TestOneChip:
         assert written("scan", "f32", 64) == 1 and written("scan", "bf16", 64) == 0
         assert sum("custom_call_target=\"tpu_custom_call\"" in line for line in entry) == 1
 
+    def test_the_state_space_kernel_at_the_cells_shape(self, one_chip):
+        """``nemotron_twotower_ep2.score``'s state-space layer: the convolution's
+        output [2, 8192, 6144] bfloat16 as ONE operand (64 heads of 64, then B
+        and C at 8 groups of 128), a step and a decay [2, 8192, 64] float32. The
+        chip's compiler takes the kernel (a transpose of 16 rows' numbers a
+        chunk, bfloat16 products in three parts, a group's B and C by block
+        index), and the program's arguments are what the mechanism has: no
+        copy of B or C to 64 heads (0.54 GB where they are 0.07), nothing
+        float32 of x's size but the output."""
+        from tpu_tfrecord.models import linear_attn
+
+        b, l, h, g, p, n = 2, 8192, 64, 8, 64, 128
+        xbc = jax.ShapeDtypeStruct((b, l, h * p + 2 * g * n), jnp.bfloat16, sharding=one_chip)
+        by_token = jax.ShapeDtypeStruct((b, l, h), jnp.float32, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((b, l), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(lambda x, dt, a, s: linear_attn._ssm_fused(
+            x, dt, a, s, heads=h, groups=g, state=n, tile=linear_attn._TILES[0])).lower(
+                xbc, by_token, by_token, segs).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        assert mem.output_size_in_bytes == 4 * b * l * h * p
+        assert mem.argument_size_in_bytes == 2 * b * l * (h * p + 2 * g * n) + 2 * 4 * b * l * h + 4 * b * l
+        # the segment ids a lane block wide and the two running arrays, tokens along the lanes
+        assert mem.temp_size_in_bytes < 4 * b * l * h * p // 8
+
+    def test_a_state_space_layer_hands_its_kernel_what_the_convolution_wrote(self, one_chip, monkeypatch):
+        """One state-space layer of the cell alone at the cell's shape, compiled
+        for the chip as a TPU runs it: the kernel once, no array of B or C at 64
+        heads in any dtype, no float32 array the size of the convolution's
+        output, and of float32 arrays of x's size under the scan scope only the
+        kernel's output."""
+        from tpu_tfrecord.models import linear_attn
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = lm.PatternLMConfig(
+            vocab_size=256, d_model=2688, layer_pattern=("ssm",), ffn_pattern=("none",), kda_heads=64,
+            kda_head_dim=64, ssm_state=128, ssm_groups=8, conv_taps=4, max_len=8192, kda_chunk=128,
+            dtype=jnp.bfloat16)
+        assert linear_attn.ssm_tile((2, 8192, 6144), cfg.dtype, 64, 8, 128, 128) == linear_attn._TILES[0]
+        layer = lm.pattern_param_shapes(cfg)["layers"][0]
+        assert layer["w_in"][0] == (2688, 10304) and "router" not in layer
+        p = {name: jax.ShapeDtypeStruct(*sd, sharding=one_chip) for name, sd in layer.items()}
+        x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+        head = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        entry = _entry_lines(
+            jax.jit(lambda p, x, s, h: lm.ssm_mixer(p, x, s, cfg, h)).lower(p, x, segs, head).compile().as_text())
+        results = [re.match(r"\s*(?:ROOT )?\S+ = (.*?) ([a-z][\w\-]*)\(", re.sub(r"\{[^{}]*\}", "", line))
+                   for line in entry]
+        written = [(m.group(1), line) for m, line in zip(results, entry)
+                   if m and m.group(2) not in ("get-tuple-element", "tuple", "bitcast", "parameter")]
+        assert sum("custom_call_target=\"tpu_custom_call\"" in line for line in entry) == 1
+        for sized in ("[2,8192,64,128]", "[2,64,8192,128]", "[2,8192,8192]", "f32[2,8192,6144]", "f32[2,1,8192,6144]"):
+            assert not [r for r, _ in written if sized in r], sized
+        scan = [r for r, line in written if re.search(r'op_name="[^"]*tfr\.ssm_scan', line)]
+        assert sum(r.count("f32[2,8192,4096]") for r in scan) == 1
+        assert sum(r.count("bf16[2,8192,6144]") + r.count("bf16[2,1,8192,6144]") for r, _ in written) <= 2
+
     def test_the_selection_kernel_at_the_cells_shape(self, one_chip):
         """``deepseek_v32_exp_ep16.score``'s selection, 64 index heads of 128
         over one row of 16,384 tokens, 2,048 keys a query: the kernel fits

@@ -7,15 +7,18 @@ in the benchmark's cells (``BENCHMARK.json``), the rest in tests and examples:
 - ``dlrm``: a Criteo-style DLRM (batch on 'data', embedding tables and
   hidden layers on 'model', padded sequence features on 'seq'), with
   ``interaction`` its dot-interaction: the two Criteo cells.
-- the pattern model, the five token cells: ``lm.PatternLMConfig`` holds the
-  layer pattern as data (``gqa | swa | mla | kda | gdn`` mixers, dense or
-  expert feed-forward parts by layer) and ``lm.score`` scores packed
+- the pattern model, the six token cells: ``lm.PatternLMConfig`` holds the
+  layer pattern as data (``gqa | swa | mla | kda | gdn | ssm`` mixers, the
+  sixth a state-space layer; dense or expert feed-forward parts by layer;
+  a layer may be ONE branch, a mixer or a feed-forward part alone, the
+  other pattern saying ``"none"``) and ``lm.score`` scores packed
   documents. Its pieces: ``attention.flash_attention_widths`` (the one
   softmax kernel, on a TPU) and ``attention.blockwise_attention``
-  (elsewhere); ``linear_attn`` (the delta rule in chunks, a kernel on a
-  TPU); ``sparse_attn`` (YaRN's blend, the indexer's exact top-k);
-  ``moe.route_top_k`` and ``moe.held_experts_apply`` (a chip's share of the
-  experts). Its plain float32 references are the benchmark's, one an
+  (elsewhere); ``linear_attn`` (the delta rule and the state-space
+  recurrence in chunks, a kernel each on a TPU); ``sparse_attn`` (YaRN's
+  blend, the indexer's exact top-k); ``moe.route_top_k`` and
+  ``moe.held_experts_apply`` (a chip's share of the experts, their unit of
+  three matrices or of two). Its plain float32 references are the benchmark's, one an
   architecture (``benchmark/models/<name>.py``); the package holds none.
 - in no cell: ``long_doc`` (a long-document classifier, ring or Ulysses
   attention over 'seq'), ``moe.moe_apply`` / ``moe_apply_ep`` (Switch-style

@@ -1,6 +1,26 @@
-"""Gated delta-rule linear attention over packed rows: a short causal
-convolution and a chunked recurrence, both reset at every ``segment_ids``
-boundary.
+"""Recurrent mixers over packed rows: a short causal convolution and three
+chunked recurrences, all reset at every ``segment_ids`` boundary.
+
+Three recurrences, two families. The gated DELTA RULE erases before it writes
+(``S_t = (I - b k k^T) diag(a) S + b k v^T``, a square state) and comes with
+its decay in two forms: one number a CHANNEL of the key (Solar's ``kda``
+layers: ``log_decay`` [B, H, L, D], nothing read grouped), or one number a
+head and token (GigaChat's ``gdn`` layers: ``log_decay`` [B, H, L], laid out
+as ``beta`` is, and q and k read GROUPED: ``Hk`` key heads under ``H`` value
+heads, by block index). Both are :func:`delta_rule_chunked`, one kernel with
+two bodies, head-major ``[B, H, L, D]``; the first part of this file. The
+STATE-SPACE recurrence (Mamba-2's: the ``ssm`` layers) only decays and writes
+(``S_t = a S + dt x B^T``, a [P x N] state that is not square, one decay and
+one step a head and token, no triangle to invert) and reads B and C GROUPED:
+``G`` groups under ``H`` heads, by block index out of the ONE token-major
+array ``[x | B | C]`` the convolution wrote; :func:`ssm_chunked`, a kernel of
+its own, the last part of this file (its note has the equations). It is a
+kernel of its own and no third body of the delta rule's because nothing of
+that body is left when the triangle's inverse is the identity: no levels of
+decayed pairs, no inverse, no pseudo-values, a state of another shape in
+another layout, operands that lie token-major; what the two share is the
+segment bookkeeping (``carry``, ``in_last``, the id before a tile), a dozen
+lines.
 
 This is where the packer meets a model with a recurrent state. A row from
 ``TokenPacker``'s bin modes holds several documents; ``segment_ids``
@@ -83,11 +103,12 @@ _BLOCK = 16  # tokens of the blocks that the triangle's inverse and the decayed 
 _HEADS = 16  # heads of a row whose chunked form the plain form lays out in memory at a time
 
 
-def short_conv(x, taps, segments):
+def short_conv(x, taps, segments, bias=None):
     """Causal depthwise convolution whose taps stop at a segment boundary.
     x [B, H, L, D], taps [K, H, D] (``taps[j]`` weighs the token j places
     back), segments [B, L] -> x's shape and dtype, accumulated in float32:
-    ``y_t = sum_j taps[j] * x_{t-j}`` over the j with t-j in t's segment."""
+    ``y_t = sum_j taps[j] * x_{t-j}`` over the j with t-j in t's segment,
+    plus ``bias`` [H, D] where the convolution has one."""
     f32, l = jnp.float32, x.shape[2]
     taps = taps.astype(f32)[:, None, :, None, :]                       # [K, 1, H, 1, D]
     out = x.astype(f32) * taps[0]
@@ -96,6 +117,8 @@ def short_conv(x, taps, segments):
         seg_back = jnp.pad(segments, ((0, 0), (j, 0)), constant_values=-1)[:, :l]
         same = (seg_back == segments)[:, None, :, None]
         out = out + jnp.where(same, back.astype(f32), 0.0) * taps[j]
+    if bias is not None:
+        out = out + bias.astype(f32)[None, :, None, :]
     return out.astype(x.dtype)
 
 
@@ -585,3 +608,270 @@ def delta_rule_chunked(q, k, v, log_decay, beta, segments, scale, chunk: int = 6
     if tile is not None:
         return _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile)
     return _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk)
+
+
+# ---------------------------------------------------------------------------
+# The state-space recurrence (Mamba-2's): no erasure, a [P x N] state, B and C by group
+# ---------------------------------------------------------------------------
+#
+# Per head (P channels, a state of N), for the tokens of ONE document::
+#
+#     S_t = a_t S_{t-1} + dt_t x_t B_t^T        S [P x N] float32, S = 0 where the document begins
+#     y_t = S_t C_t
+#
+# with ONE decay ``a_t = exp(log_decay_t)`` and one step ``dt_t`` a head and
+# token, and B_t, C_t [N] shared by the ``H / G`` heads of a group (head h
+# reads group ``h // (H / G)``). It is not the delta rule with something set
+# to zero: nothing is erased before the write (the rule's ``beta`` scales both),
+# so a chunk has no triangle to invert; the state is not square; and the
+# operands lie token-major, ``[B, L, channels]``, as ONE array: the
+# convolution's output ``[x | B | C]`` (H * P, then G * N twice), which a
+# kernel's block index cuts where the mechanism does, so that neither a
+# group's B and C nor a slice of x is ever copied. ``chunk`` tokens at a time:
+#
+#     G_t  = sum of log_decay over the chunk's tokens up to t          (float32)
+#     W_ij = (C_i . B_j) e^(G_i - G_j) dt_j    for j <= i in i's segment, else 0
+#     Y    = W X + carry * e^G * (C S^T)
+#     S'   = carry_end * e^G_end * S + (X * e^(G_end - G) dt * in_last_segment)^T B
+#
+# ``C_i . B_j`` is one product a GROUP and chunk; the exponent is never
+# positive. x, B and C are bfloat16 values, so their products are exact in
+# float32; what multiplies them (W, the state, the decayed x) is float32 and
+# goes through the matrix unit as three bfloat16 parts (high, middle, low:
+# 24 bits), each product accumulated in float32: the precision of a float32
+# product at half of ``HIGHEST``'s six passes.
+
+_SSM_CHUNK = 128       # the chunk the kernel computes in: one matrix-unit tile of tokens
+_SSM_LANES = 128       # x's channels a lane block: whole heads of P = 128 / k channels
+_SSM_HEADS = 8         # heads a grid step, all of one group
+
+
+def _ssm_cut(xbc, heads: int, groups: int, state: int):
+    """(x [.., H * P], B [.., G * N], C [.., G * N], P) of ``xbc``'s columns."""
+    gn = groups * state
+    hp = xbc.shape[-1] - 2 * gn
+    return xbc[..., :hp], xbc[..., hp:hp + gn], xbc[..., hp + gn:], hp // heads
+
+
+def ssm_recurrent(xbc, dt, log_decay, segments, heads: int, groups: int, state: int):
+    """The state-space recurrence token by token: the oracle. xbc [B, L, H * P
+    + 2 * G * N] (x, then B, then C), dt and log_decay [B, L, H] float32,
+    segments [B, L] -> y [B, L, H * P] float32. The state is zeroed wherever
+    ``segments`` changes; B and C are copied to their group's heads here (what
+    the chunked forms do without)."""
+    b, l, _ = xbc.shape
+    f32 = jnp.float32
+    x, bm, cm, p = _ssm_cut(xbc, heads, groups, state)
+    x = x.astype(f32).reshape(b, l, heads, p)
+    of_head = jnp.arange(heads) // (heads // groups)
+    bm, cm = (a.astype(f32).reshape(b, l, groups, state)[:, :, of_head] for a in (bm, cm))
+    starts = jnp.concatenate(
+        [jnp.ones((b, 1), bool), segments[:, 1:] != segments[:, :-1]], axis=1)
+
+    def step(s, xs):
+        x_t, b_t, c_t, dt_t, g_t, new = xs  # [B, H, P], [B, H, N] x 2, [B, H] x 2, [B]
+        s = jnp.where(new[:, None, None, None], 0.0, s) * jnp.exp(g_t)[..., None, None]
+        s = s + jnp.einsum("bhp,bhn->bhpn", x_t * dt_t[..., None], b_t)
+        return s, jnp.einsum("bhpn,bhn->bhp", s, c_t, precision=_HIGHEST)
+
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (x, bm, cm, dt.astype(f32), log_decay.astype(f32), starts))
+    _, y = jax.lax.scan(step, jnp.zeros((b, heads, p, state), f32), xs)
+    return jnp.moveaxis(y, 0, 1).reshape(b, l, heads * p)
+
+
+def _ssm_plain(xbc, dt, log_decay, segments, heads: int, groups: int, state: int, chunk: int):
+    """:func:`ssm_chunked` in plain JAX, for any chunk and any L (the tail is
+    padded with a segment of its own): a scan over the chunks of all rows and
+    heads at once, B and C at their groups (a head's group is an axis of the
+    reshape, not a copy)."""
+    b, l, _ = xbc.shape
+    f32, per = jnp.float32, heads // groups
+    pad = -l % chunk
+    if pad:
+        xbc, dt, log_decay = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (xbc, dt, log_decay))
+        segments = jnp.pad(segments, ((0, 0), (0, pad)), constant_values=-1)
+    n = (l + pad) // chunk
+
+    def cut(a, *shape):  # [B, L, ..] -> [n, B, C, ..]
+        return jnp.moveaxis(a.astype(f32).reshape(b, n, chunk, *shape), 1, 0)
+
+    x, bm, cm, p = _ssm_cut(xbc, heads, groups, state)
+    x, bm, cm = cut(x, groups, per, p), cut(bm, groups, state), cut(cm, groups, state)
+    g = jnp.cumsum(cut(log_decay, groups, per), axis=2)                 # log of the decay so far
+    step = cut(dt, groups, per)
+    seg = jnp.moveaxis(segments.reshape(b, n, chunk), 1, 0)             # [n, B, C]
+    before = jnp.concatenate([jnp.full((1, b), -2, seg.dtype), seg[:-1, :, -1]])
+    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def one(s, xs):  # s [B, G, R, P, N]
+        x_c, b_c, c_c, g_c, dt_c, seg_c, before_c = xs
+        carry = seg_c == before_c[:, None]                              # [B, C]
+        seen = (seg_c[:, :, None] == seg_c[:, None, :]) & tri           # [B, C(i), C(j)]
+        in_last = seg_c == seg_c[:, -1:]
+        cb = jnp.einsum("bign,bjgn->bgij", c_c, b_c, precision=_HIGHEST)
+        decay = jnp.exp(jnp.minimum(g_c[:, :, None] - g_c[:, None, :], 0.0))   # [B, i, j, G, R]
+        w = jnp.where(seen[..., None, None], decay * dt_c[:, None], 0.0) * jnp.moveaxis(cb, 1, -1)[..., None]
+        y = jnp.einsum("bijgr,bjgrp->bigrp", w, x_c, precision=_HIGHEST)
+        since = jnp.where(carry[..., None, None], jnp.exp(g_c), 0.0)    # [B, C, G, R]
+        y = y + since[..., None] * jnp.einsum("bign,bgrpn->bigrp", c_c, s, precision=_HIGHEST)
+        kept = jnp.where(in_last[..., None, None], jnp.exp(g_c[:, -1:] - g_c) * dt_c, 0.0)
+        s = (jnp.where(carry[:, -1, None, None], jnp.exp(g_c[:, -1]), 0.0)[..., None, None] * s
+             + jnp.einsum("bjgrp,bjgn->bgrpn", x_c * kept[..., None], b_c, precision=_HIGHEST))
+        return s, y
+
+    _, y = jax.lax.scan(one, jnp.zeros((b, groups, per, p, state), f32), (x, bm, cm, g, step, seg, before))
+    return jnp.moveaxis(y, 0, 1).reshape(b, n * chunk, heads * p)[:, :l]
+
+
+def ssm_tile(shape, dtype, heads: int, groups: int, state: int, chunk: int):
+    """Tokens a grid step of the state-space kernel holds for xbc of ``shape``
+    [B, L, H * P + 2 * G * N] and ``dtype``, or None where the plain form runs:
+    off a TPU, for operands that are not bfloat16 (the kernel's products count
+    on x, B and C being exact in it), at another chunk than the kernel's, for
+    rows that are not whole chunks,
+    and for geometries the kernel's blocks do not cut: heads whose P channels
+    do not fill lane blocks of 128 evenly, a state that is not one lane block,
+    groups of other than whole steps of ``_SSM_HEADS`` heads."""
+    l, p = shape[1], (shape[2] - 2 * groups * state) // heads
+    fits = (_SSM_LANES % p == 0 and state == _SSM_LANES and (heads // groups) % _SSM_HEADS == 0
+            and (_SSM_HEADS * p) % _SSM_LANES == 0)
+    if jax.default_backend() != "tpu" or jnp.dtype(dtype) != jnp.bfloat16 or chunk != _SSM_CHUNK or not fits:
+        return None
+    return next((t for t in _TILES if l % t == 0), None)
+
+
+def _split3(a):
+    """float32 a as three bfloat16 parts whose sum is a to 24 bits."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    high = a.astype(bf16)
+    rest = a - high.astype(f32)
+    middle = rest.astype(bf16)
+    return high, middle, (rest - middle.astype(f32)).astype(bf16)
+
+
+def _dot3(a, b):
+    """a @ b in float32 where one of the two is bfloat16 (exact) and the other
+    float32: the float32 one in three bfloat16 parts, three passes."""
+    def dot(x, y):
+        return jax.lax.dot_general(x, y, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    if a.dtype == jnp.bfloat16:
+        return sum(dot(a, part) for part in _split3(b))
+    return sum(dot(part, b) for part in _split3(a))
+
+
+def _ssm_kernel(seg_col_ref, seg_row_ref, x_ref, b_ref, c_ref, rows_ref, o_ref, state_ref, before_ref,
+                *, p: int):
+    """One tile of ``_SSM_HEADS`` heads of one group: each chunk's ``C B^T``
+    once, then every head's weights against its x, the state's part and the
+    state's update a lane block (128 / P heads side by side) at a time; the
+    state ``[N, lanes]`` and the id before the tile in scratch from tile to
+    tile. ``rows_ref`` [2 * heads, tile]: the heads' running log-decay inside
+    each chunk, then their steps, tokens along the lanes; the same numbers are
+    needed down the rows, and come from one transpose a chunk."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first():
+        state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
+        before_ref[...] = jnp.full(before_ref.shape, -2, jnp.int32)
+
+    tile, q, lanes, hs = x_ref.shape[1], _SSM_CHUNK, _SSM_LANES, _SSM_HEADS
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (q, lanes), 1)
+    last = before_ref[:1]
+    for at in range(0, tile, q):
+        rows = slice(at, at + q)
+        seg_col = seg_col_ref[0, rows]                                  # [q, lanes], a token's id in every lane
+        c, b = c_ref[0, rows], b_ref[0, rows]                           # [q, N]
+        cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())), preferred_element_type=f32)
+        cb = jnp.where((seg_col == seg_row_ref[0, :1, rows]) & (col <= row), cb, 0.0)
+        carry = seg_col == last
+        in_last = seg_col == seg_col[q - 1:q]
+        along = rows_ref[0, 0, :, rows]                                 # [2 hs, q]
+        down = jnp.concatenate([along, jnp.zeros((lanes - 2 * hs, q), f32)]).T   # [q, lanes]: lane h, lane hs + h
+        b_t = b.astype(f32).T.astype(bf16)                              # [N, q]
+        for block in range(x_ref.shape[2] // lanes):
+            x = x_ref[0, rows, block * lanes:(block + 1) * lanes]       # [q, lanes]: 128 / p heads
+            y = jnp.zeros((q, lanes), f32)
+            g_down = dt_down = jnp.zeros((q, lanes), f32)
+            for k in range(lanes // p):
+                h = block * (lanes // p) + k
+                mine = (lane >= k * p) & (lane < (k + 1) * p)
+                g_i = down[:, h:h + 1]
+                w = cb * jnp.exp(jnp.minimum(g_i - along[h:h + 1], 0.0)) * along[hs + h:hs + h + 1]
+                y = jnp.where(mine, _dot3(w, x), y)
+                g_down = jnp.where(mine, g_i, g_down)
+                dt_down = jnp.where(mine, down[:, hs + h:hs + h + 1], dt_down)
+            s = state_ref[block]                                        # [N, lanes]
+            o_ref[0, rows, block * lanes:(block + 1) * lanes] = y + jnp.where(
+                carry, jnp.exp(g_down), 0.0) * _dot3(c, s)
+            g_end = g_down[q - 1:q]
+            kept = jnp.where(in_last, jnp.exp(g_end - g_down) * dt_down, 0.0)
+            state_ref[block] = (jnp.where(seg_col[q - 1:q] == last, jnp.exp(g_end), 0.0) * s
+                                + _dot3(b_t, x.astype(f32) * kept))
+        last = seg_col[q - 1:q]
+    before_ref[...] = jnp.broadcast_to(last, before_ref.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "groups", "state", "tile", "interpret"))
+def _ssm_fused(xbc, dt, log_decay, segments, *, heads: int, groups: int, state: int, tile: int,
+               interpret: bool = False):
+    """:func:`ssm_chunked` in chunks of 128 as one Pallas TPU kernel: grid
+    (rows, steps of 8 heads, tiles of ``tile`` tokens), a step's tiles in
+    order. x, B and C are three block specs over the ONE array ``xbc``, in the
+    dtype it comes in: a step's 8 heads of x by their block index, their
+    group's B and C by theirs. The decay's running sum inside each chunk and
+    the step are laid out with the tokens along the lanes, 8 heads' of each a
+    block. jitted, so that a program's layers of one shape are traced once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, l, width = xbc.shape
+    f32, hs, q = jnp.float32, _SSM_HEADS, _SSM_CHUNK
+    p = (width - 2 * groups * state) // heads
+    steps, per = heads // hs, heads // groups // hs       # grid steps of heads; of them a group
+    first_b, first_c = heads * p // state, (heads * p + groups * state) // state
+    segments = segments.astype(jnp.int32)
+    g = jnp.cumsum(log_decay.astype(f32).reshape(b, l // q, q, heads), axis=2).reshape(b, l, heads)
+
+    def along(a):  # [B, L, H] -> [B, steps, hs, L]: tokens along the lanes
+        return jnp.swapaxes(a.astype(f32), 1, 2).reshape(b, steps, hs, l)
+
+    call = pl.pallas_call(
+        functools.partial(_ssm_kernel, p=p),
+        grid=(b, steps, l // tile),
+        in_specs=[
+            pl.BlockSpec((1, tile, _SSM_LANES), lambda bi, hi, ti: (bi, ti, 0)),
+            pl.BlockSpec((1, 8, tile), lambda bi, hi, ti: (bi, 0, ti)),
+            pl.BlockSpec((1, tile, hs * p), lambda bi, hi, ti: (bi, ti, hi)),
+            pl.BlockSpec((1, tile, state), lambda bi, hi, ti: (bi, ti, first_b + hi // per)),
+            pl.BlockSpec((1, tile, state), lambda bi, hi, ti: (bi, ti, first_c + hi // per)),
+            pl.BlockSpec((1, 1, 2 * hs, tile), lambda bi, hi, ti: (bi, hi, 0, ti)),
+        ],
+        out_specs=pl.BlockSpec((1, tile, hs * p), lambda bi, hi, ti: (bi, ti, hi)),
+        scratch_shapes=[pltpu.VMEM((hs * p // _SSM_LANES, state, _SSM_LANES), f32),
+                        pltpu.VMEM((8, _SSM_LANES), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((b, l, heads * p), f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )
+    with kernel_trace("kernel.trace.ssm_scan"):  # the body's trace, as a program is traced
+        return call(
+            jnp.broadcast_to(segments[:, :, None], (b, l, _SSM_LANES)),
+            jnp.broadcast_to(segments[:, None, :], (b, 8, l)),
+            xbc, xbc, xbc, jnp.concatenate([along(g), along(dt)], axis=2))
+
+
+def ssm_chunked(xbc, dt, log_decay, segments, heads: int, groups: int, state: int, chunk: int = 128):
+    """The state-space recurrence ``chunk`` tokens at a time (the section's
+    note). Shapes as :func:`ssm_recurrent`: ``xbc`` the convolution's output
+    as it lies, token-major. On a TPU, for geometries the kernel takes
+    (:func:`ssm_tile`), one Pallas kernel; elsewhere the plain JAX form."""
+    tile = ssm_tile(xbc.shape, xbc.dtype, heads, groups, state, chunk)
+    if tile is not None:
+        return _ssm_fused(xbc, dt, log_decay, segments, heads=heads, groups=groups, state=state, tile=tile)
+    return _ssm_plain(xbc, dt, log_decay, segments, heads, groups, state, chunk)

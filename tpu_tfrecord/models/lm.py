@@ -770,7 +770,9 @@ def bigram_table(vocab: int, n_next: int, seed: int = 1234) -> np.ndarray:
 # alternate kinds — a softmax layer, then a few linear-attention layers —
 # and put a sparse expert FFN behind each. `PatternLMConfig.layer_pattern`
 # names the mixer of each layer and `ffn_pattern` its feed-forward part
-# (experts, or a dense gated unit: leading dense layers are data); the
+# (experts, or a dense gated unit: leading dense layers are data); either may
+# say "none" where a layer is ONE pre-normed branch, a mixer or a feed-forward
+# part alone (never both: such a layer would be no layer). The
 # parameters are a list of per-layer dicts (layers of different kinds do
 # not stack). The model reads no position table: order comes from the
 # causal mask, the short convolution and the recurrence, and in a latent-
@@ -782,26 +784,30 @@ def bigram_table(vocab: int, n_next: int, seed: int = 1234) -> np.ndarray:
 # ``segment_ids`` [B, L+1]; every document comes out as it would alone in
 # a row (attention, positions, taps and state all stop at a boundary).
 
-MIXERS = ("gqa", "kda", "mla", "swa", "gdn")
+MIXERS = ("gqa", "kda", "mla", "swa", "gdn", "ssm")
 FFNS = ("moe", "dense")
+NONE = "none"  # in either pattern: the layer has no such part
 
 
 @dataclass(frozen=True)
 class PatternLMConfig:
     vocab_size: int = 256          # rows of the embedding, columns of the head, held here
     d_model: int = 64
-    layer_pattern: Tuple[str, ...] = ("gqa", "kda", "kda", "kda")
-    ffn_pattern: Tuple[str, ...] = ()  # "moe" or "dense" for each layer; () = experts in every one
+    layer_pattern: Tuple[str, ...] = ("gqa", "kda", "kda", "kda")  # a mixer, or "none", for each layer
+    ffn_pattern: Tuple[str, ...] = ()  # "moe", "dense" or "none" for each layer; () = experts in every one
     n_heads: int = 4               # softmax and latent-attention layers: query heads
     n_kv_heads: int = 2
     head_dim: int = 16
     window: int = 0                # sliding-window layer ("swa"): the keys a query sees, its own among them
     qk_norm: bool = False          # softmax and sliding-window layers: an RMSNorm over each head of q and of k
-    kda_heads: int = 4             # delta-rule layers ("kda", "gdn"): heads of d_k = d_v = kda_head_dim
-    kda_head_dim: int = 16
+    gqa_gate: bool = True          # ... and a sigmoid gate from the layer's input on the attention's output
+    kda_heads: int = 4             # recurrent layers ("kda", "gdn", "ssm"): heads of kda_head_dim channels
+    kda_head_dim: int = 16         # ... (a delta-rule head's d_k = d_v; a state-space head's P)
     conv_taps: int = 4
     gate_rank: int = 8             # rank of the "kda" layer's decay and output gates
     gdn_key_heads: int = 0         # "gdn" layer: key heads, a divisor of its kda_heads value heads (0: as many)
+    ssm_state: int = 16            # "ssm" layer: a head's state is kda_head_dim x ssm_state,
+    ssm_groups: int = 1            # ... and its kda_heads read B and C in so many groups (head h reads h // (H / G))
     qk_nope_dim: int = 16          # latent-attention layer: a head's query/key width without positions,
     qk_rope_dim: int = 8           # ... its rotary width (the key's is one head shared by all),
     v_head_dim: int = 16           # ... its value width,
@@ -820,6 +826,8 @@ class PatternLMConfig:
     top_k: int = 2
     d_expert: int = 32
     n_shared: int = 1
+    d_shared: int = 0              # width of the shared unit (0: n_shared experts' summed, d_expert * n_shared)
+    expert_unit: str = "gated"     # a routed or shared expert: "gated" (three matrices, SiLU) or "relu2" (two)
     routed_scale: float = 1.0
     router_bias: bool = False      # a per-expert bias beside the router: it picks, it never weighs
     n_group: int = 1               # the router's group limit: the experts in so many equal runs,
@@ -833,15 +841,16 @@ class PatternLMConfig:
     dtype: Any = jnp.bfloat16
     # how the program cuts the work (no effect on the result beyond rounding)
     attn_block: int = 1024         # query and key block of the softmax and latent-attention layers
-    kda_chunk: int = 64            # tokens a step of the chunked delta rule
+    kda_chunk: int = 64            # tokens a step of the chunked recurrence (delta rule, state space)
     expert_tile: int = 256         # visits a tile of the expert loop
     head_block: int = 2048         # tokens a block of the head's logits
 
 
 def ffn_kinds(cfg: PatternLMConfig) -> Tuple[str, ...]:
-    """Each layer's feed-forward part: ``cfg.ffn_pattern``, or experts everywhere."""
+    """Each layer's feed-forward part: ``cfg.ffn_pattern``, or experts
+    everywhere; ``"none"`` where the layer is its mixer alone."""
     kinds = cfg.ffn_pattern or ("moe",) * len(cfg.layer_pattern)
-    if len(kinds) != len(cfg.layer_pattern) or set(kinds) - set(FFNS):
+    if len(kinds) != len(cfg.layer_pattern) or set(kinds) - set(FFNS) - {NONE}:
         raise ValueError(f"ffn_pattern {kinds} has to name one of {FFNS} for each of the "
                          f"{len(cfg.layer_pattern)} layers")
     return kinds
@@ -853,7 +862,7 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
     d, dt, f32 = cfg.d_model, cfg.dtype, jnp.float32
     hq, hkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
     kd, r = cfg.kda_heads * cfg.kda_head_dim, cfg.gate_rank
-    fs = cfg.d_expert * cfg.n_shared
+    fs = cfg.d_shared or cfg.d_expert * cfg.n_shared
     after = {"post_ffn_norm": ((d,), f32)} if cfg.branch_norms else {}
     ffns = {"dense": {
         **after, "ffn_norm": ((d,), f32),
@@ -899,13 +908,32 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
         "dt_bias": ((cfg.kda_heads,), f32), "a_log": ((cfg.kda_heads,), f32),
         "w_beta": ((d, cfg.kda_heads), dt), "o_norm": ((cfg.kda_head_dim,), f32), "wo": ((kd, d), dt),
     }
-    for kind in cfg.layer_pattern:
-        if kind not in MIXERS:
+    inner, bc = kd, cfg.ssm_groups * cfg.ssm_state
+    mixers["ssm"] = {  # one projection in, [z | x B C | dt]; one convolution over x, B and C; one out
+        "attn_norm": ((d,), f32), "w_in": ((d, 2 * inner + 2 * bc + cfg.kda_heads), dt),
+        "conv_x": ((cfg.conv_taps, inner + 2 * bc), f32), "conv_bias": ((inner + 2 * bc,), f32),
+        "a_log": ((cfg.kda_heads,), f32), "d_skip": ((cfg.kda_heads,), f32),
+        "dt_bias": ((cfg.kda_heads,), f32), "o_norm": ((inner,), f32), "wo": ((inner, d), dt),
+    }
+    mixers[NONE] = ffns[NONE] = {}
+    for kind, ffn in zip(cfg.layer_pattern, ffn_kinds(cfg)):
+        if kind not in MIXERS and kind != NONE:
             raise ValueError(f"layer_pattern names {kind!r}; the mixers are {MIXERS}")
+        if kind == NONE and ffn == NONE:
+            raise ValueError("a layer is a mixer, a feed-forward part or both: 'none' in one pattern alone")
+    if "ssm" in cfg.layer_pattern and cfg.kda_heads % cfg.ssm_groups:
+        raise ValueError(f"ssm_groups {cfg.ssm_groups} has to divide the {cfg.kda_heads} heads")
+    if cfg.expert_unit not in ("gated", "relu2"):
+        raise ValueError(f"expert_unit {cfg.expert_unit!r}: 'gated' or 'relu2'")
     if "swa" in cfg.layer_pattern and cfg.window < 1:
         raise ValueError("a sliding-window layer needs cfg.window: the keys a query sees")
     if cfg.kda_heads % (cfg.gdn_key_heads or 1):
         raise ValueError(f"gdn_key_heads {cfg.gdn_key_heads} has to divide the {cfg.kda_heads} value heads")
+    if not cfg.gqa_gate:
+        mixers["gqa"].pop("wg")
+    if cfg.expert_unit == "relu2":  # two matrices a unit: no gate beside the way up
+        moe.pop("w_gate")
+        moe["shared"].pop("w_gate")
     if cfg.qk_norm:  # one weight for all heads
         mixers["gqa"].update({"q_norm": ((cfg.head_dim,), f32), "k_norm": ((cfg.head_dim,), f32)})
     if cfg.branch_norms:
@@ -945,7 +973,8 @@ def pattern_init_params(rng: jax.Array, cfg: PatternLMConfig) -> Dict[str, Any]:
     norms 1 (under ``cfg.centred_norms`` normal(0, 0.2): gains of 1 +- 0.1;
     a delta-rule head's own ``o_norm`` is a plain gain either way), decays of
     0.001 to 0.1 a token (``a_log`` 0, ``f_bias`` / ``dt_bias`` the inverse
-    softplus of a log-uniform rate), taps that favour the current token, a
+    softplus of a log-uniform rate), taps that favour the current token (a
+    convolution's bias normal(0, 0.1)), a state-space head's skip 1, a
     router bias normal(0, 0.05)."""
     shapes = pattern_param_shapes(cfg)
     leaves, tree = jax.tree.flatten_with_path(shapes, is_leaf=_is_shape)
@@ -956,6 +985,10 @@ def pattern_init_params(rng: jax.Array, cfg: PatternLMConfig) -> Dict[str, Any]:
             value = jax.random.normal(key, shape) * 0.2
         elif name.endswith("norm"):
             value = jnp.ones(shape)
+        elif name == "d_skip":
+            value = jnp.ones(shape)
+        elif name == "conv_bias":
+            value = jax.random.normal(key, shape) * 0.1
         elif name == "a_log":
             value = jnp.zeros(shape)
         elif name in ("f_bias", "dt_bias"):
@@ -1057,9 +1090,11 @@ def _attend(q, k, v, segments, block: int, scale=None, keep=None, window=None):
 
 
 def gqa_mixer(p, x, segments, cfg: PatternLMConfig, sliding: bool = False):
-    """The softmax layer: grouped causal attention inside each document, an
-    elementwise sigmoid gate on its output; with ``cfg.qk_norm`` an RMSNorm
-    over each head of q and of k. Without positions, every key of the
+    """The softmax layer: grouped causal attention inside each document; with
+    ``cfg.gqa_gate`` (the default: the layers that came first have it) an
+    elementwise sigmoid gate ``sigmoid(u wg)`` on its output, without it the
+    attention's output goes to ``wo`` as it is and the layer holds no ``wg``;
+    with ``cfg.qk_norm`` an RMSNorm over each head of q and of k. Without positions, every key of the
     document before the query; ``sliding`` (the "swa" layers): rotary turns
     over the whole head by each token's index in its own document, and of
     those keys the query's own and the ``cfg.window - 1`` before it. x
@@ -1108,9 +1143,12 @@ def gqa_mixer_probed(p, x, segments, cfg: PatternLMConfig, sliding: bool = False
                   "router": {"q_swa": sampled(q), "att_swa": sampled(att),
                              "swa_pos": jnp.take_along_axis(at, sample_at, axis=1)}}
     with jax.named_scope(around):
-        gate = jax.nn.sigmoid(
-            jnp.einsum("bld,dhk->bhlk", u, p["wg"].reshape(d, h, dh)).astype(jnp.float32))
-        gated = (att.astype(jnp.float32) * gate).astype(x.dtype)
+        if cfg.gqa_gate:
+            gate = jax.nn.sigmoid(
+                jnp.einsum("bld,dhk->bhlk", u, p["wg"].reshape(d, h, dh)).astype(jnp.float32))
+            gated = (att.astype(jnp.float32) * gate).astype(x.dtype)
+        else:
+            gated = att.astype(x.dtype)
         return jnp.einsum("bhlk,hkd->bld", gated, p["wo"].reshape(h, dh, d)), record
 
 
@@ -1361,9 +1399,64 @@ def gdn_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
         return jnp.einsum("bhlk,hkd->bld", o, p["wo"].reshape(h, dh, d)), probe
 
 
+def ssm_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
+    """The state-space layer (Mamba-2's; ``models.linear_attn`` has the
+    recurrence): ONE projection in, cut by columns into the gate z, the
+    convolution's input ``[x | B | C]`` and a step a head (three products over
+    views of ``w_in``: no slice of a wider array is copied); one causal
+    convolution of ``cfg.conv_taps`` taps with a bias over all of ``[x | B | C]``
+    and SiLU (no unit norm), its output behind a barrier in the dtype it was
+    written: the recurrence and a probe of it read that very array,
+    token-major, the heads' x by column block and a group's B and C from where
+    they lie; ``dt = softplus(. + dt_bias)``, ONE decay ``exp(-exp(a_log) dt)``
+    a head and token, both float32 ``[B, L, H]``; the skip ``d_skip * x``; the
+    gate BEFORE the norm, ``rms(y * silu(z))`` over each group's channels; out.
+
+    Returns (y, probe) as :func:`gdn_mixer` does: with ``probe_head`` that
+    head's ``x``, ``o`` [B, L, P], its group's ``b``, ``c`` [B, L, N] and its
+    ``dt``, ``log_decay`` [B, L], float32 for that head alone."""
+    h, ph, n, g, f32 = cfg.kda_heads, cfg.kda_head_dim, cfg.ssm_state, cfg.ssm_groups, jnp.float32
+    inner, bc = h * ph, g * n
+    with jax.named_scope("tfr.ssm_proj"):
+        u = _norm(x, p["attn_norm"], cfg)
+        z = u @ p["w_in"][:, :inner]
+        xbc = u @ p["w_in"][:, inner:2 * inner + 2 * bc]
+        dt = jax.nn.softplus(jnp.dot(u, p["w_in"][:, 2 * inner + 2 * bc:], preferred_element_type=f32)
+                             + p["dt_bias"])
+        log_decay = -jnp.exp(p["a_log"]) * dt
+    with jax.named_scope("tfr.ssm_conv"):
+        mixed = _la.short_conv(xbc[:, None], p["conv_x"][:, None], segments, p["conv_bias"][None])[:, 0]
+        xbc = jax.lax.optimization_barrier(jax.nn.silu(mixed.astype(f32)).astype(xbc.dtype))
+    with jax.named_scope("tfr.ssm_scan"):
+        o = _la.ssm_chunked(xbc, dt, log_decay, segments, h, g, n, chunk=cfg.kda_chunk)
+    probe = None
+    if probe_head is not None:
+        group = probe_head // (h // g)
+
+        def cut(a, first, width):  # [B, L, .] -> the columns first .. first + width, float32
+            return jax.lax.dynamic_slice_in_dim(a, first, width, axis=2).astype(f32)
+
+        probe = {"x": cut(xbc, probe_head * ph, ph), "b": cut(xbc, inner + group * n, n),
+                 "c": cut(xbc, inner + bc + group * n, n), "dt": jnp.take(dt, probe_head, axis=2),
+                 "log_decay": jnp.take(log_decay, probe_head, axis=2), "o": cut(o, probe_head * ph, ph)}
+    with jax.named_scope("tfr.ssm_proj"):
+        # everything stays [B, L, channels]: cut into [.., heads, P] or [.., groups, 512] the channels
+        # would change tiles, and each float32 array of o's size would be copied into the new order
+        # (three copies a layer, 0.8 ms each on a v5e). So the skip's gain is spread to a channel
+        # each, and a group's mean square is a product with the groups' indicator, there and back.
+        y = o + xbc[..., :inner].astype(f32) * jnp.repeat(p["d_skip"], ph)
+        y = y * jax.nn.silu(z.astype(f32))
+        in_group = (jnp.arange(inner)[:, None] // (inner // g) == jnp.arange(g)).astype(f32)
+        mean_sq = jnp.einsum("blc,cg->blg", jnp.square(y), in_group, precision=_la._HIGHEST) / (inner // g)
+        scale = jnp.einsum("blg,cg->blc", jax.lax.rsqrt(mean_sq + cfg.norm_eps), in_group,
+                           precision=_la._HIGHEST)
+        return (y * scale * p["o_norm"]).astype(x.dtype) @ p["wo"], probe
+
+
 #: where a mixer's branch norm is counted: with the layer's other projections
 _BRANCH_SCOPE = {"gqa": "tfr.gqa", "swa": "tfr.swa_proj", "mla": "tfr.mla_proj", "kda": "tfr.kda_proj",
-                 "gdn": "tfr.gdn_proj"}
+                 "gdn": "tfr.gdn_proj", "ssm": "tfr.ssm_proj"}
+_RECURRENT = {"kda": kda_mixer, "gdn": gdn_mixer, "ssm": ssm_mixer}
 
 
 def _joined(x, y, weight, cfg: PatternLMConfig, scope: str):
@@ -1386,8 +1479,8 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
     inputs: ``router`` (with ``sample_at`` [B, S]: at those positions of
     every expert layer the router's input ``u`` [n_layers, B, S, D] and its
     ``experts`` and ``gates`` [n_layers, B, S, top_k]) and ``scan`` (with
-    ``probe_head``: :func:`kda_mixer`'s or :func:`gdn_mixer`'s probe of the
-    first delta-rule layer).
+    ``probe_head``: :func:`kda_mixer`'s, :func:`gdn_mixer`'s or
+    :func:`ssm_mixer`'s probe of the first recurrent layer).
     Where latent-attention layers have an indexer, ``selected`` [layers, 2]
     (:func:`index_select`'s counts) and, with ``sample_at``, the first expert
     layer's selection: its keys under ``scan`` and the rest beside the
@@ -1422,12 +1515,14 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
                 selection, probes["scan"] = index["router"], index["scan"]
         elif kind == "mla":
             y = mla_mixer(layer, x, segments, cfg)
-        else:
-            y, scan = (kda_mixer if kind == "kda" else gdn_mixer)(
-                layer, x, segments, cfg, None if "scan" in probes else probe_head)
+        elif kind != NONE:
+            y, scan = _RECURRENT[kind](layer, x, segments, cfg, None if "scan" in probes else probe_head)
             if scan is not None:
                 probes["scan"] = scan
-        x = _joined(x, y, layer.get("post_attn_norm"), cfg, _BRANCH_SCOPE[kind])
+        if kind != NONE:  # else the layer is its feed-forward part alone
+            x = _joined(x, y, layer.get("post_attn_norm"), cfg, _BRANCH_SCOPE[kind])
+        if ffn == NONE:   # the layer is its mixer alone
+            continue
         if ffn == "dense":
             with jax.named_scope("tfr.dense_ffn"):
                 u = _norm(x, layer["ffn_norm"], cfg)
@@ -1437,12 +1532,13 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
             continue
         with jax.named_scope("tfr.moe_route"):
             u = _norm(x, layer["moe_norm"], cfg)
-            if cfg.n_group > 1 or cfg.branch_norms:
+            if cfg.n_group > 1 or cfg.branch_norms or kind == NONE:
                 # ONE array for the router and for the probe of it. Left alone, the compiler
                 # computes the norm once for each reader, the two fusions round a few
                 # elements in a thousand to different bfloat16 neighbours, and the probe held
                 # the router to inputs it never saw (gates 2.6e-4 apart on the chip where
-                # float32 reads 2e-7; under sandwich norms 5.3e-4). The patterns that came before
+                # float32 reads 2e-7; under sandwich norms 5.3e-4). So does every pattern since,
+                # a layer that is its experts alone among them; the patterns that came before
                 # either keep the program they had (tests/test_mla_lm.py holds their jaxprs).
                 u = jax.lax.optimization_barrier(u)
         y, n, lost, (experts, gates) = _moe.held_experts_apply(
@@ -1488,7 +1584,7 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
 
     x, visits, dropped, probes = pattern_hidden(params, tokens, segment_ids, cfg, sample_at,
                                                 probe_head)
-    if not {"kda", "gdn"} & set(cfg.layer_pattern):
+    if not set(_RECURRENT) & set(cfg.layer_pattern):
         probes.setdefault("scan", {})
     # every delta-rule layer of a program has one shape, so one answer of the
     # function that decides the dispatch (as the program is traced, not as it runs)
@@ -1498,6 +1594,12 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     if "gdn" in cfg.layer_pattern:  # the same kernel under its other decay, and how the key heads are shared
         METRICS.gauge("gdn.fused_layers", cfg.layer_pattern.count("gdn") if fused else 0)
         METRICS.gauge("gdn.key_group", cfg.kda_heads // (cfg.gdn_key_heads or cfg.kda_heads))
+    if "ssm" in cfg.layer_pattern:  # the state-space layers' own kernel, and the heads that share a B and a C
+        width = cfg.kda_heads * cfg.kda_head_dim + 2 * cfg.ssm_groups * cfg.ssm_state
+        fused = _la.ssm_tile((tokens.shape[0], cfg.max_len, width), cfg.dtype, cfg.kda_heads, cfg.ssm_groups,
+                             cfg.ssm_state, cfg.kda_chunk) is not None
+        METRICS.gauge("ssm.fused_layers", cfg.layer_pattern.count("ssm") if fused else 0)
+        METRICS.gauge("ssm.group", cfg.kda_heads // cfg.ssm_groups)
     # likewise the selection: one shape for every layer that has an indexer
     in_kernel = cfg.index_topk and _sa.select_tile(
         (tokens.shape[0], cfg.index_heads, cfg.max_len, cfg.index_dim), cfg.index_topk) is not None

@@ -562,6 +562,25 @@ def gated_ffn(x, w_gate, w_up, w_down, limit=None):
     return jnp.dot(hidden.astype(x.dtype), w_down, preferred_element_type=f32)
 
 
+def relu2_ffn(x, w_up, w_down):
+    """``w_down(relu(w_up x)^2)``, the unit of two matrices (no gate): products
+    accumulate in float32, the hidden activation is rounded to x's dtype;
+    returns float32."""
+    f32 = jnp.float32
+    hidden = jnp.square(jax.nn.relu(jnp.dot(x, w_up, preferred_element_type=f32)))
+    return jnp.dot(hidden.astype(x.dtype), w_down, preferred_element_type=f32)
+
+
+def expert_unit(x, w: Dict[str, Any], limit=None, e=None):
+    """One feed-forward unit on x by the matrices ``w`` holds: with ``w_gate``
+    :func:`gated_ffn`'s three, without it :func:`relu2_ffn`'s two; ``e``
+    picks one expert of stacked matrices."""
+    pick = (lambda a: a) if e is None else (lambda a: a[e])
+    if "w_gate" in w:
+        return gated_ffn(x, pick(w["w_gate"]), pick(w["w_up"]), pick(w["w_down"]), limit)
+    return relu2_ffn(x, pick(w["w_up"]), pick(w["w_down"]))
+
+
 def route_top_k(x, router, top_k: int, routed_scale: float = 1.0, bias=None, n_group: int = 1,
                 topk_group: int = 1):
     """Sigmoid scores over ALL experts in float32, the ``top_k`` largest,
@@ -604,6 +623,8 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     [Eh, F, D] (the Eh experts held, numbers ``held_offset`` ..
     ``held_offset + Eh``), optional ``shared`` with the same three names
     un-stacked (several shared experts are one unit of their summed width).
+    Without ``w_gate`` (in the held experts, in the shared one) the unit is
+    the one of two matrices, ``relu(w_up x)^2`` (:func:`expert_unit`).
     x [T, D]; ``valid`` [T] bool: tokens that
     are pads visit no expert (their rows get the shared expert only);
     ``n_group`` / ``topk_group``: :func:`route_top_k`'s group limit;
@@ -631,7 +652,7 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     visits a layer and the read-back would gather 131,072 rows (nearly all of
     them the zero row) out of a 2 GB buffer sized for the worst case."""
     t, d = x.shape
-    n_held = params["w_gate"].shape[0]
+    n_held = params["w_down"].shape[0]
     f32 = jnp.float32
     with jax.named_scope("tfr.moe_route"):
         grouped = {} if n_group == 1 else {"n_group": n_group, "topk_group": topk_group}
@@ -664,9 +685,7 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
             rows = first[e] + nth * tile + lane
             real = rows < first[e] + visits[e]
             visit = order[jnp.minimum(rows, t * top_k - 1)]
-            y = gated_ffn(x[visit // top_k], params["w_gate"][e], params["w_up"][e],
-                          params["w_down"][e], limit)
-            return visit, real, y
+            return visit, real, expert_unit(x[visit // top_k], params, limit, e)
 
         def one_tile(j, carry):
             laid, done = carry
@@ -698,6 +717,5 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
                                           (jnp.zeros((t, d), f32), jnp.int32(0)))
     if "shared" in params:
         with jax.named_scope("tfr.moe_shared"):
-            sh = params["shared"]
-            out = out + gated_ffn(x, sh["w_gate"], sh["w_up"], sh["w_down"], limit)
+            out = out + expert_unit(x, params["shared"], limit)
     return out.astype(x.dtype), visits, visits.sum() - done, (experts, gates)

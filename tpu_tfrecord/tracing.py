@@ -85,6 +85,13 @@ ANNOTATIONS = {
                     "of q and k at their own heads and of v",
     "tfr.gdn_scan": "pattern LM: the gated delta-net layer's recurrence call alone (models.linear_attn "
                     "under one decay a head and token, key heads shared by their value heads)",
+    "tfr.ssm_proj": "pattern LM: the state-space layer's norm, the three products of its one projection in "
+                    "(gate, [x | B | C], step), the step's softplus and the decay, the skip, the gate "
+                    "before the grouped norm, out",
+    "tfr.ssm_conv": "pattern LM: the state-space layer's one short convolution with its bias and SiLU "
+                    "over all channels of [x | B | C]",
+    "tfr.ssm_scan": "pattern LM: the state-space layer's recurrence call alone (models.linear_attn.ssm_chunked: "
+                    "one decay and one step a head and token, B and C read by group from where they lie)",
     "tfr.mla_proj": "pattern LM: the latent-attention layer's norm, query projection, latent "
                     "projection and norm, expansion to keys and values, rotary turns, the output's "
                     "sigmoid gate where the layer has one, out",

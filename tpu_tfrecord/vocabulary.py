@@ -183,6 +183,7 @@ STAGES: Dict[str, str] = {
     "compile.cache_read": "the persistent cache read inside a compile.backend that hit",
     "kernel.trace.mla_attn": "the attention Pallas kernel built while a program is traced (attention._flash_widths_call: a latent, a windowed or, since PR 43, a full softmax layer's; the name is its first user's)",
     "kernel.trace.kda_scan": "the delta-rule Pallas kernel built while a program is traced (linear_attn._delta_rule_fused, under either form of the decay)",
+    "kernel.trace.ssm_scan": "the state-space Pallas kernel built while a program is traced (linear_attn._ssm_fused: once a program, its layers of one shape share the jitted call)",
     "kernel.trace.dsa_index": "the selection Pallas kernel built while a program is traced (sparse_attn._select_fused)",
     "kernel.trace.interaction": "the dot-interaction Pallas kernel built while a program is traced (interaction.dot_interaction_pallas)",
 }
@@ -211,6 +212,8 @@ GAUGES: Dict[str, str] = {
     "kda.fused_layers": "pattern LM, the score program last traced: delta-rule layers whose recurrence took the Pallas kernel (0 off a TPU)",
     "gdn.fused_layers": "pattern LM, the score program last traced: gated delta-net layers whose recurrence took the Pallas kernel, one decay a head and token (0 off a TPU)",
     "gdn.key_group": "pattern LM, the score program last traced: value heads of a gated delta-net layer that read one key head, from where it lies",
+    "ssm.fused_layers": "pattern LM, the score program last traced: state-space layers whose recurrence took the Pallas kernel (0 off a TPU)",
+    "ssm.group": "pattern LM, the score program last traced: heads of a state-space layer that read one group's B and C, from where they lie",
     "dsa.kernel_layers": "pattern LM, the score program last traced: latent-attention layers whose selection took the Pallas kernel (0 off a TPU and without an indexer)",
     "mla.split_layers": "pattern LM, the score program last traced: latent-attention layers whose attention kernel was handed q and k in their two parts, plain and rotary, never joined in memory (0 off a TPU)",
     "dsa.selected_share": "pattern LM, latest step recorded: keys the indexers kept over the causal candidates they chose from (lm.record_selected)",
@@ -269,6 +272,7 @@ SPANS: Dict[str, str] = {
     "compile.cache_read": "one persistent cache read",
     "kernel.trace.mla_attn": "one build of the attention kernel (a latent, a windowed or a full softmax layer's)",
     "kernel.trace.kda_scan": "one build of the delta-rule kernel",
+    "kernel.trace.ssm_scan": "one build of the state-space kernel",
     "kernel.trace.dsa_index": "one build of the selection kernel",
     "kernel.trace.interaction": "one build of the dot-interaction kernel",
 }
