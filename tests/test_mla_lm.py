@@ -487,7 +487,10 @@ def test_an_unknown_mixer_or_feed_forward_part_is_named_with_the_ones_there_are(
 #: operation), as PR 31 left them the recommender's ``forward`` and
 #: ``sparse_train_step``, and as PR 40 left it ``kimi``, this file's latent-attention
 #: program (its mixer's four projections each an array of its own, joined off a TPU alone).
-#: A PR that means to change one of them records its own.
+#: A PR that means to change one of them records its own. PR 47 gave the expert loop tail
+#: tiles of 256 rows under tiles that are several such units; these three are traced at
+#: ``expert_tile=8``, under the unit, where there is one loop and the program it was: no
+#: hash moved.
 OLDER_PROGRAMS = {
     "solar": "c2d1f66a567aa6a4e8b2558c821d20ad80395c2014c71aeba6e98e3dd190cdab",
     "gigachat": "d36b4cb8034c876ee4bb3f4a243c8c63ef2d23a177013e5ed13b483926e3def6",

@@ -532,6 +532,84 @@ def test_a_skewed_router_costs_time_and_never_a_visit(tile):
     assert int(lost) >= x.shape[0] - 24
 
 
+#: visits to the one held expert under a unit of 8 rows and tiles of 32: none, one, around a
+#: unit, around a tile, several tiles and a tail
+RUNS = [0, 1, 7, 8, 9, 31, 32, 33, 55, 71, 96]
+
+
+def one_run(visits, held, t=96):
+    """A layer of 16 experts and tokens of which the first ``visits`` pick expert 5 and no
+    other token does (its router column is raised or sunk), with the share of the experts
+    that starts at expert 5: ``held`` of them."""
+    cfg, p, x = moe_layer(seed=2, t=t)
+    x = jnp.abs(x).at[visits:].multiply(-1.0)                # rows of one sign: a column decides
+    p["router"] = p["router"].at[:, 5].set(4.0)
+    share = {**p, **{k: p[k][5:5 + held] for k in ("w_gate", "w_up", "w_down")}}
+    return {**cfg, "n_routed_experts_held": held, "held_offset": 5}, share, x
+
+
+@pytest.fixture
+def unit_of_8(monkeypatch):
+    """The tail's unit at a size these layers fill: tiles of 16, 24 and 32 rows have tails."""
+    monkeypatch.setattr(moe, "TAIL_UNIT", 8)
+    return jax.jit(moe.held_experts_apply, static_argnames=(
+        "held_offset", "top_k", "routed_scale", "tile", "n_group", "topk_group"))
+
+
+@pytest.mark.parametrize("held", [2, 1], ids=["read_back", "added_as_computed"])
+@pytest.mark.parametrize("visits", RUNS)
+def test_a_run_of_any_length_goes_through_whole_tiles_and_tails(unit_of_8, visits, held):
+    """Both forms (an eighth of the experts held: laid down and read back; a sixteenth:
+    added as computed) against the reference, for runs that end before, on and after a
+    unit's and a tile's edge; nothing dropped, the visits what the router chose."""
+    cfg, share, x = one_run(visits, held)
+    assert moe.adds_as_computed(held, 16) == (held == 1) and moe.row_unit(32) == 8
+    y, n, dropped, (experts, _) = unit_of_8(share, x, held_offset=5, top_k=4, tile=32)
+    assert int(n[0]) == visits == int((experts == 5).sum()) and int(dropped) == 0
+    with jax.default_matmul_precision("highest"):
+        want, _, _ = ref.ref_moe(flat(share), x, cfg)
+    np.testing.assert_allclose(y, want, atol=2e-5)
+    # and the tile that is the unit, or no multiple of it, is the one loop it was
+    for tile in (8, 12):
+        again, n_again, dropped, _ = unit_of_8(share, x, held_offset=5, top_k=4, tile=tile)
+        assert moe.row_unit(tile) == tile and int(dropped) == 0
+        np.testing.assert_array_equal(n_again, n)
+        np.testing.assert_allclose(again, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("tile, held, rows", [
+    (32, 1, [0, 8, 8, 8, 16, 32, 32, 40, 56, 72, 96]),       # added as computed: every tail through tail tiles
+    (32, 2, [0, 8, 8, 8, 16, 32, 32, 40, 64, 72, 96]),       # read back: a tail of three units takes the whole tile
+    (16, 2, [0, 8, 8, 8, 16, 32, 32, 40, 56, 72, 96]),       # two units a tile: the one tail there can be
+    (24, 2, [0, 8, 8, 8, 16, 32, 32, 40, 56, 72, 96]),       # three: a tail of one or two
+    (8, 2, [0, 8, 8, 8, 16, 32, 32, 40, 56, 72, 96]),        # the tile is the unit
+    (12, 2, [0, 12, 12, 12, 12, 36, 36, 36, 60, 72, 96]),    # no multiple of the unit: whole tiles alone
+])
+def test_the_rows_the_two_loops_compute_are_what_the_gauge_counts(unit_of_8, monkeypatch, tile, held, rows):
+    """``moe.region_units`` against the loops themselves: each tile's real rows are counted
+    as it is computed (``done``), and a patched expert unit counts the rows it is handed."""
+    from tpu_tfrecord.metrics import METRICS
+
+    scattered = moe.adds_as_computed(held, 16)
+    got = [int(moe.region_units(np.int64(v), tile, scattered)) * moe.row_unit(tile) for v in RUNS]
+    assert got == rows
+    handed = []
+    unit = moe.expert_unit
+    monkeypatch.setattr(moe, "expert_unit", lambda x, w, limit=None, e=None: (
+        handed.append(x.shape[0]) if e is not None else None, unit(x, w, limit, e))[1])
+    cfg, share, x = one_run(55, held)
+    jaxpr = jax.make_jaxpr(lambda p, x: moe.held_experts_apply(
+        p, x, held_offset=5, top_k=4, tile=tile))(share, x)
+    loops = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "while"
+             and "dot_general" in str(e.params["body_jaxpr"])]
+    assert sorted(handed, reverse=True) == ([tile, 8] if moe.row_unit(tile) == 8 and tile > 8 else [tile])
+    assert len(loops) == len(handed)
+    # one layer's visits as a step's: the gauge is the real visits over the rows of the run of 55
+    cfg_of = program_cfg({**CFG, "n_routed_experts_held": held}, expert_tile=tile)
+    lm.record_moe_counters(np.array([[55] + [0] * (held - 1)]), np.array([0]), cfg_of)
+    assert METRICS.gauge_value("moe.tile_fill") == round(55 / rows[RUNS.index(55)], 4)
+
+
 def test_the_moe_counters_sit_beside_pack_density():
     from tpu_tfrecord.metrics import METRICS
 
@@ -539,6 +617,25 @@ def test_the_moe_counters_sit_beside_pack_density():
     uneven = lm.record_moe_counters(np.array([[10, 30], [20, 20]]), np.array([0, 0]))
     assert uneven == 1.5 and METRICS.gauge_value("moe.visits_max_over_mean") == 1.5
     assert METRICS.counter("moe.visits_dropped") == before
+
+
+@pytest.mark.parametrize("tile, tail_unit, fill", [(8, 0, 0.8333), (512, 256, 0.0781), (1024, 256, 0.0781)])
+def test_the_tilings_gauges_sit_beside_them(params, tile, tail_unit, fill):
+    """``moe.tail_unit`` is set as the score program is traced (the rows of a tail tile, 0
+    where one loop runs), and the two-argument call, the benchmark's, reckons
+    ``moe.tile_fill`` by the program last traced: the emptiest layer's visits over its rows."""
+    from tpu_tfrecord.metrics import METRICS
+
+    cfg = program_cfg(expert_tile=tile)
+    batch, _ = packed_rows()
+    jax.make_jaxpr(lambda p, t, s, a: lm.score(p, t, s, a, cfg))(
+        params, batch["tokens"], batch["segment_ids"], SAMPLE_AT)
+    assert METRICS.gauge_value("moe.tail_unit") == tail_unit
+    lm.record_moe_counters(np.array([[10, 30, 0, 0], [20, 20, 20, 20]]), np.array([0, 0]))
+    assert METRICS.gauge_value("moe.tile_fill") == fill
+    # a caller that names the program is not at the mercy of what was traced last
+    lm.record_moe_counters(np.array([[8, 8, 8, 8]]), np.array([0]), program_cfg(expert_tile=8))
+    assert METRICS.gauge_value("moe.tile_fill") == 1.0
 
 
 @pytest.mark.parametrize("packing", ["best_fit", "first_fit"])

@@ -490,6 +490,33 @@ class TestOneChip:
         assert compiled.memory_analysis().output_size_in_bytes == 2 * 48 * l * 128
         assert (len(_grid_pairs(l, 1024, 1024, 4096)), len(_grid_pairs(l, 1024, 1024))) == (150, 528)
 
+    @pytest.mark.parametrize("t, d, f, n_experts, held, top_k, laid", [
+        (16384, 2048, 1408, 64, 64, 6, (384 + 64 * 2) * 256 + 1),      # kimi_vl_a3b_lm.score: read back
+        (16384, 7168, 2048, 256, 16, 8, None),                          # gigachat35_ep16.score: added as computed
+    ], ids=["read_back", "added_as_computed"])
+    def test_the_expert_loops_at_tiles_of_1024(self, one_chip, t, d, f, n_experts, held, top_k, laid):
+        """``moe.held_experts_apply`` at two cells' shapes under ``expert_tile=1024``:
+        the program for the chip holds TWO loops under ``tfr.moe_experts`` (whole
+        tiles, then tails of 256 rows), the buffer of the read-back form is the
+        worst case in units (131,073 rows where whole tiles alone took 163,841)
+        with its write inside the last product of either loop, and nothing else
+        of T * top_k rows or more of the model's width exists in either form."""
+        from tpu_tfrecord.models import moe
+
+        shapes = {"router": ((d, n_experts), jnp.float32), "w_gate": ((held, d, f), jnp.bfloat16),
+                  "w_up": ((held, d, f), jnp.bfloat16), "w_down": ((held, f, d), jnp.bfloat16)}
+        p = {k: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for k, (shape, dtype) in shapes.items()}
+        x = jax.ShapeDtypeStruct((t, d), jnp.bfloat16, sharding=one_chip)
+        hlo = jax.jit(lambda p, x: moe.held_experts_apply(
+            p, x, held_offset=0, top_k=top_k, tile=1024)).lower(p, x).compile().as_text()
+        loops = [line for line in _entry_lines(hlo) if re.search(r" while\(", line)]
+        assert len(loops) == 2 and all(re.search(r'op_name="[^"]*tfr\.moe_experts/while', line) for line in loops)
+        tall = {int(rows) for rows in re.findall(rf"\[(\d+),{d}\]", hlo) if int(rows) >= t * top_k}
+        assert tall == ({laid} if laid else set())
+        if laid:  # the write of a tile stays in the product that makes it, in both bodies
+            assert not re.findall(r"\n\s*%?\S+ = \S+ dynamic-update-slice\(", hlo)
+            assert len(re.findall(r"\n\s*ROOT %?\S+ = \S+ dynamic-update-slice\(", hlo)) == 2
+
     def test_lm_train_step_on_dp(self, topo):
         """examples/train_lm.py's widths on a one-device ``data`` mesh."""
         mesh = Mesh(np.array(topo.devices[:1]), ("data",))

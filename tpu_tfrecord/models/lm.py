@@ -1610,6 +1610,11 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     # and of every softmax layer, full or under a window: grouped K/V heads read as they lie
     in_kernel = _takes_kernel(cfg.max_len, cfg.head_dim, cfg.attn_block)
     METRICS.gauge("gqa.kernel_layers", cfg.layer_pattern.count("gqa") if in_kernel else 0)
+    # and the expert loop's tiling: the rows of a tail tile, 0 where the one loop of whole tiles runs
+    global _last_scored
+    _last_scored = cfg
+    unit = _moe.row_unit(cfg.expert_tile)
+    METRICS.gauge("moe.tail_unit", unit if unit != cfg.expert_tile else 0)
     if "swa" in cfg.layer_pattern:  # one shape for every sliding layer
         METRICS.gauge("swa.kernel_layers", cfg.layer_pattern.count("swa") if in_kernel else 0)
         tile = min(cfg.attn_block, cfg.max_len)
@@ -1639,17 +1644,33 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     return out
 
 
-def record_moe_counters(visits, dropped) -> float:
+#: the configuration of the score program last traced (:func:`score` sets it as it sets the gauges):
+#: what :func:`record_moe_counters` reckons the expert loops' rows by where its caller names none
+_last_scored: Optional[PatternLMConfig] = None
+
+
+def record_moe_counters(visits, dropped, cfg: Optional[PatternLMConfig] = None) -> float:
     """A step's expert counters into ``metrics.METRICS``, beside the packer's
     ``pack.density``: the gauge ``moe.visits_max_over_mean`` (the busiest
-    held expert of the step's most uneven layer against that layer's mean)
-    and the counter ``moe.visits_dropped`` (stays 0). Returns the gauge."""
+    held expert of the step's most uneven layer against that layer's mean),
+    the counter ``moe.visits_dropped`` (stays 0) and the gauge
+    ``moe.tile_fill``: a layer's real visits over the rows its expert loops
+    compute for them (``moe.region_units`` under ``cfg``'s tile and share of
+    the experts), of the step's emptiest layer. ``cfg``: the program's; left
+    out, that of the score program last traced, and with none traced
+    ``moe.tile_fill`` is left alone. Returns the first gauge."""
     from tpu_tfrecord.metrics import METRICS
 
-    visits = np.asarray(visits, np.float64)
+    visits = np.asarray(visits, np.int64)
     uneven = float((visits.max(axis=1) / np.maximum(visits.mean(axis=1), 1e-30)).max())
     METRICS.gauge("moe.visits_max_over_mean", round(uneven, 4))
     METRICS.count("moe.visits_dropped", int(np.asarray(dropped).sum()))
+    cfg = cfg or _last_scored
+    if cfg is not None:
+        units = _moe.region_units(visits, cfg.expert_tile,
+                                  _moe.adds_as_computed(visits.shape[1], cfg.n_experts))
+        rows = units.sum(axis=1) * _moe.row_unit(cfg.expert_tile)
+        METRICS.gauge("moe.tile_fill", round(float((visits.sum(axis=1) / np.maximum(rows, 1)).min()), 4))
     return uneven
 
 

@@ -58,6 +58,7 @@ own, so a skewed router costs time, never a token.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -612,6 +613,54 @@ def route_top_k(x, router, top_k: int, routed_scale: float = 1.0, bias=None, n_g
     return experts, top / top.sum(axis=-1, keepdims=True) * routed_scale
 
 
+#: Rows of a tail tile, the unit an expert's visits are rounded up to. One tile of m rows does
+#: 6·D·F·m FLOPs on 6·D·F bytes of one expert's bfloat16 weights, m FLOPs a byte, and a v5e turns
+#: 197 TFLOP/s over 819 GB/s = 240 FLOPs a byte: under about 240 rows a tile costs its expert's
+#: weights' read whatever it holds, so a smaller unit buys nothing.
+TAIL_UNIT = 256
+
+
+def row_unit(tile: int) -> int:
+    """The rows :func:`held_experts_apply` rounds an expert's visits up to under
+    tiles of ``tile``: ``TAIL_UNIT`` where the tile is several whole units (what
+    is left of a run under one tile then goes through tail tiles of a unit),
+    the tile itself everywhere else (one loop, no tails)."""
+    return TAIL_UNIT if tile > TAIL_UNIT and tile % TAIL_UNIT == 0 else tile
+
+
+def tail_units(per: int, scattered: bool) -> int:
+    """The most units of a run's tail that go through tail tiles, under tiles
+    of ``per`` units; a longer tail takes one more whole tile. A tile of 256
+    rows costs its expert's weights' read BESIDE its products (XLA's dot at
+    that size overlaps neither with the other: 62 microseconds a tail where a
+    whole tile of four units is 131, at 2,048 x 1,408 on a v5e), and at 240
+    FLOPs a byte the two are equal: ``r`` tail tiles cost ``2 r`` units'
+    products where the whole tile costs ``per + 1``. ``scattered``: the form
+    that adds a tile's rows to their tokens, where a row's scatter-add costs
+    more than its products and every tail pays."""
+    return per - 1 if scattered else min((per + 1) // 2, per - 1)
+
+
+def adds_as_computed(n_held: int, n_experts: int) -> bool:
+    """Which form :func:`held_experts_apply` takes: under an eighth of the
+    experts held, a tile's rows are added to their tokens as it is computed;
+    from an eighth on they are laid down and read back."""
+    return n_held * 8 < n_experts
+
+
+def region_units(visits, tile: int, scattered: bool):
+    """The units of :func:`row_unit` rows that :func:`held_experts_apply`'s
+    loops compute for each expert's ``visits`` (numpy or JAX integers, any
+    shape): the visits rounded up to whole units, and a tail of more units
+    than :func:`tail_units` allows rounded up to one more whole tile."""
+    unit = row_unit(tile)
+    units, per = (visits + unit - 1) // unit, tile // unit
+    if per > 1:
+        tail = units % per
+        units = units + (tail > tail_units(per, scattered)) * (per - tail)
+    return units
+
+
 def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: int,
                        routed_scale: float = 1.0, tile: int = 256, valid=None,
                        n_group: int = 1, topk_group: int = 1, limit=None):
@@ -633,19 +682,40 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     dropped: visits to held experts that were not computed, always 0,
     (experts [T, top_k] int32, gates [T, top_k] float32): the routing).
 
-    The T * top_k visits are sorted by held expert (absent ones last) and
-    each expert's run is cut into tiles of ``tile`` visits. A loop over the
-    tiles there ARE gathers a tile's tokens, runs that expert's FFN and lays
-    the result down where the tile lies in the sorted order (a contiguous
-    write; a buffer for the worst case, every visit to a held expert, is
-    T * top_k rows of x's dtype). Each token then reads its own ``top_k``
-    rows back and sums them under its gates in float32: a gather, where a
-    scatter-add of the same rows costs three times as much a row on a TPU.
+    The T * top_k visits are sorted by held expert (absent ones last) and an
+    expert's run of ``v`` visits is rounded up to whole UNITS of rows
+    (:func:`row_unit`): ``TAIL_UNIT`` = 256 where ``tile`` is several whole
+    units, else the tile. The whole tiles of ``tile`` rows in that,
+    ``floor(q * unit / tile)`` of ``q = ceil(v / unit)`` units, come first;
+    what is left, under one tile, is TAIL tiles of one unit each, where that
+    many tails cost less than the one whole tile they stand for
+    (:func:`tail_units`; where they do not, the run takes the whole tile, as a
+    run whose tail would fill a tile's units does: 960 visits under tiles of
+    1,024 are one tile, not four tails). An expert whose tail goes through
+    tail tiles costs ``q * unit`` rows, at most ``unit - 1`` of them empty,
+    where whole tiles alone cost ``ceil(v / tile) * tile``
+    (:func:`region_units` is the count). Why 256: a tile of ``m`` rows does
+    ``6 D F m`` FLOPs on the ``6 D F`` bytes of one expert's bfloat16 weights,
+    ``m`` FLOPs a byte, and a v5e turns 240 FLOPs a byte, so under about 240
+    rows a tile costs its expert's weights' read whatever it holds. A loop
+    over the whole tiles there ARE, then one with the same body over the tails
+    there are (none where the tile is the unit: every ``tile`` up to 256, or no
+    multiple of it), gathers a tile's tokens, runs that expert's FFN and lays
+    the result down where the tile lies in the sorted order, an expert's region
+    its units, whole tiles first (a contiguous write; a buffer for the worst
+    case, every visit to a held expert and each expert's rounding, is
+    ``(ceil(T * top_k / unit) + Eh * spare) * unit + 1`` rows of x's dtype,
+    ``spare`` 1 where every tail goes through tail tiles and else the units a
+    tail can be rounded up by; handed from the first loop to the second as its
+    carry). Each token then reads its own ``top_k`` rows back and sums them
+    under its gates in float32: a gather, where a scatter-add of the same rows
+    costs three times as much a row on a TPU.
 
     Where this chip holds less than an eighth of the experts there is no
-    buffer and no read-back: each tile's rows are added to their tokens as the
-    tile is computed, under their gates, in float32 (a scatter-add of ``tile``
-    rows; a token is in a tile once, so the sum has one order). The read-back
+    buffer and no read-back: each tile's rows, whole or tail, are added to
+    their tokens as the tile is computed, under their gates, in float32 (a
+    scatter-add of the tile's rows; a token is in a tile once, and the tiles
+    are summed in the order the two loops walk them). The read-back
     gathers ``top_k`` rows a token whatever the share, a row of 7,168 costs
     0.12 microseconds to gather and 1.1 to scatter-add on a v5e, so the two
     cross at a share of a ninth: with 16 of 256 held, 16,384 tokens make 8,192
@@ -654,6 +724,9 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     t, d = x.shape
     n_held = params["w_down"].shape[0]
     f32 = jnp.float32
+    unit = row_unit(tile)
+    per = tile // unit                                      # units a tile: 1 where there are no tails
+    scattered = adds_as_computed(n_held, params["router"].shape[1])
     with jax.named_scope("tfr.moe_route"):
         grouped = {} if n_group == 1 else {"n_group": n_group, "topk_group": topk_group}
         experts, gates = route_top_k(x, params["router"], top_k, routed_scale,
@@ -666,37 +739,59 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         visits = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
         first = jnp.cumsum(visits) - visits                 # an expert's first sorted visit
-        n_tiles = (visits + tile - 1) // tile
-        tiles_to = jnp.cumsum(n_tiles)                      # tiles up to and with an expert
-        # where a visit's result will lie: its expert's first tile, then its place in the run
+        units = region_units(visits, tile, scattered)
+        units_to = jnp.cumsum(units)                        # units up to and with an expert
+        # where a visit's result will lie: its expert's first unit, then its place in the run
         rank = jnp.zeros((t * top_k,), jnp.int32).at[order].set(
             jnp.arange(t * top_k, dtype=jnp.int32))
         safe = jnp.minimum(key, n_held - 1)
-        lies_at = (tiles_to[safe] - n_tiles[safe]) * tile + rank - first[safe]
-        max_tiles = -(-t * top_k // tile) + n_held
-        lies_at = jnp.where(held.reshape(-1), lies_at, max_tiles * tile).reshape(t, top_k)
+        lies_at = (units_to[safe] - units[safe]) * unit + rank - first[safe]
+        # the worst case: every visit to a held expert, and each expert's rounding
+        max_units = -(-t * top_k // unit) + n_held * max(1, per - tail_units(per, scattered))
+        lies_at = jnp.where(held.reshape(-1), lies_at, max_units * unit).reshape(t, top_k)
+        # the loops: (rows a tile, an expert's tiles, their running sum, the units of its run before them)
+        if per == 1:
+            loops = [(tile, units, units_to, None)]
+        else:
+            whole = units // per
+            tails = units - whole * per
+            loops = [(tile, whole, jnp.cumsum(whole), None),
+                     (unit, tails, jnp.cumsum(tails), whole * per)]
     with jax.named_scope("tfr.moe_experts"):
-        lane = jnp.arange(tile, dtype=jnp.int32)
+        lanes = [jnp.arange(rows, dtype=jnp.int32) for rows, _, _, _ in loops]
 
-        def tile_of(j):
-            """Tile j: (the visits it holds, which of its rows are real, their expert's output)."""
-            e = jnp.searchsorted(tiles_to, j, side="right").astype(jnp.int32)
-            nth = j - (tiles_to[e] - n_tiles[e])
-            rows = first[e] + nth * tile + lane
-            real = rows < first[e] + visits[e]
-            visit = order[jnp.minimum(rows, t * top_k - 1)]
-            return visit, real, expert_unit(x[visit // top_k], params, limit, e)
+        def walk(lay, acc):
+            """Every loop's tiles through their expert, each handed to ``lay`` with
+            where it lies; returns (``acc``, the visits computed)."""
+            def one_tile(rows, n, upto, before, lane, j, carry):
+                acc, done = carry
+                e = jnp.searchsorted(upto, j, side="right").astype(jnp.int32)
+                nth = j - (upto[e] - n[e])
+                if per == 1:                                # every region is whole tiles: tile j lies at j tiles
+                    run, lies = first[e] + nth * rows + lane, lambda: j * rows
+                else:                                       # in units, so that every start is seen to be whole units
+                    begins = nth * per if before is None else before[e] + nth
+                    run, lies = first[e] + begins * unit + lane, lambda: (units_to[e] - units[e] + begins) * unit
+                real = run < first[e] + visits[e]           # run: the tile's places in the sorted order
+                visit = order[jnp.minimum(run, t * top_k - 1)]
+                acc = lay(acc, lies, visit, real, expert_unit(x[visit // top_k], params, limit, e))
+                return acc, done + real.sum(dtype=jnp.int32)
 
-        def one_tile(j, carry):
-            laid, done = carry
-            _, real, y = tile_of(j)
-            laid = jax.lax.dynamic_update_slice(laid, y.astype(x.dtype), (j * tile, 0))
-            return laid, done + real.sum(dtype=jnp.int32)
+            carry = (acc, jnp.int32(0))
+            for (rows, n, upto, before), lane in zip(loops, lanes):
+                carry = jax.lax.fori_loop(
+                    0, upto[-1], functools.partial(one_tile, rows, n, upto, before, lane), carry)
+            return carry
 
-        if n_held * 8 >= params["router"].shape[1]:
+        if not scattered:
+            def lay(laid, lies, visit, real, y):
+                # no start is negative; said where there are tails, the start stays whole units to the
+                # compiler (the wrap-around's select hides it) and the write stays in the last product
+                return jax.lax.dynamic_update_slice(laid, y.astype(x.dtype), (lies(), 0),
+                                                    allow_negative_indices=per == 1)
+
             # one spare row past the worst case stays zero: what a token reads for an absent expert
-            laid = jnp.zeros((max_tiles * tile + 1, d), x.dtype)
-            laid, done = jax.lax.fori_loop(0, tiles_to[-1], one_tile, (laid, jnp.int32(0)))
+            laid, done = walk(lay, jnp.zeros((max_units * unit + 1, d), x.dtype))
             out = jnp.zeros((t, d), f32)
             for slot in range(top_k):
                 out = out + jnp.where(held[:, slot], gates[:, slot], 0.0)[:, None] * laid[
@@ -704,17 +799,13 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
         else:
             flat_gates = gates.reshape(-1)
 
-            def add_tile(j, carry):
-                out, done = carry
-                visit, real, y = tile_of(j)
+            def add(out, lies, visit, real, y):
                 gate = jnp.where(real, flat_gates[visit], 0.0)
                 # a row past the run's end goes nowhere (index t is dropped)
-                out = out.at[jnp.where(real, visit // top_k, t)].add(
+                return out.at[jnp.where(real, visit // top_k, t)].add(
                     gate[:, None] * y.astype(x.dtype).astype(f32), mode="drop")
-                return out, done + real.sum(dtype=jnp.int32)
 
-            out, done = jax.lax.fori_loop(0, tiles_to[-1], add_tile,
-                                          (jnp.zeros((t, d), f32), jnp.int32(0)))
+            out, done = walk(add, jnp.zeros((t, d), f32))
     if "shared" in params:
         with jax.named_scope("tfr.moe_shared"):
             out = out + expert_unit(x, params["shared"], limit)

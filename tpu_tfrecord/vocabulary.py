@@ -219,6 +219,8 @@ GAUGES: Dict[str, str] = {
     "dsa.selected_share": "pattern LM, latest step recorded: keys the indexers kept over the causal candidates they chose from (lm.record_selected)",
     "mla.plain_pair_share": "pattern LM, latest step recorded: of the block pairs the latent-attention kernel computes, those wholly under the diagonal of one document, where every key is seen (lm.record_pair_kinds)",
     "gqa.kernel_layers": "pattern LM, the score program last traced: full softmax layers whose attention took the Pallas kernel, grouped K/V heads read as the projections wrote them, never copied to the query heads (0 off a TPU)",
+    "moe.tail_unit": "pattern LM, the score program last traced: rows of a tail tile of the expert loop, the unit an expert's visits are rounded up to (0 where the tile is the unit and one loop of whole tiles runs)",
+    "moe.tile_fill": "held experts, latest step recorded: real visits over the rows the expert loops compute, each expert's visits rounded up to whole units (emptiest layer; lm.record_moe_counters)",
     "swa.kernel_layers": "pattern LM, the score program last traced: sliding-window layers whose attention took the Pallas kernel under a window (0 off a TPU)",
     "swa.pairs_walked_share": "pattern LM, the score program last traced: the block pairs a sliding-window layer walks (the band) over the pairs at or under the diagonal of a row",
     "moe.gate_entropy": "latest per-step router gate entropy",
