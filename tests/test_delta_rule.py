@@ -229,3 +229,93 @@ def test_short_documents_under_decays_near_0_and_near_1(rates, form):
            else interpreted_kernel(q, k, v, scalar, beta, segs, 0.25, 256))
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The kernel handed the projections and their taps: it prepares its own q, k and v
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def from_projections(q, k, v, taps, log_decay, beta, segs, scale, tile):
+    """The kernel a TPU runs for a layer, interpreted: the projections in, o and what it prepared out."""
+    return linear_attn._delta_rule_fused(q, k, v, log_decay, beta, segs, scale, tile, interpret=True, taps=taps,
+                                         handed=True)
+
+
+@jax.jit
+def plainly_prepared(q, k, v, taps, segs):
+    """The plain preparation, whole arrays at a time: what the kernel's has to equal."""
+    return tuple(linear_attn.prepared(x, t, segs, unit) for x, t, unit in zip((q, k, v), taps, (True, True, False)))
+
+
+def projections(seed, length, h, group, scalar, dtype=jnp.bfloat16):
+    """Projections as a layer's three products write them (not normed, a few units wide), taps of the
+    model's size, and the rest of :func:`grouped_scalar_inputs` at the kernel's width, one row."""
+    _, _, _, log_decay, beta, segs = delta_rule_inputs(seed, length, b=1, h=h, d=128)
+    r = np.random.default_rng(seed)
+    q, k, v = (jnp.asarray(1.5 * r.standard_normal((1, n, length, 128)), dtype) for n in (h // group, h // group, h))
+    taps = tuple(jnp.asarray(0.5 * r.standard_normal((4, n * 128)), dtype) for n in (h // group, h // group, h))
+    return q, k, v, taps, (log_decay[..., 0] if scalar else log_decay), beta, segs
+
+
+def bits(a):
+    return np.asarray(a).view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def held_to_the_plain_forms(q, k, v, taps, log_decay, beta, segs, tile, exact=True):
+    """The interpreted kernel, its prologue included, against the plain preparation (bit for bit
+    where the operands are bfloat16: both round the same float32 numbers twice), the plain chunked
+    form and the token-by-token recurrence over the plainly prepared q, k and v."""
+    o, *handed = from_projections(q, k, v, taps, log_decay, beta, segs, 0.25, tile)
+    want = plainly_prepared(q, k, v, taps, segs)
+    for name, got, plain in zip("qkv", handed, want):
+        assert got.shape == plain.shape and got.dtype == plain.dtype == q.dtype, name
+        if exact:
+            assert np.array_equal(bits(got), bits(plain)), (name, int((bits(got) != bits(plain)).sum()))
+        else:  # float32 operands are never rounded: the last place follows how a compiler joins a product and a sum
+            np.testing.assert_allclose(got, plain, atol=2e-6, err_msg=name)
+    assert o.shape == v.shape and o.dtype == jnp.float32
+    np.testing.assert_allclose(o, recurrent(*want, log_decay, beta, segs, scale=0.25), atol=5e-6)
+    np.testing.assert_allclose(o, chunked(*want, log_decay, beta, segs, scale=0.25, chunk=64), atol=5e-6)
+    np.testing.assert_allclose(np.linalg.norm(np.asarray(handed[0], np.float32), axis=-1), 1, atol=1e-2)
+    return o, handed
+
+
+@pytest.mark.parametrize("length,tile,h,group,scalar", [
+    (256, 128, 2, 1, False), (256, 128, 2, 2, True), (256, 256, 4, 4, True), (384, 128, 3, 1, True)],
+    ids=["a_channel", "a_token_two_share", "a_token_four_share", "a_token_own_keys_odd_heads"])
+def test_the_kernel_that_prepares_its_own_is_the_plain_preparation_and_the_recurrence(length, tile, h, group, scalar):
+    """Both forms of the decay, a key head a value head or shared by two or four (prepared once a KEY
+    head: where four share, two grid steps prepare it and write the same block), two heads a grid
+    step or one, tiles of 256 and 128, one tile a row and several: the recurrence runs a grid step
+    behind the preparation and reads it from VMEM."""
+    held_to_the_plain_forms(*projections(length + h, length, h, group, scalar), tile)
+
+
+def test_the_kernel_prepares_float32_projections_too():
+    held_to_the_plain_forms(*projections(5, 256, 2, 1, False, jnp.float32), 128, exact=False)
+
+
+@pytest.mark.parametrize("tile", [128, 256])
+def test_the_taps_stop_at_a_boundary_wherever_it_falls_in_a_tile(tile):
+    """Documents that start at rows 0, 1, 2 and 3 of a tile of 128 (the tap three places back reads
+    the tile before, or must not), one that ends inside the three rows before a tile and one that
+    lies wholly in them, a boundary at a tile's last row and at a strip's, a row's first tile (nothing
+    before it: the first three tokens have fewer taps), pads at the end. The kernel's preparation is
+    the plain one to the bit, so a tap that crossed a boundary or missed the rows before a tile shows."""
+    length = 768
+    q, k, v, taps, log_decay, beta, _ = projections(11, length, 2, 1, False)
+    segs = np.zeros((1, length), np.int32)
+    cuts = [0, 126, 128, 193, 257, 386, 515, 640, 700]   # 128 + 0, 256 + 1, 384 + 2, 512 + 3; 640 = 5 x 128
+    for s, (a, z) in enumerate(zip(cuts, cuts[1:])):
+        segs[0, a:z] = s + 1
+    segs = jnp.asarray(segs)
+    _, (qp, _, vp) = held_to_the_plain_forms(q, k, v, taps, log_decay, beta, segs, tile)
+    # and it matters: told that the row is one document, the tokens after a boundary come out otherwise
+    whole = plainly_prepared(q, k, v, taps, jnp.ones_like(segs))
+    for start in cuts[1:-1]:
+        assert np.abs(np.asarray(whole[2][0, :, start:start + 3] - vp[0, :, start:start + 3], np.float32)).max() > 1e-2
+    # a row's first token has one tap: v there is SiLU of the rounded x * taps[0]
+    first = (v[0, :, 0].astype(jnp.float32) * taps[2][0].reshape(2, 128).astype(jnp.float32)).astype(v.dtype)
+    np.testing.assert_array_equal(bits(vp[0, :, 0]), bits(jax.nn.silu(first.astype(jnp.float32)).astype(v.dtype)))

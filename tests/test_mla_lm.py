@@ -490,7 +490,11 @@ def test_an_unknown_mixer_or_feed_forward_part_is_named_with_the_ones_there_are(
 #: A PR that means to change one of them records its own. PR 47 gave the expert loop tail
 #: tiles of 256 rows under tiles that are several such units; these three are traced at
 #: ``expert_tile=8``, under the unit, where there is one loop and the program it was: no
-#: hash moved.
+#: hash moved. PR 48 meant to change ``solar`` and ``gigachat`` and changed them on a TPU
+#: alone, where the delta-rule kernel is handed the projections and prepares its own q, k
+#: and v (tests/test_tpu_compile.py reads that program): off a TPU the preparation moved
+#: from ``lm._handed_over`` to ``linear_attn.prepared`` and is traced operation for
+#: operation as it was, barrier and all, so both hashes stand as recorded.
 OLDER_PROGRAMS = {
     "solar": "c2d1f66a567aa6a4e8b2558c821d20ad80395c2014c71aeba6e98e3dd190cdab",
     "gigachat": "d36b4cb8034c876ee4bb3f4a243c8c63ef2d23a177013e5ed13b483926e3def6",

@@ -259,9 +259,16 @@ def the_parents_three_lines(p, q, k, v, segments):
         taps = taps.reshape(taps.shape[0], a.shape[1], a.shape[3])
         return jax.nn.silu(linear_attn.short_conv(a, taps, segments).astype(jnp.float32))
 
-    q = lm._l2_norm(conv_silu(q, p["conv_q"])).astype(q.dtype)
-    k = lm._l2_norm(conv_silu(k, p["conv_k"])).astype(k.dtype)
+    q = linear_attn.unit_norm(conv_silu(q, p["conv_q"])).astype(q.dtype)
+    k = linear_attn.unit_norm(conv_silu(k, p["conv_k"])).astype(k.dtype)
     return q, k, conv_silu(v, p["conv_v"]).astype(v.dtype)
+
+
+def the_hand_over(p, q, k, v, segments):
+    """What ``linear_attn.delta_rule_layer`` prepares off a TPU (since PR 48 the preparation lives
+    beside ``short_conv``, one function an array: ``linear_attn.prepared``)."""
+    return tuple(linear_attn.prepared(a, p[name], segments, unit)
+                 for a, name, unit in ((q, "conv_q", True), (k, "conv_k", True), (v, "conv_v", False)))
 
 
 @pytest.mark.parametrize("key_heads", [4, 2], ids=["a_key_head_a_value_head", "shared_key_heads"])
@@ -279,7 +286,7 @@ def test_the_hand_over_is_the_parents_three_lines_bit_for_bit(dtype, key_heads):
     q, k, v = (jnp.asarray(rng.standard_normal((2, h, 64, 8)), dtype) for h in (key_heads, key_heads, 4))
     p = {name: jnp.asarray(rng.standard_normal((4, h * 8)), jnp.float32)
          for name, h in (("conv_q", key_heads), ("conv_k", key_heads), ("conv_v", 4))}
-    got = jax.jit(lm._handed_over)(p, q, k, v, segs)
+    got = jax.jit(the_hand_over)(p, q, k, v, segs)
     want = jax.jit(the_parents_three_lines)(p, q, k, v, segs)
     for name, a, b in zip("qkv", got, want):
         assert a.dtype == b.dtype == dtype and a.shape == b.shape, name
@@ -346,12 +353,13 @@ def test_a_layer_that_takes_the_kernel_is_the_layer_and_is_counted(monkeypatch):
         return jax.jit(lambda *a: lm.score(*a, cfg))(params, tokens, segs, at)["logprob"]
 
     plain = traced_anew()
-    assert METRICS.gauge_value("kda.fused_layers") == 0
+    assert METRICS.gauge_value("kda.fused_layers") == 0 and METRICS.gauge_value("conv.kernel_layers") == 0
     monkeypatch.setattr(linear_attn, "fused_tile",
                         lambda shape, chunk: 128 if shape[-1] == 128 and chunk == 64 else None)
     with pltpu.force_tpu_interpret_mode():
         fused = traced_anew()
-    assert METRICS.gauge_value("kda.fused_layers") == 2
+    # both layers' kernels prepared their own q, k and v from the projections
+    assert METRICS.gauge_value("kda.fused_layers") == 2 and METRICS.gauge_value("conv.kernel_layers") == 2
     np.testing.assert_allclose(fused, plain, atol=2e-5)
     assert np.abs(np.asarray(plain)).max() > 1
 

@@ -176,50 +176,61 @@ class TestOneChip:
 
     def test_the_delta_rule_kernel_at_the_cells_shape(self, one_chip):
         """``solar_open2_ep8.score``'s delta-rule layer, [2, 64, 8192, 128] in
-        chunks of 64 and grid steps of two heads' 256 tokens, q, k and v bfloat16
-        as the convolution writes them and the decay a channel float32: the kernel
-        fits VMEM (the compiler refuses one that does not), the program's
-        arguments are those operands as they are, and nothing of q's size exists
-        beside them and the output."""
+        chunks of 64 and grid steps of two heads' 256 tokens, handed what the
+        layer has: the three PROJECTIONS bfloat16 as they were written, their
+        taps [4, 64 * 128], the decay a channel float32, beta, the segment ids.
+        The kernel prepares its own q, k and v (taps, SiLU, unit norm, a grid
+        step ahead of the recurrence) and fits VMEM (the compiler refuses one
+        that does not); the program's arguments are those operands as they
+        are, and nothing of q's size exists beside them and the output."""
         from tpu_tfrecord.models import linear_attn
 
         shape = (2, 64, 8192, 128)
         x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+        taps = jax.ShapeDtypeStruct((4, 64 * 128), jnp.bfloat16, sharding=one_chip)
         decay = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
         beta = jax.ShapeDtypeStruct(shape[:3], jnp.float32, sharding=one_chip)
         segs = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
-        compiled = jax.jit(lambda q, k, v, g, b, s: linear_attn._delta_rule_fused(
-            q, k, v, g, b, s, 128 ** -0.5, linear_attn._TILES[0])).lower(x, x, x, decay, beta, segs).compile()
+        compiled = jax.jit(lambda q, k, v, t, g, b, s: linear_attn._delta_rule_fused(
+            q, k, v, g, b, s, 128 ** -0.5, linear_attn._TILES[0], taps=t)).lower(
+                x, x, x, (taps,) * 3, decay, beta, segs).compile()
         assert "tpu_custom_call" in compiled.as_text()
         mem = compiled.memory_analysis()
         assert mem.output_size_in_bytes == 4 * np.prod(shape)
-        assert mem.argument_size_in_bytes == (3 * 2 + 4) * np.prod(shape) + 4 * np.prod(shape[:3]) + 4 * 2 * 8192
+        assert mem.argument_size_in_bytes == ((3 * 2 + 4) * np.prod(shape) + 4 * np.prod(shape[:3]) + 4 * 2 * 8192
+                                              + 3 * 2 * 4 * 64 * 128)
         assert mem.temp_size_in_bytes < 4 * np.prod(shape) // 8
 
     def test_the_delta_rule_kernel_under_one_decay_a_token_at_the_cells_shape(self, one_chip):
-        """``gigachat35_ep16.score``'s delta-net layer: v [2, 64, 8192, 128] over q
-        and k at 32 heads, all three bfloat16 as the convolution writes them, a
-        decay and a beta [2, 64, 8192]. The chip's compiler takes the kernel's
-        other form (lane rolls of a row, bfloat16 blocks, a key head's block
-        index), and the program's arguments are what the mechanism has: no
-        copy of q or k to 64 heads, no decay of v's shape, nothing float32 of
-        v's size but the output."""
+        """``gigachat35_ep16.score``'s delta-net layer: v's projection [2, 64,
+        8192, 128] over q's and k's at 32 heads, all three bfloat16 as they were
+        written, their taps, a decay and a beta [2, 64, 8192]. The chip's
+        compiler takes the kernel's other form (lane rolls of a row, bfloat16
+        blocks, a key head's block index for its projections AND its taps, q
+        and k prepared once a key head), and the program's arguments are what
+        the mechanism has: no copy of q or k to 64 heads, no decay of v's
+        shape, nothing float32 of v's size but the output. With the prepared
+        q, k and v handed back (the probed layer) they are three more outputs
+        in bfloat16, at their own heads."""
         from tpu_tfrecord.models import linear_attn
 
         b, h, hk, l, d = 2, 64, 32, 8192, 128
         keys = jax.ShapeDtypeStruct((b, hk, l, d), jnp.bfloat16, sharding=one_chip)
         values = jax.ShapeDtypeStruct((b, h, l, d), jnp.bfloat16, sharding=one_chip)
+        taps = tuple(jax.ShapeDtypeStruct((4, n * d), jnp.bfloat16, sharding=one_chip) for n in (hk, hk, h))
         by_token = jax.ShapeDtypeStruct((b, h, l), jnp.float32, sharding=one_chip)
         segs = jax.ShapeDtypeStruct((b, l), jnp.int32, sharding=one_chip)
-        compiled = jax.jit(lambda q, k, v, g, bt, s: linear_attn._delta_rule_fused(
-            q, k, v, g, bt, s, d ** -0.5, linear_attn._TILES[0])).lower(
-                keys, keys, values, by_token, by_token, segs).compile()
-        assert "tpu_custom_call" in compiled.as_text()
-        mem = compiled.memory_analysis()
-        assert mem.output_size_in_bytes == 4 * b * h * l * d
-        operands = 2 * (2 * b * hk * l * d + b * h * l * d) + 2 * 4 * b * h * l + 4 * b * l
-        assert mem.argument_size_in_bytes == operands          # 545 MB where the broadcasts are 2.1 GB
-        assert mem.temp_size_in_bytes < 4 * b * h * l * d // 8
+        operands = (2 * (2 * b * hk * l * d + b * h * l * d) + 2 * 4 * b * h * l + 4 * b * l
+                    + 2 * 4 * d * (hk + hk + h))                # 545 MB where the broadcasts are 2.1 GB
+        for handed, more in ((False, 0), (True, 2 * (2 * b * hk * l * d + b * h * l * d))):
+            compiled = jax.jit(lambda q, k, v, t, g, bt, s: linear_attn._delta_rule_fused(
+                q, k, v, g, bt, s, d ** -0.5, linear_attn._TILES[0], taps=t, handed=handed)).lower(
+                    keys, keys, values, taps, by_token, by_token, segs).compile()
+            assert "tpu_custom_call" in compiled.as_text()
+            mem = compiled.memory_analysis()
+            assert 0 <= mem.output_size_in_bytes - (4 * b * h * l * d + more) < 1024      # a tuple's table
+            assert mem.argument_size_in_bytes == operands
+            assert mem.temp_size_in_bytes < 4 * b * h * l * d // 8
 
     def test_the_solar_patterns_score_holds_no_loop_under_the_scan(self, one_chip, monkeypatch):
         """``lm.score`` for the softmax / delta-rule period at the cell's row
@@ -228,8 +239,9 @@ class TestOneChip:
         it: under ``tfr.kda_scan`` the compiled program holds the kernel
         once a layer and no ``while`` (the plain form's loops over head
         groups and chunks), and of float32 arrays of q's size only the
-        kernel's output (q, k and v come in bfloat16 from ``tfr.kda_conv``, the
-        log-decay is ``tfr.kda_proj``'s)."""
+        kernel's output (the projections come in bfloat16 from ``tfr.kda_proj``,
+        as the log-decay does, and the kernel prepares its own q, k and v: since
+        PR 48 ``tfr.kda_conv`` holds no operation)."""
         from tpu_tfrecord.models import linear_attn
 
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -254,20 +266,21 @@ class TestOneChip:
         assert not [line for line in scan if re.search(r"= \S+ while\(", line)]
         q_sized = "f32[" + ",".join(map(str, q_shape)) + "]"
         assert sum(bool(re.match(rf"\s*(ROOT )?%?\S+ = {re.escape(q_sized)}", line)) for line in scan) == 3
+        assert "tfr.kda_conv" not in hlo
 
     @pytest.mark.parametrize("kind, widths", [
         ("kda", dict(d_model=4096, gate_rank=128)),
         ("gdn", dict(d_model=7168, gdn_key_heads=32, centred_norms=True))],
         ids=["solar_open2_ep8", "gigachat35_ep16"])
-    def test_a_delta_rule_layer_hands_its_kernel_what_the_convolution_wrote(self, one_chip, monkeypatch, kind, widths):
+    def test_a_delta_rule_layer_hands_its_kernel_the_projections(self, one_chip, monkeypatch, kind, widths):
         """One delta-rule layer of each cell alone at the cell's shape, compiled for
-        the chip as a TPU runs it: under the conv scope no float32 array of v's
-        size (or of q's, where key heads are fewer) exists and five bfloat16 ones
-        are written, v once and q and k twice (the rounded convolution beside its
-        sum of squares, then the normalised array the kernel reads: the compiler
-        stores the former, and on the chip that is faster than reading four
-        shifted windows of the projection again, PERF.md PR 42); under the scan
-        scope the kernel and nothing of that size but its output."""
+        the chip as a TPU runs it (until PR 48 the convolution wrote five bfloat16
+        arrays a layer under the conv scope and the kernel read three of them):
+        under the conv scope NO operation is left, so no array of q's or of v's
+        size is written there in either dtype; under the scan scope ONE custom
+        call, and of arrays of those sizes its float32 output and, because this
+        layer is probed, exactly three bfloat16 ones: the q, k and v the kernel
+        prepared and consumed, written beside the output for the probe to read."""
         from tpu_tfrecord.models import linear_attn
 
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -295,10 +308,13 @@ class TestOneChip:
                     count += result.count(sized)
             return count
 
-        sizes = {64, cfg.gdn_key_heads or 64}      # v's heads, and q's and k's where they are fewer
-        assert not any(written("conv", "f32", heads) for heads in sizes)
-        assert sum(written("conv", "bf16", heads) for heads in sizes) <= 2 + 2 + 1
-        assert written("scan", "f32", 64) == 1 and written("scan", "bf16", 64) == 0
+        key_heads = cfg.gdn_key_heads or 64        # q's and k's heads, fewer than v's 64 or as many
+        assert not any(re.search(rf'op_name="[^"]*tfr\.{kind}_conv', line) for line in entry)
+        assert written("scan", "f32", 64) == 1 and not (key_heads != 64 and written("scan", "f32", key_heads))
+        if key_heads == 64:
+            assert written("scan", "bf16", 64) == 3
+        else:
+            assert written("scan", "bf16", key_heads) == 2 and written("scan", "bf16", 64) == 1
         assert sum("custom_call_target=\"tpu_custom_call\"" in line for line in entry) == 1
 
     def test_the_state_space_kernel_at_the_cells_shape(self, one_chip):

@@ -82,7 +82,13 @@ Two forms of it. On a TPU, for heads of whole 128s and rows of whole pairs
 of chunks of 64, one Pallas kernel (``_delta_rule_fused``): a grid step
 takes two heads' tile of a row from q, k, v, log_decay, beta and segments
 to o with every intermediate in VMEM and the state in a scratch carried
-from tile to tile. Everywhere else (the CPU, other widths and chunks) the
+from tile to tile. A layer hands that kernel its PROJECTIONS and their taps
+(:func:`delta_rule_layer`) and the kernel prepares q, k and v itself, a
+tile a grid step ahead of the recurrence (the four taps, SiLU, the unit
+norm and both roundings, :func:`prepared`'s arithmetic on strips of rows):
+a projection crosses memory once, where a convolution beside the kernel
+read it through four shifted windows and wrote it twice. Everywhere else
+(the CPU, other widths and chunks) the
 plain JAX form (``_delta_rule_plain``), which lays the same intermediates
 out in memory a group of ``_HEADS`` heads at a time and loops over the
 chunks; the tests hold the interpreted kernel to it and both to the
@@ -120,6 +126,31 @@ def short_conv(x, taps, segments, bias=None):
     if bias is not None:
         out = out + bias.astype(f32)[None, :, None, :]
     return out.astype(x.dtype)
+
+
+def unit_norm(x, eps: float = 1e-6):
+    """x over its norm along the last axis (a head's channels)."""
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def _activated(y, unit: bool):
+    """What follows the convolution's rounded result ``y``: SiLU in float32,
+    for q and k (``unit``) the unit norm, rounded to y's dtype once more. One
+    spelling for the plain form's whole arrays and the kernel's strips."""
+    s = jax.nn.silu(y.astype(jnp.float32))
+    return (unit_norm(s) if unit else s).astype(y.dtype)
+
+
+def prepared(x, taps, segments, unit: bool):
+    """What a delta-rule layer's recurrence reads of one projection: x
+    [B, H, L, D] as the projection wrote it under its taps [K, H * D] that stop
+    at a document's start (:func:`short_conv`, rounded), SiLU, for q and k
+    (``unit``) a unit norm over the head's channels, rounded once more to the
+    dtype it came in: float32 arithmetic, two roundings. The plain form, on
+    whole arrays; the kernel prepares a tile of the projection itself
+    (:func:`_prepared_strip`) with the same arithmetic in the same order."""
+    taps = taps.reshape(taps.shape[0], x.shape[1], x.shape[3])
+    return _activated(short_conv(x, taps, segments), unit)
 
 
 def delta_rule_recurrent(q, k, v, log_decay, beta, segments, scale):
@@ -265,6 +296,8 @@ def _chunked_heads(q, k, v, g, beta, seg, scale):
 _PAIR = 128           # tokens laid out at a time: two chunks side by side, one matrix-unit tile
 _KERNEL_CHUNK = 64    # the chunk the kernel computes in
 _TILES = (256, 128)   # tokens of a grid step, the larger that divides the row
+_STRIP = 128          # rows of a tile the kernel prepares at a time (a trace's seconds go by the number of strips)
+_HALO = 8             # rows kept of the tile before, for the taps that reach back: one float32 tile of rows
 
 
 def fused_tile(shape, chunk: int):
@@ -469,20 +502,61 @@ def _in_turn(generators):
     return out
 
 
-def _delta_rule_kernel(seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
-                       state_ref, before_ref, *, scale: float):
+@functools.partial(jax.jit, static_argnames=("unit", "dtype"))
+def _prepared_strip(x, before, taps, same, *, unit: bool, dtype):
+    """:func:`prepared` for ``_STRIP`` rows of one head: x [R, D] float32 (the
+    projection's rows, widened), ``before`` [8, D] the rows that precede them
+    (the last of the strip before), taps [K, D] float32, ``same[j - 1]`` [R, D]
+    whether the token j places back is of the row's own document. The taps'
+    sum in :func:`short_conv`'s order; a shift is a roll of the rows with the
+    rows before them on top, never a second read. -> [R, D] ``dtype``. Jitted:
+    a kernel's strips are traced once, whatever their number."""
+    rows = jnp.concatenate([before, x])
+    mixed = x * taps[:1]
+    for j in range(1, taps.shape[0]):
+        mixed = mixed + jnp.where(same[j - 1], _roll(rows, j)[_HALO:], 0.0) * taps[j:j + 1]
+    return _activated(mixed.astype(dtype), unit)
+
+
+def _prepared_tile(seg_col_ref, sources, halo_ref, seg_halo_ref):
+    """The kernel's prologue: every head of a grid step's tile of q, k and v
+    prepared as :func:`prepared` prepares whole arrays, ``_STRIP`` rows at a
+    time. ``sources``: (x_ref [1, n, tile, D] the projection's block, taps_ref
+    [n, K, D], out_ref as x_ref, unit) for q, k and v; ``halo_ref`` [heads, 8, D]
+    float32 and ``seg_halo_ref`` [8, D] carry the last rows of the strip before
+    and their segment ids from strip to strip and from tile to tile (-1 before
+    a row's first tile: no tap counts there). What decides whether a tap counts
+    is the same for every head: made once a strip. Returns the function of one
+    strip, ``strip(at)`` for the rows from ``at * _STRIP`` (a number or a loop's
+    index), and the number of strips; they have to run in order."""
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    reach = sources[0][1].shape[1] - 1
+    heads = [(x_ref, taps_ref[i].astype(f32), out_ref, unit, i)
+             for x_ref, taps_ref, out_ref, unit in sources for i in range(x_ref.shape[1])]
+
+    def strip(at):
+        rows = pl.ds(at * _STRIP if isinstance(at, int) else pl.multiple_of(at * _STRIP, _STRIP), _STRIP)
+        seg = seg_col_ref[0, rows]
+        ids = jnp.concatenate([seg_halo_ref[...], seg])
+        same = [_roll(ids, j)[_HALO:] == seg for j in range(1, reach + 1)]
+        seg_halo_ref[...] = seg[-_HALO:]
+        for slot, (x_ref, taps, out_ref, unit, i) in enumerate(heads):
+            x = x_ref[0, i, rows].astype(f32)
+            out_ref[0, i, rows] = _prepared_strip(x, halo_ref[slot], taps, same, unit=unit, dtype=out_ref.dtype)
+            halo_ref[slot] = x[-_HALO:]
+
+    return strip, seg_col_ref.shape[1] // _STRIP
+
+
+def _tile_of_heads(seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state_ref, before_ref,
+                   scale: float):
     """One tile of the heads of a grid step: every pair of chunks laid out in
     VMEM, then each head's chunks in order against its state, which a
     scratch carries from tile to tile. ``q_ref`` and ``k_ref`` hold the key
     heads these value heads read (as many, or one for all); ``g_ref`` lies as
     ``v_ref`` does (a decay a channel) or as ``beta_ref`` (one a token)."""
-    from jax.experimental import pallas as pl
-
-    @pl.when(pl.program_id(2) == 0)
-    def _first():
-        state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
-        before_ref[...] = jnp.full(before_ref.shape, -2, jnp.int32)
-
     heads, tile, d = v_ref.shape[1:]
     shared = heads // q_ref.shape[1]          # value heads of this step that read one key head
     scalar = len(g_ref.shape) == 5
@@ -514,13 +588,84 @@ def _delta_rule_kernel(seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, bet
     before_ref[...] = jnp.broadcast_to(last, before_ref.shape)
 
 
-def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, interpret=False):
+def _delta_rule_kernel(seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
+                       state_ref, before_ref, *, scale: float):
+    """A grid step handed q, k and v as the recurrence reads them: the state
+    starts a row at zero, then :func:`_tile_of_heads`."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first():
+        state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
+        before_ref[...] = jnp.full(before_ref.shape, -2, jnp.int32)
+
+    _tile_of_heads(seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state_ref, before_ref,
+                   scale)
+
+
+def _delta_rule_kernel_from_projections(seg_next_ref, seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                                        tq_ref, tk_ref, tv_ref, o_ref, *rest, scale: float, handed: bool):
+    """A grid step handed the PROJECTIONS and their taps: the kernel prepares
+    a tile itself (:func:`_prepared_tile`) and works a step behind. Step t of a
+    row's n + 1 prepares tile t into one of two places in VMEM while the
+    recurrence (:func:`_tile_of_heads`, the body it is without) runs over tile
+    t - 1 from the other: the preparation waits for nothing the recurrence
+    computes, so the two are one straight line and the compiler is free to
+    put the preparation's vector work into bundles the recurrence's products
+    leave room in (it hides half of it under one decay a token and a sixth
+    under a decay a channel; where the strips stand in the source moves
+    nothing). A row's first step prepares alone, in a loop over the strips;
+    its last prepares its last tile once more, for nobody. ``q_ref``,
+    ``k_ref``, ``v_ref``, the taps and ``seg_next_ref`` are tile t's; the other
+    operands and ``o_ref`` tile t - 1's. ``handed``: three more outputs, tile
+    t's q, k and v as prepared."""
+    from jax.experimental import pallas as pl
+
+    handed_refs, rest = (rest[:3], rest[3:]) if handed else ((), rest)
+    state_ref, before_ref, halo_ref, seg_halo_ref, *ready = rest
+    step, last_step = pl.program_id(2), pl.num_programs(2) - 1
+    into, of = ([r.at[pl.ds(slot, 1)] for r in ready] for slot in (step % 2, 1 - step % 2))
+    strip, strips = _prepared_tile(
+        seg_next_ref, list(zip((q_ref, k_ref, v_ref), (tq_ref, tk_ref, tv_ref), into, (True, True, False))),
+        halo_ref, seg_halo_ref)
+
+    @pl.when(step == 0)
+    def _first():
+        state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
+        before_ref[...] = jnp.full(before_ref.shape, -2, jnp.int32)
+        halo_ref[...] = jnp.zeros(halo_ref.shape, jnp.float32)
+        seg_halo_ref[...] = jnp.full(seg_halo_ref.shape, -1, jnp.int32)
+        jax.lax.fori_loop(0, strips, lambda at, _: strip(at), None)
+
+    @pl.when(step > 0)
+    def _later():
+        _tile_of_heads(seg_col_ref, seg_row_ref, *of, g_ref, beta_ref, o_ref, state_ref, before_ref, scale)
+        for at in range(strips):  # in the same straight line: the compiler places them among the products
+            strip(at)
+
+    if handed:
+        @pl.when(step < last_step)
+        def _hand_back():
+            for out_ref, ref in zip(handed_refs, into):
+                out_ref[...] = ref[...]
+
+
+def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, interpret=False, taps=None,
+                      handed: bool = False):
     """:func:`delta_rule_chunked` in chunks of 64 as one Pallas TPU kernel:
     grid (rows, pairs of heads, tiles of ``tile`` tokens), a head's tiles in
     order. Reads q, k, v, log_decay and beta once, writes o once: q, k and v
     in the dtype they come in (float32 in VMEM), a grid step's key heads by
     their block index where they are fewer than its value heads, a decay of
-    one number a token in the layout ``beta`` has."""
+    one number a token in the layout ``beta`` has.
+
+    With ``taps`` (q's, k's and v's, each [K, heads * D]) q, k and v are the
+    projections as they were written and the kernel prepares each tile itself
+    (:func:`prepared`'s arithmetic) a grid step ahead of the recurrence
+    (:func:`_delta_rule_kernel_from_projections`: one step more a row): a
+    projection crosses memory once. What it prepared stays in VMEM unless
+    ``handed``: then -> (o, q, k, v), the three written out in the dtype they
+    came in, for a caller that wants to read what the recurrence consumed."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -528,37 +673,71 @@ def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, inte
     f32 = jnp.float32
     heads = 2 - h % 2          # two chains in turn keep the matrix unit busier than one
     segments = segments.astype(jnp.int32)
-    per_head = pl.BlockSpec((1, heads, tile, d), lambda bi, hi, ti: (bi, hi, ti, 0))
-    per_token = pl.BlockSpec((1, heads, 1, 1, tile), lambda bi, hi, ti: (bi, hi, ti, 0, 0))
+    tiles, lag = l // tile, taps is not None
+
+    def now(ti):    # the tile the recurrence works on: a grid step behind where the kernel prepares
+        return jnp.maximum(ti - 1, 0) if lag else ti
+
+    def ahead(ti):  # the tile that is prepared
+        return jnp.minimum(ti, tiles - 1) if lag else ti
+
     group = h // q.shape[1]    # value heads a key head
     key_heads = max(1, heads // group)
-    per_key_head = per_head if group == 1 else pl.BlockSpec(
-        (1, key_heads, tile, d), lambda bi, hi, ti: (bi, hi * heads // (group * key_heads), ti, 0))
+
+    def per_head(at):
+        return pl.BlockSpec((1, heads, tile, d), lambda bi, hi, ti: (bi, hi, at(ti), 0))
+
+    def per_key_head(at):
+        return per_head(at) if group == 1 else pl.BlockSpec(
+            (1, key_heads, tile, d), lambda bi, hi, ti: (bi, hi * heads // (group * key_heads), at(ti), 0))
+
+    per_token = pl.BlockSpec((1, heads, 1, 1, tile), lambda bi, hi, ti: (bi, hi, now(ti), 0, 0))
     scalar = log_decay.ndim == 3
 
     def by_token(a):  # [B, H, L] a tile of tokens along the lanes
         return a.astype(f32).reshape(b, h, l // tile, 1, tile)
 
+    projections = [per_key_head(ahead), per_key_head(ahead), per_head(ahead)]
+    in_specs = [
+        pl.BlockSpec((1, tile, d), lambda bi, hi, ti: (bi, now(ti), 0)),
+        pl.BlockSpec((1, 8, tile), lambda bi, hi, ti: (bi, 0, now(ti))),
+        *projections, per_token if scalar else per_head(now), per_token,
+    ]
+    operands = [jnp.broadcast_to(segments[:, :, None], (b, l, d)), jnp.broadcast_to(segments[:, None, :], (b, 8, l)),
+                q, k, v, by_token(log_decay) if scalar else log_decay.astype(f32), by_token(beta)]
+    out_specs, out_shape = per_head(now), jax.ShapeDtypeStruct((b, h, l, d), f32)
+    scratch = [pltpu.VMEM((heads, d, d), f32), pltpu.VMEM((8, d), jnp.int32)]
+    kernel = functools.partial(_delta_rule_kernel, scale=scale)
+    if lag:
+        kernel = functools.partial(_delta_rule_kernel_from_projections, scale=scale, handed=handed)
+        # the segment ids of the tile that is prepared, beside those of the tile the recurrence works on
+        in_specs.insert(0, pl.BlockSpec((1, tile, d), lambda bi, hi, ti: (bi, ahead(ti), 0)))
+        operands.insert(0, operands[0])
+        for x, t, spec in zip((q, k, v), taps, projections):
+            # a head's taps together, [heads, K, D]: a block of some heads' has whole last two sizes
+            operands.append(jnp.swapaxes(t.reshape(t.shape[0], x.shape[1], d), 0, 1))
+            in_specs.append(pl.BlockSpec((spec.block_shape[1], t.shape[0], d),
+                                         lambda bi, hi, ti, at=spec.index_map: (at(bi, hi, ti)[1], 0, 0)))
+        # the rows before a strip, a head-tile each, and their ids; two places for what is prepared
+        scratch += [pltpu.VMEM((2 * key_heads + heads, _HALO, d), f32), pltpu.VMEM((_HALO, d), jnp.int32)]
+        scratch += [pltpu.VMEM((2, *spec.block_shape[1:]), x.dtype) for spec, x in zip(projections, (q, k, v))]
+        if handed:
+            out_specs = [out_specs, *projections]
+            out_shape = [out_shape] + [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)]
+
     call = pl.pallas_call(
-        functools.partial(_delta_rule_kernel, scale=scale),
-        grid=(b, h // heads, l // tile),
-        in_specs=[
-            pl.BlockSpec((1, tile, d), lambda bi, hi, ti: (bi, ti, 0)),
-            pl.BlockSpec((1, 8, tile), lambda bi, hi, ti: (bi, 0, ti)),
-            per_key_head, per_key_head, per_head, per_token if scalar else per_head, per_token,
-        ],
-        out_specs=per_head,
-        scratch_shapes=[pltpu.VMEM((heads, d, d), f32), pltpu.VMEM((8, d), jnp.int32)],
-        out_shape=jax.ShapeDtypeStruct((b, h, l, d), f32),
+        kernel,
+        grid=(b, h // heads, tiles + lag),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )
     with kernel_trace("kernel.trace.kda_scan"):  # the body's trace, as a program is traced
-        return call(
-            jnp.broadcast_to(segments[:, :, None], (b, l, d)),
-            jnp.broadcast_to(segments[:, None, :], (b, 8, l)),
-            q, k, v, by_token(log_decay) if scalar else log_decay.astype(f32), by_token(beta))
+        return call(*operands)
 
 
 def _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk: int):
@@ -608,6 +787,40 @@ def delta_rule_chunked(q, k, v, log_decay, beta, segments, scale, chunk: int = 6
     if tile is not None:
         return _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile)
     return _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk)
+
+
+def delta_rule_layer(q, k, v, taps, log_decay, beta, segments, scale, chunk: int = 64, scope: str = "tfr.kda",
+                     handed: bool = False):
+    """A delta-rule layer from its projections to its recurrence's output, the
+    one entry of both mixers: q, k ``[B, Hk, L, D]`` and v ``[B, H, L, D]`` as
+    the projections wrote them, ``taps`` their three convolutions' ``[K, heads *
+    D]``, the rest as :func:`delta_rule_chunked` takes it -> (o, None), or with
+    ``handed`` (o, (q, k, v)): what the recurrence read, prepared and rounded
+    as :func:`prepared` says, in the dtype the projections came in.
+
+    On a TPU, for the shapes the kernel takes (:func:`fused_tile`), the kernel
+    prepares its own tiles: the projections cross memory once, nothing runs
+    under ``<scope>_conv``, and what is handed back is what the kernel wrote
+    beside its output: a caller that walks the recurrence again must read what
+    the kernel consumed, and XLA's preparation of the same projections is not
+    that (inside a fusion the TPU compiler drops the convolution's rounding to
+    bfloat16 before SiLU: 8% of q's and k's elements and a quarter of v's come
+    out a bfloat16 place away; with that rounding forced the two agree in all
+    but 5 of a million, Mosaic's and XLA's last float32 place). Elsewhere
+    :func:`prepared` on whole arrays under ``<scope>_conv``, the three behind a
+    barrier (the recurrence and the caller read those very arrays), then
+    :func:`delta_rule_chunked` under ``<scope>_scan``."""
+    tile = fused_tile(v.shape, chunk)
+    if tile is not None:
+        with jax.named_scope(f"{scope}_scan"):
+            out = _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile, taps=taps, handed=handed)
+        return (out[0], tuple(out[1:])) if handed else (out, None)
+    with jax.named_scope(f"{scope}_conv"):
+        q, k, v = jax.lax.optimization_barrier(tuple(
+            prepared(x, t, segments, unit) for x, t, unit in zip((q, k, v), taps, (True, True, False))))
+    with jax.named_scope(f"{scope}_scan"):
+        o = delta_rule_chunked(q, k, v, log_decay, beta, segments, scale=scale, chunk=chunk)
+    return o, ((q, k, v) if handed else None)
 
 
 # ---------------------------------------------------------------------------

@@ -1024,10 +1024,6 @@ def _norm(x, weight, cfg: "PatternLMConfig"):
     return weighted_rms_norm(x, weight, cfg.norm_eps)
 
 
-def _l2_norm(x, eps: float = 1e-6):
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
-
-
 def _takes_kernel(l: int, dv: int, block: int) -> bool:
     """Whether :func:`_attend` runs its Pallas kernel for rows of ``l`` tokens
     and values ``dv`` wide: on a TPU, whole blocks of whole 128s."""
@@ -1290,32 +1286,16 @@ def index_select(p, u, c_q, turn, at, segments, cfg: PatternLMConfig, sample_at=
     return keep, record
 
 
-def _handed_over(p, q, k, v, segments):
-    """What a delta-rule layer hands its recurrence, the one hand-over of both
-    mixers: q, k (at their own heads) and v ``[B, H, L, Dh]`` as the projections
-    wrote them, each under its taps ``p["conv_*"]`` [K, H * Dh] that stop at a
-    document's start (``linear_attn.short_conv``, rounded), SiLU, for q and k a
-    unit norm over the head's channels, rounded once more to the dtype they came
-    in: float32 arithmetic, and nothing float32 as large as v kept. The three
-    stand behind a barrier, so the recurrence and a probe of it read these very
-    arrays, rounded as they are (``linear_attn`` widens a tile at a time)."""
-    def prepared(a, taps, unit: bool):
-        taps = taps.reshape(taps.shape[0], a.shape[1], a.shape[3])
-        s = jax.nn.silu(_la.short_conv(a, taps, segments).astype(jnp.float32))
-        return (_l2_norm(s) if unit else s).astype(a.dtype)
-
-    return jax.lax.optimization_barrier((prepared(q, p["conv_q"], True), prepared(k, p["conv_k"], True),
-                                         prepared(v, p["conv_v"], False)))
-
-
 def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
     """The gated delta-rule layer (models.linear_attn has the equations):
     projections, a 4-tap convolution and SiLU on q, k, v, unit-norm q and k
-    (:func:`_handed_over`: q, k and v reach the recurrence in the dtype the
-    convolution wrote them), a per-channel decay (float32, of v's shape) and
+    (``linear_attn.prepared``: q, k and v reach the recurrence in the dtype the
+    projections wrote them), a per-channel decay (float32, of v's shape) and
     a per-head beta in (0, 2), the chunked recurrence, a per-head RMSNorm and
     a low-rank sigmoid gate. Everything per head is head-major
-    ``[B, H, L, D]`` from projection to projection.
+    ``[B, H, L, D]`` from projection to projection. ``linear_attn.delta_rule_layer``
+    takes the projections and the taps: on a TPU its kernel prepares its own
+    tiles and ``tfr.kda_conv`` holds no operation.
 
     Returns (y, probe). ``probe`` is None unless ``probe_head`` (an int32
     scalar) names a head: then what the chunked recurrence was given and what
@@ -1336,13 +1316,12 @@ def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
         log_decay = -jnp.exp(p["a_log"])[:, None, None] * rate
         beta = 2.0 * jax.nn.sigmoid(jnp.einsum("bld,dh->bhl", u, p["w_beta"]).astype(f32))
         gate = jax.nn.sigmoid(heads(u @ p["g_down"], p["g_up"]).astype(f32))
-    with jax.named_scope("tfr.kda_conv"):
-        q, k, v = _handed_over(p, q, k, v, segments)
-    with jax.named_scope("tfr.kda_scan"):
-        o = _la.delta_rule_chunked(q, k, v, log_decay, beta, segments,
-                                   scale=dh ** -0.5, chunk=cfg.kda_chunk)
+    o, handed = _la.delta_rule_layer(
+        q, k, v, (p["conv_q"], p["conv_k"], p["conv_v"]), log_decay, beta, segments, scale=dh ** -0.5,
+        chunk=cfg.kda_chunk, scope="tfr.kda", handed=probe_head is not None)
     probe = None
     if probe_head is not None:
+        q, k, v = handed
         probe = {name: jnp.take(a, probe_head, axis=1).astype(f32) for name, a in dict(
             q=q, k=k, v=v, log_decay=log_decay, beta=beta, o=o).items()}
     with jax.named_scope("tfr.kda_proj"):
@@ -1359,9 +1338,10 @@ def gdn_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
     head h reads key head ``h // (kda_heads / gdn_key_heads)``), and a
     full-rank output gate ``2 sigmoid(u wz)`` on the per-head RMSNorm. The
     recurrence is handed what the mechanism has: q and k at their own heads
-    and v as :func:`_handed_over` leaves them, a decay and a beta
-    ``[B, H, L]``; nothing as large as v is float32 before the recurrence's
-    own output.
+    and v as ``linear_attn.prepared`` leaves them (prepared once a KEY head,
+    on a TPU by the kernel itself: ``linear_attn.delta_rule_layer``), a decay
+    and a beta ``[B, H, L]``; nothing as large as v is float32 before the
+    recurrence's own output.
 
     Returns (y, probe) as :func:`kda_mixer` does: with ``probe_head`` (a value
     head) that head's ``v``, ``o`` [B, L, D], ``log_decay``, ``beta`` [B, L]
@@ -1381,13 +1361,12 @@ def gdn_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
         rate = jax.nn.softplus(by_head(u, p["w_a"]) + p["dt_bias"][:, None])
         log_decay = -jnp.exp(p["a_log"])[:, None] * rate
         beta = jax.nn.sigmoid(by_head(u, p["w_beta"]))
-    with jax.named_scope("tfr.gdn_conv"):
-        q, k, v = _handed_over(p, q, k, v, segments)
-    with jax.named_scope("tfr.gdn_scan"):
-        o = _la.delta_rule_chunked(q, k, v, log_decay, beta, segments,
-                                   scale=dh ** -0.5, chunk=cfg.kda_chunk)
+    o, handed = _la.delta_rule_layer(
+        q, k, v, (p["conv_q"], p["conv_k"], p["conv_v"]), log_decay, beta, segments, scale=dh ** -0.5,
+        chunk=cfg.kda_chunk, scope="tfr.gdn", handed=probe_head is not None)
     probe = None
     if probe_head is not None:
+        q, k, v = handed
         key_head = probe_head // (h // hk)
         probe = {name: jnp.take(a, at, axis=1).astype(f32) for name, (a, at) in dict(
             q=(q, key_head), k=(k, key_head), v=(v, probe_head), log_decay=(log_decay, probe_head),
@@ -1591,6 +1570,8 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     fused = _la.fused_tile((tokens.shape[0], cfg.kda_heads, cfg.max_len, cfg.kda_head_dim),
                            cfg.kda_chunk) is not None
     METRICS.gauge("kda.fused_layers", cfg.layer_pattern.count("kda") if fused else 0)
+    # and of those layers, of either decay, the ones whose kernel prepared q, k and v from the projections
+    METRICS.gauge("conv.kernel_layers", sum(map(cfg.layer_pattern.count, ("kda", "gdn"))) if fused else 0)
     if "gdn" in cfg.layer_pattern:  # the same kernel under its other decay, and how the key heads are shared
         METRICS.gauge("gdn.fused_layers", cfg.layer_pattern.count("gdn") if fused else 0)
         METRICS.gauge("gdn.key_group", cfg.kda_heads // (cfg.gdn_key_heads or cfg.kda_heads))

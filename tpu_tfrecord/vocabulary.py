@@ -209,6 +209,7 @@ GAUGES: Dict[str, str] = {
     "lm.fsdp_param_bytes": "per-device at-rest param bytes under the fsdp layout",
     "moe.dropped_fraction": "latest per-step dropped-token fraction",
     "moe.visits_max_over_mean": "held experts, latest step: the busiest over the mean (most uneven layer)",
+    "conv.kernel_layers": "pattern LM, the score program last traced: delta-rule layers, under either decay, whose kernel prepared q, k and v from the projections itself (taps, SiLU, unit norm: linear_attn.delta_rule_layer; 0 off a TPU)",
     "kda.fused_layers": "pattern LM, the score program last traced: delta-rule layers whose recurrence took the Pallas kernel (0 off a TPU)",
     "gdn.fused_layers": "pattern LM, the score program last traced: gated delta-net layers whose recurrence took the Pallas kernel, one decay a head and token (0 off a TPU)",
     "gdn.key_group": "pattern LM, the score program last traced: value heads of a gated delta-net layer that read one key head, from where it lies",
