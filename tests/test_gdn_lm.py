@@ -240,7 +240,7 @@ def test_a_delta_net_layer_that_takes_the_kernel_is_the_layer_and_is_counted(mon
     plain = traced_anew()
     assert METRICS.gauge_value("gdn.fused_layers") == 0
     monkeypatch.setattr(linear_attn, "fused_tile",
-                        lambda shape, chunk: 128 if shape[-1] == 128 and chunk == 64 else None)
+                        lambda shape, chunk, key_width=0: 128 if shape[-1] == 128 and chunk == 64 else None)
     with pltpu.force_tpu_interpret_mode():
         fused = traced_anew()
     assert METRICS.gauge_value("gdn.fused_layers") == 2 and METRICS.gauge_value("gdn.key_group") == 2

@@ -355,7 +355,7 @@ def test_a_layer_that_takes_the_kernel_is_the_layer_and_is_counted(monkeypatch):
     plain = traced_anew()
     assert METRICS.gauge_value("kda.fused_layers") == 0 and METRICS.gauge_value("conv.kernel_layers") == 0
     monkeypatch.setattr(linear_attn, "fused_tile",
-                        lambda shape, chunk: 128 if shape[-1] == 128 and chunk == 64 else None)
+                        lambda shape, chunk, key_width=0: 128 if shape[-1] == 128 and chunk == 64 else None)
     with pltpu.force_tpu_interpret_mode():
         fused = traced_anew()
     # both layers' kernels prepared their own q, k and v from the projections

@@ -17,7 +17,8 @@ from tools.graftlint.harness import iter_python_files
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODELS = os.path.join(ROOT, "benchmark", "models")
-REFERENCES = ["solar_open2", "kimi_vl_lm", "deepseek_v32", "trinity_large", "gigachat35", "nemotron_h"]
+REFERENCES = ["solar_open2", "kimi_vl_lm", "deepseek_v32", "trinity_large", "gigachat35", "nemotron_h",
+              "olmo_hybrid"]
 SIBLINGS = "benchmark.models."
 
 

@@ -232,6 +232,36 @@ class TestOneChip:
             assert mem.argument_size_in_bytes == operands
             assert mem.temp_size_in_bytes < 4 * b * h * l * d // 8
 
+    def test_the_delta_rule_kernel_at_keys_of_96_under_values_of_192(self, one_chip):
+        """``olmo_hybrid_7b_pp4.score``'s delta-net layer: q's and k's projections
+        [2, 30, 8192, 96] and v's [2, 30, 8192, 192], bfloat16 as they were
+        written, their float32 taps, a decay and a beta [2, 30, 8192]. The chip's
+        compiler takes the kernel at widths that fill no whole lane block (a tile
+        of q and k padded to 128 lanes and of v to 256 as it is read into VMEM, a
+        state of [128, 256] in scratch), and memory holds the PUBLISHED widths
+        alone: the arguments are the operands as they are, the output is
+        [.., 192] float32, with the prepared q, k and v handed back three more
+        outputs at 96, 96 and 192, and nothing padded is written between."""
+        from tpu_tfrecord.models import linear_attn
+
+        b, h, l, dk, dv = 2, 30, 8192, 96, 192
+        keys = jax.ShapeDtypeStruct((b, h, l, dk), jnp.bfloat16, sharding=one_chip)
+        values = jax.ShapeDtypeStruct((b, h, l, dv), jnp.bfloat16, sharding=one_chip)
+        taps = tuple(jax.ShapeDtypeStruct((4, h * w), jnp.float32, sharding=one_chip) for w in (dk, dk, dv))
+        by_token = jax.ShapeDtypeStruct((b, h, l), jnp.float32, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((b, l), jnp.int32, sharding=one_chip)
+        operands = 2 * b * h * l * (2 * dk + dv) + 2 * 4 * b * h * l + 4 * b * l + 4 * 4 * h * (2 * dk + dv)
+        for handed, more in ((False, 0), (True, 2 * b * h * l * (2 * dk + dv))):
+            compiled = jax.jit(lambda q, k, v, t, g, bt, s: linear_attn._delta_rule_fused(
+                q, k, v, g, bt, s, dk ** -0.5, linear_attn._TILES[0], taps=t, handed=handed)).lower(
+                    keys, keys, values, taps, by_token, by_token, segs).compile()
+            hlo = compiled.as_text()
+            assert "tpu_custom_call" in hlo and f"f32[{b},{h},{l},{dv}]" in hlo
+            assert not re.search(rf"\[{b},{h},{l},(128|256)\]", hlo)       # no padded copy of q, k, v or o
+            mem = compiled.memory_analysis()
+            assert 0 <= mem.output_size_in_bytes - (4 * b * h * l * dv + more) < 1024      # a tuple's table
+            assert 0 <= mem.argument_size_in_bytes - operands < 4096      # the taps' 2,880 columns in tiles of 128
+
     def test_the_solar_patterns_score_holds_no_loop_under_the_scan(self, one_chip, monkeypatch):
         """``lm.score`` for the softmax / delta-rule period at the cell's row
         shape and delta-rule widths (the rest narrow: this is about one
@@ -316,6 +346,38 @@ class TestOneChip:
         else:
             assert written("scan", "bf16", key_heads) == 2 and written("scan", "bf16", 64) == 1
         assert sum("custom_call_target=\"tpu_custom_call\"" in line for line in entry) == 1
+
+    def test_a_delta_net_layer_of_96_under_192_holds_one_float32_array_of_its_values_size(self, one_chip, monkeypatch):
+        """``olmo_hybrid_7b_pp4.score``'s delta-net layer alone at the cell's shape,
+        compiled for the chip as a TPU runs it: ONE custom call, no operation under
+        the conv scope, the projections written once in bfloat16 at their published
+        widths where the kernel reads them, and of float32 arrays of v's size the
+        kernel's output ALONE: the gate's projection stays bfloat16 until the gate's
+        own fusion reads it (left to itself the compiler widens it inside the
+        projection and copies 0.38 GB of float32 into the output's layout)."""
+        from tpu_tfrecord.models import linear_attn
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = lm.PatternLMConfig(
+            vocab_size=256, d_model=3840, layer_pattern=("gdn",), ffn_pattern=("dense",), kda_heads=30,
+            kda_head_dim=96, gdn_value_dim=192, gdn_neg_eigval=True, gdn_gate="silu", branch_norms=True,
+            pre_norms=False, conv_taps=4, max_len=8192, kda_chunk=64, dtype=jnp.bfloat16)
+        assert linear_attn.fused_tile((2, 30, 8192, 192), cfg.kda_chunk, 96) == linear_attn._TILES[0]
+        layer = lm.pattern_param_shapes(cfg)["layers"][0]
+        assert "attn_norm" not in layer and "ffn_norm" not in layer
+        p = {name: jax.ShapeDtypeStruct(*sd, sharding=one_chip) for name, sd in layer.items()
+             if name not in ("dense", "post_ffn_norm")}
+        x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+        entry = _entry_lines(jax.jit(lambda p, x, s: lm.gdn_mixer(p, x, s, cfg)[0]).lower(p, x, segs).compile().as_text())
+        assert sum("custom_call_target=\"tpu_custom_call\"" in line for line in entry) == 1
+        assert not any(re.search(r'op_name="[^"]*tfr\.gdn_conv', line) for line in entry)
+        results = [re.match(r"\s*(?:ROOT )?\S+ = (.*?) ([a-z][\w\-]*)\(", re.sub(r"\{[^{}]*\}", "", line)) for line in entry]
+        written = [m.group(1) for m in results if m and m.group(2) not in ("get-tuple-element", "tuple", "bitcast", "parameter")]
+        values = re.compile(r"\[(2,30,8192,192|30,192,2,8192|2,8192,30,192|2,8192,5760)\]")
+        assert sum(bool(values.search(r)) for r in written if r.startswith("f32")) == 1, written
+        assert sum(r.count("bf16[2,30,8192,192]") for r in written) == 2           # v's projection and the gate's
+        assert sum(r.count("bf16[2,30,8192,96]") for r in written) == 2            # q's and k's
 
     def test_the_state_space_kernel_at_the_cells_shape(self, one_chip):
         """``nemotron_twotower_ep2.score``'s state-space layer: the convolution's

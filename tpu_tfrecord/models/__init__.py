@@ -7,12 +7,15 @@ in the benchmark's cells (``BENCHMARK.json``), the rest in tests and examples:
 - ``dlrm``: a Criteo-style DLRM (batch on 'data', embedding tables and
   hidden layers on 'model', padded sequence features on 'seq'), with
   ``interaction`` its dot-interaction: the two Criteo cells.
-- the pattern model, the six token cells: ``lm.PatternLMConfig`` holds the
-  layer pattern as data (``gqa | swa | mla | kda | gdn | ssm`` mixers, the
-  sixth a state-space layer; dense or expert feed-forward parts by layer;
-  a layer may be ONE branch, a mixer or a feed-forward part alone, the
-  other pattern saying ``"none"``) and ``lm.score`` scores packed
-  documents. Its pieces: ``attention.flash_attention_widths`` (the one
+- the pattern model, the seven token cells (``solar_open2_ep8``,
+  ``kimi_vl_a3b_lm``, ``deepseek_v32_exp_ep16``, ``trinity_large_ep8``,
+  ``gigachat35_ep16``, ``nemotron_twotower_ep2``, ``olmo_hybrid_7b_pp4``):
+  ``lm.PatternLMConfig`` holds the layer pattern as data (``gqa | swa | mla |
+  kda | gdn | ssm`` mixers, the sixth a state-space layer; a ``gdn`` head's
+  keys and values of their own widths; dense or expert feed-forward parts by
+  layer, or no expert anywhere; norms before a branch, on it, or both; a
+  layer may be ONE branch, a mixer or a feed-forward part alone, the other
+  pattern saying ``"none"``) and ``lm.score`` scores packed documents. Its pieces: ``attention.flash_attention_widths`` (the one
   softmax kernel, on a TPU) and ``attention.blockwise_attention``
   (elsewhere); ``linear_attn`` (the delta rule and the state-space
   recurrence in chunks, a kernel each on a TPU); ``sparse_attn`` (YaRN's

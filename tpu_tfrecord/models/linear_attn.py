@@ -2,7 +2,8 @@
 chunked recurrences, all reset at every ``segment_ids`` boundary.
 
 Three recurrences, two families. The gated DELTA RULE erases before it writes
-(``S_t = (I - b k k^T) diag(a) S + b k v^T``, a square state) and comes with
+(``S_t = (I - b k k^T) diag(a) S + b k v^T``, a state [d_k, d_v] that is
+square where keys and values are as wide and need not be) and comes with
 its decay in two forms: one number a CHANNEL of the key (Solar's ``kda``
 layers: ``log_decay`` [B, H, L, D], nothing read grouped), or one number a
 head and token (GigaChat's ``gdn`` layers: ``log_decay`` [B, H, L], laid out
@@ -28,7 +29,8 @@ numbers them 1..k (0 = pad). A document packed mid-row must come out as
 it would alone at the start of a row, so neither the convolution's taps
 nor the recurrent state may reach across a boundary.
 
-Per head (d_k = d_v = head_dim), for the tokens of ONE document::
+Per head (keys and queries ``d_k`` wide, values and outputs ``d_v`` wide: the
+two may differ, S is [d_k, d_v]), for the tokens of ONE document::
 
     S_t = (I - b_t k_t k_t^T) diag(a_t) S_{t-1} + b_t k_t v_t^T     S_0 = 0
     o_t = S_t^T q_t * scale
@@ -78,8 +80,11 @@ are float32, and their matrix products run at ``HIGHEST`` precision (on a
 TPU a float32 product otherwise rounds its inputs to bfloat16, which is
 the state kept in bfloat16 by another name).
 
-Two forms of it. On a TPU, for heads of whole 128s and rows of whole pairs
-of chunks of 64, one Pallas kernel (``_delta_rule_fused``): a grid step
+Two forms of it. On a TPU, for rows of whole pairs of chunks of 64 and the
+widths :func:`fused_tile` names (heads of whole 128s, or keys up to 128 under
+values up to 256 that fill more than half their lane blocks, as 96 under 192
+do: a tile is padded with zero lanes as it is read into VMEM and memory holds
+the published widths alone), one Pallas kernel (``_delta_rule_fused``): a grid step
 takes two heads' tile of a row from q, k, v, log_decay, beta and segments
 to o with every intermediate in VMEM and the state in a scratch carried
 from tile to tile. A layer hands that kernel its PROJECTIONS and their taps
@@ -154,11 +159,12 @@ def prepared(x, taps, segments, unit: bool):
 
 
 def delta_rule_recurrent(q, k, v, log_decay, beta, segments, scale):
-    """The recurrence token by token. v [B, H, L, D], q, k [B, Hk, L, D]
-    (``Hk`` divides ``H``), log_decay [B, H, L, D] or, one a head and token,
-    [B, H, L], beta [B, H, L], segments [B, L] -> o [B, H, L, D] float32. The
-    state is zeroed wherever ``segments`` changes. The oracle: it writes out
-    both broadcasts (module docstring) that the chunked form does without."""
+    """The recurrence token by token. v [B, H, L, Dv], q, k [B, Hk, L, Dk]
+    (``Hk`` divides ``H``; ``Dk`` and ``Dv`` may differ: the state is
+    [Dk, Dv]), log_decay [B, H, L, Dk] or, one a head and token, [B, H, L],
+    beta [B, H, L], segments [B, L] -> o [B, H, L, Dv] float32. The state is
+    zeroed wherever ``segments`` changes. The oracle: it writes out both
+    broadcasts (module docstring) that the chunked form does without."""
     b, h, l, d = v.shape
     f32 = jnp.float32
     if q.shape[1] != h:
@@ -177,7 +183,7 @@ def delta_rule_recurrent(q, k, v, log_decay, beta, segments, scale):
         return state, jnp.einsum("bhkv,bhk->bhv", state, q_t, precision=_HIGHEST) * scale
 
     xs = tuple(jnp.moveaxis(a.astype(f32), 2, 0) for a in (q, k, v, log_decay, beta))
-    _, o = jax.lax.scan(step, jnp.zeros((b, h, d, d), f32), xs + (starts.T,))
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, q.shape[-1], d), f32), xs + (starts.T,))
     return jnp.moveaxis(o, 0, 2)
 
 
@@ -247,9 +253,10 @@ def _running_sum(g):
 
 
 def _chunked_heads(q, k, v, g, beta, seg, scale):
-    """The chunked form for some heads of ONE row: v [H, n, C, D], q, k
-    [Hk, n, C, D] (a key head's value heads lie together), g (log-decay)
-    [H, n, C, D] or [H, n, C, 1], beta [H, n, C], seg [n, C] -> o [H, n, C, D]."""
+    """The chunked form for some heads of ONE row: v [H, n, C, Dv], q, k
+    [Hk, n, C, Dk] (a key head's value heads lie together), g (log-decay)
+    [H, n, C, Dk] or [H, n, C, 1], beta [H, n, C], seg [n, C] -> o [H, n, C, Dv];
+    the state between chunks is [H, Dk, Dv]."""
     h, n, chunk, d = v.shape
     if q.shape[0] != h:  # these heads' keys, once a value head: what this form lays out anyway
         q, k = (jnp.repeat(a, h // a.shape[0], axis=0) for a in (q, k))
@@ -286,7 +293,7 @@ def _chunked_heads(q, k, v, g, beta, seg, scale):
             "hck,hcv->hkv", at(keep, i), u, precision=_HIGHEST)
         return state, jax.lax.dynamic_update_index_in_dim(out, o, i, axis=1)
 
-    return jax.lax.fori_loop(0, n, step, (jnp.zeros((h, d, d), q.dtype), jnp.zeros_like(tv)))[1]
+    return jax.lax.fori_loop(0, n, step, (jnp.zeros((h, q.shape[-1], d), q.dtype), jnp.zeros_like(tv)))[1]
 
 
 # ---------------------------------------------------------------------------
@@ -300,16 +307,53 @@ _STRIP = 128          # rows of a tile the kernel prepares at a time (a trace's 
 _HALO = 8             # rows kept of the tile before, for the taps that reach back: one float32 tile of rows
 
 
-def fused_tile(shape, chunk: int):
-    """Tokens a grid step of the kernel holds for q of ``shape`` [B, H, L, D]
-    cut in ``chunk``s, or None where the plain form runs: off a TPU (the
-    kernel exists for no other backend), at a head width that is not whole
-    lanes of 128, at another chunk than the kernel's, and for rows that are
-    not whole pairs of chunks (those fall back, they are not padded)."""
+def _whole_lanes(width: int) -> int:
+    """``width`` channels as the kernel lays them out: whole lane blocks of 128."""
+    return -(-width // 128) * 128
+
+
+def fused_tile(shape, chunk: int, key_width: int = 0):
+    """Tokens a grid step of the kernel holds for v of ``shape`` [B, H, L, Dv]
+    under keys ``key_width`` wide (0: as wide as v) cut in ``chunk``s, or None
+    where the plain form runs: off a TPU (the kernel exists for no other
+    backend), at another chunk than the kernel's, for rows that are not whole
+    pairs of chunks (those fall back, they are not padded), and at widths the
+    kernel does not lay out. It takes keys and values of DIFFERENT widths, and
+    widths that fill no whole lane block: a head's channels a multiple of 8
+    that fill more than half of the lane blocks they are laid out in (16 would
+    compute eight times its width: the plain form's), the keys at most one
+    lane block (or, as they always could be, as wide as the values in whole
+    blocks of 128), the values at most two. What is narrower than its lane blocks is padded with
+    zero lanes INSIDE VMEM, as a tile is read (``_lanes``): zero channels add
+    nothing to a product over the channels or to a unit norm, a state's rows
+    and columns that no channel writes stay zero, and memory holds the
+    published widths alone. ``lane_fill`` says what the padding wastes."""
     l, d = shape[2:]
-    if jax.default_backend() != "tpu" or chunk != _KERNEL_CHUNK or d % 128:
+    dk = key_width or d
+    if jax.default_backend() != "tpu" or chunk != _KERNEL_CHUNK:
+        return None
+    square = dk == d and d % 128 == 0           # the widths the kernel always took
+    if not square and any(w % 8 or w > most or 2 * w <= _whole_lanes(w) for w, most in ((dk, 128), (d, 256))):
         return None
     return next((t for t in _TILES if l % t == 0), None)
+
+
+def lane_fill(key_width: int, value_width: int) -> float:
+    """Published channels over the lanes the kernel's tiles of q, k and v
+    occupy in VMEM: 1.0 where every width is whole lane blocks, (96 + 96 +
+    192) / (128 + 128 + 256) = 0.75 for keys of 96 under values of 192."""
+    return (2 * key_width + value_width) / (2 * _whole_lanes(key_width) + _whole_lanes(value_width))
+
+
+def _lanes(x, width: int):
+    """x [..., w] at ``width`` lanes: as it is where it has them, its first
+    ``width`` where it is wider, zero lanes behind it where it is narrower."""
+    w = x.shape[-1]
+    if w == width:
+        return x
+    if w > width:
+        return x[..., :width]
+    return jnp.concatenate([x, jnp.zeros(x.shape[:-1] + (width - w,), x.dtype)], axis=-1)
 
 
 def _dot(a, b, contract=((1,), (0,))):
@@ -469,7 +513,7 @@ def _chunks_in_order(laid, state, o_ref, head: int):
     as :func:`_pair_of_chunks` is: two heads' chains go in turn."""
     half = _KERNEL_CHUNK
     for i, (qs, tk, tv, p, keep_t, decay) in enumerate(laid):
-        u = jnp.zeros(qs.shape, jnp.float32)
+        u = jnp.zeros(tv.shape, jnp.float32)
         for c in range(2):
             own = slice(c * half, (c + 1) * half)
             seen = _dot(jnp.concatenate([qs[own], tk[own]]), state)                   # [128, D_v]
@@ -479,7 +523,8 @@ def _chunks_in_order(laid, state, o_ref, head: int):
             yield
             # p is zero between the chunks: the other chunk's rows of u count for nothing
             u = jnp.concatenate([u_c, u[half:]]) if c == 0 else jnp.concatenate([u[:half], u_c])
-            o_ref[0, head, i * _PAIR + c * half: i * _PAIR + (c + 1) * half] = seen[:half] + _dot(p[own], u)
+            o_ref[0, head, i * _PAIR + c * half: i * _PAIR + (c + 1) * half] = _lanes(
+                seen[:half] + _dot(p[own], u), o_ref.shape[-1])
             yield
     return state
 
@@ -543,9 +588,14 @@ def _prepared_tile(seg_col_ref, sources, halo_ref, seg_halo_ref):
         same = [_roll(ids, j)[_HALO:] == seg for j in range(1, reach + 1)]
         seg_halo_ref[...] = seg[-_HALO:]
         for slot, (x_ref, taps, out_ref, unit, i) in enumerate(heads):
-            x = x_ref[0, i, rows].astype(f32)
-            out_ref[0, i, rows] = _prepared_strip(x, halo_ref[slot], taps, same, unit=unit, dtype=out_ref.dtype)
-            halo_ref[slot] = x[-_HALO:]
+            # a head's rows at the lanes its place in VMEM has (zero lanes behind a width that fills no whole
+            # block), and as many lanes of the halo, which is as wide as the widest head
+            wide = out_ref.shape[-1]
+            own = (slot,) if wide == halo_ref.shape[-1] else (slot, slice(None), slice(wide))
+            x = _lanes(x_ref[0, i, rows].astype(f32), wide)
+            out_ref[0, i, rows] = _prepared_strip(x, halo_ref[own], taps, [_lanes(s, wide) for s in same], unit=unit,
+                                                  dtype=out_ref.dtype)
+            halo_ref[own] = x[-_HALO:]
 
     return strip, seg_col_ref.shape[1] // _STRIP
 
@@ -557,25 +607,27 @@ def _tile_of_heads(seg_col_ref, seg_row_ref, q_ref, k_ref, v_ref, g_ref, beta_re
     scratch carries from tile to tile. ``q_ref`` and ``k_ref`` hold the key
     heads these value heads read (as many, or one for all); ``g_ref`` lies as
     ``v_ref`` does (a decay a channel) or as ``beta_ref`` (one a token)."""
-    heads, tile, d = v_ref.shape[1:]
+    heads, tile = v_ref.shape[1:3]
+    d, dv = _whole_lanes(q_ref.shape[-1]), _whole_lanes(v_ref.shape[-1])   # the lanes keys and values are laid out in
     shared = heads // q_ref.shape[1]          # value heads of this step that read one key head
     scalar = len(g_ref.shape) == 5
     f32, half = jnp.float32, _KERNEL_CHUNK
     pairs, last = [], before_ref[:1]
     for at in range(0, tile, _PAIR):
         rows = slice(at, at + _PAIR)
-        seg_col = seg_col_ref[0, rows]
+        seg_col = _lanes(seg_col_ref[0, rows], d)
         before = jnp.concatenate([jnp.broadcast_to(last, (half, d)),
                                   jnp.broadcast_to(seg_col[half - 1:half], (half, d))])
         qk = {}
         if scalar:  # [q; k] k^T once a key head: a pair's decay multiplies it afterwards
             for kh in range(q_ref.shape[1]):
-                q, k = q_ref[0, kh, rows].astype(f32), k_ref[0, kh, rows].astype(f32)
+                q, k = _lanes(q_ref[0, kh, rows].astype(f32), d), _lanes(k_ref[0, kh, rows].astype(f32), d)
                 qk[kh] = _dot(jnp.concatenate([q, k]), k, ((1,), (1,)))
         # q, k and v float32 from here on, whatever they lie as in memory
         pairs += [_pair_of_chunks(
-            q_ref[0, h // shared, rows].astype(f32), k_ref[0, h // shared, rows].astype(f32),
-            v_ref[0, h, rows].astype(f32), g_ref[0, h, 0, :, rows] if scalar else g_ref[0, h, rows],
+            _lanes(q_ref[0, h // shared, rows].astype(f32), d), _lanes(k_ref[0, h // shared, rows].astype(f32), d),
+            _lanes(v_ref[0, h, rows].astype(f32), dv),
+            g_ref[0, h, 0, :, rows] if scalar else _lanes(g_ref[0, h, rows], d),
             beta_ref[0, h, 0, :, rows], seg_col, seg_row_ref[0, :1, rows], before, scale,
             qk.get(h // shared))
             for h in range(heads)]
@@ -647,7 +699,7 @@ def _delta_rule_kernel_from_projections(seg_next_ref, seg_col_ref, seg_row_ref, 
         @pl.when(step < last_step)
         def _hand_back():
             for out_ref, ref in zip(handed_refs, into):
-                out_ref[...] = ref[...]
+                out_ref[...] = _lanes(ref[...], out_ref.shape[-1])
 
 
 def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, interpret=False, taps=None,
@@ -670,6 +722,9 @@ def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, inte
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, l, d = v.shape
+    dk = q.shape[-1]           # keys may be narrower or wider than values: the state is [dk, d]
+    dk_laid, d_laid = _whole_lanes(dk), _whole_lanes(d)    # the lanes a tile occupies in VMEM (``_lanes``)
+    wide = max(dk_laid, d_laid)
     f32 = jnp.float32
     heads = 2 - h % 2          # two chains in turn keep the matrix unit busier than one
     segments = segments.astype(jnp.int32)
@@ -684,12 +739,12 @@ def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, inte
     group = h // q.shape[1]    # value heads a key head
     key_heads = max(1, heads // group)
 
-    def per_head(at):
-        return pl.BlockSpec((1, heads, tile, d), lambda bi, hi, ti: (bi, hi, at(ti), 0))
+    def per_head(at, width=d):
+        return pl.BlockSpec((1, heads, tile, width), lambda bi, hi, ti: (bi, hi, at(ti), 0))
 
     def per_key_head(at):
-        return per_head(at) if group == 1 else pl.BlockSpec(
-            (1, key_heads, tile, d), lambda bi, hi, ti: (bi, hi * heads // (group * key_heads), at(ti), 0))
+        return per_head(at, dk) if group == 1 else pl.BlockSpec(
+            (1, key_heads, tile, dk), lambda bi, hi, ti: (bi, hi * heads // (group * key_heads), at(ti), 0))
 
     per_token = pl.BlockSpec((1, heads, 1, 1, tile), lambda bi, hi, ti: (bi, hi, now(ti), 0, 0))
     scalar = log_decay.ndim == 3
@@ -699,28 +754,31 @@ def _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile: int, inte
 
     projections = [per_key_head(ahead), per_key_head(ahead), per_head(ahead)]
     in_specs = [
-        pl.BlockSpec((1, tile, d), lambda bi, hi, ti: (bi, now(ti), 0)),
+        pl.BlockSpec((1, tile, wide), lambda bi, hi, ti: (bi, now(ti), 0)),
         pl.BlockSpec((1, 8, tile), lambda bi, hi, ti: (bi, 0, now(ti))),
-        *projections, per_token if scalar else per_head(now), per_token,
+        *projections, per_token if scalar else per_head(now, dk), per_token,
     ]
-    operands = [jnp.broadcast_to(segments[:, :, None], (b, l, d)), jnp.broadcast_to(segments[:, None, :], (b, 8, l)),
+    operands = [jnp.broadcast_to(segments[:, :, None], (b, l, wide)), jnp.broadcast_to(segments[:, None, :], (b, 8, l)),
                 q, k, v, by_token(log_decay) if scalar else log_decay.astype(f32), by_token(beta)]
     out_specs, out_shape = per_head(now), jax.ShapeDtypeStruct((b, h, l, d), f32)
-    scratch = [pltpu.VMEM((heads, d, d), f32), pltpu.VMEM((8, d), jnp.int32)]
+    scratch = [pltpu.VMEM((heads, dk_laid, d_laid), f32), pltpu.VMEM((8, dk_laid), jnp.int32)]
     kernel = functools.partial(_delta_rule_kernel, scale=scale)
     if lag:
         kernel = functools.partial(_delta_rule_kernel_from_projections, scale=scale, handed=handed)
         # the segment ids of the tile that is prepared, beside those of the tile the recurrence works on
-        in_specs.insert(0, pl.BlockSpec((1, tile, d), lambda bi, hi, ti: (bi, ahead(ti), 0)))
+        in_specs.insert(0, pl.BlockSpec((1, tile, wide), lambda bi, hi, ti: (bi, ahead(ti), 0)))
         operands.insert(0, operands[0])
         for x, t, spec in zip((q, k, v), taps, projections):
-            # a head's taps together, [heads, K, D]: a block of some heads' has whole last two sizes
-            operands.append(jnp.swapaxes(t.reshape(t.shape[0], x.shape[1], d), 0, 1))
-            in_specs.append(pl.BlockSpec((spec.block_shape[1], t.shape[0], d),
+            # a head's taps together, [heads, K, D] (D the lanes its tiles have in VMEM: the taps of the
+            # zero lanes are zero): a block of some heads' has whole last two sizes
+            laid = _whole_lanes(x.shape[-1])
+            operands.append(_lanes(jnp.swapaxes(t.reshape(t.shape[0], x.shape[1], x.shape[-1]), 0, 1), laid))
+            in_specs.append(pl.BlockSpec((spec.block_shape[1], t.shape[0], laid),
                                          lambda bi, hi, ti, at=spec.index_map: (at(bi, hi, ti)[1], 0, 0)))
         # the rows before a strip, a head-tile each, and their ids; two places for what is prepared
-        scratch += [pltpu.VMEM((2 * key_heads + heads, _HALO, d), f32), pltpu.VMEM((_HALO, d), jnp.int32)]
-        scratch += [pltpu.VMEM((2, *spec.block_shape[1:]), x.dtype) for spec, x in zip(projections, (q, k, v))]
+        scratch += [pltpu.VMEM((2 * key_heads + heads, _HALO, wide), f32), pltpu.VMEM((_HALO, wide), jnp.int32)]
+        scratch += [pltpu.VMEM((2, *spec.block_shape[1:3], _whole_lanes(x.shape[-1])), x.dtype)
+                    for spec, x in zip(projections, (q, k, v))]
         if handed:
             out_specs = [out_specs, *projections]
             out_shape = [out_shape] + [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)]
@@ -783,7 +841,7 @@ def delta_rule_chunked(q, k, v, log_decay, beta, segments, scale, chunk: int = 6
     hold the interpreted kernel to."""
     if chunk & (chunk - 1):
         raise ValueError(f"chunk must be a power of two, got {chunk}")
-    tile = fused_tile(v.shape, chunk)
+    tile = fused_tile(v.shape, chunk, q.shape[-1])
     if tile is not None:
         return _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile)
     return _delta_rule_plain(q, k, v, log_decay, beta, segments, scale, chunk)
@@ -810,7 +868,7 @@ def delta_rule_layer(q, k, v, taps, log_decay, beta, segments, scale, chunk: int
     :func:`prepared` on whole arrays under ``<scope>_conv``, the three behind a
     barrier (the recurrence and the caller read those very arrays), then
     :func:`delta_rule_chunked` under ``<scope>_scan``."""
-    tile = fused_tile(v.shape, chunk)
+    tile = fused_tile(v.shape, chunk, q.shape[-1])
     if tile is not None:
         with jax.named_scope(f"{scope}_scan"):
             out = _delta_rule_fused(q, k, v, log_decay, beta, segments, scale, tile, taps=taps, handed=handed)

@@ -799,13 +799,17 @@ class PatternLMConfig:
     n_kv_heads: int = 2
     head_dim: int = 16
     window: int = 0                # sliding-window layer ("swa"): the keys a query sees, its own among them
-    qk_norm: bool = False          # softmax and sliding-window layers: an RMSNorm over each head of q and of k
+    qk_norm: bool = False          # softmax and sliding-window layers: an RMSNorm over each head of q and of k,
+    qk_norm_whole: bool = False    # ... or over the WHOLE projection before it is cut into heads (a weight its width)
     gqa_gate: bool = True          # ... and a sigmoid gate from the layer's input on the attention's output
     kda_heads: int = 4             # recurrent layers ("kda", "gdn", "ssm"): heads of kda_head_dim channels
-    kda_head_dim: int = 16         # ... (a delta-rule head's d_k = d_v; a state-space head's P)
+    kda_head_dim: int = 16         # ... (a delta-rule head's d_k, and its d_v but for "gdn" below; a state-space head's P)
     conv_taps: int = 4
     gate_rank: int = 8             # rank of the "kda" layer's decay and output gates
-    gdn_key_heads: int = 0         # "gdn" layer: key heads, a divisor of its kda_heads value heads (0: as many)
+    gdn_key_heads: int = 0         # "gdn" layer: key heads, a divisor of its kda_heads value heads (0: as many),
+    gdn_value_dim: int = 0         # ... a value head's d_v where it is not kda_head_dim (0: it is): a state [d_k, d_v],
+    gdn_neg_eigval: bool = False   # ... beta in (0, 2), a negative eigenvalue of the transition allowed, where it is (0, 1),
+    gdn_gate: str = "sigmoid2"     # ... and the gate on a head's normed output: "sigmoid2" (2 sigmoid(z)) or "silu"
     ssm_state: int = 16            # "ssm" layer: a head's state is kda_head_dim x ssm_state,
     ssm_groups: int = 1            # ... and its kda_heads read B and C in so many groups (head h reads h // (H / G))
     qk_nope_dim: int = 16          # latent-attention layer: a head's query/key width without positions,
@@ -836,6 +840,7 @@ class PatternLMConfig:
     centred_norms: bool = False    # a norm's gain is 2 sigmoid(w), 1 at w = 0, where it is w
     swiglu_limit: float = 0.0      # every gated unit clipped at it before it multiplies (moe.gated_ffn; 0: not)
     branch_norms: bool = False     # sandwich: x + norm(branch(norm(x))), the mixer's and the feed-forward's
+    pre_norms: bool = True         # False: no norm on a branch's way IN (with branch_norms: x + norm(branch(x)))
     embed_scale: bool = False      # the embedding's rows times sqrt(d_model)
     max_len: int = 64              # L: a row is L + 1 tokens
     dtype: Any = jnp.bfloat16
@@ -901,12 +906,14 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
     }
     mixers["swa"] = mixers["gqa"]  # a softmax layer under a window, with rotary positions
     kk = (cfg.gdn_key_heads or cfg.kda_heads) * cfg.kda_head_dim
-    mixers["gdn"] = {  # "kda" with full-rank gates, one decay a head and token, fewer key heads
-        "attn_norm": ((d,), f32), "wq": ((d, kk), dt), "wk": ((d, kk), dt), "wv": ((d, kd), dt),
-        "wz": ((d, kd), dt), "conv_q": ((cfg.conv_taps, kk), f32), "conv_k": ((cfg.conv_taps, kk), f32),
-        "conv_v": ((cfg.conv_taps, kd), f32), "w_a": ((d, cfg.kda_heads), dt),
+    dv = cfg.gdn_value_dim or cfg.kda_head_dim
+    vd = cfg.kda_heads * dv
+    mixers["gdn"] = {  # "kda" with full-rank gates, one decay a head and token, fewer key heads, values of their own width
+        "attn_norm": ((d,), f32), "wq": ((d, kk), dt), "wk": ((d, kk), dt), "wv": ((d, vd), dt),
+        "wz": ((d, vd), dt), "conv_q": ((cfg.conv_taps, kk), f32), "conv_k": ((cfg.conv_taps, kk), f32),
+        "conv_v": ((cfg.conv_taps, vd), f32), "w_a": ((d, cfg.kda_heads), dt),
         "dt_bias": ((cfg.kda_heads,), f32), "a_log": ((cfg.kda_heads,), f32),
-        "w_beta": ((d, cfg.kda_heads), dt), "o_norm": ((cfg.kda_head_dim,), f32), "wo": ((kd, d), dt),
+        "w_beta": ((d, cfg.kda_heads), dt), "o_norm": ((dv,), f32), "wo": ((vd, d), dt),
     }
     inner, bc = kd, cfg.ssm_groups * cfg.ssm_state
     mixers["ssm"] = {  # one projection in, [z | x B C | dt]; one convolution over x, B and C; one out
@@ -925,6 +932,10 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
         raise ValueError(f"ssm_groups {cfg.ssm_groups} has to divide the {cfg.kda_heads} heads")
     if cfg.expert_unit not in ("gated", "relu2"):
         raise ValueError(f"expert_unit {cfg.expert_unit!r}: 'gated' or 'relu2'")
+    if cfg.gdn_gate not in ("sigmoid2", "silu"):
+        raise ValueError(f"gdn_gate {cfg.gdn_gate!r}: 'sigmoid2' or 'silu'")
+    if cfg.qk_norm and cfg.qk_norm_whole:
+        raise ValueError("qk_norm (a head) or qk_norm_whole (the projection): one of the two")
     if "swa" in cfg.layer_pattern and cfg.window < 1:
         raise ValueError("a sliding-window layer needs cfg.window: the keys a query sees")
     if cfg.kda_heads % (cfg.gdn_key_heads or 1):
@@ -936,9 +947,15 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
         moe["shared"].pop("w_gate")
     if cfg.qk_norm:  # one weight for all heads
         mixers["gqa"].update({"q_norm": ((cfg.head_dim,), f32), "k_norm": ((cfg.head_dim,), f32)})
+    if cfg.qk_norm_whole:  # a weight a channel of the projection
+        mixers["gqa"].update({"q_norm": ((hq,), f32), "k_norm": ((hkv,), f32)})
     if cfg.branch_norms:
         for mixer in mixers.values():
             mixer["post_attn_norm"] = ((d,), f32)
+    if not cfg.pre_norms:  # a branch reads the stream as it is
+        for part in (*mixers.values(), *ffns.values()):
+            for name in ("attn_norm", "ffn_norm", "moe_norm"):
+                part.pop(name, None)
     if cfg.q_rank:  # the query through a normed latent, as the keys and values go
         wq = mixers["mla"].pop("wq")[0]
         mixers["mla"].update({"wq_a": ((d, cfg.q_rank), dt), "q_norm": ((cfg.q_rank,), f32),
@@ -1022,6 +1039,23 @@ def _norm(x, weight, cfg: "PatternLMConfig"):
     if cfg.centred_norms:
         weight = 2.0 * jax.nn.sigmoid(weight)
     return weighted_rms_norm(x, weight, cfg.norm_eps)
+
+
+def _pre_norm(x, p, name: str, cfg: "PatternLMConfig"):
+    """What a branch reads: the stream under the branch's own norm ``p[name]``,
+    or without ``cfg.pre_norms`` the stream as it is (the layer holds no such weight)."""
+    return _norm(x, p[name], cfg) if cfg.pre_norms else x
+
+
+def _norm_whole(x, weight, cfg: "PatternLMConfig"):
+    """:func:`_norm` over a WHOLE projection that lies head-major: x [B, H, L, Dh],
+    ``weight`` [H * Dh] in the projection's column order; the mean square runs
+    over a token's H * Dh channels."""
+    if cfg.centred_norms:
+        weight = 2.0 * jax.nn.sigmoid(weight)
+    x32 = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=(1, 3), keepdims=True) + cfg.norm_eps)
+    return (x32 * scale * weight.reshape(x.shape[1], 1, x.shape[3])).astype(x.dtype)
 
 
 def _takes_kernel(l: int, dv: int, block: int) -> bool:
@@ -1113,13 +1147,16 @@ def gqa_mixer_probed(p, x, segments, cfg: PatternLMConfig, sliding: bool = False
     probed = sample_at is not None and probe_head is not None
     around, call = ("tfr.swa_proj", "tfr.swa_attn") if sliding else ("tfr.gqa", "tfr.gqa")
     with jax.named_scope(around):
-        u = _norm(x, p["attn_norm"], cfg)
+        u = _pre_norm(x, p, "attn_norm", cfg)
         q = jnp.einsum("bld,dhk->bhlk", u, p["wq"].reshape(d, h, dh))
         k = jnp.einsum("bld,dhk->bhlk", u, p["wk"].reshape(d, hkv, dh))
         v = jnp.einsum("bld,dhk->bhlk", u, p["wv"].reshape(d, hkv, dh))
         if cfg.qk_norm:
             q = _norm(q, p["q_norm"], cfg)
             k = _norm(k, p["k_norm"], cfg)
+        if cfg.qk_norm_whole:
+            q = _norm_whole(q, p["q_norm"], cfg)
+            k = _norm_whole(k, p["k_norm"], cfg)
         at = segment_positions(segments) if sliding or probed else None
         if sliding:
             q, k = rotary(q, at, cfg.rope_theta), rotary(k, at, cfg.rope_theta)
@@ -1212,7 +1249,7 @@ def mla_mixer_probed(p, x, segments, cfg: PatternLMConfig, sample_at=None):
         return rotary(a, at, cfg.rope_theta, *yarn)
 
     with jax.named_scope("tfr.mla_proj"):
-        u = _norm(x, p["attn_norm"], cfg)
+        u = _pre_norm(x, p, "attn_norm", cfg)
         if cfg.q_rank:
             c_q = _norm(u @ p["wq_a"], p["q_norm"], cfg)
             q_in, wq = c_q, p["wq_b"].reshape(cfg.q_rank, h, dn + dr)
@@ -1309,7 +1346,7 @@ def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
         return jnp.einsum("blm,mhk->bhlk", a, w.reshape(w.shape[0], h, dh))
 
     with jax.named_scope("tfr.kda_proj"):
-        u = _norm(x, p["attn_norm"], cfg)
+        u = _pre_norm(x, p, "attn_norm", cfg)
         q, k, v = heads(u, p["wq"]), heads(u, p["wk"]), heads(u, p["wv"])
         rate = jax.nn.softplus(
             heads(u @ p["f_down"], p["f_up"]).astype(f32) + p["f_bias"].reshape(h, 1, dh))
@@ -1333,34 +1370,41 @@ def kda_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
 def gdn_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
     """The gated delta-net layer: :func:`kda_mixer`'s convolution, unit norms
     and recurrence with ONE decay a head and token,
-    ``exp(-exp(a_log) softplus(u w_a + dt_bias))``, a beta in (0, 1),
-    ``cfg.gdn_key_heads`` key heads under ``cfg.kda_heads`` value heads (value
-    head h reads key head ``h // (kda_heads / gdn_key_heads)``), and a
-    full-rank output gate ``2 sigmoid(u wz)`` on the per-head RMSNorm. The
-    recurrence is handed what the mechanism has: q and k at their own heads
-    and v as ``linear_attn.prepared`` leaves them (prepared once a KEY head,
-    on a TPU by the kernel itself: ``linear_attn.delta_rule_layer``), a decay
-    and a beta ``[B, H, L]``; nothing as large as v is float32 before the
+    ``exp(-exp(a_log) softplus(u w_a + dt_bias))``, a beta in (0, 1) or, with
+    ``cfg.gdn_neg_eigval``, ``2 sigmoid(.)`` in (0, 2) (a negative eigenvalue
+    of the transition allowed), ``cfg.gdn_key_heads`` key heads under
+    ``cfg.kda_heads`` value heads (value head h reads key head ``h //
+    (kda_heads / gdn_key_heads)``), keys ``cfg.kda_head_dim`` wide under values
+    ``cfg.gdn_value_dim`` wide (0: as wide; else the state is [d_k, d_v] and
+    not square; scores are scaled by ``d_k ** -0.5``), and a full-rank output
+    gate on the per-head RMSNorm over a head's d_v: ``2 sigmoid(u wz)`` or,
+    with ``cfg.gdn_gate == "silu"``, ``silu(u wz)``. The recurrence is handed
+    what the mechanism has: q and k at their own heads and widths and v as
+    ``linear_attn.prepared`` leaves them (prepared once a KEY head, on a TPU
+    by the kernel itself: ``linear_attn.delta_rule_layer``), a decay and a
+    beta ``[B, H, L]``; nothing as large as v is float32 before the
     recurrence's own output.
 
     Returns (y, probe) as :func:`kda_mixer` does: with ``probe_head`` (a value
-    head) that head's ``v``, ``o`` [B, L, D], ``log_decay``, ``beta`` [B, L]
-    and its key head's ``q``, ``k`` [B, L, D], float32 for that head alone."""
+    head) that head's ``v``, ``o`` [B, L, Dv], ``log_decay``, ``beta`` [B, L]
+    and its key head's ``q``, ``k`` [B, L, Dk], float32 for that head alone."""
     d, h, dh, f32 = x.shape[-1], cfg.kda_heads, cfg.kda_head_dim, jnp.float32
-    hk = cfg.gdn_key_heads or h
+    hk, dv = cfg.gdn_key_heads or h, cfg.gdn_value_dim or cfg.kda_head_dim
 
-    def heads(a, w, n):  # a [B, L, m] through w [m, n * dh] -> [B, n, L, dh]
-        return jnp.einsum("blm,mhk->bhlk", a, w.reshape(w.shape[0], n, dh))
+    def heads(a, w, n, width=dh):  # a [B, L, m] through w [m, n * width] -> [B, n, L, width]
+        return jnp.einsum("blm,mhk->bhlk", a, w.reshape(w.shape[0], n, width))
 
     def by_head(a, w):  # a [B, L, m] through w [m, H] -> [B, H, L] float32
         return jnp.einsum("bld,dh->bhl", a, w).astype(f32)
 
     with jax.named_scope("tfr.gdn_proj"):
-        u = _norm(x, p["attn_norm"], cfg)
-        q, k, v = heads(u, p["wq"], hk), heads(u, p["wk"], hk), heads(u, p["wv"], h)
+        u = _pre_norm(x, p, "attn_norm", cfg)
+        q, k, v = heads(u, p["wq"], hk), heads(u, p["wk"], hk), heads(u, p["wv"], h, dv)
         rate = jax.nn.softplus(by_head(u, p["w_a"]) + p["dt_bias"][:, None])
         log_decay = -jnp.exp(p["a_log"])[:, None] * rate
         beta = jax.nn.sigmoid(by_head(u, p["w_beta"]))
+        if cfg.gdn_neg_eigval:
+            beta = 2.0 * beta
     o, handed = _la.delta_rule_layer(
         q, k, v, (p["conv_q"], p["conv_k"], p["conv_v"]), log_decay, beta, segments, scale=dh ** -0.5,
         chunk=cfg.kda_chunk, scope="tfr.gdn", handed=probe_head is not None)
@@ -1372,10 +1416,19 @@ def gdn_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
             q=(q, key_head), k=(k, key_head), v=(v, probe_head), log_decay=(log_decay, probe_head),
             beta=(beta, probe_head), o=(o, probe_head)).items()}
     with jax.named_scope("tfr.gdn_proj"):
-        gate = 2.0 * jax.nn.sigmoid(heads(u, p["wz"], h).astype(f32))
+        z = heads(u, p["wz"], h, dv)
+        if dv % 128 and jax.default_backend() == "tpu":
+            # ONE array in the dtype the projection writes. Left alone at a width that fills no whole
+            # lane block, the TPU compiler widens inside the projection, writes float32 with the tokens
+            # along the lanes and copies it into the layout the recurrence's output has: 0.6 GB more
+            # written a layer and 0.5 GB more held (the layer compiled for a described v5e; at whole
+            # 128s it writes bfloat16 where the gate reads it, and the program is the one it was)
+            z = jax.lax.optimization_barrier(z)
+        z = z.astype(f32)
+        gate = jax.nn.silu(z) if cfg.gdn_gate == "silu" else 2.0 * jax.nn.sigmoid(z)
         o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
         o = (o * p["o_norm"] * gate).astype(x.dtype)
-        return jnp.einsum("bhlk,hkd->bld", o, p["wo"].reshape(h, dh, d)), probe
+        return jnp.einsum("bhlk,hkd->bld", o, p["wo"].reshape(h, dv, d)), probe
 
 
 def ssm_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
@@ -1397,7 +1450,7 @@ def ssm_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
     h, ph, n, g, f32 = cfg.kda_heads, cfg.kda_head_dim, cfg.ssm_state, cfg.ssm_groups, jnp.float32
     inner, bc = h * ph, g * n
     with jax.named_scope("tfr.ssm_proj"):
-        u = _norm(x, p["attn_norm"], cfg)
+        u = _pre_norm(x, p, "attn_norm", cfg)
         z = u @ p["w_in"][:, :inner]
         xbc = u @ p["w_in"][:, inner:2 * inner + 2 * bc]
         dt = jax.nn.softplus(jnp.dot(u, p["w_in"][:, 2 * inner + 2 * bc:], preferred_element_type=f32)
@@ -1504,13 +1557,13 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
             continue
         if ffn == "dense":
             with jax.named_scope("tfr.dense_ffn"):
-                u = _norm(x, layer["ffn_norm"], cfg)
+                u = _pre_norm(x, layer, "ffn_norm", cfg)
                 w = layer["dense"]
                 y = _moe.gated_ffn(u, w["w_gate"], w["w_up"], w["w_down"], limit).astype(x.dtype)
                 x = _joined(x, y, layer.get("post_ffn_norm"), cfg, "tfr.dense_ffn")
             continue
         with jax.named_scope("tfr.moe_route"):
-            u = _norm(x, layer["moe_norm"], cfg)
+            u = _pre_norm(x, layer, "moe_norm", cfg)
             if cfg.n_group > 1 or cfg.branch_norms or kind == NONE:
                 # ONE array for the router and for the probe of it. Left alone, the compiler
                 # computes the norm once for each reader, the two fusions round a few
@@ -1565,21 +1618,29 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
                                                 probe_head)
     if not set(_RECURRENT) & set(cfg.layer_pattern):
         probes.setdefault("scan", {})
-    # every delta-rule layer of a program has one shape, so one answer of the
+    # the delta-rule layers of a kind have one shape, so one answer a kind of the
     # function that decides the dispatch (as the program is traced, not as it runs)
-    fused = _la.fused_tile((tokens.shape[0], cfg.kda_heads, cfg.max_len, cfg.kda_head_dim),
-                           cfg.kda_chunk) is not None
-    METRICS.gauge("kda.fused_layers", cfg.layer_pattern.count("kda") if fused else 0)
+    dk, dv = cfg.kda_head_dim, cfg.gdn_value_dim or cfg.kda_head_dim
+
+    def fused(width: int) -> bool:  # values ``width`` wide under keys of ``dk``
+        return _la.fused_tile((tokens.shape[0], cfg.kda_heads, cfg.max_len, width), cfg.kda_chunk, dk) is not None
+
+    kda_fused, gdn_fused = (cfg.layer_pattern.count(kind) if fused(width) else 0
+                            for kind, width in (("kda", dk), ("gdn", dv)))
+    METRICS.gauge("kda.fused_layers", kda_fused)
     # and of those layers, of either decay, the ones whose kernel prepared q, k and v from the projections
-    METRICS.gauge("conv.kernel_layers", sum(map(cfg.layer_pattern.count, ("kda", "gdn"))) if fused else 0)
+    METRICS.gauge("conv.kernel_layers", kda_fused + gdn_fused)
     if "gdn" in cfg.layer_pattern:  # the same kernel under its other decay, and how the key heads are shared
-        METRICS.gauge("gdn.fused_layers", cfg.layer_pattern.count("gdn") if fused else 0)
+        METRICS.gauge("gdn.fused_layers", gdn_fused)
         METRICS.gauge("gdn.key_group", cfg.kda_heads // (cfg.gdn_key_heads or cfg.kda_heads))
+        # the state a head keeps in the kernel, and what of the lanes its tiles of q, k and v occupy is published
+        METRICS.gauge("gdn.state_shape", dk * dv if gdn_fused else 0)
+        METRICS.gauge("gdn.lane_fill", round(_la.lane_fill(dk, dv), 6) if gdn_fused else 0.0)
     if "ssm" in cfg.layer_pattern:  # the state-space layers' own kernel, and the heads that share a B and a C
         width = cfg.kda_heads * cfg.kda_head_dim + 2 * cfg.ssm_groups * cfg.ssm_state
-        fused = _la.ssm_tile((tokens.shape[0], cfg.max_len, width), cfg.dtype, cfg.kda_heads, cfg.ssm_groups,
-                             cfg.ssm_state, cfg.kda_chunk) is not None
-        METRICS.gauge("ssm.fused_layers", cfg.layer_pattern.count("ssm") if fused else 0)
+        in_kernel = _la.ssm_tile((tokens.shape[0], cfg.max_len, width), cfg.dtype, cfg.kda_heads, cfg.ssm_groups,
+                                 cfg.ssm_state, cfg.kda_chunk) is not None
+        METRICS.gauge("ssm.fused_layers", cfg.layer_pattern.count("ssm") if in_kernel else 0)
         METRICS.gauge("ssm.group", cfg.kda_heads // cfg.ssm_groups)
     # likewise the selection: one shape for every layer that has an indexer
     in_kernel = cfg.index_topk and _sa.select_tile(

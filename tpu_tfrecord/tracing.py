@@ -78,13 +78,15 @@ ANNOTATIONS = {
     "tfr.kda_proj": "pattern LM: the delta-rule layer's projections, decay, beta, gates, norm, out",
     "tfr.kda_conv": "pattern LM: the short convolution, SiLU and unit norm of q, k, v",
     "tfr.kda_scan": "pattern LM: the chunked gated delta rule (models.linear_attn)",
-    "tfr.gdn_proj": "pattern LM: the gated delta-net layer's norm, six projections (q, k, v, the "
-                    "output gate z, the decay's and beta's one a head), decay, beta, head norm, "
-                    "gate, out, branch norm",
+    "tfr.gdn_proj": "pattern LM: the gated delta-net layer's norm on its way in (where the pattern has "
+                    "one), six projections (q, k, v, the output gate z, the decay's and beta's one a "
+                    "head), decay, beta in (0, 1) or (0, 2), head norm, gate (2 sigmoid(z) or silu(z)), "
+                    "out, branch norm",
     "tfr.gdn_conv": "pattern LM: the gated delta-net layer's short convolution, SiLU and unit norm "
-                    "of q and k at their own heads and of v",
+                    "of q and k at their own heads and of v (empty on a TPU: the kernel prepares them)",
     "tfr.gdn_scan": "pattern LM: the gated delta-net layer's recurrence call alone (models.linear_attn "
-                    "under one decay a head and token, key heads shared by their value heads)",
+                    "under one decay a head and token, key heads shared by their value heads, a state "
+                    "[d_k, d_v] that need not be square)",
     "tfr.ssm_proj": "pattern LM: the state-space layer's norm, the three products of its one projection in "
                     "(gate, [x | B | C], step), the step's softplus and the decay, the skip, the gate "
                     "before the grouped norm, out",

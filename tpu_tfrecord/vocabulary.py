@@ -213,6 +213,8 @@ GAUGES: Dict[str, str] = {
     "kda.fused_layers": "pattern LM, the score program last traced: delta-rule layers whose recurrence took the Pallas kernel (0 off a TPU)",
     "gdn.fused_layers": "pattern LM, the score program last traced: gated delta-net layers whose recurrence took the Pallas kernel, one decay a head and token (0 off a TPU)",
     "gdn.key_group": "pattern LM, the score program last traced: value heads of a gated delta-net layer that read one key head, from where it lies",
+    "gdn.state_shape": "pattern LM, the score program last traced: d_k x d_v of the state a head of the gated delta-net layers keeps in the Pallas kernel (96 x 192 = 18,432; 0 where no layer took it)",
+    "gdn.lane_fill": "pattern LM, the score program last traced: published channels over the lanes the delta-rule kernel's tiles of q, k and v occupy in VMEM (linear_attn.lane_fill: 1.0 at whole 128s, 0.75 for keys of 96 under values of 192; 0 where no layer took the kernel)",
     "ssm.fused_layers": "pattern LM, the score program last traced: state-space layers whose recurrence took the Pallas kernel (0 off a TPU)",
     "ssm.group": "pattern LM, the score program last traced: heads of a state-space layer that read one group's B and C, from where they lie",
     "dsa.kernel_layers": "pattern LM, the score program last traced: latent-attention layers whose selection took the Pallas kernel (0 off a TPU and without an indexer)",
