@@ -495,10 +495,14 @@ def test_an_unknown_mixer_or_feed_forward_part_is_named_with_the_ones_there_are(
 #: and v (tests/test_tpu_compile.py reads that program): off a TPU the preparation moved
 #: from ``lm._handed_over`` to ``linear_attn.prepared`` and is traced operation for
 #: operation as it was, barrier and all, so both hashes stand as recorded.
+#: PR 50 recorded the three token programs anew for ONE equation's place: the head's blocks
+#: are joined inside ``models.head.logprob`` (which answers with the [T] array and whether
+#: the kernel ran), so the ``concatenate`` stands before the mask's ``eq``, ``ne`` and ``and``
+#: and not after them; every other equation is the parent's, in the parent's order.
 OLDER_PROGRAMS = {
-    "solar": "c2d1f66a567aa6a4e8b2558c821d20ad80395c2014c71aeba6e98e3dd190cdab",
-    "gigachat": "d36b4cb8034c876ee4bb3f4a243c8c63ef2d23a177013e5ed13b483926e3def6",
-    "kimi": "4d0e48d35253802a8d47e7bee4c761f0ef2730b67b87e565d57d91f97bede802",
+    "solar": "148634b493d8912f247cf46e580482ef5d9c5d0758c27144d3379d3bbc06999a",
+    "gigachat": "ba765537bf2281247fed76ac71522dbde8f33149f075dea52699a74941c431b0",
+    "kimi": "13fae3c8927d8e67b17f6ddab5a35499678100597973ae196bccd568a6c4739c",
     "dlrm_forward": "74937f331a59e45e91ba132bca04da279ac57e7cdc91574931627704728350cc",
     "sparse_train_step": "ea35280a10973a3d8af6c8c0a1a8b17679edde3e8d4005f6012a10f3f8360d9d",
 }

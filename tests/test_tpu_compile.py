@@ -595,6 +595,43 @@ class TestOneChip:
             assert not re.findall(r"\n\s*%?\S+ = \S+ dynamic-update-slice\(", hlo)
             assert len(re.findall(r"\n\s*ROOT %?\S+ = \S+ dynamic-update-slice\(", hlo)) == 2
 
+    @pytest.mark.parametrize("d, v", [(2048, 163840), (3072, 25024), (7168, 16160)],
+                             ids=["kimi_vl_a3b_lm", "a_ragged_vocabulary", "ragged_and_the_width_cut_in_two"])
+    def test_the_head_keeps_its_logits_on_the_chip(self, one_chip, monkeypatch, d, v):
+        """``lm.score`` at a cell's head shape (2 rows of 8,192, the rest narrow:
+        this is about one scope), steered onto the TPU's paths: under
+        ``tfr.lm_head`` the compiled program holds exactly one kernel, of float32
+        arrays with the vocabulary's columns only the sampled positions'
+        ``[2, 4, V]``, and no copy of the head: the kernel reads the weights where
+        they lie, and a vocabulary that is not whole 128s lies COLUMN-major on the
+        chip (the compiler's own choice for a parameter whose minor dimension it
+        would have to pad), which the kernel takes as ``head.T``, a bitcast; the
+        third shape is ragged AND cut in two products with a float32 accumulator.
+        The size rule (``_MIN_LOGITS``) is set aside: this is about the kernel
+        wherever it will run. That every token cell's head has a tiling,
+        tests/test_head.py holds."""
+        from tpu_tfrecord.models import head
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(head, "_MIN_LOGITS", 0)      # the kernel at every shape, also those the size rule leaves to the plain form
+        assert head.head_tile(2 * 8192, d, v, jnp.bfloat16) is not None
+        cfg = lm.PatternLMConfig(
+            vocab_size=v, d_model=d, layer_pattern=("gqa",), ffn_pattern=("dense",), n_heads=2, n_kv_heads=1,
+            head_dim=128, d_dense=256, max_len=8192, dtype=jnp.bfloat16, attn_block=1024, head_block=2048)
+        params = jax.tree.map(lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip),
+                              lm.pattern_param_shapes(cfg),
+                              is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple))
+        rows = jax.ShapeDtypeStruct((2, cfg.max_len + 1), jnp.int32, sharding=one_chip)
+        at = jax.ShapeDtypeStruct((2, 4), jnp.int32, sharding=one_chip)
+        hlo = jax.jit(lambda p, t, s, a: lm.score(p, t, s, a, cfg)).lower(params, rows, rows, at).compile().as_text()
+        scope = [line for line in _entry_lines(hlo) if re.search(r'op_name="[^"]*tfr\.lm_head', line)]
+        assert sum("custom_call_target=\"tpu_custom_call\"" in line for line in scope) == 1
+        wide = set(re.findall(rf"f32\[[\d,]*\b{v}\]", hlo))
+        assert wide == {f"f32[2,4,{v}]"}
+        layout = "{0,1" if v % 128 else "{1,0"        # as the chip's compiler lays the parameter
+        assert re.search(rf"bf16\[{d},{v}\]{re.escape(layout)}[^}}]*}} parameter\(", hlo)
+        assert not re.search(rf"bf16\[({d},{v}|{v},{d})\]\S* (copy|transpose|fusion)\(", hlo)
+
     def test_lm_train_step_on_dp(self, topo):
         """examples/train_lm.py's widths on a one-device ``data`` mesh."""
         mesh = Mesh(np.array(topo.devices[:1]), ("data",))

@@ -21,7 +21,9 @@ in the benchmark's cells (``BENCHMARK.json``), the rest in tests and examples:
   recurrence in chunks, a kernel each on a TPU); ``sparse_attn`` (YaRN's
   blend, the indexer's exact top-k); ``moe.route_top_k`` and
   ``moe.held_experts_apply`` (a chip's share of the experts, their unit of
-  three matrices or of two). Its plain float32 references are the benchmark's, one an
+  three matrices or of two); ``head`` (log p(next token): on a TPU one kernel
+  that keeps the float32 logits in VMEM a tile at a time, blocks of them
+  elsewhere). Its plain float32 references are the benchmark's, one an
   architecture (``benchmark/models/<name>.py``); the package holds none.
 - in no cell: ``long_doc`` (a long-document classifier, ring or Ulysses
   attention over 'seq'), ``moe.moe_apply`` / ``moe_apply_ep`` (Switch-style

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpu_tfrecord.models import head as _head
 from tpu_tfrecord.models import linear_attn as _la
 from tpu_tfrecord.models import moe as _moe
 from tpu_tfrecord.models import pipeline as _pipeline
@@ -848,7 +849,7 @@ class PatternLMConfig:
     attn_block: int = 1024         # query and key block of the softmax and latent-attention layers
     kda_chunk: int = 64            # tokens a step of the chunked recurrence (delta rule, state space)
     expert_tile: int = 256         # visits a tile of the expert loop
-    head_block: int = 2048         # tokens a block of the head's logits
+    head_block: int = 2048         # tokens a block of the head's logits in the plain form (models.head: not the kernel's tiles)
 
 
 def ffn_kinds(cfg: PatternLMConfig) -> Tuple[str, ...]:
@@ -1611,7 +1612,12 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     ``selected`` [layers, 2] int32, only where layers have an indexer: the keys
         kept by and the candidates of the real queries (:func:`record_selected`)
 
-    The head's logits exist a block of ``cfg.head_block`` tokens at a time."""
+    The head's float32 logits never exist whole: on a TPU they exist a VMEM
+    tile at a time, inside ``models.head``'s kernel (the product, the running
+    log-sum-exp and the target's pick; gauge ``head.fused`` 1), elsewhere and
+    at shapes ``head.head_tile`` declines a block of ``cfg.head_block`` tokens
+    at a time (the plain form; ``head.fused`` 0). The sampled positions' are an
+    einsum over a handful of rows."""
     from tpu_tfrecord.metrics import METRICS
 
     x, visits, dropped, probes = pattern_hidden(params, tokens, segment_ids, cfg, sample_at,
@@ -1667,15 +1673,11 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     with jax.named_scope("tfr.lm_head"):
         xn = _norm(x, params["final_norm"], cfg)
         flat, targets = xn.reshape(b * l, d), tokens[:, 1:].reshape(b * l)
-        out = []
-        for t0 in range(0, b * l, cfg.head_block):
-            logits = jnp.dot(flat[t0:t0 + cfg.head_block], params["head"],
-                             preferred_element_type=jnp.float32)
-            picked = jnp.take_along_axis(
-                logits, targets[t0:t0 + cfg.head_block, None], axis=-1)[:, 0]
-            out.append(picked - jax.nn.logsumexp(logits, axis=-1))
+        out, fused = _head.logprob(flat, params["head"], targets, cfg.head_block)
+        # 1 where the logits stay in VMEM a tile at a time, 0 where the plain form's blocks run
+        METRICS.gauge("head.fused", int(fused))
         scored = (segment_ids[:, 1:] == segment_ids[:, :-1]) & (segment_ids[:, :-1] != 0)
-        logprob = jnp.where(scored, jnp.concatenate(out).reshape(b, l), 0.0)
+        logprob = jnp.where(scored, out.reshape(b, l), 0.0)
         sampled = jnp.take_along_axis(xn, sample_at[:, :, None], axis=1)
         sample_logits = jnp.einsum("bsd,dv->bsv", sampled, params["head"],
                                    preferred_element_type=jnp.float32)

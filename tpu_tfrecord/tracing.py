@@ -107,7 +107,9 @@ ANNOTATIONS = {
     "tfr.moe_route": "held experts: pre-norm, scores over all experts, top-k, visits sorted by expert",
     "tfr.moe_experts": "held experts: the loop over the tiles of visits to the experts held here",
     "tfr.moe_shared": "held experts: the shared expert, every token",
-    "tfr.lm_head": "pattern LM: final norm, the head's logits by blocks, log-probabilities",
+    "tfr.lm_head": "pattern LM: final norm, the head's log-probabilities (models.head: on a TPU one "
+                   "kernel whose float32 logits stay in VMEM a tile at a time, else blocks of them), "
+                   "the sampled positions' logits",
 }
 
 

@@ -186,6 +186,7 @@ STAGES: Dict[str, str] = {
     "kernel.trace.ssm_scan": "the state-space Pallas kernel built while a program is traced (linear_attn._ssm_fused: once a program, its layers of one shape share the jitted call)",
     "kernel.trace.dsa_index": "the selection Pallas kernel built while a program is traced (sparse_attn._select_fused)",
     "kernel.trace.interaction": "the dot-interaction Pallas kernel built while a program is traced (interaction.dot_interaction_pallas)",
+    "kernel.trace.lm_head": "the scoring head's Pallas kernel built while a program is traced (head._head_fused: the product, the running log-sum-exp and the target's pick a VMEM tile at a time)",
 }
 
 #: Instantaneous gauges (``Metrics.gauge``): last write wins.
@@ -222,6 +223,7 @@ GAUGES: Dict[str, str] = {
     "dsa.selected_share": "pattern LM, latest step recorded: keys the indexers kept over the causal candidates they chose from (lm.record_selected)",
     "mla.plain_pair_share": "pattern LM, latest step recorded: of the block pairs the latent-attention kernel computes, those wholly under the diagonal of one document, where every key is seen (lm.record_pair_kinds)",
     "gqa.kernel_layers": "pattern LM, the score program last traced: full softmax layers whose attention took the Pallas kernel, grouped K/V heads read as the projections wrote them, never copied to the query heads (0 off a TPU)",
+    "head.fused": "pattern LM, the score program last traced: 1 where the head's float32 logits stay in VMEM a tile at a time (the Pallas kernel of models.head), 0 where the plain form writes them a block of head_block tokens at a time (off a TPU, and at shapes head.head_tile declines)",
     "moe.tail_unit": "pattern LM, the score program last traced: rows of a tail tile of the expert loop, the unit an expert's visits are rounded up to (0 where the tile is the unit and one loop of whole tiles runs)",
     "moe.tile_fill": "held experts, latest step recorded: real visits over the rows the expert loops compute, each expert's visits rounded up to whole units (emptiest layer; lm.record_moe_counters)",
     "swa.kernel_layers": "pattern LM, the score program last traced: sliding-window layers whose attention took the Pallas kernel under a window (0 off a TPU)",
@@ -280,6 +282,7 @@ SPANS: Dict[str, str] = {
     "kernel.trace.ssm_scan": "one build of the state-space kernel",
     "kernel.trace.dsa_index": "one build of the selection kernel",
     "kernel.trace.interaction": "one build of the dot-interaction kernel",
+    "kernel.trace.lm_head": "one build of the scoring head's kernel",
 }
 
 #: Prefixes under which names are formed at runtime and cannot be
