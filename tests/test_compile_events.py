@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from jax._src import compilation_cache as jax_cache
 
-from tpu_tfrecord import compile_cache, telemetry, vocabulary
+from tpu_tfrecord import compile_cache, telemetry, tracing, vocabulary
 from tpu_tfrecord.metrics import METRICS
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "examples"))
@@ -57,6 +57,7 @@ def log(tmp_path, monkeypatch):
     monitoring.unregister_event_duration_listener(compile_cache._on_duration)
     monitoring.unregister_event_listener(compile_cache._on_event)
     compile_cache._LOG = None
+    tracing.unwatch_host()  # enable() started it
     for name, value in before.items():
         jax.config.update(name, value)
     jax_cache.reset_cache()

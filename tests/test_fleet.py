@@ -1131,7 +1131,7 @@ class TestFleetIntegration:
             if e.get("ph") == "M" and e["name"] == "process_name"
         }
         assert pids <= named  # one named track per pid
-        assert any(e["name"] == "decode" for e in doc["traceEvents"])
+        assert any(e["name"] == "tfr:decode" for e in doc["traceEvents"])
 
     def test_killed_worker_flagged_stale(self, sandbox, tmp_path):
         """SIGKILL a demonstrably-alive worker: the aggregator flags it

@@ -453,14 +453,16 @@ class TestEndToEnd:
         assert rows == 150
         spans = telemetry.RECORDER.spans()
         names = {s[0] for s in spans}
-        assert {"open", "decode", "batch"} <= names
+        # open and decode are the feed's own spans (tracing.trace writes
+        # the ring and the profiler's timeline in one call since PR 51)
+        assert {"tfr:open", "tfr:decode", "batch"} <= names
         decode_shards = {
-            (s[4] or {}).get("shard") for s in spans if s[0] == "decode"
+            (s[4] or {}).get("shard") for s in spans if s[0] == "tfr:decode"
         }
         assert len(decode_shards) == 3  # every shard attributed
         # and the export is valid trace-event JSON containing decode spans
         doc = json.loads(json.dumps(telemetry.RECORDER.to_chrome_trace()))
-        assert any(e["name"] == "decode" for e in doc["traceEvents"])
+        assert any(e["name"] == "tfr:decode" for e in doc["traceEvents"])
 
     def test_trace_off_records_nothing(self, sandbox):
         from tpu_tfrecord.io.dataset import TFRecordDataset
@@ -472,7 +474,10 @@ class TestEndToEnd:
         with ds.batches() as it:
             for _ in it:
                 pass
-        assert len(telemetry.RECORDER) == 0
+        # nothing but the host log, which is always on (tracing.HOST_SPANS)
+        from tpu_tfrecord.tracing import HOST_SPANS
+
+        assert {s[0] for s in telemetry.RECORDER.spans()} <= HOST_SPANS
         # but gauges and histograms (always-on, batch-granularity) flowed
         assert METRICS.gauge_value("prefetch.queue_depth") is not None
         assert "decode" in METRICS.quantiles()
@@ -659,7 +664,7 @@ class TestDoctorReport:
         assert report["straggler_p99_p50"] >= 1.0
         assert report["slowest_shard"]
         doc = json.load(open(trace_out))
-        assert any(e["name"] == "decode" for e in doc["traceEvents"])
+        assert any(e["name"] == "tfr:decode" for e in doc["traceEvents"])
 
     def test_report_unreadable_dataset_exits_2(self, sandbox):
         proc = subprocess.run(

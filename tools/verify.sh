@@ -173,7 +173,7 @@ pulse.stop()  # final tick guarantees at least one line
 telemetry.disable()
 assert rows == 120, rows
 trace = json.loads(json.dumps(telemetry.RECORDER.to_chrome_trace()))
-decode = [e for e in trace["traceEvents"] if e["name"] == "decode"]
+decode = [e for e in trace["traceEvents"] if e["name"] == "tfr:decode"]
 assert decode, "no decode spans in exported trace"
 assert all("ts" in e and "dur" in e for e in decode), decode[0]
 line = json.loads(json.dumps(pulses[-1]))

@@ -24,7 +24,9 @@ recorder is on, goes to ``telemetry.record_span`` under the stage's name.
 Pallas kernel while a program is traced (stages ``kernel.trace.<kernel>``,
 phase ``kernel_trace``). :func:`events` and :func:`summary` read the log:
 what a slow start was spent on, program by program, and which program a
-window recompiled.
+window recompiled. :func:`enable` also starts the host watch
+(``tracing.watch_host``): what the host's pauses and the collector took of
+the same start is in the host log, on the same clock.
 """
 
 from __future__ import annotations
@@ -35,7 +37,7 @@ import time
 from collections import deque
 from typing import Dict, Iterable, List, NamedTuple, Optional
 
-from tpu_tfrecord import telemetry
+from tpu_tfrecord import telemetry, tracing
 from tpu_tfrecord.metrics import METRICS, timed
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
@@ -207,10 +209,13 @@ def enable() -> str:
     (JAX's own config reads the variable); otherwise
     ``jax_compilation_cache_dir`` becomes :data:`DEFAULT_DIR`. Either way
     the cache key includes the programs' metadata. The log's listeners are
-    registered once, however often this is called."""
+    registered once, however often this is called; so is the host watch
+    (``tracing.watch_host``: the witness for the host's pauses and the
+    collector's clock, in the host log from before the first compile)."""
     global _LOG
     import jax
 
+    tracing.watch_host()
     if _LOG is None:
         _LOG = _Log(LOG_CAPACITY)
         jax.monitoring.register_event_time_span_listener(_on_span)

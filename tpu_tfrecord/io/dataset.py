@@ -578,14 +578,14 @@ class TFRecordDataset:
             start, n = entry.chunk_span(i)
             if n == 0 or start + n <= skip:
                 continue
-            with timed("cache.serve", METRICS) as t, trace("tfr:cache"), \
-                    telemetry.span("cache.serve", shard=shard_path) as sp:
+            with timed("cache.serve", METRICS) as t, \
+                    trace("tfr:cache", shard=shard_path) as tr:
                 chunk = entry.chunk_batch(i, dtype_of)
                 if skip > start:
                     chunk = slice_batch(chunk, skip - start, chunk.num_rows)
                     start = skip
                 t.records += chunk.num_rows
-                sp.set(rows=chunk.num_rows)
+                tr.set_metadata(rows=chunk.num_rows)
             yield chunk, epoch, pos, start
 
     def _decode_shard_inner(
@@ -648,14 +648,13 @@ class TFRecordDataset:
                 continue
             for start in range(max(0, next_index[0] - base), n, chunk_records):
                 stop = min(start + chunk_records, n)
-                with timed("decode", METRICS) as t, trace("tfr:decode") as tr, \
-                        telemetry.span("decode", shard=shard_path) as sp:
+                with timed("decode", METRICS) as t, \
+                        trace("tfr:decode", shard=shard_path) as tr:
                     chunk = self._decode_chunk(
                         buf, offsets[start:stop], lengths[start:stop]
                     )
                     t.records += chunk.num_rows
                     t.bytes += int(lengths[start:stop].sum())
-                    sp.set(rows=chunk.num_rows)
                     tr.set_metadata(rows=chunk.num_rows, bytes=t.bytes)
                 if self._partition_fields:
                     self._attach_partition_chunk(chunk, shard_idx)
@@ -799,8 +798,8 @@ class TFRecordDataset:
                     bpos = 0
                     while True:
                         hint(bpos)
-                        with timed("decode", METRICS) as t, trace("tfr:decode") as tr, \
-                                telemetry.span("decode", shard=shard.path) as sp:
+                        with timed("decode", METRICS) as t, \
+                                trace("tfr:decode", shard=shard.path) as tr:
                             cb, n_sk, n_done, consumed = dec.scan_decode(
                                 buf, bpos, verify, to_skip, chunk_records,
                                 length=size,
@@ -808,7 +807,6 @@ class TFRecordDataset:
                             )
                             t.records += n_done
                             t.bytes += consumed - bpos
-                            sp.set(rows=n_done)
                             tr.set_metadata(rows=n_done, bytes=consumed - bpos)
                         to_skip -= n_sk
                         abs_idx += n_sk
@@ -894,8 +892,8 @@ class TFRecordDataset:
                     buf = scratch["buf"]
                     bpos = 0
                     while True:
-                        with timed("decode", METRICS) as t, trace("tfr:decode") as tr, \
-                                telemetry.span("decode", shard=shard.path) as sp:
+                        with timed("decode", METRICS) as t, \
+                                trace("tfr:decode", shard=shard.path) as tr:
                             cb, n_sk, n_done, consumed = dec.scan_decode(
                                 buf, bpos, verify, to_skip, chunk_records,
                                 length=data_len,
@@ -903,7 +901,6 @@ class TFRecordDataset:
                             )
                             t.records += n_done
                             t.bytes += consumed - bpos
-                            sp.set(rows=n_done)
                             tr.set_metadata(rows=n_done, bytes=consumed - bpos)
                         to_skip -= n_sk
                         abs_idx += n_sk

@@ -36,10 +36,10 @@ from tpu_tfrecord.tracing import trace
 def _timed_open(open_fn, path: str, codec):
     """One owner for the shard-open instrumentation every span stream pays:
     the open's latency lands in the ``read.open`` histogram (shard opens
-    are a classic straggler source on object stores) and, when the flight
-    recorder is on, as an ``open`` span attributed to the shard."""
-    with timed("read.open", METRICS), trace("tfr:open"), \
-            telemetry.span("open", shard=path):
+    are a classic straggler source on object stores) and one ``tfr:open``
+    span attributed to the shard goes to the profiler's timeline and the
+    host log (``tracing.trace``)."""
+    with timed("read.open", METRICS), trace("tfr:open", shard=path):
         return open_fn(path, codec)
 
 

@@ -17,10 +17,12 @@ profiler. Four pieces:
   module-level ``span()``/``instant()`` return a shared no-op without
   taking any lock (one attribute read on the hot path). Exportable as
   Chrome trace-event JSON (``to_chrome_trace``/``save_chrome_trace``) —
-  loadable in Perfetto / chrome://tracing. Spans are mirrored onto the
-  jax-profiler timeline through the existing ``tracing.trace`` annotations
-  every instrumented site already holds, so xprof captures show the same
-  regions.
+  loadable in Perfetto / chrome://tracing. The same ring is the HOST LOG:
+  ``SpanRecorder.log`` is its always-recorded path, which
+  ``tracing.trace`` takes for the feed's ``tfr:*`` hand-offs and the host
+  watch for ``host:pause`` / ``host:gc`` whether or not the recorder is
+  enabled (``tracing.host_events`` reads them back; one call a site
+  writes the ring and the jax-profiler timeline).
 
 - **Latency histograms** (``Histogram``): log-bucketed (~19% geometric
   buckets → quantile relative error ≤ ~10%), folded into ``Metrics`` via
@@ -608,8 +610,8 @@ class SpanRecorder:
         name: str,
         t0_ns: int,
         dur_ns: int,
-        attrs: Optional[dict],
-        ph: str,
+        attrs: Optional[dict] = None,
+        ph: str = "X",
         tid: Optional[int] = None,
     ) -> None:
         # ``tid`` override: per-request spans (serving) record onto a
@@ -624,6 +626,13 @@ class SpanRecorder:
             if seq >= self.capacity:
                 self.dropped += 1
             self._ring[seq % self.capacity] = (name, t0_ns, dur_ns, tid, attrs, ph)
+
+    #: The always-recorded path, ``log(name, t0_ns, dur_ns, attrs=None)``: one
+    #: already-measured span into the ring whether or not ``enabled`` (which
+    #: goes on governing ``span``/``instant``/``record_span``). For the names
+    #: the host log owns (``tracing.HOST_SPANS``), one a batch or rarer. The
+    #: ring's one writer under its public name: no call in between.
+    log = _record
 
     # -- reading -------------------------------------------------------------
 
@@ -728,11 +737,20 @@ class SpanRecorder:
         )
 
 
+#: Records the process's ring keeps; older ones are overwritten and counted
+#: (``dropped``). The host log (``SpanRecorder.log``) is always on, so the
+#: ring has to hold a whole run of the densest benchmark cell twice over:
+#: ``criteo_mlperf.score`` wrote 34,006 records from process start to the end
+#: of its run (a 20 s window of 185 batches a second at 9 records a batch, the
+#: ingest check's epoch before it; my chip run, PR 51), a token cell 4,100-4,400.
+RING_CAPACITY = 131072
+
 #: Process-global flight recorder — spans come from dataset iterators,
 #: prefetch workers, writer pipeline threads, and the stall guard, so the
 #: ring is shared (one timeline). ``TFRecordOptions(trace="on")`` enables it
-#: at dataset/writer construction; it stays on until ``disable()``.
-RECORDER = SpanRecorder()
+#: at dataset/writer construction; it stays on until ``disable()``. The host
+#: log's records land in it regardless (``SpanRecorder.log``).
+RECORDER = SpanRecorder(capacity=RING_CAPACITY)
 
 
 def span(name: str, **attrs):

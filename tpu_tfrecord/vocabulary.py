@@ -187,6 +187,8 @@ STAGES: Dict[str, str] = {
     "kernel.trace.dsa_index": "the selection Pallas kernel built while a program is traced (sparse_attn._select_fused)",
     "kernel.trace.interaction": "the dot-interaction Pallas kernel built while a program is traced (interaction.dot_interaction_pallas)",
     "kernel.trace.lm_head": "the scoring head's Pallas kernel built while a program is traced (head._head_fused: the product, the running log-sum-exp and the target's pick a VMEM tile at a time)",
+    "host.gc": "collections of the cyclic collector since tracing.watch_host(): every one counted, with its seconds (folded in once a second)",
+    "host.pause": "wakes of the host watch more than 50 ms late: the process, or the whole guest, stood still (count, seconds, latency histogram)",
 }
 
 #: Instantaneous gauges (``Metrics.gauge``): last write wins.
@@ -235,18 +237,34 @@ GAUGES: Dict[str, str] = {
 }
 
 #: Trace span / instant names (``telemetry.span``/``instant``/
-#: ``record_span``; the flight-recorder and Perfetto vocabulary).
+#: ``record_span``; the flight-recorder and Perfetto vocabulary). The
+#: ``tfr:*`` and ``host:*`` names are the host log's: written to the same
+#: ring by ``tracing.trace`` and the host watch whether or not the flight
+#: recorder is on, and owned by ``tracing.ANNOTATIONS`` (tests/test_host_log.py
+#: holds the two tables together).
 SPANS: Dict[str, str] = {
-    "open": "one shard open",
+    "tfr:open": "one shard open (shard-attributed)",
     "read": "one guarded read region",
-    "decode": "one chunk decode (shard-attributed)",
+    "tfr:decode": "one chunk decode: frame scan + CRC + decode + hash (shard-attributed; rows, bytes)",
+    "tfr:cache": "one cached chunk serve (shard-attributed; rows)",
+    "tfr:pack": "one batch through host_batch_from_columnar or pack_mixed (rows, bytes out)",
+    "tfr:pack_tokens": "one reader batch's documents placed by TokenPacker (docs in, rows and tokens out)",
+    "tfr:h2d": "the dispatch of one batch's host-to-device copy (rows, bytes)",
+    "tfr:h2d_land": "the transfer thread's wait for that copy to land",
+    "tfr:blocked.batch": "the decode thread's put waited on a full prefetch queue",
+    "tfr:starved.batch": "the dataset's consumer found its prefetch queue empty",
+    "tfr:blocked.host": "HostPrefetcher's thread waited on its full queue",
+    "tfr:starved.host": "HostPrefetcher's consumer found its queue empty",
+    "tfr:blocked.device": "the transfer thread waited on its full queue",
+    "tfr:starved.device": "DeviceIterator's consumer found its queue empty",
+    "host:pause": "the host watch woke more than 50 ms late (late_s, cause, and what the operating system says of the interval)",
+    "host:gc": "one collection of 1 ms or more (generation, collected)",
     "batch": "one consumer batch get",
     "write.encode": "one slab encode",
     "write.compress": "one slab compression",
     "write.io": "one slab append",
     "write.commit": "one shard commit",
     "cache.open": "one cache entry open",
-    "cache.serve": "one cached chunk serve",
     "cache.commit": "one cache entry commit",
     "service.serve": "one worker shard stream",
     "train.step": "one train step (phase-decomposed)",
