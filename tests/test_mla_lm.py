@@ -499,10 +499,15 @@ def test_an_unknown_mixer_or_feed_forward_part_is_named_with_the_ones_there_are(
 #: are joined inside ``models.head.logprob`` (which answers with the [T] array and whether
 #: the kernel ran), so the ``concatenate`` stands before the mask's ``eq``, ``ne`` and ``and``
 #: and not after them; every other equation is the parent's, in the parent's order.
+#: PR 52 recorded the three token programs anew for the expert layer alone: a tile of
+#: ``moe.held_experts_apply``'s loops reads its row of a table made where the routing is and
+#: slices its visits out of the padded order, where it searched and gathered (tests/
+#: test_pattern_lm.py holds the table to the arithmetic it replaced and the layer to the
+#: reference); ``dlrm_forward``, the train step and ``SOLARS_KERNEL`` read as they did.
 OLDER_PROGRAMS = {
-    "solar": "148634b493d8912f247cf46e580482ef5d9c5d0758c27144d3379d3bbc06999a",
-    "gigachat": "ba765537bf2281247fed76ac71522dbde8f33149f075dea52699a74941c431b0",
-    "kimi": "13fae3c8927d8e67b17f6ddab5a35499678100597973ae196bccd568a6c4739c",
+    "solar": "a7fd7648656de9a78a939e9816d39b8a5253ca97e32c820609dcf1f7bc571981",
+    "gigachat": "a5bee9d1c8f65d5c29a9c498938f04967294758c4867f2c24807acab9f81bbfb",
+    "kimi": "0e7c8bc704658f1a7d5327b2cebbfad28983c2f641311f166c9898e84f268759",
     "dlrm_forward": "74937f331a59e45e91ba132bca04da279ac57e7cdc91574931627704728350cc",
     "sparse_train_step": "ea35280a10973a3d8af6c8c0a1a8b17679edde3e8d4005f6012a10f3f8360d9d",
 }

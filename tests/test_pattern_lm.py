@@ -618,6 +618,133 @@ def test_the_rows_the_two_loops_compute_are_what_the_gauge_counts(unit_of_8, mon
     assert METRICS.gauge_value("moe.tile_fill") == round(55 / rows[RUNS.index(55)], 4)
 
 
+#: visits to six held experts that sum to 96 tokens' 4 choices, so that no visit of the sorted
+#: order is an absent expert's: none; runs that end on a tile's edge under every tiling here and
+#: on a unit's edge alone (88); a ragged run; and a last run whose last tile reaches 3 places or
+#: more past the end of the order, into its pad
+TILED_VISITS = [0, 96, 96, 88, 91, 13]
+TILINGS = [32, 16, 24, 8, 12]
+FORMS = pytest.mark.parametrize("scattered", [False, True], ids=["read_back", "added_as_computed"])
+
+
+def a_walk_of_the_runs(visits, tile, scattered):
+    """Each loop's tiles as (expert, first place in the sorted order, first row of the buffer,
+    real rows), run by run in plain Python: an expert's whole tiles, then its tails."""
+    unit = moe.row_unit(tile)
+    per = tile // unit
+    loops, place, row = ([], []), 0, 0
+    for e, v in enumerate(visits):
+        units = -(-v // unit)
+        if units % per > moe.tail_units(per, scattered):
+            units += per - units % per
+        for k in range(units // per):
+            at = k * tile
+            loops[0].append((e, place + at, row + at, min(v - at, tile)))
+        for k in range(units % per):
+            at = units // per * tile + k * unit
+            loops[1].append((e, place + at, row + at, min(v - at, unit)))
+        place, row = place + v, row + units * unit
+    return list(loops[:1 if per == 1 else 2])
+
+
+@FORMS
+@pytest.mark.parametrize("tile", TILINGS)
+def test_every_row_of_the_tables_is_the_tile_a_walk_of_the_runs_finds(unit_of_8, tile, scattered):
+    """``moe.tile_tables`` against the arithmetic each tile did for itself: expert, first place,
+    buffer row and real rows of every tile there is, in the loops' order, and no more tiles
+    than the table has rows for."""
+    unit = moe.row_unit(tile)
+    rng = np.random.default_rng(tile)
+    for visits in (TILED_VISITS, [0] * 6, [384, 0, 0, 0, 0, 0], *rng.multinomial(300, [1 / 6] * 6, size=4).tolist()):
+        most = moe.buffer_units(384, 6, tile, scattered)
+        first, region, loops = moe.tile_tables(jnp.asarray(visits, jnp.int32), tile, scattered, most)
+        want = a_walk_of_the_runs(visits, tile, scattered)
+        assert [rows for rows, _, _ in loops] == ([tile, unit] if len(want) == 2 else [tile])
+        for (rows, tiles, table), tiles_of in zip(loops, want):
+            assert int(tiles) == len(tiles_of) <= table.shape[0]
+            got = np.asarray(table)[:len(tiles_of)] * [1, 1, unit, 1]
+            np.testing.assert_array_equal(got.reshape(-1, 4), np.asarray(tiles_of).reshape(-1, 4))
+        np.testing.assert_array_equal(first, np.cumsum(visits) - visits)
+        units = moe.region_units(np.asarray(visits), tile, scattered)
+        np.testing.assert_array_equal(region, np.cumsum(units) - units)
+
+
+def a_layer_that_visits(visits, n_experts, seed=3):
+    """96 tokens, ``n_experts`` experts of which numbers 5 to 10 are held, and a router under
+    which token ``i`` picks exactly the held experts whose mark its row carries: a held
+    expert's column sinks every row but the marked (rows are positive), so the held experts'
+    visits are ``visits`` and, four marks a token, no visit is an absent expert's."""
+    cfg, p, x = moe_layer(seed=seed)
+    assert sum(visits) == 4 * x.shape[0] and visits[0] == 0
+    left_out = np.repeat(np.arange(1, 6), [x.shape[0] - v for v in visits[1:]])   # a token omits one of five
+    marks = np.ones((x.shape[0], 6), np.float32)
+    marks[:, 0] = 0
+    marks[np.arange(x.shape[0]), left_out] = 0
+    np.testing.assert_array_equal(marks.sum(axis=0), visits)
+    x = jnp.abs(x).at[:, :6].set(marks)
+    router = np.random.default_rng(seed).standard_normal((x.shape[1], n_experts)).astype(np.float32)
+    router[:, 5:11] = -1.0
+    router[np.arange(6), 5 + np.arange(6)] = 60.0
+    share = {**p, "router": jnp.asarray(router), **{k: p[k][5:11] for k in ("w_gate", "w_up", "w_down")}}
+    return {**cfg, "n_routed_experts": n_experts, "n_routed_experts_held": 6, "held_offset": 5}, share, x
+
+
+@FORMS
+@pytest.mark.parametrize("tile", TILINGS)
+def test_a_layer_reads_its_tiles_from_the_table_and_no_row_past_them(unit_of_8, monkeypatch, tile, scattered):
+    """The layer under ``TILED_VISITS`` in both forms against the reference: an expert
+    without a visit, runs that end on a unit's and on a tile's edge, a last tile whose slice
+    reaches into the pad of the sorted order. The tables' rows past the tiles there are hold
+    poison here: what no tile reads cannot show."""
+    tables = moe.tile_tables
+
+    def poisoned(*args):
+        first, region, loops = tables(*args)
+        return first, region, [(rows, tiles, jnp.where(
+            jnp.arange(table.shape[0])[:, None] < tiles, table, 2 ** 30)) for rows, tiles, table in loops]
+
+    monkeypatch.setattr(moe, "tile_tables", poisoned)
+    cfg, share, x = a_layer_that_visits(TILED_VISITS, 56 if scattered else 16)
+    assert moe.adds_as_computed(6, cfg["n_routed_experts"]) == scattered
+    y, n, dropped, (experts, _) = unit_of_8(share, x, held_offset=5, top_k=4, tile=tile)
+    np.testing.assert_array_equal(n, TILED_VISITS)
+    assert int(dropped) == 0 and ((experts >= 5) & (experts < 11)).all()     # the order holds held visits alone
+    reach = max(place + rows for tiles_of, rows in zip(a_walk_of_the_runs(TILED_VISITS, tile, scattered), (tile, 8))
+                for _, place, _, _ in tiles_of)
+    assert reach >= 4 * x.shape[0] + 3                          # a slice reaches into the pad
+    with jax.default_matmul_precision("highest"):
+        want, _, _ = ref.ref_moe(flat(share), x, cfg)
+    np.testing.assert_allclose(y, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("tile, held", [(32, 2), (32, 1), (8, 2), (8, 1)],
+                         ids=["two_loops_read_back", "two_loops_added", "one_loop_read_back", "one_loop_added"])
+def test_a_loops_body_holds_no_search_and_no_gather_of_indices(unit_of_8, tile, held):
+    """What a tile does beside its products: one read of its table's row, one slice of the
+    padded order, ONE gather (its rows of x) and the write; where rows are added as computed
+    also the gates' gather and the one scatter-add. No ``while`` inside the loop (a search),
+    no ``sort``, no ``cumsum``: a tile works out nothing about where it is."""
+    cfg, share, x = one_run(55, held)
+    jaxpr = jax.make_jaxpr(lambda p, x: moe.held_experts_apply(
+        p, x, held_offset=5, top_k=4, tile=tile))(share, x)
+    loops = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "while"]
+    assert len(loops) == (2 if tile == 32 else 1)                # and none outside them: the table is no search
+    for loop, rows in zip(loops, (tile, 8)):
+        body = list(equations(loop.params["body_jaxpr"].jaxpr))
+        inside = [e.primitive.name for e in body]
+        assert not {"while", "sort", "cumsum", "argsort"} & set(inside)
+        scattered = moe.adds_as_computed(held, 16)
+        assert inside.count("gather") == (2 if scattered else 1)
+        assert inside.count("scatter-add") == (1 if scattered else 0)
+        assert inside.count("dynamic_update_slice") == (0 if scattered else 1)
+        slices = [e for e in body if e.primitive.name == "dynamic_slice"]
+        shapes = [tuple(e.outvars[0].aval.shape) for e in slices]
+        # the table's row, the tile's visits out of the padded order, and an expert's three matrices
+        assert shapes.count((1, 4)) == shapes.count((rows,)) == 1 and len(shapes) == 5
+        (visits,) = [e for e in slices if e.outvars[0].aval.shape == (rows,)]
+        assert visits.invars[0].aval.shape == (x.shape[0] * 4 + tile,)
+
+
 def test_the_moe_counters_sit_beside_pack_density():
     from tpu_tfrecord.metrics import METRICS
 

@@ -80,6 +80,18 @@ def _entry_lines(hlo: str) -> list:
     return entry[:entry.index("\n}")].splitlines()
 
 
+def _inside(hlo: str, computation: str) -> list:
+    """The instructions of ``computation`` and of every computation it calls
+    (a fusion's, a nested loop's body and condition), as lines."""
+    bodies = dict(re.findall(r"\n%?([\w.\-]+) \([^\n]*\) -> [^\n]* \{\n(.*?)\n\}", hlo, re.S))
+    lines, todo = [], [computation]
+    while todo:
+        found = bodies[todo.pop()].splitlines()
+        lines += found
+        todo += [name for line in found for name in re.findall(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", line)]
+    return lines
+
+
 class TestOneChip:
     def test_device_kind_is_one_the_smoke_knows(self, topo):
         assert topo.devices[0].platform == "tpu"
@@ -578,7 +590,11 @@ class TestOneChip:
         tiles, then tails of 256 rows), the buffer of the read-back form is the
         worst case in units (131,073 rows where whole tiles alone took 163,841)
         with its write inside the last product of either loop, and nothing else
-        of T * top_k rows or more of the model's width exists in either form."""
+        of T * top_k rows or more of the model's width exists in either form.
+        A loop's body works out nothing about where its tile is: no ``while``
+        inside it (a search), the tile's visits a ``dynamic-slice`` of the padded
+        order, and its ONE gather the tile's rows of x; where the rows are added
+        as computed also the gates' gather and the one scatter."""
         from tpu_tfrecord.models import moe
 
         shapes = {"router": ((d, n_experts), jnp.float32), "w_gate": ((held, d, f), jnp.bfloat16),
@@ -594,6 +610,16 @@ class TestOneChip:
         if laid:  # the write of a tile stays in the product that makes it, in both bodies
             assert not re.findall(r"\n\s*%?\S+ = \S+ dynamic-update-slice\(", hlo)
             assert len(re.findall(r"\n\s*ROOT %?\S+ = \S+ dynamic-update-slice\(", hlo)) == 2
+        for loop, rows in zip(loops, (1024, 256)):
+            body = _inside(hlo, re.search(r"body=%?([\w.\-]+)", loop).group(1))
+            ops = [re.match(r"\s*(?:ROOT )?%?\S+ = .*? ([a-z][\w\-]*)\(", re.sub(r"\{[^{}]*\}", "", line)) for line in body]
+            ops = [op.group(1) for op in ops if op]
+            assert "while" not in ops and "sort" not in ops
+            assert (ops.count("gather"), ops.count("scatter")) == ((1, 0) if laid else (2, 1))
+            assert [line for line in body if re.search(
+                rf"= s32\[{rows}\]\S* dynamic-slice\(%?\S+, %?\S+\), dynamic_slice_sizes=\{{{rows}\}}", line)]
+            gathered = [line for line in body if re.search(r" gather\(", line)]
+            assert sum(bool(re.search(rf"= bf16\[{rows},{d}\]", line)) for line in gathered) == 1
 
     @pytest.mark.parametrize("d, v", [(2048, 163840), (3072, 25024), (7168, 16160)],
                              ids=["kimi_vl_a3b_lm", "a_ragged_vocabulary", "ragged_and_the_width_cut_in_two"])

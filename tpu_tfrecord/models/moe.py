@@ -661,6 +661,56 @@ def region_units(visits, tile: int, scattered: bool):
     return units
 
 
+def buffer_units(slots: int, n_held: int, tile: int, scattered: bool) -> int:
+    """The units of :func:`row_unit` rows that :func:`held_experts_apply`'s loops
+    can compute at most: every one of ``slots`` visits to a held expert, and
+    each expert's rounding (a unit, or the units a tail can be rounded up by)."""
+    unit = row_unit(tile)
+    per = tile // unit
+    return -(-slots // unit) + n_held * max(1, per - tail_units(per, scattered))
+
+
+def tile_tables(visits, tile: int, scattered: bool, max_units: int):
+    """What every tile of :func:`held_experts_apply`'s loops is, worked out once
+    a layer from the held experts' ``visits`` [Eh] so that a tile reads its row
+    and searches nothing. Returns (``first`` [Eh]: an expert's first place in
+    the sorted order, ``region`` [Eh]: the first unit of its region of the
+    buffer, and for each loop, whole tiles and then tails (one loop where the
+    tile is the unit), (rows a tile, the tiles there are, ``table`` int32
+    [J, 4])). Row ``j`` of a table, for ``j`` under the tiles there are: the
+    tile's expert, its first place in the sorted order, the unit of the buffer
+    it lies at, and how many of its rows are real; the rows from there on are
+    never read. ``J`` is the worst case under ``max_units`` units of buffer:
+    ``max_units // per`` whole tiles of ``per`` units, :func:`tail_units` tails
+    an expert. A tile's expert is found by comparing every ``j`` with every
+    expert's run of tiles at once, J x Eh comparisons and no search."""
+    unit = row_unit(tile)
+    per, n_held = tile // unit, visits.shape[0]
+    first = jnp.cumsum(visits) - visits
+    units = region_units(visits, tile, scattered)
+    region = jnp.cumsum(units) - units
+    # the loops: (rows a tile, an expert's tiles, the units of its run before them, the most there can be)
+    if per == 1:
+        loops = [(tile, units, 0 * units, max_units)]
+    else:
+        whole = units // per
+        loops = [(tile, whole, 0 * units, max_units // per),
+                 (unit, units - whole * per, whole * per, n_held * tail_units(per, scattered))]
+    tables = []
+    for rows, n, before, most in loops:
+        upto = jnp.cumsum(n)
+        j = jnp.arange(most, dtype=jnp.int32)
+        mine = (j[:, None] >= (upto - n)[None]) & (j[:, None] < upto[None])    # [J, Eh]: tile j is expert e's
+        facts = jnp.stack([jnp.arange(n_held, dtype=jnp.int32), upto - n, before, first, first + visits, region])
+        e, lo, before, first_of, end, region_of = jnp.sum(      # each [J]: what tile j's expert has
+            jnp.where(mine[None], facts[:, None], 0), axis=2, dtype=jnp.int32)
+        begins = (j - lo) * (rows // unit) + before             # in units of the run
+        start = first_of + begins * unit
+        real = jnp.minimum(jnp.maximum(end - start, 0), rows)
+        tables.append((rows, upto[-1], jnp.stack([e, start, region_of + begins, real], axis=1)))
+    return first, region, tables
+
+
 def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: int,
                        routed_scale: float = 1.0, tile: int = 256, valid=None,
                        n_group: int = 1, topk_group: int = 1, limit=None):
@@ -707,7 +757,14 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     ``(ceil(T * top_k / unit) + Eh * spare) * unit + 1`` rows of x's dtype,
     ``spare`` 1 where every tail goes through tail tiles and else the units a
     tail can be rounded up by; handed from the first loop to the second as its
-    carry). Each token then reads its own ``top_k`` rows back and sums them
+    carry). What a tile is, it reads: its expert, its first place in the
+    sorted order, the unit of the buffer it lies at and the count of its real
+    rows are row ``j`` of its loop's table, made once a layer where the routing
+    is (:func:`tile_tables`: every tile compared with every expert's run at
+    once), and its visits are a slice of the sorted order, padded by one tile
+    so that the last run's last tile may reach past the end. A loop's body
+    searches nothing and gathers no index: its one gather is the tile's rows
+    of x. Each token then reads its own ``top_k`` rows back and sums them
     under its gates in float32: a gather, where a scatter-add of the same rows
     costs three times as much a row on a TPU.
 
@@ -725,7 +782,6 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
     n_held = params["w_down"].shape[0]
     f32 = jnp.float32
     unit = row_unit(tile)
-    per = tile // unit                                      # units a tile: 1 where there are no tails
     scattered = adds_as_computed(n_held, params["router"].shape[1])
     with jax.named_scope("tfr.moe_route"):
         grouped = {} if n_group == 1 else {"n_group": n_group, "topk_group": topk_group}
@@ -738,57 +794,43 @@ def held_experts_apply(params: Dict[str, Any], x, *, held_offset: int, top_k: in
         key = jnp.where(held, local, n_held).reshape(-1)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         visits = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
-        first = jnp.cumsum(visits) - visits                 # an expert's first sorted visit
-        units = region_units(visits, tile, scattered)
-        units_to = jnp.cumsum(units)                        # units up to and with an expert
+        max_units = buffer_units(t * top_k, n_held, tile, scattered)
+        first, region, loops = tile_tables(visits, tile, scattered, max_units)
         # where a visit's result will lie: its expert's first unit, then its place in the run
         rank = jnp.zeros((t * top_k,), jnp.int32).at[order].set(
             jnp.arange(t * top_k, dtype=jnp.int32))
         safe = jnp.minimum(key, n_held - 1)
-        lies_at = (units_to[safe] - units[safe]) * unit + rank - first[safe]
-        # the worst case: every visit to a held expert, and each expert's rounding
-        max_units = -(-t * top_k // unit) + n_held * max(1, per - tail_units(per, scattered))
+        lies_at = region[safe] * unit + rank - first[safe]
         lies_at = jnp.where(held.reshape(-1), lies_at, max_units * unit).reshape(t, top_k)
-        # the loops: (rows a tile, an expert's tiles, their running sum, the units of its run before them)
-        if per == 1:
-            loops = [(tile, units, units_to, None)]
-        else:
-            whole = units // per
-            tails = units - whole * per
-            loops = [(tile, whole, jnp.cumsum(whole), None),
-                     (unit, tails, jnp.cumsum(tails), whole * per)]
+        # a tile's visits are a contiguous run of the sorted order, and the last run's last tile may
+        # reach past its end by less than a tile: those rows are not real, what they read is never used
+        padded = jnp.concatenate([order, jnp.zeros((tile,), jnp.int32)])
     with jax.named_scope("tfr.moe_experts"):
-        lanes = [jnp.arange(rows, dtype=jnp.int32) for rows, _, _, _ in loops]
-
         def walk(lay, acc):
             """Every loop's tiles through their expert, each handed to ``lay`` with
             where it lies; returns (``acc``, the visits computed)."""
-            def one_tile(rows, n, upto, before, lane, j, carry):
+            def one_tile(rows, table, lane, j, carry):
                 acc, done = carry
-                e = jnp.searchsorted(upto, j, side="right").astype(jnp.int32)
-                nth = j - (upto[e] - n[e])
-                if per == 1:                                # every region is whole tiles: tile j lies at j tiles
-                    run, lies = first[e] + nth * rows + lane, lambda: j * rows
-                else:                                       # in units, so that every start is seen to be whole units
-                    begins = nth * per if before is None else before[e] + nth
-                    run, lies = first[e] + begins * unit + lane, lambda: (units_to[e] - units[e] + begins) * unit
-                real = run < first[e] + visits[e]           # run: the tile's places in the sorted order
-                visit = order[jnp.minimum(run, t * top_k - 1)]
-                acc = lay(acc, lies, visit, real, expert_unit(x[visit // top_k], params, limit, e))
-                return acc, done + real.sum(dtype=jnp.int32)
+                e, start, lies, count = jax.lax.dynamic_index_in_dim(
+                    table, j, keepdims=False, allow_negative_indices=False)
+                visit = jax.lax.dynamic_slice(padded, (start,), (rows,), allow_negative_indices=False)
+                # in units, so that every start is seen to be whole units
+                acc = lay(acc, lies * unit, visit, lane < count,
+                          expert_unit(x[visit // top_k], params, limit, e))
+                return acc, done + count
 
             carry = (acc, jnp.int32(0))
-            for (rows, n, upto, before), lane in zip(loops, lanes):
-                carry = jax.lax.fori_loop(
-                    0, upto[-1], functools.partial(one_tile, rows, n, upto, before, lane), carry)
+            for rows, tiles, table in loops:
+                carry = jax.lax.fori_loop(0, tiles, functools.partial(
+                    one_tile, rows, table, jnp.arange(rows, dtype=jnp.int32)), carry)
             return carry
 
         if not scattered:
             def lay(laid, lies, visit, real, y):
-                # no start is negative; said where there are tails, the start stays whole units to the
-                # compiler (the wrap-around's select hides it) and the write stays in the last product
-                return jax.lax.dynamic_update_slice(laid, y.astype(x.dtype), (lies(), 0),
-                                                    allow_negative_indices=per == 1)
+                # no start is negative; said, the start stays whole units to the compiler (the
+                # wrap-around's select hides it) and the write stays in the last product
+                return jax.lax.dynamic_update_slice(laid, y.astype(x.dtype), (lies, 0),
+                                                    allow_negative_indices=False)
 
             # one spare row past the worst case stays zero: what a token reads for an absent expert
             laid, done = walk(lay, jnp.zeros((max_units * unit + 1, d), x.dtype))
