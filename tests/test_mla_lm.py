@@ -469,7 +469,7 @@ def test_the_shares_of_64_experts_held_8_by_8_add_up_to_the_uncut_layer():
 
 
 def test_an_unknown_mixer_or_feed_forward_part_is_named_with_the_ones_there_are():
-    with pytest.raises(ValueError, match=r"\('gqa', 'kda', 'mla', 'swa', 'gdn', 'ssm'\)"):
+    with pytest.raises(ValueError, match=r"\('gqa', 'kda', 'mla', 'swa', 'gdn', 'ssm', 'bda'\)"):
         lm.pattern_param_shapes(lm.PatternLMConfig(layer_pattern=("mla", "rope")))
     with pytest.raises(ValueError, match=r"\('moe', 'dense'\)"):
         lm.pattern_param_shapes(lm.PatternLMConfig(layer_pattern=("mla",), ffn_pattern=("ffn",)))

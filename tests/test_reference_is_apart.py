@@ -18,7 +18,7 @@ from tools.graftlint.harness import iter_python_files
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODELS = os.path.join(ROOT, "benchmark", "models")
 REFERENCES = ["solar_open2", "kimi_vl_lm", "deepseek_v32", "trinity_large", "gigachat35", "nemotron_h",
-              "olmo_hybrid"]
+              "olmo_hybrid", "sdar_moe"]
 SIBLINGS = "benchmark.models."
 
 

@@ -159,7 +159,7 @@ def test_the_parameters_are_the_models(params):
     assert first["conv_x"].shape == (4, 96) and first["conv_bias"].shape == (96,)
     assert first["o_norm"].shape == (32,) and first["wo"].shape == (32, 32)
     assert second["w_up"].shape == (16, 32, 16) and second["shared"]["w_up"].shape == (32, 32)
-    assert lm.MIXERS == ("gqa", "kda", "mla", "swa", "gdn", "ssm")
+    assert lm.MIXERS == ("gqa", "kda", "mla", "swa", "gdn", "ssm", "bda")
 
 
 @pytest.mark.parametrize("patterns,refused", [

@@ -580,6 +580,42 @@ class TestOneChip:
         assert compiled.memory_analysis().output_size_in_bytes == 2 * 48 * l * 128
         assert (len(_grid_pairs(l, 1024, 1024, 4096)), len(_grid_pairs(l, 1024, 1024))) == (150, 528)
 
+    def test_a_block_diffusion_layer_at_the_cells_shape_holds_no_mask_of_the_rows_square(self, one_chip,
+                                                                                        monkeypatch):
+        """``sdar_30b_a3b_pp8.score``'s layers (``gqa_mixer_probed(streams=True)`` alone): 32
+        query heads on 4 key-value heads of 128 over one row of TWO streams of 8,192
+        tokens under the block mask of 4. One custom call, under the kernel's own
+        limit of fast memory; its grid the 80 pairs the mask can see (the clean
+        stream's 36, the noised queries' 36 clean key blocks and 8 own blocks) where a
+        causal row of 16,384 walks 136; K and V handed to it as the 4 heads the
+        projections wrote; and no operand of the row's square anywhere: two integers a
+        token decide the mask."""
+        from tpu_tfrecord.models.attention import _grid_pairs
+
+        monkeypatch.setattr(lm.jax, "default_backend", lambda: "tpu")     # the described chip's branch
+        l = 8192
+        cfg = lm.PatternLMConfig(
+            vocab_size=256, d_model=2048, layer_pattern=("bda",), ffn_pattern=("dense",), n_heads=32,
+            n_kv_heads=4, head_dim=128, qk_norm=True, gqa_gate=False, diffusion_block=4, mask_id=255,
+            rope_theta=1e6, max_len=l, attn_block=1024, dtype=jnp.bfloat16)
+        layer = lm.pattern_param_shapes(cfg)["layers"][0]
+        p = {name: jax.ShapeDtypeStruct(*sd, sharding=one_chip) for name, sd in layer.items()
+             if name not in ("dense", "ffn_norm")}
+        assert "wg" not in p and p["q_norm"].shape == (128,)
+        x = jax.ShapeDtypeStruct((1, 2 * l, 2048), jnp.bfloat16, sharding=one_chip)
+        segs = jax.ShapeDtypeStruct((1, 2 * l), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(lambda p, x, s: lm.gqa_mixer_probed(p, x, s, cfg, streams=True)[0]).lower(
+            p, x, segs).compile()
+        text = compiled.as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+        (call,) = [line for line in _entry_lines(text) if "tpu_custom_call" in line]
+        assert f"bf16[1,32,{2 * l},128]" in call and call.count(f"bf16[1,4,{2 * l},128]") >= 2
+        pairs = len(_grid_pairs(2 * l, 1024, 1024, streams=True))
+        assert (pairs, len(_grid_pairs(2 * l, 1024, 1024))) == (80, 136) and text.count(f"s32[{pairs}]") >= 2
+        assert not re.findall(rf"\[(?:\d+,)*(?:{l},{l}|{2 * l},{2 * l})\]", text)
+        q_bytes = 2 * 32 * 2 * l * 128
+        assert compiled.memory_analysis().temp_size_in_bytes < 3.5 * q_bytes   # q, the output, k, v and the norm's float32
+
     @pytest.mark.parametrize("t, d, f, n_experts, held, top_k, laid", [
         (16384, 2048, 1408, 64, 64, 6, (384 + 64 * 2) * 256 + 1),      # kimi_vl_a3b_lm.score: read back
         (16384, 7168, 2048, 256, 16, 8, None),                          # gigachat35_ep16.score: added as computed

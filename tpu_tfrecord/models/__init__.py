@@ -7,11 +7,14 @@ in the benchmark's cells (``BENCHMARK.json``), the rest in tests and examples:
 - ``dlrm``: a Criteo-style DLRM (batch on 'data', embedding tables and
   hidden layers on 'model', padded sequence features on 'seq'), with
   ``interaction`` its dot-interaction: the two Criteo cells.
-- the pattern model, the seven token cells (``solar_open2_ep8``,
+- the pattern model, the eight token cells (``solar_open2_ep8``,
   ``kimi_vl_a3b_lm``, ``deepseek_v32_exp_ep16``, ``trinity_large_ep8``,
-  ``gigachat35_ep16``, ``nemotron_twotower_ep2``, ``olmo_hybrid_7b_pp4``):
+  ``gigachat35_ep16``, ``nemotron_twotower_ep2``, ``olmo_hybrid_7b_pp4``,
+  ``sdar_30b_a3b_pp8``):
   ``lm.PatternLMConfig`` holds the layer pattern as data (``gqa | swa | mla |
-  kda | gdn | ssm`` mixers, the sixth a state-space layer; a ``gdn`` head's
+  kda | gdn | ssm | bda`` mixers, the sixth a state-space layer, the seventh
+  softmax attention under block diffusion's mask, two streams a row, scored
+  without a shift from a row and its noised copy; a ``gdn`` head's
   keys and values of their own widths; dense or expert feed-forward parts by
   layer, or no expert anywhere; norms before a branch, on it, or both; a
   layer may be ONE branch, a mixer or a feed-forward part alone, the other

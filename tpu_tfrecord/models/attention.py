@@ -74,7 +74,7 @@ def _expand_kv(q, kv):
 
 def attention_reference(
     q, k, v, lengths=None, scale: Optional[float] = None, causal: bool = False,
-    segments=None, window: Optional[int] = None,
+    segments=None, window: Optional[int] = None, blocks=None, noised=None,
 ):
     """Dense softmax attention oracle. q [B, L, H, D], k/v [B, L, Hkv, D]
     with Hkv == H (MHA) or H % Hkv == 0 (GQA/MQA: each K/V head serves
@@ -84,7 +84,15 @@ def attention_reference(
     to j only when segments[b, i] == segments[b, j], so documents packed
     into one row (TokenPacker's bin modes) never leak mass across their
     boundaries. ``window`` (with ``causal``) keeps of those the keys j with
-    i - j < window: the query's own and the ``window - 1`` before it."""
+    i - j < window: the query's own and the ``window - 1`` before it.
+    ``blocks`` [B, L] int (with ``segments``; not with ``causal``) is the mask
+    of block diffusion in place of the triangle: each token's block number in
+    its own document, and ``noised`` [B, L] bool which tokens belong to the
+    noised stream (none where left out). A clean query sees the clean keys of
+    its document whose block is at or before its own (its own block whole:
+    keys after it too); a noised query the clean keys of blocks before its
+    own and the noised keys of its own block; no clean query sees a noised
+    key. Nothing here knows of rows' halves or tiles: two arrays a token."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     k, v = _expand_kv(q, k), _expand_kv(q, v)
     # the scale goes onto q, before the contraction (L·D multiplies, not
@@ -107,12 +115,36 @@ def attention_reference(
         if window is not None:
             tri = tri & (jnp.arange(l)[:, None] - jnp.arange(m)[None, :] < window)
         scores = jnp.where(tri[None, None, :, :], scores, _NEG)
+    if blocks is not None:
+        noised = jnp.zeros(blocks.shape, bool) if noised is None else noised
+        q_at, k_at = blocks[:, :, None], blocks[:, None, :]
+        q_noised, k_noised = noised[:, :, None], noised[:, None, :]
+        seen = jnp.where(k_noised, q_noised & (k_at == q_at), jnp.where(q_noised, k_at < q_at, k_at <= q_at))
+        scores = jnp.where(seen[:, None, :, :], scores, _NEG)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhlm,bmhd->blhd", probs, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _fold_keys(qb, k, v, k0: int, k1: int, seen, top, total, acc):
+    """The keys ``k0`` to ``k1`` folded into a block of queries' running
+    maximum, sum and weighted value (float32, flash-style): what both plain
+    forms do a pair of blocks. ``seen()`` -> [B, queries, keys] bool, asked for
+    after the scores, and the values cut where they are used, so that the
+    traced program keeps its order. Every probability is multiplied by its
+    mask: a row that has seen nothing yet carries zeros."""
+    scores = jnp.einsum("bqkgd,bmkd->bkgqm", qb, k[:, k0:k1], preferred_element_type=jnp.float32)
+    mask = seen()[:, None, None]
+    new_top = jnp.maximum(top, jnp.where(mask, scores, _NEG).max(axis=-1))
+    probs = jnp.where(mask, jnp.exp(scores - new_top[..., None]), 0.0)
+    fade = jnp.exp(top - new_top)
+    total = total * fade + probs.sum(axis=-1)
+    acc = acc * fade[..., None] + jnp.einsum(
+        "bkgqm,bmkd->bkgqd", probs.astype(v.dtype), v[:, k0:k1], preferred_element_type=jnp.float32)
+    return new_top, total, acc
+
+
 def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block: int = 1024,
-                        keep=None, window: Optional[int] = None):
+                        keep=None, window: Optional[int] = None, blocks=None):
     """Causal softmax attention within ``segments``, by key blocks: the
     same answer as ``attention_reference(causal=True, segments=...)``
     without ever holding a ``[B, H, L, L]`` array. q [B, L, H, D], k/v
@@ -131,7 +163,21 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
     learned selection (``sparse_attn.select_keys``); ``window`` keeps of a
     query's keys its own and the ``window - 1`` before it, and the key
     blocks wholly behind a query block's window are skipped like the later
-    ones."""
+    ones.
+
+    ``blocks`` = (numbers [B, L] int, length): block diffusion's mask in the
+    triangle's place (:func:`attention_reference` has the rule). The row is
+    two streams of L / 2 tokens, the clean one then the noised one, the same
+    documents at the same places in both; ``numbers`` is each token's block
+    in its own document, of ``length`` tokens at most. A block of clean
+    queries walks the clean keys up to its last query's block's end, a block
+    of noised queries the clean keys before its last query and the noised
+    keys within a block's length of its own: cost follows the pairs seen,
+    and no ``[L, L]`` mask exists. Documents may start anywhere in a row."""
+    if blocks is not None:
+        if keep is not None or window is not None:
+            raise ValueError("the block mask comes without a selection and without a window")
+        return _blockwise_two_streams(q, k, v, segments, scale, block, *blocks)
     b, l, h, d = q.shape
     hkv, dv = k.shape[2], v.shape[-1]
     if h % hkv:
@@ -150,23 +196,50 @@ def blockwise_attention(q, k, v, segments, scale: Optional[float] = None, block:
         behind = 0 if window is None else max(0, q0 - window + 1) // block * block
         for k0 in range(behind, q1, block):
             k1 = min(k0 + block, l)
-            scores = jnp.einsum("bqkgd,bmkd->bkgqm", qb, k[:, k0:k1],
-                                preferred_element_type=jnp.float32)
-            mask = (sq[:, :, None] == segments[:, None, k0:k1]) & (
-                at[q0:q1, None] >= at[None, k0:k1])
-            if window is not None and q1 - 1 - k0 >= window:  # the block reaches behind some query's window
-                mask = mask & (at[q0:q1, None] - at[None, k0:k1] < window)
-            if keep is not None:
-                mask = mask & (keep[:, q0:q1, k0:k1] != 0)
-            mask = mask[:, None, None]
-            new_top = jnp.maximum(top, jnp.where(mask, scores, _NEG).max(axis=-1))
-            probs = jnp.where(mask, jnp.exp(scores - new_top[..., None]), 0.0)
-            fade = jnp.exp(top - new_top)
-            total = total * fade + probs.sum(axis=-1)
-            acc = acc * fade[..., None] + jnp.einsum(
-                "bkgqm,bmkd->bkgqd", probs.astype(v.dtype), v[:, k0:k1],
-                preferred_element_type=jnp.float32)
-            top = new_top
+            def seen(q0=q0, q1=q1, k0=k0, k1=k1):
+                mask = (sq[:, :, None] == segments[:, None, k0:k1]) & (
+                    at[q0:q1, None] >= at[None, k0:k1])
+                if window is not None and q1 - 1 - k0 >= window:  # the block reaches behind some query's window
+                    mask = mask & (at[q0:q1, None] - at[None, k0:k1] < window)
+                if keep is not None:
+                    mask = mask & (keep[:, q0:q1, k0:k1] != 0)
+                return mask
+
+            top, total, acc = _fold_keys(qb, k, v, k0, k1, seen, top, total, acc)
+        out.append(jnp.moveaxis(acc / total[..., None], 3, 1).reshape(b, q1 - q0, h, dv))
+    return jnp.concatenate(out, axis=1).astype(q.dtype)
+
+
+def _blockwise_two_streams(q, k, v, segments, scale, block: int, numbers, length: int):
+    """:func:`blockwise_attention` under block diffusion's mask: a row of the
+    clean stream then the noised one."""
+    b, l, h, d = q.shape
+    hkv, dv, half = k.shape[2], v.shape[-1], q.shape[1] // 2
+    if h % hkv or l % 2:
+        raise ValueError(f"a row of two streams of {h} heads over {hkv}, {l} tokens")
+    scale = scale if scale is not None else d ** -0.5
+    qg = (q * jnp.asarray(scale, q.dtype)).reshape(b, l, hkv, h // hkv, d)
+    rules = {"at_or_before": jnp.less_equal, "before": jnp.less, "own": jnp.equal}
+    out = []
+    for q0 in [*range(0, half, block), *range(half, l, block)]:
+        q1 = min(q0 + block, half if q0 < half else l)
+        qb, sq, nq = qg[:, q0:q1], segments[:, q0:q1], numbers[:, q0:q1]
+        if q0 < half:   # clean queries: clean keys to the end of the last query's block
+            runs = [(0, min(q1 + length - 1, half), "at_or_before")]
+        else:           # noised queries: clean keys before them, noised keys of their own blocks
+            runs = [(0, q1 - half, "before"), (max(half, q0 - length + 1), min(l, q1 + length - 1), "own")]
+        shape = (b, hkv, h // hkv, q1 - q0)
+        top = jnp.full(shape, _NEG)
+        total = jnp.zeros(shape, jnp.float32)
+        acc = jnp.zeros(shape + (dv,), jnp.float32)
+        for first, end, rule in runs:
+            for k0 in range(first, end, block):
+                k1 = min(k0 + block, end)
+                def seen(k0=k0, k1=k1, rule=rule):
+                    return (sq[:, :, None] == segments[:, None, k0:k1]) & rules[rule](
+                        numbers[:, None, k0:k1], nq[:, :, None])
+
+                top, total, acc = _fold_keys(qb, k, v, k0, k1, seen, top, total, acc)
         out.append(jnp.moveaxis(acc / total[..., None], 3, 1).reshape(b, q1 - q0, h, dv))
     return jnp.concatenate(out, axis=1).astype(q.dtype)
 
@@ -189,7 +262,7 @@ _VMEM_LIMIT = 64 * 2 ** 20  # two heads' blocks, twice, and their scratch: 11 MB
 def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref,
                          o_ref, m_ref, l_ref, acc_ref, *, scale: float, block_q: int,
                          block_k: int, keep_ref=None, window: Optional[int] = None,
-                         q_rope_ref=None, k_rope_ref=None):
+                         q_rope_ref=None, k_rope_ref=None, streams: Optional[tuple] = None):
     """One (query block, key block) pair of the grid step's heads: scores
     stay on the chip, the running maximum and sum are kept 128 lanes wide
     (every lane the same), the weighted values are divided by the sum once,
@@ -257,15 +330,19 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
         share = heads // ref.shape[1]
         return head if share == 1 else lax.div(head, share)
 
-    def pair(origin, far=None):
+    def pair(origin, far=None, rule=None):
         """The body of one kind of pair. ``origin``: where the key block starts,
         counted from the query block's first row (None: wholly before it, and
         no position is compared). ``far``: how far before that row it starts,
-        where it reaches behind some row's window (None: it does not)."""
+        where it reaches behind some row's window (None: it does not).
+        ``rule`` (under ``streams``, with ``origin`` 0): which of the block
+        mask's three compares stands in the diagonal's place."""
         whole = min(rows, block_k)    # a pass's keys start on a whole one of these
         passes = [(at, 0 if far is None else max(0, far + at - window + 1) // whole * whole,
                    block_k if origin is None else min(block_k, at + rows - origin))
                   for at in range(0, block_q, rows)]
+        if rule == "own":             # the keys that face the pass's rows, no others
+            passes = [(at, at, at + rows) for at in range(0, block_q, rows)]
         # rows before the block's first key, or whose windows end after its last
         passes = [(at, skip, keys) for at, skip, keys in passes if keys > skip]
 
@@ -284,7 +361,15 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
             out = lax.mul(out, scale)
             wide = keys - skip
             seen = [jnp.tile(qseg_ref[0, at:at + rows], (1, wide // _LANES)) == kseg_ref[0, :1, skip:keys]]
-            if origin is not None:    # the pass's last keys are its own rows
+            if rule is not None:      # block diffusion: a compare of blocks, not of positions
+                across, down = (lax.broadcasted_iota(jnp.int32, (rows, wide), n) for n in (1, 0))
+                if rule == "own":     # the key's block is the query's (skip == at, whole blocks both)
+                    seen.append(lax.bitwise_xor(across, down) < streams[0])
+                else:                 # keys to the end of the query's block, or before its start
+                    ends = lax.bitwise_or(down, jnp.int32(streams[0] - 1)) + (
+                        at - skip - (streams[0] if rule == "before" else 0))
+                    seen.append(across <= ends)
+            elif origin is not None:    # the pass's last keys are its own rows
                 seen.append(lax.broadcasted_iota(jnp.int32, (rows, wide), 1)
                             - lax.broadcasted_iota(jnp.int32, (rows, wide), 0) <= at - origin - skip)
             if far is not None:       # its first keys lie behind its last rows' windows
@@ -326,17 +411,25 @@ def _flash_widths_kernel(lo_ref, hi_ref, qi_ref, ki_ref, qseg_ref, kseg_ref, q_r
         # lower and compile whatever their number
         lax.fori_loop(0, heads, one_head, 0)
 
-    under = needed & (ki < qi * per)                           # every key before every query
-    if window is not None:
-        behind = qi * block_q - ki * block_k                   # ... its first key so far before the first
-        under = under & (behind + block_q - 1 < window)        # ... and every key inside every row's window
-        for far in range(block_k, window + block_k - 1, block_k):  # the band's trailing blocks
-            if far + block_q - 1 >= window:
-                pl.when(needed & (behind == far))(functools.partial(pair, None, far))
-    pl.when(under)(lambda: pair(None))
-    for j in range(per):    # the key blocks a query block's own rows cross
-        far = None if window is None or block_q - 1 - j * block_k < window else -j * block_k
-        pl.when(ki == qi * per + j)(functools.partial(pair, j * block_k, far))
+    if streams is not None:    # the block mask: the diagonal's place is taken by three kinds
+        noised = qi >= streams[1]
+        place = lax.select(noised, qi - streams[1], qi)        # the query block's place in its own stream
+        pl.when(needed & (ki < place))(lambda: pair(None))     # clean keys under either stream's queries
+        pl.when((ki == qi) & jnp.logical_not(noised))(functools.partial(pair, 0, None, "at_or_before"))
+        pl.when((ki == place) & noised)(functools.partial(pair, 0, None, "before"))
+        pl.when((ki == qi) & noised)(functools.partial(pair, 0, None, "own"))
+    else:
+        under = needed & (ki < qi * per)                           # every key before every query
+        if window is not None:
+            behind = qi * block_q - ki * block_k                   # ... its first key so far before the first
+            under = under & (behind + block_q - 1 < window)        # ... and every key inside every row's window
+            for far in range(block_k, window + block_k - 1, block_k):  # the band's trailing blocks
+                if far + block_q - 1 >= window:
+                    pl.when(needed & (behind == far))(functools.partial(pair, None, far))
+        pl.when(under)(lambda: pair(None))
+        for j in range(per):    # the key blocks a query block's own rows cross
+            far = None if window is None or block_q - 1 - j * block_k < window else -j * block_k
+            pl.when(ki == qi * per + j)(functools.partial(pair, j * block_k, far))
 
     @pl.when(ki == (qi + 1) * per - 1)
     def _last():
@@ -373,11 +466,20 @@ def _pair_kind(lo, hi, bi, qi, ki, per: int):
     return needed, (q_lo == q_hi) & (k_lo == k_hi) & (q_lo == k_lo)
 
 
-def _grid_pairs(l: int, block_q: int, block_k: int, window: Optional[int] = None):
+def _grid_pairs(l: int, block_q: int, block_k: int, window: Optional[int] = None,
+                streams: bool = False):
     """[pairs, 2] int32: the (query block, key block) pairs at or under the
     diagonal, a query block's in order: what the kernel's grid walks. With a
     ``window`` the band alone: no key block wholly behind the window of a
-    query block's first row."""
+    query block's first row. With ``streams`` (square blocks; the row is a
+    clean stream then a noised one) a clean query block's pairs at or under
+    its own stream's diagonal, and a noised one's with the clean key blocks
+    at or under its own place, then its own noised key block."""
+    if streams:
+        each = l // 2 // block_q
+        return np.array([(qi, ki) for qi in range(each) for ki in range(qi + 1)]
+                        + [(each + qi, ki) for qi in range(each) for ki in (*range(qi + 1), each + qi)],
+                        np.int32)
     per = block_q // block_k
     reach = l if window is None else window - 1     # how far behind its first row a query block sees
     return np.array([(qi, ki) for qi in range(l // block_q)
@@ -385,22 +487,38 @@ def _grid_pairs(l: int, block_q: int, block_k: int, window: Optional[int] = None
                     np.int32)
 
 
-def pair_kinds(segments, block_q: int = 1024, block_k: int = 1024, window: Optional[int] = None):
+def pair_kinds(segments, block_q: int = 1024, block_k: int = 1024, window: Optional[int] = None,
+               streams: bool = False):
     """(skipped, plain, masked): how many of the block pairs at or under the
     diagonal of ``segments`` [B, L] (with a ``window``: of its band) the
     kernel skips, computes with every key seen (*plain*: wholly under the
     diagonal and inside every row's window, one document; no compare is
     needed there) and computes under a mask that hides something (the
     diagonal's, the window's, or several documents'), by the rule its
-    scalars apply."""
+    scalars apply. With ``streams`` (``segments`` the row of two streams the
+    kernel is handed, square blocks) five counts: (skipped, plain, masked,
+    before, own), the third now the pairs under a mask of segment ids or of
+    the clean stream's own diagonal (a query sees to the end of its block),
+    the last two the block mask's own kinds, one each a noised query block:
+    the clean key block at its own place (the keys before a query's block)
+    and its own noised key block."""
     segments = np.asarray(segments, np.int32)
     b, l = segments.shape
     block_q, block_k = min(block_q, l), min(block_k, l)
     by_block = segments.reshape(b, l // block_k, block_k)
     per = block_q // block_k
-    qi, ki = _grid_pairs(l, block_q, block_k, window).T
+    qi, ki = _grid_pairs(l, block_q, block_k, window, streams).T
     needed, one_document = _pair_kind(by_block.min(axis=-1), by_block.max(axis=-1),
                                       np.arange(b)[:, None], qi, ki, per)
+    if streams:
+        each = l // 2 // block_q
+        place = np.where(qi >= each, qi - each, qi)
+        own, before = (qi >= each) & (ki == qi), (qi >= each) & (ki == place)
+        under = ki < place
+        plain = needed & one_document & under
+        masked = (needed & under & ~plain) | ((qi < each) & (ki == qi))
+        return tuple(int(np.sum(n & np.ones((b, 1), bool))) for n in (
+            under & ~needed, plain, masked, before, own))
     plain = needed & one_document & (ki < qi * per)
     if window is not None:
         plain = plain & ((qi + 1) * block_q - 1 - ki * block_k < window)
@@ -409,7 +527,7 @@ def pair_kinds(segments, block_q: int = 1024, block_k: int = 1024, window: Optio
 
 def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
                            block_k: int = 1024, keep=None, window: Optional[int] = None,
-                           q_rope=None, k_rope=None):
+                           q_rope=None, k_rope=None, diffusion_block: Optional[int] = None):
     """Causal attention inside ``segments`` as a Pallas TPU kernel, for
     queries and keys of one width and values of another (JAX's own flash
     kernel takes one width, and only 128s).
@@ -433,6 +551,16 @@ def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
     ``window``: a query sees its own key and the ``window - 1`` before it,
     and the grid walks that band of block pairs alone (150 of a 32,768-token
     document's 528 at 4,096 keys); none: the program it always was.
+    ``diffusion_block``: block diffusion's mask in the triangle's place
+    (:func:`attention_reference` has the rule). The row is two streams of
+    L / 2 tokens, the clean one then the noised one, ``segments`` the same in
+    both; every document starts at a whole multiple of ``diffusion_block`` (a
+    power of two that divides the tile) in its stream, as
+    ``TokenPacker(noise=)`` places them, so a token's block is its place over
+    the block length and no mask is handed in: the grid walks the clean
+    stream's triangle, the noised queries' clean key blocks and each noised
+    query block's own noised key block, (n + 1) n + n pairs where a causal row
+    of 2 n blocks has (2 n + 1) n; none: the program it always was.
     Forward only. One trace and one lowering for a program's calls of one
     shape: the call sits in a jitted function."""
     l, dv = q.shape[2], v.shape[-1]
@@ -446,13 +574,23 @@ def flash_attention_widths(q, k, v, segments, scale: float, block_q: int = 1024,
             k_rope is not None and k_rope.shape[1] not in (1, k.shape[1])):
         raise ValueError("a second part of the queries comes with one of the keys, of one head "
                          f"or of the keys' {k.shape[1]}")
-    return _flash_widths_call(q, k, v, segments, keep, q_rope, k_rope, scale=float(scale),
-                              block_q=block_q, block_k=block_k, window=window)
+    if diffusion_block is None:
+        return _flash_widths_call(q, k, v, segments, keep, q_rope, k_rope, scale=float(scale),
+                                  block_q=block_q, block_k=block_k, window=window)
+    n = int(diffusion_block)
+    block_q = block_k = min(block_q, l // 2)
+    if (keep is not None or window is not None or q_rope is not None or n < 1 or n & (n - 1)
+            or l % (2 * block_q) or block_q % _LANES or min(_ROWS, block_q) % n):
+        raise ValueError(f"a block mask of {n} over two streams of {l // 2} tokens in tiles of {block_q}: a power "
+                         "of two that divides the tile, whole tiles a stream, no selection, window or second part")
+    return _flash_widths_call(q, k, v, segments, None, scale=float(scale), block_q=block_q,
+                              block_k=block_k, streams=(n, l // 2 // block_q))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k", "window"))
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k", "window", "streams"))
 def _flash_widths_call(q, k, v, segments, keep, q_rope=None, k_rope=None, *, scale: float,
-                       block_q: int, block_k: int, window: Optional[int] = None):
+                       block_q: int, block_k: int, window: Optional[int] = None,
+                       streams: Optional[tuple] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -462,7 +600,7 @@ def _flash_widths_call(q, k, v, segments, keep, q_rope=None, k_rope=None, *, sca
     lo, hi = by_block.min(axis=-1), by_block.max(axis=-1)
     per = block_q // block_k
     heads = next(n for n in _HEADS if h % n == 0 and (rep % n == 0 or n % rep == 0))
-    pairs = _grid_pairs(l, block_q, block_k, window)     # the grid's third axis
+    pairs = _grid_pairs(l, block_q, block_k, window, streams is not None)     # the grid's third axis
 
     def key_block(bi, t, lo_ref, hi_ref, qi_ref, ki_ref):
         """A skipped pair asks for the query block's own last key block, which
@@ -490,7 +628,7 @@ def _flash_widths_call(q, k, v, segments, keep, q_rope=None, k_rope=None, *, sca
             (1, block_q, block_k), lambda bi, hi, t, *r: (bi, r[2][t], key_block(bi, t, *r))))
     kernel = functools.partial(
         _flash_widths_kernel_with(*more) if more else _flash_widths_kernel,
-        scale=scale, block_q=block_q, block_k=block_k, window=window)
+        scale=scale, block_q=block_q, block_k=block_k, window=window, streams=streams)
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -785,7 +785,7 @@ def bigram_table(vocab: int, n_next: int, seed: int = 1234) -> np.ndarray:
 # ``segment_ids`` [B, L+1]; every document comes out as it would alone in
 # a row (attention, positions, taps and state all stop at a boundary).
 
-MIXERS = ("gqa", "kda", "mla", "swa", "gdn", "ssm")
+MIXERS = ("gqa", "kda", "mla", "swa", "gdn", "ssm", "bda")
 FFNS = ("moe", "dense")
 NONE = "none"  # in either pattern: the layer has no such part
 
@@ -800,6 +800,8 @@ class PatternLMConfig:
     n_kv_heads: int = 2
     head_dim: int = 16
     window: int = 0                # sliding-window layer ("swa"): the keys a query sees, its own among them
+    diffusion_block: int = 0       # block-diffusion layers ("bda"): tokens a block, counted from a document's first (0: none),
+    mask_id: int = 0               # ... and the id that stands for a noised token: scored where the noised row holds it
     qk_norm: bool = False          # softmax and sliding-window layers: an RMSNorm over each head of q and of k,
     qk_norm_whole: bool = False    # ... or over the WHOLE projection before it is cut into heads (a weight its width)
     gqa_gate: bool = True          # ... and a sigmoid gate from the layer's input on the attention's output
@@ -835,6 +837,7 @@ class PatternLMConfig:
     expert_unit: str = "gated"     # a routed or shared expert: "gated" (three matrices, SiLU) or "relu2" (two)
     routed_scale: float = 1.0
     router_bias: bool = False      # a per-expert bias beside the router: it picks, it never weighs
+    router_scoring: str = "sigmoid"  # the router's scores: "sigmoid", or "softmax" over all experts (moe.route_top_k)
     n_group: int = 1               # the router's group limit: the experts in so many equal runs,
     topk_group: int = 1            # ... of which a token's choice may touch so many (moe.route_top_k)
     norm_eps: float = 1e-5
@@ -939,6 +942,12 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
         raise ValueError("qk_norm (a head) or qk_norm_whole (the projection): one of the two")
     if "swa" in cfg.layer_pattern and cfg.window < 1:
         raise ValueError("a sliding-window layer needs cfg.window: the keys a query sees")
+    if ("bda" in cfg.layer_pattern) != (cfg.diffusion_block > 0) or (
+            cfg.diffusion_block and set(cfg.layer_pattern) - {"bda", NONE}):
+        raise ValueError("block diffusion runs two streams through EVERY layer: diffusion_block > 0 and "
+                         "'bda' for every mixer of the pattern, or neither")
+    if cfg.router_scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"router_scoring {cfg.router_scoring!r}: 'sigmoid' or 'softmax'")
     if cfg.kda_heads % (cfg.gdn_key_heads or 1):
         raise ValueError(f"gdn_key_heads {cfg.gdn_key_heads} has to divide the {cfg.kda_heads} value heads")
     if not cfg.gqa_gate:
@@ -974,6 +983,8 @@ def pattern_param_shapes(cfg: PatternLMConfig) -> Dict[str, Any]:
         moe.pop("shared")
     if cfg.router_bias:
         moe["router_bias"] = ((cfg.n_experts,), f32)
+    # the softmax layer under block diffusion's mask: what "swa" holds, and never an output gate
+    mixers["bda"] = {name: shape for name, shape in mixers["gqa"].items() if name != "wg"}
     return {
         "embed": ((cfg.vocab_size, d), dt), "head": ((d, cfg.vocab_size), dt),
         "final_norm": ((d,), f32),
@@ -1066,7 +1077,15 @@ def _takes_kernel(l: int, dv: int, block: int) -> bool:
     return jax.default_backend() == "tpu" and dv % 128 == 0 and tile % 128 == 0 and l % tile == 0
 
 
-def _attend(q, k, v, segments, block: int, scale=None, keep=None, window=None):
+def _takes_block_kernel(stream: int, dv: int, block: int, n: int) -> bool:
+    """Whether :func:`_attend` runs its Pallas kernel under block diffusion's
+    mask for two streams of ``stream`` tokens and blocks of ``n``: what
+    :func:`_takes_kernel` asks of a stream, and a power of two that divides
+    the rows a pass of the kernel takes."""
+    return _takes_kernel(stream, dv, block) and n & (n - 1) == 0 and 128 % n == 0
+
+
+def _attend(q, k, v, segments, block: int, scale=None, keep=None, window=None, diffusion=None):
     """Causal attention inside each document; q [B, H, L, D], k [B, Hkv, L, D],
     v [B, Hkv, L, Dv] -> [B, H, L, Dv], over the keys ``keep`` [B, L, L] marks
     non-zero (every key of the document at or before the query where none is
@@ -1101,7 +1120,29 @@ def _attend(q, k, v, segments, block: int, scale=None, keep=None, window=None):
     answer (tests/test_pattern_lm.py, tests/test_mla_lm.py,
     tests/test_swa_lm.py and tests/test_dsa_lm.py hold the kernel to it);
     there, and only there, the two parts are joined and the rotary key head
-    is copied to every head."""
+    is copied to every head.
+
+    ``diffusion`` = n: block diffusion's mask in the triangle's place. The
+    row is two streams, the clean one then the noised one, ``segments`` the
+    same in both; a token's block is its PLACE in its stream over ``n``, which
+    is its block in its own document where every document starts at a whole
+    multiple of ``n`` (``TokenPacker(noise=)`` packs so; :func:`score` counts
+    the documents that do not: ``starts_off_block``). A clean query sees its
+    document's clean keys to the end of its own block, a noised one the clean
+    keys before its block and the noised keys of its block
+    (``attention.attention_reference`` has the rule). The same kernel on a
+    TPU, which walks the pairs the mask can see; ``blockwise_attention``
+    elsewhere: one rule for a block in both."""
+    if diffusion is not None:
+        n, half = diffusion, q.shape[2] // 2
+        if _takes_block_kernel(half, v.shape[-1], block, n):
+            return flash_attention_widths(q, k, v, segments, scale or q.shape[-1] ** -0.5, block, block,
+                                          diffusion_block=n)
+        numbers = jnp.broadcast_to(jnp.tile(jnp.arange(half, dtype=jnp.int32) // n, 2), segments.shape)
+        out = blockwise_attention(
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), segments,
+            scale=scale, block=block, blocks=(numbers, n))
+        return jnp.swapaxes(out, 1, 2)
     parts = {}
     if isinstance(q, tuple):
         (q, parts["q_rope"]), (k, parts["k_rope"]) = q, k
@@ -1135,7 +1176,7 @@ def gqa_mixer(p, x, segments, cfg: PatternLMConfig, sliding: bool = False):
 
 
 def gqa_mixer_probed(p, x, segments, cfg: PatternLMConfig, sliding: bool = False, sample_at=None,
-                     probe_head=None):
+                     probe_head=None, streams: bool = False):
     """(:func:`gqa_mixer`'s y, a record of one head's attention or None).
     With ``sample_at`` [B, S] and ``probe_head`` (an int32 scalar naming a
     query head) what the attention call was given and gave, float32, so that
@@ -1143,10 +1184,24 @@ def gqa_mixer_probed(p, x, segments, cfg: PatternLMConfig, sliding: bool = False
     ``record["scan"]`` = that head's keys and values ``k_swa``, ``v_swa``
     [B, L, Dh], ``record["router"]`` = at the sampled positions its queries
     and outputs ``q_swa``, ``att_swa`` [B, S, Dh] and ``swa_pos`` [B, S], a
-    position's index in its own document."""
+    position's index in its own document.
+
+    ``streams`` (the "bda" layers, block diffusion): x [B, 2L, D] and
+    ``segments`` [B, 2L] are a row's clean stream then its noised one. Rotary
+    turns as in a sliding layer, by a token's index in its own document, the
+    same in both streams; no window and never a gate; the attention under the
+    block mask of ``cfg.diffusion_block`` tokens (:func:`_attend`). Its record:
+    ``scan`` = the head's keys and values of each stream ``k_bda``, ``v_bda``,
+    ``k_bda_noised``, ``v_bda_noised`` [B, L, Dh]; ``router`` = at the sampled
+    positions of each stream the queries and outputs ``q_bda``, ``att_bda``
+    (the noised stream's) and ``q_bda_clean``, ``att_bda_clean`` [B, S, Dh],
+    and ``bda_pos``, ``bda_block`` [B, S]: a position's index and block in its
+    own document."""
     d, h, hkv, dh = x.shape[-1], cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     probed = sample_at is not None and probe_head is not None
     around, call = ("tfr.swa_proj", "tfr.swa_attn") if sliding else ("tfr.gqa", "tfr.gqa")
+    if streams:
+        around, call = "tfr.bda_proj", "tfr.bda_attn"
     with jax.named_scope(around):
         u = _pre_norm(x, p, "attn_norm", cfg)
         q = jnp.einsum("bld,dhk->bhlk", u, p["wq"].reshape(d, h, dh))
@@ -1158,15 +1213,37 @@ def gqa_mixer_probed(p, x, segments, cfg: PatternLMConfig, sliding: bool = False
         if cfg.qk_norm_whole:
             q = _norm_whole(q, p["q_norm"], cfg)
             k = _norm_whole(k, p["k_norm"], cfg)
-        at = segment_positions(segments) if sliding or probed else None
-        if sliding:
+        if streams:  # a token's index in its document: one stream's, for both
+            half = segments.shape[1] // 2
+            at = jnp.tile(segment_positions(segments[:, :half]), (1, 2))
+        else:
+            at = segment_positions(segments) if sliding or probed else None
+        if sliding or streams:
             q, k = rotary(q, at, cfg.rope_theta), rotary(k, at, cfg.rope_theta)
         if probed:  # the call and the record of it read these very arrays (see pattern_hidden's note)
             q, k, v = jax.lax.optimization_barrier((q, k, v))
     with jax.named_scope(call):
-        att = _attend(q, k, v, segments, cfg.attn_block, window=cfg.window if sliding else None)
+        if streams:
+            att = _attend(q, k, v, segments, cfg.attn_block, diffusion=cfg.diffusion_block)
+        else:
+            att = _attend(q, k, v, segments, cfg.attn_block, window=cfg.window if sliding else None)
     record = None
-    if probed:
+    if probed and streams:
+        def of(a, lo):  # [B, H', 2L, Dh] -> one head's rows of the stream that starts at ``lo``, float32
+            return jax.lax.dynamic_slice_in_dim(a, lo, half, axis=1).astype(jnp.float32)
+
+        held = probe_head // (h // hkv)
+        keys, values = jnp.take(k, held, axis=1), jnp.take(v, held, axis=1)
+        asked, given = jnp.take(q, probe_head, axis=1), jnp.take(att, probe_head, axis=1)
+        where, pos = sample_at[:, :, None], jnp.take_along_axis(at[:, :half], sample_at, axis=1)
+        record = {"scan": {"k_bda": of(keys, 0), "v_bda": of(values, 0),
+                           "k_bda_noised": of(keys, half), "v_bda_noised": of(values, half)},
+                  "router": {"q_bda": jnp.take_along_axis(of(asked, half), where, axis=1),
+                             "att_bda": jnp.take_along_axis(of(given, half), where, axis=1),
+                             "q_bda_clean": jnp.take_along_axis(of(asked, 0), where, axis=1),
+                             "att_bda_clean": jnp.take_along_axis(of(given, 0), where, axis=1),
+                             "bda_pos": pos, "bda_block": pos // cfg.diffusion_block}}
+    elif probed:
         def sampled(a):  # [B, H, L, Dh] -> the probed head's rows at sample_at, [B, S, Dh]
             return jnp.take_along_axis(jnp.take(a, probe_head, axis=1), sample_at[:, :, None],
                                        axis=1).astype(jnp.float32)
@@ -1177,7 +1254,7 @@ def gqa_mixer_probed(p, x, segments, cfg: PatternLMConfig, sliding: bool = False
                   "router": {"q_swa": sampled(q), "att_swa": sampled(att),
                              "swa_pos": jnp.take_along_axis(at, sample_at, axis=1)}}
     with jax.named_scope(around):
-        if cfg.gqa_gate:
+        if cfg.gqa_gate and not streams:
             gate = jax.nn.sigmoid(
                 jnp.einsum("bld,dhk->bhlk", u, p["wg"].reshape(d, h, dh)).astype(jnp.float32))
             gated = (att.astype(jnp.float32) * gate).astype(x.dtype)
@@ -1488,7 +1565,7 @@ def ssm_mixer(p, x, segments, cfg: PatternLMConfig, probe_head=None):
 
 #: where a mixer's branch norm is counted: with the layer's other projections
 _BRANCH_SCOPE = {"gqa": "tfr.gqa", "swa": "tfr.swa_proj", "mla": "tfr.mla_proj", "kda": "tfr.kda_proj",
-                 "gdn": "tfr.gdn_proj", "ssm": "tfr.ssm_proj"}
+                 "gdn": "tfr.gdn_proj", "ssm": "tfr.ssm_proj", "bda": "tfr.bda_proj"}
 _RECURRENT = {"kda": kda_mixer, "gdn": gdn_mixer, "ssm": ssm_mixer}
 
 
@@ -1503,7 +1580,7 @@ def _joined(x, y, weight, cfg: PatternLMConfig, scope: str):
 
 
 def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=None,
-                   probe_head=None):
+                   probe_head=None, noised=None):
     """tokens, segment_ids [B, L+1] -> (x [B, L, D] before the final norm,
     visits [n_layers, experts_held], dropped [n_layers], probes);
     ``n_layers`` counts the layers that have experts.
@@ -1519,15 +1596,34 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
     layer's selection: its keys under ``scan`` and the rest beside the
     router's entries, each with a leading axis of 1. Likewise, with both
     ``sample_at`` and ``probe_head``, the first sliding-window layer's record
-    of that head's attention (:func:`gqa_mixer_probed`)."""
+    of that head's attention (:func:`gqa_mixer_probed`).
+
+    Under ``cfg.diffusion_block`` (block diffusion; ``noised`` [B, L+1], the
+    row with a share of its tokens replaced by ``cfg.mask_id``, is then
+    required) every layer runs TWO streams as one row of 2 L positions, the
+    clean row then the noised one: x comes back [B, 2L, D]; the streams share
+    every projection, the router and the one expert loop a layer (a pad of
+    either stream visits no expert) and part only inside the attention
+    (:func:`_attend`). The router's probe is then of the NOISED stream's
+    positions ``sample_at``, and the first layer's attention is recorded as a
+    sliding layer's is (:func:`gqa_mixer_probed`)."""
     l = tokens.shape[1] - 1
     if l != cfg.max_len:
         raise ValueError(
             f"packed batch carries {l} input tokens but cfg.max_len is {cfg.max_len} "
             f"(the packer's seq_len must match)")
     segments = segment_ids[:, :-1]
+    if bool(cfg.diffusion_block) != (noised is not None):
+        raise ValueError("a block-diffusion pattern is scored from the clean row AND the noised one; "
+                         "no other pattern takes a noised row")
     with jax.named_scope("tfr.embed"):
-        x = params["embed"][tokens[:, :-1]]
+        if noised is not None:  # two streams, one row: what follows sees 2 L positions
+            x = params["embed"][jnp.concatenate([tokens[:, :-1], noised[:, :-1]], axis=1)]
+            segments, l = jnp.tile(segments, (1, 2)), 2 * l
+            if sample_at is not None:
+                sample_at = sample_at + l // 2
+        else:
+            x = params["embed"][tokens[:, :-1]]
         if cfg.embed_scale:
             x = (x.astype(jnp.float32) * cfg.d_model ** 0.5).astype(x.dtype)
     b, _, d = x.shape
@@ -1536,6 +1632,12 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
     for kind, ffn, layer in zip(cfg.layer_pattern, ffn_kinds(cfg), params["layers"]):
         if kind == "swa" and "scan" not in probes:  # the first sliding layer, one head probed
             y, window = gqa_mixer_probed(layer, x, segments, cfg, True, sample_at, probe_head)
+            if window is not None:
+                selection, probes["scan"] = window["router"], window["scan"]
+        elif kind == "bda":  # likewise the first block-diffusion layer (its record samples a stream's own places)
+            first = "scan" not in probes and sample_at is not None
+            y, window = gqa_mixer_probed(layer, x, segments, cfg, False, sample_at - l // 2 if first else None,
+                                         probe_head if first else None, streams=True)
             if window is not None:
                 selection, probes["scan"] = window["router"], window["scan"]
         elif kind in ("gqa", "swa"):
@@ -1565,7 +1667,7 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
             continue
         with jax.named_scope("tfr.moe_route"):
             u = _pre_norm(x, layer, "moe_norm", cfg)
-            if cfg.n_group > 1 or cfg.branch_norms or kind == NONE:
+            if cfg.n_group > 1 or cfg.branch_norms or kind in (NONE, "bda"):
                 # ONE array for the router and for the probe of it. Left alone, the compiler
                 # computes the norm once for each reader, the two fusions round a few
                 # elements in a thousand to different bfloat16 neighbours, and the probe held
@@ -1578,7 +1680,7 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
             layer, u.reshape(b * l, d), held_offset=cfg.held_offset, top_k=cfg.top_k,
             routed_scale=cfg.routed_scale, tile=cfg.expert_tile,
             valid=(segments != 0).reshape(b * l), n_group=cfg.n_group, topk_group=cfg.topk_group,
-            limit=limit)
+            limit=limit, scoring=cfg.router_scoring)
         x = _joined(x, y.reshape(b, l, d), layer.get("post_ffn_norm"), cfg, "tfr.moe_experts")
         visits.append(n)
         dropped.append(lost)
@@ -1598,7 +1700,7 @@ def pattern_hidden(params, tokens, segment_ids, cfg: PatternLMConfig, sample_at=
     return x, jnp.stack(visits), jnp.stack(dropped), probes
 
 
-def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_head=None):
+def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_head=None, noised=None):
     """The scoring step over one packed batch. Returns a dict:
 
     ``logprob`` [B, L] float32: log p(tokens[:, t+1] | its document up to t)
@@ -1617,11 +1719,28 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
     log-sum-exp and the target's pick; gauge ``head.fused`` 1), elsewhere and
     at shapes ``head.head_tile`` declines a block of ``cfg.head_block`` tokens
     at a time (the plain form; ``head.fused`` 0). The sampled positions' are an
-    einsum over a handful of rows."""
+    einsum over a handful of rows.
+
+    A block-diffusion pattern (``cfg.diffusion_block``; ``TokenPacker(noise=)``
+    feeds it) takes ``noised`` [B, L+1] beside the clean row, runs both
+    streams through every layer (:func:`pattern_hidden`) and hands the noised
+    one alone to the head. Nothing is shifted there: ``logprob`` [B, L] is
+    ``log p(tokens[:, i])`` from the noised stream's position ``i`` where the
+    noised row holds ``cfg.mask_id`` (a masked position's logits are over its
+    OWN token), 0 elsewhere (pads, positions left as they were);
+    ``logits`` and the router's probe are the noised stream's at
+    ``sample_at``; ``probes`` carries the first layer's attention on its own
+    inputs (:func:`gqa_mixer_probed`). A document's bound is the caller's sum:
+    ``sum over blocks of (1 / t) sum of -logprob`` with the feed's ``noise_level``.
+    Every document has to start at a whole multiple of the block length in its
+    row, as that packer starts them: the attention takes a token's block from
+    its place (:func:`_attend`), on every backend. ``starts_off_block`` (int32)
+    counts the batch's documents that do not, whose scores are then of another
+    mask; a caller holds it to 0."""
     from tpu_tfrecord.metrics import METRICS
 
     x, visits, dropped, probes = pattern_hidden(params, tokens, segment_ids, cfg, sample_at,
-                                                probe_head)
+                                                probe_head, noised)
     if not set(_RECURRENT) & set(cfg.layer_pattern):
         probes.setdefault("scan", {})
     # the delta-rule layers of a kind have one shape, so one answer a kind of the
@@ -1669,20 +1788,38 @@ def score(params, tokens, segment_ids, sample_at, cfg: PatternLMConfig, probe_he
         one_document = np.ones((1, -(-cfg.max_len // tile) * tile), np.int32)
         band, triangle = (sum(pair_kinds(one_document, tile, tile, w)) for w in (cfg.window, None))
         METRICS.gauge("swa.pairs_walked_share", round(band / triangle, 6))
+    if cfg.diffusion_block:  # one shape for every layer: the kernel under the block mask, and what its grid walks
+        n, tile = cfg.diffusion_block, min(cfg.attn_block, cfg.max_len)
+        in_kernel = _takes_block_kernel(cfg.max_len, cfg.head_dim, cfg.attn_block, n)
+        METRICS.gauge("bda.kernel_layers", cfg.layer_pattern.count("bda") if in_kernel else 0)
+        METRICS.gauge("bda.block", n)
+        a_stream = np.ones((1, -(-cfg.max_len // tile) * tile), np.int32)
+        walked = sum(pair_kinds(np.tile(a_stream, 2), tile, tile, streams=True))
+        METRICS.gauge("bda.pairs_walked_share", round(walked / (2 * sum(pair_kinds(a_stream, tile, tile))), 6))
+        with jax.named_scope("tfr.lm_head"):
+            x = x[:, cfg.max_len:]  # the noised stream alone goes to the head
     b, l, d = x.shape
     with jax.named_scope("tfr.lm_head"):
         xn = _norm(x, params["final_norm"], cfg)
-        flat, targets = xn.reshape(b * l, d), tokens[:, 1:].reshape(b * l)
-        out, fused = _head.logprob(flat, params["head"], targets, cfg.head_block)
+        shifted = not cfg.diffusion_block  # a masked position's logits are over its own token
+        flat, targets = xn.reshape(b * l, d), tokens[:, 1:] if shifted else tokens[:, :-1]
+        out, fused = _head.logprob(flat, params["head"], targets.reshape(b * l), cfg.head_block)
         # 1 where the logits stay in VMEM a tile at a time, 0 where the plain form's blocks run
         METRICS.gauge("head.fused", int(fused))
-        scored = (segment_ids[:, 1:] == segment_ids[:, :-1]) & (segment_ids[:, :-1] != 0)
+        if shifted:
+            scored = (segment_ids[:, 1:] == segment_ids[:, :-1]) & (segment_ids[:, :-1] != 0)
+        else:
+            scored = (noised[:, :-1] == cfg.mask_id) & (segment_ids[:, :-1] != 0)
         logprob = jnp.where(scored, out.reshape(b, l), 0.0)
         sampled = jnp.take_along_axis(xn, sample_at[:, :, None], axis=1)
         sample_logits = jnp.einsum("bsd,dv->bsv", sampled, params["head"],
                                    preferred_element_type=jnp.float32)
     out = {"logprob": logprob, "logits": sample_logits, "visits": visits, "dropped": dropped,
            "probes": probes}
+    if cfg.diffusion_block:  # what the attention's rule for a block asks of the rows (:func:`_attend`): stays 0
+        segs = segment_ids[:, :-1]
+        first = jnp.concatenate([segs[:, :1], jnp.where(segs[:, 1:] != segs[:, :-1], segs[:, 1:], 0)], axis=1) != 0
+        out["starts_off_block"] = jnp.sum(first & (jnp.arange(l)[None] % cfg.diffusion_block != 0), dtype=jnp.int32)
     if "selected" in probes:
         out["selected"] = probes.pop("selected")
     return out

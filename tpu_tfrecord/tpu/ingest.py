@@ -12,7 +12,7 @@ stays off the critical path (the >=95% duty-cycle target, BASELINE.md).
 from __future__ import annotations
 
 import time
-from typing import Dict, Iterable, Iterator, List, Optional, Union
+from typing import Any, Dict, Iterable, Iterator, List, Optional, Tuple, Union
 
 import jax
 import numpy as np
@@ -419,13 +419,38 @@ class TokenPacker:
     it NEXT TO the dataset's `IteratorState` and a kill -9/resume
     replays the packed stream byte-identically (pinned by
     examples/train_lm.py's harness test).
+
+    ``noise=(block_length, mask_id, seed)`` (bin modes) is the input
+    pipeline of a block-diffusion model: dynamic masking. Beside ``tokens``
+    and ``segment_ids`` a batch then carries ``noised`` [B, L+1] int32, the
+    row with a share of its tokens replaced by ``mask_id``, and
+    ``noise_level`` [B, L+1] float32, each token's block's ``t`` (0 on
+    pads). A document's blocks are ``block_length`` tokens counted from its
+    OWN first token (its end id among them); block ``j`` draws ``t_j`` ~
+    U(0, 1] and each of its tokens is masked with probability ``t_j``,
+    independently. The draw is a counter-based generator (Philox) keyed by
+    ``seed`` at the counter of the document's number in the stream, a
+    block's ``t`` and a token's draw at their own places in that stream: a
+    document's noise does not depend on the bin it lands in, and the number
+    of documents placed rides ``state()`` / ``restore()``, so a restored
+    packer replays all four columns byte for byte. Two things about the
+    rows change with it: every document starts at a whole multiple of
+    ``block_length`` in its row (pads of segment 0 between documents, under
+    ``block_length`` a document), so that a block never straddles a
+    multiple of it (an attention kernel's key tile, ``models.attention``);
+    and a row's last column holds a pad or a document's END id, never
+    another token (a longer document is pre-split into chunks of L, not
+    L+1): such a model scores a token at its own position, so the consumer
+    reads ``row[:-1]`` as input AND target, and only a document that fills
+    its row goes without its end id scored.
+    Without ``noise`` the packer is byte for byte what it was.
     """
 
     _MODES = ("slice", "first_fit", "best_fit")
 
     def __init__(
         self, batch_size: int, seq_len: int, eos_id: int = 0,
-        packing: str = "slice",
+        packing: str = "slice", noise: Optional[Tuple[int, int, int]] = None,
     ):
         if batch_size < 1 or seq_len < 1:
             raise ValueError(
@@ -440,6 +465,18 @@ class TokenPacker:
         self.seq_len = seq_len
         self.eos_id = int(eos_id)
         self.packing = packing
+        if noise is not None:
+            if packing == "slice":
+                raise ValueError("noise needs a bin mode: a block is counted from its document's first token")
+            block, mask_id, seed = (int(n) for n in noise)
+            if block < 1 or mask_id == self.eos_id:
+                raise ValueError(f"noise {noise}: a block of at least 1 token and a mask id that is not the end id")
+            noise = (block, mask_id, seed)
+        self.noise = noise
+        self._align = noise[0] if noise else 1        # a document starts at a whole multiple of this in its row
+        self._split = seq_len + (0 if noise else 1)   # the tokens of a chunk of a document longer than a row
+        self._placed = 0                              # noise: documents (chunks) placed so far, the draw's counter
+        self._numbers: List[List[int]] = []           # ... and each open bin's documents' numbers
         self._buf: List[np.ndarray] = []   # chunks, flattened lazily
         self._buf_len = 0
         # bin modes: open row-bins, each a list of document chunks
@@ -475,34 +512,40 @@ class TokenPacker:
                 # long documents pre-split into cap-sized chunks; each chunk
                 # is its own attention segment (they cannot share a row and
                 # attend to each other anyway)
-                for at in range(0, arr.size, cap):
-                    self._place_chunk(arr[at : at + cap])
+                step = cap if arr.size <= cap else self._split
+                for at in range(0, arr.size, step):
+                    self._place_chunk(arr[at : at + step])
                 n_docs += 1
             rows = (len(self._pending) - ready) * self.batch_size
             tr.set_metadata(docs=n_docs, rows=rows, tokens=rows * cap)
 
     def _place_chunk(self, chunk: np.ndarray) -> None:
-        cap = self.seq_len + 1
+        cap, align = self.seq_len + 1, self._align
+        if self.noise:
+            number, self._placed = self._placed, self._placed + 1
         fit = -1
         if self.packing == "best_fit":
             best_room = cap + 1
             for i, used in enumerate(self._fill):
-                room = cap - used
+                room = cap - -(-used // align) * align    # from the next whole multiple on
                 if chunk.size <= room < best_room:
                     fit, best_room = i, room
         else:  # first_fit — the greedy binning baseline
             for i, used in enumerate(self._fill):
-                if chunk.size <= cap - used:
+                if chunk.size <= cap - -(-used // align) * align:
                     fit = i
                     break
-        if fit >= 0:
-            self._bins[fit].append(chunk)
-            self._fill[fit] += chunk.size
-            return
-        if len(self._bins) == self.batch_size:
-            self._close_bins()
-        self._bins.append([chunk])
-        self._fill.append(chunk.size)
+        if fit < 0:
+            if len(self._bins) == self.batch_size:
+                self._close_bins()
+            self._bins.append([])
+            self._fill.append(0)
+            if self.noise:
+                self._numbers.append([])
+        self._bins[fit].append(chunk)
+        self._fill[fit] = -(-self._fill[fit] // align) * align + chunk.size
+        if self.noise:
+            self._numbers[fit].append(number)
 
     def flush(self) -> None:
         """End of a finite stream (bin modes): close the open bins into one
@@ -514,22 +557,54 @@ class TokenPacker:
     def _close_bins(self) -> None:
         """Flush the B open bins into one pending {tokens, segment_ids}
         batch: rows pad to L+1 with EOS, pad segment id 0."""
-        cap = self.seq_len + 1
+        cap, align = self.seq_len + 1, self._align
         toks = np.full((self.batch_size, cap), self.eos_id, np.int32)
         segs = np.zeros((self.batch_size, cap), np.int32)
-        nonpad = 0
+        nonpad, lies = 0, []     # lies: (row, first column, tokens) of every chunk, the bins' order
         for r, b in enumerate(self._bins):
             at = 0
             for s, chunk in enumerate(b):
+                at = -(-at // align) * align
                 toks[r, at : at + chunk.size] = chunk
                 segs[r, at : at + chunk.size] = s + 1
+                lies.append((r, at, chunk.size))
                 at += chunk.size
-            nonpad += at
-        self._bins, self._fill = [], []
-        self._pending.append({"tokens": toks, "segment_ids": segs})
+                nonpad += chunk.size
+        batch = {"tokens": toks, "segment_ids": segs}
+        if self.noise:
+            batch.update(self._noised(toks, lies))
+        self._bins, self._fill, self._numbers = [], [], []
+        self._pending.append(batch)
         self._emitted_tokens += self.batch_size * cap
         self._emitted_nonpad += nonpad
         METRICS.gauge("pack.density", round(self.density(), 4))
+
+    def _noised(self, toks: np.ndarray, lies: List[Tuple[int, int, int]]) -> Dict[str, np.ndarray]:
+        """The open bins' rows ``toks`` noised: ``noised`` and ``noise_level``
+        (the class docstring has the law); ``lies``: (row, first column,
+        tokens) of every document, the bins' order. A document's draws come
+        from its number in the stream alone, so they are made here, as the
+        batch closes, and the open bins carry no noise of their own."""
+        block, mask_id, seed = self.noise
+        noised = toks.copy()
+        level = np.zeros(toks.shape, np.float32)
+        masked = positions = 0
+        # tracing.ANNOTATIONS: one span a batch
+        with trace("tfr:noise") as tr:
+            numbers = (number for bin_numbers in self._numbers for number in bin_numbers)
+            for (r, at, n), number in zip(lies, numbers):
+                rng = np.random.Generator(np.random.Philox(key=seed, counter=[0, 0, 0, number]))
+                t = (1.0 - rng.random(-(-n // block))).astype(np.float32)     # (0, 1], a block
+                mine = np.repeat(t, block)[:n]
+                hit = rng.random(n, dtype=np.float32) < mine
+                noised[r, at : at + n][hit] = mask_id
+                level[r, at : at + n] = mine
+                masked, positions = masked + int(hit.sum()), positions + n
+            tr.set_metadata(rows=len(self._bins), positions=positions, masked=masked)
+        METRICS.count("noise.masked", masked)
+        METRICS.count("noise.positions", positions)
+        METRICS.gauge("noise.masked_share", round(masked / max(positions, 1), 4))
+        return {"noised": noised, "noise_level": level}
 
     def density(self) -> float:
         """Fraction of emitted batch tokens that are real document tokens
@@ -587,18 +662,18 @@ class TokenPacker:
                 "residual": flat,
                 "pending": [b.tolist() for b in self._pending],
             }
-        return {
+        out = {
             "bins": [[c.tolist() for c in b] for b in self._bins],
             "pending": [
-                {
-                    "tokens": d["tokens"].tolist(),
-                    "segment_ids": d["segment_ids"].tolist(),
-                }
+                {name: a.tolist() for name, a in d.items()}
                 for d in self._pending
             ],
             "emitted_tokens": self._emitted_tokens,
             "emitted_nonpad": self._emitted_nonpad,
         }
+        if self.noise:  # the draw's counter, and whose draws the open bins' documents will get
+            out["noise"] = {"placed": self._placed, "numbers": [list(n) for n in self._numbers]}
+        return out
 
     def restore(self, state: dict) -> None:
         if self.packing == "slice":
@@ -613,14 +688,21 @@ class TokenPacker:
             [np.asarray(c, np.int32) for c in b]
             for b in state.get("bins", [])
         ]
-        self._fill = [sum(c.size for c in b) for b in self._bins]
+        self._fill = []
+        for b in self._bins:
+            used = 0
+            for c in b:
+                used = -(-used // self._align) * self._align + c.size
+            self._fill.append(used)
         self._pending = [
-            {
-                "tokens": np.asarray(d["tokens"], np.int32),
-                "segment_ids": np.asarray(d["segment_ids"], np.int32),
-            }
+            {name: np.asarray(a, np.float32 if name == "noise_level" else np.int32)
+             for name, a in d.items()}
             for d in state.get("pending", [])
         ]
+        if self.noise:
+            said = state.get("noise", {})
+            self._placed = int(said.get("placed", 0))
+            self._numbers = [[int(n) for n in numbers] for numbers in said.get("numbers", [])]
         self._emitted_tokens = int(state.get("emitted_tokens", 0))
         self._emitted_nonpad = int(state.get("emitted_nonpad", 0))
 

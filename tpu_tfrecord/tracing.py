@@ -69,6 +69,8 @@ ANNOTATIONS = {
     "tfr:pack": "host_batch_from_columnar or pack_mixed on one batch; rows, bytes out",
     "tfr:pack_tokens": "TokenPacker (bin modes) placing one reader batch's documents; docs in, "
                        "rows and tokens out",
+    "tfr:noise": "TokenPacker(noise=) noising one closing batch for a block-diffusion model: a level a "
+                 "block of each document, its tokens masked with that probability; rows, positions, masked",
     "tfr:h2d": "make_global_batch: the dispatch of one batch's copy; rows, bytes",
     "tfr:h2d_land": "the transfer thread's wait for that copy to land",
     "tfr:blocked.batch": "the decode thread's put waited on a full prefetch queue",

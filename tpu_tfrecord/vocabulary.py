@@ -64,6 +64,8 @@ COUNTERS: Dict[str, str] = {
     "read.skipped_shards": "shards dropped by on_corrupt/on_stall=skip_shard",
     "read.stalls": "reads converted to StallError by the deadline",
     "moe.visits_dropped": "visits to held experts that were not computed (stays 0)",
+    "noise.masked": "TokenPacker(noise=): document tokens replaced by the mask id",
+    "noise.positions": "TokenPacker(noise=): document tokens the noise was drawn for (pads excluded)",
     "read.deadline_misses": "per-read deadlines that fired",
     "read.hedges": "straggler hedge opens issued",
     "read.hedge_wins": "hedge backup finished before the primary",
@@ -209,6 +211,7 @@ GAUGES: Dict[str, str] = {
     "train.share.ckpt": "windowed share of step wall in checkpointing",
     "ckpt.inflight": "background checkpoint commits in flight (0 or 1)",
     "pack.density": "fraction of emitted packed tokens that are real (bin modes)",
+    "noise.masked_share": "TokenPacker(noise=), latest batch: document tokens replaced by the mask id over document tokens (about 1/2 under t ~ U(0, 1])",
     "lm.fsdp_param_bytes": "per-device at-rest param bytes under the fsdp layout",
     "moe.dropped_fraction": "latest per-step dropped-token fraction",
     "moe.visits_max_over_mean": "held experts, latest step: the busiest over the mean (most uneven layer)",
@@ -230,6 +233,9 @@ GAUGES: Dict[str, str] = {
     "moe.tile_fill": "held experts, latest step recorded: real visits over the rows the expert loops compute, each expert's visits rounded up to whole units (emptiest layer; lm.record_moe_counters)",
     "swa.kernel_layers": "pattern LM, the score program last traced: sliding-window layers whose attention took the Pallas kernel under a window (0 off a TPU)",
     "swa.pairs_walked_share": "pattern LM, the score program last traced: the block pairs a sliding-window layer walks (the band) over the pairs at or under the diagonal of a row",
+    "bda.kernel_layers": "pattern LM, the score program last traced: block-diffusion layers whose attention took the Pallas kernel under the block mask (0 off a TPU)",
+    "bda.block": "pattern LM, the score program last traced: tokens a block of the block-diffusion layers' mask, counted from a document's own first token",
+    "bda.pairs_walked_share": "pattern LM, the score program last traced: the block pairs a block-diffusion layer's kernel walks for a row's two streams (each stream's triangle and a noised query block's own block) over the pairs at or under the diagonals of two causal rows",
     "moe.gate_entropy": "latest per-step router gate entropy",
     "moe.expert_imbalance": "latest per-step expert imbalance",
     "pipeline.bubble_fraction": "latest per-step pipeline bubble fraction",
@@ -249,6 +255,7 @@ SPANS: Dict[str, str] = {
     "tfr:cache": "one cached chunk serve (shard-attributed; rows)",
     "tfr:pack": "one batch through host_batch_from_columnar or pack_mixed (rows, bytes out)",
     "tfr:pack_tokens": "one reader batch's documents placed by TokenPacker (docs in, rows and tokens out)",
+    "tfr:noise": "one closing batch noised by TokenPacker(noise=): a level a block, tokens masked with that probability (rows, positions, masked)",
     "tfr:h2d": "the dispatch of one batch's host-to-device copy (rows, bytes)",
     "tfr:h2d_land": "the transfer thread's wait for that copy to land",
     "tfr:blocked.batch": "the decode thread's put waited on a full prefetch queue",
